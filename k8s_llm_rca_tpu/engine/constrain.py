@@ -1095,7 +1095,7 @@ def make_grammar(name, tokenizer: Tokenizer, prefer_native: bool = True):
     if name == "json":
         # bounded-depth DFA first: generic JSON then rides the engines'
         # on-device constrained scan like schema grammars (the unbounded
-        # automaton cannot compile — VERDICT r2 item 6).  The bounds
+        # automaton cannot compile — round-2 review item 6).  The bounds
         # restrict output to canonical JSON of modest depth/size, which is
         # strictly parseable; oversized vocabularies blow the table budget
         # and fall through to the unbounded host-side grammars.

@@ -11,6 +11,7 @@ engine/constrain.JsonGrammar; parity is asserted by tests/test_native.py.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -23,12 +24,12 @@ from k8s_llm_rca_tpu.utils.logging import get_logger
 log = get_logger(__name__)
 
 _PKG_DIR = os.path.dirname(os.path.abspath(__file__))
-_LIB_PATH = os.path.join(_PKG_DIR, "libk8s_rca_native.so")
+_LIB_STEM = "libk8s_rca_native"
 _CSRC_DIR = os.path.join(os.path.dirname(os.path.dirname(_PKG_DIR)), "csrc")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_build_failed = False
+_build_error: Optional[str] = None
 
 # status codes (csrc/native.cpp)
 OK = 0
@@ -41,64 +42,92 @@ ERR_BAD_ARG = 6
 ERR_GRAMMAR_VIOLATION = 7
 
 
-def _stale() -> bool:
-    """True when the .so is missing or older than any csrc/ source."""
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
+def _existing_libs() -> List[str]:
+    return sorted(os.path.join(_PKG_DIR, f) for f in os.listdir(_PKG_DIR)
+                  if f.startswith(_LIB_STEM) and f.endswith(".so"))
+
+
+def lib_path() -> Optional[str]:
+    """Where the library built from the csrc/ sources ON DISK lives: the
+    file name carries a digest of their content, so a library built from
+    other sources (an earlier commit, another checkout copied over this
+    one) is never mistaken for current — file times say nothing after a
+    copy.  Installed without sources: whatever library is present."""
     try:
-        sources = os.listdir(_CSRC_DIR)
+        names = sorted(os.listdir(_CSRC_DIR))
     except OSError:
-        return False                 # installed without sources: use as-is
-    return any(os.path.getmtime(os.path.join(_CSRC_DIR, f)) > lib_mtime
-               for f in sources)
+        libs = _existing_libs()
+        return libs[-1] if libs else None
+    digest = hashlib.sha256()
+    for name in names:
+        digest.update(name.encode())
+        with open(os.path.join(_CSRC_DIR, name), "rb") as f:
+            digest.update(f.read())
+    return os.path.join(_PKG_DIR,
+                        f"{_LIB_STEM}-{digest.hexdigest()[:16]}.so")
+
+
+def build_error() -> Optional[str]:
+    """Why the last build or load failed (None: no failure so far)."""
+    return _build_error
 
 
 def ensure_built() -> bool:
-    """Build csrc/ into the package tree if missing or stale; True when a
-    current .so is present.  The library is compiled to a process-unique
-    temp path and atomically renamed, so concurrent first-builds from
-    several processes can't hand each other a half-written file."""
-    global _build_failed
-    if _build_failed:
+    """Build csrc/ into the package tree unless the library for the
+    current sources is already there; True when it is present.  The
+    library is compiled to a process-unique temp path and atomically
+    renamed, so concurrent first-builds from several processes can't hand
+    each other a half-written file."""
+    global _build_error
+    if _build_error is not None:
         return False
-    if not _stale():
+    path = lib_path()
+    if path is None:
+        _build_error = (f"no library in {_PKG_DIR} and no sources in "
+                        f"{_CSRC_DIR}")
+        return False
+    if os.path.exists(path):
         return True
     with _lock:
-        if not _stale():
+        if os.path.exists(path):
             return True
-        tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+        tmp = f"{path}.{os.getpid()}.tmp"
         try:
             subprocess.run(["make", "-C", _CSRC_DIR, "-B", f"OUT={tmp}"],
                            check=True, capture_output=True, timeout=120)
-            os.replace(tmp, _LIB_PATH)
+            os.replace(tmp, path)
         except (OSError, subprocess.SubprocessError) as e:
-            log.warning("native build failed, using Python fallbacks: %s", e)
-            _build_failed = True
+            stderr = getattr(e, "stderr", None) or b""
+            _build_error = (f"{e}: "
+                            f"{stderr.decode(errors='replace')[-2000:]}")
+            log.warning("native build failed, using the Python "
+                        "components: %s", _build_error)
             return False
         finally:
             if os.path.exists(tmp):
                 os.remove(tmp)
-    return os.path.exists(_LIB_PATH)
+        for stale in _existing_libs():
+            if stale != path:
+                os.remove(stale)
+    return True
 
 
 def load_library() -> Optional[ctypes.CDLL]:
     """The loaded library, building it first if necessary; None when
     unavailable (callers fall back to Python)."""
-    global _lib
+    global _lib, _build_error
     if _lib is not None:
         return _lib
     if not ensure_built():
         return None
-    global _build_failed
     with _lock:
         if _lib is None:
             try:
-                lib = ctypes.CDLL(_LIB_PATH)
+                lib = ctypes.CDLL(lib_path())
                 _configure(lib)
             except OSError as e:     # corrupt/incompatible .so: fall back
+                _build_error = f"load failed: {e}"
                 log.warning("native library failed to load: %s", e)
-                _build_failed = True
                 return None
             _lib = lib
     return _lib

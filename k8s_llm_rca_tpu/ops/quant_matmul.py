@@ -36,18 +36,18 @@ first (the scale is constant over K), numerically within bf16/f32
 accumulation tolerance of the dq() reference — what
 tests/test_quant_matmul.py pins for every (bits x layout x shape) cell.
 
-Capability gating: this host has no Pallas-on-TPU lowering, so the
-``qmm*`` shims take the kernel path only on a real TPU backend and fall
-back to the byte-identical ``dq()`` XLA expressions everywhere else —
+Dispatch: the ``qmm*`` shims take the kernel path only on a TPU backend
+and use the byte-identical ``dq()`` XLA expressions everywhere else —
 CPU engines with ``ModelConfig.fused_quant_matmul=True`` stay greedy
 byte-identical by construction, and GSPMD-sharded consumption (which
-pallas_call cannot partition) also lands on the fallback.  Shard-LOCAL
+pallas_call cannot partition) also lands on the XLA expression.  Shard-LOCAL
 consumption inside shard_map stage bodies (PP×TP, weights repacked by
 quant.repack_nibbles_grouped and unwrapped at the boundary) runs the
 kernel on its self-contained split-half shard.  Grouped-repacked tensors
 consumed GLOBALLY raise a loud ValueError (quant._reject_grouped).
-Kernels themselves are validated in interpret mode on CPU, the
-tests/test_kernels.py pattern.
+Kernels themselves are validated in interpret mode on CPU
+(tests/test_quant_matmul.py) and compiled for a described v5e chip at
+Llama-3-8B widths (tests/test_aot_compile.py).
 """
 
 from __future__ import annotations
@@ -71,11 +71,6 @@ def _q():
     return quant
 
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both so the
-# interpret-mode tests run on every jax this framework targets
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams", None)
-
 # block-size targets: K tiles deep (weight streaming amortizes the
 # revisit of x), M/N moderate so the f32 scratch stays small.  _blk
 # clamps each to the largest divisor of the actual dim, so tiny test
@@ -89,6 +84,10 @@ def _interp(interpret: Optional[bool]) -> bool:
     return interpret
 
 
+def _sem(*semantics: str):
+    return pltpu.CompilerParams(dimension_semantics=semantics)
+
+
 def _blk(dim: int, target: int) -> int:
     b = min(dim, target)
     while dim % b:
@@ -96,20 +95,20 @@ def _blk(dim: int, target: int) -> int:
     return b
 
 
-def _params(sem):
-    if _CompilerParams is None:
-        return {}
-    return {"compiler_params": _CompilerParams(dimension_semantics=sem)}
+# Mosaic has no int8 vector shift ("failed to legalize 'arith.shli'" on
+# vector<..xi8>), so the nibbles are split on int32 lanes, the same way
+# ops/paged_attention.py unpacks int4 KV pages.
 
 
 def _lo_nibbles(p):
-    # (p << 4) >> 4 sign-extends the low nibble without a select — the
+    # (p << 28) >> 28 sign-extends the low nibble without a select — the
     # arithmetic-shift twin of quant._unpack_nibbles's where()
-    return jnp.right_shift(jnp.left_shift(p, 4), 4)
+    p = p.astype(jnp.int32)
+    return jnp.right_shift(jnp.left_shift(p, 28), 28)
 
 
 def _hi_nibbles(p):
-    return jnp.right_shift(p, 4)
+    return jnp.right_shift(p.astype(jnp.int32), 4)
 
 
 # ---------------------------------------------------------------------------
@@ -261,7 +260,7 @@ def _matmul_kn(x2, w, interpret: bool):
             out_shape=jax.ShapeDtypeStruct((m, n), x2.dtype),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
             interpret=interpret,
-            **_params(("parallel", "parallel", "arbitrary")),
+            compiler_params=_sem("parallel", "parallel", "arbitrary"),
         )(x2, w.q, w.scale.reshape(1, n))
     n_packed = w.q.shape[1]                           # logical N / 2
     bnp = _blk(n_packed, _BN)
@@ -280,7 +279,7 @@ def _matmul_kn(x2, w, interpret: bool):
         scratch_shapes=[pltpu.VMEM((bm, bnp), jnp.float32),
                         pltpu.VMEM((bm, bnp), jnp.float32)],
         interpret=interpret,
-        **_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=_sem("parallel", "parallel", "arbitrary"),
     )(x2, w.q, w.scale.reshape(2, n_packed))
     # [M, 2, N/2] -> [M, N]: row-major flatten restores the split-half
     # column order (lo block = columns [0, N/2), hi = [N/2, N))
@@ -307,7 +306,7 @@ def _matmul_nk(x2, w, interpret: bool):
             out_shape=jax.ShapeDtypeStruct((m, n), x2.dtype),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
             interpret=interpret,
-            **_params(("parallel", "parallel", "arbitrary")),
+            compiler_params=_sem("parallel", "parallel", "arbitrary"),
         )(x2, w.q, scale)
     k_packed = w.q.shape[1]                           # K / 2
     bkp = _blk(k_packed, _BK)
@@ -328,7 +327,7 @@ def _matmul_nk(x2, w, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((m, n), x2.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        **_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=_sem("parallel", "parallel", "arbitrary"),
     )(x_lo, x_hi, w.q, scale)
 
 
@@ -355,7 +354,8 @@ def _matmul_ekn(xe, w, interpret: bool):
             out_shape=jax.ShapeDtypeStruct((e, m, n), xe.dtype),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
             interpret=interpret,
-            **_params(("parallel", "parallel", "parallel", "arbitrary")),
+            compiler_params=_sem("parallel", "parallel", "parallel",
+                                 "arbitrary"),
         )(xe, w.q, w.scale.reshape(e, 1, n))
     n_packed = w.q.shape[2]
     bnp = _blk(n_packed, _BN)
@@ -377,7 +377,8 @@ def _matmul_ekn(xe, w, interpret: bool):
         scratch_shapes=[pltpu.VMEM((bm, bnp), jnp.float32),
                         pltpu.VMEM((bm, bnp), jnp.float32)],
         interpret=interpret,
-        **_params(("parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=_sem("parallel", "parallel", "parallel",
+                                 "arbitrary"),
     )(xe, w.q, w.scale.reshape(e, 2, n_packed))
     return out.reshape(e, m, 2 * n_packed)
 

@@ -27,6 +27,7 @@ from k8s_llm_rca_tpu.engine.engine import InferenceEngine
 from k8s_llm_rca_tpu.faults import inject
 from k8s_llm_rca_tpu.obs import trace as obs_trace
 from k8s_llm_rca_tpu.utils import pages, wal
+from k8s_llm_rca_tpu.utils.logging import METRICS
 from k8s_llm_rca_tpu.utils.tokenizer import Tokenizer
 
 
@@ -187,6 +188,13 @@ class EngineBackend:
         ids = self.tokenizer.encode(prompt + opts.forced_prefix, add_bos=True)
         grammar = make_grammar(opts.grammar, self.tokenizer,
                                prefer_native=self.engine.engine_cfg.native)
+        # how this run will decode: a compiled DFA rides the jitted scan,
+        # an interpreted FSM holds the WHOLE batch to one step per tick
+        # (engine._scan_chunk)
+        mode = ("free" if grammar is None
+                else "dfa" if getattr(grammar, "tables", None) is not None
+                else "interpreted")
+        METRICS.inc(f"serve.grammar.{mode}.{opts.assistant_name}")
         min_budget = getattr(grammar, "min_budget", None)
         if min_budget is not None:
             # check the budget AFTER engine clamping: a long prompt shrinks

@@ -8,11 +8,9 @@ chosen by config, and the hermetic in-memory backends are first-class.
 from __future__ import annotations
 
 import argparse
-from typing import Optional, Tuple
+from typing import Tuple
 
-from k8s_llm_rca_tpu.config import (
-    MODEL_REGISTRY, EngineConfig, RCAConfig, TINY,
-)
+from k8s_llm_rca_tpu.config import MODEL_REGISTRY, EngineConfig
 from k8s_llm_rca_tpu.graph import InMemoryGraphExecutor
 from k8s_llm_rca_tpu.graph.fixtures import build_metagraph, build_stategraph
 from k8s_llm_rca_tpu.rca.oracle import OracleBackend
@@ -26,8 +24,8 @@ def add_common_args(parser: argparse.ArgumentParser) -> None:
                         help="LM backend: scripted oracle (hermetic) or the "
                              "TPU inference engine")
     parser.add_argument("--model", default="tiny",
-                        help=f"model preset for --backend engine: "
-                             f"{sorted(MODEL_REGISTRY)}")
+                        choices=sorted(MODEL_REGISTRY),
+                        help="model preset for --backend engine")
     parser.add_argument("--max-batch", type=int, default=8)
     parser.add_argument("--max-seq-len", type=int, default=2048)
     parser.add_argument("--decode-chunk", type=int, default=None,
@@ -65,28 +63,38 @@ def add_common_args(parser: argparse.ArgumentParser) -> None:
 
 
 def build_service(args) -> AssistantService:
-    tokenizer = get_tokenizer()
     if args.backend == "oracle":
-        return AssistantService(OracleBackend(tokenizer))
+        return AssistantService(OracleBackend(get_tokenizer()))
     # engine backend: build the model + continuous-batching engine
     import jax
 
     from k8s_llm_rca_tpu.engine import make_engine
     from k8s_llm_rca_tpu.models import llama
+    from k8s_llm_rca_tpu.models.quant import (
+        quantize_params, quantizing_transform,
+    )
+    from k8s_llm_rca_tpu.runtime.compile_cache import enable_compile_cache
     from k8s_llm_rca_tpu.serve.backend import EngineBackend
 
-    model_cfg = MODEL_REGISTRY.get(args.model, TINY)
-    if getattr(args, "weights", None):
+    enable_compile_cache()
+    model_cfg = MODEL_REGISTRY[args.model]
+    # grammar masks are [tokenizer vocab] and meet logits of [model vocab]:
+    # the byte tokenizer is padded to the model's width
+    tokenizer = get_tokenizer(vocab_size=model_cfg.vocab_size)
+    bits = 4 if args.int4 else 8 if args.int8 else None
+    if args.weights:
         from k8s_llm_rca_tpu.models.loader import load_llama
 
         params = load_llama(model_cfg, args.weights)
+        if bits:
+            params = quantize_params(params, bits=bits)
     else:
-        params = llama.init_params(model_cfg, jax.random.PRNGKey(0))
-    if getattr(args, "int8", False) or getattr(args, "int4", False):
-        from k8s_llm_rca_tpu.models.quant import quantize_params
-
-        params = quantize_params(
-            params, bits=4 if getattr(args, "int4", False) else 8)
+        # quantize each weight as it is created: a full bf16 llama3-8b is
+        # 16 GB, the whole HBM of one v5e chip
+        params = llama.init_params(
+            model_cfg, jax.random.PRNGKey(0),
+            tensor_transform=quantizing_transform(bits=bits) if bits
+            else None)
     # the CLI default (2048) may exceed a small preset's RoPE table; clamp
     # so `--backend engine` works out of the box for every --model
     max_seq = min(args.max_seq_len, model_cfg.max_seq_len)
@@ -97,9 +105,8 @@ def build_service(args) -> AssistantService:
             "clamping --max-seq-len %d to %s's model maximum %d",
             args.max_seq_len, model_cfg.name, max_seq)
     ecfg_kw = dict(max_batch=args.max_batch, max_seq_len=max_seq,
-                   paged=getattr(args, "paged", False),
-                   kv_cache_dtype=getattr(args, "kv_dtype", None))
-    if getattr(args, "decode_chunk", None) is not None:
+                   paged=args.paged, kv_cache_dtype=args.kv_dtype)
+    if args.decode_chunk is not None:
         ecfg_kw["decode_chunk"] = args.decode_chunk   # else EngineConfig's
     engine = make_engine(model_cfg, EngineConfig(**ecfg_kw),
                          params, tokenizer)

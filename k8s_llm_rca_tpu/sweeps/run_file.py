@@ -75,19 +75,12 @@ def load_corpus(path: str) -> list:
     return messages
 
 
-def scan_output(output_path: str, truncate_partial: bool = False):
-    """Resumability scan: (completed records' error_messages, character
-    offset past the last COMPLETE record).  The file is a stream of
-    concatenated pretty-printed JSON objects (reference format); a crash
-    mid-append leaves a partial tail object, which the offset excludes —
-    ``truncate_partial`` rewrites the file without it (one read, in here,
-    so resume doesn't re-read the whole output just to truncate)."""
-    if not os.path.exists(output_path):
-        return [], 0
-    with open(output_path) as f:
-        text = f.read()
+def _complete_records(text: str):
+    """(records, character offset past the last COMPLETE one) of an output
+    stream: concatenated pretty-printed JSON objects (reference format),
+    possibly ending in the partial object a crash mid-append leaves."""
     decoder = json.JSONDecoder()
-    idx, msgs, end = 0, [], 0
+    idx, records, end = 0, [], 0
     while idx < len(text):
         while idx < len(text) and text[idx].isspace():
             idx += 1
@@ -97,8 +90,29 @@ def scan_output(output_path: str, truncate_partial: bool = False):
             obj, idx = decoder.raw_decode(text, idx)
         except ValueError:
             break                         # trailing partial record
-        msgs.append(obj.get("error_message"))
+        records.append(obj)
         end = idx
+    return records, end
+
+
+def load_records(output_path: str) -> list:
+    """Every complete record in the output file."""
+    with open(output_path) as f:
+        return _complete_records(f.read())[0]
+
+
+def scan_output(output_path: str, truncate_partial: bool = False):
+    """Resumability scan: (completed records' error_messages, character
+    offset past the last COMPLETE record).  A crash mid-append leaves a
+    partial tail object, which the offset excludes — ``truncate_partial``
+    rewrites the file without it (one read, in here, so resume doesn't
+    re-read the whole output just to truncate)."""
+    if not os.path.exists(output_path):
+        return [], 0
+    with open(output_path) as f:
+        text = f.read()
+    records, end = _complete_records(text)
+    msgs = [obj.get("error_message") for obj in records]
     if truncate_partial and len(text.rstrip()) > end:
         log.warning("truncating partial tail record in %s (crash artifact)",
                     output_path)
@@ -118,7 +132,10 @@ def completed_incidents(output_path: str) -> int:
     return len(scan_output(output_path)[0])
 
 
-def main(argv=None) -> dict:
+def main(argv=None, service=None) -> dict:
+    """``service``: drain through this AssistantService instead of building
+    one from the arguments — for a caller that has already paid for the
+    weights or wants the engine's counters afterwards (chip_smoke.py)."""
     parser = argparse.ArgumentParser(description=__doc__)
     add_common_args(parser)
     parser.add_argument("--input", default="data/incidents.csv")
@@ -156,6 +173,9 @@ def main(argv=None) -> dict:
                      "scheduler over ONE engine; it composes with neither "
                      "--replicas (engine per device) nor --workers "
                      "(thread per incident)")
+    if service is not None and args.replicas > 1:
+        parser.error("--replicas builds one engine per device and cannot "
+                     "drain through a caller's service")
     if args.concurrency > 1 and not args.fresh_threads:
         parser.error("--concurrency > 1 requires --fresh-threads: "
                      "interleaved incidents on persistent stage threads "
@@ -196,12 +216,13 @@ def main(argv=None) -> dict:
     sweep_sched = None
     if args.concurrency > 1:
         costs, failures, per_replica, sweep_sched = _drain_pipelined(
-            args, messages, args.concurrency)
+            args, messages, args.concurrency, service)
     elif args.workers > 1:
         costs, failures, per_replica = _drain_shared(args, messages,
-                                                     args.workers)
+                                                     args.workers, service)
     elif n_rep == 1:
-        costs, failures, per_replica = _drain_serial(args, messages)
+        costs, failures, per_replica = _drain_serial(args, messages,
+                                                     service)
     else:
         costs, failures, per_replica = _drain_replicated(args, messages,
                                                          n_rep)
@@ -225,8 +246,8 @@ def main(argv=None) -> dict:
     return summary
 
 
-def _build_pipeline(args):
-    service = build_service(args)
+def _build_pipeline(args, service=None):
+    service = service or build_service(args)
     meta, state = build_executors(args)
     return RCAPipeline(
         service, meta, state, RCAConfig(model=args.model,
@@ -253,8 +274,8 @@ def _run_one(pipeline, message, output_path, lock=None):
     return result["time_cost"], failed
 
 
-def _drain_serial(args, messages):
-    pipeline = _build_pipeline(args)
+def _drain_serial(args, messages, service=None):
+    pipeline = _build_pipeline(args, service)
     costs, failures = [], 0
     for message in messages:
         cost, failed = _run_one(pipeline, message, args.output)
@@ -265,7 +286,7 @@ def _drain_serial(args, messages):
     return costs, failures, None
 
 
-def _drain_pipelined(args, messages, k):
+def _drain_pipelined(args, messages, k, service=None):
     """Pipelined sweep: K incidents in flight on ONE service via the
     single-threaded ``SweepScheduler`` (rca/scheduler.py) — each pipeline
     submits its next LLM run and yields, the scheduler pumps the shared
@@ -276,7 +297,7 @@ def _drain_pipelined(args, messages, k):
     Records are appended at sweep end, in input order."""
     from k8s_llm_rca_tpu.rca.scheduler import IncidentFailure, SweepScheduler
 
-    service = build_service(args)       # ONE engine, shared by all slots
+    service = service or build_service(args)   # ONE engine for all slots
     executors = [build_executors(args) for _ in range(k)]
     pipelines = [
         RCAPipeline(
@@ -310,7 +331,7 @@ def _drain_pipelined(args, messages, k):
     return costs, failures, None, sched.stats.snapshot()
 
 
-def _drain_shared(args, messages, n_workers):
+def _drain_shared(args, messages, n_workers, service=None):
     """Shared-engine concurrent sweep: ``n_workers`` threads — each with
     its OWN RCAPipeline (own assistants/threads, so incident conversations
     stay isolated) — submit to ONE AssistantService/engine.  The
@@ -321,7 +342,7 @@ def _drain_shared(args, messages, n_workers):
     import queue
     import threading
 
-    service = build_service(args)       # ONE engine, shared by all workers
+    service = service or build_service(args)   # ONE engine for all workers
     work: "queue.Queue[str]" = queue.Queue()
     for m in messages:
         work.put(m)

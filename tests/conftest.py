@@ -17,6 +17,10 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
+# tests compile tiny programs by the thousand: keep the persistent compile
+# cache off whatever the entry points under test ask for
+# (runtime/compile_cache.py)
+jax.config.update("jax_enable_compilation_cache", False)
 
 import pytest  # noqa: E402
 

@@ -1,0 +1,225 @@
+"""Guards from the chip bring-up that need no chip.
+
+1. The main-path kernels compiled by the installed TPU compiler for a
+   DESCRIBED ``v5e:2x2`` chip at Llama-3-8B widths.  Interpret mode passes
+   kernels Mosaic refuses (an int8 vector shift, a mis-tiled block): these
+   compiles are what a later PR's kernel edit has to get through before it
+   costs chip time.  Nothing runs, so nothing here says a result is right —
+   tests/test_kernels.py and tests/test_quant_matmul.py do that in interpret
+   mode, chip_smoke.py on the chip.  Whole engine steps (16-25 s each) stay
+   in a scratch rehearsal (.claude/skills/verify/SKILL.md).
+2. No fallback hides the device: an unknown TPU kind has no peaks, a missing
+   TPU or a dead leg fails bench.py, an unknown ``--model`` is an argument
+   error, and the compile cache is placed from outside.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from k8s_llm_rca_tpu.config import LLAMA3_8B, MIXTRAL_8X7B
+from k8s_llm_rca_tpu.models.quant import QuantTensor, QuantTensor4
+from k8s_llm_rca_tpu.ops.flash_attention import flash_attention
+from k8s_llm_rca_tpu.ops.paged_attention import (
+    paged_attention, paged_attention_quant,
+)
+from k8s_llm_rca_tpu.ops.quant_matmul import (
+    quant_matmul, quant_matmul_experts, quant_matmul_head,
+)
+from k8s_llm_rca_tpu.runtime import compile_cache, profiling
+
+CFG = LLAMA3_8B
+H, KV, D = CFG.n_heads, CFG.n_kv_heads, CFG.head_dim
+BF16, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """Shapes placed on one chip of a described (not attached) v5e:2x2,
+    with the persistent compile cache off: a described-chip executable can
+    be written to the cache but not read back without a chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _compiles_with_kernel(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+class TestAttentionKernelsCompileForV5e:
+    # the bench's flagship pool (144 slots, page 64) and the pool the sweep
+    # CLI builds (16 slots, EngineConfig's page 16, 4096-token tables)
+    POOLS = [pytest.param(144, 64, 1864, 12, id="b144-page64"),
+             pytest.param(16, 16, 1024, 256, id="b16-page16")]
+
+    @pytest.mark.parametrize("b,page,n_pages,pages_per_seq", POOLS)
+    def test_paged_attention(self, chip, b, page, n_pages, pages_per_seq):
+        pool = chip((n_pages, page, KV * D), BF16)
+        _compiles_with_kernel(
+            functools.partial(paged_attention, interpret=False),
+            chip((b, H, D), BF16), pool, pool, chip((b,), I32),
+            chip((b, pages_per_seq), I32))
+
+    @pytest.mark.parametrize("packed", [False, True], ids=["int8", "int4"])
+    @pytest.mark.parametrize("b,page,n_pages,pages_per_seq", POOLS)
+    def test_paged_attention_quant(self, chip, b, page, n_pages,
+                                   pages_per_seq, packed):
+        pool = chip((n_pages, page, KV * D // (2 if packed else 1)), I8)
+        scales = chip((n_pages, page), F32)
+        _compiles_with_kernel(
+            functools.partial(paged_attention_quant, packed=packed,
+                              interpret=False),
+            chip((b, H, D), BF16), pool, pool, scales, scales,
+            chip((b,), I32), chip((b, pages_per_seq), I32))
+
+    def test_flash_attention_512(self, chip):
+        kv = chip((1, 512, KV, D), BF16)
+        _compiles_with_kernel(
+            functools.partial(flash_attention, interpret=False),
+            chip((1, 512, H, D), BF16), kv, kv, chip((1,), I32))
+
+
+def _weight(chip, bits, shape, scale_shape):
+    """A quantized weight of logical ``shape``: int4 packs the last dim."""
+    if bits == 8:
+        return QuantTensor(q=chip(shape, I8), scale=chip(scale_shape, BF16))
+    return QuantTensor4(q=chip((*shape[:-1], shape[-1] // 2), I8),
+                        scale=chip(scale_shape, BF16))
+
+
+class TestFusedDequantMatmulsCompileForV5e:
+    """int4 was refused before this file existed: ``arith.shli`` on
+    ``vector<8x128x4xi8>`` — the nibble split now widens to int32 first."""
+
+    @pytest.mark.parametrize("bits", [8, 4])
+    @pytest.mark.parametrize("m", [144, 4096])
+    @pytest.mark.parametrize("k,n", [
+        (CFG.hidden_size, CFG.intermediate_size),
+        (CFG.intermediate_size, CFG.hidden_size),
+        (CFG.hidden_size, CFG.kv_dim)])
+    def test_quant_matmul(self, chip, k, n, m, bits):
+        _compiles_with_kernel(
+            functools.partial(quant_matmul, interpret=False),
+            chip((m, k), BF16), _weight(chip, bits, (k, n), (1, n)))
+
+    @pytest.mark.parametrize("m", [144, 4096])
+    def test_quant_matmul_head_int4(self, chip, m):
+        v, k = CFG.vocab_size, CFG.hidden_size
+        _compiles_with_kernel(
+            functools.partial(quant_matmul_head, interpret=False),
+            chip((m, k), BF16), _weight(chip, 4, (v, k), (v, 1)))
+
+    def test_quant_matmul_experts_int4(self, chip):
+        cfg = MIXTRAL_8X7B
+        e, k, n = cfg.n_experts, cfg.hidden_size, cfg.intermediate_size
+        _compiles_with_kernel(
+            functools.partial(quant_matmul_experts, interpret=False),
+            chip((1, 144, k), BF16), _weight(chip, 4, (e, k, n), (e, 1, n)))
+
+
+class _Device:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+class TestNoFallbackHidesTheDevice:
+    def test_unknown_tpu_kind_has_no_peaks(self):
+        with pytest.raises(ValueError, match="TPU v9"):
+            profiling.chip_peaks(_Device("tpu", "TPU v9"))
+        with pytest.raises(ValueError, match="TPU v9"):
+            profiling.mfu(CFG, 100.0, 512, device=_Device("tpu", "TPU v9"))
+        assert profiling.chip_peaks(_Device("cpu", "cpu")) is None
+
+    def test_bench_fails_without_a_tpu(self, monkeypatch):
+        import bench
+
+        monkeypatch.setattr(bench, "_leg", lambda expr, timeout=0: {
+            "platform": "cpu", "kind": "cpu", "count": 1})
+        with pytest.raises(SystemExit) as e:
+            bench.main()
+        assert e.value.code not in (0, None)
+
+    def test_bench_leg_that_dies_raises(self):
+        import bench
+
+        with pytest.raises(bench.LegFailed, match="rc=1"):
+            bench._leg("1 / 0")
+        with pytest.raises(bench.LegFailed, match="timed out"):
+            bench._leg("__import__('time').sleep(60)", timeout=1)
+
+    def test_unknown_model_is_an_argument_error(self, capsys):
+        from k8s_llm_rca_tpu.sweeps import run_file
+
+        with pytest.raises(SystemExit) as e:
+            run_file.main(["--backend", "engine", "--model", "llama3-80b"])
+        assert e.value.code == 2
+        assert "invalid choice: 'llama3-80b'" in capsys.readouterr().err
+
+
+class TestBuildService:
+    def test_int4_weights_are_quantized_as_created(self):
+        """``--int4`` through build_service: every matmul weight is a
+        QuantTensor4, and the tokenizer is as wide as the model's vocab
+        (grammar masks meet the logits)."""
+        import argparse
+
+        from k8s_llm_rca_tpu.sweeps.common import (
+            add_common_args, build_service,
+        )
+
+        parser = argparse.ArgumentParser()
+        add_common_args(parser)
+        service = build_service(parser.parse_args(
+            ["--backend", "engine", "--model", "tiny", "--paged", "--int4",
+             "--kv-dtype", "int4", "--max-seq-len", "256"]))
+        engine = service.backend.engine
+        weights = [leaf for leaf in jax.tree.leaves(
+            engine.params, is_leaf=lambda x: isinstance(x, QuantTensor4))
+            if getattr(leaf, "ndim", 0) >= 2]
+        assert weights and all(isinstance(w, QuantTensor4) for w in weights)
+        assert engine.tokenizer.vocab_size == engine.model_cfg.vocab_size
+        assert engine.pool.k.dtype == jnp.int8          # packed int4 pages
+
+
+class TestCompileCachePlacedFromOutside:
+    @pytest.fixture
+    def cache_dir_restored(self):
+        before = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_env_var_set_code_sets_nothing(self, monkeypatch,
+                                           cache_dir_restored):
+        monkeypatch.setenv(compile_cache.ENV_VAR, "/somewhere/else")
+        before = jax.config.jax_compilation_cache_dir
+        assert compile_cache.enable_compile_cache() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_env_var_unset_fixed_path_in_checkout(self, monkeypatch,
+                                                  cache_dir_restored):
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert compile_cache.enable_compile_cache() == os.path.join(
+            repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            repo, ".jax_cache")

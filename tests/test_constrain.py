@@ -815,7 +815,7 @@ def test_bounded_json_depth_cap_rejects():
 
 
 def test_json_grammar_compiles_to_dfa_and_scan_parity():
-    """grammar="json" now rides the on-device DFA scan (VERDICT r2 item
+    """grammar="json" now rides the on-device DFA scan (round-2 review item
     6): chunked scan and stepwise host ticks emit identical parseable
     JSON from random weights."""
     import jax

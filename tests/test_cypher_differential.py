@@ -1,4 +1,4 @@
-"""Differential validation of graph/cypher.py (VERDICT r3+r4: the
+"""Differential validation of graph/cypher.py (round-3 review+r4: the
 interpreter's trail-uniqueness / var-length / direction semantics must be
 checked against something that is NOT the interpreter's own expectations).
 

@@ -195,7 +195,7 @@ def test_project_fields_reranks_and_caps():
 
 
 def test_rerank_fused_prompts_shrink_and_preserve_findings():
-    """VERDICT r2 item 8: with field-level rerank fusion ON, the analyzer
+    """round-2 review item 8: with field-level rerank fusion ON, the analyzer
     reads FEWER prompt tokens for the same incident while the report's
     findings (clue labels, missing-STATE scores, report schema) are
     preserved."""

@@ -191,7 +191,7 @@ class TestPagedAttentionQuant:
 
 
 class TestFlashSharded:
-    """flash under TP (VERDICT r2 item 7): the kernel runs PER HEAD SHARD
+    """flash under TP (round-2 review item 7): the kernel runs PER HEAD SHARD
     inside shard_map instead of conceding sharded prefill to XLA."""
 
     def _mesh(self, cpu_devices):

@@ -5,7 +5,7 @@ CONTRACT — mirroring the reference executor (reference
 common/neo4j_query_executor.py:6-24) — is testable: connectivity verified
 at construction, parameters passed through verbatim, results eagerly
 materialized (usable after the session closes), close() delegated to the
-driver.  VERDICT r1 item 10.
+driver.  round-1 review item 10.
 """
 
 import sys

@@ -439,7 +439,7 @@ def test_ep_sharded_engine_matches_unsharded(cpu_devices):
 
 
 def test_ep_engine_matches_dense(cpu_devices):
-    """Serving EP (VERDICT r1 item 4): an engine built with an expert-axis
+    """Serving EP (round-1 review item 4): an engine built with an expert-axis
     mesh — every MoE MLP dispatching through the all-to-all path, prefill
     AND decode — must emit the same greedy tokens as the dense
     soft-dispatch engine (lossless capacity)."""
@@ -520,7 +520,7 @@ def test_ep_mesh_validation():
 
 
 def test_paged_tp_engine_matches_unsharded(cpu_devices):
-    """Paged serving TP (VERDICT r1 item 5): the paged engine with
+    """Paged serving TP (round-1 review item 5): the paged engine with
     TP-sharded params AND the page pool sharded on the merged kv axis must
     emit the unsharded paged engine's greedy tokens."""
     from k8s_llm_rca_tpu.config import TINY, EngineConfig
@@ -590,7 +590,7 @@ def test_paged_tp_engine_quantized_pool(cpu_devices, kv_dtype):
 
 @pytest.mark.parametrize("use_kernel", [True, False])
 def test_paged_tp_kernel_matches_unsharded(cpu_devices, use_kernel):
-    """The paged-attention KERNEL under TP (VERDICT r4 item 3): decode
+    """The paged-attention KERNEL under TP (round-4 review item 3): decode
     runs ops.paged_attention_sharded — the Pallas kernel per head shard
     inside shard_map — and emits exactly the plain paged engine's greedy
     tokens.  Parametrized against the XLA path so a silent fallback
@@ -733,7 +733,7 @@ def test_contiguous_tp_engine_cache_sharded(cpu_devices):
 
 
 def test_pp_prefill_decode_matches_plain(cpu_devices):
-    """PP SERVING (VERDICT r1 item 9): pipelined prefill writes per-stage
+    """PP SERVING (round-1 review item 9): pipelined prefill writes per-stage
     KV (cache layer axis sharded over "stage") and the pipelined decode
     step — slot-group microbatches flowing GPipe-style — produces the
     plain path's exact greedy tokens over multiple steps."""
@@ -1380,7 +1380,7 @@ def test_cp_ep_requires_one_composed_mesh(cpu_devices):
 
 
 # ---------------------------------------------------------------------------
-# PP ENGINE integration (VERDICT r2 item 1): pp_mesh= on both engines
+# PP ENGINE integration (round-2 review item 1): pp_mesh= on both engines
 # ---------------------------------------------------------------------------
 
 
@@ -1502,7 +1502,7 @@ def test_pp_paged_prefix_cache_reuse(cpu_devices, kv_dtype):
 
 @pytest.mark.parametrize("kv_dtype", [None, "int8", "int4"])
 def test_pp_tp_paged_prefix_cache_reuse(cpu_devices, kv_dtype):
-    """Prefix caching composes with PP×TP (VERDICT r4 item 9 — the
+    """Prefix caching composes with PP×TP (round-4 review item 9 — the
     production mesh of the agent workload the cache was built for): a
     repeated prompt's second admission routes through the pipelined
     chunked prefix prefill whose stage bodies run the MANUAL-TP chunk

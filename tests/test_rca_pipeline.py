@@ -200,7 +200,7 @@ def test_pipeline_on_real_engine_backend_is_crash_safe():
 
 @pytest.mark.parametrize("paged", [False, True])
 def test_incident_completes_on_engine_backend(paged):
-    """VERDICT r1 item 3: the full pipeline on the REAL engine with random
+    """round-1 review item 3: the full pipeline on the REAL engine with random
     weights must COMPLETE — not merely fail gracefully.  Stage 1 is
     schema-constrained to the kind vocabulary (structured outputs), so the
     plan always names real kinds; stage 2 falls back to the deterministic
@@ -281,7 +281,7 @@ def test_incident_completes_on_engine_backend(paged):
 
 def test_auditor_rejects_label_injection():
     """Cypher can't parameterize labels; kinds interpolated into label
-    position must be identifier-whitelisted (VERDICT r1 weak #7)."""
+    position must be identifier-whitelisted (round-1 review weak #7)."""
     from k8s_llm_rca_tpu.rca.auditor import (
         ad_hoc_find_entity_name, find_loose_states, find_strict_states,
     )

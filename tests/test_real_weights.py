@@ -1,4 +1,4 @@
-"""Real-weights readiness path (VERDICT r1 item 7).
+"""Real-weights readiness path (round-1 review item 7).
 
 Every semantic path in this repo is otherwise validated against random
 weights or the scripted oracle — fine for mechanics, silent on whether

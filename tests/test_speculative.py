@@ -223,7 +223,7 @@ def test_feature_matrix_greedy_equivalence():
 @pytest.mark.parametrize("paged", [False, True])
 @pytest.mark.parametrize("grammar_name", ["schema", "json"])
 def test_speculative_dfa_greedy_exactness(paged, grammar_name):
-    """spec × DFA (VERDICT r2 item 6): with every grammar slot on one
+    """spec × DFA (round-2 review item 6): with every grammar slot on one
     compiled DFA, drafted tokens verify through the DFA ON DEVICE
     (engine.dfa_greedy_multi) — multi-token verify is kept and the output
     must equal the non-speculative greedy run token-for-token."""

@@ -52,7 +52,7 @@ def test_graft_dryrun_multichip():
 
 
 def test_run_file_replicated_oracle(tmp_path):
-    """DP sweep serving (VERDICT r1 item 6): N pipeline replicas drain one
+    """DP sweep serving (round-1 review item 6): N pipeline replicas drain one
     queue; every incident lands exactly once, per-replica accounting sums."""
     inp = str(tmp_path / "incidents.csv")
     out = str(tmp_path / "results.json")
