@@ -24,7 +24,7 @@ indices, which guards the silent-clamp semantics of dynamic_update_slice
 
 from __future__ import annotations
 
-import functools
+import dataclasses
 import itertools
 import time
 from dataclasses import dataclass, field
@@ -449,6 +449,59 @@ def validate_cp_divisibility(cp_seq_axis: str, n_cp: int, sizes) -> None:
             f"every prefill bucket and max_seq_len; offending sizes: {bad}")
 
 
+@dataclass(frozen=True)
+class SequenceTiming:
+    """One request's lifecycle, stamped on the engine's clock
+    (``EngineBase._now``): arrival at ``submit``, the first slot grant,
+    the host commit of its first and of its newest token.  A stamp the
+    sequence never reached is None.  ``seq_id`` is the key its
+    ``engine.request`` span carries."""
+
+    seq_id: int
+    t_arrival: float
+    t_admitted: Optional[float] = None
+    t_first: Optional[float] = None
+    t_last: Optional[float] = None
+    preemptions: int = 0
+
+    @property
+    def queue_wait_s(self) -> Optional[float]:
+        if self.t_admitted is None:
+            return None
+        return self.t_admitted - self.t_arrival
+
+    @property
+    def ttft_s(self) -> Optional[float]:
+        return None if self.t_first is None else self.t_first - self.t_arrival
+
+    @property
+    def decode_s(self) -> Optional[float]:
+        return None if self.t_first is None else self.t_last - self.t_first
+
+
+@dataclass
+class _Life:
+    """The mutable form of ``SequenceTiming`` (the same fields in the
+    same order, less the id): ONE record per sequence, handed from its
+    ``_Pending`` to its ``_Active`` and back through a preemption's
+    requeue, so the stamps survive every resume."""
+
+    t_arrival: float
+    t_admitted: Optional[float] = None
+    t_first: Optional[float] = None
+    t_last: Optional[float] = None
+    preemptions: int = 0
+
+    def admitted(self, now: float) -> None:
+        if self.t_admitted is None:
+            self.t_admitted = now
+
+    def committed(self, now: float) -> None:
+        if self.t_first is None:
+            self.t_first = now
+        self.t_last = now
+
+
 @dataclass
 class SequenceResult:
     seq_id: int
@@ -457,6 +510,7 @@ class SequenceResult:
     finish_reason: str          # "stop" | "eos" | "length" | "expired"
     prompt_tokens: int
     completion_tokens: int
+    timing: Optional[SequenceTiming] = None
 
 
 @dataclass
@@ -473,6 +527,7 @@ class _Active:
     # orders admission and preemption-victim selection.  Deadlines live in
     # the engine's _deadlines registry, not on the sequence records.
     priority: int = 1
+    life: Optional[_Life] = None        # always set by the engine
 
 
 @dataclass
@@ -483,6 +538,7 @@ class _Pending:
     stop_strings: Tuple[str, ...]
     grammar: Optional[object] = None
     priority: int = 1
+    life: Optional[_Life] = None        # always set by the engine
 
 
 class EngineBase:
@@ -603,7 +659,8 @@ class EngineBase:
         self._register(seq_id, prompt_ids)
         self._enqueue(
             _Pending(seq_id, prompt_ids, max_new, tuple(stop_strings),
-                     grammar, priority=int(priority)))
+                     grammar, priority=int(priority),
+                     life=_Life(self._now())))
         if deadline_s is not None:
             self._deadline_set(seq_id, self._now() + float(deadline_s))
         return seq_id
@@ -851,7 +908,8 @@ class EngineBase:
             self._enqueue(_Pending(
                 seq_id, prompt + gen, remaining,
                 tuple(s["stop_strings"]), g,
-                priority=int(s.get("priority", 1))))
+                priority=int(s.get("priority", 1)),
+                life=_Life(self._now())))
             if s.get("deadline") is not None:
                 self._deadline_set(seq_id, float(s["deadline"]))
             restored.append(seq_id)
@@ -987,17 +1045,20 @@ class EngineBase:
         (forced {slot: token}, allow [B, V] bool or None)."""
         forced = {}
         allow = None
-        for slot in active_slots:
-            st = self._active[slot]
-            if st.grammar is None:
-                continue
-            c = st.grammar.constraint(self._budget_remaining(st))
-            if c.force is not None:
-                forced[slot] = c.force
-            elif c.allow is not None:
-                if allow is None:
-                    allow = np.ones((n_slots, vocab), bool)
-                allow[slot] = c.allow
+        constrained = [s for s in active_slots
+                       if self._active[s].grammar is not None]
+        if not constrained:
+            return forced, allow
+        with profiling.annotate("engine.grammar_mask"):
+            for slot in constrained:
+                st = self._active[slot]
+                c = st.grammar.constraint(self._budget_remaining(st))
+                if c.force is not None:
+                    forced[slot] = c.force
+                elif c.allow is not None:
+                    if allow is None:
+                        allow = np.ones((n_slots, vocab), bool)
+                    allow[slot] = c.allow
         return forced, allow
 
     # -------------------------------------------------- tick + observability
@@ -1028,6 +1089,40 @@ class EngineBase:
             c = self._counts = {}
         c[name] = c.get(name, 0.0) + value
 
+    def _count_decode(self, steps: int) -> None:
+        """One decode dispatch of ``steps`` model steps (a scan's chunk,
+        1 for the stepwise and overlapped programs, the verified length
+        under speculation): ``engine.decode_step.count`` counts
+        dispatches, this counts the steps they ran."""
+        self._count("engine.decode_steps", steps)
+
+    def _settle_timing(self, st: _Active, tokens: int) -> SequenceTiming:
+        """Close a retiring sequence's lifecycle: the frozen record for
+        its ``SequenceResult``, the three durations into METRICS
+        (``engine.queue_wait`` / ``engine.ttft`` / ``engine.tpot``, read
+        as ``.total_s`` / ``.count``) and, under an active tracer, one
+        ``engine.request`` span from arrival to the newest token."""
+        timing = SequenceTiming(st.seq_id, *dataclasses.astuple(st.life))
+        if timing.t_admitted is not None:
+            METRICS.observe("engine.queue_wait", timing.queue_wait_s)
+        if timing.t_first is None:
+            return timing
+        METRICS.observe("engine.ttft", timing.ttft_s)
+        if tokens >= 2:
+            METRICS.observe("engine.tpot", timing.decode_s / (tokens - 1))
+        tr = obs_trace._ACTIVE
+        if tr is not None:
+            tr.add_span(
+                "engine.request", timing.t_arrival, timing.t_last,
+                cat="engine",
+                args={"seq": st.seq_id,
+                      "queue_wait_s": timing.queue_wait_s,
+                      "prefill_s": timing.t_first - timing.t_admitted,
+                      "decode_s": timing.decode_s,
+                      "tokens": tokens,
+                      "preemptions": timing.preemptions})
+        return timing
+
     # ---------------------------------------- overlapped hot loop (shared)
     #
     # docs/performance.md is the design note.  Invariants enforced here:
@@ -1046,14 +1141,19 @@ class EngineBase:
         ``engine.d2h_syncs`` when any input actually lives on device —
         the counter measures sync POINTS (each is one blocking round
         trip to the device whatever the payload count), not arrays
-        moved."""
-        if any(not isinstance(a, np.ndarray) for a in arrays):
-            self._count("engine.d2h_syncs")
-        for a in arrays:
-            start = getattr(a, "copy_to_host_async", None)
-            if start is not None:
-                start()
-        return tuple(host_np(a) for a in arrays)
+        moved — and timed as ONE ``engine.fetch`` span then; host arrays
+        pass through with neither."""
+        if all(isinstance(a, np.ndarray) for a in arrays):
+            return tuple(host_np(a) for a in arrays)
+        self._count("engine.d2h_syncs")
+        # the time the host is blocked on the chip (JAX's own
+        # ``np.asarray(jax.Array)`` trace event nests inside)
+        with profiling.annotate("engine.fetch"):
+            for a in arrays:
+                start = getattr(a, "copy_to_host_async", None)
+                if start is not None:
+                    start()
+            return tuple(host_np(a) for a in arrays)
 
     def _overlap_fast(self) -> bool:
         """Whether THIS tick may dispatch without waiting to commit (the
@@ -1104,6 +1204,7 @@ class EngineBase:
         if not live:
             return None
         st.generated.append(token)
+        st.life.committed(self._now())
         self._note_first_token(st.slot, token, update_dev=update_dev)
         reason = self._finish_reason(st, token, st.prompt_tokens)
         if reason is not None:
@@ -1124,10 +1225,11 @@ class EngineBase:
                 order.append(a)
         hosts = self._fetch(*order)
         out: List[SequenceResult] = []
-        for st, a, i in pend:
-            r = self._commit_first(st, int(hosts[uniq[id(a)]][i]))
-            if r is not None:
-                out.append(r)
+        with profiling.annotate("engine.commit"):
+            for st, a, i in pend:
+                r = self._commit_first(st, int(hosts[uniq[id(a)]][i]))
+                if r is not None:
+                    out.append(r)
         return out
 
     def _note_flush_entry(self, entry: dict) -> None:
@@ -1192,25 +1294,22 @@ class EngineBase:
 
     def step(self) -> List[SequenceResult]:
         """One engine tick (the public pump surface): apply this tick's
-        scheduled fault, run the subclass tick body (``_tick``), and —
-        only when a tracer is active — wrap the tick in an
-        ``engine.tick`` span and record a TickSample of the scheduler/
-        pool gauges.  The untraced, disarmed, unwatched hot path pays
-        exactly two module-slot identity checks plus the heartbeat bump
-        (one int add and one falsy check)."""
+        scheduled fault, run the subclass tick body (``_tick``) inside
+        the ``engine.tick`` span (``profiling.annotate``: profiler
+        annotation, always-on timer, obs span) and, only when a tracer
+        is active, record a TickSample of the scheduler/pool gauges."""
         self.heartbeat += 1                    # liveness tick serial
         if self._hb_stamp:                     # unwatched cost: this check
             self.heartbeat_t = self._now()
         if inject._ARMED is not None:          # disarmed cost: this check
             self._tick_fault()
-        tr = obs_trace._ACTIVE
-        if tr is None:                         # untraced cost: this check
-            return self._tick()
         targs = ({} if self.obs_replica is None
                  else {"replica": self.obs_replica})
-        with tr.span("engine.tick", cat="engine", **targs):
+        with profiling.annotate("engine.tick", **targs):
             finished = self._tick()
-        self._record_tick(tr)
+        tr = obs_trace._ACTIVE
+        if tr is not None:                     # untraced cost: this check
+            self._record_tick(tr)
         return finished
 
     def _tick(self) -> List[SequenceResult]:
@@ -1344,6 +1443,12 @@ class EngineBase:
                     tabs.append(t)
         if not tabs:
             return None
+        with profiling.annotate("engine.grammar_mask"):
+            return self._fuse_dfa_tables(tabs)
+
+    def _fuse_dfa_tables(self, tabs):
+        """``_scan_dfa_setup``'s work once a grammar slot is known to be
+        active: ``tabs`` are the distinct DFA table sets in flight."""
         tabs.sort(key=id)
         key = tuple(id(t) for t in tabs)
         cache = getattr(self, "_dfa_fused", None)
@@ -1417,17 +1522,25 @@ class EngineBase:
         never committed — see the inline comment), and stop strings/EOS
         inside a chunk are trimmed after the fact, same text semantics
         as the stepwise path."""
-        limit = self.engine_cfg.decode_chunk
+        # which bound set the chunk: ONE ``engine.scan_limit.<reason>``
+        # increment per decode tick ("full" = nothing cut it)
+        limit = full = self.engine_cfg.decode_chunk
+        reason = "full"
         if limit <= 1:
+            self._count("engine.scan_limit.full")
             return 1
         if self.engine_cfg.prompt_admission and self._pending:
-            return 1       # admit promptly: a retirement frees a slot within
-            # one step instead of up to decode_chunk-1 steps
+            # admit promptly: a retirement frees a slot within one step
+            # instead of up to decode_chunk-1 steps
+            self._count("engine.scan_limit.admission")
+            return 1
         for slot, st in self._active.items():
             if st.grammar is not None:
                 t = getattr(st.grammar, "tables", None)
                 if t is None or not self._dfa_scan:
-                    return 1           # interpreted FSM: per-token host work
+                    # interpreted FSM: per-token host work
+                    self._count("engine.scan_limit.grammar")
+                    return 1
             # bound by CACHE headroom (never write past max_seq_len), NOT
             # by the slot's token budget: DFA slots enforce budgets
             # in-scan (the `remaining` vector force-closes), and a plain
@@ -1438,12 +1551,20 @@ class EngineBase:
             # runs, SOME slot is almost always in its tail, so the scan
             # degenerated to per-token dispatches exactly when the batch
             # was busiest (observed on the shared-engine sweep).
-            headroom = self.engine_cfg.max_seq_len - (
-                st.prompt_tokens + len(st.generated))
-            limit = min(limit, max(1, headroom), self._chunk_bound(slot))
+            headroom = max(1, self.engine_cfg.max_seq_len - (
+                st.prompt_tokens + len(st.generated)))
+            if headroom < limit:
+                limit, reason = headroom, "headroom"
+            bound = self._chunk_bound(slot)
+            if bound < limit:
+                limit, reason = bound, "pages"
         chunk = 1
         while chunk * 2 <= limit:
             chunk *= 2
+        # a bound that still leaves the largest power of two under
+        # decode_chunk cut nothing
+        self._count("engine.scan_limit."
+                    + ("full" if chunk * 2 > full else reason))
         return chunk
 
     def _commit_scanned(self, active_slots, toks_host, chunk: int,
@@ -1454,23 +1575,26 @@ class EngineBase:
         lets a subclass update its host-side length/token arrays per
         commit."""
         finished: List[SequenceResult] = []
-        for slot in active_slots:
-            st = self._active[slot]
-            base_len = st.prompt_tokens + len(st.generated)
-            committed = 0
-            reason = None
-            for j in range(chunk):
-                token = int(toks_host[j, slot])
-                st.generated.append(token)
-                committed += 1
-                if post_commit is not None:
-                    post_commit(slot, token)
-                reason = self._finish_reason(st, token, base_len + j)
+        with profiling.annotate("engine.commit"):
+            now = self._now()       # one stamp for every slot of the tick
+            for slot in active_slots:
+                st = self._active[slot]
+                base_len = st.prompt_tokens + len(st.generated)
+                committed = 0
+                reason = None
+                for j in range(chunk):
+                    token = int(toks_host[j, slot])
+                    st.generated.append(token)
+                    committed += 1
+                    if post_commit is not None:
+                        post_commit(slot, token)
+                    reason = self._finish_reason(st, token, base_len + j)
+                    if reason is not None:
+                        break
+                st.life.committed(now)
+                self._count("engine.decode_tokens", committed)
                 if reason is not None:
-                    break
-            self._count("engine.decode_tokens", committed)
-            if reason is not None:
-                finished.append(self._retire(slot, reason))
+                    finished.append(self._retire(slot, reason))
         return finished
 
     def run_to_completion(self) -> List[SequenceResult]:
@@ -1639,41 +1763,46 @@ class EngineBase:
         re-application (the FSM still advances per commit, which also
         validates the device transition)."""
         finished: List[SequenceResult] = []
-        for slot in active_slots:
-            st = self._active[slot]
-            draft = drafts[slot]
-            base_len = st.prompt_tokens + len(st.generated)
-            committed: List[int] = []
-            reason = None
-            for j in range(len(draft) + 1):
-                if constrained:
-                    token = int(greedy_host[slot, j])
-                else:
-                    token = self._greedy_with_grammar(
-                        st, int(greedy_host[slot, j]),
-                        logits_host[slot, j]
-                        if logits_host is not None else None)
-                st.generated.append(token)
-                if st.grammar is not None:
-                    st.grammar.advance(token)
-                committed.append(token)
-                if post_commit is not None:
-                    post_commit(slot, token)
-                # cache now holds j+1 more tokens than before this commit:
-                # tokens_in[0..j] are written; token itself is written on a
-                # LATER tick (same as the regular path's current token)
-                reason = self._finish_reason(st, token, base_len + j)
-                accepted = (reason is None and j < len(draft)
-                            and token == draft[j])
-                if not accepted:
-                    break
-            self._count("engine.decode_tokens", len(committed))
-            self._count("engine.spec_drafted", len(draft))
-            self._count("engine.spec_accepted", max(0, len(committed) - 1))
-            if reason is not None:
-                finished.append(self._retire(slot, reason))
-            elif self._draft is not None:
-                self._draft.advance(slot, st.seq_id, committed)
+        with profiling.annotate("engine.commit"):
+            now = self._now()
+            for slot in active_slots:
+                st = self._active[slot]
+                draft = drafts[slot]
+                base_len = st.prompt_tokens + len(st.generated)
+                committed: List[int] = []
+                reason = None
+                for j in range(len(draft) + 1):
+                    if constrained:
+                        token = int(greedy_host[slot, j])
+                    else:
+                        token = self._greedy_with_grammar(
+                            st, int(greedy_host[slot, j]),
+                            logits_host[slot, j]
+                            if logits_host is not None else None)
+                    st.generated.append(token)
+                    if st.grammar is not None:
+                        st.grammar.advance(token)
+                    committed.append(token)
+                    if post_commit is not None:
+                        post_commit(slot, token)
+                    # cache now holds j+1 more tokens than before this
+                    # commit: tokens_in[0..j] are written; token itself is
+                    # written on a LATER tick (same as the regular path's
+                    # current token)
+                    reason = self._finish_reason(st, token, base_len + j)
+                    accepted = (reason is None and j < len(draft)
+                                and token == draft[j])
+                    if not accepted:
+                        break
+                st.life.committed(now)
+                self._count("engine.decode_tokens", len(committed))
+                self._count("engine.spec_drafted", len(draft))
+                self._count("engine.spec_accepted",
+                            max(0, len(committed) - 1))
+                if reason is not None:
+                    finished.append(self._retire(slot, reason))
+                elif self._draft is not None:
+                    self._draft.advance(slot, st.seq_id, committed)
         return finished
 
     def _need_spec_logits(self, active_slots) -> bool:
@@ -2015,29 +2144,30 @@ class InferenceEngine(EngineBase):
                 model_cfg, ep_mesh)
             sp_mesh = tp_mesh if sp else None
             self._prefill = jax.jit(
-                functools.partial(llama.prefill, use_flash=use_flash,
-                                  ep_mesh=ep_mesh, flash_mesh=flash_mesh,
-                                  sp_mesh=sp_mesh),
+                profiling.named_partial(llama.prefill, use_flash=use_flash,
+                                        ep_mesh=ep_mesh, flash_mesh=flash_mesh,
+                                        sp_mesh=sp_mesh),
                 static_argnums=0)
             self._prefill_batch = jax.jit(
-                functools.partial(llama.prefill_batch, use_flash=use_flash,
-                                  ep_mesh=ep_mesh, flash_mesh=flash_mesh,
-                                  sp_mesh=sp_mesh),
+                profiling.named_partial(llama.prefill_batch,
+                                        use_flash=use_flash,
+                                        ep_mesh=ep_mesh, flash_mesh=flash_mesh,
+                                        sp_mesh=sp_mesh),
                 static_argnums=0)
         # batched admission needs the plain prefill path (prefill_cp is
         # per-sequence)
         self._batch_admission = cp_mesh is None
         self._decode = jax.jit(
             pp_decode_fn if pp_decode_fn is not None
-            else functools.partial(llama.decode_step, ep_mesh=ep_mesh),
+            else profiling.named_partial(llama.decode_step, ep_mesh=ep_mesh),
             static_argnums=0)
         # fused overlapped step (engine.overlap_step): decode + key split
         # + sample + length advance in ONE dispatch.  The in-jit
         # jax.random.split computes the identical subkey stream as the
         # host split in the plain tick, so sampled tokens match exactly.
         self._overlap_decode = jax.jit(
-            functools.partial(overlap_step, ep_mesh=ep_mesh,
-                              decode_fn=pp_decode_fn),
+            profiling.named_partial(overlap_step, ep_mesh=ep_mesh,
+                                    decode_fn=pp_decode_fn),
             static_argnums=(0, 6, 7))
         if pp_mesh is not None:
             def _verify_step(cfg, params_t, cache, tokens, lengths):
@@ -2061,13 +2191,13 @@ class InferenceEngine(EngineBase):
         self._sample = jax.jit(sample_tokens, static_argnums=2)
         self._sample_masked = jax.jit(sample_tokens_masked, static_argnums=2)
         self._decode_scan = jax.jit(
-            functools.partial(decode_scan, ep_mesh=ep_mesh,
-                              decode_fn=pp_decode_fn),
+            profiling.named_partial(decode_scan, ep_mesh=ep_mesh,
+                                    decode_fn=pp_decode_fn),
             static_argnums=(0, 6, 7, 8))
         self._dfa_scan = True
         self._decode_scan_dfa = jax.jit(
-            functools.partial(decode_scan_dfa, ep_mesh=ep_mesh,
-                              decode_fn=pp_decode_fn),
+            profiling.named_partial(decode_scan_dfa, ep_mesh=ep_mesh,
+                                    decode_fn=pp_decode_fn),
             static_argnums=(0, 6, 7, 8))
         self._dfa_dev: Dict[int, tuple] = {}   # id(tables) -> device arrays
         self._prompts: Dict[int, List[int]] = {}   # seq_id -> prompt (for
@@ -2144,6 +2274,7 @@ class InferenceEngine(EngineBase):
             self.model_cfg.vocab_size)
         with profiling.annotate("engine.decode_step"):
             self._count("engine.dispatches")
+            self._count_decode(1)
             self.cache, logits = self._decode(
                 self.model_cfg, self.params, self.cache,
                 self.cur_tokens, self.lengths)
@@ -2169,15 +2300,19 @@ class InferenceEngine(EngineBase):
         else:
             self.cur_tokens = next_tokens
 
-        for slot in active_slots:
-            st = self._active[slot]
-            token = int(host_next[slot])
-            st.generated.append(token)
-            if st.grammar is not None:
-                st.grammar.advance(token)
-            reason = self._finish_reason(st, token, int(lengths_host[slot]))
-            if reason is not None:
-                finished.append(self._retire(slot, reason))
+        with profiling.annotate("engine.commit"):
+            now = self._now()
+            for slot in active_slots:
+                st = self._active[slot]
+                token = int(host_next[slot])
+                st.generated.append(token)
+                st.life.committed(now)
+                if st.grammar is not None:
+                    st.grammar.advance(token)
+                reason = self._finish_reason(st, token,
+                                             int(lengths_host[slot]))
+                if reason is not None:
+                    finished.append(self._retire(slot, reason))
         return finished
 
     def _overlap_step_tick(self) -> List[SequenceResult]:
@@ -2191,6 +2326,7 @@ class InferenceEngine(EngineBase):
         slots = [(s, self._active[s].seq_id) for s in sorted(self._active)]
         with profiling.annotate("engine.decode_step"):
             self._count("engine.dispatches")
+            self._count_decode(1)
             self.cache, nxt, self.lengths, self._key = self._overlap_decode(
                 self.model_cfg, self.params, self.cache, self.cur_tokens,
                 self.lengths, self._key, self.sampling, self._overlap_cap)
@@ -2224,6 +2360,7 @@ class InferenceEngine(EngineBase):
             self._key, sub = jax.random.split(self._key)
             first = self._sample(logits, sub, self.sampling)
         self._count("engine.prefill_tokens", n)
+        self._count("engine.prefill_padded_tokens", padded.size)
         if req.grammar is not None:
             # grammar first tokens stay synchronous: the FSM needs the
             # sampled value (and possibly a masked resample off these
@@ -2246,7 +2383,8 @@ class InferenceEngine(EngineBase):
         st = _Active(
             seq_id=req.seq_id, slot=slot, prompt_tokens=n,
             max_new_tokens=req.max_new_tokens, stop_strings=req.stop_strings,
-            grammar=req.grammar)
+            grammar=req.grammar, life=req.life)
+        st.life.admitted(self._now())
         self._active[slot] = st
         self.lengths = self.lengths.at[slot].set(n)
         return st
@@ -2326,6 +2464,7 @@ class InferenceEngine(EngineBase):
             self._key, sub = jax.random.split(self._key)
             firsts = self._sample(logits, sub, self.sampling)
         self._count("engine.prefill_tokens", int(lens[:n].sum()))
+        self._count("engine.prefill_padded_tokens", tokens.size)
         self._count("engine.batched_admissions", n)
 
         if any(r.grammar is not None for r in reqs):
@@ -2365,6 +2504,7 @@ class InferenceEngine(EngineBase):
             prompt_tokens=(len(orig_prompt) if orig_prompt is not None
                            else st.prompt_tokens),
             completion_tokens=len(generated),
+            timing=self._settle_timing(st, len(generated)),
         )
 
     # ------------------------------------------------- chunked scan tick
@@ -2378,6 +2518,7 @@ class InferenceEngine(EngineBase):
         setup = self._scan_dfa_setup()
         self._key, sub = jax.random.split(self._key)
         self._count("engine.dispatches")
+        self._count_decode(chunk)
         if setup is None:
             with profiling.annotate("engine.decode_step"):
                 self.cache, toks, self.lengths = self._decode_scan(
@@ -2414,6 +2555,7 @@ class InferenceEngine(EngineBase):
 
         with profiling.annotate("engine.decode_step"):
             self._count("engine.dispatches")
+            self._count_decode(tokens_in.shape[1])
             self.cache, greedy, logits = self._decode_multi(
                 self.model_cfg, self.params, self.cache,
                 jnp.asarray(tokens_in), self.lengths)

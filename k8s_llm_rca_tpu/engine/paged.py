@@ -1421,9 +1421,9 @@ class PagedInferenceEngine(EngineBase):
                 params, None if fsdp_mesh is not None else tp_mesh,
                 model_cfg, ep_mesh)
             self._prefill = jax.jit(
-                functools.partial(paged_prefill, use_flash=use_flash,
-                                  ep_mesh=ep_mesh, flash_mesh=flash_mesh,
-                                  sp_mesh=tp_mesh if sp else None),
+                profiling.named_partial(paged_prefill, use_flash=use_flash,
+                                        ep_mesh=ep_mesh, flash_mesh=flash_mesh,
+                                        sp_mesh=tp_mesh if sp else None),
                 static_argnums=0, donate_argnums=donate)
         if pp_mesh is None:
             if cp_mesh is not None:
@@ -1433,17 +1433,18 @@ class PagedInferenceEngine(EngineBase):
                                                            model_cfg,
                                                            ep_mesh)
             self._prefill_batch = jax.jit(
-                functools.partial(paged_prefill_batch, use_flash=use_flash,
-                                  ep_mesh=ep_mesh, flash_mesh=flash_mesh,
-                                  sp_mesh=tp_mesh if sp else None),
+                profiling.named_partial(paged_prefill_batch,
+                                        use_flash=use_flash,
+                                        ep_mesh=ep_mesh, flash_mesh=flash_mesh,
+                                        sp_mesh=tp_mesh if sp else None),
                 static_argnums=0, donate_argnums=donate)
         if pp_mesh is None:
             self._prefill_chunk = jax.jit(
-                functools.partial(paged_prefill_chunk, ep_mesh=ep_mesh),
+                profiling.named_partial(paged_prefill_chunk, ep_mesh=ep_mesh),
                 static_argnums=0, donate_argnums=donate)
             self._prefill_chunk_batch = jax.jit(
-                functools.partial(paged_prefill_chunk_batch,
-                                  ep_mesh=ep_mesh),
+                profiling.named_partial(paged_prefill_chunk_batch,
+                                        ep_mesh=ep_mesh),
                 static_argnums=0, donate_argnums=donate)
         else:
             # PP's pipelined chunk prefill is per-sequence (GPipe m=1);
@@ -1451,8 +1452,8 @@ class PagedInferenceEngine(EngineBase):
             self._prefill_chunk_batch = None
         self._decode = jax.jit(
             pp_decode_fn if pp_decode_fn is not None
-            else functools.partial(paged_decode_step, ep_mesh=ep_mesh,
-                                    tp_mesh=self._kernel_mesh),
+            else profiling.named_partial(paged_decode_step, ep_mesh=ep_mesh,
+                                         tp_mesh=self._kernel_mesh),
             static_argnums=(0,),
             donate_argnums=donate, static_argnames=("use_kernel",))
         # fused overlapped step (paged_overlap_step): decode + key split
@@ -1461,27 +1462,27 @@ class PagedInferenceEngine(EngineBase):
         # identical subkey stream as the host split in the plain tick,
         # so sampled tokens match exactly.
         self._overlap_decode = jax.jit(
-            functools.partial(paged_overlap_step, ep_mesh=ep_mesh,
-                              tp_mesh=self._kernel_mesh,
-                              decode_fn=pp_decode_fn),
+            profiling.named_partial(paged_overlap_step, ep_mesh=ep_mesh,
+                                    tp_mesh=self._kernel_mesh,
+                                    decode_fn=pp_decode_fn),
             static_argnums=(0, 7, 8),
             donate_argnums=donate, static_argnames=("use_kernel",))
         self._decode_scan = jax.jit(
-            functools.partial(paged_decode_scan, ep_mesh=ep_mesh,
-                              tp_mesh=self._kernel_mesh,
-                              decode_fn=pp_decode_fn),
+            profiling.named_partial(paged_decode_scan, ep_mesh=ep_mesh,
+                                    tp_mesh=self._kernel_mesh,
+                                    decode_fn=pp_decode_fn),
             static_argnums=(0, 7, 8, 9),
             donate_argnums=donate, static_argnames=("use_kernel",))
         self._dfa_scan = True
         self._decode_scan_dfa = jax.jit(
-            functools.partial(paged_decode_scan_dfa, ep_mesh=ep_mesh,
-                              tp_mesh=self._kernel_mesh,
-                              decode_fn=pp_decode_fn),
+            profiling.named_partial(paged_decode_scan_dfa, ep_mesh=ep_mesh,
+                                    tp_mesh=self._kernel_mesh,
+                                    decode_fn=pp_decode_fn),
             static_argnums=(0, 7, 8, 9),
             donate_argnums=donate, static_argnames=("use_kernel",))
         self._decode_multi = jax.jit(
             pp_decode_multi_fn if pp_decode_multi_fn is not None
-            else functools.partial(paged_decode_multi, ep_mesh=ep_mesh),
+            else profiling.named_partial(paged_decode_multi, ep_mesh=ep_mesh),
             static_argnums=0, donate_argnums=donate)
         from k8s_llm_rca_tpu.engine.engine import dfa_greedy_multi
         self._spec_dfa_greedy = jax.jit(dfa_greedy_multi, static_argnums=3)
@@ -1568,6 +1569,16 @@ class PagedInferenceEngine(EngineBase):
         g["evictable_pages"] = (self.prefix_cache.n_evictable
                                 if self.prefix_cache is not None else 0)
         return g
+
+    def _count_attn_pages(self, steps: int, active_slots) -> None:
+        """What the decode kernel's grid visits per layer in this
+        dispatch (``max_batch x pages_per_seq`` page slots a step,
+        whatever the live context) beside the pages that hold live
+        context, from the host length mirror at dispatch."""
+        live = -(-self.lengths[active_slots] // self.page_size)
+        self._count("engine.attn_pages_live", steps * int(live.sum()))
+        self._count("engine.attn_pages_grid",
+                    steps * self.engine_cfg.max_batch * self.pages_per_seq)
 
     # --------------------------------------------- device-resident state
 
@@ -1710,6 +1721,8 @@ class PagedInferenceEngine(EngineBase):
         cur_d, lens_d, bt_d = self._device_state()
         with profiling.annotate("engine.decode_step"):
             self._count("engine.dispatches")
+            self._count_decode(1)
+            self._count_attn_pages(1, active_slots)
             self.pool, logits = self._decode(
                 self.model_cfg, self.params, self.pool,
                 cur_d, lens_d, bt_d,
@@ -1723,17 +1736,21 @@ class PagedInferenceEngine(EngineBase):
         self._count("engine.decode_tokens", len(active_slots))
 
         (host_next,) = self._fetch(next_tokens)
-        for slot in active_slots:
-            self.lengths[slot] += 1
-            st = self._active[slot]
-            token = forced.get(slot, int(host_next[slot]))
-            self.cur_tokens[slot] = token
-            st.generated.append(token)
-            if st.grammar is not None:
-                st.grammar.advance(token)
-            reason = self._finish_reason(st, token, int(self.lengths[slot]))
-            if reason is not None:
-                finished.append(self._retire(slot, reason))
+        with profiling.annotate("engine.commit"):
+            now = self._now()
+            for slot in active_slots:
+                self.lengths[slot] += 1
+                st = self._active[slot]
+                token = forced.get(slot, int(host_next[slot]))
+                self.cur_tokens[slot] = token
+                st.generated.append(token)
+                st.life.committed(now)
+                if st.grammar is not None:
+                    st.grammar.advance(token)
+                reason = self._finish_reason(st, token,
+                                             int(self.lengths[slot]))
+                if reason is not None:
+                    finished.append(self._retire(slot, reason))
         # the plain step does not advance the device lengths/tokens; the
         # host commit above is authoritative — re-upload next dispatch
         self._invalidate_device_state()
@@ -1753,6 +1770,8 @@ class PagedInferenceEngine(EngineBase):
         slots = [(s, self._active[s].seq_id) for s in active_slots]
         with profiling.annotate("engine.decode_step"):
             self._count("engine.dispatches")
+            self._count_decode(1)
+            self._count_attn_pages(1, active_slots)
             self.pool, nxt, new_lens, self._key = self._overlap_decode(
                 self.model_cfg, self.params, self.pool, cur_d, lens_d,
                 bt_d, self._key, self.sampling, self._dev_cap,
@@ -1952,6 +1971,7 @@ class PagedInferenceEngine(EngineBase):
         self._count("engine.h2d_uploads", 2)
         with profiling.annotate("engine.decode_step"):
             self._count("engine.dispatches")
+            self._count_decode(tokens_in.shape[1])
             self.pool, greedy, logits = self._decode_multi(
                 self.model_cfg, self.params, self.pool,
                 jnp.asarray(tokens_in), jnp.asarray(self.lengths, jnp.int32),
@@ -1994,6 +2014,8 @@ class PagedInferenceEngine(EngineBase):
         setup = self._scan_dfa_setup()
         self._key, sub = jax.random.split(self._key)
         cur_d, lens_d, bt_d = self._device_state()
+        self._count_decode(chunk)
+        self._count_attn_pages(chunk, active_slots)
         if setup is None:
             with profiling.annotate("engine.decode_step"):
                 self._count("engine.dispatches")
@@ -2220,6 +2242,7 @@ class PagedInferenceEngine(EngineBase):
             self._key, sub = jax.random.split(self._key)
             first = self._sample(logits, sub, self.sampling)
         self._count("engine.prefill_tokens", len(rest))
+        self._count("engine.prefill_padded_tokens", padded.size)
 
         if req.grammar is not None:
             # grammar first tokens stay synchronous: the FSM needs the
@@ -2270,6 +2293,7 @@ class PagedInferenceEngine(EngineBase):
                 self.prefix_cache.release(cached_pages)
             raise
         slot = self._free_slots.pop(0)
+        req.life.admitted(self._now())
         # the full table lives in _prefilling, NOT block_tables: the slot
         # stays inactive (row TRASH_PAGE) until the final chunk activates
         # it, so interleaved decode ticks' garbage writes for this slot
@@ -2324,6 +2348,7 @@ class PagedInferenceEngine(EngineBase):
                 jnp.int32(done), jnp.asarray(prefix_table),
                 jnp.asarray(page_map))
         self._count("engine.prefill_tokens", chunk_len)
+        self._count("engine.prefill_padded_tokens", padded.size)
         st["done"] = done + chunk_len
         if st["done"] < total:
             return None
@@ -2429,7 +2454,9 @@ class PagedInferenceEngine(EngineBase):
         st = _Active(seq_id=req.seq_id, slot=slot, prompt_tokens=n,
                      max_new_tokens=req.max_new_tokens,
                      stop_strings=req.stop_strings, grammar=req.grammar,
-                     n_shared=n_shared, priority=req.priority)
+                     n_shared=n_shared, priority=req.priority,
+                     life=req.life)
+        st.life.admitted(self._now())
         self._active[slot] = st
         self.lengths[slot] = n
         self._dev_edit_len(slot, n)
@@ -2527,6 +2554,7 @@ class PagedInferenceEngine(EngineBase):
             firsts = self._sample(logits, sub, self.sampling)
         self._count("engine.prefill_tokens",
                     sum(len(rest) for rest in rests))
+        self._count("engine.prefill_padded_tokens", tokens.size)
         self._count("engine.prefix_hit_tokens", n_cached * n)
         self._count("engine.prefix_batch_hit_admissions", n)
 
@@ -2601,6 +2629,7 @@ class PagedInferenceEngine(EngineBase):
             self._key, sub = jax.random.split(self._key)
             firsts = self._sample(logits, sub, self.sampling)
         self._count("engine.prefill_tokens", int(lens[:n].sum()))
+        self._count("engine.prefill_padded_tokens", tokens.size)
         self._count("engine.batched_admissions", n)
 
         if any(r.grammar is not None for r in reqs):
@@ -2692,11 +2721,13 @@ class PagedInferenceEngine(EngineBase):
                  st.seq_id, slot, len(resumed_prompt),
                  "kv spilled" if spilled else "re-prefill")
         self._count("engine.preemptions", 1)
-        # the grammar FSM rides along: its state already reflects every
-        # generated token now baked into the resume prompt
+        st.life.preemptions += 1
+        # the grammar FSM rides along (its state already reflects every
+        # generated token now baked into the resume prompt), and so does
+        # the lifecycle record: arrival and first-token stamps survive
         self._enqueue(_Pending(
             st.seq_id, resumed_prompt, remaining, st.stop_strings,
-            st.grammar, priority=st.priority), front=True)
+            st.grammar, priority=st.priority, life=st.life), front=True)
 
     def _demote_prefix_pages(self, pages: List[int]
                              ) -> Optional[List[Dict[str, object]]]:
@@ -2908,7 +2939,9 @@ class PagedInferenceEngine(EngineBase):
                          prompt_tokens=resume_len,
                          max_new_tokens=req.max_new_tokens,
                          stop_strings=req.stop_strings, grammar=req.grammar,
-                         n_shared=n_shared, priority=req.priority)
+                         n_shared=n_shared, priority=req.priority,
+                         life=req.life)
+            st.life.admitted(self._now())
             self._active[slot] = st
             self.lengths[slot] = length
             self.cur_tokens[slot] = int(rec["cur_token"])
@@ -3100,4 +3133,5 @@ class PagedInferenceEngine(EngineBase):
         return SequenceResult(
             seq_id=st.seq_id, token_ids=list(generated), text=text,
             finish_reason=reason, prompt_tokens=len(orig_prompt),
-            completion_tokens=len(generated))
+            completion_tokens=len(generated),
+            timing=self._settle_timing(st, len(generated)))

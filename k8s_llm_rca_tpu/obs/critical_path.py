@@ -15,11 +15,21 @@ interval of the run's [t0, t1] window to exactly one named segment:
         the three phases of a KV handoff (cluster/disagg.py spans)
     cp.relink        link outage: cluster.net.partition -> .relink
     cp.retry         retry/degradation ladder activity
-    cp.prefill       engine.prefill spans (parent or shipped worker)
-    cp.decode        engine.decode_step spans
+    cp.prefill       the run's own slot grant -> first token
+    cp.decode        the run's own first token -> newest token
     cp.wire          cluster.proc.rpc spans (frame round-trips)
     cp.queue_wait    the unattributed residual — time the run spent
                      waiting for anything above to happen to IT
+
+``cp.prefill`` and ``cp.decode`` come from the engine's record of THAT
+request where there is one: a ``serve.run`` span whose ``seq`` /
+``seq_t0`` args name an ``engine.request`` span (engine.py
+``_settle_timing``) takes the record's stamps, so a batch-mate's prefill
+is never counted as the run's own and the time the host spends blocked
+on an asynchronous dispatch is decode, not queue wait.  A run without a
+record (echo and oracle workers, spans shipped from worker processes)
+falls back to overlaying every ``engine.prefill`` / ``engine.decode_step``
+span, which time the DISPATCH and carry no request id.
 
 Overlaps resolve by fixed priority (SEGMENT_PRIORITY order: a decode
 step inside an RPC inside a relink outage counts as the outage — the
@@ -50,8 +60,8 @@ SEGMENT_PRIORITY: Tuple[str, ...] = (
     "cp.wire",
 )
 
-# every segment name (all SITES-registered in obs/trace.py);
-# cp.queue_wait is the exact integer residual, never an interval source
+# every segment name; cp.queue_wait is the exact integer residual, never
+# an interval source
 SEGMENTS: Tuple[str, ...] = SEGMENT_PRIORITY + ("cp.queue_wait",)
 
 _SPAN_SEGMENT = {
@@ -97,18 +107,37 @@ def _intervals(tracer) -> List[Tuple[int, int, str]]:
     return ivs
 
 
-def critical_path(tracer, runs: Optional[Any] = None,
-                  emit: bool = False) -> Dict[Any, Dict[str, Any]]:
+_ENGINE_SEGMENTS = ("cp.prefill", "cp.decode")
+
+
+def _requests(tracer) -> Dict[Tuple[Any, int], List[Tuple[int, int, str]]]:
+    """(seq, arrival_us) -> that request's own prefill and decode
+    intervals, from the ``engine.request`` spans.  The arrival stamp is
+    part of the key because every engine numbers its sequences from 0."""
+    out: Dict[Tuple[Any, int], List[Tuple[int, int, str]]] = {}
+    for sp in tracer.spans:
+        if sp.name != "engine.request" or sp.t1 is None:
+            continue
+        t_admitted = sp.t0 + sp.args["queue_wait_s"]
+        t_first = _us(t_admitted + sp.args["prefill_s"])
+        out[(sp.args["seq"], _us(sp.t0))] = [
+            (_us(t_admitted), t_first, "cp.prefill"),
+            (t_first, _us(sp.t1), "cp.decode")]
+    return out
+
+
+def critical_path(tracer, runs: Optional[Any] = None
+                  ) -> Dict[Any, Dict[str, Any]]:
     """Decompose every settled run's end-to-end latency into SEGMENTS.
 
     Returns ``{run_id: breakdown}`` where ``breakdown["segments_us"]``
     maps each segment name to integer microseconds summing exactly to
-    ``breakdown["total_us"]``.  ``runs`` restricts to those run ids;
-    ``emit=True`` additionally records one ``cp.*`` event per segment
-    into the tracer (dashboards / the SITES coverage self-check) —
-    MUTATES the tracer, so never emit before a golden export.
+    ``breakdown["total_us"]``.  ``runs`` restricts to those run ids.
+    Pure: the tracer is only read.
     """
     ivs = _intervals(tracer)
+    not_engine = [iv for iv in ivs if iv[2] not in _ENGINE_SEGMENTS]
+    requests = _requests(tracer)
     retry_ts = [_us(e.ts) for e in tracer.events
                 if e.name == "resilience.retry"
                 or (e.name == "cluster.handoff"
@@ -125,7 +154,11 @@ def critical_path(tracer, runs: Optional[Any] = None,
             continue
         t0, t1 = _us(sp.t0), _us(sp.t1)
         segs = {name: 0 for name in SEGMENTS}
-        clipped = [(max(a, t0), min(b, t1), seg) for a, b, seg in ivs
+        own = None
+        if sp.args.get("seq") is not None:
+            own = requests.get((sp.args["seq"], _us(sp.args["seq_t0"])))
+        run_ivs = ivs if own is None else not_engine + own
+        clipped = [(max(a, t0), min(b, t1), seg) for a, b, seg in run_ivs
                    if b > t0 and a < t1 and b > a]
         # sweep the elementary intervals between all clip points; on
         # overlap the highest-priority segment takes the whole slice,
@@ -150,9 +183,6 @@ def critical_path(tracer, runs: Optional[Any] = None,
             "retries": sum(1 for ts in retry_ts if t0 <= ts <= t1),
             "degraded": sum(1 for ts in degraded_ts if t0 <= ts <= t1),
         }
-        if emit:
-            for name in SEGMENTS:
-                tracer.event(name, run=run, us=segs[name])
     return out
 
 

@@ -53,12 +53,28 @@ from k8s_llm_rca_tpu.obs.timeline import TickTimeline
 # tests/test_obs.py drives each layer and asserts coverage_missing() is
 # empty — add the site HERE when instrumenting a new call site.
 SITES = frozenset({
-    # engine layer (EngineBase.step + paged tick phases via annotate)
+    # engine layer: every span goes through profiling.annotate, so each
+    # name is also a TraceAnnotation in an XProf capture and a METRICS
+    # timer (<name>.total_s / .count).  engine.tick wraps the whole tick
+    # (EngineBase.step); .admission / .eviction are the paged tick's
+    # phases; engine.prefill and engine.decode_step time the DISPATCH of a
+    # program, engine.fetch the time the host then blocks on the chip;
+    # engine.grammar_mask the FSM masks and DFA tables built on the host
+    # for a tick; engine.commit the host loop that appends the tick's
+    # tokens and retires finished sequences
     "engine.tick",
     "engine.tick.admission",
     "engine.prefill",
     "engine.decode_step",
     "engine.tick.eviction",
+    "engine.fetch",
+    "engine.grammar_mask",
+    "engine.commit",
+    # one span per retired sequence from its arrival to its newest token
+    # (explicit times on the engine's clock; args carry seq, queue_wait_s,
+    # prefill_s, decode_s, tokens, preemptions) — the record
+    # obs/critical_path.py reads for the run that owns the seq
+    "engine.request",
     # overload survival (engine/paged.py): KV page spill-to-host on
     # preemption and the h2d page restore that resumes the sequence
     "engine.spill",
@@ -151,19 +167,6 @@ SITES = frozenset({
     "resilience.degraded",
     "resilience.breaker_open",
     "resilience.breaker_close",
-    # per-run critical-path segments (obs/critical_path.py): the
-    # decomposition pass emits one event per segment when invoked with
-    # emit=True, so dashboards and the coverage self-check see the
-    # attribution vocabulary alongside the raw spans it is derived from
-    "cp.queue_wait",
-    "cp.prefill",
-    "cp.decode",
-    "cp.handoff.export",
-    "cp.handoff.adopt",
-    "cp.handoff.release",
-    "cp.wire",
-    "cp.relink",
-    "cp.retry",
 })
 
 

@@ -155,6 +155,7 @@ def flash_attention(
 
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

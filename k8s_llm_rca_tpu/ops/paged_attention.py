@@ -261,6 +261,7 @@ def paged_attention(
 
     out = pl.pallas_call(
         functools.partial(_paged_kernel, page_size=page_size, head_dim=d),
+        name="paged_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
@@ -325,6 +326,7 @@ def paged_attention_quant(
     out = pl.pallas_call(
         functools.partial(_paged_kernel_quant, page_size=page_size,
                           head_dim=d, packed=packed),
+        name="paged_attention_quant",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,

@@ -250,6 +250,7 @@ def _matmul_kn(x2, w, interpret: bool):
         grid = (m // bm, n // bn, kdim // bk)
         return pl.pallas_call(
             functools.partial(_kn8_kernel, nk=grid[2]),
+            name="quant_matmul_kn8",
             grid=grid,
             in_specs=[
                 pl.BlockSpec((bm, bk), lambda mi, ni, ki: (mi, ki)),
@@ -267,6 +268,7 @@ def _matmul_kn(x2, w, interpret: bool):
     grid = (m // bm, n_packed // bnp, kdim // bk)
     out = pl.pallas_call(
         functools.partial(_kn4_kernel, nk=grid[2]),
+        name="quant_matmul_kn4",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda mi, ni, ki: (mi, ki)),
@@ -296,6 +298,7 @@ def _matmul_nk(x2, w, interpret: bool):
         grid = (m // bm, n // bn, kdim // bk)
         return pl.pallas_call(
             functools.partial(_nk8_kernel, nk=grid[2]),
+            name="quant_matmul_nk8",
             grid=grid,
             in_specs=[
                 pl.BlockSpec((bm, bk), lambda mi, ni, ki: (mi, ki)),
@@ -316,6 +319,7 @@ def _matmul_nk(x2, w, interpret: bool):
     x_lo, x_hi = x2[:, :k_packed], x2[:, k_packed:]
     return pl.pallas_call(
         functools.partial(_nk4_kernel, nk=grid[2]),
+        name="quant_matmul_nk4",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bkp), lambda mi, ni, ki: (mi, ki)),
@@ -340,6 +344,7 @@ def _matmul_ekn(xe, w, interpret: bool):
         grid = (e, m // bm, n // bn, kdim // bk)
         return pl.pallas_call(
             functools.partial(_ekn8_kernel, nk=grid[3]),
+            name="quant_matmul_ekn8",
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, bm, bk),
@@ -362,6 +367,7 @@ def _matmul_ekn(xe, w, interpret: bool):
     grid = (e, m // bm, n_packed // bnp, kdim // bk)
     out = pl.pallas_call(
         functools.partial(_ekn4_kernel, nk=grid[3]),
+        name="quant_matmul_ekn4",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bm, bk),

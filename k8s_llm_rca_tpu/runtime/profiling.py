@@ -12,8 +12,7 @@ and sweeps can report tokens/sec/chip against the hardware ceiling.
 from __future__ import annotations
 
 import contextlib
-import time
-from dataclasses import dataclass
+import functools
 from typing import Any, Dict, Iterator, NamedTuple, Optional
 
 import jax
@@ -72,16 +71,34 @@ def trace(log_dir: str, host_tracer_level: int = 2) -> Iterator[None]:
 
 
 @contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named region in the profiler timeline, the METRICS timers, AND the
-    obs span tracer — ONE name shared by XProf captures and flight
-    records, so a region found slow in one shows up under the same name
-    in the other (obs.span is a no-op global check when no tracer is
-    active)."""
+def annotate(name: str, **args) -> Iterator[None]:
+    """The one way a span is made: a named region in the profiler
+    timeline (``TraceAnnotation``: on the device trace's clock, free
+    while no profile runs), the METRICS timers (always on:
+    ``<name>.total_s`` / ``<name>.count``) AND the obs span tracer (when
+    a ``Tracer`` is active) — ONE name shared by XProf captures, counters
+    and flight records, so a region found slow in one shows up under the
+    same name in the others.  ``args`` go to the obs span only: the
+    annotation's name never carries them, because trace readers match
+    names exactly."""
     with jax.profiler.TraceAnnotation(name):
         with METRICS.timer(name):
-            with obs_trace.span(name, cat="xprof"):
+            with obs_trace.span(name, cat="xprof", **args):
                 yield
+
+
+def named_partial(fn, **kwargs):
+    """``functools.partial(fn, **kwargs)`` that keeps ``fn``'s name, so
+    ``jax.jit`` of it compiles ``jit_<fn name>`` and not ``jit__unknown``:
+    the device trace's ``XLA Modules`` line then names the engine's
+    programs by themselves.  (The name is part of the compile-cache
+    key.)"""
+    part = functools.partial(fn, **kwargs)
+    # not functools.wraps: its ``__wrapped__`` would make
+    # ``inspect.signature`` (which jit reads for static arguments) show
+    # the bound keywords again
+    part.__name__, part.__qualname__ = fn.__name__, fn.__qualname__
+    return part
 
 
 def device_memory_stats(device: Optional[Any] = None) -> Dict[str, float]:
@@ -272,40 +289,3 @@ def roofline_prefill_tps(cfg: ModelConfig, prompt_len: int,
         return None
     return (peaks.bf16_tflops * 1e12
             / decode_flops_per_token(cfg, prompt_len // 2))
-
-
-@dataclass
-class StepTimer:
-    """Rolling decode-step timing for sweeps: tokens/sec and per-phase p50
-    without a profiler attached."""
-
-    started: float = 0.0
-    steps: int = 0
-    tokens: int = 0
-
-    def start(self) -> None:
-        self.started = time.perf_counter()
-        self.steps = 0
-        self.tokens = 0
-
-    def tick(self, n_tokens: int) -> None:
-        self.steps += 1
-        self.tokens += n_tokens
-
-    @property
-    def tokens_per_sec(self) -> float:
-        dt = time.perf_counter() - self.started
-        return self.tokens / dt if dt > 0 else 0.0
-
-    def report(self, cfg: Optional[ModelConfig] = None,
-               context_len: int = 512) -> Dict[str, Any]:
-        out: Dict[str, Any] = {
-            "steps": self.steps,
-            "tokens": self.tokens,
-            "tokens_per_sec": round(self.tokens_per_sec, 2),
-        }
-        if cfg is not None:
-            u = mfu(cfg, self.tokens_per_sec, context_len)
-            out["mfu"] = round(u, 4) if u is not None else None
-        out.update({f"hbm_{k}": v for k, v in device_memory_stats().items()})
-        return out

@@ -115,6 +115,11 @@ class Run:
     completed_at: Optional[int] = None
     usage: Dict[str, int] = field(default_factory=lambda: {
         "prompt_tokens": 0, "completion_tokens": 0, "total_tokens": 0})
+    # the engine's own clock on the run (None until it settles, and from
+    # backends that time nothing): seconds queued before a slot, to the
+    # first token, and from the first token to the last; with the
+    # engine's ``seq``, which the run's ``engine.request`` span carries
+    timing: Optional[Dict[str, Any]] = None
     error: Optional[str] = None
     # book-keeping
     instructions_override: Optional[str] = None
@@ -361,11 +366,15 @@ class AssistantService:
         assistant = self.assistants.get(run.assistant_id)
         now = self._clock.time()
         t0 = run.t_started if run.t_started is not None else now
-        tr.add_span("serve.run", t0, now, cat="serve",
-                    args={"run": run.id, "status": run.status,
-                          "assistant": assistant.name if assistant else "",
-                          "completion_tokens":
-                          run.usage["completion_tokens"]})
+        args = {"run": run.id, "status": run.status,
+                "assistant": assistant.name if assistant else "",
+                "completion_tokens": run.usage["completion_tokens"]}
+        if run.timing is not None:
+            # the key of the engine's ``engine.request`` span for this run
+            # (obs/critical_path.py reads its stamps instead of guessing)
+            args["seq"] = run.timing["seq"]
+            args["seq_t0"] = run.timing["t_arrival"]
+        tr.add_span("serve.run", t0, now, cat="serve", args=args)
 
     @_locked
     def list_runs(self, thread_id: str, limit: int = 20,
@@ -501,6 +510,13 @@ class AssistantService:
                 run.usage["completion_tokens"] = res.completion_tokens
                 run.usage["total_tokens"] = (
                     run.usage["prompt_tokens"] + res.completion_tokens)
+                timing = getattr(res, "timing", None)
+                if timing is not None:
+                    run.timing = {"seq": timing.seq_id,
+                                  "t_arrival": timing.t_arrival,
+                                  "queue_wait_s": timing.queue_wait_s,
+                                  "ttft_s": timing.ttft_s,
+                                  "decode_s": timing.decode_s}
                 run.completed_at = int(self._clock.time())
                 del self._inflight[handle]
                 if self._journal is not None:

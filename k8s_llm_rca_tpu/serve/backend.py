@@ -94,6 +94,10 @@ class BackendResult:
     # the engine reaped the sequence past its deadline (finish_reason
     # "expired"): the service settles the run as EXPIRED, not FAILED
     expired: bool = False
+    # the engine's own lifecycle record of the sequence
+    # (engine.SequenceTiming); None from backends that run no engine in
+    # this process
+    timing: Optional[object] = None
 
 
 class LMBackend(Protocol):
@@ -264,12 +268,13 @@ class EngineBackend:
                     completion_tokens=res.completion_tokens,
                     prompt_tokens=res.prompt_tokens,
                     error="deadline exceeded (engine deadline reap)",
-                    expired=True)
+                    expired=True, timing=res.timing)
                 continue
             results[handle] = BackendResult(
                 text=text,
                 completion_tokens=res.completion_tokens,
-                prompt_tokens=res.prompt_tokens)
+                prompt_tokens=res.prompt_tokens,
+                timing=res.timing)
         if results:
             obs_trace.event("backend.settled", n=len(results))
         return results

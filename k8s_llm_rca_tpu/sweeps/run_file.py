@@ -39,7 +39,9 @@ def chip_metrics(elapsed_s: float) -> dict:
     from k8s_llm_rca_tpu.runtime import profiling
 
     decode_tokens = METRICS.count("engine.decode_tokens")
-    decode_s = METRICS.total("engine.decode_step")
+    # over whole ticks: the engine.decode_step timer closes when the
+    # asynchronous dispatch returns, which on a chip is a sliver of the step
+    decode_s = METRICS.total("engine.tick")
     out = {
         "decode_tokens": decode_tokens,
         "prefill_tokens": METRICS.count("engine.prefill_tokens"),

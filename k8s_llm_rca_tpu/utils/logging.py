@@ -103,9 +103,14 @@ class Metrics:
         try:
             yield
         finally:
-            dt = time.perf_counter() - t0
-            with self._lock:
-                self.timings[name].append(dt)
+            self.observe(name, time.perf_counter() - t0)
+
+    def observe(self, name: str, seconds: float) -> None:
+        """Append one duration to the reservoir a ``timer`` of the same
+        name would use: for durations that are a difference of two
+        stamps and not a ``with`` block."""
+        with self._lock:
+            self.timings[name].append(seconds)
 
     def count(self, name: str) -> float:
         """Current value of an ``inc`` counter (0 if never incremented)."""

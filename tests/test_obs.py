@@ -592,6 +592,12 @@ class TestSiteCoverage:
             service.add_message(t.id, "node notready")
             run = service.create_run(t.id, a.id)
             assert service.wait_run(run.id).status == RunStatus.COMPLETED
+            # a grammar-constrained run: the per-tick FSM masks are built
+            # inside the engine.grammar_mask span
+            masked = service.create_run(t.id, a.id, gen=GenOptions(
+                max_new_tokens=8, grammar="json"))
+            assert (service.wait_run(masked.id).status
+                    == RunStatus.COMPLETED)
             service._journal.close()
             recovered, _ = recover_service(wal, EngineBackend(engine))
             assert recovered.runs[run.id].status == RunStatus.COMPLETED
@@ -838,8 +844,8 @@ class TestSiteCoverage:
         # and close() flushes the ring (cluster.telemetry.drain); the
         # handoff PHASE spans (cluster.handoff.export/adopt/release,
         # disagg._attempt_handoff) already fired in segment (11).  Then
-        # the critical-path pass re-emits its cp.* segment vocabulary
-        # over the recorded serve.run spans (obs/critical_path.py)
+        # the critical-path pass decomposes the recorded serve.run span
+        # (obs/critical_path.py; it reads the tracer and emits nothing)
         from k8s_llm_rca_tpu.obs import critical_path
 
         tr_fleet = Tracer(clock=VirtualClock())
@@ -858,7 +864,7 @@ class TestSiteCoverage:
             tr_fleet.add_span("serve.run", 0.0, tr_fleet.now(),
                               cat="serve", args={"run": "cover-cp",
                                                  "status": "completed"})
-            assert critical_path(tr_fleet, emit=True)
+            assert critical_path(tr_fleet)
         assert {"cluster.proc.serve", "cluster.telemetry.ship",
                 "cluster.telemetry.drain"} <= tr_fleet.emitted_names()
 

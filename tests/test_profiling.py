@@ -93,18 +93,6 @@ class TestRoofline:
         assert 10_000 < roof < 208_000, roof
 
 
-class TestStepTimer:
-    def test_tokens_per_sec_and_report(self):
-        t = profiling.StepTimer()
-        t.start()
-        for _ in range(5):
-            t.tick(8)
-        rep = t.report(TINY, context_len=128)
-        assert rep["steps"] == 5 and rep["tokens"] == 40
-        assert rep["tokens_per_sec"] > 0
-        assert "mfu" in rep                  # None on CPU, key present
-
-
 class TestTraceAndMemory:
     def test_memory_stats_shape(self):
         stats = profiling.device_memory_stats()
