@@ -49,8 +49,9 @@ from k8s_llm_rca_tpu.models.llama import _quantize_kv
 from k8s_llm_rca_tpu.ops.attention import decode_attention
 from k8s_llm_rca_tpu.ops.norms import rms_norm
 from k8s_llm_rca_tpu.ops.paged_attention import (
-    paged_attention, paged_attention_quant, paged_attention_quant_sharded,
-    paged_attention_sharded, paged_attention_xla,
+    block_pages, paged_attention, paged_attention_quant,
+    paged_attention_quant_sharded, paged_attention_sharded,
+    paged_attention_xla,
 )
 from k8s_llm_rca_tpu.engine.prefix import (
     CACHE_OWNER, PrefixCache, PrefixStore, _page_keys,
@@ -639,6 +640,15 @@ def paged_decode_step(cfg: ModelConfig, params, pool: PagePool,
     else:
         attn_fn = paged_attention_xla
 
+    attn_lengths = lengths + 1
+    if kernel_on:
+        # the kernel walks each slot's context by its length: a slot that
+        # holds no sequence (its row starts at the trash page, which no
+        # sequence is ever given) is told 0 and costs it nothing,
+        # whatever stale length the slot carries
+        attn_lengths = jnp.where(block_tables[:, 0] == TRASH_PAGE, 0,
+                                 attn_lengths)
+
     k_scale, v_scale = pool.k_scale, pool.v_scale
     for li, layer in enumerate(params["layers"]):
         q, k, v = llama._decode_qkv(cfg, layer, x, angles,
@@ -659,11 +669,11 @@ def paged_decode_step(cfg: ModelConfig, params, pool: PagePool,
                         k_scale, v_scale)
         if pool.quantized and kernel_on and tp_mesh is not None:
             attn = paged_attention_quant_sharded(
-                q[:, 0], kp, vp, k_scale[li], v_scale[li], lengths + 1,
+                q[:, 0], kp, vp, k_scale[li], v_scale[li], attn_lengths,
                 block_tables, tp_mesh)
         elif pool.quantized and kernel_on:
             attn = paged_attention_quant(
-                q[:, 0], kp, vp, k_scale[li], v_scale[li], lengths + 1,
+                q[:, 0], kp, vp, k_scale[li], v_scale[li], attn_lengths,
                 block_tables, packed=packed)
         elif pool.quantized:
             k_all = _gather_dequant_pages(kp, k_scale[li], block_tables,
@@ -672,9 +682,9 @@ def paged_decode_step(cfg: ModelConfig, params, pool: PagePool,
             v_all = _gather_dequant_pages(vp, v_scale[li], block_tables,
                                           cfg.n_kv_heads, cfg.head_dim,
                                           dtype, packed)
-            attn = decode_attention(q, k_all, v_all, lengths + 1)
+            attn = decode_attention(q, k_all, v_all, attn_lengths)
         else:
-            attn = attn_fn(q[:, 0], kp, vp, lengths + 1, block_tables)
+            attn = attn_fn(q[:, 0], kp, vp, attn_lengths, block_tables)
         x = llama._decode_finish(cfg, layer, x,
                                  attn.reshape(b, 1, cfg.q_dim), ep_mesh)
 
@@ -1571,14 +1581,15 @@ class PagedInferenceEngine(EngineBase):
         return g
 
     def _count_attn_pages(self, steps: int, active_slots) -> None:
-        """What the decode kernel's grid visits per layer in this
-        dispatch (``max_batch x pages_per_seq`` page slots a step,
-        whatever the live context) beside the pages that hold live
-        context, from the host length mirror at dispatch."""
+        """The pages that hold live context in this dispatch, from the
+        host length mirror, beside the pages the decode kernel visits
+        per layer: each live slot's, rounded up to the kernel's block; a
+        slot that holds no sequence is visited not at all."""
         live = -(-self.lengths[active_slots] // self.page_size)
+        block = block_pages(self.page_size, self.pages_per_seq)
         self._count("engine.attn_pages_live", steps * int(live.sum()))
         self._count("engine.attn_pages_grid",
-                    steps * self.engine_cfg.max_batch * self.pages_per_seq)
+                    steps * int((-(-live // block) * block).sum()))
 
     # --------------------------------------------- device-resident state
 

@@ -2,10 +2,34 @@
 
 Decode attention where each sequence's KV lives in non-contiguous
 fixed-size pages of a shared pool (vLLM-style block tables, re-designed
-for the TPU: the page gather is expressed through a scalar-prefetched
-BlockSpec index map, so Pallas's own pipelining DMAs exactly the pages
-named by the block table — no host-side gather, no dense [B, S_max]
-cache).
+for the TPU).  The kernel's work follows the live context: the pools stay
+in HBM (``pl.ANY``), the grid has one step a slot, and inside it a loop
+walks the slot's block table in BLOCKS of P consecutive entries, for
+``ceil(ceil(length / page_size) / P)`` blocks only.  Each iteration
+starts the P page copies of the next block (``pltpu.make_async_copy``
+from ``pool.at[table[b, i]]`` into the other half of a VMEM double
+buffer, one DMA semaphore a half) and attends the block that has
+arrived.  A slot of length 0 copies nothing and multiplies nothing; no
+table entry past the last live block is read.  This is the structure of
+``jax.experimental.pallas.ops.tpu.paged_attention`` (pages per compute
+block, kernel-issued copies) on this repo's merged-lane layout.
+
+The block size is no knob.  ``block_pages`` gives P from what the call
+shows: as many pages as hold ``_BLOCK_TOKENS`` (256) tokens, at most the
+table's width, at least one.  A table whose width is no multiple of P is
+walked with its tail clamped to the last entry; those columns lie past
+every length and are masked.  On a v5e at 1024 merged lanes a block of
+256 tokens took 16% less time a call than one of 128, and 512 another 3%
+at twice the padding of each slot's last block (PERF.md, PR 24).  VMEM
+grows with P x page_size x lanes: two halves of the stored pages for k
+and for v, and the f32 copies the matmuls read (1 MiB each at 256 tokens
+and 1024 lanes).
+
+What is left of the fixed costs: the first block of every live slot
+starts cold (its copy is waited for with nothing to overlap, a few
+microseconds a slot; the JAX reference kernel hides it by starting the
+next slot's first block early, this one does not), and every grid step,
+live or not, brings its q block and writes its output block.
 
 Layouts:
 - ``k_pages``/``v_pages``: [n_pages, page_size, n_kv*d] — the kv-head and
@@ -15,25 +39,34 @@ Layouts:
   traffic.  With the merged axis the lane dim is n_kv*d (a multiple of
   128 for every real config) and pages are stored/streamed unpadded.
 - ``block_tables``: [B, pages_per_seq] int32 page ids; entries past a
-  sequence's length MUST still be valid ids (the allocator uses 0) —
-  they are fetched but masked out of the softmax.
+  sequence's length MUST still be valid ids (the allocator uses 0): the
+  last live block is copied whole, so the entries that share it with
+  live pages are fetched and masked out of the softmax.
 - ``lengths``: [B] valid kv tokens per sequence (including the current
-  decode position).
+  decode position); 0 for a slot that holds no sequence.
+- quantized pools: int8 pages (or split-half nibble-packed int4) and one
+  f32 scale a token, ``[n_pages, page_size]``.  A row of fewer than 128
+  lanes cannot be sliced out of an HBM array by a kernel's own copy
+  (Mosaic: "must be aligned to tiling (128)"), so the wrapper reshapes
+  the scale pool to rows of 128 lanes (``_lane_dense_scales``: 8 pages
+  of 16 tokens share a row; ``page_size`` has to divide 128 or be a
+  multiple of it), the kernel copies the row that holds each page of the
+  block, and a lane rotation puts the page's scales under its columns of
+  the block's scores (``_scale_row``).
 
-Because a page block now carries ALL kv heads side by side on lanes, the
-kernel processes every query head in one grid step using a
-block-diagonal-q trick: queries are pre-expanded to [n_heads, n_kv*d]
-with each row zero everywhere except its own kv-head's d-slice, so the
-single [n_heads, n_kv*d] x [page, n_kv*d]^T matmul contracts over the
-merged axis and the zeros kill every cross-head term.  The p @ v matmul
+Because a page carries ALL kv heads side by side on lanes, the kernel
+processes every query head at once using a block-diagonal-q trick:
+queries are pre-expanded to [n_heads, n_kv*d] with each row zero
+everywhere except its own kv-head's d-slice, so the single
+[n_heads, n_kv*d] x [P*page, n_kv*d]^T matmul contracts over the merged
+axis and the zeros kill every cross-head term.  The p @ v matmul
 produces [n_heads, n_kv*d] whose valid output lives on the row's own
 d-slice; the caller extracts that block diagonal with one cheap gather.
 This trades a constant-factor of extra MXU work (the zero blocks) for
-halved DMA on an op that is bandwidth-bound — the right trade on TPU.
-
-Grid is (batch, page); the page axis is innermost and carries running
-max / denominator / accumulator scratch across the sweep (online
-softmax, same scheme as ops/flash_attention.py).
+halved DMA.  Scores, running max, denominator and accumulator are f32
+(online softmax, same scheme as ops/flash_attention.py); the bf16 and
+the quantized pool share the loop and the helpers and differ in the
+unpack and the scales.
 
 The reference has no KV cache at all (server-side, reference
 common/openai_generic_assistant.py:45-51); SURVEY §2.2 names the paged
@@ -51,6 +84,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 _LANES = 128
+# tokens one loop iteration attends: on a v5e, at 1024 merged lanes, 256
+# took 16% less time a call than 128 and 512 another 3%, at twice the
+# padding of every slot's last block (PERF.md, Findings, PR 24)
+_BLOCK_TOKENS = 256
 
 
 def _flash_init(acc_ref, m_ref, l_ref):
@@ -60,9 +97,9 @@ def _flash_init(acc_ref, m_ref, l_ref):
 
 
 def _flash_accumulate(s, v, acc_ref, m_ref, l_ref, p_scale=None):
-    """One online-softmax accumulation over this page's scores ``s``
-    [n_heads, page] and values ``v`` [page, KV] (shared by the bf16 and
-    quantized kernels).  ``p_scale`` [page]: optional per-token value
+    """One online-softmax accumulation over a block's scores ``s``
+    [n_heads, T] and values ``v`` [T, KV] (shared by the bf16 and
+    quantized kernels).  ``p_scale`` [1, T]: optional per-token value
     scale folded into the softmax weights (quantized pools)."""
     m_prev = m_ref[:, 0:1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -72,7 +109,7 @@ def _flash_accumulate(s, v, acc_ref, m_ref, l_ref, p_scale=None):
 
     l_ref[:, 0:1] = l_ref[:, 0:1] * correction + jnp.sum(
         p, axis=-1, keepdims=True)
-    pv = p if p_scale is None else p * p_scale[None, :]
+    pv = p if p_scale is None else p * p_scale
     acc_ref[:] = acc_ref[:] * correction + jax.lax.dot_general(
         pv, v,
         dimension_numbers=(((1,), (0,)), ((), ())),
@@ -87,131 +124,149 @@ def _flash_finalize(o_ref, acc_ref, l_ref):
     o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
 
 
+def block_pages(page_size: int, pages_per_seq: int) -> int:
+    """P, the table entries one loop iteration of the kernel covers: as
+    many pages as make ``_BLOCK_TOKENS`` tokens, at least one, at most the
+    table.  A slot's live pages are visited rounded up to a multiple of
+    it (the engine's ``engine.attn_pages_grid`` counts just that)."""
+    return max(1, min(pages_per_seq, _BLOCK_TOKENS // page_size))
+
+
+def _scale_row(buf_ref, slot, page_ids, page_size: int):
+    """The block's per-token scales as one lane-major row [1, P * page].
+
+    ``buf_ref[slot, i]`` is the [1, W] row of the lane-dense scale pool
+    that holds page ``page_ids[i]``'s scales among those of its
+    ``W // page`` neighbours; a lane rotation moves them to where the
+    block's scores have that page's columns."""
+    n = len(page_ids)
+    width = buf_ref.shape[-1]
+    group = width // page_size
+    lane_page = jax.lax.broadcasted_iota(
+        jnp.int32, (1, width), 1) // page_size
+    chunks = []
+    for c in range(0, n, group):
+        chunk = None
+        for i in range(c, min(n, c + group)):
+            row = buf_ref[slot, i]                         # [1, W]
+            if group > 1:
+                shift = ((i % group) - page_ids[i] % group) * page_size
+                row = pltpu.roll(row, jax.lax.rem(shift + width, width), 1)
+            chunk = (row if chunk is None
+                     else jnp.where(lane_page == i % group, row, chunk))
+        chunks.append(chunk)
+    row = chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks, axis=1)
+    return row[:, :n * page_size]
+
+
 def _paged_kernel(
-    lengths_ref,        # SMEM [B]
-    tables_ref,         # SMEM [B, pages_per_seq]  (index-map only)
-    q_ref,              # VMEM [1, n_heads, KV]  (block-diagonal expanded)
-    k_ref,              # VMEM [1, page_size, KV]
-    v_ref,              # VMEM [1, page_size, KV]
-    o_ref,              # VMEM [1, n_heads, KV]
-    acc_ref,            # VMEM scratch [n_heads, KV] f32
-    m_ref,              # VMEM scratch [n_heads, _LANES] f32
-    l_ref,              # VMEM scratch [n_heads, _LANES] f32
-    *,
-    page_size: int,
-    head_dim: int,
-):
-    del tables_ref
-    bi = pl.program_id(0)
-    j = pl.program_id(1)
-    n_pages = pl.num_programs(1)
-
-    @pl.when(j == 0)
-    def _init():
-        _flash_init(acc_ref, m_ref, l_ref)
-
-    length = lengths_ref[bi]
-
-    @pl.when(j * page_size < length)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)               # [n_heads, KV]
-        k = k_ref[0].astype(jnp.float32)               # [page, KV]
-        v = v_ref[0].astype(jnp.float32)               # [page, KV]
-        n_heads = q.shape[0]
-
-        # rows of q are zero outside their own kv-head's d-slice, so
-        # contracting over the merged axis equals the per-head q.k dot
-        scale = jax.lax.rsqrt(jnp.float32(head_dim))
-        s = jax.lax.dot_general(
-            q * scale, k,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                              # [n_heads, page]
-
-        k_pos = (jax.lax.broadcasted_iota(jnp.int32, (n_heads, page_size), 1)
-                 + j * page_size)
-        s = jnp.where(k_pos < length, s, NEG_INF)
-        _flash_accumulate(s, v, acc_ref, m_ref, l_ref)
-
-    @pl.when(j == n_pages - 1)
-    def _finalize():
-        _flash_finalize(o_ref, acc_ref, l_ref)
-
-
-def _paged_kernel_quant(
     lengths_ref,        # SMEM [B]
     tables_ref,         # SMEM [B, pages_per_seq]
     q_ref,              # VMEM [1, n_heads, KV]  (block-diagonal expanded)
-    k_ref,              # VMEM [1, page_size, KV'] int8 (KV' = KV or KV/2)
-    v_ref,              # VMEM [1, page_size, KV'] int8
-    ks_ref,             # VMEM [8, page_size]  scale rows around this page
-    vs_ref,             # VMEM [8, page_size]
-    o_ref,              # VMEM [1, n_heads, KV]
-    acc_ref,            # VMEM scratch [n_heads, KV] f32
-    m_ref,              # VMEM scratch [n_heads, _LANES] f32
-    l_ref,              # VMEM scratch [n_heads, _LANES] f32
-    *,
+    *refs,
     page_size: int,
     head_dim: int,
+    n_block: int,
+    quant: bool,
     packed: bool,
 ):
-    """Quantized-pool variant of ``_paged_kernel``: pages are int8 (or
-    split-half nibble-packed int4) with one scale per token.  The scales
-    never touch the [page, KV] operands — the k scale multiplies the
-    [n_heads, page] score columns and the v scale folds into the softmax
-    weights, so dequantization costs two small row broadcasts.  Scale rows
-    arrive as (8, page_size) blocks (a (1, page_size) block would violate
-    the sublane tiling rule); the row select is a one-hot contraction."""
+    """One slot a grid step: loop over the blocks of ``n_block``
+    table entries that hold live context, copying the next block's pages
+    from the pool (HBM) into the other half of a double buffer while
+    this block is attended.
+
+    ``refs``: the pools in HBM (k, v pages; with ``quant`` also the
+    lane-dense k, v scale rows), the output block, one VMEM double
+    buffer per pool, a DMA semaphore per buffer half, and the running
+    accumulator / max / denominator.
+
+    Quantized pages are int8 (or split-half nibble-packed int4) with one
+    scale per token.  The scales never touch the [T, KV] operands: the k
+    scale multiplies the [n_heads, T] score columns and the v scale
+    folds into the softmax weights."""
+    n_pools = 4 if quant else 2
+    pools, o_ref = refs[:n_pools], refs[n_pools]
+    bufs = refs[n_pools + 1:2 * n_pools + 1]
+    sems, acc_ref, m_ref, l_ref = refs[2 * n_pools + 1:]
+
     bi = pl.program_id(0)
-    j = pl.program_id(1)
-    n_pages = pl.num_programs(1)
-
-    @pl.when(j == 0)
-    def _init():
-        _flash_init(acc_ref, m_ref, l_ref)
-
     length = lengths_ref[bi]
+    pages_per_seq = tables_ref.shape[1]
+    block_tokens = n_block * page_size
+    n_blocks = (length + block_tokens - 1) // block_tokens
 
-    @pl.when(j * page_size < length)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)               # [n_heads, KV]
-        n_heads = q.shape[0]
+    def page_ids(blk):
+        # a table no multiple of the block: the tail repeats its last
+        # entry, whose columns lie past every length
+        return [tables_ref[bi, jnp.minimum(blk * n_block + i,
+                                           pages_per_seq - 1)]
+                for i in range(n_block)]
 
-        def unpack(ref):
-            raw = ref[0].astype(jnp.int32)             # [page, KV']
-            if not packed:
-                return raw.astype(jnp.float32)
-            lo = ((raw << 28) >> 28).astype(jnp.float32)   # sign-extended
-            hi = (raw >> 4).astype(jnp.float32)
-            return jnp.concatenate([lo, hi], axis=-1)  # [page, KV]
+    def copies(pids, slot):
+        out = []
+        for i, pid in enumerate(pids):
+            for pool, buf in zip(pools[:2], bufs[:2]):
+                out.append(pltpu.make_async_copy(
+                    pool.at[pid], buf.at[slot, i], sems.at[slot]))
+            for pool, buf in zip(pools[2:], bufs[2:]):
+                group = buf.shape[-1] // page_size
+                out.append(pltpu.make_async_copy(
+                    pool.at[pl.ds(pid // group, 1)], buf.at[slot, i],
+                    sems.at[slot]))
+        return out
 
-        k = unpack(k_ref)
-        v = unpack(v_ref)
+    def unpack(ref, slot):
+        """[P, page, KV'] as stored -> f32 [P * page, KV]."""
+        x = ref[slot]
+        if packed:
+            # widened first: Mosaic has no int8 vector shifts
+            x = x.astype(jnp.int32)
+            x = jnp.concatenate([(x << 28) >> 28, x >> 4], axis=-1)
+        x = x.astype(jnp.float32)
+        return x.reshape(block_tokens, x.shape[-1])
 
-        # select this page's scale row from the (8, page_size) block.
-        # where-then-sum, NOT multiply-by-onehot: rows past the pool's end
-        # are uninitialized block padding that may hold inf/NaN, and
-        # NaN * 0 would poison the sum
-        row = tables_ref[bi, j] % 8
-        onehot = (jax.lax.broadcasted_iota(jnp.int32, (8, 1), 0) == row)
-        ks = jnp.sum(jnp.where(onehot, ks_ref[:, :], 0.0), axis=0)
-        vs = jnp.sum(jnp.where(onehot, vs_ref[:, :], 0.0), axis=0)
+    _flash_init(acc_ref, m_ref, l_ref)
 
-        scale = jax.lax.rsqrt(jnp.float32(head_dim))
+    @pl.when(n_blocks > 0)
+    def _first():
+        for c in copies(page_ids(0), 0):
+            c.start()
+
+    # rows of q are zero outside their own kv-head's d-slice, so
+    # contracting over the merged axis equals the per-head q.k dot
+    q = q_ref[0].astype(jnp.float32) * jax.lax.rsqrt(jnp.float32(head_dim))
+    n_heads = q.shape[0]
+
+    def block(blk, carry):
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _next():
+            for c in copies(page_ids(blk + 1), 1 - slot):
+                c.start()
+
+        pids = page_ids(blk)
+        for c in copies(pids, slot):
+            c.wait()
+
         s = jax.lax.dot_general(
-            q * scale, k,
+            q, unpack(bufs[0], slot),
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * ks[None, :]                                # [n_heads, page]
-
-        k_pos = (jax.lax.broadcasted_iota(jnp.int32, (n_heads, page_size), 1)
-                 + j * page_size)
+        )                                              # [n_heads, T]
+        p_scale = None
+        if quant:
+            s = s * _scale_row(bufs[2], slot, pids, page_size)
+            p_scale = _scale_row(bufs[3], slot, pids, page_size)
+        k_pos = (jax.lax.broadcasted_iota(
+            jnp.int32, (n_heads, block_tokens), 1) + blk * block_tokens)
         s = jnp.where(k_pos < length, s, NEG_INF)
-        _flash_accumulate(s, v, acc_ref, m_ref, l_ref, p_scale=vs)
+        _flash_accumulate(s, unpack(bufs[1], slot), acc_ref, m_ref, l_ref,
+                          p_scale=p_scale)
+        return carry
 
-    @pl.when(j == n_pages - 1)
-    def _finalize():
-        _flash_finalize(o_ref, acc_ref, l_ref)
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+    _flash_finalize(o_ref, acc_ref, l_ref)
 
 
 def _expand_block_diag(q: jnp.ndarray, n_kv: int) -> jnp.ndarray:
@@ -235,6 +290,76 @@ def _extract_block_diag(out: jnp.ndarray, n_kv: int, d: int) -> jnp.ndarray:
     return out[:, jnp.arange(n_heads), head_kv]
 
 
+def _lane_dense_scales(scales: jnp.ndarray) -> jnp.ndarray:
+    """[n_pages, page] -> f32 [rows, W] with W = max(128, page): the
+    kernel copies rows of the pool itself, and a row of fewer than 128
+    lanes cannot be sliced out of HBM (Mosaic: "must be aligned to
+    tiling (128)"), so pages narrower than that share a row."""
+    n_pages, page_size = scales.shape
+    if _LANES % page_size and page_size % _LANES:
+        raise ValueError(
+            f"the quantized paged-attention kernel needs a page_size that "
+            f"divides {_LANES} or is a multiple of it, got {page_size}")
+    group = max(1, _LANES // page_size)
+    scales = jnp.pad(scales.astype(jnp.float32),
+                     ((0, -n_pages % group), (0, 0)))
+    return scales.reshape(-1, group * page_size)
+
+
+def _paged_call(name, q, pools, lengths, block_tables, *, packed, interpret):
+    """The one ``pallas_call`` both pools' kernels are: ``pools`` are the
+    k and v pages and, for a quantized pool, the two [n_pages, page] scale
+    pools."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+
+    b, n_heads, d = q.shape
+    _, page_size, kv_store = pools[0].shape
+    kv_dim = kv_store * 2 if packed else kv_store
+    assert kv_dim % d == 0, (kv_dim, d)
+    n_kv = kv_dim // d
+    assert n_heads % n_kv == 0, (n_heads, n_kv)
+    n_block = block_pages(page_size, block_tables.shape[1])
+
+    pools = list(pools[:2]) + [_lane_dense_scales(s) for s in pools[2:]]
+    buffers = [pltpu.VMEM((2, n_block, page_size, kv_store), p.dtype)
+               for p in pools[:2]]
+    buffers += [pltpu.VMEM((2, n_block, 1, p.shape[-1]), p.dtype)
+                for p in pools[2:]]
+    slot_block = pl.BlockSpec((1, n_heads, kv_dim),
+                              lambda bi, lens, tabs: (bi, 0, 0))
+
+    out = pl.pallas_call(
+        functools.partial(_paged_kernel, page_size=page_size, head_dim=d,
+                          n_block=n_block, quant=len(pools) > 2,
+                          packed=packed),
+        name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[slot_block] + [pl.BlockSpec(memory_space=pl.ANY)
+                                     for _ in pools],
+            out_specs=slot_block,
+            scratch_shapes=buffers + [
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((n_heads, kv_dim), jnp.float32),
+                pltpu.VMEM((n_heads, _LANES), jnp.float32),
+                pltpu.VMEM((n_heads, _LANES), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, n_heads, kv_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+        ),
+        interpret=interpret,
+    )(
+        lengths.astype(jnp.int32),
+        block_tables.astype(jnp.int32),
+        _expand_block_diag(q, n_kv), *pools,
+    )
+    return _extract_block_diag(out, n_kv, d)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(
     q: jnp.ndarray,             # [B, n_heads, d]
@@ -246,52 +371,8 @@ def paged_attention(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Single-step decode attention over a paged KV pool: [B, n_heads, d]."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
-    b, n_heads, d = q.shape
-    _, page_size, kv_dim = k_pages.shape
-    assert kv_dim % d == 0, (kv_dim, d)
-    n_kv = kv_dim // d
-    assert n_heads % n_kv == 0, (n_heads, n_kv)
-    pages_per_seq = block_tables.shape[1]
-
-    q_exp = _expand_block_diag(q, n_kv)
-    grid = (b, pages_per_seq)
-
-    out = pl.pallas_call(
-        functools.partial(_paged_kernel, page_size=page_size, head_dim=d),
-        name="paged_attention",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, n_heads, kv_dim),
-                             lambda bi, j, lens, tabs: (bi, 0, 0)),
-                pl.BlockSpec((1, page_size, kv_dim),
-                             lambda bi, j, lens, tabs: (tabs[bi, j], 0, 0)),
-                pl.BlockSpec((1, page_size, kv_dim),
-                             lambda bi, j, lens, tabs: (tabs[bi, j], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, n_heads, kv_dim),
-                                   lambda bi, j, lens, tabs: (bi, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((n_heads, kv_dim), jnp.float32),
-                pltpu.VMEM((n_heads, _LANES), jnp.float32),
-                pltpu.VMEM((n_heads, _LANES), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, n_heads, kv_dim), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(
-        lengths.astype(jnp.int32),
-        block_tables.astype(jnp.int32),
-        q_exp, k_pages, v_pages,
-    )
-    return _extract_block_diag(out, n_kv, d)
+    return _paged_call("paged_attention", q, (k_pages, v_pages), lengths,
+                       block_tables, packed=False, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("packed", "interpret"))
@@ -309,65 +390,9 @@ def paged_attention_quant(
 ) -> jnp.ndarray:
     """Decode attention over a QUANTIZED paged pool (int8, or split-half
     nibble-packed int4 when ``packed``): [B, n_heads, d]."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
-    b, n_heads, d = q.shape
-    _, page_size, kv_store = k_pages.shape
-    kv_dim = kv_store * 2 if packed else kv_store
-    assert kv_dim % d == 0, (kv_dim, d)
-    n_kv = kv_dim // d
-    assert n_heads % n_kv == 0, (n_heads, n_kv)
-    pages_per_seq = block_tables.shape[1]
-
-    q_exp = _expand_block_diag(q, n_kv)
-    grid = (b, pages_per_seq)
-
-    out = pl.pallas_call(
-        functools.partial(_paged_kernel_quant, page_size=page_size,
-                          head_dim=d, packed=packed),
-        name="paged_attention_quant",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, n_heads, kv_dim),
-                             lambda bi, j, lens, tabs: (bi, 0, 0)),
-                pl.BlockSpec((1, page_size, kv_store),
-                             lambda bi, j, lens, tabs: (tabs[bi, j], 0, 0)),
-                pl.BlockSpec((1, page_size, kv_store),
-                             lambda bi, j, lens, tabs: (tabs[bi, j], 0, 0)),
-                # scale rows: (8, page) blocks — a (1, page) block would
-                # break the sublane tiling rule; the kernel one-hot-selects
-                # row tabs[bi, j] % 8
-                pl.BlockSpec((8, page_size),
-                             lambda bi, j, lens, tabs: (tabs[bi, j] // 8, 0)),
-                pl.BlockSpec((8, page_size),
-                             lambda bi, j, lens, tabs: (tabs[bi, j] // 8, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, n_heads, kv_dim),
-                                   lambda bi, j, lens, tabs: (bi, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((n_heads, kv_dim), jnp.float32),
-                pltpu.VMEM((n_heads, _LANES), jnp.float32),
-                pltpu.VMEM((n_heads, _LANES), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, n_heads, kv_dim), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(
-        lengths.astype(jnp.int32),
-        block_tables.astype(jnp.int32),
-        q_exp, k_pages, v_pages,
-        # scales enter as f32 regardless of the pool's compute dtype: the
-        # (8, page_size) scale BlockSpec is validated on-chip for f32
-        # sublane tiling, and the cast is O(n_pages * page_size) — noise
-        k_scales.astype(jnp.float32), v_scales.astype(jnp.float32),
-    )
-    return _extract_block_diag(out, n_kv, d)
+    return _paged_call("paged_attention_quant", q,
+                       (k_pages, v_pages, k_scales, v_scales), lengths,
+                       block_tables, packed=packed, interpret=interpret)
 
 
 def _validate_head_shard(n_heads: int, n_kv: int, n_tp: int) -> None:

@@ -67,10 +67,13 @@ def _compiles_with_kernel(fn, *args):
 
 
 class TestAttentionKernelsCompileForV5e:
-    # the bench's flagship pool (144 slots, page 64) and the pool the sweep
-    # CLI builds (16 slots, EngineConfig's page 16, 4096-token tables)
+    # the bench's flagship pool (144 slots, page 64), the pool the sweep
+    # CLI builds (16 slots, EngineConfig's page 16, 4096-token tables) and
+    # the pool of BENCHMARK.json's cells (mistral-7b-v0.3: 32 slots, 3,072
+    # pages; Mistral and Mixtral share Llama-3-8B's 32/8 heads of 128)
     POOLS = [pytest.param(144, 64, 1864, 12, id="b144-page64"),
-             pytest.param(16, 16, 1024, 256, id="b16-page16")]
+             pytest.param(16, 16, 1024, 256, id="b16-page16"),
+             pytest.param(32, 16, 3072, 256, id="b32-page16-cells")]
 
     @pytest.mark.parametrize("b,page,n_pages,pages_per_seq", POOLS)
     def test_paged_attention(self, chip, b, page, n_pages, pages_per_seq):

@@ -23,6 +23,7 @@ from k8s_llm_rca_tpu.obs import trace as obs_trace
 from k8s_llm_rca_tpu.obs import (
     SITES, Tracer, coverage_missing, critical_path,
 )
+from k8s_llm_rca_tpu.ops.paged_attention import block_pages
 from k8s_llm_rca_tpu.runtime import profiling
 from k8s_llm_rca_tpu.utils.logging import METRICS, Metrics
 from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
@@ -241,11 +242,14 @@ class TestWorkCounters:
         eng.submit([1] * 5, max_new_tokens=4)
         eng.submit([1] * 17, max_new_tokens=4)
         eng.step()      # both admitted; one scan of 4 steps at lengths 5, 17
-        pages_per_seq = 64 // 8
         # ceil(5 / 8) + ceil(17 / 8) = 1 + 3 pages hold context
         assert counters.count("engine.attn_pages_live") == 4 * (1 + 3)
-        assert (counters.count("engine.attn_pages_grid")
-                == 4 * eng.engine_cfg.max_batch * pages_per_seq)
+        # the kernel visits each live slot's pages rounded up to its block
+        # (here the whole table of 64 // 8 pages: 256 tokens are more),
+        # and no page of a slot that holds no sequence
+        block = block_pages(8, 64 // 8)
+        assert block == 8 and eng.engine_cfg.max_batch > 2
+        assert counters.count("engine.attn_pages_grid") == 4 * (block + block)
         eng.run_to_completion()
         snap = counters.snapshot()
         assert (snap["engine.attn_pages_live"]

@@ -13,7 +13,7 @@ import pytest
 from k8s_llm_rca_tpu.ops.attention import causal_attention
 from k8s_llm_rca_tpu.ops.flash_attention import flash_attention
 from k8s_llm_rca_tpu.ops.paged_attention import (
-    paged_attention, paged_attention_xla,
+    block_pages, paged_attention, paged_attention_xla,
 )
 
 
@@ -65,7 +65,65 @@ class TestFlashAttention:
             rtol=3e-2, atol=3e-2)
 
 
+def _scattered_tables(lengths, page, pages_per_seq, n_pages, seed=0):
+    """A table of distinct, shuffled page ids (never page 0) for the live
+    pages of every slot, 0 past them: the allocator's contract."""
+    rng = np.random.default_rng(seed)
+    free = list(rng.permutation(np.arange(1, n_pages)))
+    tables = np.zeros((len(lengths), pages_per_seq), np.int32)
+    for b, n in enumerate(lengths):
+        for j in range(-(-n // page)):
+            tables[b, j] = free.pop()
+    return jnp.asarray(tables)
+
+
+# (id, page, pages_per_seq, n_pages, n_heads, n_kv, d, lengths given the
+# tokens of one kernel block at that page size and table width): what the
+# live-context walk has to get right, one case each
+WALK_CASES = [
+    # a slot of length 0 beside live ones: no copy, no matmul, zeros out
+    ("dead-slot-beside-live", 16, 32, 96, 4, 2, 64,
+     lambda blk: [40, 0, blk + 7, 0]),
+    # a length exactly on a block boundary, and one token past it
+    ("block-boundary", 16, 48, 128, 4, 2, 64,
+     lambda blk: [blk, blk + 1, 2 * blk, 2 * blk + 1]),
+    # one live page in a table of 256
+    ("one-page-of-256", 16, 256, 40, 4, 2, 64, lambda blk: [9, 16]),
+    # tables whose width is no multiple of the block: the tail repeats
+    # the last entry
+    ("table-20-page-16", 16, 20, 64, 4, 2, 64, lambda blk: [320, 257, 17]),
+    ("table-10-page-64", 64, 10, 40, 4, 2, 64,
+     lambda blk: [10 * 64, 5 * 64 + 3, 1]),
+    # the bench's table (tests/test_aot_compile.py), and one narrower
+    # than a block: the block is the table
+    ("table-12-page-64", 64, 12, 40, 4, 2, 64,
+     lambda blk: [12 * 64, 4 * 64, 65]),
+    ("table-12-page-16", 16, 12, 40, 4, 2, 64, lambda blk: [192, 100, 17]),
+    # GQA 32/8 at 128 lanes a head: the benchmark's head shape, small pool
+    ("gqa-32-8-d128", 16, 20, 48, 32, 8, 128,
+     lambda blk: [300, 16, 0, 161]),
+]
+WALK_IDS = [c[0] for c in WALK_CASES]
+
+
+def _walk_case(case):
+    _, page, pages_per_seq, n_pages, n_heads, n_kv, d, lengths = case
+    return (page, pages_per_seq, n_pages, n_heads, n_kv, d,
+            lengths(block_pages(page, pages_per_seq) * page))
+
+
 class TestPagedAttention:
+    @pytest.mark.parametrize("page,pages_per_seq,pages", [
+        (16, 256, 16),      # the benchmark's cells: 256 tokens a block
+        (64, 12, 4),        # the bench's pool
+        (16, 12, 12),       # a table narrower than a block: the table
+        (8, 8, 8),
+        (512, 4, 1),        # a page longer than a block: one page
+    ])
+    def test_block_pages_follow_page_and_table(self, page, pages_per_seq,
+                                               pages):
+        assert block_pages(page, pages_per_seq) == pages
+
     def _mk_pool(self, key, n_kv, n_pages, page, d):
         kk, kv = jax.random.split(key)
         kp = jax.random.normal(kk, (n_pages, page, n_kv * d))
@@ -100,6 +158,24 @@ class TestPagedAttention:
         out = paged_attention(q, kp, vp, lengths, tables)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("case", WALK_CASES, ids=WALK_IDS)
+    def test_walks_the_live_context(self, case):
+        page, pages_per_seq, n_pages, n_heads, n_kv, d, lengths = (
+            _walk_case(case))
+        q = jax.random.normal(jax.random.PRNGKey(11),
+                              (len(lengths), n_heads, d))
+        kp, vp = self._mk_pool(jax.random.PRNGKey(12), n_kv, n_pages, page,
+                               d)
+        tables = _scattered_tables(lengths, page, pages_per_seq, n_pages)
+        lens = jnp.asarray(lengths, jnp.int32)
+        out = np.asarray(paged_attention(q, kp, vp, lens, tables))
+        ref = np.asarray(paged_attention_xla(q, kp, vp, lens, tables))
+        live = np.asarray(lengths) > 0
+        np.testing.assert_allclose(out[live], ref[live],
+                                   rtol=2e-5, atol=2e-5)
+        # nothing to attend: zeros, where the reference averages page 0
+        assert not out[~live].any()
 
 
 class TestPagedAttentionQuant:
@@ -158,6 +234,86 @@ class TestPagedAttentionQuant:
                                     packed=packed)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize("packed", [False, True], ids=["int8", "int4"])
+    @pytest.mark.parametrize("case", WALK_CASES, ids=WALK_IDS)
+    def test_walks_the_live_context(self, case, packed):
+        from k8s_llm_rca_tpu.ops.paged_attention import paged_attention_quant
+
+        page, pages_per_seq, n_pages, n_heads, n_kv, d, lengths = (
+            _walk_case(case))
+        q = jax.random.normal(jax.random.PRNGKey(13),
+                              (len(lengths), n_heads, d))
+        kq, vq, ks, vs = self._mk_quant_pool(jax.random.PRNGKey(14), n_kv,
+                                             n_pages, page, d, packed)
+        # shuffled ids: a block's pages fall in different 128-lane scale
+        # rows, at every position inside them
+        tables = _scattered_tables(lengths, page, pages_per_seq, n_pages)
+        lens = jnp.asarray(lengths, jnp.int32)
+        out = np.asarray(paged_attention_quant(q, kq, vq, ks, vs, lens,
+                                               tables, packed=packed))
+        ref = np.asarray(self._reference(q, kq, vq, ks, vs, lens, tables,
+                                         packed))
+        live = np.asarray(lengths) > 0
+        np.testing.assert_allclose(out[live], ref[live],
+                                   rtol=2e-4, atol=2e-4)
+        assert not out[~live].any()
+
+    def test_pool_no_multiple_of_a_scale_row(self):
+        """9 pages of 16 tokens are 144 scales, no multiple of the 128
+        lanes a row of the kernel's scale pool has: the last page's row
+        is padded, not dropped."""
+        from k8s_llm_rca_tpu.ops.paged_attention import paged_attention_quant
+
+        q = jax.random.normal(jax.random.PRNGKey(15), (2, 4, 64))
+        kq, vq, ks, vs = self._mk_quant_pool(jax.random.PRNGKey(16), 2, 9,
+                                             16, 64, False)
+        tables = jnp.asarray([[8, 7, 0], [1, 0, 0]], jnp.int32)
+        lens = jnp.asarray([30, 16], jnp.int32)
+        ref = self._reference(q, kq, vq, ks, vs, lens, tables, False)
+        out = paged_attention_quant(q, kq, vq, ks, vs, lens, tables)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-4, atol=2e-4)
+
+    def test_page_size_has_to_share_a_scale_row(self):
+        from k8s_llm_rca_tpu.ops.paged_attention import paged_attention_quant
+
+        z8 = jnp.zeros((4, 24, 128), jnp.int8)
+        zs = jnp.zeros((4, 24), jnp.float32)
+        with pytest.raises(ValueError, match="page_size"):
+            paged_attention_quant(jnp.zeros((1, 2, 64)), z8, z8, zs, zs,
+                                  jnp.asarray([3]), jnp.zeros((1, 2),
+                                                              jnp.int32))
+
+    def test_engine_decode_step_holds_no_sequence_is_told_zero(self):
+        """A slot whose table row starts at the trash page enters the
+        kernel at length 0 whatever stale length it carries; the live
+        slot's logits are those of the gather path."""
+        from k8s_llm_rca_tpu.config import TINY
+        from k8s_llm_rca_tpu.engine.paged import (
+            init_paged_cache, paged_decode_step, paged_prefill,
+        )
+        from k8s_llm_rca_tpu.models import llama
+
+        cfg = TINY.replace(max_seq_len=64)
+        params = llama.init_params(cfg, jax.random.PRNGKey(0))
+        pool = init_paged_cache(cfg, 32, 8, kv_dtype=jnp.int8)
+        padded = jnp.zeros((1, 16), jnp.int32).at[0, :13].set(
+            jnp.arange(5, 18))
+        pool, _ = paged_prefill(cfg, params, pool, padded, jnp.int32(13),
+                                jnp.asarray([7, 3], jnp.int32))
+        tables = jnp.zeros((2, 8), jnp.int32).at[0, :3].set(
+            jnp.asarray([7, 3, 11]))
+        args = (jnp.asarray([21, 4], jnp.int32),
+                jnp.asarray([13, 57], jnp.int32), tables)   # 57: stale
+        _, lg_kernel = paged_decode_step(cfg, params, pool, *args,
+                                         use_kernel=True)
+        _, lg_xla = paged_decode_step(cfg, params, pool, *args,
+                                      use_kernel=False)
+        np.testing.assert_allclose(np.asarray(lg_kernel[0]),
+                                   np.asarray(lg_xla[0]),
+                                   rtol=2e-4, atol=2e-4)
+        assert np.isfinite(np.asarray(lg_kernel)).all()
 
     def test_engine_decode_step_uses_kernel_path(self):
         # use_kernel=True on CPU runs the quant kernel in interpret mode;
