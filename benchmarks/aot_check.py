@@ -42,7 +42,6 @@ def main(argv=None) -> int:
     from benchmarks.lib import build
     from k8s_llm_rca_tpu.engine import paged
     from k8s_llm_rca_tpu.engine.sampling import SamplingParams
-    from k8s_llm_rca_tpu.models import llama
     from k8s_llm_rca_tpu.models.quant import quantize_params
 
     conf = build.load_json(os.path.join(build.BENCH_DIR, "configs",
@@ -62,10 +61,11 @@ def main(argv=None) -> int:
             tree)
 
     bits = conf.get("weight_quant_bits")
+    init_params = build.init_params_fn(conf)
     params = described(jax.eval_shape(
         lambda: quantize_params(
-            llama.init_params(cfg, jax.random.PRNGKey(0)), bits=bits)
-        if bits else llama.init_params(cfg, jax.random.PRNGKey(0))))
+            init_params(cfg, jax.random.PRNGKey(0)), bits=bits)
+        if bits else init_params(cfg, jax.random.PRNGKey(0))))
     pool = described(jax.eval_shape(
         lambda: paged.init_paged_cache(cfg, ecfg.num_pages, ecfg.page_size,
                                        ecfg.kv_cache_dtype)))

@@ -1,7 +1,9 @@
 """Share of the device's busy time spent in the expert MLP's operations (the
 three expert einsums and the dequantization feeding them), found in the
-trace by the shapes in their HLO text (``costs.expert_mlp_pattern``).  With dense soft dispatch every expert
-runs on every token, so three quarters of it is work on unrouted experts."""
+trace by the shapes in their HLO text (``costs.expert_mlp_pattern``); the
+shapes are the built model's (``engine.model_cfg``), whatever its
+configuration file calls them.  With dense soft dispatch every expert runs
+on every token, so three quarters of it is work on unrouted experts."""
 
 from benchmarks.trace import costs
 
@@ -11,11 +13,11 @@ MOVES = "out_tokens_per_s"
 
 
 def read(ctx):
-    if ctx.trace is None or not ctx.conf.get("num_local_experts"):
+    cfg = ctx.engine.model_cfg
+    if ctx.trace is None or not cfg.n_experts:
         return None
-    pattern = costs.expert_mlp_pattern(ctx.conf["num_local_experts"],
-                                       ctx.conf["hidden_size"],
-                                       ctx.conf["intermediate_size"])
+    pattern = costs.expert_mlp_pattern(cfg.n_experts, cfg.hidden_size,
+                                       cfg.intermediate_size)
     seconds = costs.kernel_time(ctx.trace["op_seconds"], pattern,
                                 ctx.trace["op_text"])
     busy = ctx.trace["busy_s"]

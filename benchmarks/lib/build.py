@@ -3,22 +3,29 @@ into the program's build path.
 
 A configuration file (``benchmarks/configs/<name>.json``) holds the published
 ``config.json`` keys as they are run, the two serving precisions, and an
-``engine`` group that becomes the ``EngineConfig``.  The engine is built as
-``sweeps/common.py::build_service`` builds it: ``init_params`` with the
-quantizing transform, the byte tokenizer padded to the vocabulary,
-``make_engine(paged=True)``.
+``engine`` group that becomes the ``EngineConfig``.  Which published key
+becomes which field of the program's ``ModelConfig``, which values the
+program can honour, and which function of the program makes the weights is
+the ARCHITECTURE's, one file for each ``model_type``
+(``benchmarks/architectures/<model_type>.json``): nothing here names a
+published key.  The engine is built as ``sweeps/common.py::build_service``
+builds it: that weight builder with the quantizing transform, the byte
+tokenizer padded to the vocabulary, ``make_engine(paged=True)``.
 """
 
 from __future__ import annotations
 
+import dataclasses
+import importlib
 import json
 import os
 import time
-from typing import Any, Dict, Tuple
+from typing import Any, Callable, Dict, Tuple
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 BENCH_DIR = os.path.join(ROOT, "benchmarks")
+ARCH_DIR = os.path.join(BENCH_DIR, "architectures")
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
@@ -84,27 +91,88 @@ def memory_peak_bytes(chips: int = 1) -> int:
     return int(max(peaks))
 
 
+# top-level keys of a configuration file that are the harness's own; every
+# other one is a published key and has to be known to the architecture's file
+HARNESS_KEYS = ("source", "model_type", "weight_quant_bits", "kv_cache_dtype",
+                "engine", "reference", "assumed", "deployment", "reduced_why",
+                "memory")
+# keys every published ``config.json`` may carry that say nothing about the
+# model's shape; an architecture lists its own such keys under ``ignored``
+SHAPELESS_KEYS = ("architectures", "transformers_version", "bos_token_id",
+                  "eos_token_id", "pad_token_id", "use_cache",
+                  "initializer_range")
+
+
+def architecture(conf: Dict[str, Any]) -> Dict[str, Any]:
+    """The file of the configuration's ``model_type``:
+
+    - ``fields``: published key -> ``ModelConfig`` field; each key has to be
+      in the configuration file;
+    - ``honoured``: published key -> the values the program computes
+      correctly; another value is refused, never ignored;
+    - ``fixed``: ``ModelConfig`` fields the architecture implies and no key
+      states;
+    - ``ignored``: its keys that say nothing about shape;
+    - ``init_params``: ``module:function`` of the program that makes the
+      weights, ``f(model_cfg, key, tensor_transform=None)``.
+    """
+    known = sorted(f[:-len(".json")] for f in os.listdir(ARCH_DIR)
+                   if f.endswith(".json"))
+    model_type = conf.get("model_type")
+    if model_type not in known:
+        raise ValueError(
+            f"no architecture for model_type {model_type!r} (known: "
+            f"{', '.join(known)}); a new one adds benchmarks/architectures/"
+            f"<model_type>.json beside the program code that computes it")
+    return load_json(os.path.join(ARCH_DIR, model_type + ".json"))
+
+
 def model_config(conf: Dict[str, Any], name: str):
+    """The program's ``ModelConfig`` for a configuration file, strict both
+    ways: a published key the architecture's file does not know, a value it
+    does not list as honoured and a key it needs and the file lacks are each
+    a ``ValueError`` that names the key."""
     from k8s_llm_rca_tpu.config import ModelConfig
 
+    arch = architecture(conf)
+    what = f"{name} (model_type {conf['model_type']!r})"
+    published = {k: v for k, v in conf.items() if k not in HARNESS_KEYS}
+    fields, honoured = arch["fields"], arch.get("honoured", {})
+    known = {*fields, *honoured, *arch.get("ignored", ()), *SHAPELESS_KEYS}
+    for key, value in published.items():
+        if key not in known:
+            raise ValueError(
+                f"{what}: nothing reads the key {key!r}; the program has to "
+                f"compute what it stands for before the architecture's file "
+                f"may list it")
+        if key in honoured and value not in honoured[key]:
+            raise ValueError(
+                f"{what}: {key} = {value!r}, and the program honours only "
+                f"{honoured[key]!r}")
+    defaults = {f.name: f.default for f in dataclasses.fields(ModelConfig)}
+    kw = dict(arch.get("fixed", {}))
+    for key, field in fields.items():
+        if key not in published:
+            raise ValueError(f"{what}: the key {key!r} is missing")
+        value = published[key]
+        if isinstance(value, list):     # a ModelConfig is a static argument
+            value = tuple(value)
+        elif isinstance(defaults.get(field), float):
+            value = float(value)
+        kw[field] = value
+    unknown = sorted(set(kw) - set(defaults))
+    if unknown:
+        raise ValueError(f"{what}: ModelConfig has no field {unknown[0]!r}")
     return ModelConfig(
-        name=name,
-        vocab_size=conf["vocab_size"],
-        hidden_size=conf["hidden_size"],
-        n_layers=conf["num_hidden_layers"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf["head_dim"],
-        intermediate_size=conf["intermediate_size"],
-        rope_theta=float(conf["rope_theta"]),
-        rms_norm_eps=float(conf["rms_norm_eps"]),
-        max_seq_len=conf["max_position_embeddings"],
-        dtype=conf["torch_dtype"],
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        n_experts=conf.get("num_local_experts", 0),
-        n_experts_per_tok=conf.get("num_experts_per_tok", 2),
+        name=name, **kw,
         fused_quant_matmul=bool(conf["engine"].get("fused_quant_matmul",
                                                    False)))
+
+
+def init_params_fn(conf: Dict[str, Any]) -> Callable:
+    """The program's weight builder for the configuration's architecture."""
+    module, _, function = architecture(conf)["init_params"].partition(":")
+    return getattr(importlib.import_module(module), function)
 
 
 def engine_config(conf: Dict[str, Any]):
@@ -123,14 +191,13 @@ def build_engine(conf: Dict[str, Any], name: str, seed: int
     import jax
 
     from k8s_llm_rca_tpu.engine import make_engine
-    from k8s_llm_rca_tpu.models import llama
     from k8s_llm_rca_tpu.models.quant import quantizing_transform
     from k8s_llm_rca_tpu.utils import get_tokenizer
 
     mcfg, ecfg = model_config(conf, name), engine_config(conf)
     bits = conf.get("weight_quant_bits")
     t0 = time.perf_counter()
-    params = llama.init_params(
+    params = init_params_fn(conf)(
         mcfg, jax.random.PRNGKey(seed),
         tensor_transform=quantizing_transform(bits=bits) if bits else None)
     jax.block_until_ready(params)

@@ -179,7 +179,11 @@ def main(argv=None) -> int:
                   "warm_programs": n_warm, "programs": meter.count,
                   "cache_hits": meter.cache_hits,
                   "compile_s": meter.seconds},
-        "generator": measure.generator_report(ctx)}}
+        "generator": measure.generator_report(ctx),
+        # what the program counted while traced: the denominators of the
+        # readers that divide a trace's seconds
+        "traced_counters": ctx.trace and {
+            k: v for k, v in ctx.trace["counters"].items() if v}}}
     print(json.dumps(report), flush=True)
     print(json.dumps(line), flush=True)
     return 0

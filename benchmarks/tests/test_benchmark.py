@@ -343,11 +343,19 @@ def test_refuses_to_run_a_cell_on_a_cpu():
 
 @pytest.fixture(scope="module")
 def throwaway_cell():
-    """A configuration, a mix, a per-layer metric and a cell of their own,
-    added as files and entries with no edit to a file that is there."""
+    """An architecture, a configuration, a mix, a per-layer metric and a
+    cell of their own, added as files and entries with no edit to a file
+    that is there.  The architecture is ``tiny``'s under another
+    ``model_type``, with another name for its expert count."""
+    arch = load(os.path.join(BENCH, "architectures", "mixtral.json"))
+    del arch["fields"]["num_local_experts"]
+    arch["fields"]["n_routed_experts"] = "n_experts"
+    conf = load(os.path.join(BENCH, "configs", "tiny.json"))
+    conf["model_type"] = "throwaway"
+    conf["n_routed_experts"] = conf.pop("num_local_experts")
     added = {
-        os.path.join(BENCH, "configs", "throwaway.json"):
-            load(os.path.join(BENCH, "configs", "tiny.json")),
+        os.path.join(BENCH, "architectures", "throwaway.json"): arch,
+        os.path.join(BENCH, "configs", "throwaway.json"): conf,
         os.path.join(BENCH, "traffic", "throwaway-mix.json"): dict(
             load(os.path.join(BENCH, "traffic", "chat-open.json")),
             ramp_s=2, arrivals={"kind": "poisson", "rate_rps": 3.0}),
@@ -424,6 +432,8 @@ def test_traced_line_on_a_cpu_holds_no_device_metric(throwaway_cell):
     assert "busy_s" not in last["device"]
     assert report["run"]["compiles_in_window"] == 0
     assert report["run"]["check"]["ok"]
+    # the check asked the built model, not the file, whether it has experts
+    assert report["run"]["check"]["positions_allowed_over"] == 18 // 3
 
 
 SWEEP_READERS = ("report_s_p50", "incidents_inflight_mean",
@@ -495,18 +505,23 @@ def test_reduce_recorded_v5e_trace(sample_trace):
             p["seconds"], rel=1e-9)
     assert [k for k, _ in got["idle_gaps"]] == \
         [k for k, _ in expected["idle_gaps"]]
-    # 41 scans of 16 steps on the 2-layer ``tiny`` configuration
-    seconds, steps = costs.decode_program_time(
-        got["programs"], got["op_counts"], n_layers=2)
+    # 41 scans of 16 steps on the 2-layer ``tiny`` configuration; the sample
+    # predates the engine's step counter (PR 23), so the steps it would have
+    # counted are the kernel's calls over the layers
+    seconds = costs.decode_program_time(got["programs"])
+    steps = sum(c for name, c in got["op_counts"].items()
+                if costs.PAGED_ATTENTION.search(name)) / 2
     assert steps == 41 * 16 and seconds > 0
     assert costs.kernel_time(got["op_seconds"], costs.PAGED_ATTENTION) > 0
     from types import SimpleNamespace
 
     from benchmarks.layer_metrics import decode_step_ms
 
-    ctx = SimpleNamespace(trace=got, engine=SimpleNamespace(
-        model_cfg=SimpleNamespace(n_layers=2)))
+    ctx = SimpleNamespace(trace=dict(
+        got, counters={"engine.decode_steps": steps}))
     assert decode_step_ms.read(ctx) == pytest.approx(1e3 * seconds / steps)
+    ctx.trace["counters"] = {}              # a program that counts no steps
+    assert decode_step_ms.read(ctx) is None
 
 
 class _Ev:
@@ -520,15 +535,22 @@ class _Named:
         self.__dict__.update(kw)
 
 
-def test_decode_steps_are_counted_whatever_the_scan_length():
-    """Scans of 16 and of 4 steps and a stepwise program, all the same
-    function to the trace: 21 steps, not 2 x 16 + 1."""
-    from benchmarks.trace import costs, reduce
+def test_decode_steps_come_from_the_engine_and_gaps_name_its_phases():
+    """Scans of 16 and of 4 steps and a stepwise program are the same
+    function to the trace; the steps are what the engine counted while
+    traced (21, not 2 x 16 + 1), whether or not a layer calls the
+    paged-attention kernel.  The idle gap before each program lies inside
+    ``bench.pump`` and ``engine.tick`` and is charged to the innermost
+    phase that covers it."""
+    from types import SimpleNamespace
 
-    layers, ops, modules, calls = 2, [], [], []
+    from benchmarks.layer_metrics import decode_step_ms
+    from benchmarks.trace import reduce
+
+    layers, ops, modules, host = 2, [], [], []
     t = 1_000
 
-    def program(name, fingerprint, steps, scan):
+    def program(name, fingerprint, steps, scan, phase):
         nonlocal t
         start = t
         if scan:
@@ -536,8 +558,8 @@ def test_decode_steps_are_counted_whatever_the_scan_length():
         body = t
         for _ in range(steps):
             for layer in range(layers):
-                ops.append(_Ev(f"%paged_attention_quant.{layer} = bf16[32,32,"
-                               f"128] custom-call(...)", t, 50))
+                ops.append(_Ev(f"%some_attention.{layer} = bf16[32,32,128] "
+                               f"custom-call(...)", t, 50))
                 ops.append(_Ev(f"%fusion.{layer} = bf16[32,4096] fusion(...)",
                                t + 50, 30))
                 t += 100
@@ -545,23 +567,33 @@ def test_decode_steps_are_counted_whatever_the_scan_length():
             ops.append(_Ev("%while.1 = (...) while(...)", body - 5,
                            t - body + 10))
             t += 10
-        modules.append(_Ev(f"jit__unknown({fingerprint})", start, t - start))
-        calls.append(_Ev(f"PjitFunction({name})", start - 500, 100))
-        t += 1_000
+        modules.append(_Ev(f"jit_{name}({fingerprint})", start, t - start))
+        host.append(_Ev(f"PjitFunction({name})", start - 500, 100))
+        # the 100 us in which the device waits for the next program
+        host.append(_Ev("bench.pump", t - 1_000, 102_000))
+        host.append(_Ev("engine.tick", t, 100_000))
+        host.append(_Ev(phase, t + 10_000, 90_000))
+        t += 100_000
 
-    program("paged_decode_scan", 16, 16, True)
-    program("paged_decode_scan", 4, 4, True)
-    program("paged_decode_step", 1, 1, False)
+    program("paged_decode_scan", 16, 16, True, "engine.fetch")
+    program("paged_decode_scan", 4, 4, True, "engine.commit")
+    program("paged_decode_step", 1, 1, False, "engine.grammar_mask")
+    ops.append(_Ev("%fusion.9 = bf16[32,4096] fusion(...)", t, 30))
     data = _Named("trace", planes=[
         _Named("/device:TPU:0", lines=[
             _Named("XLA Ops", events=ops),
             _Named("XLA Modules", events=modules)]),
-        _Named("/host:CPU", lines=[_Named("python", events=calls)])])
+        _Named("/host:CPU", lines=[_Named("python", events=host)])])
     got = reduce.reduce(data)
     assert got["programs"]["paged_decode_scan"]["count"] == 2
     assert got["programs"]["paged_decode_step"]["count"] == 1
-    seconds, steps = costs.decode_program_time(
-        got["programs"], got["op_counts"], n_layers=layers)
-    assert steps == 21
-    assert seconds == pytest.approx(
-        1e-9 * sum(m.duration_ns for m in modules))
+    got["counters"] = {"engine.decode_steps": 21.0}
+    assert decode_step_ms.read(SimpleNamespace(trace=got)) == pytest.approx(
+        1e3 * 1e-9 * sum(m.duration_ns for m in modules) / 21)
+    gaps = dict(got["idle_gaps"])
+    assert {"engine.fetch", "engine.commit", "engine.grammar_mask"} <= set(
+        gaps)
+    assert "bench.pump" not in gaps and "engine.tick" not in gaps
+    assert reduce.HOST_SPANS.index("engine.fetch") < \
+        reduce.HOST_SPANS.index("engine.tick") < \
+        reduce.HOST_SPANS.index("bench.pump")

@@ -55,27 +55,14 @@ def kernel_time(op_seconds: Dict[str, float], pattern,
                if pattern.search(op_text[name] if op_text else name))
 
 
-def kernel_count(op_counts: Dict[str, float], pattern) -> float:
-    """How often the operations whose name matches ``pattern`` ran."""
-    return sum(c for name, c in op_counts.items() if pattern.search(name))
-
-
-def decode_program_time(programs: Dict[str, Dict[str, float]],
-                        op_counts: Dict[str, float], n_layers: int
-                        ) -> Tuple[float, float]:
-    """(seconds, decode steps) of the decode programs in the trace.
-
-    A scan program runs 1, 2, 4, 8 or ``decode_chunk`` steps (its length is
-    a static argument, bound by each slot's allocated pages) and every
-    length carries the same module name, so the steps are not counted from
-    the programs: the paged-attention decode kernel runs once per layer in
-    every decode step and nowhere else (prefill attends by
-    ``flash_attention`` or in XLA), so its calls over the layers are the
-    steps."""
-    seconds = sum(p["seconds"] for name, p in programs.items()
-                  if name in DECODE_SCANS or name in DECODE_STEPS)
-    steps = kernel_count(op_counts, PAGED_ATTENTION) / n_layers
-    return seconds, steps
+def decode_program_time(programs: Dict[str, Dict[str, float]]) -> float:
+    """Seconds of the decode programs in the trace.  A scan program runs 1,
+    2, 4, 8 or ``decode_chunk`` steps (its length is a static argument,
+    bound by each slot's allocated pages) and every length carries the same
+    module name, so the steps they ran are not counted from the programs:
+    the engine counts them where it dispatches (``engine.decode_steps``)."""
+    return sum(p["seconds"] for name, p in programs.items()
+               if name in DECODE_SCANS or name in DECODE_STEPS)
 
 
 def prefill_program_time(programs: Dict[str, Dict[str, float]]) -> float:

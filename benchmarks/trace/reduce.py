@@ -36,10 +36,13 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 HOST_PLANE = "/host:CPU"
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 CALL = re.compile(r"^PjitFunction\((.*)\)$")
-# host spans a gap can be charged to, innermost first
-HOST_SPANS = ("np.asarray(jax.Array)", "engine.decode_step", "engine.prefill",
-              "engine.tick.admission", "engine.tick.eviction", "bench.submit",
-              "generator.wait", "bench.pump")
+# host spans a gap can be charged to, innermost first: the blocking fetch
+# inside the engine's fetch phase, the phases of a tick inside the tick, the
+# tick inside the benchmark's pump
+HOST_SPANS = ("np.asarray(jax.Array)", "engine.fetch", "engine.grammar_mask",
+              "engine.commit", "engine.decode_step", "engine.prefill",
+              "engine.tick.admission", "engine.tick.eviction", "engine.tick",
+              "bench.submit", "generator.wait", "bench.pump")
 OTHER = "host.other"
 GAP_FLOOR_NS = 20_000        # shorter gaps are launch latency, not waiting
 
