@@ -63,7 +63,7 @@ def main(argv=None) -> int:
     # what warms the engine
     engine, _ = build.build_engine(conf, cell["config"], args.seed)
     correct.check(engine, conf, seed=args.seed, **traffic["check"])
-    warmup.warm(engine, traffic)
+    warmup.warm(engine, traffic, build.check_driver(conf))
     generator = importlib.import_module(
         "benchmarks.generators." + traffic["generator"])
     slots = engine.engine_cfg.max_batch

@@ -26,6 +26,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 BENCH_DIR = os.path.join(ROOT, "benchmarks")
 ARCH_DIR = os.path.join(BENCH_DIR, "architectures")
+CHECKS_DIR = os.path.join(BENCH_DIR, "checks")
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
@@ -114,7 +115,12 @@ def architecture(conf: Dict[str, Any]) -> Dict[str, Any]:
       states;
     - ``ignored``: its keys that say nothing about shape;
     - ``init_params``: ``module:function`` of the program that makes the
-      weights, ``f(model_cfg, key, tensor_transform=None)``.
+      weights, ``f(model_cfg, key, tensor_transform=None)``;
+    - ``check``: the module of ``benchmarks/checks/`` through which the
+      logits check and the warm-up reach the model's cache; a missing or
+      unknown one is refused here, before anything is built;
+    - ``near_ties`` (optional): the sentence that allows the logits check a
+      third of its positions over the tolerance (``lib/correct.py``).
     """
     known = sorted(f[:-len(".json")] for f in os.listdir(ARCH_DIR)
                    if f.endswith(".json"))
@@ -124,7 +130,24 @@ def architecture(conf: Dict[str, Any]) -> Dict[str, Any]:
             f"no architecture for model_type {model_type!r} (known: "
             f"{', '.join(known)}); a new one adds benchmarks/architectures/"
             f"<model_type>.json beside the program code that computes it")
-    return load_json(os.path.join(ARCH_DIR, model_type + ".json"))
+    arch = load_json(os.path.join(ARCH_DIR, model_type + ".json"))
+    drivers = sorted(f[:-len(".py")] for f in os.listdir(CHECKS_DIR)
+                     if f.endswith(".py") and not f.startswith("_"))
+    if arch.get("check") not in drivers:
+        raise ValueError(
+            f"architecture {model_type!r}: "
+            + (f"no check driver {arch['check']!r}" if "check" in arch
+               else "the key 'check' is missing")
+            + f" (known: {', '.join(drivers)}); a model with another kind "
+            f"of cache adds benchmarks/checks/<name>.py")
+    return arch
+
+
+def check_driver(conf: Dict[str, Any]):
+    """The module of ``benchmarks/checks/`` that the file of the
+    configuration's architecture names."""
+    return importlib.import_module(
+        "benchmarks.checks." + architecture(conf)["check"])
 
 
 def model_config(conf: Dict[str, Any], name: str):

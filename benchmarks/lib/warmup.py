@@ -35,9 +35,10 @@ requests share prefixes lists what only its author knows:
   ends ``steps`` short of its bucket first decodes by a scan of ``steps``
   (by the stepwise program where ``steps`` is 1) and by ``decode_chunk`` once
   its pages have grown: one unshared request per listed length.
-- ``decode_step``: the stepwise program, called directly as the check calls
-  it, for a mix whose own ramp runs interpreted grammars and so warms what
-  surrounds it.
+- ``decode_step``: the stepwise program, run once on an idle batch by the
+  architecture's check driver (``benchmarks/checks/``, ``decode_once``), for
+  a mix whose own ramp runs interpreted grammars and so warms what surrounds
+  it.
 - ``dfa_schemas: ["module:function", ...]``: one request under each schema's
   compiled DFA, alone in the batch, which rides the DFA scan.
 """
@@ -84,16 +85,16 @@ def shapes(engine, traffic: Dict[str, Any]) -> Dict[str, Any]:
     return spec
 
 
-def warm(engine, traffic: Dict[str, Any], seed: int = 0) -> int:
+def warm(engine, traffic: Dict[str, Any], driver, seed: int = 0) -> int:
+    """``driver``: the architecture's check driver
+    (``lib/build.py::check_driver``)."""
     import jax
-    import jax.numpy as jnp
 
     from k8s_llm_rca_tpu.engine.constrain import make_grammar
-    from k8s_llm_rca_tpu.engine.paged import TRASH_PAGE
 
     spec = shapes(engine, traffic)
     cfg, ecfg = engine.model_cfg, engine.engine_cfg
-    page, b, pps = ecfg.page_size, ecfg.max_batch, engine.pages_per_seq
+    page = ecfg.page_size
     rng = np.random.default_rng(seed)
 
     def tokens(n: int) -> List[int]:
@@ -132,11 +133,7 @@ def warm(engine, traffic: Dict[str, Any], seed: int = 0) -> int:
                max_new_tokens=int(steps) + ecfg.decode_chunk + 1)
         n += 1
     if spec.get("decode_step"):
-        engine.pool, _ = engine._decode(
-            cfg, engine.params, engine.pool, jnp.ones((b,), jnp.int32),
-            jnp.ones((b,), jnp.int32),
-            jnp.full((b, pps), TRASH_PAGE, jnp.int32),
-            use_kernel=engine.use_kernel)
+        driver.decode_once(engine)
         n += 1
     for path in spec.get("dfa_schemas", []):
         module, function = path.split(":")

@@ -13,7 +13,9 @@ The weights are the ones the engine serves, read from their storage form
 ``scale``) into float32: quantization is part of the configuration, so both
 sides see the same numbers.  Departure from the published model: none in the
 mathematics; the KV cache's int8 rounding exists only on the engine's side
-and is part of what the tolerance in ``lib/correct.py`` covers.
+and is part of what the tolerances in ``lib/correct.py`` cover.  ``forward``
+also hands out each layer's keys and values as a cache would hold them, for
+the check to hold the engine's cache against.
 
 One jitted layer is called once per layer (the layers share their shapes), and
 the experts run one at a time under ``lax.map``, so that a layer's float32
@@ -26,6 +28,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 F32 = jnp.float32
 
@@ -65,13 +68,15 @@ def _swiglu(h, gate, up, down):
 @partial(jax.jit, static_argnames=("n_heads", "n_kv", "head_dim", "eps",
                                    "theta", "top_k"))
 def layer(x, p, *, n_heads, n_kv, head_dim, eps, theta, top_k):
-    """One block over x [S, H] (float32)."""
+    """One block over x [S, H] (float32): the block's output, and the keys
+    (rotated) and values [S, n_kv * head_dim] that a cache would hold."""
     with jax.default_matmul_precision("highest"):
         s = x.shape[0]
         h = _rms_norm(x, p["attn_norm"], eps)
         q = _rope((h @ weight(p["wq"])).reshape(s, n_heads, head_dim), theta)
         k = _rope((h @ weight(p["wk"])).reshape(s, n_kv, head_dim), theta)
         v = (h @ weight(p["wv"])).reshape(s, n_kv, head_dim)
+        held = k.reshape(s, -1), v.reshape(s, -1)
         group = n_heads // n_kv
         k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
         scores = jnp.einsum("qhd,khd->hqk", q, k) / jnp.sqrt(F32(head_dim))
@@ -82,8 +87,8 @@ def layer(x, p, *, n_heads, n_kv, head_dim, eps, theta, top_k):
 
         h = _rms_norm(x, p["mlp_norm"], eps)
         if "router" not in p:
-            return x + _swiglu(h, weight(p["w_gate"]), weight(p["w_up"]),
-                               weight(p["w_down"]))
+            return (x + _swiglu(h, weight(p["w_gate"]), weight(p["w_up"]),
+                                weight(p["w_down"])), *held)
         logits = h @ weight(p["router"])                       # [S, E]
         top_v, top_i = jax.lax.top_k(logits, top_k)
         share = jnp.zeros_like(logits).at[
@@ -98,7 +103,7 @@ def layer(x, p, *, n_heads, n_kv, head_dim, eps, theta, top_k):
                            weight(pick(p["w_down"])))
 
         outs = jax.lax.map(one_expert, jnp.arange(n_experts))  # [E, S, H]
-        return x + jnp.einsum("esh,se->sh", outs, share)
+        return (x + jnp.einsum("esh,se->sh", outs, share), *held)
 
 
 @partial(jax.jit, static_argnames=("eps",))
@@ -115,18 +120,29 @@ def embed(table, tokens):
     return weight(rows)
 
 
-def logits(conf, params, tokens, positions) -> jnp.ndarray:
-    """Float32 logits [len(positions), V] of one sequence ``tokens`` [S] at
-    the given positions, from the configuration file's published keys."""
+def forward(conf, params, tokens, positions):
+    """One sequence ``tokens`` [S], from the configuration file's published
+    keys: float32 logits [len(positions), V] at the given positions, and
+    what a cache of keys and values holds of the sequence, ``{"k", "v"}``,
+    each float32 [layers, S, n_kv * head_dim] (keys rotated)."""
     x = embed(params["embedding"], jnp.asarray(tokens, jnp.int32))
     kw = dict(n_heads=conf["num_attention_heads"],
               n_kv=conf["num_key_value_heads"], head_dim=conf["head_dim"],
               eps=float(conf["rms_norm_eps"]),
               theta=float(conf["rope_theta"]),
               top_k=conf.get("num_experts_per_tok", 0))
+    keys, values = [], []
     for p in params["layers"]:
-        x = layer(x, p, **kw)
+        x, k, v = layer(x, p, **kw)
+        keys.append(np.asarray(k))
+        values.append(np.asarray(v))
     out_w = params["embedding"] if conf["tie_word_embeddings"] \
         else params["lm_head"]
-    return head(x[jnp.asarray(positions)], params["final_norm"], out_w,
-                eps=float(conf["rms_norm_eps"]))
+    out = head(x[jnp.asarray(positions)], params["final_norm"], out_w,
+               eps=float(conf["rms_norm_eps"]))
+    return out, {"k": np.stack(keys), "v": np.stack(values)}
+
+
+def logits(conf, params, tokens, positions) -> jnp.ndarray:
+    """The logits of ``forward`` alone."""
+    return forward(conf, params, tokens, positions)[0]

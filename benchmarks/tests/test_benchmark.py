@@ -31,6 +31,8 @@ HERE = os.path.join(BENCH, "tests")
 from benchmarks.lib import observe, stats  # noqa: E402
 
 CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+# the harness's own, last in the line: each number compared beside its limit
+COMPARED = "compared"
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
@@ -242,7 +244,9 @@ def test_every_traffic_file_names_a_generator_and_its_shapes():
         generator = importlib.import_module("benchmarks.generators."
                                             + traffic["generator"])
         assert generator.BACKEND in ("observed", "steered")
-        assert set(traffic["check"]) == {"rows", "bucket"}
+        # one prefill shape, or the lengths to check at (lib/correct.py)
+        assert set(traffic["check"]) in ({"rows", "bucket"},
+                                         {"prompt_tokens"})
         warm = traffic["warm"]
         # shapes are derived from the mix's own length range, or listed
         assert warm.get("derive") is True or warm.get("miss")
@@ -409,7 +413,12 @@ def test_new_files_run_a_new_cell_and_the_last_line_keeps_the_contract(
                      "--seconds", "3", "--trace", "0", "--allow-cpu"],
                     benchmark=throwaway_cell)
     *_, last = _lines(done)
-    assert set(last) == CONTRACT_KEYS
+    assert set(last) == CONTRACT_KEYS | {COMPARED}
+    assert list(last)[-1] == COMPARED
+    for name, c in last[COMPARED].items():
+        assert set(c) == {"value", "limit"} and c["value"] <= c["limit"]
+        assert f"compared {name} {c['value']} limit {c['limit']}" in \
+            done.stderr.strip().splitlines()[-len(last[COMPARED]):]
     assert set(last["device"]) == {"platform", "kind", "count",
                                    "memory_peak_bytes"}
     assert last["device"]["platform"] == "cpu"
@@ -425,15 +434,16 @@ def test_traced_line_on_a_cpu_holds_no_device_metric(throwaway_cell):
                      "--seconds", "3", "--trace", "1", "--allow-cpu"],
                     benchmark=throwaway_cell)
     report, last = _lines(done)[-2:]
-    assert set(last) == CONTRACT_KEYS
+    assert set(last) == CONTRACT_KEYS | {COMPARED}
     assert last["metrics"]["throwaway_ticks"]["value"] >= 1
     assert "tokens_per_tick" in last["metrics"]
     assert "device_idle_share" not in last["metrics"]
     assert "busy_s" not in last["device"]
     assert report["run"]["compiles_in_window"] == 0
     assert report["run"]["check"]["ok"]
-    # the check asked the built model, not the file, whether it has experts
+    # near-ties are allowed because the architecture's file says why
     assert report["run"]["check"]["positions_allowed_over"] == 18 // 3
+    assert report["run"]["check"]["driver"] == "paged_kv"
 
 
 SWEEP_READERS = ("report_s_p50", "incidents_inflight_mean",

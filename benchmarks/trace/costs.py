@@ -71,7 +71,13 @@ def prefill_program_time(programs: Dict[str, Dict[str, float]]) -> float:
 
 def kv_bytes_per_token(model_cfg, engine_cfg) -> float:
     """Bytes of cached keys and values one token holds across all layers,
-    scales included."""
+    scales included.  ASSUMES that every one of ``n_layers`` layers caches
+    keys and values of ``kv_dim`` for every token of the context.  A model
+    for which that is untrue (layers that keep a recurrent state and no
+    keys, a latent cache, a window, a selector that reads some blocks only)
+    would be counted too many bytes here and read over 100% of a roofline:
+    it brings a bytes-and-operations module of its own beside this one, and
+    a roofline reader that uses it (``benchmarks/README.md``)."""
     per_elem = {"int8": 1.0, "int4": 0.5}.get(
         engine_cfg.kv_cache_dtype, 2.0)
     scales = 4.0 if engine_cfg.kv_cache_dtype in ("int8", "int4") else 0.0
@@ -83,6 +89,8 @@ def paged_attention_bytes(model_cfg, engine_cfg,
                           ) -> float:
     """Bytes of keys and values the decode kernel has to read over the given
     ticks: every decode step of a tick reads the whole cached context of
-    every live sequence (``live_tokens``), in every layer."""
+    every live sequence (``live_tokens``), in every layer
+    (``kv_bytes_per_token``'s assumption, and that the kernel reads all live
+    tokens and selects none)."""
     per_token = kv_bytes_per_token(model_cfg, engine_cfg)
     return sum(t[5] * t[4] * per_token for t in ticks)
