@@ -344,6 +344,27 @@ def _write_pool_pages(cfg: ModelConfig, pool: PagePool, new_k, new_v,
                     pool.v.at[:, page_map].set(new_v), k_scale, v_scale)
 
 
+def _write_pool_rows(cfg: ModelConfig, pool: PagePool, li: int, page_ids,
+                     offsets, k_rows, v_rows) -> PagePool:
+    """Scatter one layer's new rows into the stacked pool where it lies:
+    ``k_rows``/``v_rows`` [..., kv_dim] land at ``[li, page_ids, offsets]``
+    (index arrays of the rows' leading shape), quantized per row first
+    when the pool is quantized, their scales beside them.  The layer is
+    a scatter index: no layer of the pool is sliced out or set back, so
+    with the pool donated XLA writes the rows and nothing else (shared
+    by the single- and multi-token decode steps)."""
+    k_scale, v_scale = pool.k_scale, pool.v_scale
+    if pool.quantized:
+        packed = _pool_packed(cfg, pool)
+        k_rows, ks = _quantize_kv(k_rows, packed)
+        v_rows, vs = _quantize_kv(v_rows, packed)
+        k_scale = k_scale.at[li, page_ids, offsets].set(ks)
+        v_scale = v_scale.at[li, page_ids, offsets].set(vs)
+    return PagePool(pool.k.at[li, page_ids, offsets].set(k_rows),
+                    pool.v.at[li, page_ids, offsets].set(v_rows),
+                    k_scale, v_scale)
+
+
 def paged_prefill(cfg: ModelConfig, params, pool: PagePool,
                   tokens: jnp.ndarray, length: jnp.ndarray,
                   page_map: jnp.ndarray, use_flash: bool = False,
@@ -634,11 +655,13 @@ def paged_decode_step(cfg: ModelConfig, params, pool: PagePool,
                          "(split-half packing vs head shard); the engine "
                          "gating should have routed this to XLA")
     if kernel_on and tp_mesh is not None:
-        attn_fn = functools.partial(paged_attention_sharded, mesh=tp_mesh)
+        attn_fn = functools.partial(
+            paged_attention_quant_sharded if pool.quantized
+            else paged_attention_sharded, mesh=tp_mesh)
+    elif kernel_on and pool.quantized:
+        attn_fn = functools.partial(paged_attention_quant, packed=packed)
     elif kernel_on:
         attn_fn = paged_attention
-    else:
-        attn_fn = paged_attention_xla
 
     attn_lengths = lengths + 1
     if kernel_on:
@@ -649,42 +672,28 @@ def paged_decode_step(cfg: ModelConfig, params, pool: PagePool,
         attn_lengths = jnp.where(block_tables[:, 0] == TRASH_PAGE, 0,
                                  attn_lengths)
 
-    k_scale, v_scale = pool.k_scale, pool.v_scale
     for li, layer in enumerate(params["layers"]):
         q, k, v = llama._decode_qkv(cfg, layer, x, angles,
                                     positions)              # [B,1,·,d]
-        # scatter this token's k/v: [B, n_kv*d] -> pool[li, page, off]
-        k_tok = k[:, 0].reshape(b, cfg.kv_dim)
-        v_tok = v[:, 0].reshape(b, cfg.kv_dim)
-        if pool.quantized:
-            k_tok, ks = _quantize_kv(k_tok, packed)
-            v_tok, vs = _quantize_kv(v_tok, packed)
-            k_scale = k_scale.at[li].set(
-                k_scale[li].at[page_ids, offsets].set(ks))
-            v_scale = v_scale.at[li].set(
-                v_scale[li].at[page_ids, offsets].set(vs))
-        kp = pool.k[li].at[page_ids, offsets].set(k_tok)
-        vp = pool.v[li].at[page_ids, offsets].set(v_tok)
-        pool = PagePool(pool.k.at[li].set(kp), pool.v.at[li].set(vp),
-                        k_scale, v_scale)
-        if pool.quantized and kernel_on and tp_mesh is not None:
-            attn = paged_attention_quant_sharded(
-                q[:, 0], kp, vp, k_scale[li], v_scale[li], attn_lengths,
-                block_tables, tp_mesh)
-        elif pool.quantized and kernel_on:
-            attn = paged_attention_quant(
-                q[:, 0], kp, vp, k_scale[li], v_scale[li], attn_lengths,
-                block_tables, packed=packed)
+        # this token's k/v: [B, n_kv*d] -> pool[li, page, off]
+        pool = _write_pool_rows(cfg, pool, li, page_ids, offsets,
+                                k[:, 0].reshape(b, cfg.kv_dim),
+                                v[:, 0].reshape(b, cfg.kv_dim))
+        if kernel_on:
+            # the kernel reads layer li of the whole pool, by reference
+            attn = attn_fn(q[:, 0], *(p for p in pool if p is not None),
+                           attn_lengths, block_tables, layer=li)
         elif pool.quantized:
-            k_all = _gather_dequant_pages(kp, k_scale[li], block_tables,
-                                          cfg.n_kv_heads, cfg.head_dim,
-                                          dtype, packed)
-            v_all = _gather_dequant_pages(vp, v_scale[li], block_tables,
-                                          cfg.n_kv_heads, cfg.head_dim,
-                                          dtype, packed)
+            k_all = _gather_dequant_pages(pool.k[li], pool.k_scale[li],
+                                          block_tables, cfg.n_kv_heads,
+                                          cfg.head_dim, dtype, packed)
+            v_all = _gather_dequant_pages(pool.v[li], pool.v_scale[li],
+                                          block_tables, cfg.n_kv_heads,
+                                          cfg.head_dim, dtype, packed)
             attn = decode_attention(q, k_all, v_all, attn_lengths)
         else:
-            attn = attn_fn(q[:, 0], kp, vp, attn_lengths, block_tables)
+            attn = paged_attention_xla(q[:, 0], pool.k[li], pool.v[li],
+                                       attn_lengths, block_tables)
         x = llama._decode_finish(cfg, layer, x,
                                  attn.reshape(b, 1, cfg.q_dim), ep_mesh)
 
@@ -720,30 +729,19 @@ def paged_decode_multi(cfg: ModelConfig, params, pool: PagePool,
     offsets = (lengths % page_size)[:, None] + jnp.arange(t)[None, :]
     pages2d = jnp.broadcast_to(page_ids, (b, t))                 # [B, T]
 
-    k_scale, v_scale = pool.k_scale, pool.v_scale
     for li, layer in enumerate(params["layers"]):
         q, k, v = llama._decode_qkv(cfg, layer, x, angles,
                                     positions)               # [B,T,·,d]
-        k_tok = k.reshape(b, t, cfg.kv_dim)
-        v_tok = v.reshape(b, t, cfg.kv_dim)
-        if pool.quantized:
-            k_tok, ks = _quantize_kv(k_tok, packed)
-            v_tok, vs = _quantize_kv(v_tok, packed)
-            k_scale = k_scale.at[li].set(
-                k_scale[li].at[pages2d, offsets].set(ks))
-            v_scale = v_scale.at[li].set(
-                v_scale[li].at[pages2d, offsets].set(vs))
-        kp = pool.k[li].at[pages2d, offsets].set(k_tok)
-        vp = pool.v[li].at[pages2d, offsets].set(v_tok)
-        pool = PagePool(pool.k.at[li].set(kp), pool.v.at[li].set(vp),
-                        k_scale, v_scale)
+        pool = _write_pool_rows(cfg, pool, li, pages2d, offsets,
+                                k.reshape(b, t, cfg.kv_dim),
+                                v.reshape(b, t, cfg.kv_dim))
         # gathered dense view [B, S_max, n_kv, d] for the multi-query mask
         k_all = _gather_dequant_pages(
-            kp, k_scale[li] if pool.quantized else None, block_tables,
-            cfg.n_kv_heads, cfg.head_dim, dtype, packed)
+            pool.k[li], pool.k_scale[li] if pool.quantized else None,
+            block_tables, cfg.n_kv_heads, cfg.head_dim, dtype, packed)
         v_all = _gather_dequant_pages(
-            vp, v_scale[li] if pool.quantized else None, block_tables,
-            cfg.n_kv_heads, cfg.head_dim, dtype, packed)
+            pool.v[li], pool.v_scale[li] if pool.quantized else None,
+            block_tables, cfg.n_kv_heads, cfg.head_dim, dtype, packed)
         attn = decode_attention_multi(q, k_all, v_all, lengths + 1)
         x = llama._decode_finish(cfg, layer, x,
                                  attn.reshape(b, t, cfg.q_dim), ep_mesh)

@@ -7,8 +7,8 @@ in HBM (``pl.ANY``), the grid has one step a slot, and inside it a loop
 walks the slot's block table in BLOCKS of P consecutive entries, for
 ``ceil(ceil(length / page_size) / P)`` blocks only.  Each iteration
 starts the P page copies of the next block (``pltpu.make_async_copy``
-from ``pool.at[table[b, i]]`` into the other half of a VMEM double
-buffer, one DMA semaphore a half) and attends the block that has
+from ``pool.at[layer, table[b, i]]`` into the other half of a VMEM
+double buffer, one DMA semaphore a half) and attends the block that has
 arrived.  A slot of length 0 copies nothing and multiplies nothing; no
 table entry past the last live block is read.  This is the structure of
 ``jax.experimental.pallas.ops.tpu.paged_attention`` (pages per compute
@@ -32,8 +32,16 @@ next slot's first block early, this one does not), and every grid step,
 live or not, brings its q block and writes its output block.
 
 Layouts:
-- ``k_pages``/``v_pages``: [n_pages, page_size, n_kv*d] — the kv-head and
-  head-dim axes are stored MERGED on the lane axis.  TPU tiles the last
+- ``k_pages``/``v_pages``: [L, n_pages, page_size, n_kv*d], every
+  layer's pages as the engine's ``PagePool`` keeps them, and ``layer``,
+  one more scalar-prefetch operand, says which to read: the pool is
+  handed over by reference, because a layer sliced out of it is a copy
+  of the layer (50 MB a call at 3,072 pages of a 7B model; PERF.md,
+  PR 28).  The layer is a traced value, so every layer of a model runs
+  ONE kernel.  Without ``layer`` the entry points take one layer's
+  pages [n_pages, page_size, n_kv*d] (a free reshape to L = 1).  The
+  kv-head and head-dim axes are stored MERGED on the lane axis.  TPU
+  tiles the last
   two axes to (sublane, 128-lane) tiles; a per-head [..., page, d=64]
   layout would pad d 64 -> 128 and double both pool HBM and page DMA
   traffic.  With the merged axis the lane dim is n_kv*d (a multiple of
@@ -45,14 +53,15 @@ Layouts:
 - ``lengths``: [B] valid kv tokens per sequence (including the current
   decode position); 0 for a slot that holds no sequence.
 - quantized pools: int8 pages (or split-half nibble-packed int4) and one
-  f32 scale a token, ``[n_pages, page_size]``.  A row of fewer than 128
-  lanes cannot be sliced out of an HBM array by a kernel's own copy
-  (Mosaic: "must be aligned to tiling (128)"), so the wrapper reshapes
-  the scale pool to rows of 128 lanes (``_lane_dense_scales``: 8 pages
-  of 16 tokens share a row; ``page_size`` has to divide 128 or be a
-  multiple of it), the kernel copies the row that holds each page of the
-  block, and a lane rotation puts the page's scales under its columns of
-  the block's scores (``_scale_row``).
+  f32 scale a token, ``[L, n_pages, page_size]``, 1/256 of the pages'
+  bytes.  A row of fewer than 128 lanes cannot be sliced out of an HBM
+  array by a kernel's own copy (Mosaic: "must be aligned to tiling
+  (128)"), so the wrapper slices the layer's scales out and reshapes
+  them to rows of 128 lanes (``_lane_dense_scales``: 8 pages of 16
+  tokens share a row; ``page_size`` has to divide 128 or be a multiple
+  of it), the kernel copies the row that holds each page of the block,
+  and a lane rotation puts the page's scales under its columns of the
+  block's scores (``_scale_row``).
 
 Because a page carries ALL kv heads side by side on lanes, the kernel
 processes every query head at once using a block-diagonal-q trick:
@@ -160,6 +169,7 @@ def _scale_row(buf_ref, slot, page_ids, page_size: int):
 
 
 def _paged_kernel(
+    layer_ref,          # SMEM [1]
     lengths_ref,        # SMEM [B]
     tables_ref,         # SMEM [B, pages_per_seq]
     q_ref,              # VMEM [1, n_heads, KV]  (block-diagonal expanded)
@@ -175,10 +185,11 @@ def _paged_kernel(
     from the pool (HBM) into the other half of a double buffer while
     this block is attended.
 
-    ``refs``: the pools in HBM (k, v pages; with ``quant`` also the
-    lane-dense k, v scale rows), the output block, one VMEM double
-    buffer per pool, a DMA semaphore per buffer half, and the running
-    accumulator / max / denominator.
+    ``refs``: the pools in HBM (the k and v pages of EVERY layer,
+    [L, n_pages, page, KV'], of which ``layer_ref[0]`` is read; with
+    ``quant`` also that layer's lane-dense k, v scale rows), the output
+    block, one VMEM double buffer per pool, a DMA semaphore per buffer
+    half, and the running accumulator / max / denominator.
 
     Quantized pages are int8 (or split-half nibble-packed int4) with one
     scale per token.  The scales never touch the [T, KV] operands: the k
@@ -190,6 +201,7 @@ def _paged_kernel(
     sems, acc_ref, m_ref, l_ref = refs[2 * n_pools + 1:]
 
     bi = pl.program_id(0)
+    layer = layer_ref[0]
     length = lengths_ref[bi]
     pages_per_seq = tables_ref.shape[1]
     block_tokens = n_block * page_size
@@ -207,7 +219,7 @@ def _paged_kernel(
         for i, pid in enumerate(pids):
             for pool, buf in zip(pools[:2], bufs[:2]):
                 out.append(pltpu.make_async_copy(
-                    pool.at[pid], buf.at[slot, i], sems.at[slot]))
+                    pool.at[layer, pid], buf.at[slot, i], sems.at[slot]))
             for pool, buf in zip(pools[2:], bufs[2:]):
                 group = buf.shape[-1] // page_size
                 out.append(pltpu.make_async_copy(
@@ -306,39 +318,49 @@ def _lane_dense_scales(scales: jnp.ndarray) -> jnp.ndarray:
     return scales.reshape(-1, group * page_size)
 
 
-def _paged_call(name, q, pools, lengths, block_tables, *, packed, interpret):
-    """The one ``pallas_call`` both pools' kernels are: ``pools`` are the
-    k and v pages and, for a quantized pool, the two [n_pages, page] scale
-    pools."""
+def _paged_call(name, q, pools, layer, lengths, block_tables, *, packed,
+                interpret):
+    """The one ``pallas_call`` both pools' kernels are.  ``pools`` are the
+    k and v pages and, for a quantized pool, the two scale pools: with
+    ``layer`` None one layer's ([n_pages, page, KV'] and
+    [n_pages, page]), else every layer's, stacked on a leading axis as
+    the engine's pool keeps them, of which the kernel reads layer
+    ``layer`` (an int or a traced scalar) where it lies.  The pages are
+    handed over whole and by reference; of the scales, 1/256 of the
+    bytes, the layer is sliced out here for its lane-dense rows."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if layer is None:
+        pages, scales, layer = [p[None] for p in pools[:2]], pools[2:], 0
+    else:
+        pages, scales = pools[:2], [s[layer] for s in pools[2:]]
 
     b, n_heads, d = q.shape
-    _, page_size, kv_store = pools[0].shape
+    _, _, page_size, kv_store = pages[0].shape
     kv_dim = kv_store * 2 if packed else kv_store
     assert kv_dim % d == 0, (kv_dim, d)
     n_kv = kv_dim // d
     assert n_heads % n_kv == 0, (n_heads, n_kv)
     n_block = block_pages(page_size, block_tables.shape[1])
 
-    pools = list(pools[:2]) + [_lane_dense_scales(s) for s in pools[2:]]
+    scales = [_lane_dense_scales(s) for s in scales]
     buffers = [pltpu.VMEM((2, n_block, page_size, kv_store), p.dtype)
-               for p in pools[:2]]
-    buffers += [pltpu.VMEM((2, n_block, 1, p.shape[-1]), p.dtype)
-                for p in pools[2:]]
+               for p in pages]
+    buffers += [pltpu.VMEM((2, n_block, 1, s.shape[-1]), s.dtype)
+                for s in scales]
     slot_block = pl.BlockSpec((1, n_heads, kv_dim),
-                              lambda bi, lens, tabs: (bi, 0, 0))
+                              lambda bi, layer, lens, tabs: (bi, 0, 0))
 
     out = pl.pallas_call(
         functools.partial(_paged_kernel, page_size=page_size, head_dim=d,
-                          n_block=n_block, quant=len(pools) > 2,
+                          n_block=n_block, quant=bool(scales),
                           packed=packed),
         name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b,),
             in_specs=[slot_block] + [pl.BlockSpec(memory_space=pl.ANY)
-                                     for _ in pools],
+                                     for _ in (*pages, *scales)],
             out_specs=slot_block,
             scratch_shapes=buffers + [
                 pltpu.SemaphoreType.DMA((2,)),
@@ -353,9 +375,10 @@ def _paged_call(name, q, pools, lengths, block_tables, *, packed, interpret):
         ),
         interpret=interpret,
     )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
         lengths.astype(jnp.int32),
         block_tables.astype(jnp.int32),
-        _expand_block_diag(q, n_kv), *pools,
+        _expand_block_diag(q, n_kv), *pages, *scales,
     )
     return _extract_block_diag(out, n_kv, d)
 
@@ -363,36 +386,45 @@ def _paged_call(name, q, pools, lengths, block_tables, *, packed, interpret):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(
     q: jnp.ndarray,             # [B, n_heads, d]
-    k_pages: jnp.ndarray,       # [n_pages, page_size, n_kv*d]
-    v_pages: jnp.ndarray,       # [n_pages, page_size, n_kv*d]
+    k_pages: jnp.ndarray,       # [n_pages, page_size, n_kv*d], or [L, ...]
+    v_pages: jnp.ndarray,       # as k_pages
     lengths: jnp.ndarray,       # [B] int32
     block_tables: jnp.ndarray,  # [B, pages_per_seq] int32
     *,
+    layer=None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Single-step decode attention over a paged KV pool: [B, n_heads, d]."""
-    return _paged_call("paged_attention", q, (k_pages, v_pages), lengths,
-                       block_tables, packed=False, interpret=interpret)
+    """Single-step decode attention over a paged KV pool: [B, n_heads, d].
+    With ``layer`` the pools are every layer's pages
+    [L, n_pages, page_size, n_kv*d] and the kernel reads that layer of
+    them in place; without, one layer's."""
+    return _paged_call("paged_attention", q, (k_pages, v_pages), layer,
+                       lengths, block_tables, packed=False,
+                       interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("packed", "interpret"))
 def paged_attention_quant(
     q: jnp.ndarray,             # [B, n_heads, d]
-    k_pages: jnp.ndarray,       # [n_pages, page_size, KV'] int8
-    v_pages: jnp.ndarray,       # [n_pages, page_size, KV'] int8
-    k_scales: jnp.ndarray,      # [n_pages, page_size]
-    v_scales: jnp.ndarray,      # [n_pages, page_size]
+    k_pages: jnp.ndarray,       # [n_pages, page_size, KV'] int8, or [L, ...]
+    v_pages: jnp.ndarray,       # as k_pages
+    k_scales: jnp.ndarray,      # [n_pages, page_size], or [L, ...]
+    v_scales: jnp.ndarray,      # as k_scales
     lengths: jnp.ndarray,       # [B] int32
     block_tables: jnp.ndarray,  # [B, pages_per_seq] int32
     *,
+    layer=None,
     packed: bool = False,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Decode attention over a QUANTIZED paged pool (int8, or split-half
-    nibble-packed int4 when ``packed``): [B, n_heads, d]."""
+    nibble-packed int4 when ``packed``): [B, n_heads, d].  ``layer`` as
+    in ``paged_attention``: the four pools then carry a leading layer
+    axis."""
     return _paged_call("paged_attention_quant", q,
-                       (k_pages, v_pages, k_scales, v_scales), lengths,
-                       block_tables, packed=packed, interpret=interpret)
+                       (k_pages, v_pages, k_scales, v_scales), layer,
+                       lengths, block_tables, packed=packed,
+                       interpret=interpret)
 
 
 def _validate_head_shard(n_heads: int, n_kv: int, n_tp: int) -> None:
@@ -405,15 +437,16 @@ def _validate_head_shard(n_heads: int, n_kv: int, n_tp: int) -> None:
 
 def paged_attention_sharded(
     q: jnp.ndarray,             # [B, n_heads, d]
-    k_pages: jnp.ndarray,       # [n_pages, page_size, n_kv*d]
-    v_pages: jnp.ndarray,       # [n_pages, page_size, n_kv*d]
+    k_pages: jnp.ndarray,       # [n_pages, page_size, n_kv*d], or [L, ...]
+    v_pages: jnp.ndarray,       # as k_pages
     lengths: jnp.ndarray,       # [B] int32
     block_tables: jnp.ndarray,  # [B, pages_per_seq] int32
     mesh,
     head_axis: str = "model",
     **kw,
 ) -> jnp.ndarray:
-    """``paged_attention`` under tensor parallelism.
+    """``paged_attention`` under tensor parallelism (``layer=`` and the
+    stacked pools as there).
 
     ``pallas_call`` has no SPMD partitioning rule, so calling the kernel
     on a TP-sharded pool would silently replicate full attention on every
@@ -435,7 +468,8 @@ def paged_attention_sharded(
         return paged_attention(q, kp, vp, lens, bt, **kw)
 
     q_spec = jax.sharding.PartitionSpec(None, head_axis, None)
-    pool_spec = jax.sharding.PartitionSpec(None, None, head_axis)
+    pool_spec = jax.sharding.PartitionSpec(
+        *(None,) * (k_pages.ndim - 1), head_axis)
     vec = jax.sharding.PartitionSpec(None)
     bt_spec = jax.sharding.PartitionSpec(None, None)
     return jax.shard_map(
@@ -447,17 +481,18 @@ def paged_attention_sharded(
 
 def paged_attention_quant_sharded(
     q: jnp.ndarray,             # [B, n_heads, d]
-    k_pages: jnp.ndarray,       # [n_pages, page_size, n_kv*d] int8
-    v_pages: jnp.ndarray,       # [n_pages, page_size, n_kv*d] int8
-    k_scales: jnp.ndarray,      # [n_pages, page_size]
-    v_scales: jnp.ndarray,      # [n_pages, page_size]
+    k_pages: jnp.ndarray,       # [n_pages, page_size, n_kv*d] int8, or [L, ...]
+    v_pages: jnp.ndarray,       # as k_pages
+    k_scales: jnp.ndarray,      # [n_pages, page_size], or [L, ...]
+    v_scales: jnp.ndarray,      # as k_scales
     lengths: jnp.ndarray,       # [B] int32
     block_tables: jnp.ndarray,  # [B, pages_per_seq] int32
     mesh,
     head_axis: str = "model",
     **kw,
 ) -> jnp.ndarray:
-    """``paged_attention_quant`` under tensor parallelism (int8 pools).
+    """``paged_attention_quant`` under tensor parallelism (int8 pools;
+    ``layer=`` and the stacked pools as there).
 
     The per-token scale is a FULL-ROW scalar (one per written token,
     recovered by pmax over the TP group at write time), so the scale
@@ -484,13 +519,14 @@ def paged_attention_quant_sharded(
                                      packed=False, **kw)
 
     q_spec = jax.sharding.PartitionSpec(None, head_axis, None)
-    pool_spec = jax.sharding.PartitionSpec(None, None, head_axis)
-    scale_spec = jax.sharding.PartitionSpec(None, None)
+    pool_spec = jax.sharding.PartitionSpec(
+        *(None,) * (k_pages.ndim - 1), head_axis)
+    scale_spec = jax.sharding.PartitionSpec(*(None,) * k_scales.ndim)
     vec = jax.sharding.PartitionSpec(None)
     return jax.shard_map(
         local, mesh=mesh,
         in_specs=(q_spec, pool_spec, pool_spec, scale_spec, scale_spec,
-                  vec, scale_spec),
+                  vec, jax.sharding.PartitionSpec(None, None)),
         out_specs=q_spec, check_vma=False,
     )(q, k_pages, v_pages, k_scales, v_scales, lengths, block_tables)
 

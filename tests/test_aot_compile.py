@@ -7,7 +7,8 @@
    costs chip time.  Nothing runs, so nothing here says a result is right —
    tests/test_kernels.py and tests/test_quant_matmul.py do that in interpret
    mode, chip_smoke.py on the chip.  Whole engine steps (16-25 s each) stay
-   in a scratch rehearsal (.claude/skills/verify/SKILL.md).
+   in a scratch rehearsal (.claude/skills/verify/SKILL.md), but for one:
+   the decode step at 2 layers, for what it must not do to the pool.
 2. No fallback hides the device: an unknown TPU kind has no peaks, a missing
    TPU or a dead leg fails bench.py, an unknown ``--model`` is an argument
    error, and the compile cache is placed from outside.
@@ -100,6 +101,63 @@ class TestAttentionKernelsCompileForV5e:
         _compiles_with_kernel(
             functools.partial(flash_attention, interpret=False),
             chip((1, 512, H, D), BF16), kv, kv, chip((1,), I32))
+
+
+class TestDecodeStepWritesThePoolInPlace:
+    """The stepwise decode program at Mistral-7B widths (2 layers of the
+    32, int8 weights, the int8 pool of ``mistral7b.chat-open``: 3,072
+    pages of 16 tokens, 32 slots).  Until PR 28 every layer's pages were
+    sliced out of the pool, scattered into and set back: a 50 MB copy
+    each way, 64 times a step, 39% of that cell's device time.  What a
+    later edit must not bring back, seen here without a chip: outside the
+    kernel the compiled program holds no array of one layer's pages,
+    keeps the pool in the buffers it was donated in, and needs less room
+    for temporaries than one layer's pages (the parent: 46 such arrays,
+    70 MB of temporaries)."""
+
+    N_PAGES, PAGE, SLOTS, LAYERS = 3072, 16, 32, 2
+
+    def test_no_layer_of_the_pool_is_copied(self, chip, monkeypatch):
+        import re
+
+        from k8s_llm_rca_tpu.config import ModelConfig
+        from k8s_llm_rca_tpu.engine import paged
+        from k8s_llm_rca_tpu.models import llama
+        from k8s_llm_rca_tpu.models.quant import quantize_params
+
+        cfg = ModelConfig(vocab_size=32768, hidden_size=4096,
+                          intermediate_size=14336, n_layers=self.LAYERS,
+                          n_heads=32, n_kv_heads=8, head_dim=128,
+                          max_seq_len=4096, rope_theta=1e6,
+                          tie_embeddings=False)
+
+        def described(tree):
+            return jax.tree.map(lambda s: chip(s.shape, s.dtype), tree)
+
+        params = described(jax.eval_shape(lambda: quantize_params(
+            llama.init_params(cfg, jax.random.PRNGKey(0)), bits=8)))
+        pool = described(jax.eval_shape(lambda: paged.init_paged_cache(
+            cfg, self.N_PAGES, self.PAGE, "int8")))
+        # the kernel's interpret=None asks the backend, which is the CPU
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = jax.jit(
+            paged.paged_decode_step, static_argnums=0, donate_argnums=2,
+            static_argnames="use_kernel").lower(
+                cfg, params, pool, chip((self.SLOTS,), I32),
+                chip((self.SLOTS,), I32),
+                chip((self.SLOTS, cfg.max_seq_len // self.PAGE), I32),
+                use_kernel=True).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= self.LAYERS
+
+        # no array of one layer's pages, with or without its unit axis
+        assert not re.search(
+            rf"s8\[(1,)?{self.N_PAGES},{self.PAGE},{cfg.kv_dim}]", text)
+        # and no second pool: k and v live on in the buffers they came in
+        mem = compiled.memory_analysis()
+        layer_bytes = self.N_PAGES * self.PAGE * cfg.kv_dim
+        assert mem.alias_size_in_bytes >= 2 * self.LAYERS * layer_bytes
+        assert mem.temp_size_in_bytes < layer_bytes
 
 
 def _weight(chip, bits, shape, scale_shape):

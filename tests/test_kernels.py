@@ -5,6 +5,8 @@ the backend is not TPU), so kernel logic is covered without hardware —
 the CPU-fallback test path SURVEY §4 calls for.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -344,6 +346,47 @@ class TestPagedAttentionQuant:
             np.testing.assert_allclose(np.asarray(lg_kernel),
                                        np.asarray(lg_xla),
                                        rtol=2e-4, atol=2e-4)
+
+
+class TestPagedAttentionLayerIndexed:
+    """``layer=`` over the engine's stacked pool: the kernel reads layer
+    ``l`` of [L, n_pages, page, KV] where it lies, and gives what the
+    3-D call gives on that layer sliced out."""
+
+    @pytest.mark.parametrize("pages_per_seq", [32, 20],
+                             ids=["table-32", "table-20-no-block-multiple"])
+    @pytest.mark.parametrize("kind", ["bf16", "int8", "int4"])
+    def test_equals_the_call_on_the_sliced_layer(self, kind, pages_per_seq):
+        from k8s_llm_rca_tpu.models.llama import _quantize_kv
+        from k8s_llm_rca_tpu.ops.paged_attention import paged_attention_quant
+
+        n_layers, page, n_pages, n_heads, n_kv, d = 3, 16, 64, 4, 2, 64
+        blk = block_pages(page, pages_per_seq) * page
+        lengths = [blk + 7, 0, pages_per_seq * page, 17]
+        kk, kv, kq = jax.random.split(jax.random.PRNGKey(17), 3)
+        shape = (n_layers, n_pages, page, n_kv * d)
+        q = jax.random.normal(kq, (len(lengths), n_heads, d))
+        pools = (jax.random.normal(kk, shape), jax.random.normal(kv, shape))
+        if kind == "bf16":
+            pools = tuple(p.astype(jnp.bfloat16) for p in pools)
+            call = paged_attention
+        else:
+            (k8, ks), (v8, vs) = (_quantize_kv(p, kind == "int4")
+                                  for p in pools)
+            pools = (k8, v8, ks, vs)
+            call = functools.partial(paged_attention_quant,
+                                     packed=kind == "int4")
+        tables = _scattered_tables(lengths, page, pages_per_seq, n_pages)
+        lens = jnp.asarray(lengths, jnp.int32)
+        for l in range(n_layers):
+            stacked = call(q, *pools, lens, tables, layer=l)
+            sliced = call(q, *(p[l] for p in pools), lens, tables)
+            np.testing.assert_array_equal(np.asarray(stacked),
+                                          np.asarray(sliced))
+        # the layers differ, so a kernel that ignored the index would show
+        assert not np.array_equal(
+            np.asarray(call(q, *pools, lens, tables, layer=0)),
+            np.asarray(stacked))
 
 
 class TestFlashSharded:

@@ -113,6 +113,130 @@ class TestPagedModelPath:
         assert got == ref
 
 
+def _parent_decode_step(cfg, params, pool, tokens, lengths, block_tables):
+    """``paged_decode_step`` (gather path) as it was before the write went
+    in place, the reference for it: a layer of every pool sliced out, the
+    token's rows scattered into the slice, the slice set back."""
+    from k8s_llm_rca_tpu.engine.paged import (
+        PagePool, _gather_dequant_pages, _pool_packed,
+    )
+    from k8s_llm_rca_tpu.models.quant import gather_rows
+    from k8s_llm_rca_tpu.ops.attention import decode_attention
+    from k8s_llm_rca_tpu.ops.paged_attention import paged_attention_xla
+    from k8s_llm_rca_tpu.ops.rope import rope_frequencies
+
+    b, page = tokens.shape[0], pool.page_size
+    dtype, packed = jnp.dtype(cfg.dtype), _pool_packed(cfg, pool)
+    angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
+    x = gather_rows(params["embedding"], tokens[:, None]).astype(dtype)
+    pids = jnp.take_along_axis(block_tables, (lengths // page)[:, None],
+                               axis=1)[:, 0]
+    offs = lengths % page
+    fields = [f for f in pool if f is not None]
+    for li, layer in enumerate(params["layers"]):
+        q, k, v = llama._decode_qkv(cfg, layer, x, angles, lengths[:, None])
+        rows = [k[:, 0].reshape(b, -1), v[:, 0].reshape(b, -1)]
+        if pool.quantized:
+            (rows[0], ks), (rows[1], vs) = (
+                llama._quantize_kv(r, packed) for r in rows)
+            rows += [ks, vs]
+        one = [f[li].at[pids, offs].set(r) for f, r in zip(fields, rows)]
+        fields = [f.at[li].set(o) for f, o in zip(fields, one)]
+        if pool.quantized:
+            k_all, v_all = (_gather_dequant_pages(
+                one[i], one[i + 2], block_tables, cfg.n_kv_heads,
+                cfg.head_dim, dtype, packed) for i in (0, 1))
+            attn = decode_attention(q, k_all, v_all, lengths + 1)
+        else:
+            attn = paged_attention_xla(q[:, 0], *one, lengths + 1,
+                                       block_tables)
+        x = llama._decode_finish(cfg, layer, x,
+                                 attn.reshape(b, 1, cfg.q_dim), None)
+    return PagePool(*fields), llama._logits(cfg, params, x)[:, 0]
+
+
+class TestDecodeWritesInPlace:
+    """The decode step scatters its rows into the stacked pool with the
+    layer as an index.  Against the sliced-layer formulation: the same
+    logits and the same pool to the bit, and no byte of the pool changed
+    outside the rows (layer, page_ids[b], offsets[b])."""
+
+    PAGE, STEPS = 8, 4
+
+    def _state(self, cfg, kv_dtype):
+        """A pool of arbitrary bytes under four slots: one mid-page, one
+        that holds no sequence (table on the trash page, a stale length),
+        one whose next token opens a page, one idle at length 0."""
+        pool = init_paged_cache(cfg, 32, self.PAGE, kv_dtype=kv_dtype)
+        keys = iter(jax.random.split(jax.random.PRNGKey(28), 4))
+
+        def arbitrary(f):
+            if f.dtype == jnp.int8:
+                return jax.random.randint(next(keys), f.shape, -127, 128,
+                                          jnp.int32).astype(jnp.int8)
+            return jax.random.uniform(next(keys), f.shape, jnp.float32,
+                                      0.01, 0.05).astype(f.dtype)
+
+        pool = type(pool)(*(None if f is None else arbitrary(f)
+                            for f in pool))
+        tables = np.full((4, 8), TRASH_PAGE, np.int32)
+        tables[0, :3] = [7, 3, 11]
+        tables[2, :4] = [5, 30, 9, 2]
+        lengths = np.asarray([13, 57, 16, 0], np.int32)
+        tokens = jnp.asarray([21, 4, 9, 0], jnp.int32)
+        return pool, tokens, lengths, tables
+
+    def _assert_only_rows_changed(self, before, after, lengths, tables,
+                                  steps):
+        written = np.zeros(before.k.shape[:3], bool)
+        for s in range(steps):
+            pos = lengths + s
+            written[:, tables[np.arange(4), pos // self.PAGE],
+                    pos % self.PAGE] = True
+        assert written.sum() < written.size // 4
+        for b, a in zip(before, after):
+            if b is None:
+                continue
+            b, a = np.asarray(b), np.asarray(a)
+            keep = ~written.reshape(written.shape + (1,) * (b.ndim - 3))
+            assert np.array_equal(np.where(keep, a, 0), np.where(keep, b, 0))
+            assert not np.array_equal(a, b)
+
+    @pytest.mark.parametrize("program", ["step", "scan4"])
+    @pytest.mark.parametrize("kv_dtype", [None, jnp.int8, "int4"],
+                             ids=["bf16", "int8", "int4"])
+    def test_bit_equal_to_the_sliced_layer_formulation(self, kv_dtype,
+                                                       program):
+        from k8s_llm_rca_tpu.engine.paged import paged_decode_scan
+        from k8s_llm_rca_tpu.engine.sampling import SamplingParams
+
+        cfg = TINY.replace(max_seq_len=64)
+        params = llama.init_params(cfg, jax.random.PRNGKey(0))
+        pool, tokens, lengths, tables = self._state(cfg, kv_dtype)
+        args = (tokens, jnp.asarray(lengths), jnp.asarray(tables))
+        if program == "step":
+            steps = 1
+            got = jax.jit(paged_decode_step, static_argnums=0,
+                          static_argnames="use_kernel")(
+                cfg, params, pool, *args, use_kernel=False)
+            ref = jax.jit(_parent_decode_step, static_argnums=0)(
+                cfg, params, pool, *args)
+        else:
+            steps = self.STEPS
+            scan = jax.jit(paged_decode_scan, static_argnums=(0, 7, 8, 9),
+                           static_argnames=("use_kernel", "decode_fn"))
+            # eos -1: every slot advances at every step
+            tail = (jax.random.PRNGKey(1), steps, SamplingParams(), -1)
+            got = scan(cfg, params, pool, *args, *tail, use_kernel=False)
+            ref = scan(cfg, params, pool, *args, *tail,
+                       decode_fn=_parent_decode_step)
+            assert np.array_equal(np.asarray(got[2]), lengths + steps)
+        for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+            assert g.dtype == r.dtype
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+        self._assert_only_rows_changed(pool, got[0], lengths, tables, steps)
+
+
 class TestPagedEngine:
     def _engine(self, **kw):
         cfg = TINY.replace(max_seq_len=64)
