@@ -96,7 +96,7 @@ def bench_engine_model(model_key: str, max_batch: int, max_seq_len: int,
         tensor_transform=quantizing_transform(bits=4))
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
     ecfg = EngineConfig(max_batch=max_batch, max_seq_len=max_seq_len,
-                        paged=True, page_size=page_size,
+                        page_size=page_size,
                         num_pages=num_pages,
                         prefill_buckets=(prompt_len,),
                         max_new_tokens=max_new, temperature=0.0,
@@ -670,7 +670,7 @@ def bench_rca_resume(n_runs: int = 8, n_appends: int = 256):
         params = llama.init_params(cfg, _jax.random.PRNGKey(0))
         tok = get_tokenizer(vocab_size=cfg.vocab_size)
         engine = make_engine(
-            cfg, EngineConfig(max_batch=4, max_seq_len=512, paged=True,
+            cfg, EngineConfig(max_batch=4, max_seq_len=512,
                               page_size=16, num_pages=128,
                               prefill_buckets=(128, 256),
                               max_new_tokens=16, temperature=0.0,
@@ -739,7 +739,7 @@ def bench_cluster(n_runs: int = 12, max_new: int = 32):
     n_replicas = 2 if len(devices) >= 2 else 1
     use = devices[:(len(devices) // n_replicas) * n_replicas]
     cfg = TINY.replace(max_seq_len=512)
-    ecfg = EngineConfig(max_batch=4, max_seq_len=512, paged=True,
+    ecfg = EngineConfig(max_batch=4, max_seq_len=512,
                         page_size=16, num_pages=160,
                         prefill_buckets=(64,), max_new_tokens=max_new,
                         temperature=0.0, decode_chunk=4,
@@ -833,7 +833,7 @@ def bench_overload(n_runs: int = 30, max_new: int = 24,
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
     engine = make_engine(
-        cfg, EngineConfig(max_batch=4, max_seq_len=256, paged=True,
+        cfg, EngineConfig(max_batch=4, max_seq_len=256,
                           page_size=16, num_pages=96,
                           prefill_buckets=(64,), max_new_tokens=max_new,
                           temperature=0.0, decode_chunk=4,
@@ -916,7 +916,7 @@ def bench_selfheal(n_runs: int = 8, max_new: int = 24):
     n_replicas = 2 if len(devices) >= 2 else 1
     use = devices[:(len(devices) // n_replicas) * n_replicas]
     cfg = TINY.replace(max_seq_len=512)
-    ecfg = EngineConfig(max_batch=4, max_seq_len=512, paged=True,
+    ecfg = EngineConfig(max_batch=4, max_seq_len=512,
                         page_size=16, num_pages=160,
                         prefill_buckets=(64,), max_new_tokens=max_new,
                         temperature=0.0, decode_chunk=4,
@@ -1363,7 +1363,7 @@ def bench_host_overlap(n_prompts: int = 48, max_batch: int = 8,
 
     def run(overlap: bool):
         ecfg = EngineConfig(max_batch=max_batch, max_seq_len=256,
-                            paged=True, page_size=16, num_pages=160,
+                            page_size=16, num_pages=160,
                             prefill_buckets=(prompt_len,),
                             max_new_tokens=max_new, temperature=0.0,
                             decode_chunk=1, prefix_cache=False,
@@ -1446,7 +1446,7 @@ def bench_prefix_leg(n_incidents: int = 100, max_new: int = 8):
 
     wave = [tok.encode(prompt(i)) for i in range(n_incidents)]
     store = PrefixStore(host_pages=4096)
-    ecfg = EngineConfig(max_batch=4, max_seq_len=256, paged=True,
+    ecfg = EngineConfig(max_batch=4, max_seq_len=256,
                         page_size=16, num_pages=160,
                         prefill_buckets=(192, 256), max_new_tokens=max_new,
                         temperature=0.0, decode_chunk=4,
@@ -1561,7 +1561,7 @@ def bench_store_leg(n_incidents: int = 40, n_gets: int = 40,
     def ecfg(**over):
         base = dict(max_batch=2, max_seq_len=128,
                     prefill_buckets=(64, 128), max_new_tokens=max_new,
-                    temperature=0.0, paged=True, page_size=16,
+                    temperature=0.0, page_size=16,
                     num_pages=40, prefix_cache=True, decode_chunk=4,
                     # chunked prefill: warm-start savings surface as
                     # fewer engine.tick.prefill_chunk dispatches, not
@@ -1685,7 +1685,7 @@ from k8s_llm_rca_tpu.utils import get_tokenizer
 
 cfg = TINY.replace(max_seq_len=256)
 ecfg = EngineConfig(max_batch=2, max_seq_len=256, prefill_buckets=(32,),
-                    max_new_tokens=160, temperature=0.0, paged=True,
+                    max_new_tokens=160, temperature=0.0,
                     page_size=16, num_pages=64, prefix_cache=False,
                     decode_chunk=8)
 params = llama.init_params(cfg, jax.random.PRNGKey(0))

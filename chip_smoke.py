@@ -37,7 +37,7 @@ MODEL = "llama3-8b"
 # the nearest set the sweep CLI offers to the flagship serving config: it has
 # no page-size / pool-size / bucket flags, so those are EngineConfig's
 # defaults (page 16, 1024 pages, buckets 64..1024 then the full 4096)
-ENGINE_ARGV = ["--backend", "engine", "--model", MODEL, "--paged", "--int4",
+ENGINE_ARGV = ["--backend", "engine", "--model", MODEL, "--int4",
                "--kv-dtype", "int4", "--fresh-threads", "--concurrency", "2",
                "--max-seq-len", "4096", "--max-batch", "16"]
 SEED = 0
@@ -636,7 +636,7 @@ def main(argv=None) -> int:
         return run_phase(meter, device, phase, fn, *a)
 
     if args.chips == 4:
-        ecfg = EngineConfig(max_batch=16, max_seq_len=4096, paged=True,
+        ecfg = EngineConfig(max_batch=16, max_seq_len=4096,
                             kv_cache_dtype="int8")
         run("tp4", phase_tp,
             cfg.replace(max_seq_len=4096, dtype="float32"), ecfg, 4)

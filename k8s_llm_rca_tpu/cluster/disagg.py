@@ -238,9 +238,9 @@ class TierRouter(ClusterRouter):
         """Cross-tier KV-record compatibility: the FIRST replica carrying
         ``kv_layout`` metadata becomes the fleet reference; every later
         one must match it on kv_dtype / kv_dim / n_layers (frame geometry
-        a page record cannot cross) and on cache kind.  ``page_size`` MAY
-        differ between tiers — the adopting paged engine re-chunks the
-        record deterministically (engine/paged.py ``adopt_run``,
+        a page record cannot cross).  ``page_size`` MAY differ between
+        tiers — the adopting engine re-chunks the record
+        deterministically (engine/paged.py ``adopt_run``,
         ``engine.handoff_kv_relayout``).  Replicas without the metadata
         (scripted echo/oracle tiers) skip."""
         kv = getattr(replica, "kv_layout", None)
@@ -259,15 +259,6 @@ class TierRouter(ClusterRouter):
                     f"handoff frame crossing this pair can neither be "
                     f"adopted nor deterministically converted (page_size "
                     f"may differ between tiers; dtype/width/depth may not)")
-        mode = "paged" if kv.get("page_size") is not None else "contiguous"
-        ref_mode = ("paged" if ref.get("page_size") is not None
-                    else "contiguous")
-        if mode != ref_mode:
-            raise ValueError(
-                f"TierRouter refuses replica {replica.replica_id}: its "
-                f"{mode} KV cache cannot hand off against replica "
-                f"{ref_rid}'s {ref_mode} one — both tiers must run the "
-                f"same cache kind (only page_size may differ)")
 
     @staticmethod
     def _check_layout_member(replica: Replica, others) -> None:

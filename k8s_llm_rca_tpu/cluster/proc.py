@@ -188,7 +188,7 @@ def _build_worker_backend(spec: Dict[str, Any]):
         ecfg = EngineConfig(max_batch=4, max_seq_len=2560,
                             prefill_buckets=(2560,),
                             max_new_tokens=96, temperature=0.0,
-                            paged=True, page_size=64, num_pages=168,
+                            page_size=64, num_pages=168,
                             prefix_cache=False, decode_chunk=16)
         overrides = spec.get("engine_overrides") or {}
         if overrides:

@@ -189,7 +189,7 @@ def build_replicas(model_cfg, engine_cfg, n_replicas: int,
     params = llama.init_params(model_cfg, jax.random.PRNGKey(seed))
     specs = llama_param_specs(model_cfg, layout=layout)
     kv_layout = {
-        "page_size": engine_cfg.page_size if engine_cfg.paged else None,
+        "page_size": engine_cfg.page_size,
         "kv_dtype": engine_cfg.kv_cache_dtype,
         "kv_dim": model_cfg.kv_dim,
         "n_layers": model_cfg.n_layers,

@@ -191,15 +191,20 @@ class EngineConfig:
     max_seq_len: int = 1024            # per-slot KV capacity
     prefill_buckets: Tuple[int, ...] = (64, 128, 256, 512, 1024)
     max_new_tokens: int = 256
-    # contiguous-cache KV storage: None = model dtype; "int8" = per-token
-    # quantized KV (half the cache HBM/bandwidth, small quality cost)
+    # KV pool storage: None = model dtype; "int8" / "int4" = per-token
+    # quantized KV (half / a quarter of the pool's HBM and bandwidth,
+    # small quality cost)
     kv_cache_dtype: Optional[str] = None
-    # paged KV cache
-    paged: bool = False
+    # the paged KV pool.  ``paged`` selects nothing: the contiguous-slot
+    # engine is gone and make_engine refuses False.  The field is kept
+    # only until a benchmark PR drops the key from the ``engine`` group of
+    # benchmarks/configs/*.json (ROADMAP B0.8), which reaches
+    # EngineConfig(**group) as is.
+    paged: bool = True
     page_size: int = 16
     num_pages: int = 1024
-    # share page-aligned prompt-prefix KV between sequences (paged engine
-    # only; engine/prefix.py) — the RCA agent threads grow monotonically,
+    # share page-aligned prompt-prefix KV between sequences
+    # (engine/prefix.py) — the RCA agent threads grow monotonically,
     # so consecutive runs re-submit almost identical prompts
     prefix_cache: bool = True
     # sampling defaults
@@ -230,29 +235,27 @@ class EngineConfig:
     # Greedy byte-parity with host_overlap=False is guaranteed for every
     # supported composition; cp_mesh is excluded (loud ValueError).
     host_overlap: bool = False
-    # per-tick prefill token budget (paged engine only; 0 = off): a
-    # prompt whose post-prefix-hit suffix exceeds the budget admits
+    # per-tick prefill token budget (0 = off): a prompt whose
+    # post-prefix-hit suffix exceeds the budget admits
     # through the existing jitted chunk-prefill path spread across ticks
     # — one <=budget page-aligned chunk per tick, the sequence's own
     # already-written pages as the growing prefix — instead of stalling
     # one tick on a monolithic prefill.  Must be a page_size multiple
     # (chunks scatter whole pages); greedy byte-parity with budget=0 is
-    # guaranteed; cp_mesh/pp_mesh and the contiguous engine are excluded
-    # (loud ValueErrors).
+    # guaranteed; cp_mesh/pp_mesh are excluded (loud ValueErrors).
     prefill_chunk_budget: int = 0
-    # overload survival (paged engine only; docs/serving.md "overload &
-    # priorities"): when > 0, a preempted sequence spills its written KV
+    # overload survival (docs/serving.md "overload & priorities"): when
+    # > 0, a preempted sequence spills its written KV
     # pages to host buffers (one coalesced d2h fetch) and resumes by h2d
     # page restore instead of re-prefill — byte-identical greedy output,
     # no re-burned prefill FLOPs.  The value caps the TOTAL host-resident
     # spilled pages; a preemption that would exceed it falls back to the
     # free-and-re-prefill path.  0 = off (today's behavior).  Excluded
     # (loud ValueError) on cp_mesh (page axis sequence-sharded) and
-    # pp_mesh (pool layer axis stage-sharded) and on the contiguous
-    # engine.
+    # pp_mesh (pool layer axis stage-sharded).
     max_spilled_pages: int = 0
-    # tiered prefix cache (paged engine only; engine/prefix.py
-    # ``PrefixStore``, docs/performance.md "tiered prefix cache"): when
+    # tiered prefix cache (engine/prefix.py ``PrefixStore``,
+    # docs/performance.md "tiered prefix cache"): when
     # any knob is set, prefix-cache eviction DEMOTES page KV to a
     # host-RAM store (one coalesced d2h gather, the same page-record
     # layout as KV spill) instead of discarding it, and tier-aware
@@ -266,14 +269,13 @@ class EngineConfig:
     # budget is its OWN — spilled-run pages (``max_spilled_pages``) and
     # cached prefix pages never share a cap.  Greedy byte-parity across
     # cold-miss / L0 / L1 / L2 hits is guaranteed; excluded (loud
-    # ValueError) on cp_mesh (page axis sequence-sharded), pp_mesh
-    # (pool layer axis stage-sharded) and the contiguous engine,
-    # mirroring the spill exclusions.
+    # ValueError) on cp_mesh (page axis sequence-sharded) and pp_mesh
+    # (pool layer axis stage-sharded), mirroring the spill exclusions.
     prefix_host_pages: int = 0
     prefix_disk_dir: Optional[str] = None
     prefix_disk_pages: int = 0
-    # pressure-driven demotion (paged engine only; docs/performance.md
-    # "cache fabric"): when > 0, an HBM high-water mark in PAGES — at
+    # pressure-driven demotion (docs/performance.md "cache fabric"):
+    # when > 0, an HBM high-water mark in PAGES — at
     # every tick boundary where the allocator's free-page count dips
     # below it, refcount-0 prefix pages demote autonomously through the
     # same coalesced ``_demote`` gather explicit eviction uses, oldest
@@ -281,12 +283,12 @@ class EngineConfig:
     # dry).  Engines keep hot pages resident under production load with
     # no router intervention; with a store attached the demoted pages
     # stay promotable, without one this is plain pressure eviction.
-    # Requires ``prefix_cache=True``; excluded (loud ValueError) on the
-    # contiguous engine and for negative / over-capacity (>= num_pages)
-    # values.  0 = off (explicit evict only, today's behavior).
+    # Requires ``prefix_cache=True``; excluded (loud ValueError) for
+    # negative / over-capacity (>= num_pages) values.  0 = off (explicit
+    # evict only, today's behavior).
     prefix_hbm_watermark: int = 0
-    # store-backed instant recovery (paged engine only, requires a
-    # tiered/remote store; docs/durability.md "store-backed restore"):
+    # store-backed instant recovery (requires a tiered/remote store;
+    # docs/durability.md "store-backed restore"):
     # when True, every tick that grew the prefix cache also publishes
     # the newly-resident full-page chains to the store WITHOUT freeing
     # them (``PrefixCache.flush_to_store``), so a crash-restart, drain

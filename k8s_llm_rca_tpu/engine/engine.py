@@ -1,25 +1,28 @@
-"""Continuous-batching inference engine.
+"""Continuous-batching inference engine: the surface and the host loop.
 
 The reference's serving model is one blocking OpenAI run at a time with an
 escalating 5 s poll (common/openai_generic_assistant.py:92-115) — strictly
-serial.  This engine replaces it with slot-based continuous batching
+serial.  The engine replaces it with slot-based continuous batching
 (Orca/vLLM-style, re-designed for XLA's static shapes):
 
-- a fixed ``max_batch``-wide KV cache (models/llama.KVCache);
-- admission = per-sequence prefill into a free slot, padded to a static
-  bucket length (one compile per bucket, cached for the process lifetime);
-- every tick runs ONE jitted decode step for ALL active slots; sequences
-  join and leave the batch at token granularity;
+- admission = prefill into a free slot, padded to a static bucket length
+  (one compile per bucket, cached for the process lifetime);
+- every tick runs ONE jitted decode dispatch for ALL active slots (a scan
+  of up to ``decode_chunk`` steps); sequences join and leave the batch at
+  token granularity;
 - completed slots are freed immediately and re-admitted from the pending
   queue the same tick.
 
-Host<->device traffic per tick is one [B] token vector each way — everything
-else stays on device.  ``decode_scan`` amortizes even that for throughput
-benches by scanning N decode steps on device.
+This module holds what the agent layer sees and what the tick shares
+(``EngineBase``: submit/generate, deadlines, snapshot/restore, grammar
+application, the overlapped hot loop, the scan-chunk rule, termination,
+draft verification), the mesh validators and the on-device DFA steps.
+The engine itself, ``PagedInferenceEngine`` with its page pool,
+allocator and model entry points, is engine/paged.py.
 
-Slot bookkeeping lives here on the host; it is the only writer of slot
-indices, which guards the silent-clamp semantics of dynamic_update_slice
-(see .claude/skills/verify/SKILL.md).
+Slot bookkeeping lives on the host; it is the only writer of slot
+indices and page ids (see .claude/skills/verify/SKILL.md on what JAX's
+index clamping would otherwise hide).
 """
 
 from __future__ import annotations
@@ -36,10 +39,9 @@ import numpy as np
 
 from k8s_llm_rca_tpu.config import EngineConfig, ModelConfig
 from k8s_llm_rca_tpu.engine.sampling import (
-    SamplingParams, sample_tokens, sample_tokens_masked,
+    SamplingParams, sample_tokens_masked,
 )
 from k8s_llm_rca_tpu.faults import inject
-from k8s_llm_rca_tpu.models import llama
 from k8s_llm_rca_tpu.obs import trace as obs_trace
 from k8s_llm_rca_tpu.runtime import profiling
 from k8s_llm_rca_tpu.utils.logging import METRICS, get_logger
@@ -56,8 +58,8 @@ def host_np(x) -> np.ndarray:
     otherwise every process participates in a ``process_allgather`` —
     safe because the engine's host driver runs SPMD-identically in all
     processes (same prompts, same deterministic schedule), so the
-    collective lines up across the cluster.  ONE definition for both
-    engines and the speculative path: every per-tick sync routes
+    collective lines up across the cluster.  ONE definition for the
+    engine and the speculative path: every per-tick sync routes
     through here."""
     if getattr(x, "is_fully_addressable", True):
         return np.asarray(x)
@@ -113,14 +115,14 @@ def params_multi_device(params) -> bool:
 
 def validate_tp_mesh(tp_mesh, model_cfg, engine_cfg, cp_mesh=None,
                      cp_seq_axis: str = "seq") -> None:
-    """TP cache-sharding preconditions: the merged kv axis splits over
-    "model" head-aligned (see runtime.sharding.kv_cache_specs) and the
+    """TP pool-sharding preconditions: the merged kv axis splits over
+    "model" head-aligned (see runtime.sharding.paged_pool_specs) and the
     slot batch over "data".
 
-    CP composes with TP only on ONE mesh carrying both axes (the cache
-    takes the composed seq-major × head-minor layout and the ring/Ulysses
-    prefill runs per head shard — SURVEY §7 hard part 6); two DIFFERENT
-    mesh objects cannot both own the cache."""
+    CP composes with TP only on ONE mesh carrying both axes (the pool
+    takes the composed page-over-seq × kv-over-model layout and the
+    ring/Ulysses prefill runs per head shard — SURVEY §7 hard part 6);
+    two DIFFERENT mesh objects cannot both own the pool."""
     if tp_mesh is None:
         return
     for axis in ("data", "model"):
@@ -132,7 +134,7 @@ def validate_tp_mesh(tp_mesh, model_cfg, engine_cfg, cp_mesh=None,
             raise ValueError(
                 "cp_mesh and tp_mesh must be the SAME composed mesh "
                 "(one Mesh carrying 'data', 'model' and the seq axis); "
-                "two distinct meshes cannot both lay out the cache")
+                "two distinct meshes cannot both lay out the pool")
         if cp_seq_axis not in tp_mesh.shape:
             raise ValueError(f"composed mesh lacks the '{cp_seq_axis}' axis")
         n_tp = tp_mesh.shape["model"]
@@ -157,9 +159,9 @@ def validate_tp_mesh(tp_mesh, model_cfg, engine_cfg, cp_mesh=None,
 def validate_fsdp_mesh(fsdp_mesh, model_cfg, engine_cfg, tp_mesh=None,
                        cp_mesh=None, ep_mesh=None, pp_mesh=None,
                        sp: bool = False) -> None:
-    """FSDP serving preconditions (shared by both engines): parameters
-    shard along the "fsdp" axis (runtime/rules.py FSDP_LAYOUT — the
-    non-TP matmul dim: hidden for the blocks, vocab for the embeddings)
+    """FSDP serving preconditions: parameters shard along the "fsdp"
+    axis (runtime/rules.py FSDP_LAYOUT — the non-TP matmul dim: hidden
+    for the blocks, vocab for the embeddings)
     and GSPMD all-gathers each weight on use, so prefill and decode run
     unchanged and greedy parity is byte-identical.
 
@@ -168,8 +170,8 @@ def validate_fsdp_mesh(fsdp_mesh, model_cfg, engine_cfg, tp_mesh=None,
     refused loudly until their greedy-parity matrix lands: each of those
     modes hand-places weights or activations (stage bodies, ring
     attention, all-to-all dispatch) and would silently gather the full
-    weight per device without a proven composition rule.  KV caches never
-    shard on fsdp (kv_cache_specs) — only the weights do."""
+    weight per device without a proven composition rule.  The KV pool
+    never shards on fsdp (paged_pool_specs) — only the weights do."""
     if fsdp_mesh is None:
         return
     for axis in ("data", "fsdp", "model"):
@@ -257,7 +259,7 @@ def validate_ep_mesh(ep_mesh, model_cfg, engine_cfg, cp_mesh,
     seq axis: CP prefill then shards MoE tokens over (seq, expert) — the
     sequence stays put, dispatch rides the expert axis (models/llama.py
     prefill_kv_cp) — and decode tokens shard over (data, expert) as in
-    plain EP, over the seq-sharded cache."""
+    plain EP, over the seq-sharded pool."""
     if ep_mesh is None:
         return
     if model_cfg.n_experts <= 0:
@@ -291,8 +293,7 @@ def validate_ep_mesh(ep_mesh, model_cfg, engine_cfg, cp_mesh,
             raise ValueError(
                 f"prefill bucket {b} not divisible by the prefill token "
                 f"sharding {p_pref}")
-    if engine_cfg.paged and engine_cfg.prefix_cache \
-            and engine_cfg.page_size % p_tok:
+    if engine_cfg.prefix_cache and engine_cfg.page_size % p_tok:
         # the prefix-cache chunked prefill runs at ANY page-multiple width
         # (capped by remaining pages), so every width is divisible only if
         # one page already is — fail at construction, not mid-serve
@@ -307,18 +308,18 @@ def validate_pp_mesh(pp_mesh, model_cfg, engine_cfg, cp_mesh, ep_mesh,
                      tp_mesh, microbatches: Optional[int],
                      stage_axis: str = "stage",
                      params=None) -> Optional[int]:
-    """PP serving preconditions (shared by both engines).  Returns the
-    resolved microbatch count (None when pp_mesh is None).
+    """PP serving preconditions.  Returns the resolved microbatch count
+    (None when pp_mesh is None).
 
     PP composes with TP on ONE mesh carrying "stage" and "model" (the
     multi-host pod topology: stages over DCN, heads/hidden over ICI; the
     stage bodies run the manual-TP block with psum combines —
-    parallel/pipeline.py).  Quantized KV composes with PP×TP on both
-    engines: the per-token scale is the full-row scale recovered by pmax
-    over the TP group (llama._quantize_kv axis_name), so scale caches
-    replicate across TP and numerics match the plain quantized paths
-    exactly.  Quantized WEIGHTS compose too: int8 payloads shard on the
-    weight spec with per-channel scales replicating their reduced dims,
+    parallel/pipeline.py).  Quantized KV composes with PP×TP: the
+    per-token scale is the full-row scale recovered by pmax over the TP
+    group (llama._quantize_kv axis_name), so scale pools replicate
+    across TP and numerics match the plain quantized paths exactly.
+    Quantized WEIGHTS compose too: int8 payloads shard on the weight
+    spec with per-channel scales replicating their reduced dims,
     and int4 payloads are re-packed per shard at the sharding boundary
     ("shard first, pack second") so the stage bodies' shard-local
     dequant is exact — see pipeline.shard_stacked_layers.
@@ -330,9 +331,9 @@ def validate_pp_mesh(pp_mesh, model_cfg, engine_cfg, cp_mesh, ep_mesh,
     the shared all-to-all dispatch (parallel/pipeline._moe_mlp_ep);
     PP×TP×EP is not composed (the manual-TP stage block computes a
     dense MLP).  Speculative decoding composes: the verify step runs the
-    pipelined multi-token decode (parallel/pipeline.llama_pp_decode_multi
-    / paged_pp_decode_multi), so n-gram and draft-model speculation work
-    under PP, PP×TP and PP×EP.  CP remains exclusive."""
+    pipelined multi-token decode (parallel/pipeline.paged_pp_decode_multi),
+    so n-gram and draft-model speculation work under PP, PP×TP and PP×EP.
+    CP remains exclusive."""
     if pp_mesh is None:
         return None
     if cp_mesh is not None:
@@ -367,7 +368,7 @@ def validate_pp_mesh(pp_mesh, model_cfg, engine_cfg, cp_mesh, ep_mesh,
             raise ValueError(
                 "pp_mesh and tp_mesh must be the SAME composed mesh "
                 "(one Mesh carrying 'stage' and 'model'); two distinct "
-                "meshes cannot both lay out the weights and cache")
+                "meshes cannot both lay out the weights and pool")
         n_tp = tp_mesh.shape["model"]
         if (model_cfg.n_heads % n_tp or model_cfg.n_kv_heads % n_tp):
             raise ValueError(
@@ -421,8 +422,8 @@ def validate_pp_mesh(pp_mesh, model_cfg, engine_cfg, cp_mesh, ep_mesh,
 
 
 def setup_draft(draft_model, model_cfg, engine_cfg):
-    """Validate + build the ModelDraft for ``draft_model=(cfg, params)``
-    (shared by both engine constructors); None passes through."""
+    """Validate + build the ModelDraft for ``draft_model=(cfg, params)``;
+    None passes through."""
     if draft_model is None:
         return None
     if engine_cfg.speculative_k <= 0:
@@ -440,8 +441,8 @@ def setup_draft(draft_model, model_cfg, engine_cfg):
 
 def validate_cp_divisibility(cp_seq_axis: str, n_cp: int, sizes) -> None:
     """CP prefill shards the padded sequence over the mesh axis; every
-    prefill bucket (and max_seq_len — paged callers pass page-rounded
-    sizes) must split evenly across it.  Shared by both engines."""
+    prefill bucket (and max_seq_len — the caller passes page-rounded
+    sizes) must split evenly across it."""
     bad = [s for s in sizes if s % n_cp]
     if bad:
         raise ValueError(
@@ -542,21 +543,20 @@ class _Pending:
 
 
 class EngineBase:
-    """Shared continuous-batching engine surface.
+    """The continuous-batching engine's surface and host loop.
 
-    Subclasses (contiguous InferenceEngine, paged.PagedInferenceEngine)
-    implement ``step()`` and their own slot/cache bookkeeping; everything
-    the agent layer sees — submit/generate semantics, prompt clamping,
-    finish reasons, stop-string trimming — lives here so the two cache
-    designs can't drift apart.
+    ``paged.PagedInferenceEngine``, the one engine, implements the tick
+    body (``_tick``), admission, retirement (``_retire``) and the pool's
+    bookkeeping, including the per-slot rules this class calls and does
+    not define (``_chunk_bound``, ``_spec_room_ok``, ``_drop_spill``,
+    ``_expire_extra``, the resident-state edits of the overlapped loop).
+    Everything the agent layer sees — submit/generate semantics, prompt
+    clamping, finish reasons, stop-string trimming — lives here.
     """
 
     model_cfg: ModelConfig
     engine_cfg: EngineConfig
     tokenizer: Tokenizer
-    # whether _scan_tick can run compiled-DFA grammar slots on device
-    # (engine.decode_scan_dfa); the contiguous engine overrides to True
-    _dfa_scan: bool = False
     # pipeline-parallel serving (pp_mesh=): admissions route through the
     # batched pipelined prefill, padded to _pp_m microbatch multiples
     _pp: bool = False
@@ -570,8 +570,8 @@ class EngineBase:
     # activated this tick whose sampled first token has not crossed to
     # host yet.  _flushed_out: results produced by an out-of-tick flush
     # (cancel/snapshot/fault barrier), surfaced by the next _tick so
-    # step() callers never lose them.  All three are lazily re-bound to
-    # real lists by the subclass constructors.
+    # step() callers never lose them.  All three are re-bound to real
+    # lists by the engine's constructor.
     _overlap: bool = False
     _overlap_lag: int = 2
     _inflight: Optional[List[dict]] = None
@@ -675,7 +675,7 @@ class EngineBase:
         FIFO within a class (submission order is the tiebreak), lower
         ``priority`` ints ahead.  ``front=True`` (preemption requeue)
         puts the request ahead of its OWN class — a preempted sequence
-        resumes before un-admitted peers, preserving the paged engine's
+        resumes before un-admitted peers, preserving the engine's
         always-makes-progress invariant.  All-NORMAL traffic degenerates
         to exactly the old append / insert(0) behavior."""
         pri = req.priority
@@ -744,24 +744,17 @@ class EngineBase:
             finish_reason="expired", prompt_tokens=len(prompt),
             completion_tokens=len(gen))
 
-    def _expire_extra(self, seq_id: int) -> Optional["SequenceResult"]:
-        """Subclass hook: reap a deadline-expired sequence living outside
-        the pending/active books (the paged engine's chunked-prefill
-        slots)."""
-        return None
-
-    def _drop_spill(self, seq_id: int) -> None:
-        """Subclass hook: discard a sequence's host-spilled KV record (no
-        pages to free on the base engine)."""
-
     def _register(self, seq_id: int, prompt_ids: List[int]) -> None:
-        """Subclass hook called once per submitted sequence."""
+        """Keep a submitted (or restored) sequence's ORIGINAL prompt:
+        results report against it, and drafts, snapshots and resumes
+        rebuild their context from it."""
+        self._prompts[seq_id] = list(prompt_ids)
 
     def cancel_seq(self, seq_id: int) -> bool:
         """Abort a sequence NOW: a queued request leaves the pending list,
-        an active one retires its slot immediately (the paged engine frees
-        its pages through the normal ``_retire`` path, so an abandoned run
-        cannot leak allocator blocks).  No result is produced — callers
+        an active one retires its slot immediately (its pages are freed
+        through the normal ``_retire`` path, so an abandoned run cannot
+        leak allocator blocks).  No result is produced — callers
         that already dropped the handle simply never see one.  Returns
         whether the sequence was still live."""
         self._overlap_barrier()   # commit in-flight tokens before retiring
@@ -830,7 +823,7 @@ class EngineBase:
             gen = list(resumed.get(req.seq_id, ()))
             # a preempted request's prompt_ids already carry its generated
             # prefix; recover the ORIGINAL prompt from _prompts.  A
-            # KV-spilled sequence (paged engine) sits in this queue too,
+            # KV-spilled sequence sits in this queue too,
             # so it snapshots as exactly its token record — the spill
             # buffers themselves are process-local device-layout memory
             # and are never serialized
@@ -853,7 +846,7 @@ class EngineBase:
                           ) -> List[int]:
         """Re-admit sequences exported by ``snapshot_sequences`` — into
         this engine or a fresh same-model one.  Each sequence is queued
-        for a normal prefill of prompt + generated-so-far (the paged
+        for a normal prefill of prompt + generated-so-far (the
         preemption/resume path, ``_preempt_slot``), so the engine's
         greedy-parity guarantees carry over: a restored sequence finishes
         with exactly the tokens a never-interrupted run produces.
@@ -940,39 +933,12 @@ class EngineBase:
             "deadline": (self._deadlines or {}).get(req.seq_id),
         }
 
-    def export_run(self, seq_id: int
-                   ) -> Optional[Tuple[Dict[str, object],
-                                       Optional[Dict[str, object]]]]:
-        """Per-run EXPORT half of the disaggregated handoff: freeze ONE
-        sequence and return ``(entry, kv_record)`` — the snapshot-shaped
-        token entry plus (paged engine only) the host page record of its
-        computed KV.  The sequence STAYS live here, pinned in the pending
-        queue with its spill record, until the adopter acks and the
-        caller cancels it (RELEASE) — so a death anywhere mid-handoff
-        leaves a re-runnable source, never a torn sequence.
-
-        Returns None when the run is not exportable THIS pump (base
-        engine: actively decoding — it will settle here instead; paged:
-        mid-chunked-prefill or holding uncommitted first tokens).  A
-        settled/unknown seq_id raises.
-        """
-        self._overlap_barrier()
-        resumed = getattr(self, "_resumed", None)
-        for req in self._pending:
-            if req.seq_id == seq_id:
-                return self._export_entry(req, resumed or {}), None
-        for st in self._active.values():
-            if st.seq_id == seq_id:
-                # the base engine cannot preempt mid-decode; let the run
-                # settle locally — the handoff queue self-cleans
-                return None
-        raise ValueError(f"export_run: seq {seq_id} is not live")
-
     def adopt_run(self, entry: Dict[str, object], kv=None,
                   grammar=None) -> int:
-        """Per-run ADOPT half: re-admit ONE exported entry (optionally
-        with its KV page record — ignored on the base engine, which
-        re-prefills byte-identically).  Returns the seq_id adopted."""
+        """Per-run ADOPT half of the disaggregated handoff: re-admit ONE
+        exported entry through ``restore_sequences``, which re-prefills
+        byte-identically (the engine stages the KV page record on top, so
+        that the resume restores instead).  Returns the seq_id adopted."""
         sid = int(entry["seq_id"])
         self.restore_sequences(
             {"rng_key": None, "sequences": [entry]},
@@ -998,15 +964,11 @@ class EngineBase:
             self._apply_tick_fault(fault, plan)
 
     def _apply_tick_fault(self, fault, plan) -> None:
-        """Base engine tick faults: host stall (virtual-clock delay).  The
-        paged engine overrides to add allocator exhaustion and forced
-        preemption waves; page-pool kinds scheduled against the contiguous
-        engine are ignored with a warning (no pool to exhaust)."""
+        """Host-stall tick faults (virtual-clock delay).  The engine
+        extends this with allocator exhaustion, forced preemption waves
+        and device KV loss."""
         if fault.kind in ("stall", "slow"):
             plan.clock.sleep(fault.delay_s or 0.05)
-        elif fault.kind in ("oom", "preempt", "crash"):
-            log.warning("tick fault %r ignored: contiguous engine has no "
-                        "preemption/requeue machinery", fault.kind)
         else:
             log.warning("tick fault %r not applicable to engine ticks",
                         fault.kind)
@@ -1184,13 +1146,6 @@ class EngineBase:
         pend, self._admit_pending = self._admit_pending, []
         return pend
 
-    def _note_first_token(self, slot: int, token: int,
-                          update_dev: bool) -> None:
-        """Subclass hook: reflect an admission's first committed token
-        into the engine's token state.  ``update_dev`` is False when the
-        commit happens at a lagged flush — the device array has already
-        advanced past the first token, so only host mirrors may move."""
-
     def _commit_first(self, st: _Active, token: int,
                       update_dev: bool = True) -> Optional[SequenceResult]:
         """Host-side commit of an admission's first token (the deferred
@@ -1231,14 +1186,6 @@ class EngineBase:
                 if r is not None:
                     out.append(r)
         return out
-
-    def _note_flush_entry(self, entry: dict) -> None:
-        """Subclass hook, called once per flushed entry BEFORE its commits
-        (the paged engine decrements its per-slot in-flight counters)."""
-
-    def _overlap_post_commit(self, slot: int, token: int) -> None:
-        """Subclass hook: per-token host-mirror update during a lagged
-        flush commit (the paged engine advances lengths/cur_tokens)."""
 
     def _overlap_flush(self) -> List[SequenceResult]:
         """Commit every in-flight fast-path tick: one coalesced fetch for
@@ -1286,15 +1233,9 @@ class EngineBase:
                 self._flushed_out.extend(out)
             self._invalidate_device_state()
 
-    def _invalidate_device_state(self) -> None:
-        """Subclass hook — the single invalidation point: host mirrors
-        changed behind the device-resident cache, re-upload before the
-        next dispatch.  No-op for engines whose token state IS the device
-        array (contiguous) and for the plain path."""
-
     def step(self) -> List[SequenceResult]:
         """One engine tick (the public pump surface): apply this tick's
-        scheduled fault, run the subclass tick body (``_tick``) inside
+        scheduled fault, run the tick body (``_tick``) inside
         the ``engine.tick`` span (``profiling.annotate``: profiler
         annotation, always-on timer, obs span) and, only when a tracer
         is active, record a TickSample of the scheduler/pool gauges."""
@@ -1316,8 +1257,8 @@ class EngineBase:
         raise NotImplementedError
 
     def _tick_gauges(self) -> Dict[str, Optional[int]]:
-        """Scheduler gauges for the tick timeline; the paged engine
-        overrides to add pool pressure (free/evictable pages)."""
+        """Scheduler gauges for the tick timeline; the engine adds pool
+        pressure (free/evictable pages)."""
         crit = norm = batch = 0
         for r in self._pending:
             if r.priority <= 0:
@@ -1379,11 +1320,6 @@ class EngineBase:
                 "occupancy", 0.0)))
 
     # ---------------------------------------- chunked scan tick (shared)
-
-    def _chunk_bound(self, slot: int) -> int:
-        """Subclass hook: extra per-slot cap on the scan chunk (the paged
-        engine bounds by distance to the slot's next page boundary)."""
-        return self.engine_cfg.decode_chunk
 
     def _dfa_device_tables(self, tables):
         """Upload one grammar's DFA tables once; reuse across scans."""
@@ -1516,10 +1452,10 @@ class EngineBase:
         admission happens at the next step() either way, and the knob
         trades one dispatch per token for up to decode_chunk-1 steps of
         TTFT.  The chunk is the largest power of two <=
-        decode_chunk that fits every slot's CACHE headroom and subclass
-        bound; per-slot token budgets deliberately do NOT bound it (DFA
-        slots force-close in-scan, plain slots' over-decoded tokens are
-        never committed — see the inline comment), and stop strings/EOS
+        decode_chunk that fits every slot's CACHE headroom and its
+        allocated pages (``_chunk_bound``); per-slot token budgets
+        deliberately do NOT bound it (DFA slots force-close in-scan,
+        plain slots' over-decoded tokens are never committed — see the inline comment), and stop strings/EOS
         inside a chunk are trimmed after the fact, same text semantics
         as the stepwise path."""
         # which bound set the chunk: ONE ``engine.scan_limit.<reason>``
@@ -1537,7 +1473,7 @@ class EngineBase:
         for slot, st in self._active.items():
             if st.grammar is not None:
                 t = getattr(st.grammar, "tables", None)
-                if t is None or not self._dfa_scan:
+                if t is None:
                     # interpreted FSM: per-token host work
                     self._count("engine.scan_limit.grammar")
                     return 1
@@ -1572,7 +1508,7 @@ class EngineBase:
         """Shared commit loop for scanned tokens: append, per-token finish
         check at the stepwise-equivalent device length (prompt +
         len(generated) - 1), metrics, mid-chunk retirement.  ``post_commit``
-        lets a subclass update its host-side length/token arrays per
+        lets the tick update its host-side length/token arrays per
         commit."""
         finished: List[SequenceResult] = []
         with profiling.annotate("engine.commit"):
@@ -1664,18 +1600,14 @@ class EngineBase:
 
     # --------------------------------------------- speculative decoding
 
-    def _spec_room_ok(self, slot: int, t: int, lengths_host) -> bool:
-        """Subclass hook: whether slot can take a T-token write this tick."""
-        return int(lengths_host[slot]) + t <= self.engine_cfg.max_seq_len
-
     def _speculation_applies(self) -> bool:
         """Speculate only when exact-equivalence is guaranteed and every
         slot has cache room for the full T = k+1 token write."""
         k = self.engine_cfg.speculative_k
         if k <= 0 or self.engine_cfg.temperature != 0.0:
             return False
-        # ONE device sync per tick (free on the paged engine: its lengths
-        # mirror is host numpy, which _fetch passes through uncounted)
+        # the lengths mirror is host numpy, which _fetch passes through
+        # uncounted
         (lengths_host,) = self._fetch(self.lengths)
         return all(self._spec_room_ok(s, k + 1, lengths_host)
                    for s in self._active)
@@ -1834,839 +1766,13 @@ class EngineBase:
         return self._fetch(greedy)[0], None, True
 
 
-class InferenceEngine(EngineBase):
-    """Single-host engine over one model replica (sharded or not)."""
-
-    def __init__(
-        self,
-        model_cfg: ModelConfig,
-        engine_cfg: EngineConfig,
-        params,
-        tokenizer: Tokenizer,
-        cp_mesh=None,
-        cp_seq_axis: str = "seq",
-        cp_mode: str = "ring",
-        ep_mesh=None,
-        tp_mesh=None,
-        fsdp_mesh=None,
-        pp_mesh=None,
-        pp_microbatches: Optional[int] = None,
-        pp_stage_axis: str = "stage",
-        sp: bool = False,
-        draft_model=None,
-        prefix_store=None,
-    ):
-        """``draft_model``: optional (ModelConfig, params) of a small
-        draft Llama (same vocabulary) — speculation then drafts with the
-        model instead of n-gram prompt lookup (engine/speculative.py
-        ModelDraft; requires ``speculative_k > 0``).  A distilled
-        checkpoint (rca/distill.py) is the intended source.
-
-        ``cp_mesh``: optional Mesh with a ``cp_seq_axis`` axis — prefill
-        then runs context-parallel over it (long-context mode; the axis
-        size must divide every prefill bucket and max_seq_len, validated
-        below).  ``cp_mode``: "ring" (ppermute KV rotation) or "ulysses"
-        (head<->seq all-to-all).  The KV cache is placed SEQUENCE-sharded
-        over the same axis, so each device stores 1/P of a long context's
-        KV; decode runs over the sharded cache via GSPMD-partitioned
-        attention (combine collectives inserted per step).
-
-        ``ep_mesh``: optional Mesh with "data" and "expert" axes — every
-        MoE MLP (prefill AND decode) dispatches through the all-to-all
-        expert-parallel path (parallel/moe.py) with experts sharded over
-        "expert" (BASELINE configs[3]: Mixtral EP serving).  Requires an
-        MoE model and token counts divisible by the mesh (validated
-        below).
-
-        ``sp``: Megatron-style sequence parallelism inside the TP prefill
-        — the residual stream between matmul regions seq-shards over
-        "model" (llama._sp_constrain), so norms/elementwise stop
-        replicating across the TP group.  Requires ``tp_mesh``; the CP
-        modes already seq-shard activations their own way (exclusive).
-
-        ``fsdp_mesh``: optional Mesh with an "fsdp" axis — parameters
-        arrive sharded along it (runtime/rules.py FSDP_LAYOUT; the non-TP
-        matmul dim splits) and GSPMD all-gathers each weight on use in
-        both prefill and decode.  Composes with TP on the SAME mesh
-        (fsdp×tp); PP/CP/EP/sp are refused loudly (validate_fsdp_mesh).
-        The KV cache never shards on fsdp."""
-        if cp_mode not in ("ring", "ulysses"):
-            raise ValueError(f"unknown cp_mode {cp_mode!r}")
-        if sp and (tp_mesh is None or cp_mesh is not None
-                   or pp_mesh is not None):
-            raise ValueError("sp=True (Megatron sequence parallelism) "
-                             "requires tp_mesh, is exclusive with cp_mesh "
-                             "(CP already seq-shards activations), and is "
-                             "unsupported on the PP paths (the pipelined "
-                             "prefill/decode do not thread sp_mesh)")
-        if engine_cfg.host_overlap and cp_mesh is not None:
-            raise ValueError(
-                "host_overlap=True is unsupported with cp_mesh: CP admits "
-                "per-sequence through prefill_cp and its multi-process "
-                "host_np collectives must line up SPMD-identically across "
-                "processes — a lagged commit would reorder them.  Run CP "
-                "engines with host_overlap=False")
-        if engine_cfg.prefill_chunk_budget:
-            raise ValueError(
-                "prefill_chunk_budget is a paged-engine feature: the "
-                "contiguous cache has no chunked prefix-prefill path to "
-                "spread a prompt across ticks (its prefill writes one "
-                "monolithic slot slice).  Use paged=True "
-                "(PagedInferenceEngine) or prefill_chunk_budget=0")
-        if engine_cfg.max_spilled_pages:
-            raise ValueError(
-                "max_spilled_pages (KV spill-to-host preemption) requires "
-                "the paged engine: the contiguous cache has no page pool "
-                "to spill from and never preempts.  Use paged=True "
-                "(PagedInferenceEngine) or max_spilled_pages=0")
-        if (engine_cfg.prefix_host_pages or engine_cfg.prefix_disk_dir
-                or engine_cfg.prefix_disk_pages or prefix_store is not None):
-            raise ValueError(
-                "the tiered prefix cache (prefix_host_pages / "
-                "prefix_disk_dir / prefix_disk_pages / a shared "
-                "prefix_store) requires the paged engine: the contiguous "
-                "cache has no page pool to demote prefix pages from or "
-                "promote them into.  Use paged=True "
-                "(PagedInferenceEngine) or leave the tier knobs unset")
-        if engine_cfg.prefix_hbm_watermark:
-            raise ValueError(
-                "prefix_hbm_watermark (pressure-driven prefix demotion) "
-                "requires the paged engine: the contiguous cache has no "
-                "page allocator whose free count could dip below a "
-                "watermark.  Use paged=True (PagedInferenceEngine) or "
-                "prefix_hbm_watermark=0")
-        if engine_cfg.prefix_store_writethrough:
-            raise ValueError(
-                "prefix_store_writethrough requires the paged engine "
-                "and a store: the contiguous cache has no prefix pages "
-                "to publish.  Use paged=True (PagedInferenceEngine) or "
-                "prefix_store_writethrough=False")
-        if cp_mesh is not None:
-            validate_cp_divisibility(
-                cp_seq_axis, cp_mesh.shape[cp_seq_axis],
-                tuple(engine_cfg.prefill_buckets)
-                + (engine_cfg.max_seq_len,))
-        validate_ep_mesh(ep_mesh, model_cfg, engine_cfg, cp_mesh,
-                         cp_seq_axis)
-        validate_tp_mesh(tp_mesh, model_cfg, engine_cfg, cp_mesh,
-                         cp_seq_axis)
-        validate_fsdp_mesh(fsdp_mesh, model_cfg, engine_cfg, tp_mesh=tp_mesh,
-                           cp_mesh=cp_mesh, ep_mesh=ep_mesh, pp_mesh=pp_mesh,
-                           sp=sp)
-        self._pp_m = validate_pp_mesh(pp_mesh, model_cfg, engine_cfg,
-                                      cp_mesh, ep_mesh, tp_mesh,
-                                      pp_microbatches, pp_stage_axis,
-                                      params=params)
-        self._pp = pp_mesh is not None
-        self.model_cfg = model_cfg
-        self.engine_cfg = engine_cfg
-        self.params = params
-        self.tokenizer = tokenizer
-        self._draft = setup_draft(draft_model, model_cfg, engine_cfg)
-        if self._draft is not None:
-            # the draft model's own token fetch is a real sync point
-            self._draft.on_sync = (
-                lambda: self._count("engine.d2h_syncs"))
-        self.sampling = SamplingParams(
-            temperature=engine_cfg.temperature,
-            top_k=engine_cfg.top_k,
-            top_p=engine_cfg.top_p,
-        )
-
-        b = engine_cfg.max_batch
-        if engine_cfg.kv_cache_dtype not in (None, "int8", "int4"):
-            raise ValueError(
-                f"unsupported kv_cache_dtype {engine_cfg.kv_cache_dtype!r} "
-                f"(None, 'int8' or 'int4')")
-        self.cache = llama.init_cache(
-            model_cfg, b, engine_cfg.max_seq_len,
-            kv_dtype={"int8": jnp.int8, "int4": "int4", None: None}[
-                engine_cfg.kv_cache_dtype])
-        if pp_mesh is not None and tp_mesh is not None:
-            # PP×TP composed serving: the cache's LAYER axis shards over
-            # "stage" AND its merged kv axis over "model" — each device
-            # holds its stage's layers × its TP shard's kv heads.  The
-            # spec comes from the pipeline module so the placement and
-            # the shard_map in/out specs cannot drift.  Quantized scale
-            # caches shard layer-over-stage and REPLICATE across model
-            # (every TP shard writes the identical pmax full-row scale).
-            from k8s_llm_rca_tpu.parallel.pipeline import (
-                kv_cache_stage_specs, kv_scale_stage_specs,
-            )
-            from k8s_llm_rca_tpu.runtime.sharding import shard_pytree
-
-            kv_spec = kv_cache_stage_specs("model", pp_stage_axis)
-            sc_spec = (kv_scale_stage_specs(pp_stage_axis) if self.cache.quantized
-                       else None)
-            self.cache = shard_pytree(
-                self.cache,
-                llama.KVCache(kv_spec, kv_spec, sc_spec, sc_spec), pp_mesh)
-        elif tp_mesh is not None and cp_mesh is not None:
-            # CP×TP composed serving (one mesh, validated above): the
-            # cache takes the seq-major × head-minor layout — S over the
-            # seq axis, the merged kv axis over "model", slots over
-            # "data".  Prefill rides the TP-aware ring/Ulysses below;
-            # decode needs no custom kernel (GSPMD partitions attention
-            # over BOTH axes and inserts the combines)
-            from k8s_llm_rca_tpu.runtime.sharding import (
-                kv_cache_cp_specs, shard_pytree,
-            )
-
-            kv_spec, scale_spec = kv_cache_cp_specs(cp_seq_axis, "model",
-                                                    "data")
-            self.cache = shard_pytree(
-                self.cache,
-                llama.KVCache(kv_spec, kv_spec, scale_spec, scale_spec),
-                tp_mesh)
-        elif tp_mesh is not None or fsdp_mesh is not None:
-            # place the cache sharded from the start (merged kv axis over
-            # "model", slots over "data") so each device holds 1/P of the
-            # KV bytes — the real memory win of serving TP.  fsdp never
-            # shards KV (rules.kv_cache_specs): an fsdp-only mesh places
-            # the cache on the same device set as the weights with the
-            # "model" axis degenerate, so GSPMD keeps cache and gathered
-            # weights co-resident
-            from jax.sharding import PartitionSpec as _P
-
-            from k8s_llm_rca_tpu.runtime.sharding import (
-                kv_cache_specs, shard_pytree,
-            )
-
-            kv_spec = kv_cache_specs()
-            self.cache = shard_pytree(
-                self.cache,
-                llama.KVCache(kv_spec, kv_spec,
-                              _P(None, "data", None), _P(None, "data", None)),
-                tp_mesh if tp_mesh is not None else fsdp_mesh)
-        elif cp_mesh is not None:
-            # context-parallel serving: the cache's SEQUENCE axis shards
-            # over the CP mesh, so a context too large for one chip's HBM
-            # spreads its KV across the ring.  Prefill already computes
-            # context-parallel (ring/Ulysses); decode needs no custom
-            # kernel — GSPMD partitions the attention reduction over S
-            from k8s_llm_rca_tpu.runtime.sharding import (
-                kv_cache_cp_specs, shard_pytree,
-            )
-
-            kv_spec, scale_spec = kv_cache_cp_specs(cp_seq_axis)
-            self.cache = shard_pytree(
-                self.cache,
-                llama.KVCache(kv_spec, kv_spec, scale_spec, scale_spec),
-                cp_mesh)
-        elif pp_mesh is not None:
-            # PP serving: the cache's LAYER axis shards over "stage" so
-            # each device holds only its stage's layers' KV — the cache
-            # half of the per-stage split (weights below)
-            from k8s_llm_rca_tpu.parallel.pipeline import (
-                kv_cache_stage_specs, kv_scale_stage_specs,
-            )
-            from k8s_llm_rca_tpu.runtime.sharding import shard_pytree
-
-            kv_spec = kv_cache_stage_specs()
-            sc_spec = kv_scale_stage_specs(pp_stage_axis)
-            self.cache = shard_pytree(
-                self.cache,
-                llama.KVCache(kv_spec, kv_spec, sc_spec, sc_spec), pp_mesh)
-        self.lengths = jnp.zeros((b,), jnp.int32)
-        self.cur_tokens = jnp.zeros((b,), jnp.int32)
-        self._key = jax.random.PRNGKey(engine_cfg.seed)
-        # overlapped hot loop state (EngineBase machinery)
-        self._overlap = engine_cfg.host_overlap
-        self._inflight = []
-        self._admit_pending = []
-        self._flushed_out = []
-        # fused-step clamp: retired slots keep advancing until the flush
-        # notices; their writes stay inside row capacity and are
-        # overwritten by any re-admission's prefill before first attended
-        self._overlap_cap = engine_cfg.max_seq_len - 1
-
-        self._free_slots = list(range(b))
-        self._active: Dict[int, _Active] = {}       # slot -> state
-        self._pending: List[_Pending] = []
-        self._seq_counter = itertools.count()
-
-        pp_decode_fn = None
-        if pp_mesh is not None:
-            # PP serving: weights restacked [P, L/P, ...] and sharded over
-            # "stage" (each device holds ONE stage's layers); self.params
-            # becomes (non-layer params, stacked layers) — every PP entry
-            # point unpacks the pair, and the stacked tree travels as a jit
-            # ARGUMENT (a closure would inline the weights as constants).
-            from k8s_llm_rca_tpu.parallel import pipeline as pp
-
-            pp_tp_axis = "model" if tp_mesh is not None else None
-            pp_ep_axis = "expert" if ep_mesh is not None else None
-            n_stages = pp_mesh.shape[pp_stage_axis]
-            stacked = pp.shard_stacked_layers(
-                pp.stack_llama_stages(params, n_stages), pp_mesh,
-                pp_stage_axis, cfg=model_cfg, tp_axis=pp_tp_axis,
-                ep_axis=pp_ep_axis)
-            light = {k: v for k, v in params.items() if k != "layers"}
-            self.params = (light, stacked)
-            m = self._pp_m
-
-            def _pp_prefill_batch(cfg, params_t, cache, toks, lens, slots):
-                p, stk = params_t
-                return pp.llama_pp_prefill(cfg, p, cache, toks, lens,
-                                           pp_mesh, m, pp_stage_axis, stk,
-                                           slots, tp_axis=pp_tp_axis,
-                                           ep_axis=pp_ep_axis)
-
-            def pp_decode_fn(cfg, params_t, cache, toks, lens):
-                p, stk = params_t
-                return pp.llama_pp_decode_step(cfg, p, cache, toks, lens,
-                                               pp_mesh, m, pp_stage_axis,
-                                               stk, tp_axis=pp_tp_axis,
-                                               ep_axis=pp_ep_axis)
-
-            self._prefill = None        # PP admits through the batched path
-            self._prefill_batch = jax.jit(_pp_prefill_batch, static_argnums=0)
-        elif cp_mesh is not None:
-            # composed CP×TP names "model" so the ring/all-to-all runs per
-            # head shard instead of all-gathering TP-sharded heads;
-            # composed CP×EP threads ep_mesh so MoE MLPs dispatch over
-            # (seq, expert) instead of densifying
-            cp_head_axis = "model" if tp_mesh is not None else None
-
-            def _prefill_cp(cfg, params, cache, toks, n, slot):
-                return llama.prefill_cp(cfg, params, cache, toks, n, slot,
-                                        cp_mesh, cp_seq_axis, cp_mode,
-                                        cp_head_axis, ep_mesh)
-
-            self._prefill = jax.jit(_prefill_cp, static_argnums=0)
-        else:
-            # fsdp-sharded weights exclude the per-shard flash kernel (the
-            # head-sharded shard_map would consume a weight shard as if it
-            # were the full tensor) — the XLA path with GSPMD all-gathers
-            # serves fsdp/fsdp×tp prefill
-            use_flash, flash_mesh = flash_prefill_plan(
-                params, None if fsdp_mesh is not None else tp_mesh,
-                model_cfg, ep_mesh)
-            sp_mesh = tp_mesh if sp else None
-            self._prefill = jax.jit(
-                profiling.named_partial(llama.prefill, use_flash=use_flash,
-                                        ep_mesh=ep_mesh, flash_mesh=flash_mesh,
-                                        sp_mesh=sp_mesh),
-                static_argnums=0)
-            self._prefill_batch = jax.jit(
-                profiling.named_partial(llama.prefill_batch,
-                                        use_flash=use_flash,
-                                        ep_mesh=ep_mesh, flash_mesh=flash_mesh,
-                                        sp_mesh=sp_mesh),
-                static_argnums=0)
-        # batched admission needs the plain prefill path (prefill_cp is
-        # per-sequence)
-        self._batch_admission = cp_mesh is None
-        self._decode = jax.jit(
-            pp_decode_fn if pp_decode_fn is not None
-            else profiling.named_partial(llama.decode_step, ep_mesh=ep_mesh),
-            static_argnums=0)
-        # fused overlapped step (engine.overlap_step): decode + key split
-        # + sample + length advance in ONE dispatch.  The in-jit
-        # jax.random.split computes the identical subkey stream as the
-        # host split in the plain tick, so sampled tokens match exactly.
-        self._overlap_decode = jax.jit(
-            profiling.named_partial(overlap_step, ep_mesh=ep_mesh,
-                                    decode_fn=pp_decode_fn),
-            static_argnums=(0, 6, 7))
-        if pp_mesh is not None:
-            def _verify_step(cfg, params_t, cache, tokens, lengths):
-                p, stk = params_t
-                return pp.llama_pp_decode_multi(
-                    cfg, p, cache, tokens, lengths, pp_mesh, self._pp_m,
-                    pp_stage_axis, stk, tp_axis=pp_tp_axis,
-                    ep_axis=pp_ep_axis)
-        else:
-            def _verify_step(cfg, params, cache, tokens, lengths):
-                cache, logits = llama.decode_multi(cfg, params, cache,
-                                                   tokens, lengths,
-                                                   ep_mesh=ep_mesh)
-                # greedy choices computed on device: the [B, T] int
-                # transfer is 32000x smaller than the logits; full logits
-                # leave the device only for grammar slots (fetched lazily)
-                return cache, jnp.argmax(logits, axis=-1), logits
-
-        self._decode_multi = jax.jit(_verify_step, static_argnums=0)
-        self._spec_dfa_greedy = jax.jit(dfa_greedy_multi, static_argnums=3)
-        self._sample = jax.jit(sample_tokens, static_argnums=2)
-        self._sample_masked = jax.jit(sample_tokens_masked, static_argnums=2)
-        self._decode_scan = jax.jit(
-            profiling.named_partial(decode_scan, ep_mesh=ep_mesh,
-                                    decode_fn=pp_decode_fn),
-            static_argnums=(0, 6, 7, 8))
-        self._dfa_scan = True
-        self._decode_scan_dfa = jax.jit(
-            profiling.named_partial(decode_scan_dfa, ep_mesh=ep_mesh,
-                                    decode_fn=pp_decode_fn),
-            static_argnums=(0, 6, 7, 8))
-        self._dfa_dev: Dict[int, tuple] = {}   # id(tables) -> device arrays
-        self._prompts: Dict[int, List[int]] = {}   # seq_id -> prompt (for
-        # n-gram draft lookup; dropped at retirement)
-        # pre-restore generated tokens (restore_sequences): the contiguous
-        # engine never preempts, but a crash-restored sequence still needs
-        # its already-generated prefix stitched back at retirement
-        self._resumed: Dict[int, List[int]] = {}
-
-        self._buckets = tuple(
-            s for s in sorted(set(engine_cfg.prefill_buckets))
-            if s <= engine_cfg.max_seq_len
-        ) or (engine_cfg.max_seq_len,)
-
-    # ------------------------------------------------------------------ api
-
-    def _register(self, seq_id: int, prompt_ids: List[int]) -> None:
-        self._prompts[seq_id] = list(prompt_ids)
-
-    def _tick(self) -> List[SequenceResult]:
-        """One engine tick: admit pending into free slots, then one decode
-        step for all active slots.  Returns sequences finished this tick.
-        (Fault polling and tracing live in EngineBase.step, the public
-        pump surface.)
-
-        With host_overlap on and no grammar/speculation/scan in play, the
-        decode dispatch is the fused ``overlap_step`` and the host commit
-        lags one-to-two ticks behind (_overlap_step_tick); every other
-        path flushes the lag first, so it observes fully committed
-        state."""
-        finished: List[SequenceResult] = self._reap_deadlines()
-        if self._flushed_out:
-            finished.extend(self._flushed_out)
-            self._flushed_out = []
-        fast = self._overlap_fast()
-        if self._inflight and not fast:
-            finished.extend(self._overlap_flush())
-        while self._pending and self._free_slots:
-            group = self._admission_group()
-            # PP has no single-sequence prefill: every admission goes
-            # through the batched pipelined path (padded to a microbatch
-            # multiple in _admit_batch)
-            if len(group) == 1 and not self._pp:
-                early = self._admit(group[0])
-                if early is not None:    # first sampled token already terminal
-                    finished.append(early)
-            else:
-                finished.extend(self._admit_batch(group))
-        if not fast:
-            # one coalesced fetch commits every deferred admission first
-            # token before any state-dependent path (spec drafts, scan
-            # chunk bounds) reads st.generated
-            finished.extend(self._drain_admission_commits())
-        if not self._active:
-            finished.extend(self._overlap_flush())
-            return finished
-
-        if self._speculation_applies():
-            finished.extend(self._speculative_tick())
-            return finished
-
-        chunk = self._scan_chunk()
-        if chunk > 1:
-            finished.extend(self._scan_tick(chunk))
-            return finished
-
-        if fast:
-            finished.extend(self._overlap_step_tick())
-            return finished
-
-        active_slots = list(self._active)
-        forced, allow = self._tick_constraints(
-            active_slots, self.engine_cfg.max_batch,
-            self.model_cfg.vocab_size)
-        with profiling.annotate("engine.decode_step"):
-            self._count("engine.dispatches")
-            self._count_decode(1)
-            self.cache, logits = self._decode(
-                self.model_cfg, self.params, self.cache,
-                self.cur_tokens, self.lengths)
-            self._key, sub = jax.random.split(self._key)
-            if allow is not None:
-                next_tokens = self._sample_masked(
-                    logits, sub, self.sampling, jnp.asarray(allow))
-            else:
-                next_tokens = self._sample(logits, sub, self.sampling)
-        self._count("engine.decode_tokens", len(self._active))
-
-        self.lengths = self.lengths.at[jnp.asarray(active_slots)].add(1)
-        # ONE coalesced fetch for tokens + lengths (two blocking syncs
-        # before the hot-loop rework)
-        host_next, lengths_host = self._fetch(next_tokens, self.lengths)
-        if forced:
-            # np.asarray of a device array is a read-only view; copy to edit
-            host_next = host_next.copy()
-            for slot, token in forced.items():
-                host_next[slot] = token
-            self._count("engine.h2d_uploads")
-            self.cur_tokens = jnp.asarray(host_next)
-        else:
-            self.cur_tokens = next_tokens
-
-        with profiling.annotate("engine.commit"):
-            now = self._now()
-            for slot in active_slots:
-                st = self._active[slot]
-                token = int(host_next[slot])
-                st.generated.append(token)
-                st.life.committed(now)
-                if st.grammar is not None:
-                    st.grammar.advance(token)
-                reason = self._finish_reason(st, token,
-                                             int(lengths_host[slot]))
-                if reason is not None:
-                    finished.append(self._retire(slot, reason))
-        return finished
-
-    def _overlap_step_tick(self) -> List[SequenceResult]:
-        """Fast-path tick body: ONE fused dispatch (decode + sample +
-        length advance, RNG key carried in-jit), no blocking fetch — the
-        token vector joins ``_inflight`` and commits when the lag flushes
-        (every ``_overlap_lag`` ticks, one coalesced sync).  decode_tokens
-        are counted at commit (in _commit_scanned), so totals match the
-        plain path exactly."""
-        admits = self._take_admit_pending()
-        slots = [(s, self._active[s].seq_id) for s in sorted(self._active)]
-        with profiling.annotate("engine.decode_step"):
-            self._count("engine.dispatches")
-            self._count_decode(1)
-            self.cache, nxt, self.lengths, self._key = self._overlap_decode(
-                self.model_cfg, self.params, self.cache, self.cur_tokens,
-                self.lengths, self._key, self.sampling, self._overlap_cap)
-        self.cur_tokens = nxt
-        self._inflight.append({"slots": slots, "toks": nxt,
-                               "admits": admits})
-        if len(self._inflight) >= self._overlap_lag:
-            return self._overlap_flush()
-        return []
-
-    # ------------------------------------------------------------- internals
-
-    def _bucket(self, n: int) -> int:
-        for b in self._buckets:
-            if n <= b:
-                return b
-        return self.engine_cfg.max_seq_len
-
-    def _admit(self, req: _Pending) -> Optional[SequenceResult]:
-        slot = self._free_slots.pop(0)
-        n = len(req.prompt_ids)
-        bucket = self._bucket(n)
-        assert n <= bucket, f"prompt {n} exceeds largest bucket {bucket}"
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :n] = req.prompt_ids
-        with profiling.annotate("engine.prefill"):
-            self._count("engine.dispatches")
-            self.cache, logits = self._prefill(
-                self.model_cfg, self.params, self.cache,
-                jnp.asarray(padded), jnp.int32(n), jnp.int32(slot))
-            self._key, sub = jax.random.split(self._key)
-            first = self._sample(logits, sub, self.sampling)
-        self._count("engine.prefill_tokens", n)
-        self._count("engine.prefill_padded_tokens", padded.size)
-        if req.grammar is not None:
-            # grammar first tokens stay synchronous: the FSM needs the
-            # sampled value (and possibly a masked resample off these
-            # logits) before the next dispatch
-            return self._activate(req, slot, logits,
-                                  int(self._fetch(first)[0][0]))
-        # deferred admission: the device already has the first token (the
-        # decode input), the HOST value commits at the next coalesced
-        # drain/flush — admission no longer blocks on a per-group sync
-        st = self._preactivate(req, slot)
-        self.cur_tokens = self.cur_tokens.at[slot].set(first[0])
-        self._defer_first(st, first, 0)
-        return None
-
-    def _preactivate(self, req: _Pending, slot: int) -> _Active:
-        """Token-independent half of activation: register the slot and
-        set its device length (the first token is handled separately —
-        synchronously for grammar slots, deferred otherwise)."""
-        n = len(req.prompt_ids)
-        st = _Active(
-            seq_id=req.seq_id, slot=slot, prompt_tokens=n,
-            max_new_tokens=req.max_new_tokens, stop_strings=req.stop_strings,
-            grammar=req.grammar, life=req.life)
-        st.life.admitted(self._now())
-        self._active[slot] = st
-        self.lengths = self.lengths.at[slot].set(n)
-        return st
-
-    def _note_first_token(self, slot: int, token: int,
-                          update_dev: bool) -> None:
-        # deferred admissions already wrote the on-device first token at
-        # _defer_first time; only the grammar path (whose constrained
-        # token can differ from the sampled one) and pre-dispatch drains
-        # write it here.  update_dev=False at a lagged flush: the device
-        # vector has advanced past the first token.
-        if update_dev:
-            self.cur_tokens = self.cur_tokens.at[slot].set(token)
-
-    def _activate(self, req: _Pending, slot: int, logits_1v,
-                  first_token: int) -> Optional[SequenceResult]:
-        """Synchronous activation: grammar-constrain the first token,
-        register the slot, early-retire if already terminal."""
-        st = self._preactivate(req, slot)
-        token = first_token
-        if st.grammar is not None:
-            remaining = min(st.max_new_tokens,
-                            self.engine_cfg.max_seq_len
-                            - st.prompt_tokens - 1)
-            token = self._grammar_first_token(st.grammar, logits_1v, token,
-                                              remaining)
-            st.grammar.advance(token)
-        # the first sampled token may already terminate the sequence
-        return self._commit_first(st, token, update_dev=True)
-
-    def _admission_group(self) -> List[_Pending]:
-        """Pop a FIFO run of pending requests sharing one prefill bucket,
-        bounded by free slots and a batch cap — they prefill in ONE
-        dispatch (prefill_batch).  CP mode admits singly (prefill_cp is
-        per-sequence)."""
-        group = [self._pending.pop(0)]
-        if self._batch_admission:
-            b0 = self._bucket(len(group[0].prompt_ids))
-            while (self._pending and len(group) < len(self._free_slots)
-                   and len(group) < 8
-                   and self._bucket(len(self._pending[0].prompt_ids)) == b0):
-                group.append(self._pending.pop(0))
-        return group
-
-    def _admit_batch(self, reqs: List[_Pending]) -> List[SequenceResult]:
-        """Admit N same-bucket sequences with one batched prefill.  The
-        batch is padded to a power of two by repeating the last row
-        (same slot id: the duplicate scatter writes are idempotent)."""
-        n = len(reqs)
-        bucket = self._bucket(max(len(r.prompt_ids) for r in reqs))
-        n_pad = 1
-        while n_pad < n:
-            n_pad *= 2
-        if self._pp and n_pad % self._pp_m:
-            # the pipelined prefill microbatches its rows: pad the batch
-            # to a microbatch multiple (rows repeat the last real row, so
-            # the extra scatter writes stay idempotent)
-            n_pad = -(-n_pad // self._pp_m) * self._pp_m
-        slots = [self._free_slots.pop(0) for _ in range(n)]
-        tokens = np.zeros((n_pad, bucket), np.int32)
-        lens = np.zeros((n_pad,), np.int32)
-        slot_arr = np.zeros((n_pad,), np.int32)
-        for i, r in enumerate(reqs):
-            tokens[i, :len(r.prompt_ids)] = r.prompt_ids
-            lens[i] = len(r.prompt_ids)
-            slot_arr[i] = slots[i]
-        tokens[n:] = tokens[n - 1]
-        lens[n:] = lens[n - 1]
-        slot_arr[n:] = slot_arr[n - 1]
-
-        with profiling.annotate("engine.prefill"):
-            self._count("engine.dispatches")
-            self.cache, logits = self._prefill_batch(
-                self.model_cfg, self.params, self.cache,
-                jnp.asarray(tokens), jnp.asarray(lens),
-                jnp.asarray(slot_arr))
-            self._key, sub = jax.random.split(self._key)
-            firsts = self._sample(logits, sub, self.sampling)
-        self._count("engine.prefill_tokens", int(lens[:n].sum()))
-        self._count("engine.prefill_padded_tokens", tokens.size)
-        self._count("engine.batched_admissions", n)
-
-        if any(r.grammar is not None for r in reqs):
-            # a grammar member forces the whole group synchronous so its
-            # masked-resample key split keeps its stream position
-            finished: List[SequenceResult] = []
-            (firsts_host,) = self._fetch(firsts)
-            for i, req in enumerate(reqs):
-                early = self._activate(req, slots[i], logits[i:i + 1],
-                                       int(firsts_host[i]))
-                if early is not None:
-                    finished.append(early)
-            return finished
-        for i, req in enumerate(reqs):
-            st = self._preactivate(req, slots[i])
-            self.cur_tokens = self.cur_tokens.at[slots[i]].set(firsts[i])
-            self._defer_first(st, firsts, i)
-        return []
-
-    def _retire(self, slot: int, reason: str) -> SequenceResult:
-        st = self._active.pop(slot)
-        if self._deadlines:
-            self._deadlines.pop(st.seq_id, None)
-        self._free_slots.append(slot)
-        # a crash-restored sequence's st.generated holds only post-restore
-        # tokens and its admitted prompt carried the pre-crash generation;
-        # stitch the prefix back and report against the ORIGINAL prompt
-        # (mirrors the paged engine's preemption accounting)
-        orig_prompt = self._prompts.pop(st.seq_id, None)
-        generated = self._resumed.pop(st.seq_id, []) + st.generated
-        text = self._final_text(generated, reason, st.stop_strings)
-        return SequenceResult(
-            seq_id=st.seq_id,
-            token_ids=list(generated),
-            text=text,
-            finish_reason=reason,
-            prompt_tokens=(len(orig_prompt) if orig_prompt is not None
-                           else st.prompt_tokens),
-            completion_tokens=len(generated),
-            timing=self._settle_timing(st, len(generated)),
-        )
-
-    # ------------------------------------------------- chunked scan tick
-
-    def _scan_tick(self, chunk: int) -> List[SequenceResult]:
-        """Commit ``chunk`` decode steps from one on-device scan; token
-        accounting and finish semantics identical to the stepwise tick.
-        Grammar slots whose FSM compiled to DFA tables run constrained
-        INSIDE the scan (decode_scan_dfa) — zero per-token host work."""
-        active_slots = list(self._active)
-        setup = self._scan_dfa_setup()
-        self._key, sub = jax.random.split(self._key)
-        self._count("engine.dispatches")
-        self._count_decode(chunk)
-        if setup is None:
-            with profiling.annotate("engine.decode_step"):
-                self.cache, toks, self.lengths = self._decode_scan(
-                    self.model_cfg, self.params, self.cache,
-                    self.cur_tokens, self.lengths, sub, chunk,
-                    self.sampling, self.tokenizer.eos_id)
-        else:
-            (allow_t, next_t, dist_t, close_t, complete_t), states, \
-                remaining = setup
-            with profiling.annotate("engine.decode_step"):
-                self.cache, toks, self.lengths, _ = self._decode_scan_dfa(
-                    self.model_cfg, self.params, self.cache,
-                    self.cur_tokens, self.lengths, sub, chunk,
-                    self.sampling, self.tokenizer.eos_id,
-                    jnp.asarray(states), jnp.asarray(remaining),
-                    allow_t, next_t, dist_t, close_t, complete_t)
-        (toks_host,) = self._fetch(toks)                 # [chunk, B]
-        self.cur_tokens = toks[-1]
-
-        return self._commit_scanned(active_slots, toks_host, chunk,
-                                    self._grammar_post_commit)
-
-    # --------------------------------------------- speculative decoding
-
-    def _speculative_tick(self) -> List[SequenceResult]:
-        """One verification tick on the contiguous cache: score all draft
-        positions in one decode_multi, commit via _verify_and_commit.
-        When every grammar slot shares one compiled DFA, the constrained
-        greedy is computed ON DEVICE (dfa_greedy_multi) — spec×grammar
-        keeps multi-token verify with no [B, T, V] logits transfer."""
-        active_slots = list(self._active)
-        cur_host, lengths_host = self._fetch(self.cur_tokens, self.lengths)
-        tokens_in, drafts = self._build_drafts(active_slots, cur_host)
-
-        with profiling.annotate("engine.decode_step"):
-            self._count("engine.dispatches")
-            self._count_decode(tokens_in.shape[1])
-            self.cache, greedy, logits = self._decode_multi(
-                self.model_cfg, self.params, self.cache,
-                jnp.asarray(tokens_in), self.lengths)
-            greedy_host, logits_host, constrained = \
-                self._spec_constrained_greedy(greedy, logits, active_slots)
-
-        lengths_host = lengths_host.copy()
-        next_cur = cur_host.copy()
-
-        def post_commit(slot: int, token: int) -> None:
-            lengths_host[slot] += 1
-            next_cur[slot] = token
-
-        finished = self._verify_and_commit(active_slots, drafts, greedy_host,
-                                           logits_host, post_commit,
-                                           constrained)
-        self._count("engine.h2d_uploads", 2)
-        self.lengths = jnp.asarray(lengths_host)
-        self.cur_tokens = jnp.asarray(next_cur)
-        return finished
-
-
-# ---------------------------------------------------------------------------
-# On-device multi-step decode (throughput path, used by bench.py)
-# ---------------------------------------------------------------------------
-
-
-def overlap_step(
-    cfg: ModelConfig,
-    params,
-    cache: llama.KVCache,
-    cur_tokens: jnp.ndarray,    # [B]
-    lengths: jnp.ndarray,       # [B]
-    key: jax.Array,
-    sampling: SamplingParams,
-    cap: int,
-    ep_mesh=None,
-    decode_fn=None,
-) -> Tuple[llama.KVCache, jnp.ndarray, jnp.ndarray, jax.Array]:
-    """One fused hot-loop step for the overlapped engine: decode + RNG
-    split + sample + length advance in a single dispatch, so the host
-    never touches the carried state between ticks.
-
-    ``jax.random.split`` is deterministic, so splitting in-jit yields the
-    identical subkey stream as the plain tick's host-side split — sampled
-    tokens match token-for-token.  ALL slots advance (clamped at ``cap``,
-    the last writable cache position): a slot whose sequence already
-    finished on the host keeps decoding garbage until the lagged flush
-    retires it, which is safe because its tokens are never committed and
-    its KV row is fully rewritten by the next admission's prefill before
-    any position is attended.  Returns (cache, next_tokens, lengths, key).
-    """
-    if decode_fn is None:
-        cache, logits = llama.decode_step(cfg, params, cache, cur_tokens,
-                                          lengths, ep_mesh)
-    else:
-        cache, logits = decode_fn(cfg, params, cache, cur_tokens, lengths)
-    key, sub = jax.random.split(key)
-    nxt = sample_tokens(logits, sub, sampling)
-    lengths = jnp.minimum(lengths + 1, cap).astype(lengths.dtype)
-    return cache, nxt, lengths, key
-
-
-def decode_scan(
-    cfg: ModelConfig,
-    params,
-    cache: llama.KVCache,
-    cur_tokens: jnp.ndarray,    # [B]
-    lengths: jnp.ndarray,       # [B]
-    key: jax.Array,
-    n_steps: int,
-    sampling: SamplingParams = SamplingParams(),
-    eos_id: int = -1,
-    ep_mesh=None,
-    decode_fn=None,
-) -> Tuple[llama.KVCache, jnp.ndarray, jnp.ndarray]:
-    """Decode ``n_steps`` for the whole batch with zero host sync.
-
-    Returns (cache, tokens [n_steps, B], lengths).  Slots that hit ``eos_id``
-    stop advancing (their token repeats; host trims after the fact).
-    ``decode_fn``: optional (cfg, params, cache, tokens, lengths) ->
-    (cache, logits) override — the PP engine scans its pipelined step.
-    """
-
-    def body(carry, _):
-        cache, cur, lens, done, key = carry
-        if decode_fn is None:
-            cache, logits = llama.decode_step(cfg, params, cache, cur, lens,
-                                              ep_mesh)
-        else:
-            cache, logits = decode_fn(cfg, params, cache, cur, lens)
-        key, sub = jax.random.split(key)
-        nxt = sample_tokens(logits, sub, sampling)
-        newly_done = done | (nxt == eos_id)
-        advance = jnp.logical_not(done)
-        cur = jnp.where(advance, nxt, cur)
-        lens = lens + advance.astype(jnp.int32)
-        return (cache, cur, lens, newly_done, key), cur
-
-    done0 = jnp.zeros_like(cur_tokens, dtype=bool)
-    (cache, _, lengths, _, _), toks = jax.lax.scan(
-        body, (cache, cur_tokens, lengths, done0, key), None, length=n_steps)
-    return cache, toks, lengths
-
-
 def dfa_scan_step(logits, cur, lens, done, states, remaining, key,
                   sampling: SamplingParams, eos_id: int,
                   allow_t, next_t, dist_t, close_t, complete_t):
-    """One on-device DFA-constrained sampling step, shared by the
-    contiguous and paged scan bodies (single source for the budget-fits
-    mask, force-close, complete->EOS, and state-transition logic).
+    """One on-device DFA-constrained sampling step, the body of
+    ``paged.paged_decode_scan_dfa``'s scan (single source for the
+    budget-fits mask, force-close, complete->EOS, and state-transition
+    logic).
 
     Returns (cur', lens', done', states', remaining', sub_key_consumed).
     """
@@ -2717,52 +1823,3 @@ def dfa_greedy_multi(logits, states, remaining, eos_id: int,
     _, toks = jax.lax.scan(step, (states, remaining),
                            jnp.swapaxes(logits, 0, 1))
     return jnp.swapaxes(toks, 0, 1)                       # [B, T]
-
-
-def decode_scan_dfa(
-    cfg: ModelConfig,
-    params,
-    cache: llama.KVCache,
-    cur_tokens: jnp.ndarray,    # [B]
-    lengths: jnp.ndarray,       # [B]
-    key: jax.Array,
-    n_steps: int,
-    sampling: SamplingParams,
-    eos_id: int,
-    states: jnp.ndarray,        # [B] int32 DFA state per slot (FREE = none)
-    remaining: jnp.ndarray,     # [B] int32 token budget per slot
-    allow_t: jnp.ndarray,       # [S, V] bool
-    next_t: jnp.ndarray,        # [S, V] int32
-    dist_t: jnp.ndarray,        # [S] int32
-    close_t: jnp.ndarray,       # [S] int32
-    complete_t: jnp.ndarray,    # [S] bool
-    ep_mesh=None,
-    decode_fn=None,
-) -> Tuple[llama.KVCache, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """``decode_scan`` with the grammar DFA riding INSIDE the scan.
-
-    Per step, entirely on device: gather the state's token mask, sample
-    under it, force the budget-close / EOS transitions, and step the DFA
-    (constrain.compile_schema_dfa tables).  Grammar-constrained sequences
-    thus decode in chunked dispatches with ZERO per-token host work —
-    SURVEY §7's "constrained decode that stays on the fast decode path".
-    Returns (cache, tokens [n_steps, B], lengths, states).
-    """
-
-    def body(carry, _):
-        cache, cur, lens, done, states, remaining, key = carry
-        if decode_fn is None:
-            cache, logits = llama.decode_step(cfg, params, cache, cur, lens,
-                                              ep_mesh)
-        else:
-            cache, logits = decode_fn(cfg, params, cache, cur, lens)
-        cur, lens, done, states, remaining, key = dfa_scan_step(
-            logits, cur, lens, done, states, remaining, key, sampling,
-            eos_id, allow_t, next_t, dist_t, close_t, complete_t)
-        return (cache, cur, lens, done, states, remaining, key), cur
-
-    done0 = jnp.zeros_like(cur_tokens, dtype=bool)
-    (cache, _, lengths, _, states, _, _), toks = jax.lax.scan(
-        body, (cache, cur_tokens, lengths, done0, states, remaining, key),
-        None, length=n_steps)
-    return cache, toks, lengths, states

@@ -429,7 +429,7 @@ def paged_prefill_batch(cfg: ModelConfig, params, pool: PagePool,
     tokens [N, S_pad] right-padded (S_pad a page multiple); lengths [N];
     page_maps [N, S_pad // page_size] int32 page ids — DISTINCT across
     rows except padding rows repeating the last real row (idempotent
-    duplicate writes, same contract as llama.prefill_batch slots).
+    duplicate writes).
     Returns (pool', logits [N, V] at each row's last valid token).
     """
     n, s_pad = tokens.shape
@@ -806,8 +806,8 @@ def paged_decode_scan_dfa(cfg: ModelConfig, params, pool: PagePool,
                           use_kernel: Optional[bool] = None, ep_mesh=None,
                           tp_mesh=None, decode_fn=None):
     """``paged_decode_scan`` with the compiled grammar DFA riding inside
-    the scan (mirrors engine.decode_scan_dfa: budget-aware mask, sample,
-    state transition — all gathers on device).  Returns
+    the scan (engine.dfa_scan_step: budget-aware mask, sample, state
+    transition — all gathers on device).  Returns
     (pool', tokens [n_steps, B], lengths', states')."""
 
     from k8s_llm_rca_tpu.engine.engine import dfa_scan_step
@@ -841,7 +841,7 @@ def paged_overlap_step(cfg: ModelConfig, params, pool: PagePool,
                        sampling: SamplingParams, cap: int,
                        use_kernel: Optional[bool] = None, ep_mesh=None,
                        tp_mesh=None, decode_fn=None):
-    """One fused hot-loop step for the overlapped paged engine: decode +
+    """One fused hot-loop step for the overlapped engine: decode +
     RNG split + sample + length advance in a single dispatch over the
     device-resident state (docs/performance.md).
 
@@ -874,10 +874,9 @@ def paged_overlap_step(cfg: ModelConfig, params, pool: PagePool,
 
 
 class PagedInferenceEngine(EngineBase):
-    """Continuous batching over the paged pool with on-demand page growth
-    and preemption.
+    """The engine: continuous batching over the paged pool with on-demand
+    page growth and preemption.
 
-    Differences from engine.InferenceEngine (contiguous):
     - pages are allocated per sequence: ceil(prompt/page) at admission,
       +1 page whenever decode crosses a page boundary;
     - if the pool is exhausted when an active sequence must grow, the
@@ -900,15 +899,14 @@ class PagedInferenceEngine(EngineBase):
                  pp_stage_axis: str = "stage", sp: bool = False,
                  draft_model=None, prefix_store: Optional[PrefixStore] = None):
         """``cp_mesh``: optional Mesh with a ``cp_seq_axis`` axis — prefill
-        runs context-parallel over it (ring or Ulysses, as in the
-        contiguous engine) and scatters the full-depth KV into pool pages.
+        runs context-parallel over it (ring or Ulysses) and scatters the
+        full-depth KV into pool pages.
         With axis size P > 1 the pool's PAGE axis is sharded over the
         axis and allocation is partition-aligned (PartitionedPageAllocator:
         a sequence's page j comes from the device owning positions
         [j*page, (j+1)*page)), so each device stores 1/P of a long
-        context's paged KV — the same memory win as the contiguous CP
-        cache.  Requires page-rounded buckets divisible by the axis size
-        plus pages_per_seq and num_pages divisible by P, disables batched
+        context's paged KV.  Requires page-rounded buckets divisible by
+        the axis size plus pages_per_seq and num_pages divisible by P, disables batched
         admission (prefill_kv_cp is per-sequence) and is mutually
         exclusive with the prefix cache (the chunked prefix prefill is not
         context-parallel)."""
@@ -1123,8 +1121,7 @@ class PagedInferenceEngine(EngineBase):
             if n_cp > 1:
                 # seq-sharded pool: each CP device owns the page RANGE
                 # covering its sequence shard, so long-context paged
-                # serving stores 1/P of the KV bytes per device — the
-                # memory win the contiguous CP cache already has
+                # serving stores 1/P of the KV bytes per device
                 pages_per_seq = -(-engine_cfg.max_seq_len
                                   // engine_cfg.page_size)
                 if pages_per_seq % n_cp:
@@ -1223,8 +1220,8 @@ class PagedInferenceEngine(EngineBase):
                 pp_mesh)
         elif tp_mesh is not None or fsdp_mesh is not None:
             # pool pages sharded on the merged kv axis over "model": each
-            # device stores 1/P of every page's bytes (the paged analog of
-            # kv_cache_specs); tiny per-token scale pools replicate.  fsdp
+            # device stores 1/P of every page's bytes; tiny per-token
+            # scale pools replicate.  fsdp
             # never shards the pool (rules.paged_pool_specs) — an
             # fsdp-only mesh places it on the weights' device set with the
             # "model" axis degenerate
@@ -1481,7 +1478,6 @@ class PagedInferenceEngine(EngineBase):
                                     decode_fn=pp_decode_fn),
             static_argnums=(0, 7, 8, 9),
             donate_argnums=donate, static_argnames=("use_kernel",))
-        self._dfa_scan = True
         self._decode_scan_dfa = jax.jit(
             profiling.named_partial(paged_decode_scan_dfa, ep_mesh=ep_mesh,
                                     tp_mesh=self._kernel_mesh,
@@ -1502,15 +1498,6 @@ class PagedInferenceEngine(EngineBase):
             if s <= engine_cfg.max_seq_len) or (engine_cfg.max_seq_len,)
 
     # ------------------------------------------------------------------ api
-
-    def _register(self, seq_id: int, prompt_ids: List[int]) -> None:
-        self._prompts[seq_id] = list(prompt_ids)
-
-    def _stop_context(self, st: _Active) -> List[int]:
-        # include pre-preemption tokens so stop strings spanning the
-        # resume boundary still match
-        prefix = self._resumed.get(st.seq_id)
-        return prefix + st.generated if prefix else st.generated
 
     # -------------------------------------------------- fault injection
 
@@ -1620,6 +1607,8 @@ class PagedInferenceEngine(EngineBase):
         return self._dev_cur, self._dev_lens, self._dev_bt
 
     def _invalidate_device_state(self) -> None:
+        """The single invalidation point: host mirrors changed behind the
+        device-resident state, re-upload before the next dispatch."""
         self._dev_dirty = True
 
     def _dev_edit_token(self, slot: int, token) -> None:
@@ -1649,7 +1638,8 @@ class PagedInferenceEngine(EngineBase):
         return int(self.lengths[slot]) + self._inflight_n.get(slot, 0)
 
     def _note_flush_entry(self, entry: dict) -> None:
-        # every slot in the entry was dispatched once, live or not
+        """Called once per flushed entry BEFORE its commits: every slot
+        in the entry was dispatched once, live or not."""
         for s, _ in entry["slots"]:
             n = self._inflight_n.get(s, 0) - 1
             if n > 0:
@@ -1658,13 +1648,15 @@ class PagedInferenceEngine(EngineBase):
                 self._inflight_n.pop(s, None)
 
     def _overlap_post_commit(self, slot: int, token: int) -> None:
-        # lagged-flush commit: host mirrors catch up to where the device
-        # already is, so the resident state stays CLEAN
+        """Per-token commit of a lagged flush: host mirrors catch up to
+        where the device already is, so the resident state stays CLEAN."""
         self.lengths[slot] += 1
         self.cur_tokens[slot] = token
 
     def _note_first_token(self, slot: int, token: int,
                           update_dev: bool) -> None:
+        """Reflect an admission's first committed token into the token
+        state (``update_dev`` as in ``_commit_first``)."""
         self.cur_tokens[slot] = token
         if update_dev:
             # grammar-constrained first tokens can differ from the
@@ -1961,9 +1953,10 @@ class PagedInferenceEngine(EngineBase):
     # --------------------------------------------- speculative decoding
 
     def _spec_room_ok(self, slot: int, t: int, lengths_host) -> bool:
-        # all T writes must land in the slot's CURRENT page (the page id
-        # is computed once per slot in paged_decode_multi) and within the
-        # sequence cap
+        """Whether the slot can take a T-token write this tick: all T
+        writes must land in its CURRENT page (the page id is computed
+        once per slot in paged_decode_multi) and within the sequence
+        cap."""
         length = int(lengths_host[slot])
         return (length % self.page_size + t <= self.page_size
                 and length + t <= self.engine_cfg.max_seq_len)
@@ -2003,13 +1996,14 @@ class PagedInferenceEngine(EngineBase):
     # ------------------------------------------------- chunked scan tick
 
     def _chunk_bound(self, slot: int) -> int:
-        # paged-only bound: the scan may cross page boundaries into
-        # PRE-ALLOCATED pages (the per-step write indexes the block
-        # table dynamically; step()'s growth pass allocates the scan
-        # window ahead), so the bound is the slot's contiguous
-        # allocated run from its current position — with lookahead
-        # growth this is >= decode_chunk except under pool pressure,
-        # where it shrinks instead of collapsing the whole batch
+        """The slot's cap on the scan chunk (``_scan_chunk``): the scan
+        may cross page boundaries into PRE-ALLOCATED pages (the per-step
+        write indexes the block table dynamically; step()'s growth pass
+        allocates the scan window ahead), so the bound is the slot's
+        contiguous allocated run from its current position — with
+        lookahead growth this is >= decode_chunk except under pool
+        pressure, where it shrinks instead of collapsing the whole
+        batch."""
         pos = int(self.lengths[slot])
         idx = pos // self.page_size
         while (idx < self.pages_per_seq
@@ -2085,8 +2079,8 @@ class PagedInferenceEngine(EngineBase):
     def _page_part(self, seq_page_idx: int) -> int:
         """CP partition owning a sequence's page index: page j covers
         positions [j*page, (j+1)*page), which live on CP device
-        j * P // pages_per_seq — the same contiguous position split the
-        contiguous CP cache uses."""
+        j * P // pages_per_seq: the sequence split into P contiguous
+        position ranges, as the CP prefill shards it."""
         return seq_page_idx * self._cp_parts // self.pages_per_seq
 
     def _alloc_seq_pages(self, seq_page_idxs, owner: int) -> List[int]:
@@ -2590,7 +2584,7 @@ class PagedInferenceEngine(EngineBase):
         """Admit N same-bucket prefix-miss sequences with ONE batched
         paged prefill (pads to a power of two by repeating the last real
         row's tokens AND pages — the duplicate scatter writes are
-        idempotent, same contract as llama.prefill_batch slots)."""
+        idempotent)."""
         n = len(reqs)
         bucket = min(self._bucket(max(len(r.prompt_ids) for r in reqs)),
                      self.pages_per_seq * self.page_size)

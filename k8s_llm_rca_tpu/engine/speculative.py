@@ -3,7 +3,7 @@
 Draft tokens are proposed either by matching the sequence's most recent
 n-gram against its own earlier context (prompt lookup — no model), or by
 a small draft Llama running ahead greedily (``ModelDraft``).  Either way
-verification runs ONE multi-token decode step (models/llama.decode_multi)
+verification runs ONE multi-token decode step (paged.paged_decode_multi)
 scoring all draft positions at once; the longest prefix of drafts that
 matches the target model's own greedy choice is accepted, plus one bonus
 token from the first mismatching position.  Output is therefore
@@ -48,10 +48,37 @@ def ngram_draft(context: Sequence[int], n: int, k: int) -> List[int]:
     return []
 
 
+def _draft_scan(cfg, params, cache, cur_tokens, lengths, n_steps: int,
+                eos_id: int):
+    """``n_steps`` greedy decode steps of the draft model for the whole
+    batch in one dispatch, over its own ``llama.KVCache``.  Returns
+    (cache, tokens [n_steps, B]); a slot that emits ``eos_id`` stops
+    advancing (its token repeats)."""
+    import jax
+    import jax.numpy as jnp
+
+    from k8s_llm_rca_tpu.models import llama
+
+    def body(carry, _):
+        cache, cur, lens, done = carry
+        cache, logits = llama.decode_step(cfg, params, cache, cur, lens)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        advance = jnp.logical_not(done)
+        cur = jnp.where(advance, nxt, cur)
+        lens = lens + advance.astype(jnp.int32)
+        return (cache, cur, lens, done | (nxt == eos_id)), cur
+
+    done0 = jnp.zeros_like(cur_tokens, dtype=bool)
+    (cache, _, _, _), toks = jax.lax.scan(
+        body, (cache, cur_tokens, lengths, done0), None, length=n_steps)
+    return cache, toks
+
+
 class ModelDraft:
     """Draft-model speculation state: a small Llama with its own
-    contiguous cache mirrors the target engine's slots and proposes k
-    greedy tokens per tick (one ``decode_scan`` over the whole batch).
+    contiguous cache (``llama.KVCache``) mirrors the target engine's
+    slots and proposes k greedy tokens per tick (one ``_draft_scan``
+    over the whole batch).
 
     Correctness never depends on the draft — the target verifies every
     token — so the draft cache tolerates two approximations:
@@ -74,7 +101,6 @@ class ModelDraft:
         import jax
         import numpy as np
 
-        from k8s_llm_rca_tpu.engine.sampling import SamplingParams
         from k8s_llm_rca_tpu.models import llama
 
         self.cfg = cfg
@@ -90,12 +116,8 @@ class ModelDraft:
         self._buckets = tuple(
             s for s in sorted(set(engine_cfg.prefill_buckets))
             if s <= self.max_seq) or (self.max_seq,)
-        self._greedy = SamplingParams()                # temperature 0
-        from k8s_llm_rca_tpu.engine.engine import decode_scan
-
         self._prefill = jax.jit(llama.prefill, static_argnums=0)
-        self._scan = jax.jit(decode_scan, static_argnums=(0, 6, 7, 8))
-        self._key = jax.random.PRNGKey(0)              # greedy: unused noise
+        self._scan = jax.jit(_draft_scan, static_argnums=(0, 5, 6))
         # owning engines hook this to account the draft scan's blocking
         # token fetch in their engine.d2h_syncs counter (docs/performance.md)
         self.on_sync = None
@@ -160,11 +182,10 @@ class ModelDraft:
             for s in active_slots:
                 self._owner.pop(s, None)
             return {s: [] for s in active_slots}
-        self.cache, toks, _ = self._scan(
+        self.cache, toks = self._scan(
             self.cfg, self.params, self.cache,
             jnp.asarray(self.cur, jnp.int32),
-            jnp.asarray(self.lengths, jnp.int32),
-            self._key, k + 1, self._greedy, eos_id)
+            jnp.asarray(self.lengths, jnp.int32), k + 1, eos_id)
         from k8s_llm_rca_tpu.engine.engine import host_np
         if self.on_sync is not None:
             self.on_sync()

@@ -77,7 +77,7 @@ def _build_engine_service(run_timeout_s: float, clock, journal=None,
     ecfg = EngineConfig(max_batch=4, max_seq_len=2560,
                         prefill_buckets=(2560,),
                         max_new_tokens=96, temperature=0.0,
-                        paged=True, page_size=64, num_pages=168,
+                        page_size=64, num_pages=168,
                         prefix_cache=False, decode_chunk=16)
     if engine_overrides:
         import dataclasses as _dc
@@ -189,7 +189,7 @@ def _build_cluster_service(run_timeout_s: float, clock, journal=None,
             EngineConfig(max_batch=4, max_seq_len=2560,
                          prefill_buckets=(2560,),
                          max_new_tokens=96, temperature=0.0,
-                         paged=True, page_size=64, num_pages=168,
+                         page_size=64, num_pages=168,
                          prefix_cache=False, decode_chunk=16),
             n_replicas, seed=0, use_kernel=False)
         engines = [r.backend.engine for r in replicas]
@@ -1004,7 +1004,7 @@ def run_overload_soak(seed: int = 0, n_runs: int = 100, spill: bool = True,
         cfg, EngineConfig(max_batch=4, max_seq_len=256,
                           prefill_buckets=(256,),
                           max_new_tokens=max_new_tokens, temperature=0.0,
-                          paged=True, page_size=16, num_pages=96,
+                          page_size=16, num_pages=96,
                           prefix_cache=False, decode_chunk=8,
                           max_spilled_pages=(max_spilled_pages if spill
                                              else 0)),

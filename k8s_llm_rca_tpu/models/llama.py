@@ -42,6 +42,12 @@ Params = Dict[str, Any]
 class KVCache(NamedTuple):
     """Slot-based contiguous KV cache: k/v are [L, B, S_max, n_kv*d].
 
+    A reference and a draft cache, not a serving path: ``prefill`` /
+    ``decode_step`` / ``decode_multi`` over it are the plain model the
+    tests and the benchmark's logits check hold the engine to, and
+    engine/speculative.ModelDraft keeps its small draft model's context
+    in one.  The engine serves from engine/paged.PagePool.
+
     The kv-head and head-dim axes are stored MERGED: TPU tiles the last two
     axes of an array to (sublane, 128-lane) tiles, so a [..., n_kv, 64]
     layout pads head_dim 64 -> 128 and silently doubles cache HBM and
@@ -328,7 +334,7 @@ def _block_prefill(cfg, layer, x, angles, positions, seq_lens,
 def _decode_qkv(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
                 angles: jnp.ndarray, positions: jnp.ndarray):
     """Decode-block front half: pre-attention norm + roped q/k/v.  Shared
-    by the contiguous, paged and pipeline-parallel decode paths so the
+    by the reference, paged and pipeline-parallel decode paths so the
     block semantics cannot drift apart."""
     h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
     return _qkv(cfg, layer, h, angles, positions)
@@ -461,8 +467,8 @@ def prefill_kv(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
                length: jnp.ndarray, use_flash: bool = False,
                ep_mesh=None, flash_mesh=None, sp_mesh=None
                ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Shared prefill compute for both cache designs (contiguous slot write
-    below, page scatter in engine/paged.py): run the stack over ONE
+    """Shared prefill compute (the reference's slot write below, the
+    engine's page scatter in engine/paged.py): run the stack over ONE
     right-padded sequence and return its full-depth KV plus the last valid
     token's logits.
 
@@ -471,7 +477,7 @@ def prefill_kv(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     fp32 score matrix and stops compiling around S=8k, flash streams it.
     Leave False for differentiation (pallas_call has no VJP) or
     TP-sharded params (no SPMD partitioning rule — it would replicate);
-    the engines enable it automatically when safe.
+    the engine enables it automatically when safe.
 
     tokens [1, S_pad], ``length`` scalar valid length.  Returns
     (new_k [L, S_pad, n_kv, d], new_v likewise, logits [1, V]).
@@ -507,7 +513,7 @@ def prefill(cfg: ModelConfig, params: Params, cache: KVCache,
 
     tokens [1, S_pad] right-padded; ``length`` scalar valid length; returns
     (cache', last-token logits [1, V]).  One compile per padded bucket length
-    (engine/engine.py buckets prompt lengths to keep recompiles bounded).
+    (the callers bucket prompt lengths to keep recompiles bounded).
     ``use_flash``: see prefill_kv.  ``flash_mesh``: run the kernel
     per-head-shard under this TP mesh (ops.flash_attention_sharded).
     """
@@ -737,27 +743,14 @@ def prefill_kv_cp(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     return jnp.stack(ks), jnp.stack(vs), logits
 
 
-def prefill_cp(cfg: ModelConfig, params: Params, cache: KVCache,
-               tokens: jnp.ndarray, length: jnp.ndarray, slot: jnp.ndarray,
-               mesh, seq_axis: str = "seq", cp_mode: str = "ring",
-               head_axis: Optional[str] = None, ep_mesh=None
-               ) -> Tuple[KVCache, jnp.ndarray]:
-    """Context-parallel variant of ``prefill``: same cache-write contract,
-    ring/Ulysses attention compute (see prefill_kv_cp)."""
-    new_k, new_v, logits = prefill_kv_cp(cfg, params, tokens, length, mesh,
-                                         seq_axis, cp_mode, head_axis,
-                                         ep_mesh)
-    return _write_prefill_kv(cfg, cache, new_k, new_v, slot), logits
-
-
 def _prefill_batch_kv(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
                       lengths: jnp.ndarray, use_flash: bool = False,
                       ep_mesh=None, flash_mesh=None, sp_mesh=None
                       ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Batched prefill forward WITHOUT a cache write: tokens [N, S_pad]
     right-padded, lengths [N] -> (new_k [L, N, S_pad, kv_dim], new_v,
-    logits [N, V] at each row's last valid token).  Shared by the
-    contiguous (slot-scatter) and paged (page-scatter) admission paths."""
+    logits [N, V] at each row's last valid token); the caller scatters
+    the KV into pool pages (engine/paged.paged_prefill_batch)."""
     n, s_pad = tokens.shape
     angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
     positions = jnp.broadcast_to(jnp.arange(s_pad)[None, :], (n, s_pad))
@@ -778,34 +771,3 @@ def _prefill_batch_kv(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     last = x[idx, lengths - 1][:, None]              # [N, 1, H]
     logits = _logits(cfg, params, last)[:, 0]        # [N, V]
     return jnp.stack(ks), jnp.stack(vs), logits      # [L, N, S_pad, kv]
-
-
-def prefill_batch(cfg: ModelConfig, params: Params, cache: KVCache,
-                  tokens: jnp.ndarray, lengths: jnp.ndarray,
-                  slots: jnp.ndarray, use_flash: bool = False, ep_mesh=None,
-                  flash_mesh=None, sp_mesh=None
-                  ) -> Tuple[KVCache, jnp.ndarray]:
-    """Prefill N sequences into their cache slots in ONE dispatch.
-
-    tokens [N, S_pad] right-padded; lengths [N]; slots [N] DISTINCT slot
-    ids (duplicates are allowed only for identical rows — the admission
-    batcher pads a partial batch by repeating its last real row, making
-    the duplicate scatter writes idempotent).  Returns (cache', logits
-    [N, V] at each row's last valid token).  One compile per (N, S_pad)
-    bucket pair; the engine buckets both.
-    """
-    _, s_pad = tokens.shape
-    new_k, new_v, logits = _prefill_batch_kv(cfg, params, tokens, lengths,
-                                             use_flash, ep_mesh, flash_mesh,
-                                             sp_mesh)
-    if cache.quantized:
-        packed = _kv_packed(cfg, cache)
-        new_k, k_s = _quantize_kv(new_k, packed)     # scales [L, N, S_pad]
-        new_v, v_s = _quantize_kv(new_v, packed)
-        k_scale = cache.k_scale.at[:, slots, :s_pad].set(k_s)
-        v_scale = cache.v_scale.at[:, slots, :s_pad].set(v_s)
-    else:
-        k_scale, v_scale = cache.k_scale, cache.v_scale
-    k_cache = cache.k.at[:, slots, :s_pad].set(new_k)
-    v_cache = cache.v.at[:, slots, :s_pad].set(new_v)
-    return KVCache(k_cache, v_cache, k_scale, v_scale), logits

@@ -64,8 +64,8 @@ def make_ep_engine(cfg: ModelConfig, engine_cfg: EngineConfig, params,
     """Expert-parallel serving engine (BASELINE configs[3]).
 
     Builds the (data, expert) mesh (or takes ``mesh``), shards ``params``
-    over it, and returns an engine (paged when ``engine_cfg.paged``) whose
-    MoE MLPs run the all-to-all dispatch on every prefill and decode step.
+    over it, and returns an engine whose MoE MLPs run the all-to-all
+    dispatch on every prefill and decode step.
     ``n_expert_shards`` defaults to all local devices.
     """
     from k8s_llm_rca_tpu.engine import make_engine

@@ -23,7 +23,8 @@ from typing import List, Optional
 @dataclass(frozen=True)
 class TickSample:
     """Gauges at the end of one engine tick.  ``free_pages`` /
-    ``evictable_pages`` are None on the contiguous engine (no pool)."""
+    ``evictable_pages``: the allocator's free count and the prefix
+    cache's refcount-0 residency."""
 
     tick: int
     ts: float

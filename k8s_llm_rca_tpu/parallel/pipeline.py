@@ -10,12 +10,12 @@ The stage function is arbitrary (a run of transformer blocks in practice);
 ``pipeline_apply`` is deliberately generic so tests can validate the
 schedule with small closures.
 
-Serving entry points (``llama_pp_prefill``/``llama_pp_decode_step`` for the
-contiguous cache, ``paged_pp_prefill``/``paged_pp_decode_step`` for the page
-pool) share ONE schedule implementation (``_gpipe_loop``); what varies per
-entry point is only the per-stage compute + KV write.  All four support
-quantized KV (int8 / nibble-packed int4, same per-token scalar scales as
-models/llama.KVCache and engine/paged.PagePool).
+Serving entry points (``paged_pp_prefill`` / ``paged_pp_decode_step`` /
+``paged_pp_decode_multi`` / ``paged_pp_prefill_chunk`` over the page pool)
+share ONE schedule implementation (``_gpipe_loop``); what varies per entry
+point is only the per-stage compute + KV write.  All support quantized KV
+(int8 / nibble-packed int4, the per-token scalar scales of
+engine/paged.PagePool).
 """
 
 from __future__ import annotations
@@ -309,23 +309,23 @@ def pipeline_apply(fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
 
 def kv_cache_stage_specs(tp_axis: str = None,
                          stage_axis: str = "stage") -> P:
-    """KVCache k/v [L, B, S, kv]: the LAYER axis shards over
+    """PagePool k/v [L, pages, page, kv]: the LAYER axis shards over
     ``stage_axis``; under PP×TP the kv axis additionally shards over
-    ``tp_axis``.  The ONE definition of the PP cache layout — the
-    engines place the cache with it and the shard_map in/out specs
+    ``tp_axis``.  The ONE definition of the PP pool layout — the
+    engine places the pool with it and the shard_map in/out specs
     reuse it, so the two cannot drift (a mismatch would silently
-    reshard the full cache every decode tick)."""
+    reshard the full pool every decode tick)."""
     return P(stage_axis, None, None, tp_axis)
 
 
 def kv_scale_stage_specs(stage_axis: str = "stage") -> P:
-    """KVCache/PagePool scales [L, B, S] / [L, pages, page]: layer axis
-    over ``stage_axis``, like the payload they scale."""
+    """PagePool scales [L, pages, page]: layer axis over ``stage_axis``,
+    like the payload they scale."""
     return P(stage_axis, None, None)
 
 
 def _kv_tuple(cache) -> Tuple:
-    """Cache/pool -> flat array tuple for shard_map (scales only when
+    """Pool -> flat array tuple for shard_map (scales only when
     quantized, so full-precision paths don't ship None through specs)."""
     if cache.k_scale is not None:
         return (cache.k, cache.v, cache.k_scale, cache.v_scale)
@@ -441,259 +441,6 @@ def _decode_finish_ep(cfg, layer, x, attn_flat, ep_axis: str):
     x = x + attn_flat @ dq(layer["wo"])
     hm = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
     return x + _moe_mlp_ep(cfg, layer, hm, ep_axis)
-
-
-def llama_pp_prefill(cfg, params, cache, tokens, lengths, mesh: Mesh,
-                     microbatches: int = None, stage_axis: str = "stage",
-                     stacked_layers=None, slots=None, tp_axis: str = None,
-                     ep_axis: str = None):
-    """Pipeline-parallel batched prefill with per-stage KV writes.
-
-    tokens [B, S_pad] right-padded, lengths [B]; B divides into
-    ``microbatches`` slot groups (default: one per stage); ``slots`` [B]
-    cache rows to write (default arange(B); duplicates allowed only for
-    identical rows — the engines pad admission batches by repeating the
-    last real row, making the duplicate scatter writes idempotent).
-    Returns (cache', logits [B, V] at each row's last valid token),
-    matching ``llama.prefill_batch``.  Supports quantized caches.
-
-    ``tp_axis``: the PP×TP composition — stage bodies run the manual-TP
-    block (_block_prefill_tp: local head/hidden shards, psum combines)
-    with weights sharded (stage, tp) and the cache's kv axis sharded
-    over ``tp_axis``.  Quantized KV composes: the per-token scale is the
-    FULL-row scale recovered by pmax over the TP group
-    (llama._quantize_kv axis_name), so scale caches stay replicated
-    across TP and numerics match the unsharded quantized path exactly.
-    """
-    from k8s_llm_rca_tpu.models import llama as L
-
-    n_stages = mesh.shape[stage_axis]
-    m = microbatches or n_stages
-    b, s_pad = tokens.shape
-    assert b % m == 0, (b, m)
-    bm = b // m
-    assert cfg.n_layers % n_stages == 0
-    stacked = (stacked_layers if stacked_layers is not None
-               else stack_llama_stages(params, n_stages))
-    quant = cache.quantized
-    packed = quant and L._kv_packed(cfg, cache)
-
-    x = L.gather_rows(params["embedding"], tokens).astype(jnp.dtype(cfg.dtype))
-    h_dim = x.shape[-1]
-    x_mb = x.reshape(m, bm, s_pad, h_dim)
-    lengths_mb = lengths.reshape(m, bm)
-    if slots is None:
-        slots = jnp.arange(b, dtype=jnp.int32)
-    slots_mb = slots.reshape(m, bm)
-    angles = L.rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
-
-    def local(stage_layers, kv, x_mb, lengths_mb, slots_mb):
-        n_st, my, layers, perm = _stage_local_init(stage_layers, stage_axis)
-        positions = jnp.broadcast_to(jnp.arange(s_pad)[None, :], (bm, s_pad))
-
-        def stage_apply(h, mb_idx, valid, kv):
-            seq_lens = lengths_mb[mb_idx]
-            rows = slots_mb[mb_idx]                       # [bm] cache rows
-
-            def body(carry, xs):
-                layer, k_li, v_li = xs[0], xs[1], xs[2]
-                if tp_axis is not None:
-                    h2, k, v = _block_prefill_tp(cfg, layer, carry, angles,
-                                                 positions, seq_lens,
-                                                 tp_axis)
-                elif ep_axis is not None:
-                    h2, k, v = _block_prefill_ep(cfg, layer, carry, angles,
-                                                 positions, seq_lens,
-                                                 ep_axis)
-                else:
-                    h2, k, v = L._block_prefill(cfg, layer, carry, angles,
-                                                positions, seq_lens)
-                k_new = k.reshape(bm, s_pad, -1)     # kv_dim (or the local
-                v_new = v.reshape(bm, s_pad, -1)     # TP shard of it)
-                if quant:
-                    ks_li, vs_li = xs[3], xs[4]
-                    k_new, ks = L._quantize_kv(k_new, packed, tp_axis)
-                    v_new, vs = L._quantize_kv(v_new, packed, tp_axis)
-                    # row-granular garbage-tick masking, scales included
-                    ks_li = ks_li.at[rows, :s_pad].set(
-                        jnp.where(valid, ks, ks_li[rows, :s_pad]))
-                    vs_li = vs_li.at[rows, :s_pad].set(
-                        jnp.where(valid, vs, vs_li[rows, :s_pad]))
-                k_li = k_li.at[rows, :s_pad].set(
-                    jnp.where(valid, k_new.astype(k_li.dtype),
-                              k_li[rows, :s_pad]))
-                v_li = v_li.at[rows, :s_pad].set(
-                    jnp.where(valid, v_new.astype(v_li.dtype),
-                              v_li[rows, :s_pad]))
-                return h2, ((k_li, v_li, ks_li, vs_li) if quant
-                            else (k_li, v_li))
-
-            h, kv = jax.lax.scan(body, h, (layers, *kv))
-            return h, kv
-
-        return _gpipe_loop(stage_apply, x_mb, kv, m, n_st, my, perm,
-                           stage_axis)
-
-    stacked_spec = _stacked_in_specs(stacked, cfg, stage_axis, tp_axis,
-                                     ep_axis)
-    out, kv_out = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(stacked_spec, _kv_specs(quant, tp_axis, stage_axis), P(*(None,) * 4),
-                  P(None, None), P(None, None)),
-        out_specs=(P(*(None,) * 4), _kv_specs(quant, tp_axis, stage_axis)),
-        check_vma=False,
-    )(stacked, _kv_tuple(cache), x_mb, lengths_mb, slots_mb)
-
-    x_final = out.reshape(b, s_pad, h_dim)
-    last = x_final[jnp.arange(b), lengths - 1][:, None]
-    logits = L._logits(cfg, params, last)[:, 0]
-    return _rebuild(cache, kv_out), logits
-
-
-def llama_pp_decode_step(cfg, params, cache, tokens, lengths, mesh: Mesh,
-                         microbatches: int = None,
-                         stage_axis: str = "stage", stacked_layers=None,
-                         tp_axis: str = None, ep_axis: str = None):
-    """One pipeline-parallel decode step for ALL slots.
-
-    tokens [B] current token per slot, lengths [B] cached tokens; the B
-    slots split into ``microbatches`` groups that flow through the stages
-    GPipe-style (steady-state keeps every stage busy).  Returns (cache',
-    logits [B, V]) matching ``llama.decode_step``, including quantized
-    caches and the PP×TP / PP×EP compositions.
-
-    This IS the T=1 case of ``llama_pp_decode_multi`` — one shard_map
-    body serves both the regular tick and speculative verification, so
-    the masking/quantize-at-write/finish logic cannot drift between
-    them.  Hot paths MUST hoist ``stack_llama_stages`` once and pass
-    ``stacked_layers``.
-    """
-    cache, _, logits = llama_pp_decode_multi(
-        cfg, params, cache, tokens[:, None], lengths, mesh, microbatches,
-        stage_axis, stacked_layers, tp_axis, ep_axis)
-    return cache, logits[:, 0]
-
-
-def llama_pp_decode_multi(cfg, params, cache, tokens, lengths, mesh: Mesh,
-                          microbatches: int = None,
-                          stage_axis: str = "stage", stacked_layers=None,
-                          tp_axis: str = None, ep_axis: str = None):
-    """Pipeline-parallel MULTI-token decode (speculative verification).
-
-    tokens [B, T] (current token + T-1 drafts per slot, as in
-    ``llama.decode_multi``); lengths [B] cached tokens.  Writes all T
-    tokens' KV at lengths..lengths+T-1 on each stage's local layer slice
-    and returns (cache', greedy [B, T], logits [B, T, V]) — greedy
-    computed on device so the [B, T] int transfer replaces the [B, T, V]
-    logits except for grammar slots.  Composes with PP×TP (manual-TP
-    halves, pmax quant scales) and PP×EP exactly like the single-token
-    ``llama_pp_decode_step``."""
-    from k8s_llm_rca_tpu.models import llama as L
-    from k8s_llm_rca_tpu.ops.attention import decode_attention_multi
-
-    n_stages = mesh.shape[stage_axis]
-    m = microbatches or n_stages
-    b, t = tokens.shape
-    assert b % m == 0, (b, m)
-    bm = b // m
-    assert cfg.n_layers % n_stages == 0
-    stacked = (stacked_layers if stacked_layers is not None
-               else stack_llama_stages(params, n_stages))
-    s_max = cache.max_seq_len
-    quant = cache.quantized
-    packed = quant and L._kv_packed(cfg, cache)
-
-    x = L.gather_rows(params["embedding"],
-                      tokens).astype(jnp.dtype(cfg.dtype))      # [B, T, H]
-    h_dim = x.shape[-1]
-    x_mb = x.reshape(m, bm, t, h_dim)
-    lengths_mb = lengths.reshape(m, bm)
-    angles = L.rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
-    dtype = jnp.dtype(cfg.dtype)
-
-    def local(stage_layers, kv, x_mb, lengths_mb):
-        n_st, my, layers, perm = _stage_local_init(stage_layers, stage_axis)
-
-        def stage_apply(h, mb_idx, valid, kv):
-            lens = lengths_mb[mb_idx]                     # [bm]
-            positions = lens[:, None] + jnp.arange(t)[None, :]
-
-            def body(carry, xs):
-                layer, k_li, v_li = xs[0], xs[1], xs[2]
-                q, k, v = L._decode_qkv(cfg, layer, carry, angles, positions)
-                k_tok = k.reshape(bm, t, -1)   # kv_dim (or TP shard)
-                v_tok = v.reshape(bm, t, -1)
-                kv_last = k_li.shape[-1]
-                orig_k = jax.lax.dynamic_slice(
-                    k_li, (mb_idx * bm, 0, 0), (bm, s_max, kv_last))
-                orig_v = jax.lax.dynamic_slice(
-                    v_li, (mb_idx * bm, 0, 0), (bm, s_max, kv_last))
-                if quant:
-                    ks_li, vs_li = xs[3], xs[4]
-                    k_tok, ks1 = L._quantize_kv(k_tok, packed, tp_axis)
-                    v_tok, vs1 = L._quantize_kv(v_tok, packed, tp_axis)
-                    orig_ks = jax.lax.dynamic_slice(
-                        ks_li, (mb_idx * bm, 0), (bm, s_max))
-                    orig_vs = jax.lax.dynamic_slice(
-                        vs_li, (mb_idx * bm, 0), (bm, s_max))
-                    ks_rows = L._write_tokens_scale(orig_ks, ks1, lens)
-                    vs_rows = L._write_tokens_scale(orig_vs, vs1, lens)
-                else:
-                    ks_rows = vs_rows = None
-                k_rows = L._write_tokens_kv(
-                    orig_k, k_tok.astype(orig_k.dtype), lens)
-                v_rows = L._write_tokens_kv(
-                    orig_v, v_tok.astype(orig_v.dtype), lens)
-                attn = decode_attention_multi(
-                    q,
-                    L._dequant_layer(k_rows, ks_rows, dtype, packed).reshape(
-                        bm, s_max, -1, cfg.head_dim),
-                    L._dequant_layer(v_rows, vs_rows, dtype, packed).reshape(
-                        bm, s_max, -1, cfg.head_dim),
-                    lens + 1)
-                attn_flat = attn.reshape(bm, t, -1)
-                if tp_axis is not None:
-                    hx = _decode_finish_tp(cfg, layer, carry, attn_flat,
-                                           tp_axis)
-                elif ep_axis is not None:
-                    hx = _decode_finish_ep(cfg, layer, carry, attn_flat,
-                                           ep_axis)
-                else:
-                    hx = L._decode_finish(cfg, layer, carry, attn_flat)
-                k_li = jax.lax.dynamic_update_slice(
-                    k_li, jnp.where(valid, k_rows, orig_k),
-                    (mb_idx * bm, 0, 0))
-                v_li = jax.lax.dynamic_update_slice(
-                    v_li, jnp.where(valid, v_rows, orig_v),
-                    (mb_idx * bm, 0, 0))
-                if quant:
-                    ks_li = jax.lax.dynamic_update_slice(
-                        ks_li, jnp.where(valid, ks_rows, orig_ks),
-                        (mb_idx * bm, 0))
-                    vs_li = jax.lax.dynamic_update_slice(
-                        vs_li, jnp.where(valid, vs_rows, orig_vs),
-                        (mb_idx * bm, 0))
-                    return hx, (k_li, v_li, ks_li, vs_li)
-                return hx, (k_li, v_li)
-
-            h, kv = jax.lax.scan(body, h, (layers, *kv))
-            return h, kv
-
-        return _gpipe_loop(stage_apply, x_mb, kv, m, n_st, my, perm,
-                           stage_axis)
-
-    stacked_spec = _stacked_in_specs(stacked, cfg, stage_axis, tp_axis,
-                                     ep_axis)
-    out, kv_out = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(stacked_spec, _kv_specs(quant, tp_axis, stage_axis),
-                  P(*(None,) * 4), P(None, None)),
-        out_specs=(P(*(None,) * 4), _kv_specs(quant, tp_axis, stage_axis)),
-        check_vma=False,
-    )(stacked, _kv_tuple(cache), x_mb, lengths_mb)
-
-    logits = L._logits(cfg, params, out.reshape(b, t, h_dim))   # [B, T, V]
-    return (_rebuild(cache, kv_out), jnp.argmax(logits, axis=-1), logits)
 
 
 # ---------------------------------------------------------------------------
@@ -963,8 +710,8 @@ def paged_pp_prefill_chunk(cfg, params, pool, tokens, chunk_len,
     shards, psum combines)
     over the pool's kv-lane shard, so the agent-thread reuse the cache
     was built for survives in the production stage×model mesh.  EP is
-    not composed (the chunk layer has no expert dispatch; the engines
-    reject prefix_cache under PP×EP)."""
+    not composed (the chunk layer has no expert dispatch; the engine
+    rejects prefix_cache under PP×EP)."""
     from k8s_llm_rca_tpu.engine.paged import _chunk_layer, _pool_packed
     from k8s_llm_rca_tpu.models import llama as L
 

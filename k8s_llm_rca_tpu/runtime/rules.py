@@ -21,9 +21,9 @@ The declarative replacement for hand-rolled per-model spec dicts
   two-way coverage — every param matched, every rule used — is provable
   without touching a device.
 
-Serving-state derivation (``kv_cache_specs`` / ``kv_cache_cp_specs`` /
-``paged_pool_specs``) lives here too: the engines' cache placement reads
-the same layout the weights were placed with.
+Serving-state derivation (``paged_pool_specs``) lives here too: the
+engine's pool placement reads the same layout the weights were placed
+with.
 """
 
 from __future__ import annotations
@@ -300,36 +300,8 @@ def encoder_param_template(cfg) -> Dict[str, Any]:
 
 
 # ---------------------------------------------------------------------------
-# Serving-state derivation (optimizer-free: KV caches, paged pools).
+# Serving-state derivation (optimizer-free: the paged KV pool).
 # ---------------------------------------------------------------------------
-
-def kv_cache_specs(layout: Optional[SpecLayout] = None) -> P:
-    """Contiguous KV cache [L, B, S, n_kv*d] (models/llama.KVCache): batch
-    on the data axis, the merged kv-head*head_dim axis on tp — splitting
-    the merged axis over tp is identical to sharding the kv-head axis it
-    row-major-contains when the tp axis size divides n_kv; larger meshes
-    split inside heads (still correct shapes, but collectives land
-    mid-head — size the mesh like wk/wv columns).  fsdp never shards KV
-    (caches are activation state, gathered on use anyway)."""
-    lo = layout or TP_LAYOUT
-    return P(None, lo.data, None, lo.tp)
-
-
-def kv_cache_cp_specs(seq_axis: str = "seq", head_axis: Optional[str] = None,
-                      data_axis: Optional[str] = None) -> Tuple[P, P]:
-    """Context-parallel KV cache layout: the SEQUENCE axis of k/v
-    [L, B, S, kv] shards over ``seq_axis`` so each device stores 1/P of a
-    long context's KV bytes.  Decode under this layout needs no custom
-    kernel: GSPMD partitions the attention reduction over S and inserts
-    the combine collectives (greedy-parity-tested in test_parallel.py).
-    Returns (kv_spec, scale_spec) — scales [L, B, S] shard likewise.
-
-    ``head_axis``/``data_axis``: the CP×TP composition — the merged kv
-    axis additionally shards over "model" (seq-major × head-minor) and
-    slots over "data", stacking the TP layout on the CP one."""
-    return (P(None, data_axis, seq_axis, head_axis),
-            P(None, data_axis, seq_axis))
-
 
 def paged_pool_specs(layout: Optional[SpecLayout] = None) -> Tuple[P, P]:
     """Paged KV pool [L, n_pages, page, kv]: the merged kv axis over tp,

@@ -39,8 +39,6 @@ from k8s_llm_rca_tpu.runtime.rules import (  # noqa: F401  (re-exports)
     encoder_param_template,
     encoder_rules,
     is_param_leaf,
-    kv_cache_cp_specs,
-    kv_cache_specs,
     llama_param_template,
     llama_rules,
     match_partition_rules,

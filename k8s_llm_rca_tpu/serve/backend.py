@@ -1,7 +1,7 @@
 """LM backends for the assistants service.
 
 ``EngineBackend`` is the real path: requests stream through the
-continuous-batching InferenceEngine, so concurrent runs (e.g. stage 3's
+continuous-batching engine, so concurrent runs (e.g. stage 3's
 per-entity audits, SURVEY §3.4) share decode steps in one batch.
 
 ``EchoBackend`` is a trivial deterministic backend for serve-layer tests.
@@ -23,7 +23,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Protocol, Sequence, Set, Tuple
 
 from k8s_llm_rca_tpu.engine.constrain import make_grammar
-from k8s_llm_rca_tpu.engine.engine import InferenceEngine
+from k8s_llm_rca_tpu.engine.engine import EngineBase
 from k8s_llm_rca_tpu.faults import inject
 from k8s_llm_rca_tpu.obs import trace as obs_trace
 from k8s_llm_rca_tpu.utils import pages, wal
@@ -150,7 +150,7 @@ class EngineBackend:
     freeing its batch slot and — on the paged engine — its pages.
     """
 
-    def __init__(self, engine: InferenceEngine):
+    def __init__(self, engine: EngineBase):
         _assert_fully_addressable(engine)
         self.engine = engine
         self.tokenizer = engine.tokenizer

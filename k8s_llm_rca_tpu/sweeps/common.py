@@ -33,9 +33,6 @@ def add_common_args(parser: argparse.ArgumentParser) -> None:
                              "EngineConfig's; semantics identical to "
                              "stepwise — amortizes dispatch latency, and "
                              "DFA-grammar runs ride the scan)")
-    parser.add_argument("--paged", action="store_true",
-                        help="paged KV cache engine (preemption + prefix "
-                             "caching) instead of contiguous slots")
     quant = parser.add_mutually_exclusive_group()
     quant.add_argument("--int8", action="store_true",
                        help="weight-only int8 quantization")
@@ -105,7 +102,7 @@ def build_service(args) -> AssistantService:
             "clamping --max-seq-len %d to %s's model maximum %d",
             args.max_seq_len, model_cfg.name, max_seq)
     ecfg_kw = dict(max_batch=args.max_batch, max_seq_len=max_seq,
-                   paged=args.paged, kv_cache_dtype=args.kv_dtype)
+                   kv_cache_dtype=args.kv_dtype)
     if args.decode_chunk is not None:
         ecfg_kw["decode_chunk"] = args.decode_chunk   # else EngineConfig's
     engine = make_engine(model_cfg, EngineConfig(**ecfg_kw),

@@ -21,25 +21,23 @@ def model_config():
 
 
 def engine_configs():
-    """[(kind, paged, EngineConfig)] for the serve parity legs."""
+    """[(kind, EngineConfig)] for the serve parity legs: the pool cut
+    into pages of 8 and of 16."""
     from k8s_llm_rca_tpu.config import EngineConfig
 
-    out = []
-    for paged in (False, True):
-        extra = (dict(paged=True, page_size=8, num_pages=32,
-                      prefix_cache=False) if paged else {})
-        out.append(("paged" if paged else "contig", paged,
-                    EngineConfig(max_batch=2, max_seq_len=64,
-                                 prefill_buckets=(16, 32, 64),
-                                 max_new_tokens=MAX_NEW, temperature=0.0,
-                                 decode_chunk=4, **extra)))
-    return out
+    return [(f"page{page}",
+             EngineConfig(max_batch=2, max_seq_len=64,
+                          prefill_buckets=(16, 32, 64),
+                          max_new_tokens=MAX_NEW, temperature=0.0,
+                          decode_chunk=4, page_size=page,
+                          num_pages=256 // page, prefix_cache=False))
+            for page in (8, 16)]
 
 
 def serve_all(make):
     """{key: "tok,tok;..."} for every (engine, call-shape) leg.  ``make``
-    builds an engine from (model_cfg, engine_cfg, paged) — the worker
-    passes a tp_mesh-sharded builder, the test an unsharded one."""
+    builds an engine from (model_cfg, params, tokenizer, engine_cfg) —
+    the worker passes a tp_mesh-sharded builder, the test an unsharded one."""
     import jax
 
     from k8s_llm_rca_tpu.models import llama
@@ -52,8 +50,8 @@ def serve_all(make):
     single = [list(tok.encode(SINGLE_PROMPT, add_bos=True))]
     out = {}
     with jax.default_matmul_precision("float32"):
-        for kind, paged, ecfg in engine_configs():
-            eng = make(cfg, params, tok, ecfg, paged)
+        for kind, ecfg in engine_configs():
+            eng = make(cfg, params, tok, ecfg)
             for shape, prompts in (("batch", batch), ("single", single)):
                 res = eng.generate([list(p) for p in prompts],
                                    max_new_tokens=MAX_NEW)

@@ -96,11 +96,10 @@ from k8s_llm_rca_tpu.runtime.sharding import (  # noqa: E402
 )
 
 
-def _make_sharded(cfg, sparams, stok, secfg, paged):
-    skw = dict(use_kernel=False) if paged else {}
+def _make_sharded(cfg, sparams, stok, secfg):
     return make_engine(
         cfg, secfg, shard_pytree(sparams, llama_param_specs(cfg), mesh),
-        stok, tp_mesh=mesh, **skw)
+        stok, tp_mesh=mesh, use_kernel=False)
 
 
 for key, toks in serve_cfg.serve_all(_make_sharded).items():

@@ -30,3 +30,27 @@ def cpu_devices():
     devs = [d for d in jax.devices() if d.platform == "cpu"]
     assert len(devs) >= 8, f"expected 8 virtual cpu devices, got {len(devs)}"
     return devs
+
+
+def reference_greedy(cfg, params, prompt_ids, n_new, max_seq_len=None):
+    """The model's own greedy continuation of one prompt: ``llama.prefill``
+    then ``llama.decode_step`` over a one-slot ``KVCache``.  The plain
+    reference the engine is held to: no pages, no batch, no scan."""
+    import jax.numpy as jnp
+
+    from k8s_llm_rca_tpu.models import llama
+
+    n = len(prompt_ids)
+    cache = llama.init_cache(cfg, 1, max_seq_len or cfg.max_seq_len)
+    padded = jnp.zeros((1, -(-n // 16) * 16), jnp.int32)
+    padded = padded.at[0, :n].set(jnp.asarray(prompt_ids, jnp.int32))
+    cache, logits = llama.prefill(cfg, params, cache, padded,
+                                  jnp.int32(n), jnp.int32(0))
+    out = [int(jnp.argmax(logits[0]))]
+    lengths = jnp.array([n], jnp.int32)
+    for _ in range(n_new - 1):
+        cache, logits = llama.decode_step(
+            cfg, params, cache, jnp.array([out[-1]], jnp.int32), lengths)
+        out.append(int(jnp.argmax(logits[0])))
+        lengths = lengths + 1
+    return out

@@ -251,7 +251,7 @@ class TestBuildService:
         parser = argparse.ArgumentParser()
         add_common_args(parser)
         service = build_service(parser.parse_args(
-            ["--backend", "engine", "--model", "tiny", "--paged", "--int4",
+            ["--backend", "engine", "--model", "tiny", "--int4",
              "--kv-dtype", "int4", "--max-seq-len", "256"]))
         engine = service.backend.engine
         weights = [leaf for leaf in jax.tree.leaves(

@@ -278,7 +278,7 @@ class TestDrainMigration:
         cfg = TINY.replace(max_seq_len=64)
         ecfg = EngineConfig(max_batch=2, max_seq_len=64,
                             prefill_buckets=(16, 32), max_new_tokens=10,
-                            temperature=0.0, paged=True, page_size=8,
+                            temperature=0.0, page_size=8,
                             num_pages=32, decode_chunk=1,
                             prefix_cache=True)
         tok = get_tokenizer(vocab_size=cfg.vocab_size)

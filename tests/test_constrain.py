@@ -17,7 +17,6 @@ from k8s_llm_rca_tpu.config import TINY, EngineConfig
 from k8s_llm_rca_tpu.engine.constrain import (
     JsonCharAutomaton, JsonGrammar, make_grammar,
 )
-from k8s_llm_rca_tpu.engine.engine import InferenceEngine
 from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
 from k8s_llm_rca_tpu.models import llama
 from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
@@ -73,7 +72,7 @@ class TestJsonCharAutomaton:
 
 
 class TestConstrainedEngine:
-    def _engine(self, paged=False, **ecfg_kw):
+    def _engine(self, **ecfg_kw):
         cfg = TINY.replace(max_seq_len=256)
         params = llama.init_params(cfg, jax.random.PRNGKey(0))
         defaults = dict(max_batch=4, max_seq_len=128, max_new_tokens=48,
@@ -81,11 +80,7 @@ class TestConstrainedEngine:
         defaults.update(ecfg_kw)
         ecfg = EngineConfig(**defaults)
         tok = get_tokenizer()
-        if paged:
-            eng = PagedInferenceEngine(cfg, ecfg, params, tok,
-                                       use_kernel=False)
-        else:
-            eng = InferenceEngine(cfg, ecfg, params, tok)
+        eng = PagedInferenceEngine(cfg, ecfg, params, tok, use_kernel=False)
         return eng, tok
 
     def _run(self, eng, tok, prompts, **kw):
@@ -119,7 +114,7 @@ class TestConstrainedEngine:
     def test_paged_engine_with_preemption_keeps_grammar(self):
         # tight pool forces growth-path preemption mid-generation; the FSM
         # must survive the requeue/resume cycle
-        eng, tok = self._engine(paged=True, max_batch=3, max_seq_len=64,
+        eng, tok = self._engine(max_batch=3, max_seq_len=64,
                                 page_size=8, num_pages=12,
                                 prefill_buckets=(16,), temperature=1.0)
         outs = self._run(eng, tok, ["aaaaaaaaaaaa", "bbbbbbbbbbbb",
@@ -148,7 +143,7 @@ class TestBackendIntegration:
         ecfg = EngineConfig(max_batch=2, max_seq_len=128, max_new_tokens=32,
                             prefill_buckets=(32, 64))
         tok = get_tokenizer()
-        backend = EngineBackend(InferenceEngine(cfg, ecfg, params, tok))
+        backend = EngineBackend(PagedInferenceEngine(cfg, ecfg, params, tok))
         service = AssistantService(backend)
         asst = service.create_assistant(
             "emit json", "t", "m",
@@ -321,8 +316,8 @@ class TestSchemaGrammar:
         params = llama.init_params(cfg, jax.random.PRNGKey(0))
         ecfg = EngineConfig(max_batch=2, max_seq_len=256,
                             prefill_buckets=(64,))
-        backend = EngineBackend(InferenceEngine(cfg, ecfg, params,
-                                                get_tokenizer()))
+        backend = EngineBackend(PagedInferenceEngine(cfg, ecfg, params,
+                                                     get_tokenizer()))
         with pytest.raises(ValueError, match="minimal document"):
             backend.start("p", GenOptions(max_new_tokens=8,
                                           grammar=PLAN_SCHEMA))
@@ -336,7 +331,7 @@ class TestSchemaGrammar:
                             prefill_buckets=(64,), max_new_tokens=512,
                             temperature=1.0)
         tok = get_tokenizer()
-        eng = InferenceEngine(cfg, ecfg, params, tok)
+        eng = PagedInferenceEngine(cfg, ecfg, params, tok, use_kernel=False)
         sid = eng.submit(tok.encode("plan the incident", add_bos=True),
                          max_new_tokens=512,
                          grammar=SchemaGrammar(PLAN_SCHEMA, tok))
@@ -417,29 +412,6 @@ class TestCompiledDFA:
         assert make_grammar(PLAN_SCHEMA, tok).tables \
             is make_grammar(PLAN_SCHEMA, tok).tables
 
-    def test_engine_chunked_scan_matches_stepwise(self):
-        """The DFA rides inside the decode scan: chunked greedy output ==
-        per-tick host-FSM output, and both parse + respect enums."""
-        outs = {}
-        tok = get_tokenizer()
-        cfg = TINY.replace(max_seq_len=512)
-        params = llama.init_params(cfg, jax.random.PRNGKey(0))
-        for chunk in (1, 8):
-            ecfg = EngineConfig(max_batch=2, max_seq_len=512,
-                                prefill_buckets=(32,), max_new_tokens=256,
-                                temperature=0.0, decode_chunk=chunk)
-            eng = InferenceEngine(cfg, ecfg, params, tok)
-            ids = [eng.submit(tok.encode(p, add_bos=True),
-                              grammar=make_grammar(PLAN_SCHEMA, tok),
-                              max_new_tokens=256)
-                   for p in ("plan a", "plan b")]
-            res = {r.seq_id: r for r in eng.run_to_completion()}
-            outs[chunk] = [res[i].text for i in ids]
-            for text in outs[chunk]:
-                parsed = json.loads(text)
-                assert parsed["DestinationKind"] in KINDS
-        assert outs[1] == outs[8]
-
     def test_engine_scan_mixed_grammar_and_free_slots(self):
         """A scan batch mixing one DFA-constrained slot with unconstrained
         slots: the FREE state row leaves free slots untouched."""
@@ -449,14 +421,15 @@ class TestCompiledDFA:
         ecfg = EngineConfig(max_batch=3, max_seq_len=256,
                             prefill_buckets=(32,), max_new_tokens=200,
                             temperature=0.0, decode_chunk=8)
-        eng = InferenceEngine(cfg, ecfg, params, tok)
+        eng = PagedInferenceEngine(cfg, ecfg, params, tok, use_kernel=False)
         gid = eng.submit(tok.encode("plan", add_bos=True),
                          grammar=make_grammar(PLAN_SCHEMA, tok),
                          max_new_tokens=200)
         fids = [eng.submit(tok.encode(p, add_bos=True), max_new_tokens=24)
                 for p in ("free one", "free two")]
         # reference for the free slots: same engine config, no grammar slot
-        ref_eng = InferenceEngine(cfg, ecfg, params, tok)
+        ref_eng = PagedInferenceEngine(cfg, ecfg, params, tok,
+                                       use_kernel=False)
         ref_ids = [ref_eng.submit(tok.encode(p, add_bos=True),
                                   max_new_tokens=24)
                    for p in ("free one", "free two")]
@@ -484,7 +457,8 @@ class TestCompiledDFA:
             ecfg = EngineConfig(max_batch=3, max_seq_len=256,
                                 prefill_buckets=(32,), max_new_tokens=200,
                                 temperature=0.0, decode_chunk=chunk)
-            eng = InferenceEngine(cfg, ecfg, params, tok)
+            eng = PagedInferenceEngine(cfg, ecfg, params, tok,
+                                       use_kernel=False)
             a = eng.submit(tok.encode("plan", add_bos=True),
                            grammar=make_grammar(PLAN_SCHEMA, tok),
                            max_new_tokens=200)
@@ -519,7 +493,8 @@ class TestCompiledDFA:
             ecfg = EngineConfig(max_batch=2, max_seq_len=128,
                                 prefill_buckets=(32,), max_new_tokens=24,
                                 temperature=0.0, decode_chunk=chunk)
-            eng = InferenceEngine(cfg, ecfg, params, tok)
+            eng = PagedInferenceEngine(cfg, ecfg, params, tok,
+                                       use_kernel=False)
             ids = [eng.submit(tok.encode(p, add_bos=True),
                               max_new_tokens=24)
                    for p in ("alpha", "beta", "gamma", "delta", "epsilon")]
@@ -538,7 +513,7 @@ class TestCompiledDFA:
         ecfg = EngineConfig(max_batch=1, max_seq_len=512,
                             prefill_buckets=(32,), max_new_tokens=256,
                             temperature=1.0, top_k=40, decode_chunk=8)
-        eng = InferenceEngine(cfg, ecfg, params, tok)
+        eng = PagedInferenceEngine(cfg, ecfg, params, tok, use_kernel=False)
         g = make_grammar(PLAN_SCHEMA, tok)
         budget = g.min_budget() + 8
         sid = eng.submit(tok.encode("x", add_bos=True), grammar=g,
@@ -549,16 +524,19 @@ class TestCompiledDFA:
         assert parsed["DestinationKind"] in KINDS
         assert res.completion_tokens <= budget
 
-    def test_paged_engine_chunked_scan_matches_stepwise(self):
-        """The DFA scan also runs on the PAGED engine (chunk bounded by
-        page boundaries): chunked greedy output == stepwise output."""
+    @pytest.mark.parametrize("page_size", [16, 8])
+    def test_engine_chunked_scan_matches_stepwise(self, page_size):
+        """The DFA rides inside the decode scan (chunk bounded by the
+        slot's allocated pages): chunked greedy output == per-tick
+        host-FSM output, and both parse + respect enums."""
         outs = {}
         tok = get_tokenizer()
         cfg = TINY.replace(max_seq_len=512)
         params = llama.init_params(cfg, jax.random.PRNGKey(0))
         for chunk in (1, 8):
-            ecfg = EngineConfig(max_batch=2, max_seq_len=512, paged=True,
-                                page_size=16, num_pages=80,
+            ecfg = EngineConfig(max_batch=2, max_seq_len=512,
+                                page_size=page_size,
+                                num_pages=1280 // page_size,
                                 prefill_buckets=(32,), max_new_tokens=256,
                                 temperature=0.0, decode_chunk=chunk)
             eng = PagedInferenceEngine(cfg, ecfg, params, tok,
@@ -585,7 +563,7 @@ class TestCompiledDFA:
         cfg = TINY.replace(max_seq_len=256)
         params = llama.init_params(cfg, jax.random.PRNGKey(0))
         for chunk in (1, 16):
-            ecfg = EngineConfig(max_batch=2, max_seq_len=256, paged=True,
+            ecfg = EngineConfig(max_batch=2, max_seq_len=256,
                                 page_size=4, num_pages=140,
                                 prefill_buckets=(32,), max_new_tokens=48,
                                 temperature=0.0, decode_chunk=chunk)
@@ -708,7 +686,6 @@ def test_choice_engine_scan_emits_one_option_exactly():
     import jax
 
     from k8s_llm_rca_tpu.config import TINY, EngineConfig
-    from k8s_llm_rca_tpu.engine import InferenceEngine
     from k8s_llm_rca_tpu.models import llama
 
     cfg = TINY
@@ -719,7 +696,7 @@ def test_choice_engine_scan_emits_one_option_exactly():
         "MATCH (pod:Pod)-[r1:HasEvent]->(evt:EVENT)\nRETURN pod, r1, evt"]}
     outs = {}
     for chunk in (1, 8):
-        eng = InferenceEngine(
+        eng = PagedInferenceEngine(
             cfg, EngineConfig(max_batch=2, max_seq_len=256,
                               prefill_buckets=(16,), max_new_tokens=128,
                               decode_chunk=chunk), params, tok)
@@ -822,7 +799,6 @@ def test_json_grammar_compiles_to_dfa_and_scan_parity():
     import json as jsonlib
 
     from k8s_llm_rca_tpu.config import TINY, EngineConfig
-    from k8s_llm_rca_tpu.engine import InferenceEngine
     from k8s_llm_rca_tpu.engine.constrain import DFAGrammar
     from k8s_llm_rca_tpu.models import llama
 
@@ -831,7 +807,7 @@ def test_json_grammar_compiles_to_dfa_and_scan_parity():
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
     outs = {}
     for chunk in (1, 8):
-        eng = InferenceEngine(
+        eng = PagedInferenceEngine(
             cfg, EngineConfig(max_batch=2, max_seq_len=256,
                               prefill_buckets=(16,), max_new_tokens=64,
                               decode_chunk=chunk), params, tok)

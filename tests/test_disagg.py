@@ -630,7 +630,7 @@ def _small_pair(overrides):
     cfg = TINY.replace(max_seq_len=64)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    knobs = dict(max_batch=2, max_seq_len=64, paged=True,
+    knobs = dict(max_batch=2, max_seq_len=64,
                  page_size=8, num_pages=24, prefill_buckets=(16, 32),
                  max_new_tokens=8, temperature=0.0, decode_chunk=1,
                  prefix_cache=False)
@@ -768,7 +768,7 @@ class TestDisaggEngineParity:
         cfg = TINY.replace(max_seq_len=2560)
         ecfg = EngineConfig(max_batch=4, max_seq_len=2560,
                             prefill_buckets=(2560,), max_new_tokens=96,
-                            temperature=0.0, paged=True, page_size=64,
+                            temperature=0.0, page_size=64,
                             num_pages=168, prefix_cache=False,
                             decode_chunk=16)
         tok = get_tokenizer(vocab_size=cfg.vocab_size)

@@ -21,7 +21,7 @@ import jax
 import numpy as np
 
 from k8s_llm_rca_tpu.config import TINY, EngineConfig, MeshConfig, RCAConfig
-from k8s_llm_rca_tpu.engine.engine import InferenceEngine
+from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
 from k8s_llm_rca_tpu.graph import InMemoryGraphExecutor
 from k8s_llm_rca_tpu.graph.fixtures import (
     INCIDENTS, build_metagraph, build_stategraph,
@@ -67,7 +67,7 @@ def test_distill_oracle_into_tiny_end_to_end(tmp_path, cpu_devices):
                         prefill_buckets=(256, 512, 1024),
                         max_new_tokens=256, temperature=0.0,
                         decode_chunk=16)
-    clamp_eng = InferenceEngine(
+    clamp_eng = PagedInferenceEngine(
         cfg, ecfg, llama.init_params(cfg, jax.random.PRNGKey(0)), bpe)
     rows, masks = build_rows(pairs, bpe, clamp_eng._clamp_prompt, 1024)
 
@@ -87,7 +87,7 @@ def test_distill_oracle_into_tiny_end_to_end(tmp_path, cpu_devices):
     served = load_llama(cfg, str(tmp_path / "model.safetensors"))
 
     # 6. serve through the real engine, grammars OFF
-    engine = InferenceEngine(cfg, ecfg, served, bpe)
+    engine = PagedInferenceEngine(cfg, ecfg, served, bpe)
     pipeline = RCAPipeline(
         AssistantService(EngineBackend(engine)),
         InMemoryGraphExecutor(build_metagraph()),

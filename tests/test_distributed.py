@@ -42,8 +42,8 @@ def test_two_process_cluster_psum_train_and_serve():
     """Coordinator (process 0) + worker (process 1) form a cluster via
     initialize_distributed; each asserts the global device view, runs a
     cross-process psum, a DP×TP train step whose gradient reductions
-    cross the process boundary, and then SERVES: both engines (contiguous
-    + paged) prefill and decode over the process-spanning TP mesh, every
+    cross the process boundary, and then SERVES: the engine (at two
+    page sizes) prefills and decodes over the process-spanning TP mesh, every
     tick's collectives crossing the process boundary.  Both processes
     must exit 0 with matching losses, matching served tokens, and the
     served tokens must equal a SINGLE-process unsharded engine's greedy
@@ -76,8 +76,8 @@ def test_two_process_cluster_psum_train_and_serve():
                 for line in out.splitlines() if "serve[" in line}
 
     served = [serve_lines(o) for o in outs]
-    assert set(served[0]) == {"contig/batch", "contig/single",
-                              "paged/batch", "paged/single"}, served[0]
+    assert set(served[0]) == {"page8/batch", "page8/single",
+                              "page16/batch", "page16/single"}, served[0]
     assert served[0] == served[1], (served[0], served[1])
 
     # ... and match the single-process unsharded engines exactly — the
@@ -88,9 +88,8 @@ def test_two_process_cluster_psum_train_and_serve():
 
     import _distributed_serve_config as serve_cfg
 
-    def _make_plain(cfg, params, tok, ecfg, paged):
-        kw = dict(use_kernel=False) if paged else {}
-        return make_engine(cfg, ecfg, params, tok, **kw)
+    def _make_plain(cfg, params, tok, ecfg):
+        return make_engine(cfg, ecfg, params, tok, use_kernel=False)
 
     want = serve_cfg.serve_all(_make_plain)
     assert served[0] == want, (served[0], want)

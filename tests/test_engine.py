@@ -11,9 +11,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import reference_greedy
 from k8s_llm_rca_tpu.config import TINY, EngineConfig
-from k8s_llm_rca_tpu.engine import InferenceEngine
-from k8s_llm_rca_tpu.engine.engine import decode_scan
+from k8s_llm_rca_tpu.engine import make_engine as build_engine
 from k8s_llm_rca_tpu.engine.sampling import SamplingParams, sample_tokens
 from k8s_llm_rca_tpu.models import llama
 from k8s_llm_rca_tpu.utils import get_tokenizer
@@ -28,26 +28,17 @@ def setup():
 
 
 def make_engine(cfg, params, tok, **over):
-    ecfg = EngineConfig(max_batch=4, max_seq_len=128,
-                        prefill_buckets=(16, 32, 64), max_new_tokens=16, **over)
-    return InferenceEngine(cfg, ecfg, params, tok)
+    defaults = dict(max_batch=4, max_seq_len=128,
+                    prefill_buckets=(16, 32, 64), max_new_tokens=16,
+                    page_size=16, num_pages=40)
+    defaults.update(over)
+    return build_engine(cfg, EngineConfig(**defaults), params, tok,
+                        use_kernel=False)
 
 
 def ref_greedy(cfg, params, prompt_ids, n_new):
     """Direct model loop: the ground truth the engine must reproduce."""
-    cache = llama.init_cache(cfg, 1, 128)
-    n = len(prompt_ids)
-    padded = jnp.zeros((1, 32), jnp.int32).at[0, :n].set(jnp.array(prompt_ids))
-    cache, logits = llama.prefill(cfg, params, cache, padded,
-                                  jnp.int32(n), jnp.int32(0))
-    out = [int(jnp.argmax(logits[0]))]
-    lengths = jnp.array([n], jnp.int32)
-    for _ in range(n_new - 1):
-        cache, logits = llama.decode_step(
-            cfg, params, cache, jnp.array([out[-1]], jnp.int32), lengths)
-        out.append(int(jnp.argmax(logits[0])))
-        lengths = lengths + 1
-    return out
+    return reference_greedy(cfg, params, prompt_ids, n_new, max_seq_len=128)
 
 
 def test_engine_matches_direct_decode(setup):
@@ -101,24 +92,15 @@ def test_stop_string(setup):
     assert free.text.startswith(res.text)
 
 
-def test_decode_scan_matches_step_loop(setup):
+@pytest.mark.parametrize("decode_chunk", [1, 8])
+def test_decode_scan_matches_step_loop(setup, decode_chunk):
+    """The on-device scan (8 steps a dispatch) and the stepwise tick
+    both give the model's own greedy tokens."""
     cfg, params, tok = setup
     prompt = tok.encode("MountVolume.SetUp failed", add_bos=True)
-    want = ref_greedy(cfg, params, prompt, 9)
-
-    cache = llama.init_cache(cfg, 2, 128)
-    n = len(prompt)
-    padded = jnp.zeros((1, 32), jnp.int32).at[0, :n].set(jnp.array(prompt))
-    cache, logits = llama.prefill(cfg, params, cache, padded,
-                                  jnp.int32(n), jnp.int32(0))
-    first = int(jnp.argmax(logits[0]))
-    cur = jnp.array([first, 0], jnp.int32)
-    lengths = jnp.array([n, 0], jnp.int32)
-    cache, toks, lengths = decode_scan(
-        cfg, params, cache, cur, lengths, jax.random.PRNGKey(0), 8,
-        SamplingParams(), eos_id=tok.eos_id)
-    got = [first] + [int(t) for t in np.asarray(toks)[:, 0]]
-    assert got == want
+    engine = make_engine(cfg, params, tok, decode_chunk=decode_chunk)
+    [res] = engine.generate([prompt], max_new_tokens=9)
+    assert res.token_ids == ref_greedy(cfg, params, prompt, 9)
 
 
 def test_sampling_modes():
@@ -174,7 +156,7 @@ def test_engine_runs_moe_model():
     cfg = TINY_MOE.replace(max_seq_len=64)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    eng = InferenceEngine(
+    eng = build_engine(
         cfg, EngineConfig(max_batch=2, max_seq_len=64,
                           prefill_buckets=(16, 32, 64), max_new_tokens=6,
                           temperature=0.0), params, tok)
@@ -198,7 +180,7 @@ def test_batched_admission_matches_serial(setup):
         ecfg = EngineConfig(max_batch=4, max_seq_len=128,
                             prefill_buckets=(32, 64, 128),
                             max_new_tokens=8, temperature=0.0)
-        eng = InferenceEngine(cfg, ecfg, params, tok)
+        eng = build_engine(cfg, ecfg, params, tok)
         eng._batch_admission = batch_admission
         out = eng.generate([list(p) for p in prompts], max_new_tokens=8)
         return [(r.token_ids, r.finish_reason) for r in out]
@@ -220,7 +202,7 @@ def test_batched_admission_with_grammar_and_quantized_cache(setup):
     ecfg = EngineConfig(max_batch=4, max_seq_len=128,
                         prefill_buckets=(32, 64, 128), max_new_tokens=16,
                         temperature=0.0, kv_cache_dtype="int8")
-    eng = InferenceEngine(cfg, ecfg, params, tok)
+    eng = build_engine(cfg, ecfg, params, tok)
     ids = []
     for _ in range(3):
         g = make_grammar("json", tok, prefer_native=False)
@@ -244,8 +226,9 @@ def test_prompt_admission_forces_stepwise_while_queued(setup):
         ecfg = EngineConfig(max_batch=1, max_seq_len=128,
                             prefill_buckets=(32,), max_new_tokens=12,
                             temperature=0.0, decode_chunk=8,
+                            page_size=32,   # one page holds prompt + 2 scans
                             prompt_admission=prompt_admission)
-        eng = InferenceEngine(cfg, ecfg, params, tok)
+        eng = build_engine(cfg, ecfg, params, tok)
         for p in prompts:
             # budget 12 > decode_chunk 8, so one chunked scan cannot
             # retire the active sequence mid-assert
@@ -264,3 +247,32 @@ def test_prompt_admission_forces_stepwise_while_queued(setup):
     res2 = eng2.run_to_completion()
     for a, b in zip(res, res2):
         assert a.token_ids == b.token_ids  # knob changes latency, not output
+
+
+@pytest.mark.parametrize("door", ["make_engine", "export", "flag"])
+def test_the_engine_switch_is_gone(setup, door, capsys):
+    """One engine: ``paged=False`` is refused by name of what was removed,
+    the package exports no second engine, and the sweeps take no
+    ``--paged``."""
+    import k8s_llm_rca_tpu.engine as engine_pkg
+
+    cfg, params, tok = setup
+    if door == "make_engine":
+        with pytest.raises(ValueError, match="contiguous-slot engine was "
+                                             "removed"):
+            build_engine(cfg, EngineConfig(paged=False), params, tok)
+    elif door == "export":
+        import k8s_llm_rca_tpu.engine.engine as engine_mod
+
+        for mod in (engine_pkg, engine_mod):
+            assert not hasattr(mod, "InferenceEngine")
+        for gone in ("decode_scan", "decode_scan_dfa", "overlap_step"):
+            assert not hasattr(engine_mod, gone)
+    else:
+        from k8s_llm_rca_tpu.sweeps import run_file
+
+        with pytest.raises(SystemExit) as e:
+            run_file.main(["--backend", "engine", "--model", "tiny",
+                           "--paged"])
+        assert e.value.code == 2
+        assert "unrecognized arguments: --paged" in capsys.readouterr().err

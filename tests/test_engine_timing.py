@@ -29,7 +29,10 @@ from k8s_llm_rca_tpu.utils.logging import METRICS, Metrics
 from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
 
 CFG = TINY.replace(max_seq_len=64)
-ENGINES = ["contiguous", "paged"]
+# the same engine at two page sizes: nothing a request's stamps or the
+# work counters say may depend on how the pool is cut
+ENGINES = {"page8": dict(page_size=8, num_pages=40),
+           "page16": dict(page_size=16, num_pages=20)}
 NEW_SPANS = {"engine.tick", "engine.fetch", "engine.grammar_mask",
              "engine.commit", "engine.request"}
 
@@ -40,16 +43,14 @@ def model():
             get_tokenizer(vocab_size=CFG.vocab_size))
 
 
-def build(model, kind, **over):
+def build(model, kind="page8", **over):
     params, tok = model
     kw = dict(max_batch=4, max_seq_len=64, prefill_buckets=(16, 32),
-              temperature=0.0, decode_chunk=4)
-    extra = {}
-    if kind == "paged":
-        kw.update(paged=True, page_size=8, num_pages=40, prefix_cache=False)
-        extra["use_kernel"] = False
+              temperature=0.0, decode_chunk=4, prefix_cache=False,
+              **ENGINES[kind])
     kw.update(over)
-    eng = make_engine(CFG, EngineConfig(**kw), params, tok, **extra)
+    eng = make_engine(CFG, EngineConfig(**kw), params, tok,
+                      use_kernel=False)
     eng.clock = VirtualClock()
     return eng
 
@@ -111,7 +112,7 @@ class TestLifecycle:
             assert snap[f"{name}.count"] == 1.0
 
     def test_single_token_request_observes_no_tpot(self, engines, counters):
-        eng = engines["paged"]
+        eng = engines["page8"]
         eng.clock = VirtualClock()
         (res,) = eng.generate([[1, 2, 3]], max_new_tokens=1)
         assert res.timing.t_first == res.timing.t_last
@@ -120,7 +121,7 @@ class TestLifecycle:
         assert "engine.tpot.count" not in snap
 
     def test_chunked_admission_stamps_the_slot_grant(self, model, counters):
-        eng = build(model, "paged", prefill_chunk_budget=8)
+        eng = build(model, prefill_chunk_budget=8)
         clock = eng.clock
         eng.submit(list(range(1, 21)), max_new_tokens=2)    # 3 chunks of 8
         stamps = []
@@ -133,7 +134,7 @@ class TestLifecycle:
         assert counters.count("engine.prefill_chunks") == 3
 
     def test_preempted_sequence_keeps_its_record(self, engines, counters):
-        eng = engines["paged"]
+        eng = engines["page8"]
         clock = eng.clock = VirtualClock()
         sid = eng.submit([1, 2, 3, 4, 5], max_new_tokens=13)
         clock.sleep(1.0)
@@ -155,7 +156,7 @@ class TestLifecycle:
         assert counters.count("engine.preemptions") == 1
 
     def test_request_span_under_a_tracer(self, engines, counters):
-        eng = engines["contiguous"]
+        eng = engines["page16"]
         clock = eng.clock = VirtualClock()
         tr = Tracer(clock=clock)
         with obs_trace.tracing(tr):
@@ -190,12 +191,12 @@ class TestWorkCounters:
         assert sum(scan_limits(snap).values()) == len(chunks)
 
     def test_stepwise_and_speculative_steps(self, model, counters):
-        eng = build(model, "contiguous", decode_chunk=1)
+        eng = build(model, decode_chunk=1)
         eng.generate([[1, 2, 3]], max_new_tokens=5)
         assert counters.count("engine.decode_steps") == 4
         assert scan_limits(counters.snapshot()) == {"full": 4.0}
         counters.reset()
-        eng = build(model, "paged", decode_chunk=1, speculative_k=2)
+        eng = build(model, decode_chunk=1, speculative_k=2)
         eng.generate([[1, 2, 3, 1, 2, 3, 1, 2]], max_new_tokens=6)
         snap = counters.snapshot()
         # a verify dispatch scores k + 1 positions
@@ -210,23 +211,23 @@ class TestWorkCounters:
         prompts = [[1, 2, 3, 4, 5]]
         new, grammar = 12, None
         if reason == "full":
-            eng = build(model, "paged")
+            eng = build(model)
         elif reason == "pages":
             # three sequences in a 9-page pool: the lookahead pages of a
             # 16-step scan are not to be had
-            eng = build(model, "paged", max_batch=3, num_pages=9,
+            eng = build(model, max_batch=3, num_pages=9,
                         decode_chunk=16)
             prompts = [list(range(1, 14)) + [t] for t in (20, 21, 22)]
         elif reason == "headroom":
             # 40 + 17 + 1 of 64 positions taken: 6 are left for a scan of 8
-            eng = build(model, "contiguous", decode_chunk=8,
+            eng = build(model, decode_chunk=8,
                         prefill_buckets=(16, 64))
             prompts, new = [list(range(1, 41))], 23
         elif reason == "grammar":
-            eng = build(model, "paged")
+            eng = build(model)
             grammar = JsonGrammar(eng.tokenizer)       # interpreted FSM
         else:
-            eng = build(model, "contiguous", max_batch=1,
+            eng = build(model, max_batch=1,
                         prompt_admission=True)
             prompts = [[1, 2, 3], [4, 5, 6]]
         chunks = spy_chunks(eng, monkeypatch)
@@ -238,7 +239,7 @@ class TestWorkCounters:
         assert sum(limits.values()) == len(chunks)
 
     def test_attention_pages_hand_count(self, engines, counters):
-        eng = engines["paged"]
+        eng = engines["page8"]
         eng.submit([1] * 5, max_new_tokens=4)
         eng.submit([1] * 17, max_new_tokens=4)
         eng.step()      # both admitted; one scan of 4 steps at lengths 5, 17
@@ -274,7 +275,7 @@ class TestWorkCounters:
 
 class TestSeam:
     def test_fetch_of_host_arrays_opens_no_span(self, engines, counters):
-        eng = engines["paged"]
+        eng = engines["page8"]
         (host,) = eng._fetch(np.arange(3))
         assert host.tolist() == [0, 1, 2]
         snap = counters.snapshot()
@@ -318,7 +319,7 @@ class TestSeam:
         assert (snap["u.count"], snap["u.total_s"]) == (1.0, 0.5)
 
     def test_new_names_are_registered_and_emitted(self, model, counters):
-        eng = build(model, "paged", decode_chunk=1)
+        eng = build(model, decode_chunk=1)
         tr = Tracer(clock=eng.clock)
         with obs_trace.tracing(tr):
             eng.submit([1, 2, 3], max_new_tokens=6,
@@ -345,33 +346,23 @@ class TestSeam:
 
 # attribute -> the function whose name its program carries
 PROGRAMS = {
-    "contiguous": {
-        "_prefill": "prefill", "_prefill_batch": "prefill_batch",
-        "_decode": "decode_step", "_overlap_decode": "overlap_step",
-        "_decode_scan": "decode_scan", "_decode_scan_dfa": "decode_scan_dfa",
-        "_decode_multi": "_verify_step", "_sample": "sample_tokens",
-        "_sample_masked": "sample_tokens_masked",
-        "_spec_dfa_greedy": "dfa_greedy_multi"},
-    "paged": {
-        "_prefill": "paged_prefill", "_prefill_batch": "paged_prefill_batch",
-        "_prefill_chunk": "paged_prefill_chunk",
-        "_prefill_chunk_batch": "paged_prefill_chunk_batch",
-        "_decode": "paged_decode_step",
-        "_overlap_decode": "paged_overlap_step",
-        "_decode_scan": "paged_decode_scan",
-        "_decode_scan_dfa": "paged_decode_scan_dfa",
-        "_decode_multi": "paged_decode_multi", "_sample": "sample_tokens",
-        "_sample_masked": "sample_tokens_masked",
-        "_spec_dfa_greedy": "dfa_greedy_multi"},
-}
+    "_prefill": "paged_prefill", "_prefill_batch": "paged_prefill_batch",
+    "_prefill_chunk": "paged_prefill_chunk",
+    "_prefill_chunk_batch": "paged_prefill_chunk_batch",
+    "_decode": "paged_decode_step",
+    "_overlap_decode": "paged_overlap_step",
+    "_decode_scan": "paged_decode_scan",
+    "_decode_scan_dfa": "paged_decode_scan_dfa",
+    "_decode_multi": "paged_decode_multi", "_sample": "sample_tokens",
+    "_sample_masked": "sample_tokens_masked",
+    "_spec_dfa_greedy": "dfa_greedy_multi"}
 
 
 class TestProgramNames:
     @pytest.mark.parametrize("kind", ENGINES)
     def test_lowered_programs_carry_their_function_name(self, model,
                                                         monkeypatch, kind):
-        eng = build(model, kind, **({"prefix_cache": True}
-                                    if kind == "paged" else {}))
+        eng = build(model, kind, prefix_cache=True)
         heads = {}
 
         def recording(attr, jitted):
@@ -382,7 +373,7 @@ class TestProgramNames:
                 return jitted(*args, **kw)
             return call
 
-        for attr, fn_name in PROGRAMS[kind].items():
+        for attr, fn_name in PROGRAMS.items():
             jitted = getattr(eng, attr)
             assert jitted.__name__ == fn_name, attr
             monkeypatch.setattr(eng, attr, recording(attr, jitted))
@@ -395,15 +386,11 @@ class TestProgramNames:
         eng.submit([1, 2], max_new_tokens=6,
                    grammar=make_grammar("json", eng.tokenizer))
         eng.run_to_completion()        # compiled tables ride the scan
-        want = {"contiguous": {"_prefill", "_prefill_batch", "_decode",
-                               "_decode_scan", "_decode_scan_dfa",
-                               "_sample"},
-                "paged": {"_prefill", "_prefill_batch", "_prefill_chunk",
-                          "_decode", "_decode_scan", "_decode_scan_dfa",
-                          "_sample"}}[kind]
+        want = {"_prefill", "_prefill_batch", "_prefill_chunk", "_decode",
+                "_decode_scan", "_decode_scan_dfa", "_sample"}
         assert want <= set(heads), sorted(heads)
         for attr, head in heads.items():
-            assert head == f"HloModule jit_{PROGRAMS[kind][attr]}", attr
+            assert head == f"HloModule jit_{PROGRAMS[attr]}", attr
 
     def test_named_partial_keeps_name_and_binds_keywords(self):
         def scaled(x, scale=1.0):
@@ -440,7 +427,7 @@ class TestServePassThrough:
         return run
 
     def test_timing_reaches_the_run_and_its_span(self, engines, counters):
-        eng = engines["paged"]
+        eng = engines["page8"]
         clock = eng.clock = VirtualClock()
         service, a = self._service(eng, clock)
         tr = Tracer(clock=clock)
@@ -457,7 +444,7 @@ class TestServePassThrough:
 
     def test_critical_path_reads_the_requests_own_stamps(self, engines,
                                                          counters):
-        eng = engines["paged"]
+        eng = engines["page8"]
         clock = eng.clock = VirtualClock()
         service, a = self._service(eng, clock)
         tr = Tracer(clock=clock)
@@ -503,15 +490,13 @@ PARENT_SAMPLED = [
 
 
 class TestTokensUnchanged:
-    @pytest.mark.parametrize("kind", ENGINES)
+    @pytest.mark.parametrize("host_overlap", [False, True])
     @pytest.mark.parametrize("temperature,want", [
         pytest.param(0.0, PARENT_GREEDY, id="greedy"),
         pytest.param(30.0, PARENT_SAMPLED, id="sampled")])
-    def test_seeded_run_matches_the_parent(self, model, kind, temperature,
-                                           want):
-        over = dict(temperature=temperature, seed=3)
-        if kind == "paged":
-            over["num_pages"] = 24
-        eng = build(model, kind, **over)
+    def test_seeded_run_matches_the_parent(self, model, host_overlap,
+                                           temperature, want):
+        eng = build(model, temperature=temperature, seed=3, num_pages=24,
+                    host_overlap=host_overlap)
         out = eng.generate(PROMPTS, max_new_tokens=12)
         assert [r.token_ids for r in out] == want

@@ -49,7 +49,7 @@ def shared_engine():
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
     eng = make_engine(
-        cfg, EngineConfig(max_batch=4, max_seq_len=64, paged=True,
+        cfg, EngineConfig(max_batch=4, max_seq_len=64,
                           page_size=8, num_pages=24,
                           prefill_buckets=(16, 32), max_new_tokens=8,
                           temperature=0.0, decode_chunk=1,

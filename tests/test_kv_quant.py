@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from k8s_llm_rca_tpu.config import TINY, EngineConfig
-from k8s_llm_rca_tpu.engine.engine import InferenceEngine
+from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
 from k8s_llm_rca_tpu.models import llama
 from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
 
@@ -51,7 +51,7 @@ def test_engine_with_int8_kv_cache():
     cfg = TINY.replace(max_seq_len=64)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    eng = InferenceEngine(
+    eng = PagedInferenceEngine(
         cfg, EngineConfig(max_batch=2, max_seq_len=64,
                           prefill_buckets=(16, 32, 64), max_new_tokens=6,
                           temperature=0.0, kv_cache_dtype="int8"),
@@ -60,7 +60,7 @@ def test_engine_with_int8_kv_cache():
                         tok.encode("pvc pending", add_bos=True)],
                        max_new_tokens=6)
     assert all(r.completion_tokens == 6 for r in res)
-    assert eng.cache.quantized
+    assert eng.pool.quantized
 
 
 def test_int4_cache_correlates_with_full_precision():
@@ -94,7 +94,7 @@ def test_engine_with_int4_kv_cache():
     cfg = TINY.replace(max_seq_len=64)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    eng = InferenceEngine(
+    eng = PagedInferenceEngine(
         cfg, EngineConfig(max_batch=2, max_seq_len=64,
                           prefill_buckets=(16, 32, 64), max_new_tokens=6,
                           temperature=0.0, kv_cache_dtype="int4"),
@@ -103,14 +103,14 @@ def test_engine_with_int4_kv_cache():
                         tok.encode("pvc pending", add_bos=True)],
                        max_new_tokens=6)
     assert all(r.completion_tokens == 6 for r in res)
-    assert eng.cache.quantized and eng.cache.k.shape[-1] == cfg.kv_dim // 2
+    assert eng.pool.quantized and eng.pool.k.shape[-1] == cfg.kv_dim // 2
 
 
 def test_int4_cache_speculative_tick_runs():
     cfg = TINY.replace(max_seq_len=128)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    eng = InferenceEngine(
+    eng = PagedInferenceEngine(
         cfg, EngineConfig(max_batch=1, max_seq_len=128,
                           prefill_buckets=(32, 64, 128), max_new_tokens=12,
                           temperature=0.0, kv_cache_dtype="int4",
@@ -126,7 +126,7 @@ def test_int8_cache_speculative_tick_runs():
     cfg = TINY.replace(max_seq_len=128)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    eng = InferenceEngine(
+    eng = PagedInferenceEngine(
         cfg, EngineConfig(max_batch=1, max_seq_len=128,
                           prefill_buckets=(32, 64, 128), max_new_tokens=12,
                           temperature=0.0, kv_cache_dtype="int8",

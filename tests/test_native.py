@@ -157,7 +157,7 @@ class TestNativeGrammar:
         import jax
 
         from k8s_llm_rca_tpu.config import TINY, EngineConfig
-        from k8s_llm_rca_tpu.engine.engine import InferenceEngine
+        from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
         from k8s_llm_rca_tpu.models import llama
 
         cfg = TINY.replace(max_seq_len=256)
@@ -168,7 +168,7 @@ class TestNativeGrammar:
         outs = {}
         for name, grammar_cls in (("py", JsonGrammar),
                                   ("cc", native.NativeJsonGrammar)):
-            eng = InferenceEngine(cfg, ecfg, params, tok)
+            eng = PagedInferenceEngine(cfg, ecfg, params, tok)
             seq = eng.submit(tok.encode("emit json", add_bos=True),
                              grammar=grammar_cls(tok))
             (res,) = eng.run_to_completion()

@@ -50,7 +50,7 @@ def small_engine():
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
     eng = make_engine(
-        cfg, EngineConfig(max_batch=4, max_seq_len=64, paged=True,
+        cfg, EngineConfig(max_batch=4, max_seq_len=64,
                           page_size=8, num_pages=24,
                           prefill_buckets=(16, 32), max_new_tokens=8,
                           temperature=0.0, decode_chunk=1,
@@ -649,7 +649,7 @@ class TestSiteCoverage:
         tracers.append(tr_spill)
         spill_eng = make_engine(
             TINY.replace(max_seq_len=64),
-            EngineConfig(max_batch=2, max_seq_len=64, paged=True,
+            EngineConfig(max_batch=2, max_seq_len=64,
                          page_size=8, num_pages=24,
                          prefill_buckets=(16, 32), max_new_tokens=8,
                          temperature=0.0, decode_chunk=1,
@@ -705,7 +705,7 @@ class TestSiteCoverage:
         tracers.append(tr_tier)
         tier_eng = make_engine(
             TINY.replace(max_seq_len=64),
-            EngineConfig(max_batch=2, max_seq_len=64, paged=True,
+            EngineConfig(max_batch=2, max_seq_len=64,
                          page_size=8, num_pages=24,
                          prefill_buckets=(16, 32), max_new_tokens=4,
                          temperature=0.0, prefix_cache=True,

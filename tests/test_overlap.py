@@ -30,12 +30,10 @@ def setup():
     return cfg, params, tok
 
 
-def _ecfg(paged, **over):
+def _ecfg(**over):
     base = dict(max_batch=4, max_seq_len=128, prefill_buckets=(16, 32, 64),
-                max_new_tokens=12, temperature=0.0, decode_chunk=1)
-    if paged:
-        base.update(paged=True, page_size=16, num_pages=96,
-                    prefix_cache=False)
+                max_new_tokens=12, temperature=0.0, decode_chunk=1,
+                page_size=16, num_pages=96, prefix_cache=False)
     base.update(over)
     return EngineConfig(**base)
 
@@ -47,19 +45,18 @@ def _prompts(tok):
              "exceeded quota: pods=50", "hello")]
 
 
-def _run(cfg, params, tok, ecfg, prompts, grammars=(), **kw):
+def _run(cfg, params, tok, ecfg, prompts, grammars=()):
     """Generate the mixed workload; returns ([token_ids...], counters).
     ``grammars`` entries are (prompt, grammar_factory) appended to the
     plain prompts so constrained and unconstrained slots share ticks."""
-    eng = make_engine(cfg, ecfg, params, tok, **kw)
+    eng = make_engine(cfg, ecfg, params, tok, use_kernel=False)
     ids = [eng.submit(list(p), max_new_tokens=ecfg.max_new_tokens)
            for p in prompts]
     for p, gf in grammars:
         ids.append(eng.submit(list(p), max_new_tokens=ecfg.max_new_tokens,
                               grammar=gf()))
     res = {r.seq_id: r for r in eng.run_to_completion()}
-    if hasattr(eng, "allocator"):
-        eng.allocator.check()
+    eng.allocator.check()
     return ([(res[i].token_ids, res[i].finish_reason) for i in ids],
             dict(eng._counts))
 
@@ -70,11 +67,12 @@ def _run(cfg, params, tok, ecfg, prompts, grammars=(), **kw):
 
 
 class TestOverlapParity:
-    @pytest.mark.parametrize("paged", [False, True])
+    @pytest.mark.parametrize("kv_cache_dtype", [None, "int8"])
     @pytest.mark.parametrize("chunk,spec_k", [(1, 0), (8, 0), (1, 3)])
-    def test_matrix_matches_plain(self, setup, paged, chunk, spec_k):
-        """contiguous + paged × stepwise/scan × n-gram speculation, with
-        a DFA grammar slot and an interpreted python-FSM grammar slot
+    def test_matrix_matches_plain(self, setup, kv_cache_dtype, chunk,
+                                  spec_k):
+        """model-dtype + int8 pool × stepwise/scan × n-gram speculation,
+        with a DFA grammar slot and an interpreted python-FSM grammar slot
         sharing the batch with plain slots: byte parity, same finish
         reasons."""
         cfg, params, tok = setup
@@ -85,12 +83,12 @@ class TestOverlapParity:
                   lambda: SchemaGrammar({"type": "choice", "options": [
                       "verdict: missing secret",
                       "checked: node pressure"]}, tok))]
-        ecfg = _ecfg(paged, decode_chunk=chunk, speculative_k=spec_k)
-        kw = dict(use_kernel=False) if paged else {}
-        plain, _ = _run(cfg, params, tok, ecfg, prompts, gspec, **kw)
+        ecfg = _ecfg(kv_cache_dtype=kv_cache_dtype, decode_chunk=chunk,
+                     speculative_k=spec_k)
+        plain, _ = _run(cfg, params, tok, ecfg, prompts, gspec)
         over, _ = _run(cfg, params, tok,
                        dataclasses.replace(ecfg, host_overlap=True),
-                       prompts, gspec, **kw)
+                       prompts, gspec)
         assert plain == over
 
     def test_prefix_cache_hit_and_miss_admissions(self, setup):
@@ -101,7 +99,7 @@ class TestOverlapParity:
         prompts = _prompts(tok)[:4]
 
         def run(overlap):
-            ecfg = _ecfg(True, prefix_cache=True, host_overlap=overlap)
+            ecfg = _ecfg(prefix_cache=True, host_overlap=overlap)
             eng = make_engine(cfg, ecfg, params, tok, use_kernel=False)
             first = eng.generate([list(p) for p in prompts],
                                  max_new_tokens=12)
@@ -115,43 +113,43 @@ class TestOverlapParity:
         assert plain == over
         assert over_hits == plain_hits and over_hits > 0
 
-    @pytest.mark.parametrize("paged", [False, True])
-    def test_model_draft_matches_plain(self, setup, paged):
+    @pytest.mark.parametrize("page_size", [16, 8])
+    def test_model_draft_matches_plain(self, setup, page_size):
         """Draft-MODEL speculation under overlap: the draft scan's
         blocking token fetch stays accounted and greedy output is byte-
         identical to the non-overlapped speculative engine."""
         cfg, params, tok = setup
         prompts = _prompts(tok)[:3]
-        ecfg = _ecfg(paged, speculative_k=3, max_batch=2)
-        kw = dict(use_kernel=False) if paged else {}
+        ecfg = _ecfg(page_size=page_size, speculative_k=3, max_batch=2)
 
         def run(overlap):
             eng = make_engine(
                 cfg, dataclasses.replace(ecfg, host_overlap=overlap),
-                params, tok, draft_model=(cfg, params), **kw)
+                params, tok, draft_model=(cfg, params), use_kernel=False)
             return [r.token_ids for r in
                     eng.generate([list(p) for p in prompts],
                                  max_new_tokens=12)]
 
         assert run(False) == run(True)
 
-    @pytest.mark.parametrize("paged", [False, True])
-    def test_stop_strings_truncate_identically(self, setup, paged):
+    @pytest.mark.parametrize("page_size", [16, 4])
+    def test_stop_strings_truncate_identically(self, setup, page_size):
         """Stop-string slots ride the lagged commit (post-hoc truncation
         at flush, like the chunked scan): same text, same finish reason,
-        no sync fallback required."""
+        no sync fallback required.  At page 4 the lagged ticks cross
+        page boundaries, so growth has to cover the device's length."""
         cfg, params, tok = setup
         prompt = tok.encode("hello", add_bos=True)
-        ecfg = _ecfg(paged)
-        kw = dict(use_kernel=False) if paged else {}
-        free = make_engine(cfg, ecfg, params, tok, **kw).generate(
+        ecfg = _ecfg(page_size=page_size)
+        free = make_engine(cfg, ecfg, params, tok,
+                           use_kernel=False).generate(
             [list(prompt)], max_new_tokens=12)[0]
         stop = free.text[2:5]
 
         def run(overlap):
             eng = make_engine(
                 cfg, dataclasses.replace(ecfg, host_overlap=overlap),
-                params, tok, **kw)
+                params, tok, use_kernel=False)
             return eng.generate([list(prompt)], max_new_tokens=12,
                                 stop_strings=(stop,))[0]
 
@@ -166,7 +164,7 @@ class TestOverlapParity:
         prefix view and the restored run finishes byte-identically."""
         cfg, params, tok = setup
         prompts = _prompts(tok)[:2]
-        ecfg = _ecfg(True, host_overlap=True)
+        ecfg = _ecfg(host_overlap=True)
         eng = make_engine(cfg, ecfg, params, tok, use_kernel=False)
         want = eng.generate([list(p) for p in prompts], max_new_tokens=12)
         sids = [eng.submit(list(p), max_new_tokens=12) for p in prompts]
@@ -198,7 +196,7 @@ class TestOverlapParity:
 @pytest.mark.slow
 def test_tp_sharded_overlap_matches_plain(setup, cpu_devices):
     """Serving TP under overlap: TP-sharded params, overlap on vs off,
-    byte-identical greedy tokens (contiguous and paged)."""
+    byte-identical greedy tokens."""
     from k8s_llm_rca_tpu.runtime.sharding import (
         llama_param_specs, shard_pytree,
     )
@@ -207,55 +205,51 @@ def test_tp_sharded_overlap_matches_plain(setup, cpu_devices):
     mesh = build_mesh(MeshConfig(data=2, model=2), devices=cpu_devices[:4])
     sharded = shard_pytree(params, llama_param_specs(cfg), mesh)
     prompts = _prompts(tok)[:3]
-    for paged in (False, True):
-        ecfg = _ecfg(paged, max_batch=2, max_new_tokens=6)
-        kw = dict(use_kernel=False) if paged else {}
-        with jax.default_matmul_precision("float32"):
-            plain = make_engine(cfg, ecfg, sharded, tok, **kw).generate(
-                [list(p) for p in prompts], max_new_tokens=6)
-            over = make_engine(
-                cfg, dataclasses.replace(ecfg, host_overlap=True),
-                sharded, tok, **kw).generate(
-                [list(p) for p in prompts], max_new_tokens=6)
-        for r, g in zip(plain, over):
-            assert r.token_ids == g.token_ids, paged
+    ecfg = _ecfg(max_batch=2, max_new_tokens=6)
+    with jax.default_matmul_precision("float32"):
+        plain = make_engine(cfg, ecfg, sharded, tok,
+                            use_kernel=False).generate(
+            [list(p) for p in prompts], max_new_tokens=6)
+        over = make_engine(
+            cfg, dataclasses.replace(ecfg, host_overlap=True),
+            sharded, tok, use_kernel=False).generate(
+            [list(p) for p in prompts], max_new_tokens=6)
+    for r, g in zip(plain, over):
+        assert r.token_ids == g.token_ids
 
 
 @pytest.mark.slow
 def test_pp_tp_overlap_matches_plain(setup, cpu_devices):
     """PP×TP in one mesh under overlap (the multi-host pod serving
     shape): the fused overlap step routes through the stage-local
-    pp_decode_fn and must keep exact greedy parity, both engines."""
+    pp_decode_fn and must keep exact greedy parity."""
     _, _, tok = setup
     cfg = TINY.replace(max_seq_len=128, n_layers=4)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     mesh = build_mesh(MeshConfig(stage=2, model=2), devices=cpu_devices[:4])
     prompts = _prompts(tok)[:3]
-    for paged in (False, True):
-        ecfg = _ecfg(paged, max_batch=2, max_new_tokens=6)
-        with jax.default_matmul_precision("float32"):
-            plain = make_engine(cfg, ecfg, params, tok, pp_mesh=mesh,
-                                tp_mesh=mesh).generate(
-                [list(p) for p in prompts], max_new_tokens=6)
-            over = make_engine(
-                cfg, dataclasses.replace(ecfg, host_overlap=True),
-                params, tok, pp_mesh=mesh, tp_mesh=mesh).generate(
-                [list(p) for p in prompts], max_new_tokens=6)
-        for r, g in zip(plain, over):
-            assert r.token_ids == g.token_ids, paged
+    ecfg = _ecfg(max_batch=2, max_new_tokens=6)
+    with jax.default_matmul_precision("float32"):
+        plain = make_engine(cfg, ecfg, params, tok, pp_mesh=mesh,
+                            tp_mesh=mesh).generate(
+            [list(p) for p in prompts], max_new_tokens=6)
+        over = make_engine(
+            cfg, dataclasses.replace(ecfg, host_overlap=True),
+            params, tok, pp_mesh=mesh, tp_mesh=mesh).generate(
+            [list(p) for p in prompts], max_new_tokens=6)
+    for r, g in zip(plain, over):
+        assert r.token_ids == g.token_ids
 
 
 def test_cp_composition_rejected_loudly(setup, cpu_devices):
     """host_overlap × CP is excluded: CP's multi-process host_np
     collectives must line up SPMD-identically, which a lagged commit
-    would reorder — both engines refuse at construction."""
+    would reorder — the engine refuses at construction."""
     cfg, params, tok = setup
     mesh = build_mesh(MeshConfig(seq=4), devices=cpu_devices[:4])
-    for paged in (False, True):
-        ecfg = _ecfg(paged, host_overlap=True)
-        kw = dict(use_kernel=False) if paged else {}
-        with pytest.raises(ValueError, match="host_overlap"):
-            make_engine(cfg, ecfg, params, tok, cp_mesh=mesh, **kw)
+    with pytest.raises(ValueError, match="host_overlap"):
+        make_engine(cfg, _ecfg(host_overlap=True), params, tok,
+                    cp_mesh=mesh, use_kernel=False)
 
 
 # ---------------------------------------------------------------------------
@@ -266,27 +260,26 @@ def test_cp_composition_rejected_loudly(setup, cpu_devices):
 @pytest.mark.perf
 class TestHostTrafficCounters:
     """Fixed scripted workload, exact counter assertions.  The plain
-    paged stepwise tick re-uploads all three arrays (3 h2d) and blocks on
+    stepwise tick re-uploads all three arrays (3 h2d) and blocks on
     one fetch per tick; overlap must hold h2d at the single initial
     upload and at least halve the sync points for the same tokens."""
 
-    def _counts(self, setup, paged, overlap):
+    def _counts(self, setup, overlap, **over):
         """4 identical same-bucket prompts into 4 slots: exactly ONE
         batched prefill dispatch, all retirements on the same tick — the
         counter arithmetic below is exact, not approximate."""
         cfg, params, tok = setup
         prompts = [tok.encode("pod crashloop", add_bos=True)] * 4
         _, counts = _run(cfg, params, tok,
-                         _ecfg(paged, host_overlap=overlap), prompts,
-                         **(dict(use_kernel=False) if paged else {}))
+                         _ecfg(host_overlap=overlap, **over), prompts)
         for k in ("engine.h2d_uploads", "engine.d2h_syncs",
                   "engine.dispatches", "engine.decode_tokens"):
             counts.setdefault(k, 0.0)
         return counts
 
     def test_paged_exact_counts(self, setup):
-        pc = self._counts(setup, True, False)
-        oc = self._counts(setup, True, True)
+        pc = self._counts(setup, False)
+        oc = self._counts(setup, True)
         # same committed work either way
         assert oc["engine.decode_tokens"] == pc["engine.decode_tokens"] > 0
         # plain stepwise: with D decode dispatches after the single
@@ -306,26 +299,26 @@ class TestHostTrafficCounters:
         assert 2 * oc["engine.d2h_syncs"] <= pc["engine.d2h_syncs"], (
             oc, pc)
 
-    def test_contiguous_exact_counts(self, setup):
-        pc = self._counts(setup, False, False)
-        oc = self._counts(setup, False, True)
+    def test_scan_exact_counts(self, setup):
+        """The chunked scan: the plain tick uploads the three arrays
+        before every scan, overlap once (the scan's own outputs stay
+        resident); both block on one fetch a scan, and the coalesced
+        drain of the admission's first tokens."""
+        pc = self._counts(setup, False, decode_chunk=8)
+        oc = self._counts(setup, True, decode_chunk=8)
         assert oc["engine.decode_tokens"] == pc["engine.decode_tokens"] > 0
-        # the contiguous engine's arrays are born device-resident: no
-        # full-array uploads in either mode on this grammar-free workload
-        assert pc["engine.h2d_uploads"] == 0
-        assert oc["engine.h2d_uploads"] == 0
-        # same sync-point arithmetic as the paged engine
-        assert pc["engine.d2h_syncs"] == pc["engine.dispatches"]
-        assert 2 * oc["engine.d2h_syncs"] == oc["engine.dispatches"] - 1
-        assert 2 * oc["engine.d2h_syncs"] <= pc["engine.d2h_syncs"], (
-            oc, pc)
+        scans = pc["engine.dispatches"] - 1
+        assert oc["engine.dispatches"] == pc["engine.dispatches"]
+        assert pc["engine.h2d_uploads"] == 3 * scans
+        assert oc["engine.h2d_uploads"] == 3
+        assert pc["engine.d2h_syncs"] == oc["engine.d2h_syncs"] == scans + 1
 
     def test_paged_steady_state_has_zero_h2d(self, setup):
         """Direct steady-state proof: once the resident state is
         materialised, further fast ticks dispatch without ANY h2d upload
         of cur_tokens/lengths/block_tables."""
         cfg, params, tok = setup
-        eng = make_engine(cfg, _ecfg(True, host_overlap=True), params,
+        eng = make_engine(cfg, _ecfg(host_overlap=True), params,
                           tok, use_kernel=False)
         eng.submit(list(_prompts(tok)[0]), max_new_tokens=12)
         for _ in range(3):                 # admission + state upload
@@ -343,7 +336,7 @@ class TestHostTrafficCounters:
         drain fetch per tick — two admission waves (different buckets)
         in one tick cost one sync, not two."""
         cfg, params, tok = setup
-        eng = make_engine(cfg, _ecfg(True), params, tok, use_kernel=False)
+        eng = make_engine(cfg, _ecfg(), params, tok, use_kernel=False)
         eng.submit(tok.encode("short", add_bos=True), max_new_tokens=4)
         eng.submit(tok.encode(
             "a much longer prompt that lands in the next prefill bucket "

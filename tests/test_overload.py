@@ -40,7 +40,7 @@ PROMPTS = ("kubelet crashloop on node-7 gpu slice",
 
 def _ecfg(**over):
     base = dict(max_batch=2, max_seq_len=128, prefill_buckets=(64, 128),
-                max_new_tokens=24, temperature=0.0, paged=True,
+                max_new_tokens=24, temperature=0.0,
                 page_size=16, num_pages=40, prefix_cache=False,
                 decode_chunk=4)
     base.update(over)
@@ -330,14 +330,6 @@ class TestSnapshotComposition:
 
 
 class TestExclusions:
-    def test_contiguous_engine_rejects_spill(self, setup):
-        cfg, params, tok = setup
-        with pytest.raises(ValueError, match="paged"):
-            make_engine(cfg, EngineConfig(
-                max_batch=2, max_seq_len=128, prefill_buckets=(64, 128),
-                max_new_tokens=8, temperature=0.0,
-                max_spilled_pages=8), params, tok)
-
     def test_negative_budget_rejects(self, setup):
         cfg, params, tok = setup
         with pytest.raises(ValueError, match="must be >= 0"):

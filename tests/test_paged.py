@@ -11,8 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import reference_greedy
 from k8s_llm_rca_tpu.config import TINY, EngineConfig
-from k8s_llm_rca_tpu.engine.engine import InferenceEngine
 from k8s_llm_rca_tpu.engine.paged import (
     TRASH_PAGE, AllocatorError, OutOfPages, PageAllocator,
     PagedInferenceEngine, init_paged_cache, paged_decode_step, paged_prefill,
@@ -253,19 +253,25 @@ class TestPagedEngine:
         tok = get_tokenizer()
         return (PagedInferenceEngine(cfg, ecfg, params, tok,
                                      use_kernel=False),
-                InferenceEngine(cfg, ecfg, params, tok), tok, cfg)
+                params, tok, cfg)
 
-    def test_matches_contiguous_engine(self):
-        paged, contiguous, tok, cfg = self._engine()
+    @pytest.mark.parametrize("page_size,decode_chunk", [
+        (8, 16), (16, 16), (8, 1)])
+    def test_matches_model_reference(self, page_size, decode_chunk):
+        """Scans that cross page boundaries, scans inside one page and
+        the stepwise tick all give the model's own greedy tokens."""
+        paged, params, tok, cfg = self._engine(
+            page_size=page_size, num_pages=512 // page_size,
+            decode_chunk=decode_chunk)
         prompts = [tok.encode(t, add_bos=True) for t in
                    ["pod crashloop", "pvc pending why", "node notready"]]
-        a = paged.generate(prompts, max_new_tokens=6)
-        b = contiguous.generate(prompts, max_new_tokens=6)
-        for ra, rb in zip(a, b):
-            assert ra.token_ids == rb.token_ids
-            assert ra.finish_reason == rb.finish_reason
+        for res, prompt in zip(paged.generate(prompts, max_new_tokens=6),
+                               prompts):
+            assert res.token_ids == reference_greedy(cfg, params, prompt, 6)
+            assert res.finish_reason == "length"
         paged.allocator.check()
-        assert paged.allocator.n_free == 63   # everything returned
+        # everything returned
+        assert paged.allocator.n_free == 512 // page_size - 1
 
     def test_churn_many_sequences(self):
         paged, _, tok, _ = self._engine(num_pages=32)
@@ -779,7 +785,7 @@ class TestBatchedPrefixHitAdmission:
 
         cfg = TINY.replace(max_seq_len=128)
         ecfg = EngineConfig(max_batch=max_batch, max_seq_len=128,
-                            paged=True, page_size=8, num_pages=160,
+                            page_size=8, num_pages=160,
                             prefill_buckets=(32, 64), max_new_tokens=6,
                             temperature=0.0, decode_chunk=1,
                             prefix_cache=prefix_cache,
@@ -870,7 +876,7 @@ class TestBatchedPrefixHitAdmission:
         cfg = TINY.replace(max_seq_len=128)
         # 40 pages total: one 8-member hit group at ~56-token suffix
         # buckets (8 pages each) cannot allocate all-or-nothing
-        ecfg = EngineConfig(max_batch=8, max_seq_len=128, paged=True,
+        ecfg = EngineConfig(max_batch=8, max_seq_len=128,
                             page_size=8, num_pages=40,
                             prefill_buckets=(32, 64), max_new_tokens=4,
                             temperature=0.0, decode_chunk=1,
@@ -897,7 +903,7 @@ class TestEvictableAwareAdmissionCap:
 
     def _engine(self):
         cfg = TINY.replace(max_seq_len=64)
-        ecfg = EngineConfig(max_batch=8, max_seq_len=64, paged=True,
+        ecfg = EngineConfig(max_batch=8, max_seq_len=64,
                             page_size=8, num_pages=24,
                             prefill_buckets=(16, 32), max_new_tokens=4,
                             temperature=0.0, decode_chunk=1,

@@ -181,38 +181,6 @@ def test_expert_parallel_moe_drops_under_pressure(cpu_devices):
     assert float(jnp.abs(tight).sum()) < float(jnp.abs(dense).sum())
 
 
-def test_kv_cache_spec_sharded_decode_matches_unsharded(cpu_devices):
-    """kv_cache_specs must match the merged cache rank ([L, B, S, n_kv*d])
-    and a decode step over the sharded cache must equal the unsharded one."""
-    from k8s_llm_rca_tpu.config import TINY
-    from k8s_llm_rca_tpu.runtime.sharding import (
-        kv_cache_specs, llama_param_specs, shard_pytree,
-    )
-
-    cfg = TINY
-    mesh = build_mesh(MeshConfig(data=2, model=2), devices=cpu_devices[:4])
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    cache = llama.init_cache(cfg, n_slots=4, max_seq_len=32)
-    prompt = jax.random.randint(jax.random.PRNGKey(1), (1, 16), 0,
-                                cfg.vocab_size)
-    cache, _ = jax.jit(llama.prefill, static_argnums=0)(
-        cfg, params, cache, prompt, jnp.int32(16), jnp.int32(0))
-    cur = jnp.full((4,), 5, jnp.int32)
-    lengths = jnp.asarray([16, 0, 0, 0], jnp.int32)
-    ref_cache, ref_logits = jax.jit(llama.decode_step, static_argnums=0)(
-        cfg, params, cache, cur, lengths)
-
-    sharded_params = shard_pytree(params, llama_param_specs(cfg), mesh)
-    spec = kv_cache_specs()
-    sharded_cache = shard_pytree(cache, llama.KVCache(spec, spec), mesh)
-    out_cache, logits = jax.jit(llama.decode_step, static_argnums=0)(
-        cfg, sharded_params, sharded_cache, cur, lengths)
-    np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits),
-                               rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(out_cache.k),
-                               np.asarray(ref_cache.k), rtol=1e-5, atol=1e-5)
-
-
 def test_tp_sharded_engine_matches_unsharded(cpu_devices):
     """Serving TP: the continuous-batching engine fed TP-sharded params
     must emit the same greedy tokens as the unsharded engine."""
@@ -300,76 +268,21 @@ def test_cp_prefill_matches_single_device(seq_mesh):
                                rtol=2e-4, atol=2e-4)
 
 
-def test_engine_cp_prefill_matches_plain_engine(seq_mesh):
-    """InferenceEngine in context-parallel prefill mode emits the same
-    greedy tokens as the plain engine."""
-    from k8s_llm_rca_tpu.config import TINY, EngineConfig
-    from k8s_llm_rca_tpu.engine.engine import InferenceEngine
-    from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
-
-    cfg = TINY.replace(max_seq_len=64)
-    mesh = seq_mesh
-    ecfg = EngineConfig(max_batch=2, max_seq_len=64,
-                        prefill_buckets=(16, 32, 64), max_new_tokens=6,
-                        temperature=0.0)
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    prompts = [tok.encode("pod sandbox changed restarting", add_bos=True),
-               tok.encode("oom killed container", add_bos=True)]
-
-    ref = InferenceEngine(cfg, ecfg, params, tok).generate(
-        prompts, max_new_tokens=6)
-    got = InferenceEngine(cfg, ecfg, params, tok, cp_mesh=mesh).generate(
-        prompts, max_new_tokens=6)
-    for r, g in zip(ref, got):
-        assert r.token_ids == g.token_ids
-
-
-def test_engine_cp_rejects_indivisible_buckets(seq_mesh):
-    from k8s_llm_rca_tpu.config import TINY, EngineConfig
-    from k8s_llm_rca_tpu.engine.engine import InferenceEngine
-    from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
-
-    cfg = TINY.replace(max_seq_len=64)
-    ecfg = EngineConfig(max_batch=1, max_seq_len=64, prefill_buckets=(18,))
-    with pytest.raises(ValueError, match="must divide"):
-        InferenceEngine(cfg, ecfg, llama.init_params(cfg, jax.random.PRNGKey(0)),
-                        get_tokenizer(vocab_size=cfg.vocab_size),
-                        cp_mesh=seq_mesh)
-
-
-def test_engine_ulysses_prefill_matches_plain_engine(seq_mesh):
-    """Ulysses is the second engine CP mode: identical greedy output."""
-    from k8s_llm_rca_tpu.config import TINY, EngineConfig
-    from k8s_llm_rca_tpu.engine.engine import InferenceEngine
-    from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
-
-    cfg = TINY.replace(max_seq_len=64)
-    ecfg = EngineConfig(max_batch=1, max_seq_len=64,
-                        prefill_buckets=(16, 32, 64), max_new_tokens=6,
-                        temperature=0.0)
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    prompt = tok.encode("image pull backoff registry timeout", add_bos=True)
-
-    ref = InferenceEngine(cfg, ecfg, params, tok).generate(
-        [prompt], max_new_tokens=6)
-    got = InferenceEngine(cfg, ecfg, params, tok, cp_mesh=seq_mesh,
-                          cp_mode="ulysses").generate(
-        [list(prompt)], max_new_tokens=6)
-    assert ref[0].token_ids == got[0].token_ids
-
-
-def test_paged_engine_cp_prefill_matches_plain_engine(seq_mesh):
-    """PagedInferenceEngine in context-parallel prefill mode emits the
-    same greedy tokens as the plain paged engine (ring and ulysses)."""
+@pytest.mark.parametrize("cp_mode,page_size", [
+    ("ring", 8), ("ulysses", 8), ("ring", 16)])
+def test_engine_cp_prefill_matches_plain_engine(seq_mesh, cp_mode,
+                                                page_size):
+    """The engine in context-parallel prefill mode (ring, and Ulysses,
+    the second CP mode) emits the same greedy tokens as the plain
+    engine."""
     from k8s_llm_rca_tpu.config import TINY, EngineConfig
     from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
     from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
 
     cfg = TINY.replace(max_seq_len=64)
-    ecfg = EngineConfig(max_batch=2, max_seq_len=64, page_size=8,
-                        num_pages=32, prefill_buckets=(16, 32, 64),
+    ecfg = EngineConfig(max_batch=2, max_seq_len=64, page_size=page_size,
+                        num_pages=256 // page_size,
+                        prefill_buckets=(16, 32, 64),
                         max_new_tokens=6, temperature=0.0,
                         prefix_cache=False)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
@@ -380,34 +293,30 @@ def test_paged_engine_cp_prefill_matches_plain_engine(seq_mesh):
     ref = PagedInferenceEngine(cfg, ecfg, params, tok,
                                use_kernel=False).generate(
         prompts, max_new_tokens=6)
-    for mode in ("ring", "ulysses"):
-        eng = PagedInferenceEngine(cfg, ecfg, params, tok, use_kernel=False,
-                                   cp_mesh=seq_mesh, cp_mode=mode)
-        got = eng.generate([list(p) for p in prompts], max_new_tokens=6)
-        for r, g in zip(ref, got):
-            assert r.token_ids == g.token_ids, mode
-        eng.allocator.check()
+    eng = PagedInferenceEngine(cfg, ecfg, params, tok, use_kernel=False,
+                               cp_mesh=seq_mesh, cp_mode=cp_mode)
+    got = eng.generate([list(p) for p in prompts], max_new_tokens=6)
+    for r, g in zip(ref, got):
+        assert r.token_ids == g.token_ids
+    eng.allocator.check()
 
 
-def test_paged_engine_cp_rejects_bad_configs(seq_mesh):
+@pytest.mark.parametrize("knobs,refusal", [
+    (dict(page_size=8, prefix_cache=True), "prefix_cache"),
+    (dict(page_size=6, prefill_buckets=(18,), prefix_cache=False),
+     "must divide")])
+def test_engine_cp_rejects_bad_configs(seq_mesh, knobs, refusal):
     from k8s_llm_rca_tpu.config import TINY, EngineConfig
     from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
     from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
 
     cfg = TINY.replace(max_seq_len=64)
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    with pytest.raises(ValueError, match="prefix_cache"):
+    with pytest.raises(ValueError, match=refusal):
         PagedInferenceEngine(
-            cfg, EngineConfig(max_batch=1, max_seq_len=64, page_size=8,
-                              num_pages=32, prefix_cache=True),
-            params, tok, cp_mesh=seq_mesh)
-    with pytest.raises(ValueError, match="must divide"):
-        PagedInferenceEngine(
-            cfg, EngineConfig(max_batch=1, max_seq_len=64, page_size=6,
-                              num_pages=32, prefill_buckets=(18,),
-                              prefix_cache=False),
-            params, tok, cp_mesh=seq_mesh)
+            cfg, EngineConfig(max_batch=1, max_seq_len=64, num_pages=32,
+                              **knobs),
+            llama.init_params(cfg, jax.random.PRNGKey(0)),
+            get_tokenizer(vocab_size=cfg.vocab_size), cp_mesh=seq_mesh)
 
 
 def test_ep_sharded_engine_matches_unsharded(cpu_devices):
@@ -479,7 +388,7 @@ def test_ep_paged_engine_matches_dense(cpu_devices):
 
     cfg = TINY_MOE.replace(max_seq_len=64, n_experts=4)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    ecfg = EngineConfig(max_batch=4, max_seq_len=64, paged=True,
+    ecfg = EngineConfig(max_batch=4, max_seq_len=64,
                         page_size=8, num_pages=48,
                         prefill_buckets=(16, 32, 64), max_new_tokens=6,
                         temperature=0.0)
@@ -533,7 +442,7 @@ def test_paged_tp_engine_matches_unsharded(cpu_devices):
     cfg = TINY.replace(max_seq_len=64)
     mesh = build_mesh(MeshConfig(data=2, model=2), devices=cpu_devices[:4])
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    ecfg = EngineConfig(max_batch=2, max_seq_len=64, paged=True,
+    ecfg = EngineConfig(max_batch=2, max_seq_len=64,
                         page_size=8, num_pages=32,
                         prefill_buckets=(16, 32, 64), max_new_tokens=6,
                         temperature=0.0)
@@ -570,7 +479,7 @@ def test_paged_tp_engine_quantized_pool(cpu_devices, kv_dtype):
     cfg = TINY.replace(max_seq_len=64)
     mesh = build_mesh(MeshConfig(data=2, model=2), devices=cpu_devices[:4])
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    ecfg = EngineConfig(max_batch=2, max_seq_len=64, paged=True,
+    ecfg = EngineConfig(max_batch=2, max_seq_len=64,
                         page_size=8, num_pages=32,
                         prefill_buckets=(16, 32, 64), max_new_tokens=6,
                         temperature=0.0, kv_cache_dtype=kv_dtype)
@@ -605,7 +514,7 @@ def test_paged_tp_kernel_matches_unsharded(cpu_devices, use_kernel):
     cfg = TINY.replace(max_seq_len=64)
     mesh = build_mesh(MeshConfig(data=2, model=2), devices=cpu_devices[:4])
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    ecfg = EngineConfig(max_batch=2, max_seq_len=64, paged=True,
+    ecfg = EngineConfig(max_batch=2, max_seq_len=64,
                         page_size=8, num_pages=32,
                         prefill_buckets=(16, 32, 64), max_new_tokens=6,
                         temperature=0.0, decode_chunk=4)
@@ -640,7 +549,7 @@ def test_paged_tp_kernel_int8_pool_matches_unsharded(cpu_devices):
     cfg = TINY.replace(max_seq_len=64)
     mesh = build_mesh(MeshConfig(data=2, model=2), devices=cpu_devices[:4])
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    ecfg = EngineConfig(max_batch=2, max_seq_len=64, paged=True,
+    ecfg = EngineConfig(max_batch=2, max_seq_len=64,
                         page_size=8, num_pages=32,
                         prefill_buckets=(16, 32, 64), max_new_tokens=6,
                         temperature=0.0, kv_cache_dtype="int8",
@@ -671,7 +580,7 @@ def test_paged_tp_rejects_kernel_unsupported_configs(cpu_devices):
 
     cfg = TINY.replace(max_seq_len=64)
     mesh = build_mesh(MeshConfig(data=2, model=2), devices=cpu_devices[:4])
-    ecfg = EngineConfig(max_batch=2, max_seq_len=64, paged=True,
+    ecfg = EngineConfig(max_batch=2, max_seq_len=64,
                         page_size=8, num_pages=32, prefill_buckets=(16,),
                         kv_cache_dtype="int4")
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
@@ -681,7 +590,7 @@ def test_paged_tp_rejects_kernel_unsupported_configs(cpu_devices):
     # indivisible kv heads: 2 kv heads cannot split over model=4
     mesh4 = build_mesh(MeshConfig(data=2, model=4),
                        devices=cpu_devices[:8])
-    ecfg8 = EngineConfig(max_batch=2, max_seq_len=64, paged=True,
+    ecfg8 = EngineConfig(max_batch=2, max_seq_len=64,
                          page_size=8, num_pages=32, prefill_buckets=(16,))
     with pytest.raises(ValueError, match="divisible"):
         PagedInferenceEngine(cfg, ecfg8, params, get_tokenizer(),
@@ -690,7 +599,7 @@ def test_paged_tp_rejects_kernel_unsupported_configs(cpu_devices):
     # which the per-head-shard kernel cannot express — even with
     # unsharded (host) params the mesh alone must reject the kernel
     seq_mesh = build_mesh(MeshConfig(seq=2), devices=cpu_devices[:2])
-    ecfg_cp = EngineConfig(max_batch=2, max_seq_len=64, paged=True,
+    ecfg_cp = EngineConfig(max_batch=2, max_seq_len=64,
                            page_size=8, num_pages=32,
                            prefill_buckets=(16,), prefix_cache=False)
     with pytest.raises(ValueError, match="cp_mesh"):
@@ -698,193 +607,99 @@ def test_paged_tp_rejects_kernel_unsupported_configs(cpu_devices):
                              use_kernel=True, cp_mesh=seq_mesh)
 
 
-def test_contiguous_tp_engine_cache_sharded(cpu_devices):
-    """tp_mesh on the CONTIGUOUS engine: the KV cache is placed sharded
-    (slots over data, merged kv axis over model) and greedy output still
-    matches the unsharded engine — including a quantized cache whose
-    per-token scale arrays shard on data only."""
-    from k8s_llm_rca_tpu.config import TINY, EngineConfig
-    from k8s_llm_rca_tpu.engine import make_engine
-    from k8s_llm_rca_tpu.runtime.sharding import (
-        llama_param_specs, shard_pytree,
+def _pp_pool_case(cfg, b=4, s_pad=16, page=8, n_pages=40):
+    """Seeded prompts with one private run of pages a row: page maps for
+    the prefill, block tables (two pages of headroom) for the decode."""
+    from k8s_llm_rca_tpu.engine.paged import TRASH_PAGE, init_paged_cache
+
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (b, s_pad), 0,
+                                cfg.vocab_size)
+    lengths = jnp.asarray([16, 13, 9, 16], jnp.int32)[:b]
+    per_seq = s_pad // page + 2
+    tables = np.full((b, cfg.max_seq_len // page), TRASH_PAGE, np.int32)
+    tables[:, :per_seq] = 1 + np.arange(b * per_seq).reshape(b, per_seq)
+    return (init_paged_cache(cfg, n_pages, page), tokens, lengths,
+            jnp.asarray(tables[:, :s_pad // page]), jnp.asarray(tables))
+
+
+def test_paged_pp_prefill_decode_matches_plain(cpu_devices):
+    """PP SERVING, the functions the engine jits (round-1 review item 9):
+    the pipelined paged prefill scatters each stage's layers' KV into the
+    pool and the pipelined decode step — slot-group microbatches flowing
+    GPipe-style — gives the plain paged path's logits, greedy tokens and
+    pool over several steps."""
+    from k8s_llm_rca_tpu.engine.paged import (
+        paged_decode_step, paged_prefill_batch,
     )
-    from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
-
-    cfg = TINY.replace(max_seq_len=64)
-    mesh = build_mesh(MeshConfig(data=2, model=2), devices=cpu_devices[:4])
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    prompts = [tok.encode("pod pending unschedulable", add_bos=True),
-               tok.encode("pvc not bound", add_bos=True)]
-    for kv_dtype in (None, "int8"):
-        ecfg = EngineConfig(max_batch=2, max_seq_len=64,
-                            prefill_buckets=(16, 32, 64), max_new_tokens=6,
-                            temperature=0.0, kv_cache_dtype=kv_dtype)
-        ref = make_engine(cfg, ecfg, params, tok).generate(
-            prompts, max_new_tokens=6)
-        sharded = shard_pytree(params, llama_param_specs(cfg), mesh)
-        eng = make_engine(cfg, ecfg, sharded, tok, tp_mesh=mesh)
-        shard_shape = eng.cache.k.sharding.shard_shape(eng.cache.k.shape)
-        assert shard_shape[1] == 1                  # slots over data
-        assert shard_shape[-1] == eng.cache.k.shape[-1] // 2   # kv over model
-        got = eng.generate(prompts, max_new_tokens=6)
-        for r, g in zip(ref, got):
-            assert r.token_ids == g.token_ids, kv_dtype
-
-
-def test_pp_prefill_decode_matches_plain(cpu_devices):
-    """PP SERVING (round-1 review item 9): pipelined prefill writes per-stage
-    KV (cache layer axis sharded over "stage") and the pipelined decode
-    step — slot-group microbatches flowing GPipe-style — produces the
-    plain path's exact greedy tokens over multiple steps."""
     from k8s_llm_rca_tpu.parallel import (
-        llama_pp_decode_step, llama_pp_prefill, stack_llama_stages,
+        paged_pp_decode_step, paged_pp_prefill, stack_llama_stages,
     )
 
     cfg = TINY.replace(max_seq_len=64, n_layers=4)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    n_stages, m, b, s_pad, steps = 2, 2, 4, 16, 5
+    n_stages, m, steps = 2, 2, 5
     mesh = build_mesh(MeshConfig(stage=n_stages),
                       devices=cpu_devices[:n_stages])
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (b, s_pad), 0,
-                                cfg.vocab_size)
-    lengths = jnp.asarray([16, 13, 9, 16], jnp.int32)
+    pool, tokens, lengths, page_maps, tables = _pp_pool_case(cfg)
 
-    # plain reference: batched prefill + stepwise greedy decode
-    ref_cache = llama.init_cache(cfg, b, cfg.max_seq_len)
-    ref_cache, ref_logits = llama.prefill_batch(
-        cfg, params, ref_cache, tokens, lengths, jnp.arange(b))
-    ref_toks = [jnp.argmax(ref_logits, -1)]
-    ref_lens = lengths
-    for _ in range(steps - 1):
-        ref_cache, lg = llama.decode_step(cfg, params, ref_cache,
-                                          ref_toks[-1], ref_lens)
-        ref_lens = ref_lens + 1
-        ref_toks.append(jnp.argmax(lg, -1))
-
-    # PP: same schedule through the stage pipeline
+    ref_pool, ref_logits = paged_prefill_batch(cfg, params, pool, tokens,
+                                               lengths, page_maps)
     stacked = stack_llama_stages(params, n_stages)
-    pp_cache = llama.init_cache(cfg, b, cfg.max_seq_len)
-    pp_cache, pp_logits = llama_pp_prefill(
-        cfg, params, pp_cache, tokens, lengths, mesh, microbatches=m,
-        stacked_layers=stacked)
+    pp_pool, pp_logits = paged_pp_prefill(
+        cfg, params, pool, tokens, lengths, page_maps, mesh,
+        microbatches=m, stacked_layers=stacked)
     np.testing.assert_allclose(np.asarray(pp_logits), np.asarray(ref_logits),
                                rtol=2e-4, atol=2e-4)
-    pp_toks = [jnp.argmax(pp_logits, -1)]
-    pp_lens = lengths
+    ref_tok = jnp.argmax(ref_logits, -1).astype(jnp.int32)
+    pp_tok = jnp.argmax(pp_logits, -1).astype(jnp.int32)
+    lens = lengths
     for _ in range(steps - 1):
-        pp_cache, lg = llama_pp_decode_step(
-            cfg, params, pp_cache, pp_toks[-1], pp_lens, mesh,
+        np.testing.assert_array_equal(np.asarray(pp_tok),
+                                      np.asarray(ref_tok))
+        ref_pool, lg = paged_decode_step(cfg, params, ref_pool, ref_tok,
+                                         lens, tables, use_kernel=False)
+        pp_pool, pp_lg = paged_pp_decode_step(
+            cfg, params, pp_pool, pp_tok, lens, tables, mesh,
             microbatches=m, stacked_layers=stacked)
-        pp_lens = pp_lens + 1
-        pp_toks.append(jnp.argmax(lg, -1))
+        lens = lens + 1
+        ref_tok = jnp.argmax(lg, -1).astype(jnp.int32)
+        pp_tok = jnp.argmax(pp_lg, -1).astype(jnp.int32)
+    np.testing.assert_array_equal(np.asarray(pp_tok), np.asarray(ref_tok))
+    # the pools agree on every page a sequence owns (the trash page takes
+    # the padding rows' writes in whatever order)
+    np.testing.assert_allclose(np.asarray(pp_pool.k[:, 1:]),
+                               np.asarray(ref_pool.k[:, 1:]),
+                               rtol=1e-4, atol=1e-4)
 
-    for r, g in zip(ref_toks, pp_toks):
-        np.testing.assert_array_equal(np.asarray(r), np.asarray(g))
-    # the caches agree where valid (same KV written stage-locally)
-    np.testing.assert_allclose(np.asarray(pp_cache.k),
-                               np.asarray(ref_cache.k), rtol=1e-4, atol=1e-4)
 
-
-def test_pp_decode_under_jit_with_sharded_cache(cpu_devices):
-    """The PP decode step compiles under jit with the cache PLACED sharded
-    (layer axis over "stage") — each stage device holds 1/P of KV bytes."""
+def test_paged_pp_decode_under_jit_with_sharded_pool(cpu_devices):
+    """The PP decode step compiles under jit with the pool PLACED sharded
+    (layer axis over "stage") and leaves it so: each stage device holds
+    1/P of the KV bytes."""
     from jax.sharding import NamedSharding
     from k8s_llm_rca_tpu.parallel import (
-        kv_cache_stage_specs, llama_pp_decode_step, llama_pp_prefill,
+        kv_cache_stage_specs, paged_pp_decode_step, paged_pp_prefill,
+        stack_llama_stages,
     )
 
     cfg = TINY.replace(max_seq_len=64, n_layers=4)
     params = llama.init_params(cfg, jax.random.PRNGKey(3))
     mesh = build_mesh(MeshConfig(stage=2), devices=cpu_devices[:2])
-    b = 4
-    cache = llama.init_cache(cfg, b, cfg.max_seq_len)
+    pool, tokens, lengths, page_maps, tables = _pp_pool_case(cfg)
     spec = NamedSharding(mesh, kv_cache_stage_specs())
-    cache = type(cache)(jax.device_put(cache.k, spec),
-                        jax.device_put(cache.v, spec))
-    tokens = jax.random.randint(jax.random.PRNGKey(4), (b, 16), 0,
-                                cfg.vocab_size)
-    lengths = jnp.full((b,), 16, jnp.int32)
-    from k8s_llm_rca_tpu.parallel import stack_llama_stages
-
+    pool = type(pool)(jax.device_put(pool.k, spec),
+                      jax.device_put(pool.v, spec))
     stacked = stack_llama_stages(params, 2)     # hoisted off the hot path
-    cache, logits = llama_pp_prefill(cfg, params, cache, tokens, lengths,
-                                     mesh, stacked_layers=stacked)
+    pool, logits = paged_pp_prefill(cfg, params, pool, tokens, lengths,
+                                    page_maps, mesh, stacked_layers=stacked)
 
-    step = jax.jit(lambda c, t, ln: llama_pp_decode_step(
-        cfg, params, c, t, ln, mesh, stacked_layers=stacked))
-    cache, logits = step(cache, jnp.argmax(logits, -1), lengths)
+    step = jax.jit(lambda pl, t, ln: paged_pp_decode_step(
+        cfg, params, pl, t, ln, tables, mesh, stacked_layers=stacked))
+    pool, logits = step(pool, jnp.argmax(logits, -1).astype(jnp.int32),
+                        lengths)
     assert bool(jnp.isfinite(logits).all())
-    shard_shape = cache.k.sharding.shard_shape(cache.k.shape)
+    shard_shape = pool.k.sharding.shard_shape(pool.k.shape)
     assert shard_shape[0] == cfg.n_layers // 2      # layers over stages
-
-
-def test_cp_decode_with_seq_sharded_cache(cpu_devices):
-    """Context-parallel DECODE: with the KV cache's sequence axis sharded
-    over the seq mesh, plain decode_step produces the exact greedy tokens
-    of the unsharded path — GSPMD partitions the attention reduction over
-    S and inserts the combine collectives.  This is the long-context
-    serving half that complements CP prefill: each device holds 1/P of
-    the context's KV bytes."""
-    from jax.sharding import NamedSharding
-    from k8s_llm_rca_tpu.runtime.sharding import kv_cache_cp_specs
-
-    cfg = TINY.replace(max_seq_len=64)
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    mesh = build_mesh(MeshConfig(seq=4), devices=cpu_devices[:4])
-    b = 2
-    prompts = jax.random.randint(jax.random.PRNGKey(1), (b, 16), 0,
-                                 cfg.vocab_size)
-    lengths = jnp.asarray([16, 12], jnp.int32)
-    cache = llama.init_cache(cfg, b, cfg.max_seq_len)
-    cache, logits = llama.prefill_batch(cfg, params, cache, prompts,
-                                        lengths, jnp.arange(b))
-    kv_spec, _ = kv_cache_cp_specs()
-    sharded = llama.KVCache(
-        jax.device_put(cache.k, NamedSharding(mesh, kv_spec)),
-        jax.device_put(cache.v, NamedSharding(mesh, kv_spec)))
-
-    step = jax.jit(llama.decode_step, static_argnums=0)
-    cur = r_cur = jnp.argmax(logits, -1).astype(jnp.int32)
-    lens = lengths
-    cp_cache, ref_cache = sharded, cache
-    for _ in range(6):
-        ref_cache, ref_lg = step(cfg, params, ref_cache, r_cur, lens)
-        cp_cache, cp_lg = step(cfg, params, cp_cache, cur, lens)
-        r_cur = jnp.argmax(ref_lg, -1).astype(jnp.int32)
-        cur = jnp.argmax(cp_lg, -1).astype(jnp.int32)
-        np.testing.assert_array_equal(np.asarray(cur), np.asarray(r_cur))
-        lens = lens + 1
-    # the cache stayed sequence-sharded across steps
-    shard = cp_cache.k.sharding.shard_shape(cp_cache.k.shape)
-    assert shard[2] == cfg.max_seq_len // 4
-
-
-def test_cp_engine_decodes_with_sharded_cache(cpu_devices):
-    """The CP engine now places its cache sequence-sharded: greedy output
-    matches the plain engine while each device stores 1/P of the KV."""
-    from k8s_llm_rca_tpu.config import TINY, EngineConfig
-    from k8s_llm_rca_tpu.engine import make_engine
-    from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
-
-    cfg = TINY.replace(max_seq_len=64)
-    mesh = build_mesh(MeshConfig(seq=4), devices=cpu_devices[:4])
-    ecfg = EngineConfig(max_batch=2, max_seq_len=64,
-                        prefill_buckets=(16, 32, 64), max_new_tokens=6,
-                        temperature=0.0)
-    tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    prompts = [tok.encode("pod pending unschedulable", add_bos=True),
-               tok.encode("pvc not bound", add_bos=True)]
-
-    ref = make_engine(cfg, ecfg, params, tok).generate(
-        prompts, max_new_tokens=6)
-    eng = make_engine(cfg, ecfg, params, tok, cp_mesh=mesh)
-    got = eng.generate(prompts, max_new_tokens=6)
-    for r, g in zip(ref, got):
-        assert r.token_ids == g.token_ids
-    shard = eng.cache.k.sharding.shard_shape(eng.cache.k.shape)
-    assert shard[2] == ecfg.max_seq_len // 4
 
 
 def test_cp_tp_requires_one_composed_mesh(cpu_devices):
@@ -892,7 +707,7 @@ def test_cp_tp_requires_one_composed_mesh(cpu_devices):
     mesh objects (which would each claim the cache layout) are rejected,
     as is a composed mesh whose head counts don't split over 'model'."""
     from k8s_llm_rca_tpu.config import TINY, EngineConfig
-    from k8s_llm_rca_tpu.engine.engine import InferenceEngine
+    from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
     from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
 
     cfg = TINY.replace(max_seq_len=64)
@@ -903,91 +718,26 @@ def test_cp_tp_requires_one_composed_mesh(cpu_devices):
     ecfg = EngineConfig(max_batch=2, max_seq_len=64, prefill_buckets=(16,))
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     with pytest.raises(ValueError, match="SAME composed mesh"):
-        InferenceEngine(cfg, ecfg, params, get_tokenizer(),
-                        cp_mesh=mesh_a, tp_mesh=mesh_b)
+        PagedInferenceEngine(cfg, ecfg, params, get_tokenizer(),
+                             cp_mesh=mesh_a, tp_mesh=mesh_b)
     with pytest.raises(ValueError, match="not divisible by model"):
         # n_kv_heads=2 cannot split over model=4
         mesh4 = build_mesh(MeshConfig(data=1, model=4, seq=2),
                            devices=cpu_devices[:8])
-        InferenceEngine(cfg, ecfg, params, get_tokenizer(),
-                        cp_mesh=mesh4, tp_mesh=mesh4)
+        PagedInferenceEngine(cfg, ecfg, params, get_tokenizer(),
+                             cp_mesh=mesh4, tp_mesh=mesh4)
 
 
-@pytest.mark.parametrize("cp_mode", ["ring", "ulysses"])
-def test_cp_tp_composed_engine_matches_plain(cpu_devices, cp_mode):
+@pytest.mark.parametrize("cp_mode,kv_dtype", [
+    ("ring", None), ("ulysses", None), ("ring", "int8"), ("ulysses", "int8")])
+def test_cp_tp_composed_engine_matches_plain(cpu_devices, cp_mode,
+                                             kv_dtype):
     """CP×TP in ONE mesh (SURVEY §7 hard part 6 — the long-context 8B
-    shape: TP heads within a node, sequence ring across): the cache takes
-    the seq-major × head-minor layout (S over 'seq', merged kv over
-    'model', slots over 'data'), prefill runs the TP-aware ring/Ulysses
-    per head shard, decode composes via GSPMD — exact greedy parity."""
-    from k8s_llm_rca_tpu.config import TINY, EngineConfig
-    from k8s_llm_rca_tpu.engine.engine import InferenceEngine
-    from k8s_llm_rca_tpu.runtime.sharding import (
-        llama_param_specs, shard_pytree,
-    )
-    from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
-
-    cfg = TINY.replace(max_seq_len=64)
-    mesh = build_mesh(MeshConfig(data=2, model=2, seq=2),
-                      devices=cpu_devices[:8])
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    sharded = shard_pytree(params, llama_param_specs(cfg), mesh)
-    tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    ecfg = EngineConfig(max_batch=2, max_seq_len=64,
-                        prefill_buckets=(16, 32), max_new_tokens=6,
-                        decode_chunk=1)
-    prompts = [tok.encode("pod crashloop kube-system", add_bos=True),
-               tok.encode("node disk pressure taint", add_bos=True)]
-
-    with jax.default_matmul_precision("float32"):
-        ref = InferenceEngine(cfg, ecfg, params, tok).generate(
-            prompts, max_new_tokens=6)
-        eng = InferenceEngine(cfg, ecfg, sharded, tok, cp_mesh=mesh,
-                              tp_mesh=mesh, cp_mode=cp_mode)
-        got = eng.generate(prompts, max_new_tokens=6)
-    for r, g in zip(ref, got):
-        assert r.token_ids == g.token_ids, cp_mode
-    # the cache is genuinely sharded on BOTH axes: seq and merged-kv halved
-    shard = eng.cache.k.sharding.shard_shape(eng.cache.k.shape)
-    assert shard[2] == cfg.max_seq_len // 2        # seq over 'seq'
-    assert shard[3] == cfg.kv_dim // 2             # kv over 'model'
-    assert shard[1] == 1                           # slots over 'data'
-
-
-def test_cp_tp_composed_engine_quantized_cache(cpu_devices):
-    """CP×TP × int8 KV: the composed layout shards the quantized payload
-    and its per-token scales; greedy parity holds."""
-    from k8s_llm_rca_tpu.config import TINY, EngineConfig
-    from k8s_llm_rca_tpu.engine.engine import InferenceEngine
-    from k8s_llm_rca_tpu.runtime.sharding import (
-        llama_param_specs, shard_pytree,
-    )
-    from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
-
-    cfg = TINY.replace(max_seq_len=64)
-    mesh = build_mesh(MeshConfig(data=2, model=2, seq=2),
-                      devices=cpu_devices[:8])
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    sharded = shard_pytree(params, llama_param_specs(cfg), mesh)
-    tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    ecfg = EngineConfig(max_batch=2, max_seq_len=64,
-                        prefill_buckets=(16, 32), max_new_tokens=6,
-                        kv_cache_dtype="int8", decode_chunk=1)
-    prompts = [tok.encode("pvc not bound", add_bos=True)]
-
-    with jax.default_matmul_precision("float32"):
-        ref = InferenceEngine(cfg, ecfg, params, tok).generate(
-            prompts, max_new_tokens=6)
-        eng = InferenceEngine(cfg, ecfg, sharded, tok, cp_mesh=mesh,
-                              tp_mesh=mesh)
-        got = eng.generate(prompts, max_new_tokens=6)
-    assert ref[0].token_ids == got[0].token_ids
-
-
-def test_cp_tp_composed_paged_engine_matches_plain(cpu_devices):
-    """Paged CP×TP: TP-aware ring prefill scatters into the seq×model
-    sharded page pool (page axis over 'seq', merged kv over 'model');
-    decode composes via GSPMD — exact greedy parity with the plain paged
+    shape: TP heads within a node, sequence ring across): the TP-aware
+    ring/Ulysses prefill runs per head shard and scatters into the
+    seq×model sharded page pool (page axis over 'seq', merged kv over
+    'model'; an int8 pool shards its per-token scales the same way);
+    decode composes via GSPMD — exact greedy parity with the plain
     engine."""
     from k8s_llm_rca_tpu.config import TINY, EngineConfig
     from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
@@ -1004,8 +754,8 @@ def test_cp_tp_composed_paged_engine_matches_plain(cpu_devices):
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
     ecfg = EngineConfig(max_batch=2, max_seq_len=64,
                         prefill_buckets=(16, 32), max_new_tokens=6,
-                        paged=True, page_size=16, num_pages=32,
-                        prefix_cache=False, decode_chunk=1)
+                        page_size=16, num_pages=32, prefix_cache=False,
+                        kv_cache_dtype=kv_dtype, decode_chunk=1)
     prompts = [tok.encode("pod crashloop kube-system", add_bos=True),
                tok.encode("node disk pressure taint", add_bos=True)]
 
@@ -1013,7 +763,7 @@ def test_cp_tp_composed_paged_engine_matches_plain(cpu_devices):
         ref = PagedInferenceEngine(cfg, ecfg, params, tok).generate(
             prompts, max_new_tokens=6)
         eng = PagedInferenceEngine(cfg, ecfg, sharded, tok, cp_mesh=mesh,
-                                   tp_mesh=mesh)
+                                   tp_mesh=mesh, cp_mode=cp_mode)
         got = eng.generate(prompts, max_new_tokens=6)
     for r, g in zip(ref, got):
         assert r.token_ids == g.token_ids
@@ -1027,9 +777,8 @@ def test_cp_tp_composed_paged_engine_matches_plain(cpu_devices):
 def test_cp_paged_seq_sharded_pool(cpu_devices):
     """CP seq-sharded paged pool (page-aligned CP splits): each CP device
     owns the page RANGE covering its sequence shard, so the paged engine
-    stores 1/P of a long context's KV per device — the memory win the
-    contiguous CP cache already had.  Greedy parity with the plain paged
-    engine through decode that GROWS across the partition boundary, plus
+    stores 1/P of a long context's KV per device.  Greedy parity with the
+    plain engine through decode that GROWS across the partition boundary, plus
     pool-bytes-per-device and allocator-partition assertions."""
     from k8s_llm_rca_tpu.config import TINY, EngineConfig
     from k8s_llm_rca_tpu.engine.paged import (
@@ -1046,7 +795,7 @@ def test_cp_paged_seq_sharded_pool(cpu_devices):
     ecfg = EngineConfig(max_batch=2, max_seq_len=32, page_size=8,
                         num_pages=16, prefill_buckets=(16,),
                         max_new_tokens=12, temperature=0.0,
-                        prefix_cache=False, paged=True, decode_chunk=1)
+                        prefix_cache=False, decode_chunk=1)
     prompts = [tok.encode("0123456789a", add_bos=True),   # 12 tokens
                tok.encode("pvc not bnd", add_bos=True)]
     assert all(len(p) == 12 for p in prompts)
@@ -1086,11 +835,10 @@ def test_cp_paged_seq_sharded_pool(cpu_devices):
     eng.allocator.check()
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_cp_speculative_matches_plain(cpu_devices, paged):
-    """Speculation composes with CP on both engines: the multi-token
-    verify step runs over the seq-sharded cache (contiguous) / the
-    seq-sharded page pool (paged) through GSPMD, with exact greedy
+@pytest.mark.parametrize("page_size", [8, 16])
+def test_cp_speculative_matches_plain(cpu_devices, page_size):
+    """Speculation composes with CP: the multi-token verify step runs
+    over the seq-sharded page pool through GSPMD, with exact greedy
     parity against the non-speculative non-CP engine."""
     import dataclasses
 
@@ -1102,23 +850,22 @@ def test_cp_speculative_matches_plain(cpu_devices, paged):
     mesh = build_mesh(MeshConfig(seq=2), devices=cpu_devices[:2])
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    extra = (dict(paged=True, page_size=8, num_pages=16,
-                  prefix_cache=False) if paged else {})
-    kw = dict(use_kernel=False) if paged else {}
     ecfg = EngineConfig(max_batch=2, max_seq_len=32, prefill_buckets=(16,),
-                        max_new_tokens=10, temperature=0.0, **extra)
+                        max_new_tokens=10, temperature=0.0,
+                        page_size=page_size, num_pages=128 // page_size,
+                        prefix_cache=False)
     prompts = [tok.encode("the pod the pod", add_bos=True),
                tok.encode("pvc bound pvc", add_bos=True)]
     with jax.default_matmul_precision("float32"):
-        ref = make_engine(cfg, ecfg, params, tok, **kw).generate(
+        ref = make_engine(cfg, ecfg, params, tok,
+                          use_kernel=False).generate(
             [list(p) for p in prompts], max_new_tokens=10)
         spec = make_engine(cfg, dataclasses.replace(ecfg, speculative_k=3),
-                           params, tok, cp_mesh=mesh, **kw)
+                           params, tok, cp_mesh=mesh, use_kernel=False)
         got = spec.generate([list(p) for p in prompts], max_new_tokens=10)
     for r, g in zip(ref, got):
-        assert r.token_ids == g.token_ids, paged
-    if paged:
-        spec.allocator.check()
+        assert r.token_ids == g.token_ids
+    spec.allocator.check()
 
 
 def test_cp_paged_partition_exhaustion_preempts_not_crashes(cpu_devices):
@@ -1141,7 +888,7 @@ def test_cp_paged_partition_exhaustion_preempts_not_crashes(cpu_devices):
     ecfg = EngineConfig(max_batch=2, max_seq_len=32, page_size=8,
                         num_pages=16, prefill_buckets=(16,),
                         max_new_tokens=12, temperature=0.0,
-                        prefix_cache=False, paged=True, decode_chunk=1)
+                        prefix_cache=False, decode_chunk=1)
     eng = PagedInferenceEngine(cfg, ecfg, params, tok, cp_mesh=mesh)
     # exhaust partition 1 (pages 8..15) so crossing position 16 cannot grow
     stolen = eng.allocator.alloc(8, owner=999, part=1)
@@ -1237,8 +984,8 @@ def test_sp_forward_matches_and_shards_sequence(cpu_devices):
 
 
 def test_sp_engine_matches_unsharded(cpu_devices):
-    """sp=True on both engines: TP prefill with sequence-parallel
-    activations emits the plain engine's greedy tokens."""
+    """sp=True: TP prefill with sequence-parallel activations emits the
+    plain engine's greedy tokens."""
     from k8s_llm_rca_tpu.config import TINY, EngineConfig
     from k8s_llm_rca_tpu.engine import make_engine
     from k8s_llm_rca_tpu.runtime.sharding import (
@@ -1254,76 +1001,45 @@ def test_sp_engine_matches_unsharded(cpu_devices):
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
     prompts = [tok.encode("pod crashloop kube-system", add_bos=True),
                tok.encode("node disk pressure taint", add_bos=True)]
-    for paged in (False, True):
-        ecfg = EngineConfig(max_batch=2, max_seq_len=64,
-                            prefill_buckets=(16, 32), max_new_tokens=6,
-                            temperature=0.0, paged=paged, page_size=16,
-                            num_pages=32, prefix_cache=False,
-                            decode_chunk=1)
-        kw = {"use_kernel": False} if paged else {}
-        with jax.default_matmul_precision("float32"):
-            ref = make_engine(cfg, ecfg, params, tok, **kw).generate(
-                prompts, max_new_tokens=6)
-            got = make_engine(cfg, ecfg, sharded, tok, tp_mesh=mesh,
-                              sp=True, **kw).generate(
-                prompts, max_new_tokens=6)
-        for r, g in zip(ref, got):
-            assert r.token_ids == g.token_ids, paged
+    ecfg = EngineConfig(max_batch=2, max_seq_len=64,
+                        prefill_buckets=(16, 32), max_new_tokens=6,
+                        temperature=0.0, page_size=16, num_pages=32,
+                        prefix_cache=False, decode_chunk=1)
+    with jax.default_matmul_precision("float32"):
+        ref = make_engine(cfg, ecfg, params, tok,
+                          use_kernel=False).generate(
+            prompts, max_new_tokens=6)
+        got = make_engine(cfg, ecfg, sharded, tok, tp_mesh=mesh,
+                          sp=True, use_kernel=False).generate(
+            prompts, max_new_tokens=6)
+    for r, g in zip(ref, got):
+        assert r.token_ids == g.token_ids
 
 
 def test_sp_requires_tp(cpu_devices):
     from k8s_llm_rca_tpu.config import TINY, EngineConfig
-    from k8s_llm_rca_tpu.engine.engine import InferenceEngine
+    from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
     from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
 
     cfg = TINY.replace(max_seq_len=64)
     with pytest.raises(ValueError, match="requires tp_mesh"):
-        InferenceEngine(cfg, EngineConfig(max_batch=2, max_seq_len=64,
-                                          prefill_buckets=(16,)),
-                        llama.init_params(cfg, jax.random.PRNGKey(0)),
-                        get_tokenizer(vocab_size=cfg.vocab_size), sp=True)
+        PagedInferenceEngine(
+            cfg, EngineConfig(max_batch=2, max_seq_len=64,
+                              prefill_buckets=(16,)),
+            llama.init_params(cfg, jax.random.PRNGKey(0)),
+            get_tokenizer(vocab_size=cfg.vocab_size), sp=True)
 
 
-@pytest.mark.parametrize("cp_mode", ["ring", "ulysses"])
-def test_cp_ep_composed_engine_matches_dense(cpu_devices, cp_mode):
+@pytest.mark.parametrize("cp_mode,page_size", [
+    ("ring", 8), ("ulysses", 8), ("ring", 16)])
+def test_cp_ep_composed_engine_matches_dense(cpu_devices, cp_mode,
+                                             page_size):
     """CP×EP in ONE mesh (long-context MoE serving: experts across the
     expert axis, sequence ring over 'seq'): CP prefill shards MoE tokens
     over (seq, expert) — the sequence never moves, dispatch rides the
-    expert all-to-all — decode tokens shard over (data, expert) against
-    the seq-sharded cache.  Exact greedy parity vs the dense engine."""
-    from k8s_llm_rca_tpu.config import TINY_MOE, EngineConfig
-    from k8s_llm_rca_tpu.engine.engine import InferenceEngine
-    from k8s_llm_rca_tpu.models import mixtral
-    from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
-
-    cfg = TINY_MOE.replace(max_seq_len=64, n_experts=4)
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    ecfg = EngineConfig(max_batch=2, max_seq_len=64,
-                        prefill_buckets=(16, 32, 64), max_new_tokens=6,
-                        temperature=0.0, decode_chunk=1)
-    prompts = [tok.encode("pod pending unschedulable node", add_bos=True),
-               tok.encode("pvc not bound storageclass", add_bos=True)]
-
-    mesh = mixtral.build_ep_mesh(2, n_data=1, n_seq=2,
-                                 devices=cpu_devices[:4])
-    sharded = mixtral.shard_params_ep(cfg, params, mesh)
-    with jax.default_matmul_precision("float32"):
-        ref = InferenceEngine(cfg, ecfg, params, tok).generate(
-            prompts, max_new_tokens=6)
-        eng = InferenceEngine(cfg, ecfg, sharded, tok, cp_mesh=mesh,
-                              ep_mesh=mesh, cp_mode=cp_mode)
-        got = eng.generate(prompts, max_new_tokens=6)
-    for r, g in zip(ref, got):
-        assert r.token_ids == g.token_ids, cp_mode
-    # the cache is genuinely sequence-sharded across the composed mesh
-    shard = eng.cache.k.sharding.shard_shape(eng.cache.k.shape)
-    assert shard[2] == cfg.max_seq_len // 2
-
-
-def test_cp_ep_composed_paged_engine_matches_dense(cpu_devices):
-    """CP×EP on the paged engine: ring prefill writes through the
-    page-scatter path while MoE MLPs dispatch over (seq, expert)."""
+    expert all-to-all — and writes through the page-scatter path; decode
+    tokens shard over (data, expert) against the seq-sharded pool.
+    Exact greedy parity vs the dense engine."""
     from k8s_llm_rca_tpu.config import TINY_MOE, EngineConfig
     from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
     from k8s_llm_rca_tpu.models import mixtral
@@ -1332,13 +1048,13 @@ def test_cp_ep_composed_paged_engine_matches_dense(cpu_devices):
     cfg = TINY_MOE.replace(max_seq_len=64, n_experts=4)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    ecfg = EngineConfig(max_batch=2, max_seq_len=64, paged=True,
-                        page_size=8, num_pages=32,
+    ecfg = EngineConfig(max_batch=2, max_seq_len=64, page_size=page_size,
+                        num_pages=256 // page_size,
                         prefill_buckets=(16, 32, 64), max_new_tokens=6,
                         temperature=0.0, prefix_cache=False,
                         decode_chunk=1)
-    prompts = [tok.encode("node notready kubelet stopped", add_bos=True),
-               tok.encode("image pull backoff", add_bos=True)]
+    prompts = [tok.encode("pod pending unschedulable node", add_bos=True),
+               tok.encode("pvc not bound storageclass", add_bos=True)]
 
     mesh = mixtral.build_ep_mesh(2, n_data=1, n_seq=2,
                                  devices=cpu_devices[:4])
@@ -1348,18 +1064,22 @@ def test_cp_ep_composed_paged_engine_matches_dense(cpu_devices):
                                    use_kernel=False).generate(
             prompts, max_new_tokens=6)
         eng = PagedInferenceEngine(cfg, ecfg, sharded, tok, cp_mesh=mesh,
-                                   ep_mesh=mesh, use_kernel=False)
+                                   ep_mesh=mesh, cp_mode=cp_mode,
+                                   use_kernel=False)
         got = eng.generate(prompts, max_new_tokens=6)
     for r, g in zip(ref, got):
         assert r.token_ids == g.token_ids
     eng.allocator.check()
+    # the pool is genuinely page-sharded across the composed mesh
+    shard = eng.pool.k.sharding.shard_shape(eng.pool.k.shape)
+    assert shard[1] == ecfg.num_pages // 2
 
 
 def test_cp_ep_requires_one_composed_mesh(cpu_devices):
     """CP×EP composes only on ONE mesh; distinct mesh objects are
     rejected, and prefill buckets must split over seq*expert."""
     from k8s_llm_rca_tpu.config import TINY_MOE, EngineConfig
-    from k8s_llm_rca_tpu.engine.engine import InferenceEngine
+    from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
     from k8s_llm_rca_tpu.models import mixtral
     from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
 
@@ -1369,62 +1089,31 @@ def test_cp_ep_requires_one_composed_mesh(cpu_devices):
     mesh_b = mixtral.build_ep_mesh(2, n_seq=2, devices=cpu_devices[4:8])
     ecfg = EngineConfig(max_batch=2, max_seq_len=64, prefill_buckets=(16,))
     with pytest.raises(ValueError, match="SAME composed mesh"):
-        InferenceEngine(cfg, ecfg, params, get_tokenizer(),
-                        cp_mesh=mesh_a, ep_mesh=mesh_b)
+        PagedInferenceEngine(cfg, ecfg, params, get_tokenizer(),
+                             cp_mesh=mesh_a, ep_mesh=mesh_b)
     with pytest.raises(ValueError, match="prefill token sharding"):
         # 18 splits over seq=2 but not over seq*expert=4
-        InferenceEngine(cfg, EngineConfig(max_batch=2, max_seq_len=64,
-                                          prefill_buckets=(18, 64)),
-                        params, get_tokenizer(), cp_mesh=mesh_a,
-                        ep_mesh=mesh_a)
+        PagedInferenceEngine(
+            cfg, EngineConfig(max_batch=2, max_seq_len=64,
+                              prefill_buckets=(18, 64)),
+            params, get_tokenizer(), cp_mesh=mesh_a, ep_mesh=mesh_a)
 
 
 # ---------------------------------------------------------------------------
-# PP ENGINE integration (round-2 review item 1): pp_mesh= on both engines
+# PP ENGINE integration (round-2 review item 1): pp_mesh=
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("kv_dtype", [None, "int8", "int4"])
-def test_pp_engine_matches_plain(cpu_devices, kv_dtype):
+@pytest.mark.parametrize("kv_dtype,page_size", [
+    (None, 16), ("int8", 16), ("int4", 16), (None, 8), ("int8", 8)])
+def test_pp_engine_matches_plain(cpu_devices, kv_dtype, page_size):
     """Serving PP: the continuous-batching engine with ``pp_mesh=`` — layer
-    axis of weights AND KV cache sharded over "stage", admissions through
-    the batched pipelined prefill, decode GPipe-microbatched — must emit
-    the plain engine's exact greedy tokens, incl. quantized KV (the
-    optimization that carries the big single-chip configs)."""
-    from k8s_llm_rca_tpu.config import EngineConfig
-    from k8s_llm_rca_tpu.engine import make_engine
-    from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
-
-    cfg = TINY.replace(max_seq_len=64, n_layers=4)
-    mesh = build_mesh(MeshConfig(stage=2), devices=cpu_devices[:2])
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    ecfg = EngineConfig(max_batch=4, max_seq_len=64,
-                        prefill_buckets=(16, 32), max_new_tokens=6,
-                        temperature=0.0, kv_cache_dtype=kv_dtype,
-                        decode_chunk=1)
-    tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    prompts = [tok.encode("pod pending unschedulable", add_bos=True),
-               tok.encode("pvc not bound", add_bos=True),
-               tok.encode("oom killed container", add_bos=True)]
-
-    with jax.default_matmul_precision("float32"):
-        ref = make_engine(cfg, ecfg, params, tok).generate(
-            prompts, max_new_tokens=6)
-        eng = make_engine(cfg, ecfg, params, tok, pp_mesh=mesh)
-        got = eng.generate(prompts, max_new_tokens=6)
-    for r, g in zip(ref, got):
-        assert r.token_ids == g.token_ids, kv_dtype
-    # the cache is genuinely stage-sharded: 1/P of the layer axis per device
-    shard = eng.cache.k.sharding.shard_shape(eng.cache.k.shape)
-    assert shard[0] == cfg.n_layers // 2
-
-
-@pytest.mark.parametrize("kv_dtype", [None, "int8"])
-def test_pp_paged_engine_matches_plain(cpu_devices, kv_dtype):
-    """Paged PP serving: the page pool's layer axis shards over "stage";
-    pipelined prefill scatters pages per stage and decode reads the
-    gathered local page view — exact greedy parity with the plain paged
-    engine, incl. continuous-batching admission/retirement churn."""
+    axis of weights AND page pool sharded over "stage", admissions through
+    the batched pipelined prefill (pages scattered per stage), decode
+    GPipe-microbatched over the gathered local page view — must emit the
+    plain engine's exact greedy tokens, incl. quantized KV (the
+    optimization that carries the big single-chip configs) and
+    continuous-batching admission/retirement churn."""
     from k8s_llm_rca_tpu.config import EngineConfig
     from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
     from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
@@ -1435,7 +1124,7 @@ def test_pp_paged_engine_matches_plain(cpu_devices, kv_dtype):
     ecfg = EngineConfig(max_batch=4, max_seq_len=64,
                         prefill_buckets=(16, 32), max_new_tokens=6,
                         temperature=0.0, kv_cache_dtype=kv_dtype,
-                        paged=True, page_size=16, num_pages=32,
+                        page_size=page_size, num_pages=512 // page_size,
                         prefix_cache=False, decode_chunk=1)
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
     prompts = [tok.encode("pod pending unschedulable", add_bos=True),
@@ -1451,6 +1140,7 @@ def test_pp_paged_engine_matches_plain(cpu_devices, kv_dtype):
         got = eng.generate(prompts, max_new_tokens=6)
     for r, g in zip(ref, got):
         assert r.token_ids == g.token_ids, kv_dtype
+    # the pool is genuinely stage-sharded: 1/P of the layer axis per device
     shard = eng.pool.k.sharding.shard_shape(eng.pool.k.shape)
     assert shard[0] == cfg.n_layers // 2
     eng.allocator.check()                      # no pages leaked under PP
@@ -1582,58 +1272,17 @@ def test_pp_engine_dfa_scan_parity(cpu_devices):
     jsonlib.loads(outs[1])
 
 
+@pytest.mark.parametrize("page_size", [16, 8])
 @pytest.mark.parametrize("kv_dtype", [None, "int8", "int4"])
-def test_pp_tp_composed_engine_matches_plain(cpu_devices, kv_dtype):
-    """PP×TP in ONE mesh (the multi-host pod topology: stages over DCN,
-    heads/hidden over ICI): weights shard (stage, model), the cache
-    shards layer-over-stage × kv-over-model, stage bodies run the
-    manual-TP block with psum combines — exact greedy parity with the
-    plain engine, through prefill, decode and the chunked scan.
-    Quantized KV composes: the pmax full-row scale makes int8/int4
-    PP×TP bit-identical to the plain quantized engine."""
-    from k8s_llm_rca_tpu.config import TINY, EngineConfig
-    from k8s_llm_rca_tpu.engine import make_engine
-    from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
-
-    cfg = TINY.replace(n_layers=4, max_seq_len=64)
-    mesh = build_mesh(MeshConfig(stage=2, model=2),
-                      devices=cpu_devices[:4])
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    prompts = [tok.encode("pod crashloop kube-system", add_bos=True),
-               tok.encode("node disk pressure taint", add_bos=True)]
-    for chunk in (1, 4):
-        ecfg = EngineConfig(max_batch=2, max_seq_len=64,
-                            prefill_buckets=(16, 32), max_new_tokens=6,
-                            temperature=0.0, decode_chunk=chunk,
-                            kv_cache_dtype=kv_dtype)
-        with jax.default_matmul_precision("float32"):
-            ref = make_engine(cfg, ecfg, params, tok).generate(
-                prompts, max_new_tokens=6)
-            eng = make_engine(cfg, ecfg, params, tok, pp_mesh=mesh,
-                              tp_mesh=mesh)
-            got = eng.generate(prompts, max_new_tokens=6)
-        for r, g in zip(ref, got):
-            assert r.token_ids == g.token_ids, (kv_dtype, chunk)
-    # the cache is genuinely sharded on BOTH axes
-    shard = eng.cache.k.sharding.shard_shape(eng.cache.k.shape)
-    assert shard[0] == cfg.n_layers // 2           # layers over 'stage'
-    assert shard[3] == eng.cache.k.shape[3] // 2   # kv over 'model'
-    if kv_dtype is not None:
-        # scale caches shard layer-over-stage, replicate across model
-        sc = eng.cache.k_scale.sharding.shard_shape(eng.cache.k_scale.shape)
-        assert sc[0] == cfg.n_layers // 2
-
-
-@pytest.mark.parametrize("kv_dtype", [None, "int8", "int4"])
-def test_pp_tp_paged_engine_matches_plain(cpu_devices, kv_dtype):
-    """Paged PP×TP — the realistic multi-host pod serving shape (paged
-    KV + continuous batching, stages over DCN, TP over ICI): weights
-    shard (stage, model), the pool shards layer-over-stage ×
-    kv-over-model, stage bodies run manual-TP qkv/attention with psum
+def test_pp_tp_composed_engine_matches_plain(cpu_devices, kv_dtype,
+                                             page_size):
+    """PP×TP in ONE mesh — the realistic multi-host pod serving shape
+    (paged KV + continuous batching, stages over DCN, heads/hidden over
+    ICI): weights shard (stage, model), the pool shards layer-over-stage
+    × kv-over-model, stage bodies run manual-TP qkv/attention with psum
     combines.  Quantized pools (int8 + packed int4) compose via the pmax
-    full-row scale, so greedy parity with the plain paged engine is
-    exact — through admission churn, page growth and the chunked scan."""
+    full-row scale, so greedy parity with the plain engine is exact —
+    through admission churn, page growth and the chunked scan."""
     from k8s_llm_rca_tpu.config import EngineConfig
     from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
     from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
@@ -1652,7 +1301,8 @@ def test_pp_tp_paged_engine_matches_plain(cpu_devices, kv_dtype):
         ecfg = EngineConfig(max_batch=4, max_seq_len=64,
                             prefill_buckets=(16, 32), max_new_tokens=6,
                             temperature=0.0, kv_cache_dtype=kv_dtype,
-                            paged=True, page_size=16, num_pages=32,
+                            page_size=page_size,
+                            num_pages=512 // page_size,
                             prefix_cache=False, decode_chunk=chunk)
         with jax.default_matmul_precision("float32"):
             ref = PagedInferenceEngine(cfg, ecfg, params, tok).generate(
@@ -1668,18 +1318,19 @@ def test_pp_tp_paged_engine_matches_plain(cpu_devices, kv_dtype):
     assert shard[0] == cfg.n_layers // 2           # layers over 'stage'
     assert shard[3] == eng.pool.k.shape[3] // 2    # kv over 'model'
     if kv_dtype is not None:
+        # scale pools shard layer-over-stage, replicate across model
         sc = eng.pool.k_scale.sharding.shard_shape(eng.pool.k_scale.shape)
         assert sc[0] == cfg.n_layers // 2
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_pp_ep_composed_engine_matches_dense(cpu_devices, paged):
+@pytest.mark.parametrize("page_size", [16, 8])
+def test_pp_ep_composed_engine_matches_dense(cpu_devices, page_size):
     """PP×EP in ONE mesh (Mixtral across pods: stages over DCN, expert
     dispatch over ICI within each stage): stacked expert weights shard
     (stage, expert), stage bodies run dense attention on the replicated
     stream and route each expert peer's token slice through the shared
     all-to-all dispatch — exact greedy parity with the dense
-    single-device engine, on both the contiguous and the paged engine."""
+    single-device engine."""
     from k8s_llm_rca_tpu.config import TINY_MOE, EngineConfig
     from k8s_llm_rca_tpu.engine import make_engine
     from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
@@ -1692,39 +1343,37 @@ def test_pp_ep_composed_engine_matches_dense(cpu_devices, paged):
     prompts = [tok.encode("pod pending unschedulable", add_bos=True),
                tok.encode("pvc not bound", add_bos=True),
                tok.encode("oom killed container", add_bos=True)]
-    extra = (dict(paged=True, page_size=16, num_pages=32,
-                  prefix_cache=False) if paged else {})
     for chunk in (1, 4):
         ecfg = EngineConfig(max_batch=4, max_seq_len=64,
                             prefill_buckets=(16, 32), max_new_tokens=6,
-                            temperature=0.0, decode_chunk=chunk, **extra)
-        kw = dict(use_kernel=False) if paged else {}
+                            temperature=0.0, decode_chunk=chunk,
+                            page_size=page_size,
+                            num_pages=512 // page_size,
+                            prefix_cache=False)
         with jax.default_matmul_precision("float32"):
             ref = make_engine(cfg, ecfg, params, tok).generate(
                 prompts, max_new_tokens=6)
             eng = make_engine(cfg, ecfg, params, tok, pp_mesh=mesh,
-                              ep_mesh=mesh, **kw)
+                              ep_mesh=mesh, use_kernel=False)
             got = eng.generate(prompts, max_new_tokens=6)
         for r, g in zip(ref, got):
-            assert r.token_ids == g.token_ids, (paged, chunk)
+            assert r.token_ids == g.token_ids, chunk
     # expert weights genuinely sharded on BOTH axes: stage × expert
     _, stacked = eng.params
     shard = stacked["w_gate"].sharding.shard_shape(stacked["w_gate"].shape)
     assert shard[0] == 1                            # stages split
     assert shard[2] == cfg.n_experts // 2           # experts split
-    if paged:
-        eng.allocator.check()
+    eng.allocator.check()
 
 
-@pytest.mark.parametrize("paged", [False, True])
+@pytest.mark.parametrize("page_size", [16, 8])
 @pytest.mark.parametrize("draft", ["ngram", "model", "ngram-int8"])
-def test_pp_speculative_matches_plain(cpu_devices, paged, draft):
-    """Speculation composes with PP on both engines: the verify step runs
-    the PIPELINED multi-token decode (llama_pp_decode_multi /
-    paged_pp_decode_multi) over the stage-sharded cache/pool, with exact
-    greedy parity against the non-speculative non-PP engine — for n-gram
-    drafts, a draft MODEL, and an int8-quantized cache/pool (the
-    pipelined verify's quantized scale-write path)."""
+def test_pp_speculative_matches_plain(cpu_devices, page_size, draft):
+    """Speculation composes with PP: the verify step runs the PIPELINED
+    multi-token decode (paged_pp_decode_multi) over the stage-sharded
+    pool, with exact greedy parity against the non-speculative non-PP
+    engine — for n-gram drafts, a draft MODEL, and an int8-quantized
+    pool (the pipelined verify's quantized scale-write path)."""
     import dataclasses
 
     from k8s_llm_rca_tpu.config import TINY, EngineConfig
@@ -1735,31 +1384,31 @@ def test_pp_speculative_matches_plain(cpu_devices, paged, draft):
     mesh = build_mesh(MeshConfig(stage=2), devices=cpu_devices[:2])
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    extra = (dict(paged=True, page_size=16, num_pages=32,
-                  prefix_cache=False) if paged else {})
+    extra = dict(page_size=page_size, num_pages=512 // page_size,
+                 prefix_cache=False)
     if draft == "ngram-int8":
         extra["kv_cache_dtype"] = "int8"
-    kw = dict(use_kernel=False) if paged else {}
     dm = dict(draft_model=(cfg, params)) if draft == "model" else {}
     ecfg = EngineConfig(max_batch=2, max_seq_len=64, prefill_buckets=(16,),
                         max_new_tokens=10, temperature=0.0, **extra)
     prompts = [tok.encode("the pod the pod", add_bos=True),
                tok.encode("pvc bound pvc", add_bos=True)]
     with jax.default_matmul_precision("float32"):
-        ref = make_engine(cfg, ecfg, params, tok, **kw).generate(
+        ref = make_engine(cfg, ecfg, params, tok,
+                          use_kernel=False).generate(
             [list(p) for p in prompts], max_new_tokens=10)
         spec = make_engine(cfg, dataclasses.replace(ecfg, speculative_k=3),
-                           params, tok, pp_mesh=mesh, **kw, **dm)
+                           params, tok, pp_mesh=mesh, use_kernel=False,
+                           **dm)
         got = spec.generate([list(p) for p in prompts], max_new_tokens=10)
     for r, g in zip(ref, got):
-        assert r.token_ids == g.token_ids, (paged, draft)
-    if paged:
-        spec.allocator.check()
+        assert r.token_ids == g.token_ids, draft
+    spec.allocator.check()
 
 
 def test_pp_composed_speculative_matches_plain(cpu_devices):
-    """Speculation through the COMPOSED pipelined verify: PP×TP (paged,
-    the pod serving shape) and PP×EP (MoE) both match their
+    """Speculation through the COMPOSED pipelined verify: PP×TP (the
+    pod serving shape) and PP×EP (MoE) both match their
     non-speculative plain engines exactly."""
     import dataclasses
 
@@ -1769,7 +1418,7 @@ def test_pp_composed_speculative_matches_plain(cpu_devices):
 
     prompts_txt = ["the pod the pod", "pvc bound pvc"]
     with jax.default_matmul_precision("float32"):
-        # PP×TP × spec on the paged engine
+        # PP×TP × spec
         cfg = TINY.replace(n_layers=4, max_seq_len=64)
         mesh = build_mesh(MeshConfig(stage=2, model=2),
                           devices=cpu_devices[:4])
@@ -1778,7 +1427,7 @@ def test_pp_composed_speculative_matches_plain(cpu_devices):
         prompts = [tok.encode(t, add_bos=True) for t in prompts_txt]
         ecfg = EngineConfig(max_batch=2, max_seq_len=64,
                             prefill_buckets=(16,), max_new_tokens=8,
-                            temperature=0.0, paged=True, page_size=16,
+                            temperature=0.0, page_size=16,
                             num_pages=32, prefix_cache=False)
         ref = make_engine(cfg, ecfg, params, tok,
                           use_kernel=False).generate(
@@ -1800,7 +1449,7 @@ def test_pp_composed_speculative_matches_plain(cpu_devices):
         mp = [mtok.encode(t, add_bos=True) for t in prompts_txt]
         mecfg = EngineConfig(max_batch=4, max_seq_len=64,
                              prefill_buckets=(16,), max_new_tokens=8,
-                             temperature=0.0)
+                             temperature=0.0, prefix_cache=False)
         mref = make_engine(mcfg, mecfg, mparams, mtok).generate(
             [list(p) for p in mp], max_new_tokens=8)
         mspec = make_engine(mcfg,
@@ -1811,9 +1460,10 @@ def test_pp_composed_speculative_matches_plain(cpu_devices):
             assert r.token_ids == g.token_ids
 
 
-@pytest.mark.parametrize("paged", [False, True])
+@pytest.mark.parametrize("page_size", [16, 8])
 @pytest.mark.parametrize("bits", [8, 4])
-def test_pp_tp_quantized_weights_matches_plain(cpu_devices, paged, bits):
+def test_pp_tp_quantized_weights_matches_plain(cpu_devices, page_size,
+                                               bits):
     """Quantized WEIGHTS compose with PP×TP (the quantized-flagship pod
     serving shape): stacked QuantTensor leaves shard their payload on
     the weight spec and their per-channel scales with reduced dims
@@ -1834,31 +1484,29 @@ def test_pp_tp_quantized_weights_matches_plain(cpu_devices, paged, bits):
         llama.init_params(cfg, jax.random.PRNGKey(0)),
         compute_dtype=jnp.float32, bits=bits)
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    extra = (dict(paged=True, page_size=16, num_pages=32,
-                  prefix_cache=False) if paged else {})
-    kw = dict(use_kernel=False) if paged else {}
     ecfg = EngineConfig(max_batch=2, max_seq_len=64,
                         prefill_buckets=(16, 32), max_new_tokens=6,
                         temperature=0.0,
                         kv_cache_dtype="int8" if bits == 8 else "int4",
-                        **extra)
+                        page_size=page_size, num_pages=512 // page_size,
+                        prefix_cache=False)
     prompts = [tok.encode("pod crashloop kube-system", add_bos=True),
                tok.encode("node disk pressure taint", add_bos=True)]
     with jax.default_matmul_precision("float32"):
-        ref = make_engine(cfg, ecfg, params, tok, **kw).generate(
+        ref = make_engine(cfg, ecfg, params, tok,
+                          use_kernel=False).generate(
             prompts, max_new_tokens=6)
         eng = make_engine(cfg, ecfg, params, tok, pp_mesh=mesh,
-                          tp_mesh=mesh, **kw)
+                          tp_mesh=mesh, use_kernel=False)
         got = eng.generate(prompts, max_new_tokens=6)
     for r, g in zip(ref, got):
-        assert r.token_ids == g.token_ids, paged
+        assert r.token_ids == g.token_ids
     # the int8 payloads are genuinely sharded on BOTH axes
     _, stacked = eng.params
     shard = stacked["wq"].q.sharding.shard_shape(stacked["wq"].q.shape)
     assert shard[0] == 1                          # stages split
     assert shard[3] == stacked["wq"].q.shape[3] // 2   # columns over model
-    if paged:
-        eng.allocator.check()
+    eng.allocator.check()
 
 
 def test_pp_tp_exclusions(cpu_devices):
@@ -1889,14 +1537,6 @@ def test_pp_tp_exclusions(cpu_devices):
     with pytest.raises(ValueError, match="per-shard split-half"):
         make_engine(odd_cfg, ecfg, odd_params, tok,
                     pp_mesh=mesh, tp_mesh=mesh)
-    with pytest.raises(ValueError, match="per-shard split-half"):
-        # the paged engine applies the same divisibility rejection
-        make_engine(odd_cfg, dataclasses.replace(ecfg, paged=True,
-                                                 page_size=16,
-                                                 num_pages=16,
-                                                 prefix_cache=False),
-                    odd_params, tok,
-                    pp_mesh=mesh, tp_mesh=mesh, use_kernel=False)
     with pytest.raises(ValueError, match="MoE"):
         moe_cfg = TINY_MOE.replace(n_layers=4, n_experts=4, max_seq_len=64)
         make_engine(moe_cfg, ecfg,
@@ -1949,12 +1589,12 @@ def test_pp_mesh_validation(cpu_devices):
         ppep = build_mesh(MeshConfig(stage=2, expert=2),
                           devices=cpu_devices[:4])
         PagedInferenceEngine(
-            moe_cfg4, EngineConfig(paged=True, page_size=16, num_pages=32,
+            moe_cfg4, EngineConfig(page_size=16, num_pages=32,
                                    prefix_cache=True, **base),
             llama.init_params(moe_cfg4, jax.random.PRNGKey(3)), tok,
             pp_mesh=ppep, ep_mesh=ppep, use_kernel=False)
     with pytest.raises(ValueError, match="use_kernel"):
         PagedInferenceEngine(
-            cfg, EngineConfig(paged=True, page_size=16, num_pages=32,
+            cfg, EngineConfig(page_size=16, num_pages=32,
                               prefix_cache=False, **base),
             params, tok, pp_mesh=pp, use_kernel=True)

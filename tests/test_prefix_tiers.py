@@ -53,7 +53,7 @@ PROMPTS = (_PRE + "kubelet crashloop on node-7",
 
 def _ecfg(**over):
     base = dict(max_batch=2, max_seq_len=128, prefill_buckets=(64, 128),
-                max_new_tokens=16, temperature=0.0, paged=True,
+                max_new_tokens=16, temperature=0.0,
                 page_size=16, num_pages=40, prefix_cache=True,
                 decode_chunk=4)
     base.update(over)
@@ -510,22 +510,6 @@ class TestAcceptanceSweep:
 
 
 class TestExclusions:
-    def test_contiguous_engine_rejects_tiers(self, setup):
-        cfg, params, tok = setup
-        with pytest.raises(ValueError, match="paged"):
-            make_engine(cfg, EngineConfig(
-                max_batch=2, max_seq_len=128, prefill_buckets=(64, 128),
-                max_new_tokens=8, temperature=0.0,
-                prefix_host_pages=8), params, tok)
-
-    def test_contiguous_engine_rejects_shared_store(self, setup):
-        cfg, params, tok = setup
-        with pytest.raises(ValueError, match="paged"):
-            make_engine(cfg, EngineConfig(
-                max_batch=2, max_seq_len=128, prefill_buckets=(64, 128),
-                max_new_tokens=8, temperature=0.0), params, tok,
-                prefix_store=PrefixStore(host_pages=8))
-
     def test_cp_mesh_rejects_tiers(self, setup, cpu_devices):
         from k8s_llm_rca_tpu.runtime.mesh import build_mesh
 
@@ -544,19 +528,16 @@ class TestExclusions:
             make_engine(cfg, _ecfg(prefix_host_pages=8), params, tok,
                         use_kernel=False, pp_mesh=mesh)
 
-    def test_negative_and_inconsistent_knobs_reject(self, setup,
-                                                    tmp_path):
+    @pytest.mark.parametrize("knobs,refusal", [
+        (dict(prefix_host_pages=-1), "must be >= 0"),
+        (dict(prefix_disk_pages=4), "needs prefix_disk_dir"),
+        (dict(prefix_cache=False, prefix_host_pages=8),
+         "prefix_cache=True")])
+    def test_negative_and_inconsistent_knobs_reject(self, setup, knobs,
+                                                    refusal):
         cfg, params, tok = setup
-        with pytest.raises(ValueError, match="must be >= 0"):
-            make_engine(cfg, _ecfg(prefix_host_pages=-1), params, tok,
-                        use_kernel=False)
-        with pytest.raises(ValueError, match="needs prefix_disk_dir"):
-            make_engine(cfg, _ecfg(prefix_disk_pages=4), params, tok,
-                        use_kernel=False)
-        with pytest.raises(ValueError, match="prefix_cache=True"):
-            make_engine(cfg, _ecfg(prefix_cache=False,
-                                   prefix_host_pages=8),
-                        params, tok, use_kernel=False)
+        with pytest.raises(ValueError, match=refusal):
+            make_engine(cfg, _ecfg(**knobs), params, tok, use_kernel=False)
 
     def test_store_validates_its_own_knobs(self):
         with pytest.raises(ValueError, match="must be >= 0"):

@@ -498,7 +498,7 @@ class TestEngineProcParity:
         cfg = TINY.replace(max_seq_len=2560)
         ecfg = EngineConfig(max_batch=4, max_seq_len=2560,
                             prefill_buckets=(2560,), max_new_tokens=96,
-                            temperature=0.0, paged=True, page_size=64,
+                            temperature=0.0, page_size=64,
                             num_pages=168, prefix_cache=False,
                             decode_chunk=16)
         tok = get_tokenizer(vocab_size=cfg.vocab_size)

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from k8s_llm_rca_tpu.config import TINY, TINY_MOE, EngineConfig
-from k8s_llm_rca_tpu.engine.engine import InferenceEngine
+from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
 from k8s_llm_rca_tpu.models import llama
 from k8s_llm_rca_tpu.models.quant import (
     QuantTensor, dq, gather_rows, quantize, quantize_params,
@@ -86,7 +86,7 @@ def test_engine_runs_with_quantized_params():
                         prefill_buckets=(16, 32, 64), max_new_tokens=6,
                         temperature=0.0)
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    eng = InferenceEngine(cfg, ecfg, params, tok)
+    eng = PagedInferenceEngine(cfg, ecfg, params, tok)
     res = eng.generate([tok.encode("pod oom", add_bos=True)],
                        max_new_tokens=6)
     assert res[0].completion_tokens == 6
@@ -111,7 +111,6 @@ def test_gather_rows_rejects_column_scales():
 
 
 def test_paged_engine_runs_with_quantized_params():
-    from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
 
     cfg = TINY.replace(max_seq_len=64)
     params = quantize_params(llama.init_params(cfg, jax.random.PRNGKey(0)))
@@ -239,7 +238,7 @@ def test_int4_engine_generates():
                         prefill_buckets=(16, 32, 64), max_new_tokens=6,
                         temperature=0.0)
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
-    eng = InferenceEngine(cfg, ecfg, params, tok)
+    eng = PagedInferenceEngine(cfg, ecfg, params, tok)
     res = eng.generate([tok.encode("pod oom", add_bos=True)],
                        max_new_tokens=6)
     assert res[0].completion_tokens == 6

@@ -8,7 +8,7 @@ Three layers of coverage, all hermetic on CPU:
   multi-block grid paths execute (the tests/test_kernels.py pattern);
 - ENGINE greedy byte-parity with ``fused_quant_matmul=True`` (the shim
   falls back to the identical dq() expression off-TPU — the flag must be
-  token-inert for contiguous, paged and GSPMD-TP serving), plus the
+  token-inert for single-device and GSPMD-TP serving), plus the
   chunked-prefill tick budget's byte-parity against monolithic prefill;
 - LOUD EXCLUSIONS: every unsupported composition documented in
   ops/quant_matmul.py and the prefill_chunk_budget validation raises a
@@ -193,7 +193,7 @@ class TestShimsAndExclusions:
 # ---------------------------------------------------------------------------
 
 
-def _quant_engine(model_cfg, bits=4, fused=False, paged=True, params=None,
+def _quant_engine(model_cfg, bits=4, fused=False, params=None,
                   cp_mesh=None, pp_mesh=None, **ecfg_kw):
     from k8s_llm_rca_tpu.engine import make_engine
     from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
@@ -204,31 +204,28 @@ def _quant_engine(model_cfg, bits=4, fused=False, paged=True, params=None,
             compute_dtype=jnp.float32, bits=bits)
     defaults = dict(max_batch=2, max_seq_len=64, page_size=8,
                     num_pages=64, prefill_buckets=(16, 32, 64),
-                    max_new_tokens=6, temperature=0.0, paged=paged,
-                    prefix_cache=False)
+                    max_new_tokens=6, temperature=0.0, prefix_cache=False)
     defaults.update(ecfg_kw)
     cfg = model_cfg.replace(max_seq_len=64,
                             fused_quant_matmul=fused)
     tok = get_tokenizer(vocab_size=model_cfg.vocab_size)
-    kw = {"use_kernel": False} if paged else {}
+    kw = {}
     if cp_mesh is not None:
         kw["cp_mesh"] = cp_mesh
     if pp_mesh is not None:
         kw["pp_mesh"] = pp_mesh
-    return make_engine(cfg, EngineConfig(**defaults), params, tok, **kw), tok
+    return make_engine(cfg, EngineConfig(**defaults), params, tok,
+                       use_kernel=False, **kw), tok
 
 
 class TestEngineFusedFlagParity:
-    # only the flagship int4-paged cell rides the tier-1 gate (each cell
-    # compiles two engines, ~5-7 s); the rest run under -m slow
-    @pytest.mark.parametrize(
-        "paged", [pytest.param(False, marks=pytest.mark.slow), True])
+    # only the flagship int4 cell rides the tier-1 gate (each cell
+    # compiles two engines, ~5-7 s); int8 runs under -m slow
     @pytest.mark.parametrize(
         "bits", [pytest.param(8, marks=pytest.mark.slow), 4])
-    def test_greedy_byte_parity(self, paged, bits):
-        ref_eng, tok = _quant_engine(TINY, bits=bits, paged=paged)
-        fused_eng, _ = _quant_engine(TINY, bits=bits, fused=True,
-                                     paged=paged)
+    def test_greedy_byte_parity(self, bits):
+        ref_eng, tok = _quant_engine(TINY, bits=bits)
+        fused_eng, _ = _quant_engine(TINY, bits=bits, fused=True)
         prompts = [tok.encode(t, add_bos=True) for t in
                    ["pod crashloop backoff", "pvc pending why"]]
         ref = ref_eng.generate([list(p) for p in prompts],
@@ -365,13 +362,11 @@ class TestPrefillChunkBudget:
         assert entry["generated"] == []
         assert entry["remaining_new_tokens"] == 4
 
-    def test_contiguous_engine_rejects_budget(self):
-        with pytest.raises(ValueError, match="paged-engine"):
-            _quant_engine(TINY, paged=False, prefill_chunk_budget=16)
-
-    def test_non_page_multiple_budget_rejects(self):
-        with pytest.raises(ValueError, match="multiple of page_size"):
-            _quant_engine(TINY, prefill_chunk_budget=12)   # 12 % 8 != 0
+    @pytest.mark.parametrize("budget", [12, -8])   # 12 % 8 != 0; below 0
+    def test_non_page_multiple_budget_rejects(self, budget):
+        with pytest.raises(ValueError,
+                           match="positive multiple of page_size"):
+            _quant_engine(TINY, prefill_chunk_budget=budget)
 
     def test_cp_mesh_rejects_budget(self, cpu_devices):
         from k8s_llm_rca_tpu.runtime.mesh import build_mesh

@@ -279,7 +279,7 @@ class TestRestartEngineParity:
         # so the run is genuinely MID-decode when the wedge lands
         ecfg = EngineConfig(max_batch=2, max_seq_len=64,
                             prefill_buckets=(16, 32), max_new_tokens=6,
-                            temperature=0.0, paged=True, page_size=8,
+                            temperature=0.0, page_size=8,
                             num_pages=32, decode_chunk=1)
         tok = get_tokenizer(vocab_size=cfg.vocab_size)
         prompts = ["pod pending unschedulable node affinity mismatch",

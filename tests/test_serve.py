@@ -8,7 +8,7 @@ import jax
 import pytest
 
 from k8s_llm_rca_tpu.config import TINY, EngineConfig
-from k8s_llm_rca_tpu.engine import InferenceEngine
+from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
 from k8s_llm_rca_tpu.models import llama
 from k8s_llm_rca_tpu.serve import (
     AssistantService, EchoBackend, EngineBackend, GenericAssistant, RunStatus,
@@ -133,7 +133,7 @@ def test_engine_backend_end_to_end():
     cfg = TINY
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     tok = get_tokenizer()
-    engine = InferenceEngine(
+    engine = PagedInferenceEngine(
         cfg, EngineConfig(max_batch=4, max_seq_len=256,
                           prefill_buckets=(64, 128), max_new_tokens=8),
         params, tok)
@@ -235,7 +235,6 @@ def test_scan_tick_matches_stepwise_near_cache_cap():
     import jax
 
     from k8s_llm_rca_tpu.config import TINY, EngineConfig
-    from k8s_llm_rca_tpu.engine.engine import InferenceEngine
     from k8s_llm_rca_tpu.models import llama
     from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
 
@@ -245,7 +244,7 @@ def test_scan_tick_matches_stepwise_near_cache_cap():
     prompt = list(range(5, 25))           # 20 tokens; cap at 32
 
     def run(chunk):
-        eng = InferenceEngine(
+        eng = PagedInferenceEngine(
             cfg, EngineConfig(max_batch=1, max_seq_len=32,
                               prefill_buckets=(32,), max_new_tokens=30,
                               temperature=0.0, decode_chunk=chunk),

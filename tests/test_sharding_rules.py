@@ -12,7 +12,7 @@
   deterministic page-size re-chunk the tier handoff rides, plus its
   loud refusals;
 - **exact greedy parity** (slow, virtual 8-device CPU mesh): fsdp and
-  fsdp×tp sharded engines (contiguous AND paged) decode byte-identically
+  fsdp×tp sharded engines decode byte-identically
   to the plain single-device engine, a 1P+2D TierRouter fleet with
   DIFFERING per-tier KV page sizes settles byte-identically, and a
   mid-decode export adopts across the page-size boundary with the
@@ -309,15 +309,6 @@ class TestTierGeometry:
         TierRouter([self._replica(0, kv_layout=a)],
                    [self._replica(1, kv_layout=b)])
 
-    def test_paged_vs_contiguous_mix_is_refused(self):
-        from k8s_llm_rca_tpu.cluster.disagg import TierRouter
-
-        a = {"page_size": 16, "kv_dtype": None, "kv_dim": 64, "n_layers": 2}
-        b = dict(a, page_size=None)
-        with pytest.raises(ValueError, match="same cache kind"):
-            TierRouter([self._replica(0, kv_layout=a)],
-                       [self._replica(1, kv_layout=b)])
-
     def test_scripted_replicas_skip_geometry_checks(self):
         from k8s_llm_rca_tpu.cluster.disagg import TierRouter
 
@@ -354,17 +345,12 @@ class TestTierGeometry:
 # ---------------------------------------------------------------------------
 
 
-def _engine_kw(ecfg):
-    # the kernel toggle exists only on the paged engine
-    return {"use_kernel": False} if ecfg.paged else {}
-
-
 def _plain_reference(cfg, ecfg, params, tok, prompt, opts):
     from k8s_llm_rca_tpu.engine import make_engine
     from k8s_llm_rca_tpu.serve.backend import EngineBackend
 
     ref = EngineBackend(make_engine(cfg, ecfg, params, tok,
-                                    **_engine_kw(ecfg)))
+                                    use_kernel=False))
     h = ref.start(prompt, opts)
     while True:
         res = ref.pump().get(h)
@@ -380,14 +366,12 @@ class TestFsdpGreedyParity:
     GSPMD inserts the all-gathers (committed-input propagation) whether
     or not the engine also receives the mesh for cache placement."""
 
-    @pytest.mark.parametrize("paged", [False, True])
     @pytest.mark.parametrize("axes,pass_mesh", [
         ({"fsdp": 4}, False),                 # fsdp-only, params-committed
         ({"fsdp": 4}, True),                  # fsdp-only + cache placement
         ({"fsdp": 2, "model": 2}, True),      # fsdp×tp on one mesh
     ])
-    def test_fsdp_matches_plain_engine(self, cpu_devices, paged, axes,
-                                       pass_mesh):
+    def test_fsdp_matches_plain_engine(self, cpu_devices, axes, pass_mesh):
         from k8s_llm_rca_tpu.engine import make_engine
         from k8s_llm_rca_tpu.models import llama
         from k8s_llm_rca_tpu.runtime.sharding import (
@@ -397,9 +381,8 @@ class TestFsdpGreedyParity:
 
         cfg = TINY.replace(max_seq_len=64)
         knobs = dict(max_batch=2, max_seq_len=64, prefill_buckets=(32,),
-                     max_new_tokens=8, temperature=0.0, prefix_cache=False)
-        if paged:
-            knobs.update(paged=True, page_size=8, num_pages=24)
+                     max_new_tokens=8, temperature=0.0, prefix_cache=False,
+                     page_size=8, num_pages=24)
         ecfg = EngineConfig(**knobs)
         params = llama.init_params(cfg, jax.random.PRNGKey(0))
         tok = get_tokenizer(vocab_size=cfg.vocab_size)
@@ -412,12 +395,11 @@ class TestFsdpGreedyParity:
         layout = validate_layout(FSDP_LAYOUT, mesh)
         sharded = shard_pytree(params, llama_param_specs(cfg, layout),
                                mesh)
-        kw = {}
+        kw = {"use_kernel": False}
         if pass_mesh:
             kw["fsdp_mesh"] = mesh
             if axes.get("model", 1) > 1:
                 kw["tp_mesh"] = mesh
-        kw.update(_engine_kw(ecfg))
         backend = EngineBackend(make_engine(cfg, ecfg, sharded, tok, **kw))
         h = backend.start(prompt, opts)
         while True:
@@ -454,7 +436,7 @@ class TestPerTierLayoutParity:
         cfg = TINY.replace(max_seq_len=512)
         ecfg = EngineConfig(max_batch=2, max_seq_len=512,
                             prefill_buckets=(512,), max_new_tokens=16,
-                            temperature=0.0, paged=True, page_size=16,
+                            temperature=0.0, page_size=16,
                             num_pages=96, prefix_cache=False)
         ecfg_d = dataclasses.replace(ecfg, page_size=page_size_decode,
                                      num_pages=96 * 16 // page_size_decode)
@@ -503,7 +485,7 @@ class TestPerTierLayoutParity:
         cfg = TINY.replace(max_seq_len=64)
         params = llama.init_params(cfg, jax.random.PRNGKey(0))
         tok = get_tokenizer(vocab_size=cfg.vocab_size)
-        knobs = dict(max_batch=2, max_seq_len=64, paged=True, page_size=8,
+        knobs = dict(max_batch=2, max_seq_len=64, page_size=8,
                      num_pages=24, prefill_buckets=(16, 32),
                      max_new_tokens=8, temperature=0.0, decode_chunk=1,
                      prefix_cache=False)
@@ -551,7 +533,7 @@ class TestPerTierLayoutParity:
         cfg = TINY.replace(max_seq_len=64)
         params = llama.init_params(cfg, jax.random.PRNGKey(0))
         tok = get_tokenizer(vocab_size=cfg.vocab_size)
-        knobs = dict(max_batch=2, max_seq_len=64, paged=True, page_size=8,
+        knobs = dict(max_batch=2, max_seq_len=64, page_size=8,
                      num_pages=24, prefill_buckets=(16, 32),
                      max_new_tokens=8, temperature=0.0, decode_chunk=1,
                      prefix_cache=False)

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from k8s_llm_rca_tpu.config import TINY, EngineConfig
-from k8s_llm_rca_tpu.engine.engine import InferenceEngine
+from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
 from k8s_llm_rca_tpu.engine.speculative import ngram_draft
 from k8s_llm_rca_tpu.models import llama
 from k8s_llm_rca_tpu.utils.logging import METRICS
@@ -75,13 +75,18 @@ class TestSpeculativeEngine:
         cfg = TINY.replace(max_seq_len=128)
         params = llama.init_params(cfg, jax.random.PRNGKey(0))
         tok = get_tokenizer(vocab_size=cfg.vocab_size)
-        base = dict(max_batch=2, max_seq_len=128,
-                    prefill_buckets=(32, 64, 128), max_new_tokens=24,
-                    temperature=0.0)
+        # page 8 against k+1 = 5: about half the ticks have no room for
+        # the verify write and take one plain step instead
+        # (TestPagedSpeculative runs page 16, where nearly all have room)
+        base = dict(max_batch=2, max_seq_len=128, page_size=8,
+                    num_pages=64, prefill_buckets=(32, 64, 128),
+                    max_new_tokens=24, temperature=0.0, decode_chunk=1)
         base.update(kw)
-        plain = InferenceEngine(cfg, EngineConfig(**base), params, tok)
-        spec = InferenceEngine(
-            cfg, EngineConfig(speculative_k=4, **base), params, tok)
+        plain = PagedInferenceEngine(cfg, EngineConfig(**base), params, tok,
+                                     use_kernel=False)
+        spec = PagedInferenceEngine(
+            cfg, EngineConfig(speculative_k=4, **base), params, tok,
+            use_kernel=False)
         return plain, spec, tok
 
     def test_exact_equivalence_with_plain_greedy(self):
@@ -130,8 +135,7 @@ class TestSpeculativeEngine:
 
 class TestPagedSpeculative:
     def _paged(self, spec_k, **kw):
-        from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
-
+    
         cfg = TINY.replace(max_seq_len=128)
         params = llama.init_params(cfg, jax.random.PRNGKey(0))
         tok = get_tokenizer(vocab_size=cfg.vocab_size)
@@ -184,7 +188,6 @@ def test_feature_matrix_greedy_equivalence():
 
     from k8s_llm_rca_tpu.config import EngineConfig
     from k8s_llm_rca_tpu.engine.constrain import make_grammar
-    from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
 
     cfg = TINY.replace(max_seq_len=128)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
@@ -220,9 +223,9 @@ def test_feature_matrix_greedy_equivalence():
                         kv, spec_k, chunk, prefix)
 
 
-@pytest.mark.parametrize("paged", [False, True])
+@pytest.mark.parametrize("page_size", [16, 8])
 @pytest.mark.parametrize("grammar_name", ["schema", "json"])
-def test_speculative_dfa_greedy_exactness(paged, grammar_name):
+def test_speculative_dfa_greedy_exactness(page_size, grammar_name):
     """spec × DFA (round-2 review item 6): with every grammar slot on one
     compiled DFA, drafted tokens verify through the DFA ON DEVICE
     (engine.dfa_greedy_multi) — multi-token verify is kept and the output
@@ -232,9 +235,7 @@ def test_speculative_dfa_greedy_exactness(paged, grammar_name):
     import jax
 
     from k8s_llm_rca_tpu.config import TINY, EngineConfig
-    from k8s_llm_rca_tpu.engine import InferenceEngine
     from k8s_llm_rca_tpu.engine.constrain import make_grammar
-    from k8s_llm_rca_tpu.engine.paged import PagedInferenceEngine
     from k8s_llm_rca_tpu.models import llama
     from k8s_llm_rca_tpu.utils import get_tokenizer
 
@@ -248,15 +249,14 @@ def test_speculative_dfa_greedy_exactness(paged, grammar_name):
     prompt = tok.encode("diagnose: pod crashloop backoff", add_bos=True)
 
     def run(spec_k):
-        kw = dict(paged=True, page_size=16, num_pages=64,
-                  prefix_cache=False) if paged else {}
-        cls = PagedInferenceEngine if paged else InferenceEngine
-        extra = dict(use_kernel=False) if paged else {}
-        eng = cls(cfg, EngineConfig(max_batch=2, max_seq_len=256,
-                                    prefill_buckets=(16, 32),
-                                    max_new_tokens=48,
-                                    speculative_k=spec_k, decode_chunk=1,
-                                    **kw), params, tok, **extra)
+        eng = PagedInferenceEngine(
+            cfg, EngineConfig(max_batch=2, max_seq_len=256,
+                              prefill_buckets=(16, 32), max_new_tokens=48,
+                              speculative_k=spec_k, decode_chunk=1,
+                              page_size=page_size,
+                              num_pages=1024 // page_size,
+                              prefix_cache=False),
+            params, tok, use_kernel=False)
         rid = eng.submit(prompt, max_new_tokens=48,
                          grammar=make_grammar(gname, tok))
         res = {r.seq_id: r for r in eng.run_to_completion()}
@@ -283,11 +283,11 @@ def test_speculative_interpreted_grammar_host_fallback_exactness():
     prompt = tok.encode("diagnose:", add_bos=True)
 
     def run(spec_k):
-        eng = InferenceEngine(
+        eng = PagedInferenceEngine(
             cfg, EngineConfig(max_batch=2, max_seq_len=256,
                               prefill_buckets=(16,), max_new_tokens=64,
                               speculative_k=spec_k, decode_chunk=1),
-            params, tok)
+            params, tok, use_kernel=False)
         # built DIRECTLY as the interpreted FSM: make_grammar now
         # DFA-compiles small templates, but the host-fallback verify path
         # under test needs a grammar with no compiled tables
@@ -302,10 +302,10 @@ def test_speculative_interpreted_grammar_host_fallback_exactness():
     assert base in schema["options"]
 
 
-@pytest.mark.parametrize("paged", [False, True])
+@pytest.mark.parametrize("page_size", [16, 8])
 @pytest.mark.parametrize("quality", ["random", "self"])
-def test_model_draft_engine_matches_plain(paged, quality):
-    """Draft-MODEL speculation (``draft_model=`` on either engine):
+def test_model_draft_engine_matches_plain(page_size, quality):
+    """Draft-MODEL speculation (``draft_model=``):
     greedy output is identical to the plain engine for ANY draft —
     a random-weight 1-layer draft (worst case: near-zero acceptance)
     and the target model as its own draft (best case) — and the good
@@ -323,28 +323,26 @@ def test_model_draft_engine_matches_plain(paged, quality):
     else:
         dcfg = cfg.replace(n_layers=1)
         draft = (dcfg, llama.init_params(dcfg, jax.random.PRNGKey(9)))
-    extra = (dict(paged=True, page_size=16, num_pages=64,
-                  prefix_cache=False) if paged else {})
-    kw = dict(use_kernel=False) if paged else {}
     ecfg0 = EngineConfig(max_batch=2, max_seq_len=128,
                          prefill_buckets=(32, 64), max_new_tokens=20,
-                         temperature=0.0, **extra)
+                         temperature=0.0, page_size=page_size,
+                         num_pages=1024 // page_size, prefix_cache=False)
     prompts = [tok.encode("the pod the pod the pod", add_bos=True),
                tok.encode("mount failed mount failed again", add_bos=True),
                tok.encode("pvc not bound why", add_bos=True)]
 
     with jax.default_matmul_precision("float32"):
-        plain = make_engine(cfg, ecfg0, params, tok, **kw)
+        plain = make_engine(cfg, ecfg0, params, tok, use_kernel=False)
         a = plain.generate([list(p) for p in prompts], max_new_tokens=20)
         before = METRICS.counters.get("engine.spec_accepted", 0)
         spec = make_engine(cfg, dataclasses.replace(ecfg0, speculative_k=3),
-                           params, tok, draft_model=draft, **kw)
+                           params, tok, draft_model=draft,
+                           use_kernel=False)
         b = spec.generate([list(p) for p in prompts], max_new_tokens=20)
     for ra, rb in zip(a, b):
         assert ra.token_ids == rb.token_ids, quality
         assert ra.finish_reason == rb.finish_reason
-    if paged:
-        spec.allocator.check()
+    spec.allocator.check()
     if quality == "self":
         # the target drafting for itself accepts nearly everything
         accepted = METRICS.counters.get("engine.spec_accepted", 0) - before

@@ -63,7 +63,7 @@ PROMPTS = (_PRE + "kubelet crashloop on node-7",
 
 def _ecfg(**over):
     base = dict(max_batch=2, max_seq_len=128, prefill_buckets=(64, 128),
-                max_new_tokens=16, temperature=0.0, paged=True,
+                max_new_tokens=16, temperature=0.0,
                 page_size=16, num_pages=40, prefix_cache=True,
                 decode_chunk=4)
     base.update(over)
@@ -629,35 +629,15 @@ class TestExclusions:
         finally:
             server.close()
 
-    def test_remote_store_requires_paged_engine(self, setup):
+    @pytest.mark.parametrize("knobs,refusal", [
+        (dict(prefix_hbm_watermark=-1), ">= 0"),
+        (dict(prefix_hbm_watermark=40), "over capacity"),
+        (dict(prefix_cache=False, prefix_hbm_watermark=4),
+         "prefix_cache=True")])
+    def test_watermark_validation(self, setup, knobs, refusal):
         cfg, params, tok = setup
-        server = StoreServer(host_pages=4, transport="pipe")
-        try:
-            with pytest.raises(ValueError, match="paged engine"):
-                make_engine(
-                    cfg, _ecfg(paged=False, prefix_cache=False,
-                               page_size=0, num_pages=0), params, tok,
-                    prefix_store=RemoteStore(server=server))
-        finally:
-            server.close()
-
-    def test_watermark_validation(self, setup):
-        cfg, params, tok = setup
-        with pytest.raises(ValueError, match="paged engine"):
-            make_engine(cfg, _ecfg(paged=False, prefix_cache=False,
-                                   page_size=0, num_pages=0,
-                                   prefix_hbm_watermark=4),
-                        params, tok)
-        with pytest.raises(ValueError, match=">= 0"):
-            make_engine(cfg, _ecfg(prefix_hbm_watermark=-1), params, tok,
-                        use_kernel=False)
-        with pytest.raises(ValueError, match="over capacity"):
-            make_engine(cfg, _ecfg(prefix_hbm_watermark=40), params, tok,
-                        use_kernel=False)
-        with pytest.raises(ValueError, match="prefix_cache=True"):
-            make_engine(cfg, _ecfg(prefix_cache=False,
-                                   prefix_hbm_watermark=4), params, tok,
-                        use_kernel=False)
+        with pytest.raises(ValueError, match=refusal):
+            make_engine(cfg, _ecfg(**knobs), params, tok, use_kernel=False)
 
     def test_writethrough_requires_a_store(self, setup):
         cfg, params, tok = setup
