@@ -935,6 +935,7 @@ class PagedInferenceEngine(EngineBase):
                                       pp_microbatches, pp_stage_axis,
                                       params=params)
         self._pp = pp_mesh is not None
+        self._moe_in_model = ep_mesh is None and pp_mesh is None
         if self._pp:
             if engine_cfg.prefix_cache and ep_mesh is not None:
                 raise ValueError(
@@ -1564,6 +1565,18 @@ class PagedInferenceEngine(EngineBase):
         g["evictable_pages"] = (self.prefix_cache.n_evictable
                                 if self.prefix_cache is not None else 0)
         return g
+
+    def _count_prefill_padded(self, n_positions: int) -> None:
+        """One prefill dispatch of ``n_positions`` (rows x bucket, pad
+        included), and whether its expert MLPs took the token-grouped
+        path (``llama.moe_grouped``, decided from the same number): the
+        share of the two says how much of the prefill was routed.  Under
+        EP or PP the MLP runs those paths' own dispatch, over other row
+        counts, and nothing is counted."""
+        self._count("engine.prefill_padded_tokens", n_positions)
+        if self._moe_in_model and llama.moe_grouped(self.model_cfg,
+                                                    n_positions):
+            self._count("engine.moe_grouped_tokens", n_positions)
 
     def _count_attn_pages(self, steps: int, active_slots) -> None:
         """The pages that hold live context in this dispatch, from the
@@ -2245,7 +2258,7 @@ class PagedInferenceEngine(EngineBase):
             self._key, sub = jax.random.split(self._key)
             first = self._sample(logits, sub, self.sampling)
         self._count("engine.prefill_tokens", len(rest))
-        self._count("engine.prefill_padded_tokens", padded.size)
+        self._count_prefill_padded(padded.size)
 
         if req.grammar is not None:
             # grammar first tokens stay synchronous: the FSM needs the
@@ -2351,7 +2364,7 @@ class PagedInferenceEngine(EngineBase):
                 jnp.int32(done), jnp.asarray(prefix_table),
                 jnp.asarray(page_map))
         self._count("engine.prefill_tokens", chunk_len)
-        self._count("engine.prefill_padded_tokens", padded.size)
+        self._count_prefill_padded(padded.size)
         st["done"] = done + chunk_len
         if st["done"] < total:
             return None
@@ -2557,7 +2570,7 @@ class PagedInferenceEngine(EngineBase):
             firsts = self._sample(logits, sub, self.sampling)
         self._count("engine.prefill_tokens",
                     sum(len(rest) for rest in rests))
-        self._count("engine.prefill_padded_tokens", tokens.size)
+        self._count_prefill_padded(tokens.size)
         self._count("engine.prefix_hit_tokens", n_cached * n)
         self._count("engine.prefix_batch_hit_admissions", n)
 
@@ -2632,7 +2645,7 @@ class PagedInferenceEngine(EngineBase):
             self._key, sub = jax.random.split(self._key)
             firsts = self._sample(logits, sub, self.sampling)
         self._count("engine.prefill_tokens", int(lens[:n].sum()))
-        self._count("engine.prefill_padded_tokens", tokens.size)
+        self._count_prefill_padded(tokens.size)
         self._count("engine.batched_admissions", n)
 
         if any(r.grammar is not None for r in reqs):

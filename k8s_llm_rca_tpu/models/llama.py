@@ -24,6 +24,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from k8s_llm_rca_tpu.config import ModelConfig
 from k8s_llm_rca_tpu.models.quant import (
@@ -236,9 +237,10 @@ def _mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
          ep_mesh=None, ep_token_axis: str = "data") -> jnp.ndarray:
     """``ep_mesh``: optional Mesh with an "expert" axis — the MoE block then
     dispatches through the all-to-all expert-parallel path
-    (parallel/moe.expert_parallel_moe) instead of the dense soft-dispatch.
+    (parallel/moe.expert_parallel_moe) instead of ``_moe_mlp`` (token-
+    grouped for a large call, dense soft dispatch for a small one).
     Lossless capacity (capacity_factor = n_experts) so serving under EP
-    computes the same function as the dense form; engines bind this at
+    computes the same function as ``_moe_mlp``; engines bind this at
     construction (BASELINE configs[3]: Mixtral expert-parallel serving).
     ``ep_token_axis``: mesh axis the flattened token dim shards over
     alongside "expert" — "data" for batch prefill/decode, the CP seq axis
@@ -258,12 +260,55 @@ def _mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
     return _w_mm(cfg, gate * up, layer["w_down"])
 
 
-def _moe_mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray) -> jnp.ndarray:
-    """Mixtral sparse-MoE MLP, dense "soft-dispatch" formulation.
+# Rows per expert (T * k / E) from which _moe_mlp routes each token's rows to
+# its experts instead of running every expert on every token.  Set from one
+# layer's MLP at Mixtral-8x7B widths with int4 experts on one TPU v5e (my
+# chip runs, PR 30; ms a call, dense form | grouped form at XLA's own tiles,
+# by T = B * S; _GROUPED_MATMUL_TILES below takes 10-18% more off the grouped
+# form from 2048):
+#      T    rows/expert    dense   grouped
+#     32          8         5.19    14.12      (a decode call)
+#    128         32         5.86    15.63
+#    512        128        12.00    20.11
+#   1024        256        19.98    22.81
+#   1536        384        28.15    25.35      <- the grouped form wins from here
+#   2048        512        36.68    28.41
+#   4096       1024        69.72    38.47
+#  16384       4096       290.45   109.85
+# The grouped kernel reads its weights from HBM, so every call first writes
+# each expert dequantized whole (0.94 GB a weight): ~14 ms a layer before the
+# first row, where the dense form's fusion pays ~5.  From there it costs
+# 5.9 us a token against 17.4.
+MOE_GROUPED_MIN_ROWS_PER_EXPERT = 384
 
-    Every expert runs on every token and the top-k router weights zero out the
-    rest — XLA-friendly (static shapes, one big einsum per projection, experts
-    batched on the MXU) and exactly equal to hard routing.  The bandwidth-
+
+def moe_grouped(cfg: ModelConfig, n_tokens: int) -> bool:
+    """Whether ``_moe_mlp`` takes the token-grouped path for a call of
+    ``n_tokens`` positions (``B * S``, pad positions included).  The one
+    place that decides: a prefill of 2048 positions gives a top-2-of-8
+    router 512 rows an expert, a decode call of 32 slots gives 8, where
+    grouping saves no arithmetic that matters, could skip no expert's
+    dequantization (dead slots route too) and would put a sort into every
+    step of a scan.  ``cfg.fused_quant_matmul`` keeps its own kernels."""
+    if cfg.n_experts <= 0 or cfg.fused_quant_matmul:
+        return False
+    rows_per_expert = n_tokens * cfg.n_experts_per_tok / cfg.n_experts
+    return rows_per_expert >= MOE_GROUPED_MIN_ROWS_PER_EXPERT
+
+
+def _moe_mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray) -> jnp.ndarray:
+    """Mixtral sparse-MoE MLP: softmax over the top-k router logits, the
+    chosen experts' SwiGLU, their weighted sum.  Two forms of one function,
+    chosen from the call's shape by ``moe_grouped``:
+
+    - a large call (prefill, training) is **token-grouped**
+      (``_moe_experts_grouped``): each token's row goes to its k experts
+      only, so the expert arithmetic is k/E of the dense form's;
+    - a small call (decode) is **dense soft dispatch**: every expert runs
+      on every token and the router's weights zero out the rest — one
+      einsum per projection, no sort, exactly equal to hard routing.
+
+    Both are lossless (no capacity, no dropped token).  The bandwidth-
     optimal EP dispatch (all_to_all over the "expert" axis) lives in
     parallel/moe.py and is used by the sharded engine path.
     """
@@ -272,6 +317,8 @@ def _moe_mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray) -> jnp.ndarray:
     router_logits = _w_mm(cfg, x, layer["router"]).astype(jnp.float32)  # [B,S,E]
     topv, topi = jax.lax.top_k(router_logits, k)                   # [B,S,k]
     weights = jax.nn.softmax(topv, axis=-1)                        # [B,S,k]
+    if moe_grouped(cfg, b * s):
+        return _moe_experts_grouped(cfg, layer, x, topi, weights)
     # scatter the top-k weights back to a dense [B,S,E] map
     onehot = jax.nn.one_hot(topi, e, dtype=jnp.float32)            # [B,S,k,E]
     dense_w = jnp.einsum("bske,bsk->bse", onehot, weights)         # [B,S,E]
@@ -287,6 +334,65 @@ def _moe_mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray) -> jnp.ndarray:
                                 dq(layer["w_down"]))
     return jnp.einsum("bseh,bse->bsh", per_expert,
                       dense_w.astype(x.dtype))
+
+
+# Row, contraction and column tile of XLA's grouped-matmul kernel where the
+# three dimensions divide by them; left to itself XLA takes 512 x 512 x 512.
+# One layer's MLP as above, ms a call (my chip runs, PR 30), XLA's tiles |
+# these: T = 2048: 28.41 | 25.20; 4096: 38.48 | 34.35; 8192: 63.10 | 52.57;
+# 16384: 109.84 | 90.27 (megablox gmm at the same tiles: 90.43).  Of the
+# seven other tilings the kernel's VMEM admits none is better at 8192 and
+# over; 256 x 2048 x 1024 is 4-5% better at 2048 and 4096 and worse above.
+_GROUPED_MATMUL_TILES = (512, 1024, 1024)
+
+
+def _grouped_matmul(rows: jnp.ndarray, w: jnp.ndarray,
+                    group_sizes: jnp.ndarray) -> jnp.ndarray:
+    """``rows[g_e : g_e+1] @ w[e]`` for every expert ``e``: rows ``[M, K]``
+    sorted by expert, ``w`` ``[E, K, N]``, ``group_sizes`` ``[E]``.  On a
+    TPU ``jax.lax.ragged_dot`` is XLA's own grouped-matmul kernel, which
+    reads its tiles from the operation's ``ragged_dot_tiling`` attribute;
+    a shape the tiles do not divide keeps XLA's choice (its row tile is
+    the largest divisor of ``M`` up to 512).  Elsewhere the attribute is
+    ignored."""
+    dims = (rows.shape[0], rows.shape[1], w.shape[-1])
+    if any(d % t for d, t in zip(dims, _GROUPED_MATMUL_TILES)):
+        return jax.lax.ragged_dot(rows, w, group_sizes)
+    with set_xla_metadata(
+            ragged_dot_tiling=",".join(map(str, _GROUPED_MATMUL_TILES))):
+        return jax.lax.ragged_dot(rows, w, group_sizes)
+
+
+def _moe_experts_grouped(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
+                         topi: jnp.ndarray, weights: jnp.ndarray
+                         ) -> jnp.ndarray:
+    """The expert MLPs of ``_moe_mlp`` on routed rows only.  The ``T * k``
+    (token, expert) pairs are stable-sorted by expert, the tokens' rows
+    gathered in that order (``[T * k, H]``: a static shape), and gate, up
+    and down run as grouped matmuls over the stacked expert weights
+    (``_grouped_matmul``: rows ``[g_e, g_e+1)`` meet expert ``e`` only).
+    ``group_sizes`` is counted from the data, so it is exact and sums to
+    ``T * k``: every pair is computed, whatever the spread (an expert no
+    token chose is an empty group).  The pairs go back to their
+    tokens by the inverse permutation and are summed under the router's
+    weights, as the dense form sums them.  ``ragged_dot`` has JVP and
+    transpose rules, so the path differentiates (engine/train.py)."""
+    b, s, h = x.shape
+    k = cfg.n_experts_per_tok
+    pairs = b * s * k
+    expert = topi.reshape(pairs)
+    order = jnp.argsort(expert, stable=True)            # pair ids by expert
+    group_sizes = jnp.bincount(
+        expert, length=cfg.n_experts).astype(jnp.int32)
+    rows = x.reshape(b * s, h)[order // k]                         # [T*k,H]
+    gate = jax.nn.silu(
+        _grouped_matmul(rows, dq(layer["w_gate"]), group_sizes))
+    up = _grouped_matmul(rows, dq(layer["w_up"]), group_sizes)
+    out = _grouped_matmul(gate * up, dq(layer["w_down"]), group_sizes)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(pairs, dtype=order.dtype))           # pair -> sorted row
+    per_pair = out[inverse].reshape(b, s, k, h)
+    return jnp.einsum("bskh,bsk->bsh", per_pair, weights.astype(x.dtype))
 
 
 def _sp_constrain(x: jnp.ndarray, sp_mesh) -> jnp.ndarray:

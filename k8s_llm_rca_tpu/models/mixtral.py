@@ -3,7 +3,9 @@
 Architecturally this is the Llama stack with the MLP swapped for a
 top-k-routed expert block, so the block implementation lives in
 models/llama.py (``n_experts > 0`` switches it; ``llama._moe_mlp`` is the
-dense soft-dispatch form, parallel/moe.py the all-to-all EP dispatch).
+one-chip form: token-grouped matmuls on the routed rows for a prefill-sized
+call, dense soft dispatch for a decode-sized one, chosen by
+``llama.moe_grouped``; parallel/moe.py is the all-to-all EP dispatch).
 What lives HERE is what is Mixtral-specific: the presets and the
 **expert-parallel serving assembly** — building the (data, expert) mesh,
 sharding the stacked expert weights over it, and constructing an engine

@@ -463,7 +463,8 @@ def quant_matmul_experts(x: jnp.ndarray, w, *,
 
     ``w`` [E, K, N] with per-(expert, column) scales [E, 1, N] (quantize
     axis=(0, -1)).  ``x`` 3-D [B, S, K] computes ``"bsh,ehi->bsei"``
-    (every token through every expert — the dense soft-dispatch MoE);
+    (every token through every expert — the dense soft-dispatch form of
+    ``llama._moe_mlp``, which the fused flag keeps at every call size);
     4-D [B, S, E, K] computes ``"bsei,eih->bseh"`` (per-expert rows)."""
     _require_quant(w, "quant_matmul_experts")
     if w.ndim != 3:

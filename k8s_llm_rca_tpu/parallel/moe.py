@@ -9,8 +9,10 @@ device receives exactly the tokens destined for ITS experts, applies its
 expert MLPs, and reverses the exchange.
 
 With sufficient capacity this computes exactly the same function as
-models/llama._moe_mlp's dense soft-dispatch (tests assert parity); under
-pressure it drops overflow tokens like production MoE stacks do.
+models/llama._moe_mlp (tests assert parity), whose two one-chip forms
+(token-grouped matmuls for a large call, dense soft dispatch for a small
+one) have no capacity and drop nothing; under pressure this path drops
+overflow tokens like production MoE stacks do.
 """
 
 from __future__ import annotations

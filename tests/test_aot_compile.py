@@ -62,6 +62,11 @@ def chip():
     compilation_cache.reset_cache()
 
 
+def _described(chip, tree):
+    """A tree of arrays or of ``eval_shape``'s shapes, placed on the chip."""
+    return jax.tree.map(lambda s: chip(s.shape, s.dtype), tree)
+
+
 def _compiles_with_kernel(fn, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
@@ -131,13 +136,11 @@ class TestDecodeStepWritesThePoolInPlace:
                           max_seq_len=4096, rope_theta=1e6,
                           tie_embeddings=False)
 
-        def described(tree):
-            return jax.tree.map(lambda s: chip(s.shape, s.dtype), tree)
-
-        params = described(jax.eval_shape(lambda: quantize_params(
+        params = _described(chip, jax.eval_shape(lambda: quantize_params(
             llama.init_params(cfg, jax.random.PRNGKey(0)), bits=8)))
-        pool = described(jax.eval_shape(lambda: paged.init_paged_cache(
-            cfg, self.N_PAGES, self.PAGE, "int8")))
+        pool = _described(chip, jax.eval_shape(
+            lambda: paged.init_paged_cache(cfg, self.N_PAGES, self.PAGE,
+                                           "int8")))
         # the kernel's interpret=None asks the backend, which is the CPU
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         compiled = jax.jit(
@@ -158,6 +161,93 @@ class TestDecodeStepWritesThePoolInPlace:
         layer_bytes = self.N_PAGES * self.PAGE * cfg.kv_dim
         assert mem.alias_size_in_bytes >= 2 * self.LAYERS * layer_bytes
         assert mem.temp_size_in_bytes < layer_bytes
+
+
+class TestPrefillRoutesEachTokenToItsExperts:
+    """The batched prefill program of ``mixtral-d8.audit-prefill`` at its
+    largest warm-up shape: 4 rows of 4096 at Mixtral-8x7B widths (2 layers
+    of the cell's 8, int4 experts, the cell's int8 pool of 4,096 pages).
+    Until PR 30 every expert ran on every position: three activations of
+    ``[4, 4096, 8, 14336]`` (3.76 GB each) a layer, three quarters of them
+    multiplied by zero.  What a
+    later edit must not bring back, seen here without a chip: the program
+    holds no per-expert activation of all 16,384 positions, its expert
+    matmuls are the grouped kernel over the routed ``[32768, ...]`` rows,
+    and its temporaries stay under the dense form's (PR 29's tree at this
+    shape: 4.67 GB, and 5.24 GB at the cell's 8 layers; this program: 3.39
+    and 3.97 GB, most of it one layer's experts dequantized whole, 0.94
+    GB a weight, which the grouped kernel reads from HBM)."""
+
+    ROWS, BUCKET, LAYERS, N_PAGES, PAGE = 4, 4096, 2, 4096, 16
+    # the dense form's temporaries at this shape (AOT, PR 29's tree)
+    DENSE_TEMP_BYTES = 4.67e9
+
+    def test_no_activation_of_every_expert_is_live(self, chip, monkeypatch):
+        import re
+
+        from k8s_llm_rca_tpu.engine import paged
+        from k8s_llm_rca_tpu.models import llama
+        from k8s_llm_rca_tpu.models.quant import quantize_params
+
+        cfg = MIXTRAL_8X7B.replace(n_layers=self.LAYERS, max_seq_len=4096,
+                                   dtype="bfloat16")
+        positions = self.ROWS * self.BUCKET
+        assert llama.moe_grouped(cfg, positions)
+
+        params = _described(chip, jax.eval_shape(lambda: quantize_params(
+            llama.init_params(cfg, jax.random.PRNGKey(0)), bits=4)))
+        pool = _described(chip, jax.eval_shape(
+            lambda: paged.init_paged_cache(cfg, self.N_PAGES, self.PAGE,
+                                           "int8")))
+        # the kernels' interpret=None asks the backend, which is the CPU
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = jax.jit(
+            paged.paged_prefill_batch, static_argnums=0, donate_argnums=2,
+            static_argnames="use_flash").lower(
+                cfg, params, pool, chip((self.ROWS, self.BUCKET), I32),
+                chip((self.ROWS,), I32),
+                chip((self.ROWS, self.BUCKET // self.PAGE), I32),
+                use_flash=True).compile()
+        text = compiled.as_text()
+
+        e, k = cfg.n_experts, cfg.n_experts_per_tok
+        h, inter = cfg.hidden_size, cfg.intermediate_size
+        # no [..., 8, 14336] or [..., 8, 4096] activation over all 16,384
+        # positions, flattened or as rows x bucket
+        for rows in (rf"{positions}", rf"{self.ROWS},{self.BUCKET}"):
+            assert not re.search(rf"\[{rows},{e},({inter}|{h})]", text)
+        # gate, up and down of each layer: grouped matmuls of the routed
+        # pairs against the stacked weight, which is what the benchmark's
+        # expert_mlp_busy_share tells the expert MLP by
+        grouped = [line for line in text.splitlines()
+                   if "ragged-dot" in line and "custom-call(" in line
+                   and f"bf16[{positions * k}," in line]
+        assert len(grouped) == 3 * self.LAYERS
+        assert all(f"[{e},{h},{inter}]" in line or f"[{e},{inter},{h}]"
+                   in line for line in grouped)
+        # at the tiles the chip measured best, not XLA's 512 x 512 x 512
+        assert all('ragged_dot_tiling="512,1024,1024"' in line
+                   for line in grouped)
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < 0.8 * self.DENSE_TEMP_BYTES
+
+    @pytest.mark.parametrize("rows,tiling", [
+        pytest.param(2 * 2048, "512,1024,1024", id="bucket-2048-our-tiles"),
+        pytest.param(2 * 1552, "32,512,512", id="rows-512-does-not-divide"),
+    ])
+    def test_grouped_matmul_tiles(self, chip, rows, tiling):
+        """XLA's kernel takes its tiles from the operation's attribute and
+        refuses a row tile that does not divide the rows: a prefix-hit
+        chunk of 1,552 positions has to keep XLA's own choice."""
+        from k8s_llm_rca_tpu.models import llama
+
+        e, h, inter = (MIXTRAL_8X7B.n_experts, MIXTRAL_8X7B.hidden_size,
+                       MIXTRAL_8X7B.intermediate_size)
+        text = jax.jit(llama._grouped_matmul).lower(
+            chip((rows, h), BF16), chip((e, h, inter), BF16),
+            chip((e,), I32)).compile().as_text()
+        assert "tpu_custom_call" in text
+        assert f'ragged_dot_tiling="{tiling}"' in text
 
 
 def _weight(chip, bits, shape, scale_shape):
