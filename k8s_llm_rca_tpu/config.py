@@ -16,7 +16,19 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class ModelConfig:
-    """Decoder-LM architecture config (Llama family; Mixtral via n_experts>0)."""
+    """Decoder-LM architecture config.
+
+    Two families share it.  With ``layer_pattern`` empty every layer is
+    the Llama block (attention then MLP, two norms, two residuals;
+    Mixtral via ``n_experts > 0``), computed by models/llama.py.  With a
+    pattern (``nemotron_h``, models/nemotron_h.py) a layer is ONE mixer or
+    ONE feed-forward part under one norm and one residual, its kind the
+    pattern's letter: ``M`` a Mamba-2 mixer (the ``ssm_*`` fields), ``E``
+    an expert layer (router kind, latent and shared widths, the experts
+    held here), ``*`` attention.  The engine derives what it holds per
+    slot (pages for the attention layers, a recurrent state for the
+    Mamba layers) from this table and from nothing else.
+    """
 
     name: str = "tiny"
     vocab_size: int = 512
@@ -41,6 +53,96 @@ class ModelConfig:
     # other case (plain arrays, CPU/interpret hosts, GSPMD-sharded
     # consumption) falls back to the identical x @ dq(w) XLA path
     fused_quant_matmul: bool = False
+    # --- the layer table (empty = the Llama block in every layer) ---
+    layer_pattern: str = ""
+    use_rope: bool = True              # nemotron_h's attention applies none
+    # Mamba-2 mixer: heads x head_dim inner width, B and C shared by the
+    # heads of a group, a [heads, head_dim, state] recurrent state per
+    # sequence kept in ``ssm_state_dtype`` plus the depthwise
+    # convolution's last ``ssm_conv_kernel - 1`` inputs
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state_size: int = 0
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
+    ssm_state_dtype: str = "float32"
+    # shape the seeded dt_bias (init_params reads them; the forward
+    # applies no clamp to dt)
+    ssm_dt_min: float = 0.001
+    ssm_dt_max: float = 0.1
+    ssm_dt_floor: float = 1e-4
+    # expert layer.  ``n_experts`` is how many experts are HELD here
+    # (their weights exist); ``router_width`` is how many the router
+    # scores (0 = n_experts, every expert held) and ``expert_first`` the
+    # global index of the first one held: one chip's share of an
+    # expert-parallel deployment routes over all of them and computes its
+    # own experts' part (models/llama._moe_mlp)
+    router_width: int = 0
+    expert_first: int = 0
+    router_kind: str = "softmax"       # "softmax" over the kept logits |
+                                       # "sigmoid" scores + selection bias
+    routed_scaling: float = 1.0        # sigmoid router: weights sum to this
+    moe_latent_size: int = 0           # >0: experts live between two shared
+                                       # projections hidden <-> latent
+    moe_intermediate_size: int = 0     # expert width (0 = intermediate_size)
+    shared_expert_size: int = 0        # >0: an always-on expert of this width
+    mlp_act: str = "swiglu"            # "swiglu" | "relu2" (non-gated)
+    # depth of the WHOLE model these layers are a stage of (0 = n_layers):
+    # the family rescales every output projection by 1 / sqrt(depth) at
+    # initialisation (rescale_prenorm_residual), and a stage cut out of
+    # a deeper model carries the deeper model's weights
+    init_layers: int = 0
+
+    def __post_init__(self):
+        if self.layer_pattern:
+            if len(self.layer_pattern) != self.n_layers:
+                raise ValueError(
+                    f"layer_pattern {self.layer_pattern!r} has "
+                    f"{len(self.layer_pattern)} letters for n_layers="
+                    f"{self.n_layers}")
+            unknown = set(self.layer_pattern) - set("ME*")
+            if unknown:
+                raise ValueError(
+                    f"layer_pattern {self.layer_pattern!r}: unknown layer "
+                    f"kind {sorted(unknown)[0]!r} (M Mamba-2, E experts, "
+                    f"* attention)")
+        if self.n_experts and not (
+                0 <= self.expert_first
+                and self.expert_first + self.n_experts <= self.n_router):
+            last = self.expert_first + self.n_experts - 1
+            raise ValueError(
+                f"experts {self.expert_first}..{last} held of a router "
+                f"over {self.n_router}")
+
+    @property
+    def n_router(self) -> int:
+        """Experts the router scores (the published count)."""
+        return self.router_width or self.n_experts
+
+    @property
+    def expert_size(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def n_kv_layers(self) -> int:
+        """Layers that cache keys and values: the pool's layer axis."""
+        return (self.layer_pattern.count("*") if self.layer_pattern
+                else self.n_layers)
+
+    @property
+    def n_ssm_layers(self) -> int:
+        """Layers that keep a recurrent state per sequence."""
+        return self.layer_pattern.count("M")
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the depthwise convolution runs over: x, B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
 
     @property
     def q_dim(self) -> int:
@@ -63,6 +165,20 @@ class ModelConfig:
 TINY = ModelConfig(name="tiny")
 
 TINY_MOE = ModelConfig(name="tiny_moe", n_experts=4, n_experts_per_tok=2)
+
+# every layer kind of nemotron_h at toy widths: two Mamba-2 mixers, two
+# expert layers (sigmoid router over 16, 8 held from the 4th, top-4, latent
+# experts, a shared expert, squared ReLU) and one attention layer without
+# rotary embedding
+TINY_NEMOTRON_H = ModelConfig(
+    name="tiny_nemotron_h", n_layers=5, layer_pattern="ME*ME",
+    n_heads=4, n_kv_heads=2, head_dim=32, use_rope=False,
+    tie_embeddings=False,
+    ssm_heads=8, ssm_head_dim=16, ssm_groups=2, ssm_state_size=16,
+    ssm_conv_kernel=4, ssm_chunk=16,
+    n_experts=8, router_width=16, expert_first=4, n_experts_per_tok=4,
+    router_kind="sigmoid", routed_scaling=2.5, moe_latent_size=64,
+    moe_intermediate_size=96, shared_expert_size=192, mlp_act="relu2")
 
 TINYLLAMA_1B = ModelConfig(
     name="tinyllama-1.1b",
@@ -112,7 +228,8 @@ MIXTRAL_8X7B = ModelConfig(
 )
 
 MODEL_REGISTRY = {
-    c.name: c for c in (TINY, TINY_MOE, TINYLLAMA_1B, LLAMA3_8B, MIXTRAL_8X7B)
+    c.name: c for c in (TINY, TINY_MOE, TINY_NEMOTRON_H, TINYLLAMA_1B,
+                        LLAMA3_8B, MIXTRAL_8X7B)
 }
 
 

@@ -43,7 +43,7 @@ from k8s_llm_rca_tpu.engine.sampling import (
     SamplingParams, sample_tokens, sample_tokens_masked,
 )
 from k8s_llm_rca_tpu.faults import inject
-from k8s_llm_rca_tpu.models import llama
+from k8s_llm_rca_tpu.models import llama, nemotron_h
 from k8s_llm_rca_tpu.models.quant import dq, gather_rows
 from k8s_llm_rca_tpu.models.llama import _quantize_kv
 from k8s_llm_rca_tpu.ops.attention import decode_attention
@@ -266,12 +266,29 @@ class PagePool(NamedTuple):
     to the page payload.  Page ids index k/v and the scale pools
     identically, so block-table sharing (prefix cache) and page transfer
     need no extra bookkeeping.
+
+    The layer axis counts the layers that cache keys and values
+    (``cfg.n_kv_layers``: every layer of a Llama-family model, the
+    attention layers of one with a layer table).  A model with Mamba-2
+    layers keeps beside the pages, per decode SLOT and not per page,
+    ``ssm_state`` [n_ssm_layers, max_batch, heads, head_dim, state] in
+    ``cfg.ssm_state_dtype`` and ``conv_state`` [n_ssm_layers, max_batch,
+    kernel - 1, conv_dim] (the convolution's last inputs, channels on the
+    lane axis: the published ``[conv_dim, kernel - 1]`` would pad 3 lanes
+    to 128), and ``moe_local_pairs`` [1] int32, the running count of
+    (position, expert) pairs whose expert is held here, wrapping.  They
+    ride in the pool because the pool is what every program is given,
+    donates and hands back: the state is updated in place on the device
+    and never crosses to the host in a tick.
     """
 
     k: jnp.ndarray
     v: jnp.ndarray
     k_scale: Optional[jnp.ndarray] = None
     v_scale: Optional[jnp.ndarray] = None
+    ssm_state: Optional[jnp.ndarray] = None
+    conv_state: Optional[jnp.ndarray] = None
+    moe_local_pairs: Optional[jnp.ndarray] = None
 
     @property
     def page_size(self) -> int:
@@ -283,8 +300,27 @@ class PagePool(NamedTuple):
 
 
 def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
-                     kv_dtype=None) -> PagePool:
-    shape = (cfg.n_layers, n_pages, page_size, cfg.kv_dim)
+                     kv_dtype=None, n_slots: int = 0) -> PagePool:
+    """``n_slots``: the decode slots a model with Mamba-2 layers keeps a
+    recurrent state for (``EngineConfig.max_batch``)."""
+    if cfg.n_ssm_layers:
+        if n_slots <= 0:
+            raise ValueError(
+                f"{cfg.name}: its {cfg.n_ssm_layers} Mamba-2 layers keep a "
+                f"state per decode slot: init_paged_cache needs n_slots")
+        pages = init_paged_cache(cfg.replace(layer_pattern="",
+                                             n_layers=cfg.n_kv_layers),
+                                 n_pages, page_size, kv_dtype)
+        lead = (cfg.n_ssm_layers, n_slots)
+        return pages._replace(
+            ssm_state=jnp.zeros(
+                (*lead, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state_size),
+                jnp.dtype(cfg.ssm_state_dtype)),
+            conv_state=jnp.zeros(
+                (*lead, cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim),
+                jnp.dtype(cfg.dtype)),
+            moe_local_pairs=jnp.zeros((1,), jnp.int32))
+    shape = (cfg.n_kv_layers, n_pages, page_size, cfg.kv_dim)
     if isinstance(kv_dtype, str) and kv_dtype == "int4":
         assert cfg.kv_dim % 2 == 0
         pshape = (*shape[:3], cfg.kv_dim // 2)
@@ -340,8 +376,9 @@ def _write_pool_pages(cfg: ModelConfig, pool: PagePool, new_k, new_v,
         new_v, vs = _quantize_kv(new_v, packed)
         k_scale = k_scale.at[:, page_map].set(ks)
         v_scale = v_scale.at[:, page_map].set(vs)
-    return PagePool(pool.k.at[:, page_map].set(new_k),
-                    pool.v.at[:, page_map].set(new_v), k_scale, v_scale)
+    return pool._replace(k=pool.k.at[:, page_map].set(new_k),
+                         v=pool.v.at[:, page_map].set(new_v),
+                         k_scale=k_scale, v_scale=v_scale)
 
 
 def _write_pool_rows(cfg: ModelConfig, pool: PagePool, li: int, page_ids,
@@ -360,25 +397,29 @@ def _write_pool_rows(cfg: ModelConfig, pool: PagePool, li: int, page_ids,
         v_rows, vs = _quantize_kv(v_rows, packed)
         k_scale = k_scale.at[li, page_ids, offsets].set(ks)
         v_scale = v_scale.at[li, page_ids, offsets].set(vs)
-    return PagePool(pool.k.at[li, page_ids, offsets].set(k_rows),
-                    pool.v.at[li, page_ids, offsets].set(v_rows),
-                    k_scale, v_scale)
+    return pool._replace(k=pool.k.at[li, page_ids, offsets].set(k_rows),
+                         v=pool.v.at[li, page_ids, offsets].set(v_rows),
+                         k_scale=k_scale, v_scale=v_scale)
 
 
 def paged_prefill(cfg: ModelConfig, params, pool: PagePool,
                   tokens: jnp.ndarray, length: jnp.ndarray,
                   page_map: jnp.ndarray, use_flash: bool = False,
-                  ep_mesh=None, flash_mesh=None, sp_mesh=None):
+                  ep_mesh=None, flash_mesh=None, sp_mesh=None, slots=None):
     """Prefill ONE sequence, scattering its KV into ``page_map`` pages.
 
     tokens [1, S_pad] with S_pad a multiple of page_size; page_map
     [S_pad // page_size] int32 page ids (entries past the prompt's pages
     must be TRASH_PAGE).  ``use_flash``: see llama.prefill_kv.  Returns
-    (pool', logits [1, V]).
+    (pool', logits [1, V]).  ``slots`` [1]: see ``paged_prefill_batch``.
     """
     _, s_pad = tokens.shape
     page_size = pool.page_size
     assert s_pad % page_size == 0, (s_pad, page_size)
+    if cfg.layer_pattern:
+        return _prefill_rows_with_state(
+            cfg, params, pool, tokens, jnp.asarray(length).reshape(1),
+            page_map[None], slots, use_flash)
     new_k, new_v, logits = llama.prefill_kv(cfg, params, tokens, length,
                                             use_flash, ep_mesh, flash_mesh,
                                             sp_mesh)
@@ -423,18 +464,26 @@ def _chunk_attention(cfg: ModelConfig, q, k_all, v_all, mask):
 def paged_prefill_batch(cfg: ModelConfig, params, pool: PagePool,
                         tokens: jnp.ndarray, lengths: jnp.ndarray,
                         page_maps: jnp.ndarray, use_flash: bool = False,
-                        ep_mesh=None, flash_mesh=None, sp_mesh=None):
+                        ep_mesh=None, flash_mesh=None, sp_mesh=None,
+                        slots=None):
     """Prefill N sequences into their pool pages in ONE dispatch.
 
     tokens [N, S_pad] right-padded (S_pad a page multiple); lengths [N];
     page_maps [N, S_pad // page_size] int32 page ids — DISTINCT across
     rows except padding rows repeating the last real row (idempotent
-    duplicate writes).
+    duplicate writes).  ``slots`` [N] int32, for a model that keeps a
+    recurrent state per slot: the decode slot each row is admitted into
+    (a padding row repeats the last real row's), whose state is replaced
+    by the row's, computed from zero; such a model's rows run one after
+    another (``nemotron_h.prefill_rows``).
     Returns (pool', logits [N, V] at each row's last valid token).
     """
     n, s_pad = tokens.shape
     page_size = pool.page_size
     assert s_pad % page_size == 0, (s_pad, page_size)
+    if cfg.layer_pattern:
+        return _prefill_rows_with_state(cfg, params, pool, tokens, lengths,
+                                        page_maps, slots, use_flash)
     n_seq_pages = s_pad // page_size
     new_k, new_v, logits = llama._prefill_batch_kv(cfg, params, tokens,
                                                    lengths, use_flash,
@@ -447,6 +496,44 @@ def paged_prefill_batch(cfg: ModelConfig, params, pool: PagePool,
         new_v.reshape(cfg.n_layers, n * s_pad, cfg.kv_dim),
         page_maps.reshape(-1), n * n_seq_pages, page_size)
     return pool, logits
+
+
+def _prefill_rows_with_state(cfg: ModelConfig, params, pool: PagePool,
+                             tokens, lengths, page_maps, slots,
+                             use_flash: bool):
+    """``paged_prefill_batch`` for a model with a layer table: keys and
+    values of the attention layers into the rows' pages, each row's
+    recurrent state (left at the row's true length) and convolution tail
+    over whatever its slot held, the local pairs onto the pool's count."""
+    if slots is None:
+        raise ValueError(
+            f"{cfg.name}: a prefill of a model with Mamba-2 layers needs "
+            f"the decode slot of each row (slots=) to write its state to")
+    n, s_pad = tokens.shape
+    page_size = pool.page_size
+    new_k, new_v, state, tail, logits, n_local = nemotron_h.prefill_rows(
+        cfg, params, tokens, lengths, use_flash)
+    pool = _write_pool_pages(
+        cfg, pool, new_k.reshape(cfg.n_kv_layers, n * s_pad, cfg.kv_dim),
+        new_v.reshape(cfg.n_kv_layers, n * s_pad, cfg.kv_dim),
+        page_maps.reshape(-1), n * (s_pad // page_size), page_size)
+    return pool._replace(
+        ssm_state=pool.ssm_state.at[:, slots].set(
+            state.astype(pool.ssm_state.dtype)),
+        conv_state=pool.conv_state.at[:, slots].set(
+            tail.astype(pool.conv_state.dtype)),
+        moe_local_pairs=pool.moe_local_pairs + n_local), logits
+
+
+def _refuse_for_layer_table(cfg: ModelConfig, what: str, why: str) -> None:
+    """A mechanism that is not built for a model with Mamba-2 layers is
+    refused by name where it is asked for, never fallen back from."""
+    if cfg.n_ssm_layers:
+        raise ValueError(
+            f"{what} is not built for {cfg.name!r}: its "
+            f"{cfg.n_ssm_layers} Mamba-2 layers (layer_pattern "
+            f"{cfg.layer_pattern!r}) keep a recurrent state per slot "
+            f"beside the pages, and {why}")
 
 
 def paged_prefill_cp(cfg: ModelConfig, params, pool: PagePool,
@@ -571,6 +658,10 @@ def paged_prefill_chunk_batch(cfg: ModelConfig, params, pool: PagePool,
     idempotent duplicate writes, the paged_prefill_batch contract).
     Returns (pool', logits [N, V] at each row's last valid token).
     """
+    _refuse_for_layer_table(
+        cfg, "chunked prefix prefill (paged_prefill_chunk*)",
+        "a chunk would have to start from the state at its first "
+        "position, which no page holds")
     n, c_pad = tokens.shape
     page_size = pool.page_size
     assert c_pad % page_size == 0, (c_pad, page_size)
@@ -638,7 +729,8 @@ def paged_decode_step(cfg: ModelConfig, params, pool: PagePool,
     page_size = pool.page_size
     dtype = jnp.dtype(cfg.dtype)
     packed = _pool_packed(cfg, pool)
-    angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
+    angles = (rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
+              if cfg.use_rope else None)
     positions = lengths[:, None]
     x = gather_rows(params["embedding"], tokens[:, None]).astype(dtype)
 
@@ -672,30 +764,56 @@ def paged_decode_step(cfg: ModelConfig, params, pool: PagePool,
         attn_lengths = jnp.where(block_tables[:, 0] == TRASH_PAGE, 0,
                                  attn_lengths)
 
+    # the layer table: ``kind`` "" is the Llama block (attention, then its
+    # MLP), a letter one mixer alone.  ``ai`` counts the layers that cache
+    # keys and values (the pool's layer axis), ``mi`` those with a state.
+    ai = mi = 0
+    n_local = jnp.int32(0)
     for li, layer in enumerate(params["layers"]):
+        kind = cfg.layer_pattern[li] if cfg.layer_pattern else ""
+        if kind == "M":
+            # every slot's state moves on by one position, where it lies
+            x, state, tail = nemotron_h.mamba_decode(
+                cfg, layer, x, pool.ssm_state[mi], pool.conv_state[mi])
+            pool = pool._replace(
+                ssm_state=pool.ssm_state.at[mi].set(state),
+                conv_state=pool.conv_state.at[mi].set(tail))
+            mi += 1
+            continue
+        if kind == "E":
+            x, n = nemotron_h.expert_layer(cfg, layer, x)
+            n_local = n_local + n
+            continue
         q, k, v = llama._decode_qkv(cfg, layer, x, angles,
                                     positions)              # [B,1,·,d]
-        # this token's k/v: [B, n_kv*d] -> pool[li, page, off]
-        pool = _write_pool_rows(cfg, pool, li, page_ids, offsets,
+        # this token's k/v: [B, n_kv*d] -> pool[ai, page, off]
+        pool = _write_pool_rows(cfg, pool, ai, page_ids, offsets,
                                 k[:, 0].reshape(b, cfg.kv_dim),
                                 v[:, 0].reshape(b, cfg.kv_dim))
         if kernel_on:
-            # the kernel reads layer li of the whole pool, by reference
-            attn = attn_fn(q[:, 0], *(p for p in pool if p is not None),
-                           attn_lengths, block_tables, layer=li)
+            # the kernel reads layer ai of the whole pool, by reference
+            pages = (pool.k, pool.v, pool.k_scale, pool.v_scale)
+            attn = attn_fn(q[:, 0], *(p for p in pages if p is not None),
+                           attn_lengths, block_tables, layer=ai)
         elif pool.quantized:
-            k_all = _gather_dequant_pages(pool.k[li], pool.k_scale[li],
+            k_all = _gather_dequant_pages(pool.k[ai], pool.k_scale[ai],
                                           block_tables, cfg.n_kv_heads,
                                           cfg.head_dim, dtype, packed)
-            v_all = _gather_dequant_pages(pool.v[li], pool.v_scale[li],
+            v_all = _gather_dequant_pages(pool.v[ai], pool.v_scale[ai],
                                           block_tables, cfg.n_kv_heads,
                                           cfg.head_dim, dtype, packed)
             attn = decode_attention(q, k_all, v_all, attn_lengths)
         else:
-            attn = paged_attention_xla(q[:, 0], pool.k[li], pool.v[li],
+            attn = paged_attention_xla(q[:, 0], pool.k[ai], pool.v[ai],
                                        attn_lengths, block_tables)
-        x = llama._decode_finish(cfg, layer, x,
-                                 attn.reshape(b, 1, cfg.q_dim), ep_mesh)
+        attn = attn.reshape(b, 1, cfg.q_dim)
+        if kind == "*":
+            x = x + llama._w_mm(cfg, attn, layer["wo"])
+        else:
+            x = llama._decode_finish(cfg, layer, x, attn, ep_mesh)
+        ai += 1
+    if pool.moe_local_pairs is not None:
+        pool = pool._replace(moe_local_pairs=pool.moe_local_pairs + n_local)
 
     logits = llama._logits(cfg, params, x)[:, 0]
     return pool, logits
@@ -715,6 +833,10 @@ def paged_decode_multi(cfg: ModelConfig, params, pool: PagePool,
     """
     from k8s_llm_rca_tpu.ops.attention import decode_attention_multi
 
+    _refuse_for_layer_table(
+        cfg, "multi-token decode (paged_decode_multi, speculative "
+        "verification)",
+        "a rejected draft would have to roll the state back")
     b, t = tokens.shape
     page_size = pool.page_size
     dtype = jnp.dtype(cfg.dtype)
@@ -919,6 +1041,36 @@ class PagedInferenceEngine(EngineBase):
                              "(CP already seq-shards activations), and is "
                              "unsupported on the PP paths (the pipelined "
                              "prefill/decode do not thread sp_mesh)")
+        # a model with Mamba-2 layers (its layer table says so) keeps a
+        # recurrent state per slot beside the pages.  What rests on a
+        # sequence's past being its pages, or on the uniform Llama block,
+        # is not built for it and is refused here by name
+        for asked, what, why in (
+                (engine_cfg.prefix_cache,
+                 "the prefix cache (EngineConfig.prefix_cache)",
+                 "a shared prefix would need the state as it stood at the "
+                 "page boundary, which nothing keeps: build the engine "
+                 "with prefix_cache=False"),
+                (engine_cfg.max_spilled_pages,
+                 "KV spill to the host (EngineConfig.max_spilled_pages)",
+                 "a spilled sequence is its pages AND its state; a "
+                 "preempted sequence is recomputed from its tokens"),
+                (engine_cfg.prefill_chunk_budget,
+                 "chunked prefill (EngineConfig.prefill_chunk_budget)",
+                 "a later chunk would have to start from the state the "
+                 "earlier one left, and the chunk program starts from "
+                 "pages alone"),
+                (engine_cfg.speculative_k or draft_model is not None,
+                 "speculative decoding (EngineConfig.speculative_k, "
+                 "draft_model)",
+                 "a rejected draft would have to roll the state back"),
+                (any(m is not None for m in (tp_mesh, ep_mesh, cp_mesh,
+                                             pp_mesh, fsdp_mesh)),
+                 "a TP, EP, CP, PP or FSDP mesh",
+                 "the Mamba-2 and latent-expert layers have no sharding "
+                 "rule and no pipelined or ring form")):
+            if asked:
+                _refuse_for_layer_table(model_cfg, what, why)
         from k8s_llm_rca_tpu.engine.engine import (
             params_multi_device, validate_ep_mesh, validate_fsdp_mesh,
             validate_pp_mesh, validate_tp_mesh,
@@ -1180,7 +1332,15 @@ class PagedInferenceEngine(EngineBase):
                 f"(None, 'int8' or 'int4')")
         self.pool = init_paged_cache(
             model_cfg, engine_cfg.num_pages, self.page_size,
-            kv_dtype=engine_cfg.kv_cache_dtype)
+            kv_dtype=engine_cfg.kv_cache_dtype, n_slots=b)
+        # bytes of recurrent state the slots hold (0 for a model whose
+        # past is its pages), and the host's copy of the device's
+        # running count of local expert pairs
+        self._state_bytes = sum(
+            a.nbytes for a in (self.pool.ssm_state, self.pool.conv_state)
+            if a is not None)
+        self._moe_pairs_seen = 0
+        METRICS.gauge("engine.state_bytes", self._state_bytes)
         if self._cp_parts:
             # CP seq-sharded pool: the PAGE axis shards over the seq mesh
             # axis — device p holds pages [p*N/P, (p+1)*N/P), exactly the
@@ -1566,17 +1726,77 @@ class PagedInferenceEngine(EngineBase):
                                 if self.prefix_cache is not None else 0)
         return g
 
-    def _count_prefill_padded(self, n_positions: int) -> None:
+    def _count_prefill_padded(self, n_positions: int, rows: int = 1,
+                              n_true: int = 0) -> None:
         """One prefill dispatch of ``n_positions`` (rows x bucket, pad
         included), and whether its expert MLPs took the token-grouped
-        path (``llama.moe_grouped``, decided from the same number): the
-        share of the two says how much of the prefill was routed.  Under
-        EP or PP the MLP runs those paths' own dispatch, over other row
-        counts, and nothing is counted."""
+        path (``llama.moe_grouped``, decided from the positions of one
+        expert-layer call: the whole dispatch, or one of its ``rows``
+        where a model with a layer table runs them one after another):
+        the share of the two says how much of the prefill was routed.
+        Under EP or PP the MLP runs those paths' own dispatch, over other
+        row counts, and nothing is counted.  A model with a layer table
+        also counts what its Mamba-2 and expert layers ran over:
+        positions x Mamba layers (pad included, and the ``n_true`` real
+        ones apart), and positions x picks x expert layers."""
+        cfg = self.model_cfg
+        per_call = n_positions // rows if cfg.layer_pattern else n_positions
         self._count("engine.prefill_padded_tokens", n_positions)
-        if self._moe_in_model and llama.moe_grouped(self.model_cfg,
-                                                    n_positions):
+        if self._moe_in_model and llama.moe_grouped(cfg, per_call):
             self._count("engine.moe_grouped_tokens", n_positions)
+        if cfg.layer_pattern:
+            self._count("engine.ssm_prefill_tokens",
+                        n_positions * cfg.n_ssm_layers)
+            self._count("engine.ssm_prefill_true_tokens",
+                        n_true * cfg.n_ssm_layers)
+            self._count_routed_pairs(n_positions)
+
+    def _count_routed_pairs(self, n_positions: int) -> None:
+        """``engine.moe_routed_pairs``: (position, expert) pairs the
+        expert layers of a model with a layer table routed, from the
+        shape; ``engine.moe_local_pairs`` is the part whose expert is
+        held here, counted on the device (``_note_local_pairs``)."""
+        cfg = self.model_cfg
+        self._count("engine.moe_routed_pairs",
+                    n_positions * cfg.n_experts_per_tok
+                    * cfg.layer_pattern.count("E"))
+
+    def _count_state_steps(self, steps: int) -> None:
+        """One decode dispatch of ``steps`` model steps over every slot,
+        for a model with a layer table: the state updates it ran (slots
+        x steps x Mamba layers; a dead slot's is run too), the pairs its
+        expert layers routed, and how many slots hold a live sequence."""
+        cfg = self.model_cfg
+        if not cfg.layer_pattern:
+            return
+        b = self.engine_cfg.max_batch
+        METRICS.gauge("engine.state_slots_live", len(self._active))
+        self._count("engine.ssm_decode_slot_steps",
+                    b * steps * cfg.n_ssm_layers)
+        self._count_routed_pairs(b * steps)
+
+    def _local_pairs(self) -> tuple:
+        """The device's running count of local expert pairs, for the
+        tick's one coalesced fetch to bring along (nothing for a model
+        without one)."""
+        n = self.pool.moe_local_pairs
+        return () if n is None else (n,)
+
+    def _note_local_pairs(self, fetched) -> None:
+        """``engine.moe_local_pairs`` from what ``_local_pairs`` fetched:
+        the count since the last fetch (it wraps at 2**32)."""
+        for n in fetched:
+            now = int(n[0]) & 0xFFFFFFFF
+            self._count("engine.moe_local_pairs",
+                        (now - self._moe_pairs_seen) & 0xFFFFFFFF)
+            self._moe_pairs_seen = now
+
+    def _slots_kw(self, slots) -> dict:
+        """The ``slots=`` a prefill of a model with a state per slot is
+        given (nothing for any other)."""
+        if not self.model_cfg.n_ssm_layers:
+            return {}
+        return {"slots": np.asarray(slots, np.int32)}
 
     def _count_attn_pages(self, steps: int, active_slots) -> None:
         """The pages that hold live context in this dispatch, from the
@@ -1737,6 +1957,7 @@ class PagedInferenceEngine(EngineBase):
             self._count("engine.dispatches")
             self._count_decode(1)
             self._count_attn_pages(1, active_slots)
+            self._count_state_steps(1)
             self.pool, logits = self._decode(
                 self.model_cfg, self.params, self.pool,
                 cur_d, lens_d, bt_d,
@@ -1749,7 +1970,8 @@ class PagedInferenceEngine(EngineBase):
                 next_tokens = self._sample(logits, sub, self.sampling)
         self._count("engine.decode_tokens", len(active_slots))
 
-        (host_next,) = self._fetch(next_tokens)
+        host_next, *pairs = self._fetch(next_tokens, *self._local_pairs())
+        self._note_local_pairs(pairs)
         with profiling.annotate("engine.commit"):
             now = self._now()
             for slot in active_slots:
@@ -1786,6 +2008,7 @@ class PagedInferenceEngine(EngineBase):
             self._count("engine.dispatches")
             self._count_decode(1)
             self._count_attn_pages(1, active_slots)
+            self._count_state_steps(1)
             self.pool, nxt, new_lens, self._key = self._overlap_decode(
                 self.model_cfg, self.params, self.pool, cur_d, lens_d,
                 bt_d, self._key, self.sampling, self._dev_cap,
@@ -2032,6 +2255,7 @@ class PagedInferenceEngine(EngineBase):
         cur_d, lens_d, bt_d = self._device_state()
         self._count_decode(chunk)
         self._count_attn_pages(chunk, active_slots)
+        self._count_state_steps(chunk)
         if setup is None:
             with profiling.annotate("engine.decode_step"):
                 self._count("engine.dispatches")
@@ -2058,7 +2282,9 @@ class PagedInferenceEngine(EngineBase):
             # always retired, trashing its row), so the resident state
             # stays clean: the next scan dispatches with zero uploads
             self._dev_cur, self._dev_lens = toks[-1], new_lens
-        (toks_host,) = self._fetch(toks)                # [chunk, B]
+        toks_host, *pairs = self._fetch(toks,           # [chunk, B]
+                                        *self._local_pairs())
+        self._note_local_pairs(pairs)
 
         def post_commit(slot: int, token: int) -> None:
             self.lengths[slot] += 1
@@ -2254,11 +2480,11 @@ class PagedInferenceEngine(EngineBase):
                 self.pool, logits = self._prefill(
                     self.model_cfg, self.params, self.pool,
                     jnp.asarray(padded), jnp.int32(n),
-                    jnp.asarray(table[:n_pages]))
+                    jnp.asarray(table[:n_pages]), **self._slots_kw([slot]))
             self._key, sub = jax.random.split(self._key)
             first = self._sample(logits, sub, self.sampling)
         self._count("engine.prefill_tokens", len(rest))
-        self._count_prefill_padded(padded.size)
+        self._count_prefill_padded(padded.size, n_true=len(rest))
 
         if req.grammar is not None:
             # grammar first tokens stay synchronous: the FSM needs the
@@ -2641,11 +2867,14 @@ class PagedInferenceEngine(EngineBase):
             self._count("engine.dispatches")
             self.pool, logits = self._prefill_batch(
                 self.model_cfg, self.params, self.pool,
-                jnp.asarray(tokens), jnp.asarray(lens), jnp.asarray(maps))
+                jnp.asarray(tokens), jnp.asarray(lens), jnp.asarray(maps),
+                # a padding row repeats the last real row's slot too
+                **self._slots_kw(slots + slots[-1:] * (n_pad - n)))
             self._key, sub = jax.random.split(self._key)
             firsts = self._sample(logits, sub, self.sampling)
         self._count("engine.prefill_tokens", int(lens[:n].sum()))
-        self._count_prefill_padded(tokens.size)
+        self._count_prefill_padded(tokens.size, rows=n_pad,
+                                   n_true=int(lens[:n].sum()))
         self._count("engine.batched_admissions", n)
 
         if any(r.grammar is not None for r in reqs):
@@ -2993,6 +3222,9 @@ class PagedInferenceEngine(EngineBase):
         caller cancels it (RELEASE) — export is idempotent across retry
         attempts.  None = not exportable this pump (mid-chunked-prefill,
         or a deferred first token not yet committed)."""
+        _refuse_for_layer_table(
+            self.model_cfg, "export of a run (export_run)",
+            "the record that leaves holds pages alone")
         self._overlap_barrier()
         for pst in self._prefilling.values():
             if pst["req"].seq_id == seq_id:
@@ -3066,6 +3298,10 @@ class PagedInferenceEngine(EngineBase):
           dtype/kv_dim/field-set mismatch is a loud ValueError — that
           is a MISCONFIGURED tier pair (TierRouter refuses to build
           one), not a transient the retry loop could ever fix."""
+        if kv is not None:
+            _refuse_for_layer_table(
+                self.model_cfg, "adoption of a run's cache (adopt_run "
+                "with kv)", "the record that arrives holds pages alone")
         relayout = False
         if kv is not None:
             resume_len = (len(entry["prompt_ids"])

@@ -228,8 +228,9 @@ def _qkv(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
     q = _w_mm(cfg, x, layer["wq"]).reshape(b, s, -1, cfg.head_dim)
     k = _w_mm(cfg, x, layer["wk"]).reshape(b, s, -1, cfg.head_dim)
     v = _w_mm(cfg, x, layer["wv"]).reshape(b, s, -1, cfg.head_dim)
-    q = apply_rope(q, angles, positions)
-    k = apply_rope(k, angles, positions)
+    if cfg.use_rope:
+        q = apply_rope(q, angles, positions)
+        k = apply_rope(k, angles, positions)
     return q, k, v
 
 
@@ -281,48 +282,132 @@ def _mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
 # 5.9 us a token against 17.4.
 MOE_GROUPED_MIN_ROWS_PER_EXPERT = 384
 
+# The same threshold for small bf16 experts in a latent space
+# (``cfg.moe_latent_size > 0``).  Set from one expert layer (router, latent
+# projections, shared expert and all) at NVIDIA-Nemotron-3-Super's widths on
+# one TPU v5e: 1024 -> 2688 -> 1024 squared-ReLU experts in bf16, 128 of the
+# router's 512 held, 22 picks a position (my chip run, PR 32; ms a call,
+# dense | grouped, by T = B * S; "all local" is the grouped form when every
+# pick is held, the same rows with none behind the last group):
+#      T    rows/expert    dense   grouped   (all local)
+#     32         1.4        3.15     3.10       5.26       (a decode call)
+#     64         2.75       3.03     3.92       6.03       (the cell's decode call)
+#    256        11          3.11     6.18       8.48
+#   1024        44          9.76     9.10      12.71      <- grouped wins from here
+#   2048        88         18.06    14.37      21.26
+#   4096       176         35.12    20.72      32.49
+#  16384       704           -      60.53     101.68      (4 x 4096)
+#  32768      1408           -     113.20     192.13      (8 x 4096)
+# Nothing is dequantized, so the grouped form has no fixed cost to win back;
+# up to 256 positions the dense form is flat at 3 ms (it streams the 0.7 GB
+# of held experts through the MXU whatever the rows) and the grouped form
+# pays its sort and two row gathers.  The grouped kernel skips part of the
+# rows behind the last group (a quarter of the picks is local, and it costs
+# 0.6 of the all-local call, not 0.25): PERF.md section 7.
+MOE_GROUPED_MIN_ROWS_PER_EXPERT_LATENT = 44
+
 
 def moe_grouped(cfg: ModelConfig, n_tokens: int) -> bool:
-    """Whether ``_moe_mlp`` takes the token-grouped path for a call of
+    """Whether the expert layer takes the token-grouped path for a call of
     ``n_tokens`` positions (``B * S``, pad positions included).  The one
     place that decides: a prefill of 2048 positions gives a top-2-of-8
     router 512 rows an expert, a decode call of 32 slots gives 8, where
     grouping saves no arithmetic that matters, could skip no expert's
     dequantization (dead slots route too) and would put a sort into every
-    step of a scan.  ``cfg.fused_quant_matmul`` keeps its own kernels."""
+    step of a scan.  The rows an expert sees are the router's to say
+    (``T * k`` over the experts it scores, held here or not).
+    ``cfg.fused_quant_matmul`` keeps its own kernels."""
     if cfg.n_experts <= 0 or cfg.fused_quant_matmul:
         return False
-    rows_per_expert = n_tokens * cfg.n_experts_per_tok / cfg.n_experts
-    return rows_per_expert >= MOE_GROUPED_MIN_ROWS_PER_EXPERT
+    rows_per_expert = n_tokens * cfg.n_experts_per_tok / cfg.n_router
+    return rows_per_expert >= (MOE_GROUPED_MIN_ROWS_PER_EXPERT_LATENT
+                               if cfg.moe_latent_size
+                               else MOE_GROUPED_MIN_ROWS_PER_EXPERT)
+
+
+def _route(cfg: ModelConfig, layer: Params, x: jnp.ndarray):
+    """The router: x [B, S, H] -> (experts [B, S, k] int32 in the router's
+    own numbering, weights [B, S, k] float32).
+
+    - ``softmax`` (Mixtral): the top k logits, softmax over the kept ones.
+    - ``sigmoid`` (nemotron_h): scores ``s = sigmoid(x W_r)`` in float32
+      over every expert the router scores; the k chosen are the top of
+      ``s + bias`` (the selection bias moves the CHOICE only); weights
+      ``routed_scaling * s / sum(s)`` over the k chosen, held here or not.
+    """
+    k = cfg.n_experts_per_tok
+    if cfg.router_kind == "sigmoid":
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "bsh,he->bse", x, dq(layer["router"]),
+            preferred_element_type=jnp.float32))
+        _, topi = jax.lax.top_k(
+            scores + layer["router_bias"].astype(jnp.float32), k)
+        chosen = jnp.take_along_axis(scores, topi, axis=-1)
+        return topi, cfg.routed_scaling * chosen / jnp.sum(
+            chosen, axis=-1, keepdims=True)
+    if cfg.router_kind != "softmax":
+        raise ValueError(f"unknown router_kind {cfg.router_kind!r}")
+    router_logits = _w_mm(cfg, x, layer["router"]).astype(jnp.float32)  # [B,S,E]
+    topv, topi = jax.lax.top_k(router_logits, k)                   # [B,S,k]
+    return topi, jax.nn.softmax(topv, axis=-1)                     # [B,S,k]
+
+
+def _held(cfg: ModelConfig, topi: jnp.ndarray):
+    """The router's choices in the numbering of the experts HELD here:
+    (local ids [B, S, k], with ``cfg.n_experts`` for an expert that lives
+    elsewhere; which pairs are held, or None where every expert is)."""
+    if cfg.n_router == cfg.n_experts:
+        return topi, None
+    local = topi - cfg.expert_first
+    held = (local >= 0) & (local < cfg.n_experts)
+    return jnp.where(held, local, cfg.n_experts), held
 
 
 def _moe_mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray) -> jnp.ndarray:
-    """Mixtral sparse-MoE MLP: softmax over the top-k router logits, the
-    chosen experts' SwiGLU, their weighted sum.  Two forms of one function,
-    chosen from the call's shape by ``moe_grouped``:
+    """Sparse-expert MLP: the router's k experts a token (``_route``),
+    their MLPs, the weighted sum (``_experts``)."""
+    topi, weights = _route(cfg, layer, x)
+    return _experts(cfg, layer, x, topi, weights)
+
+
+def _experts(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
+             topi: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
+    """The chosen experts' MLPs on x [B, S, W] and their weighted sum, for
+    every model that has experts: SwiGLU (``w_gate``, ``w_up``, ``w_down``)
+    or the non-gated squared ReLU (``w_up``, ``w_down``), all experts or
+    the share held here (``cfg.expert_first``, ``cfg.n_experts`` of
+    ``cfg.n_router``: a pair whose expert lives elsewhere adds nothing, and
+    nothing stands in for it).  Two forms of one function, chosen from the
+    call's shape by ``moe_grouped``:
 
     - a large call (prefill, training) is **token-grouped**
-      (``_moe_experts_grouped``): each token's row goes to its k experts
+      (``_moe_experts_grouped``): each token's row goes to its experts
       only, so the expert arithmetic is k/E of the dense form's;
-    - a small call (decode) is **dense soft dispatch**: every expert runs
-      on every token and the router's weights zero out the rest — one
+    - a small call (decode) is **dense soft dispatch**: every expert held
+      runs on every token and the router's weights zero out the rest — one
       einsum per projection, no sort, exactly equal to hard routing.
 
-    Both are lossless (no capacity, no dropped token).  The bandwidth-
+    Both are lossless (no capacity, no dropped pair).  The bandwidth-
     optimal EP dispatch (all_to_all over the "expert" axis) lives in
     parallel/moe.py and is used by the sharded engine path.
     """
     b, s, h = x.shape
-    e, k = cfg.n_experts, cfg.n_experts_per_tok
-    router_logits = _w_mm(cfg, x, layer["router"]).astype(jnp.float32)  # [B,S,E]
-    topv, topi = jax.lax.top_k(router_logits, k)                   # [B,S,k]
-    weights = jax.nn.softmax(topv, axis=-1)                        # [B,S,k]
+    e = cfg.n_experts
+    topi, _ = _held(cfg, topi)
     if moe_grouped(cfg, b * s):
         return _moe_experts_grouped(cfg, layer, x, topi, weights)
-    # scatter the top-k weights back to a dense [B,S,E] map
+    # scatter the top-k weights back to a dense [B,S,E] map (an expert
+    # held elsewhere is past the last column and lands nowhere)
     onehot = jax.nn.one_hot(topi, e, dtype=jnp.float32)            # [B,S,k,E]
     dense_w = jnp.einsum("bske,bsk->bse", onehot, weights)         # [B,S,E]
 
+    if cfg.mlp_act == "relu2":
+        hid = jnp.square(jax.nn.relu(
+            jnp.einsum("bsh,ehi->bsei", x, dq(layer["w_up"]))))
+        # weight, then contract experts and width in one matmul
+        return jnp.einsum("bsei,eih->bsh",
+                          hid * dense_w.astype(x.dtype)[..., None],
+                          dq(layer["w_down"]))
     if cfg.fused_quant_matmul:
         gate = jax.nn.silu(qmm_experts(x, layer["w_gate"]))
         up = qmm_experts(x, layer["w_up"])
@@ -366,14 +451,17 @@ def _grouped_matmul(rows: jnp.ndarray, w: jnp.ndarray,
 def _moe_experts_grouped(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
                          topi: jnp.ndarray, weights: jnp.ndarray
                          ) -> jnp.ndarray:
-    """The expert MLPs of ``_moe_mlp`` on routed rows only.  The ``T * k``
+    """The expert MLPs of ``_experts`` on routed rows only.  The ``T * k``
     (token, expert) pairs are stable-sorted by expert, the tokens' rows
-    gathered in that order (``[T * k, H]``: a static shape), and gate, up
-    and down run as grouped matmuls over the stacked expert weights
+    gathered in that order (``[T * k, H]``: a static shape), and the
+    projections run as grouped matmuls over the stacked expert weights
     (``_grouped_matmul``: rows ``[g_e, g_e+1)`` meet expert ``e`` only).
-    ``group_sizes`` is counted from the data, so it is exact and sums to
-    ``T * k``: every pair is computed, whatever the spread (an expert no
-    token chose is an empty group).  The pairs go back to their
+    ``group_sizes`` is counted from the data, so it is exact: every pair
+    of an expert held here is computed, whatever the spread (an expert no
+    token chose is an empty group).  ``topi`` numbers the experts held
+    (``_held``); a pair whose expert lives elsewhere carries the id one
+    past the last, sorts behind every group, belongs to none and is given
+    no weight.  The pairs go back to their
     tokens by the inverse permutation and are summed under the router's
     weights, as the dense form sums them.  ``ragged_dot`` has JVP and
     transpose rules, so the path differentiates (engine/train.py)."""
@@ -382,16 +470,29 @@ def _moe_experts_grouped(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
     pairs = b * s * k
     expert = topi.reshape(pairs)
     order = jnp.argsort(expert, stable=True)            # pair ids by expert
-    group_sizes = jnp.bincount(
-        expert, length=cfg.n_experts).astype(jnp.int32)
+    if cfg.n_router == cfg.n_experts:
+        group_sizes = jnp.bincount(
+            expert, length=cfg.n_experts).astype(jnp.int32)
+    else:
+        group_sizes = jnp.bincount(
+            expert, length=cfg.n_experts + 1)[:-1].astype(jnp.int32)
     rows = x.reshape(b * s, h)[order // k]                         # [T*k,H]
-    gate = jax.nn.silu(
-        _grouped_matmul(rows, dq(layer["w_gate"]), group_sizes))
-    up = _grouped_matmul(rows, dq(layer["w_up"]), group_sizes)
-    out = _grouped_matmul(gate * up, dq(layer["w_down"]), group_sizes)
+    if cfg.mlp_act == "relu2":
+        hid = jnp.square(jax.nn.relu(
+            _grouped_matmul(rows, dq(layer["w_up"]), group_sizes)))
+        out = _grouped_matmul(hid, dq(layer["w_down"]), group_sizes)
+    else:
+        gate = jax.nn.silu(
+            _grouped_matmul(rows, dq(layer["w_gate"]), group_sizes))
+        up = _grouped_matmul(rows, dq(layer["w_up"]), group_sizes)
+        out = _grouped_matmul(gate * up, dq(layer["w_down"]), group_sizes)
     inverse = jnp.zeros_like(order).at[order].set(
         jnp.arange(pairs, dtype=order.dtype))           # pair -> sorted row
-    per_pair = out[inverse].reshape(b, s, k, h)
+    per_pair = out[inverse].reshape(b, s, k, -1)
+    if cfg.n_router != cfg.n_experts:
+        # rows behind the last group are no expert's: whatever the grouped
+        # kernel left there is dropped, not multiplied by a zero weight
+        per_pair = jnp.where((topi < cfg.n_experts)[..., None], per_pair, 0)
     return jnp.einsum("bskh,bsk->bsh", per_pair, weights.astype(x.dtype))
 
 

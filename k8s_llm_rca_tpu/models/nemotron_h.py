@@ -1,0 +1,307 @@
+"""nemotron_h: a decoder whose layers are of three kinds, one a layer.
+
+``cfg.layer_pattern`` gives each layer its letter, and every layer is
+``x = x + mix(RMSNorm(x))`` with ``mix`` by the letter:
+
+- ``M`` — a Mamba-2 mixer: ``[z | xBC | dt] = u W_in``; a depthwise causal
+  convolution and SiLU over ``xBC``; ``dt = softplus(dt + dt_bias)``,
+  ``A = -exp(A_log)``; the state-space recurrence of ops/ssm.py per head;
+  an RMSNorm over each group of ``y * silu(z)``; ``W_out``.  What a
+  sequence keeps of its past is the recurrent state ``[heads, head_dim,
+  state]`` in float32 and the convolution's last ``kernel - 1`` inputs.
+- ``E`` — a LatentMoE layer: a sigmoid router with a selection bias over
+  ``cfg.n_router`` experts (models/llama._route), the chosen experts'
+  non-gated squared-ReLU MLPs in a latent space between two shared
+  projections (llama._experts, on the experts held here), and an
+  always-on shared expert at the full width.
+- ``*`` — grouped-query attention with NO rotary embedding
+  (``cfg.use_rope`` False), the only kind that caches keys and values.
+
+The engine's programs (engine/paged.py) walk this table; here are the
+weights, the three mixers in their prefill and their decode form, and a
+plain ``forward`` for tests.  A prefill runs one row of the batch after
+another (a ``jax.lax.scan`` over rows): rows share nothing, a row of a
+bucket keeps every matmul large, the temporaries (the in-projection's
+18,560 columns, the routed rows of 22 picks a position) stay one row's,
+and a padding row, which repeats the row before it, costs nothing.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from k8s_llm_rca_tpu.config import ModelConfig
+from k8s_llm_rca_tpu.models import llama
+from k8s_llm_rca_tpu.models.quant import gather_rows
+from k8s_llm_rca_tpu.ops import ssm
+from k8s_llm_rca_tpu.ops.attention import causal_attention
+from k8s_llm_rca_tpu.ops.norms import rms_norm
+
+Params = Dict[str, Any]
+F32 = jnp.float32
+
+
+def init_params(cfg: ModelConfig, key: jax.Array,
+                tensor_transform=None) -> Params:
+    """Seeded weights (``tensor_transform`` as in llama.init_params: applied
+    to every matmul weight as it is made).  ``dt_bias`` is the inverse
+    softplus of a log-uniform step in ``[ssm_dt_min, ssm_dt_max]`` floored
+    at ``ssm_dt_floor``, ``A_log = log U(1, 16)``: the family's own
+    initialisation, so that the seeded recurrence has the decays a trained
+    one has (from a step of memory to thousands).  Every layer's output
+    projection is scaled by ``1 / sqrt(depth)`` (one residual a layer;
+    the family's ``rescale_prenorm_residual``), ``depth`` the whole
+    model's (``cfg.init_layers``) where these layers are a stage of a
+    deeper one."""
+    dtype = jnp.dtype(cfg.dtype)
+    h = cfg.hidden_size
+    keys = jax.random.split(key, cfg.n_layers + 2)
+    scale = 1.0 / math.sqrt(h)
+    out_scale = 1.0 / math.sqrt(cfg.init_layers or cfg.n_layers)
+    tt = tensor_transform or (lambda w, **_: w)
+
+    def dense(k, shape, sc, **tt_kw):
+        w = llama._dense(k, shape, sc, dtype)
+        out = tt(w, **tt_kw)
+        if out is not w:
+            w.delete()
+        return out
+
+    layers = []
+    for i, kind in enumerate(cfg.layer_pattern):
+        lk = jax.random.split(keys[i], 8)
+        if kind == "M":
+            inner, heads = cfg.ssm_inner, cfg.ssm_heads
+            conv_dim, kc = cfg.ssm_conv_dim, cfg.ssm_conv_kernel
+            step = jnp.exp(
+                jax.random.uniform(lk[2], (heads,), F32)
+                * (math.log(cfg.ssm_dt_max) - math.log(cfg.ssm_dt_min))
+                + math.log(cfg.ssm_dt_min))
+            step = jnp.maximum(step, cfg.ssm_dt_floor)
+            layer = {
+                "norm": jnp.ones((h,), dtype),
+                "w_in": dense(lk[0], (h, 2 * inner + 2 * cfg.ssm_groups
+                                      * cfg.ssm_state_size + heads), scale),
+                "conv_w": llama._dense(lk[1], (kc, conv_dim),
+                                       1.0 / math.sqrt(kc), dtype),
+                "conv_b": llama._dense(lk[4], (conv_dim,), 0.1, dtype),
+                "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+                "A_log": jnp.log(jax.random.uniform(
+                    lk[3], (heads,), F32, 1.0, 16.0)),
+                "D": jnp.ones((heads,), F32),
+                "gate_norm": jnp.ones((inner,), dtype),
+                "w_out": dense(lk[5], (inner, h),
+                               out_scale / math.sqrt(inner)),
+            }
+        elif kind == "E":
+            e, lat = cfg.n_experts, cfg.moe_latent_size
+            inter, shared = cfg.expert_size, cfg.shared_expert_size
+            layer = {
+                "norm": jnp.ones((h,), dtype),
+                "router": dense(lk[0], (h, cfg.n_router), scale),
+                "router_bias": 0.1 * jax.random.normal(
+                    lk[1], (cfg.n_router,), F32),
+                "w_latent_down": dense(lk[2], (h, lat), scale),
+                "w_up": dense(lk[3], (e, lat, inter), 1.0 / math.sqrt(lat),
+                              axis=(0, -1)),
+                "w_down": dense(lk[4], (e, inter, lat),
+                                out_scale / math.sqrt(inter), axis=(0, -1)),
+                "w_latent_up": dense(lk[5], (lat, h), 1.0 / math.sqrt(lat)),
+                "w_shared_up": dense(lk[6], (h, shared), scale),
+                "w_shared_down": dense(lk[7], (shared, h),
+                                       out_scale / math.sqrt(shared)),
+            }
+        else:
+            q, kv = cfg.q_dim, cfg.kv_dim
+            layer = {
+                "attn_norm": jnp.ones((h,), dtype),
+                "wq": dense(lk[0], (h, q), scale),
+                "wk": dense(lk[1], (h, kv), scale),
+                "wv": dense(lk[2], (h, kv), scale),
+                "wo": dense(lk[3], (q, h), out_scale / math.sqrt(q)),
+            }
+        layers.append(layer)
+    return {
+        "embedding": dense(keys[-2], (cfg.vocab_size, h), 1.0, axis=0),
+        "final_norm": jnp.ones((h,), dtype),
+        "layers": layers,
+        "lm_head": dense(keys[-1], (cfg.vocab_size, h), scale, axis=0),
+    }
+
+
+# ---------------------------------------------------------------------------
+# the three mixers
+# ---------------------------------------------------------------------------
+
+
+def _mamba_split(cfg: ModelConfig, layer: Params, u: jnp.ndarray):
+    """u [..., H] -> (z [..., inner], xBC [..., conv_dim], dt [..., heads])."""
+    proj = llama._w_mm(cfg, u, layer["w_in"])
+    inner, conv_dim = cfg.ssm_inner, cfg.ssm_conv_dim
+    return (proj[..., :inner], proj[..., inner:inner + conv_dim],
+            proj[..., inner + conv_dim:])
+
+
+def _xbc_split(cfg: ModelConfig, xbc: jnp.ndarray):
+    """[..., conv_dim] -> x [..., heads, head_dim], B and C [..., G, N]."""
+    inner, gn = cfg.ssm_inner, cfg.ssm_groups * cfg.ssm_state_size
+    lead = xbc.shape[:-1]
+    return (xbc[..., :inner].reshape(*lead, cfg.ssm_heads, cfg.ssm_head_dim),
+            xbc[..., inner:inner + gn].reshape(
+                *lead, cfg.ssm_groups, cfg.ssm_state_size),
+            xbc[..., inner + gn:].reshape(
+                *lead, cfg.ssm_groups, cfg.ssm_state_size))
+
+
+def _mamba_out(cfg: ModelConfig, layer: Params, y: jnp.ndarray,
+               z: jnp.ndarray) -> jnp.ndarray:
+    """y [..., heads, head_dim] float32, z [..., inner] -> [..., H]: the
+    gate, the RMSNorm over each group of channels, the out-projection."""
+    lead = z.shape[:-1]
+    gated = y.reshape(*lead, cfg.ssm_inner) * jax.nn.silu(z.astype(F32))
+    grouped = gated.reshape(*lead, cfg.ssm_groups, -1)
+    normed = grouped * jax.lax.rsqrt(
+        jnp.mean(grouped * grouped, axis=-1, keepdims=True)
+        + cfg.rms_norm_eps)
+    normed = normed.reshape(*lead, cfg.ssm_inner) \
+        * layer["gate_norm"].astype(F32)
+    return llama._w_mm(cfg, normed.astype(z.dtype), layer["w_out"])
+
+
+def mamba_prefill(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
+                  lengths: jnp.ndarray):
+    """One Mamba-2 layer over fresh right-padded sequences x [B, S, H].
+    Returns (x', ssm_state [B, heads, head_dim, N] float32, conv_state
+    [B, kernel - 1, conv_dim]) with both states as they stand after each
+    row's last TRUE position: a pad position's ``dt`` is 0, and the
+    convolution's tail is cut at ``lengths``."""
+    u = rms_norm(x, layer["norm"], cfg.rms_norm_eps)
+    z, xbc, dt = _mamba_split(cfg, layer, u)
+    tail = ssm.conv_tail(xbc, lengths, cfg.ssm_conv_kernel)
+    xs, b, c = _xbc_split(cfg, ssm.causal_conv(
+        xbc, layer["conv_w"], layer["conv_b"]))
+    dt = jax.nn.softplus(dt.astype(F32) + layer["dt_bias"])
+    true = jnp.arange(x.shape[1])[None, :] < lengths[:, None]
+    dt = jnp.where(true[..., None], dt, 0.0)
+    y, state = ssm.ssm_chunk_scan(xs, dt, -jnp.exp(layer["A_log"]), b, c,
+                                  layer["D"], cfg.ssm_chunk)
+    return x + _mamba_out(cfg, layer, y, z), state, tail
+
+
+def mamba_decode(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
+                 ssm_state: jnp.ndarray, conv_state: jnp.ndarray):
+    """One Mamba-2 layer for one new position of every slot: x [B, 1, H],
+    ssm_state [B, heads, head_dim, N], conv_state [B, kernel - 1,
+    conv_dim].  Returns (x', ssm_state', conv_state')."""
+    u = rms_norm(x[:, 0], layer["norm"], cfg.rms_norm_eps)
+    z, xbc, dt = _mamba_split(cfg, layer, u)
+    xbc, conv_state = ssm.conv_step(xbc, layer["conv_w"], layer["conv_b"],
+                                    conv_state)
+    xs, b, c = _xbc_split(cfg, xbc)
+    dt = jax.nn.softplus(dt.astype(F32) + layer["dt_bias"])
+    y, ssm_state = ssm.ssm_state_update(
+        ssm_state, xs, dt, -jnp.exp(layer["A_log"]), b, c, layer["D"])
+    return (x + _mamba_out(cfg, layer, y, z)[:, None], ssm_state,
+            conv_state)
+
+
+def expert_layer(cfg: ModelConfig, layer: Params, x: jnp.ndarray
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """One LatentMoE layer over x [B, S, H].  Returns (x', the number of
+    (position, expert) pairs whose expert is held here, an int32 scalar
+    counted from the router's choices: ``engine.moe_local_pairs``)."""
+    u = rms_norm(x, layer["norm"], cfg.rms_norm_eps)
+    topi, weights = llama._route(cfg, layer, u)
+    latent = llama._w_mm(cfg, u, layer["w_latent_down"])
+    routed = llama._experts(cfg, layer, latent, topi, weights)
+    shared = jnp.square(jax.nn.relu(
+        llama._w_mm(cfg, u, layer["w_shared_up"])))
+    out = (llama._w_mm(cfg, routed, layer["w_latent_up"])
+           + llama._w_mm(cfg, shared, layer["w_shared_down"]))
+    _, held = llama._held(cfg, topi)
+    n_local = (jnp.sum(held, dtype=jnp.int32) if held is not None
+               else jnp.int32(topi.size))
+    return x + out, n_local
+
+
+def attention_prefill(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
+                      lengths: jnp.ndarray, attention_fn=None):
+    """One attention layer over x [B, S, H]: (x', k, v [B, S, kv_dim])."""
+    b, s, _ = x.shape
+    q, k, v = llama._decode_qkv(cfg, layer, x, None, None)
+    attn = (causal_attention(q, k, v, lengths) if attention_fn is None
+            else attention_fn(q, k, v))
+    x = x + llama._w_mm(cfg, attn.reshape(b, s, cfg.q_dim), layer["wo"])
+    return x, k.reshape(b, s, cfg.kv_dim), v.reshape(b, s, cfg.kv_dim)
+
+
+# ---------------------------------------------------------------------------
+# entry points
+# ---------------------------------------------------------------------------
+
+
+def _stack(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
+           lengths: jnp.ndarray, use_flash: bool = False):
+    """The layers over right-padded rows tokens [N, S]: the residual
+    stream [N, S, H] and what each row leaves behind (keys and values of
+    the attention layers [La, N, S, kv_dim], the Mamba layers' states
+    [Lm, N, ...], the local-pair count)."""
+    x = gather_rows(params["embedding"], tokens).astype(jnp.dtype(cfg.dtype))
+    attention_fn = None
+    if use_flash and tokens.shape[1] >= 1024:
+        attention_fn = llama._flash_attention_fn(lengths, None)
+    ks, vs, states, tails = [], [], [], []
+    n_local = jnp.int32(0)
+    for kind, layer in zip(cfg.layer_pattern, params["layers"]):
+        if kind == "M":
+            x, state, tail = mamba_prefill(cfg, layer, x, lengths)
+            states.append(state.astype(jnp.dtype(cfg.ssm_state_dtype)))
+            tails.append(tail)
+        elif kind == "E":
+            x, n = expert_layer(cfg, layer, x)
+            n_local = n_local + n
+        else:
+            x, k, v = attention_prefill(cfg, layer, x, lengths,
+                                        attention_fn)
+            ks.append(k)
+            vs.append(v)
+    return x, (jnp.stack(ks), jnp.stack(vs), jnp.stack(states),
+               jnp.stack(tails), n_local)
+
+
+def forward(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
+            seq_lens: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """tokens [B, S] -> logits [B, S, V] float32 (tests and scoring)."""
+    b, s = tokens.shape
+    if seq_lens is None:
+        seq_lens = jnp.full((b,), s, jnp.int32)
+    x, _ = _stack(cfg, params, tokens, seq_lens)
+    return llama._logits(cfg, params, x)
+
+
+def prefill_rows(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
+                 lengths: jnp.ndarray, use_flash: bool = False):
+    """Batched prefill WITHOUT a cache write, one row after another (the
+    temporaries stay one row's): tokens [N, S] right-padded, lengths [N] ->
+    (k, v [La, N, S, kv_dim], ssm_state [Lm, N, heads, head_dim, N_state],
+    conv_state [Lm, N, kernel - 1, conv_dim], logits [N, V] at each row's
+    last true token, local pairs int32).  The caller writes pages and the
+    slots' state (engine/paged.paged_prefill_batch)."""
+
+    def one(row):
+        toks, n = row
+        x, (k, v, state, tail, n_local) = _stack(
+            cfg, params, toks[None], n[None], use_flash)
+        last = jax.lax.dynamic_slice_in_dim(x, n - 1, 1, axis=1)
+        logits = llama._logits(cfg, params, last)[0, 0]
+        return k[:, 0], v[:, 0], state[:, 0], tail[:, 0], logits, n_local
+
+    k, v, state, tail, logits, n_local = jax.lax.map(
+        one, (tokens, lengths.astype(jnp.int32)))
+    return (jnp.moveaxis(k, 0, 1), jnp.moveaxis(v, 0, 1),
+            jnp.moveaxis(state, 0, 1), jnp.moveaxis(tail, 0, 1), logits,
+            jnp.sum(n_local))

@@ -66,7 +66,7 @@ def build_service(args) -> AssistantService:
     import jax
 
     from k8s_llm_rca_tpu.engine import make_engine
-    from k8s_llm_rca_tpu.models import llama
+    from k8s_llm_rca_tpu.models import init_params
     from k8s_llm_rca_tpu.models.quant import (
         quantize_params, quantizing_transform,
     )
@@ -80,6 +80,10 @@ def build_service(args) -> AssistantService:
     tokenizer = get_tokenizer(vocab_size=model_cfg.vocab_size)
     bits = 4 if args.int4 else 8 if args.int8 else None
     if args.weights:
+        if model_cfg.layer_pattern:
+            raise SystemExit(
+                f"--weights: models/loader.py reads Llama-family "
+                f"checkpoints; {model_cfg.name!r} has a layer table")
         from k8s_llm_rca_tpu.models.loader import load_llama
 
         params = load_llama(model_cfg, args.weights)
@@ -88,7 +92,7 @@ def build_service(args) -> AssistantService:
     else:
         # quantize each weight as it is created: a full bf16 llama3-8b is
         # 16 GB, the whole HBM of one v5e chip
-        params = llama.init_params(
+        params = init_params(
             model_cfg, jax.random.PRNGKey(0),
             tensor_transform=quantizing_transform(bits=bits) if bits
             else None)
@@ -103,6 +107,10 @@ def build_service(args) -> AssistantService:
             args.max_seq_len, model_cfg.name, max_seq)
     ecfg_kw = dict(max_batch=args.max_batch, max_seq_len=max_seq,
                    kv_cache_dtype=args.kv_dtype)
+    if model_cfg.n_ssm_layers:
+        # the engine refuses the prefix cache for a model that keeps a
+        # recurrent state per slot (docs/serving.md, "Layer kinds")
+        ecfg_kw["prefix_cache"] = False
     if args.decode_chunk is not None:
         ecfg_kw["decode_chunk"] = args.decode_chunk   # else EngineConfig's
     engine = make_engine(model_cfg, EngineConfig(**ecfg_kw),

@@ -97,6 +97,12 @@ class Metrics:
         with self._lock:
             self.counters[name] += value
 
+    def gauge(self, name: str, value: float) -> None:
+        """Set (not add to) a value that is a level, not a count: it
+        reads like a counter in ``count`` and ``snapshot``."""
+        with self._lock:
+            self.counters[name] = float(value)
+
     @contextlib.contextmanager
     def timer(self, name: str):
         t0 = time.perf_counter()

@@ -163,6 +163,60 @@ class TestDecodeStepWritesThePoolInPlace:
         assert mem.temp_size_in_bytes < layer_bytes
 
 
+class TestDecodeStepUpdatesTheStateInPlace:
+    """The stepwise decode program of ``nemotron3-super-d11.audit-report``
+    at the configuration's own widths (2 of its 11 layers, a Mamba-2 mixer
+    and the attention layer; 64 slots, the cell's pool).  Each slot keeps
+    4.2 MB of recurrent state a Mamba layer, 268 MB over the slots: a step
+    that sliced a layer's state out, updated it and set it back would copy
+    that twice a layer (PR 28's lesson, for pages).  Seen here without a
+    chip: the compiled program copies no array of the state's shape, keeps
+    state and pages in the buffers they were donated in, and needs less
+    room for temporaries than a tenth of one layer's state."""
+
+    SLOTS, N_PAGES, PAGE = 64, 16384, 16
+
+    def test_no_layer_of_the_state_is_copied(self, chip, monkeypatch):
+        import re
+
+        from k8s_llm_rca_tpu.config import ModelConfig
+        from k8s_llm_rca_tpu.engine import paged
+        from k8s_llm_rca_tpu.models import nemotron_h
+
+        cfg = ModelConfig(
+            name="nemotron-2-layers", vocab_size=32768, hidden_size=4096,
+            n_layers=2, layer_pattern="M*", n_heads=32, n_kv_heads=2,
+            head_dim=128, use_rope=False, max_seq_len=4096,
+            dtype="bfloat16", tie_embeddings=False, ssm_heads=128,
+            ssm_head_dim=64, ssm_groups=8, ssm_state_size=128)
+        params = _described(chip, jax.eval_shape(
+            lambda: nemotron_h.init_params(cfg, jax.random.PRNGKey(0))))
+        pool = _described(chip, jax.eval_shape(
+            lambda: paged.init_paged_cache(cfg, self.N_PAGES, self.PAGE,
+                                           n_slots=self.SLOTS)))
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = jax.jit(
+            paged.paged_decode_step, static_argnums=0, donate_argnums=2,
+            static_argnames="use_kernel").lower(
+                cfg, params, pool, chip((self.SLOTS,), I32),
+                chip((self.SLOTS,), I32),
+                chip((self.SLOTS, cfg.max_seq_len // self.PAGE), I32),
+                use_kernel=True).compile()
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text            # the attention kernel
+
+        state = rf"f32\[(1,)?{self.SLOTS},128,64,128\]"
+        assert re.search(state, text)               # it is there, updated
+        copies = [line for line in text.splitlines()
+                  if re.search(rf"= {state}\S* copy\(", line)]
+        assert not copies, copies[:2]
+        mem = compiled.memory_analysis()
+        state_bytes = self.SLOTS * 128 * 64 * 128 * 4
+        pages_bytes = 2 * self.N_PAGES * self.PAGE * cfg.kv_dim * 2
+        assert mem.alias_size_in_bytes >= state_bytes + pages_bytes
+        assert mem.temp_size_in_bytes < state_bytes // 10
+
+
 class TestPrefillRoutesEachTokenToItsExperts:
     """The batched prefill program of ``mixtral-d8.audit-prefill`` at its
     largest warm-up shape: 4 rows of 4096 at Mixtral-8x7B widths (2 layers

@@ -1,0 +1,98 @@
+"""The Mamba-2 ops (ops/ssm.py): the chunked scan a prefill runs and the
+one-step update a decode step runs both equal the recurrence they stand for,
+a position whose ``dt`` is 0 leaves the state alone, and the convolution's
+tail is the last true inputs.  On the CPU: a correctness check, never a
+time."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from k8s_llm_rca_tpu.ops import ssm
+
+H, P, G, N = 8, 4, 2, 16
+
+
+def _inputs(batch, length, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+    x = jax.random.normal(k[0], (batch, length, H, P))
+    dt = jax.nn.softplus(jax.random.normal(k[1], (batch, length, H)) - 2.0)
+    a = -jnp.exp(jax.random.uniform(k[2], (H,), minval=0.0, maxval=2.5))
+    b = jax.random.normal(k[3], (batch, length, G, N))
+    c = jax.random.normal(k[4], (batch, length, G, N))
+    d = jax.random.normal(k[5], (H,))
+    return x, dt, a, b, c, d
+
+
+@pytest.mark.parametrize("length, chunk", [
+    (32, 16), (48, 16), (16, 16),       # the chunk divides the length
+    (37, 16), (5, 16), (129, 32),       # it does not
+    (64, 128),                          # one chunk longer than the sequence
+])
+def test_chunked_scan_equals_the_recurrence(length, chunk):
+    x, dt, a, b, c, d = _inputs(2, length, seed=length)
+    want_y, want_h = ssm.ssm_recurrence(x, dt, a, b, c, d)
+    with jax.default_matmul_precision("highest"):
+        y, h = ssm.ssm_chunk_scan(x, dt, a, b, c, d, chunk)
+    np.testing.assert_allclose(y, want_y, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(h, want_h, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("true", [1, 7, 16, 23])
+def test_a_pad_position_does_not_advance_the_state(true):
+    """``dt`` masked to 0 past a row's true length: the state after a
+    padded bucket is the state after the true tokens, whatever the pad
+    positions hold."""
+    x, dt, a, b, c, d = _inputs(1, 32, seed=true)
+    _, want_h = ssm.ssm_recurrence(x[:, :true], dt[:, :true], a,
+                                   b[:, :true], c[:, :true], d)
+    masked = jnp.where(jnp.arange(32)[None, :, None] < true, dt, 0.0)
+    with jax.default_matmul_precision("highest"):
+        _, h = ssm.ssm_chunk_scan(x, masked, a, b, c, d, 16)
+    np.testing.assert_allclose(h, want_h, rtol=2e-4, atol=2e-4)
+
+
+def test_state_update_is_one_step_of_the_recurrence():
+    x, dt, a, b, c, d = _inputs(3, 9)
+    want_y, want_h = ssm.ssm_recurrence(x, dt, a, b, c, d)
+    _, before = ssm.ssm_recurrence(x[:, :8], dt[:, :8], a, b[:, :8],
+                                   c[:, :8], d)
+    y, h = ssm.ssm_state_update(before, x[:, 8], dt[:, 8], a, b[:, 8],
+                                c[:, 8], d)
+    np.testing.assert_allclose(y, want_y[:, 8], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(h, want_h, rtol=1e-5, atol=1e-5)
+
+
+def test_state_update_keeps_the_state_dtype_and_head_groups():
+    """Head ``h`` reads group ``h // (heads / groups)``: moving group 1's
+    B moves the second half of the heads and not the first."""
+    x, dt, a, b, c, d = _inputs(1, 1)
+    h0 = jnp.zeros((1, H, P, N), jnp.bfloat16)
+    _, h1 = ssm.ssm_state_update(h0, x[:, 0], dt[:, 0], a, b[:, 0],
+                                 c[:, 0], d)
+    assert h1.dtype == jnp.bfloat16
+    moved = b[:, 0].at[:, 1].add(1.0)
+    _, h2 = ssm.ssm_state_update(h0, x[:, 0], dt[:, 0], a, moved, c[:, 0],
+                                 d)
+    same = np.asarray(h1 == h2).reshape(H, -1).all(axis=1)
+    assert same[:H // G].all() and not same[H // G:].any()
+
+
+@pytest.mark.parametrize("length", [1, 2, 3, 10])
+def test_convolution_steps_equal_the_sequence_form(length):
+    k, ch = 4, 6
+    keys = jax.random.split(jax.random.PRNGKey(length), 3)
+    x = jax.random.normal(keys[0], (2, length, ch))
+    w = jax.random.normal(keys[1], (k, ch))
+    bias = jax.random.normal(keys[2], (ch,))
+    want = ssm.causal_conv(x, w, bias)
+    tail = jnp.zeros((2, k - 1, ch))
+    for t in range(length):
+        out, tail = ssm.conv_step(x[:, t], w, bias, tail)
+        np.testing.assert_allclose(out, want[:, t], rtol=1e-5, atol=1e-5)
+    # the tail a prefill leaves is the one the steps arrive at, cut at the
+    # true length whatever the pad holds
+    padded = jnp.concatenate([x, 9.0 * jnp.ones((2, 5, ch))], axis=1)
+    np.testing.assert_allclose(
+        ssm.conv_tail(padded, jnp.array([length, length]), k), tail)
