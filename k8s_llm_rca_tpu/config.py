@@ -46,12 +46,15 @@ class ModelConfig:
     # MoE (0 experts == dense Llama MLP)
     n_experts: int = 0
     n_experts_per_tok: int = 2
-    # route every weight-dequant GEMM (wq/wk/wv/wo, MLP, lm head, stacked
-    # experts) through the fused Pallas kernels (ops/quant_matmul.py) that
-    # stream PACKED int8/int4 tiles and dequantize in-register — on a real
-    # TPU backend with quantized unsharded-or-shard-local weights; every
-    # other case (plain arrays, CPU/interpret hosts, GSPMD-sharded
-    # consumption) falls back to the identical x @ dq(w) XLA path
+    # route the 2-D weight-dequant GEMMs (wq/wk/wv/wo, the dense MLP, the
+    # router, the lm head) through the Pallas kernels (ops/quant_matmul.py)
+    # that stream PACKED int8/int4 tiles and dequantize in-register — on a
+    # real TPU backend with quantized unsharded-or-shard-local weights;
+    # every other case (plain arrays, CPU/interpret hosts, GSPMD-sharded
+    # consumption) falls back to the identical x @ dq(w) XLA path.  Never
+    # timed on a chip and off in every benchmark configuration.  Since PR 35
+    # it selects nothing in the expert layer: the stacked experts' kernels
+    # are chosen from the call's shape (llama.moe_fused)
     fused_quant_matmul: bool = False
     # --- the layer table (empty = the Llama block in every layer) ---
     layer_pattern: str = ""

@@ -405,7 +405,8 @@ def _write_pool_rows(cfg: ModelConfig, pool: PagePool, li: int, page_ids,
 def paged_prefill(cfg: ModelConfig, params, pool: PagePool,
                   tokens: jnp.ndarray, length: jnp.ndarray,
                   page_map: jnp.ndarray, use_flash: bool = False,
-                  ep_mesh=None, flash_mesh=None, sp_mesh=None, slots=None):
+                  ep_mesh=None, flash_mesh=None, sp_mesh=None, slots=None,
+                  expert_kernel: bool = False):
     """Prefill ONE sequence, scattering its KV into ``page_map`` pages.
 
     tokens [1, S_pad] with S_pad a multiple of page_size; page_map
@@ -422,7 +423,7 @@ def paged_prefill(cfg: ModelConfig, params, pool: PagePool,
             page_map[None], slots, use_flash)
     new_k, new_v, logits = llama.prefill_kv(cfg, params, tokens, length,
                                             use_flash, ep_mesh, flash_mesh,
-                                            sp_mesh)
+                                            sp_mesh, expert_kernel)
     pool = _write_pool_pages(cfg, pool, new_k, new_v, page_map,
                              s_pad // page_size, page_size)
     return pool, logits
@@ -465,7 +466,7 @@ def paged_prefill_batch(cfg: ModelConfig, params, pool: PagePool,
                         tokens: jnp.ndarray, lengths: jnp.ndarray,
                         page_maps: jnp.ndarray, use_flash: bool = False,
                         ep_mesh=None, flash_mesh=None, sp_mesh=None,
-                        slots=None):
+                        slots=None, expert_kernel: bool = False):
     """Prefill N sequences into their pool pages in ONE dispatch.
 
     tokens [N, S_pad] right-padded (S_pad a page multiple); lengths [N];
@@ -488,7 +489,7 @@ def paged_prefill_batch(cfg: ModelConfig, params, pool: PagePool,
     new_k, new_v, logits = llama._prefill_batch_kv(cfg, params, tokens,
                                                    lengths, use_flash,
                                                    ep_mesh, flash_mesh,
-                                                   sp_mesh)
+                                                   sp_mesh, expert_kernel)
     # fold the batch dim into the page dim: the single-sequence write
     # helper scatters [L, total_pages, page, kv] by a flat page map
     pool = _write_pool_pages(
@@ -559,7 +560,8 @@ def paged_prefill_cp(cfg: ModelConfig, params, pool: PagePool,
 
 def _chunk_layer(cfg: ModelConfig, layer, x, angles, positions, mask,
                  k_pages, v_pages, k_scales, v_scales, prefix_table,
-                 dtype, packed: bool, ep_mesh=None, tp_axis=None):
+                 dtype, packed: bool, ep_mesh=None, tp_axis=None,
+                 expert_kernel: bool = False):
     """One transformer layer of chunked prefix prefill: gather + dequant
     the layer's cached prefix pages, attend chunk-over-(prefix + chunk)
     with the absolute-position mask, finish the block.  Returns
@@ -607,14 +609,16 @@ def _chunk_layer(cfg: ModelConfig, layer, x, angles, positions, mask,
     else:
         x = x + out
         hm = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-        x = x + llama._mlp(cfg, layer, hm, ep_mesh)
+        x = x + llama._mlp(cfg, layer, hm, ep_mesh,
+                           expert_kernel=expert_kernel)
     return x, k, v
 
 
 def paged_prefill_chunk(cfg: ModelConfig, params, pool: PagePool,
                         tokens: jnp.ndarray, chunk_len: jnp.ndarray,
                         prefix_len: jnp.ndarray, prefix_table: jnp.ndarray,
-                        page_map: jnp.ndarray, ep_mesh=None):
+                        page_map: jnp.ndarray, ep_mesh=None,
+                        expert_kernel: bool = False):
     """Prefill the non-cached SUFFIX of a prompt whose first ``prefix_len``
     tokens' KV already sit in pool pages (prefix-cache hit).
 
@@ -632,14 +636,16 @@ def paged_prefill_chunk(cfg: ModelConfig, params, pool: PagePool,
         cfg, params, pool, tokens,
         jnp.asarray(chunk_len, jnp.int32)[None],
         jnp.asarray(prefix_len, jnp.int32)[None],
-        prefix_table[None], page_map[None], ep_mesh=ep_mesh)
+        prefix_table[None], page_map[None], ep_mesh=ep_mesh,
+        expert_kernel=expert_kernel)
 
 
 def paged_prefill_chunk_batch(cfg: ModelConfig, params, pool: PagePool,
                               tokens: jnp.ndarray, chunk_lens: jnp.ndarray,
                               prefix_lens: jnp.ndarray,
                               prefix_tables: jnp.ndarray,
-                              page_maps: jnp.ndarray, ep_mesh=None):
+                              page_maps: jnp.ndarray, ep_mesh=None,
+                              expert_kernel: bool = False):
     """Chunked prefix prefill of N prefix-HIT suffixes in ONE dispatch.
 
     The per-sequence ``paged_prefill_chunk`` forced every cache hit to
@@ -691,7 +697,8 @@ def paged_prefill_chunk_batch(cfg: ModelConfig, params, pool: PagePool,
             pool.k[li], pool.v[li],
             pool.k_scale[li] if pool.quantized else None,
             pool.v_scale[li] if pool.quantized else None,
-            prefix_tables, dtype, packed, ep_mesh)
+            prefix_tables, dtype, packed, ep_mesh,
+            expert_kernel=expert_kernel)
         ks.append(k.reshape(n * c_pad, cfg.kv_dim))
         vs.append(v.reshape(n * c_pad, cfg.kv_dim))
 
@@ -710,7 +717,7 @@ def paged_decode_step(cfg: ModelConfig, params, pool: PagePool,
                       tokens: jnp.ndarray, lengths: jnp.ndarray,
                       block_tables: jnp.ndarray, *,
                       use_kernel: Optional[bool] = None, ep_mesh=None,
-                      tp_mesh=None):
+                      tp_mesh=None, expert_kernel: bool = False):
     """One decode step for all sequences over the paged pool.
 
     tokens [B]; lengths [B] tokens already cached; block_tables
@@ -810,7 +817,8 @@ def paged_decode_step(cfg: ModelConfig, params, pool: PagePool,
         if kind == "*":
             x = x + llama._w_mm(cfg, attn, layer["wo"])
         else:
-            x = llama._decode_finish(cfg, layer, x, attn, ep_mesh)
+            x = llama._decode_finish(cfg, layer, x, attn, ep_mesh,
+                                     expert_kernel)
         ai += 1
     if pool.moe_local_pairs is not None:
         pool = pool._replace(moe_local_pairs=pool.moe_local_pairs + n_local)
@@ -821,7 +829,8 @@ def paged_decode_step(cfg: ModelConfig, params, pool: PagePool,
 
 def paged_decode_multi(cfg: ModelConfig, params, pool: PagePool,
                        tokens: jnp.ndarray, lengths: jnp.ndarray,
-                       block_tables: jnp.ndarray, ep_mesh=None):
+                       block_tables: jnp.ndarray, ep_mesh=None,
+                       expert_kernel: bool = False):
     """Multi-token paged decode (speculative verification).
 
     tokens [B, T]: tokens[b, 0] is the current token, the rest drafts;
@@ -866,7 +875,8 @@ def paged_decode_multi(cfg: ModelConfig, params, pool: PagePool,
             block_tables, cfg.n_kv_heads, cfg.head_dim, dtype, packed)
         attn = decode_attention_multi(q, k_all, v_all, lengths + 1)
         x = llama._decode_finish(cfg, layer, x,
-                                 attn.reshape(b, t, cfg.q_dim), ep_mesh)
+                                 attn.reshape(b, t, cfg.q_dim), ep_mesh,
+                                 expert_kernel)
 
     logits = llama._logits(cfg, params, x)                       # [B, T, V]
     return pool, jnp.argmax(logits, axis=-1), logits
@@ -877,7 +887,8 @@ def paged_decode_scan(cfg: ModelConfig, params, pool: PagePool,
                       block_tables: jnp.ndarray, key, n_steps: int,
                       sampling: SamplingParams, eos_id: int,
                       use_kernel: Optional[bool] = None, ep_mesh=None,
-                      tp_mesh=None, decode_fn=None):
+                      tp_mesh=None, decode_fn=None,
+                      expert_kernel: bool = False):
     """``n_steps`` paged decode steps with zero host sync (the paged
     engine's chunked tick).  ``block_tables`` stays static for the whole
     scan; each per-step write indexes it dynamically (lengths // page),
@@ -898,7 +909,8 @@ def paged_decode_scan(cfg: ModelConfig, params, pool: PagePool,
                                              block_tables,
                                              use_kernel=use_kernel,
                                              ep_mesh=ep_mesh,
-                                             tp_mesh=tp_mesh)
+                                             tp_mesh=tp_mesh,
+                                             expert_kernel=expert_kernel)
         else:
             pool, logits = decode_fn(cfg, params, pool, cur, lens,
                                      block_tables)
@@ -926,7 +938,8 @@ def paged_decode_scan_dfa(cfg: ModelConfig, params, pool: PagePool,
                           dist_t: jnp.ndarray, close_t: jnp.ndarray,
                           complete_t: jnp.ndarray,
                           use_kernel: Optional[bool] = None, ep_mesh=None,
-                          tp_mesh=None, decode_fn=None):
+                          tp_mesh=None, decode_fn=None,
+                          expert_kernel: bool = False):
     """``paged_decode_scan`` with the compiled grammar DFA riding inside
     the scan (engine.dfa_scan_step: budget-aware mask, sample, state
     transition — all gathers on device).  Returns
@@ -941,7 +954,8 @@ def paged_decode_scan_dfa(cfg: ModelConfig, params, pool: PagePool,
                                              block_tables,
                                              use_kernel=use_kernel,
                                              ep_mesh=ep_mesh,
-                                             tp_mesh=tp_mesh)
+                                             tp_mesh=tp_mesh,
+                                             expert_kernel=expert_kernel)
         else:
             pool, logits = decode_fn(cfg, params, pool, cur, lens,
                                      block_tables)
@@ -962,7 +976,8 @@ def paged_overlap_step(cfg: ModelConfig, params, pool: PagePool,
                        block_tables: jnp.ndarray, key,
                        sampling: SamplingParams, cap: int,
                        use_kernel: Optional[bool] = None, ep_mesh=None,
-                       tp_mesh=None, decode_fn=None):
+                       tp_mesh=None, decode_fn=None,
+                       expert_kernel: bool = False):
     """One fused hot-loop step for the overlapped engine: decode +
     RNG split + sample + length advance in a single dispatch over the
     device-resident state (docs/performance.md).
@@ -980,7 +995,8 @@ def paged_overlap_step(cfg: ModelConfig, params, pool: PagePool,
         pool, logits = paged_decode_step(cfg, params, pool, cur_tokens,
                                          lengths, block_tables,
                                          use_kernel=use_kernel,
-                                         ep_mesh=ep_mesh, tp_mesh=tp_mesh)
+                                         ep_mesh=ep_mesh, tp_mesh=tp_mesh,
+                                         expert_kernel=expert_kernel)
     else:
         pool, logits = decode_fn(cfg, params, pool, cur_tokens, lengths,
                                  block_tables)
@@ -1133,6 +1149,15 @@ class PagedInferenceEngine(EngineBase):
                                   and jax.default_backend() == "tpu")
             if use_kernel:
                 self._kernel_mesh = tp_mesh
+        # the expert kernels (``llama._experts``' fused form) are plain
+        # pallas_calls: no partitioning rule, and no per-shard wrapper as
+        # the attention kernel has.  So only where no mesh of any kind is
+        # bound and every weight sits whole on one device; the model then
+        # chooses from the weights' type and the call's shape
+        self._expert_kernel = (
+            all(m is None for m in (tp_mesh, ep_mesh, cp_mesh, pp_mesh,
+                                    fsdp_mesh))
+            and not params_multi_device(params))
         if engine_cfg.host_overlap and cp_mesh is not None:
             raise ValueError(
                 "host_overlap=True is unsupported with cp_mesh: CP admits "
@@ -1589,7 +1614,8 @@ class PagedInferenceEngine(EngineBase):
             self._prefill = jax.jit(
                 profiling.named_partial(paged_prefill, use_flash=use_flash,
                                         ep_mesh=ep_mesh, flash_mesh=flash_mesh,
-                                        sp_mesh=tp_mesh if sp else None),
+                                        sp_mesh=tp_mesh if sp else None,
+                                        expert_kernel=self._expert_kernel),
                 static_argnums=0, donate_argnums=donate)
         if pp_mesh is None:
             if cp_mesh is not None:
@@ -1602,15 +1628,18 @@ class PagedInferenceEngine(EngineBase):
                 profiling.named_partial(paged_prefill_batch,
                                         use_flash=use_flash,
                                         ep_mesh=ep_mesh, flash_mesh=flash_mesh,
-                                        sp_mesh=tp_mesh if sp else None),
+                                        sp_mesh=tp_mesh if sp else None,
+                                        expert_kernel=self._expert_kernel),
                 static_argnums=0, donate_argnums=donate)
         if pp_mesh is None:
             self._prefill_chunk = jax.jit(
-                profiling.named_partial(paged_prefill_chunk, ep_mesh=ep_mesh),
+                profiling.named_partial(paged_prefill_chunk, ep_mesh=ep_mesh,
+                                        expert_kernel=self._expert_kernel),
                 static_argnums=0, donate_argnums=donate)
             self._prefill_chunk_batch = jax.jit(
                 profiling.named_partial(paged_prefill_chunk_batch,
-                                        ep_mesh=ep_mesh),
+                                        ep_mesh=ep_mesh,
+                                        expert_kernel=self._expert_kernel),
                 static_argnums=0, donate_argnums=donate)
         else:
             # PP's pipelined chunk prefill is per-sequence (GPipe m=1);
@@ -1619,7 +1648,8 @@ class PagedInferenceEngine(EngineBase):
         self._decode = jax.jit(
             pp_decode_fn if pp_decode_fn is not None
             else profiling.named_partial(paged_decode_step, ep_mesh=ep_mesh,
-                                         tp_mesh=self._kernel_mesh),
+                                         tp_mesh=self._kernel_mesh,
+                                         expert_kernel=self._expert_kernel),
             static_argnums=(0,),
             donate_argnums=donate, static_argnames=("use_kernel",))
         # fused overlapped step (paged_overlap_step): decode + key split
@@ -1630,24 +1660,28 @@ class PagedInferenceEngine(EngineBase):
         self._overlap_decode = jax.jit(
             profiling.named_partial(paged_overlap_step, ep_mesh=ep_mesh,
                                     tp_mesh=self._kernel_mesh,
-                                    decode_fn=pp_decode_fn),
+                                    decode_fn=pp_decode_fn,
+                                    expert_kernel=self._expert_kernel),
             static_argnums=(0, 7, 8),
             donate_argnums=donate, static_argnames=("use_kernel",))
         self._decode_scan = jax.jit(
             profiling.named_partial(paged_decode_scan, ep_mesh=ep_mesh,
                                     tp_mesh=self._kernel_mesh,
-                                    decode_fn=pp_decode_fn),
+                                    decode_fn=pp_decode_fn,
+                                    expert_kernel=self._expert_kernel),
             static_argnums=(0, 7, 8, 9),
             donate_argnums=donate, static_argnames=("use_kernel",))
         self._decode_scan_dfa = jax.jit(
             profiling.named_partial(paged_decode_scan_dfa, ep_mesh=ep_mesh,
                                     tp_mesh=self._kernel_mesh,
-                                    decode_fn=pp_decode_fn),
+                                    decode_fn=pp_decode_fn,
+                                    expert_kernel=self._expert_kernel),
             static_argnums=(0, 7, 8, 9),
             donate_argnums=donate, static_argnames=("use_kernel",))
         self._decode_multi = jax.jit(
             pp_decode_multi_fn if pp_decode_multi_fn is not None
-            else profiling.named_partial(paged_decode_multi, ep_mesh=ep_mesh),
+            else profiling.named_partial(paged_decode_multi, ep_mesh=ep_mesh,
+                                         expert_kernel=self._expert_kernel),
             static_argnums=0, donate_argnums=donate)
         from k8s_llm_rca_tpu.engine.engine import dfa_greedy_multi
         self._spec_dfa_greedy = jax.jit(dfa_greedy_multi, static_argnums=3)
@@ -1760,6 +1794,18 @@ class PagedInferenceEngine(EngineBase):
         self._count("engine.moe_routed_pairs",
                     n_positions * cfg.n_experts_per_tok
                     * cfg.layer_pattern.count("E"))
+
+    def _count_moe_fused(self, steps: int, per_step: int = 1) -> None:
+        """``engine.moe_fused_steps`` beside ``engine.decode_steps``: the
+        model steps of one decode dispatch whose expert MLPs read their
+        int4 experts packed.  The model's own predicate
+        (``llama.moe_fused``) for the positions of one call, every slot
+        times the ``per_step`` tokens a verifying step feeds, and the
+        engine's word that the kernels may stand in its programs."""
+        if (self._expert_kernel and self._moe_in_model and llama.moe_fused(
+                self.model_cfg, self.params["layers"][0],
+                self.engine_cfg.max_batch * per_step)):
+            self._count("engine.moe_fused_steps", steps)
 
     def _count_state_steps(self, steps: int) -> None:
         """One decode dispatch of ``steps`` model steps over every slot,
@@ -1956,6 +2002,7 @@ class PagedInferenceEngine(EngineBase):
         with profiling.annotate("engine.decode_step"):
             self._count("engine.dispatches")
             self._count_decode(1)
+            self._count_moe_fused(1)
             self._count_attn_pages(1, active_slots)
             self._count_state_steps(1)
             self.pool, logits = self._decode(
@@ -2007,6 +2054,7 @@ class PagedInferenceEngine(EngineBase):
         with profiling.annotate("engine.decode_step"):
             self._count("engine.dispatches")
             self._count_decode(1)
+            self._count_moe_fused(1)
             self._count_attn_pages(1, active_slots)
             self._count_state_steps(1)
             self.pool, nxt, new_lens, self._key = self._overlap_decode(
@@ -2210,6 +2258,7 @@ class PagedInferenceEngine(EngineBase):
         with profiling.annotate("engine.decode_step"):
             self._count("engine.dispatches")
             self._count_decode(tokens_in.shape[1])
+            self._count_moe_fused(tokens_in.shape[1], tokens_in.shape[1])
             self.pool, greedy, logits = self._decode_multi(
                 self.model_cfg, self.params, self.pool,
                 jnp.asarray(tokens_in), jnp.asarray(self.lengths, jnp.int32),
@@ -2254,6 +2303,7 @@ class PagedInferenceEngine(EngineBase):
         self._key, sub = jax.random.split(self._key)
         cur_d, lens_d, bt_d = self._device_state()
         self._count_decode(chunk)
+        self._count_moe_fused(chunk)
         self._count_attn_pages(chunk, active_slots)
         self._count_state_steps(chunk)
         if setup is None:

@@ -28,13 +28,13 @@ from jax.experimental.xla_metadata import set_xla_metadata
 
 from k8s_llm_rca_tpu.config import ModelConfig
 from k8s_llm_rca_tpu.models.quant import (
-    _pack_nibbles, _unpack_nibbles, dq, gather_rows,
+    QuantTensor4, _pack_nibbles, _unpack_nibbles, dq, gather_rows,
 )
 from k8s_llm_rca_tpu.ops.attention import (
     causal_attention, decode_attention, decode_attention_multi,
 )
 from k8s_llm_rca_tpu.ops.norms import rms_norm
-from k8s_llm_rca_tpu.ops.quant_matmul import qmm, qmm_experts, qmm_head
+from k8s_llm_rca_tpu.ops.quant_matmul import qmm, qmm_head, qmm_swiglu_experts
 from k8s_llm_rca_tpu.ops.rope import apply_rope, rope_frequencies
 
 Params = Dict[str, Any]
@@ -235,11 +235,13 @@ def _qkv(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
 
 
 def _mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
-         ep_mesh=None, ep_token_axis: str = "data") -> jnp.ndarray:
+         ep_mesh=None, ep_token_axis: str = "data",
+         expert_kernel: bool = False) -> jnp.ndarray:
     """``ep_mesh``: optional Mesh with an "expert" axis — the MoE block then
     dispatches through the all-to-all expert-parallel path
     (parallel/moe.expert_parallel_moe) instead of ``_moe_mlp`` (token-
-    grouped for a large call, dense soft dispatch for a small one).
+    grouped for a large call; for a small one fused from the packed int4
+    experts where ``expert_kernel`` allows it, else dense soft dispatch).
     Lossless capacity (capacity_factor = n_experts) so serving under EP
     computes the same function as ``_moe_mlp``; engines bind this at
     construction (BASELINE configs[3]: Mixtral expert-parallel serving).
@@ -255,7 +257,7 @@ def _mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
                 x, layer, ep_mesh, top_k=cfg.n_experts_per_tok,
                 capacity_factor=float(cfg.n_experts),
                 data_axis=ep_token_axis)
-        return _moe_mlp(cfg, layer, x)
+        return _moe_mlp(cfg, layer, x, expert_kernel)
     gate = jax.nn.silu(_w_mm(cfg, x, layer["w_gate"]))
     up = _w_mm(cfg, x, layer["w_up"])
     return _w_mm(cfg, gate * up, layer["w_down"])
@@ -315,14 +317,58 @@ def moe_grouped(cfg: ModelConfig, n_tokens: int) -> bool:
     grouping saves no arithmetic that matters, could skip no expert's
     dequantization (dead slots route too) and would put a sort into every
     step of a scan.  The rows an expert sees are the router's to say
-    (``T * k`` over the experts it scores, held here or not).
-    ``cfg.fused_quant_matmul`` keeps its own kernels."""
-    if cfg.n_experts <= 0 or cfg.fused_quant_matmul:
+    (``T * k`` over the experts it scores, held here or not).  Below the
+    threshold ``moe_fused`` chooses between the two small-call forms."""
+    if cfg.n_experts <= 0:
         return False
     rows_per_expert = n_tokens * cfg.n_experts_per_tok / cfg.n_router
     return rows_per_expert >= (MOE_GROUPED_MIN_ROWS_PER_EXPERT_LATENT
                                if cfg.moe_latent_size
                                else MOE_GROUPED_MIN_ROWS_PER_EXPERT)
+
+
+# Positions (T = B * S) up to which a call that is not token-grouped reads
+# its stacked int4 SwiGLU experts PACKED (ops/quant_matmul.py's
+# ``quant_matmul_ekn4_swiglu`` and ``quant_matmul_ekn4``: nibbles unpacked in
+# VMEM on the way to the MXU) instead of ``einsum(x, dq(w))``, which unpacks
+# all eight experts whole in HBM first.  Set from one layer's expert MLP at
+# Mixtral-8x7B widths with int4 experts on one TPU v5e (my chip run, PR 35;
+# ms a call, XLA dense form | fused form; the grouped form for comparison
+# from the table above, at XLA's tiles | _GROUPED_MATMUL_TILES):
+#      T     dense    fused
+#      8      5.24     1.10
+#     32      5.25     1.16      (a decode call: 705 MB packed in 1.16 ms)
+#     64      5.60     1.33
+#    128      5.91     2.20
+#    256      7.80     4.27
+#    512     12.06     8.40
+#   1024     20.06    16.64
+#   1536     28.23    24.91      (grouped: 25.35 | 23.14, and it is taken)
+#   2048     36.72    33.36      (grouped: 28.41 | 25.20)
+# The fused form is 4.5 times faster at a decode call (three quarters of the
+# HBM bandwidth on the packed bytes) and never slower than the dense form at
+# any size measured: from 256 positions both are bound by the MXU and the
+# fused form saves the unpack's pass over HBM, 3.4-3.7 ms.  So below the
+# grouped threshold the XLA dense form stays only for what is not int4
+# SwiGLU; the constant is the largest size measured, and matters to a router
+# whose grouped form starts later than Mixtral's 1,536 positions.
+MOE_FUSED_MAX_POSITIONS = 2048
+
+
+def moe_fused(cfg: ModelConfig, layer: Params, n_tokens: int) -> bool:
+    """Whether a call of ``n_tokens`` positions that ``moe_grouped`` leaves
+    to the small-call forms reads its experts packed: stacked int4 weights
+    (what the kernels read), a SwiGLU MLP (what they compute), and a call
+    no larger than the chip's table allows.  Seen from the weights' storage
+    type and the call's shape; whether a Pallas kernel may be called at all
+    (no mesh bound, the weights whole on one device) is the engine's to
+    say, where it binds its programs (``expert_kernel``)."""
+    return (cfg.n_experts > 0 and cfg.mlp_act == "swiglu"
+            and not cfg.moe_latent_size
+            and all(isinstance(layer.get(name), QuantTensor4)
+                    for name in ("w_gate", "w_up", "w_down"))
+            and not moe_grouped(cfg, n_tokens)
+            and n_tokens <= MOE_FUSED_MAX_POSITIONS)
 
 
 def _route(cfg: ModelConfig, layer: Params, x: jnp.ndarray):
@@ -363,31 +409,42 @@ def _held(cfg: ModelConfig, topi: jnp.ndarray):
     return jnp.where(held, local, cfg.n_experts), held
 
 
-def _moe_mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray) -> jnp.ndarray:
+def _moe_mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
+             expert_kernel: bool = False) -> jnp.ndarray:
     """Sparse-expert MLP: the router's k experts a token (``_route``),
     their MLPs, the weighted sum (``_experts``)."""
     topi, weights = _route(cfg, layer, x)
-    return _experts(cfg, layer, x, topi, weights)
+    return _experts(cfg, layer, x, topi, weights, expert_kernel)
 
 
 def _experts(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
-             topi: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
+             topi: jnp.ndarray, weights: jnp.ndarray,
+             expert_kernel: bool = False) -> jnp.ndarray:
     """The chosen experts' MLPs on x [B, S, W] and their weighted sum, for
     every model that has experts: SwiGLU (``w_gate``, ``w_up``, ``w_down``)
     or the non-gated squared ReLU (``w_up``, ``w_down``), all experts or
     the share held here (``cfg.expert_first``, ``cfg.n_experts`` of
     ``cfg.n_router``: a pair whose expert lives elsewhere adds nothing, and
-    nothing stands in for it).  Two forms of one function, chosen from the
-    call's shape by ``moe_grouped``:
+    nothing stands in for it).  Three forms of one function, chosen here
+    from what the call shows (``moe_grouped``, ``moe_fused``):
 
     - a large call (prefill, training) is **token-grouped**
       (``_moe_experts_grouped``): each token's row goes to its experts
       only, so the expert arithmetic is k/E of the dense form's;
     - a small call (decode) is **dense soft dispatch**: every expert held
       runs on every token and the router's weights zero out the rest — one
-      einsum per projection, no sort, exactly equal to hard routing.
+      einsum per projection, no sort, exactly equal to hard routing;
+    - a small call over stacked int4 SwiGLU experts is the dense form
+      **fused from the packed weights** (``qmm_swiglu_experts``): the same
+      sums, with the nibbles unpacked in VMEM on the way to the MXU and the
+      scale applied to the float32 output tile, where ``einsum(x, dq(w))``
+      unpacks and scales every expert whole in HBM first.  Only where
+      ``expert_kernel`` says a Pallas call may stand in the program: the
+      engine's word, given where it binds its programs (no mesh, the
+      weights whole on one device); every other caller (training, the
+      reference loops, the sharded paths) leaves it False.
 
-    Both are lossless (no capacity, no dropped pair).  The bandwidth-
+    All are lossless (no capacity, no dropped pair).  The bandwidth-
     optimal EP dispatch (all_to_all over the "expert" axis) lives in
     parallel/moe.py and is used by the sharded engine path.
     """
@@ -408,10 +465,9 @@ def _experts(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
         return jnp.einsum("bsei,eih->bsh",
                           hid * dense_w.astype(x.dtype)[..., None],
                           dq(layer["w_down"]))
-    if cfg.fused_quant_matmul:
-        gate = jax.nn.silu(qmm_experts(x, layer["w_gate"]))
-        up = qmm_experts(x, layer["w_up"])
-        per_expert = qmm_experts(gate * up, layer["w_down"])
+    if expert_kernel and moe_fused(cfg, layer, b * s):
+        per_expert = qmm_swiglu_experts(x, layer["w_gate"], layer["w_up"],
+                                        layer["w_down"])
     else:
         gate = jax.nn.silu(jnp.einsum("bsh,ehi->bsei", x, dq(layer["w_gate"])))
         up = jnp.einsum("bsh,ehi->bsei", x, dq(layer["w_up"]))
@@ -515,7 +571,8 @@ def _sp_constrain(x: jnp.ndarray, sp_mesh) -> jnp.ndarray:
 
 def _block_prefill(cfg, layer, x, angles, positions, seq_lens,
                    attention_fn=None, ep_mesh=None,
-                   ep_token_axis: str = "data", sp_mesh=None):
+                   ep_token_axis: str = "data", sp_mesh=None,
+                   expert_kernel: bool = False):
     """One transformer block over a full sequence.  ``attention_fn``
     defaults to masked causal attention (always safe: differentiable for
     training, GSPMD-partitionable for TP); inference prefill passes the
@@ -534,7 +591,7 @@ def _block_prefill(cfg, layer, x, angles, positions, seq_lens,
     x = x + _w_mm(cfg, attn.reshape(b, s, cfg.q_dim), layer["wo"])
     x = _sp_constrain(x, sp_mesh)
     h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-    x = x + _mlp(cfg, layer, h, ep_mesh, ep_token_axis)
+    x = x + _mlp(cfg, layer, h, ep_mesh, ep_token_axis, expert_kernel)
     return x, k, v
 
 
@@ -548,14 +605,15 @@ def _decode_qkv(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
 
 
 def _decode_finish(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
-                   attn: jnp.ndarray, ep_mesh=None) -> jnp.ndarray:
+                   attn: jnp.ndarray, ep_mesh=None,
+                   expert_kernel: bool = False) -> jnp.ndarray:
     """Decode-block back half: attention output projection + residual +
     MLP (shared across decode paths, see _decode_qkv).  ``attn`` must
     already be flattened to [B, T, q_dim] — kernel outputs vary in rank,
     so call sites own the reshape."""
     x = x + _w_mm(cfg, attn, layer["wo"])
     hm = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-    return x + _mlp(cfg, layer, hm, ep_mesh)
+    return x + _mlp(cfg, layer, hm, ep_mesh, expert_kernel=expert_kernel)
 
 
 def _quantize_kv(kv: jnp.ndarray, packed: bool = False,
@@ -672,7 +730,8 @@ def _flash_attention_fn(seq_lens, flash_mesh):
 
 def prefill_kv(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
                length: jnp.ndarray, use_flash: bool = False,
-               ep_mesh=None, flash_mesh=None, sp_mesh=None
+               ep_mesh=None, flash_mesh=None, sp_mesh=None,
+               expert_kernel: bool = False
                ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Shared prefill compute (the reference's slot write below, the
     engine's page scatter in engine/paged.py): run the stack over ONE
@@ -702,7 +761,8 @@ def prefill_kv(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     ks, vs = [], []
     for layer in params["layers"]:
         x, k, v = _block_prefill(cfg, layer, x, angles, positions, seq_lens,
-                                 attention_fn, ep_mesh, sp_mesh=sp_mesh)
+                                 attention_fn, ep_mesh, sp_mesh=sp_mesh,
+                                 expert_kernel=expert_kernel)
         ks.append(k[0])  # [S_pad, n_kv, d]
         vs.append(v[0])
 
@@ -952,7 +1012,8 @@ def prefill_kv_cp(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
 
 def _prefill_batch_kv(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
                       lengths: jnp.ndarray, use_flash: bool = False,
-                      ep_mesh=None, flash_mesh=None, sp_mesh=None
+                      ep_mesh=None, flash_mesh=None, sp_mesh=None,
+                      expert_kernel: bool = False
                       ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Batched prefill forward WITHOUT a cache write: tokens [N, S_pad]
     right-padded, lengths [N] -> (new_k [L, N, S_pad, kv_dim], new_v,
@@ -970,7 +1031,8 @@ def _prefill_batch_kv(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     ks, vs = [], []
     for layer in params["layers"]:
         x, k, v = _block_prefill(cfg, layer, x, angles, positions, lengths,
-                                 attention_fn, ep_mesh, sp_mesh=sp_mesh)
+                                 attention_fn, ep_mesh, sp_mesh=sp_mesh,
+                                 expert_kernel=expert_kernel)
         ks.append(k.reshape(n, s_pad, cfg.kv_dim))   # [N, S_pad, kv]
         vs.append(v.reshape(n, s_pad, cfg.kv_dim))
 

@@ -9,7 +9,9 @@ from k8s_llm_rca_tpu.ops.quant_matmul import (  # noqa: F401
     qmm,
     qmm_experts,
     qmm_head,
+    qmm_swiglu_experts,
     quant_matmul,
     quant_matmul_experts,
     quant_matmul_head,
+    quant_swiglu_experts,
 )

@@ -30,6 +30,7 @@ from k8s_llm_rca_tpu.ops.paged_attention import (
 )
 from k8s_llm_rca_tpu.ops.quant_matmul import (
     quant_matmul, quant_matmul_experts, quant_matmul_head,
+    quant_swiglu_experts,
 )
 from k8s_llm_rca_tpu.runtime import compile_cache, profiling
 
@@ -304,6 +305,80 @@ class TestPrefillRoutesEachTokenToItsExperts:
         assert f'ragged_dot_tiling="{tiling}"' in text
 
 
+class TestDecodeScanReadsItsExpertsPacked:
+    """The decode scan of ``mixtral-d8.audit-prefill`` as the cell's engine
+    binds it: 16 steps over 32 slots at Mixtral-8x7B widths (2 layers of
+    the cell's 8, int4 experts, the cell's int8 pool of 4,096 pages), no
+    mesh, so ``expert_kernel`` is on and the call's 32 positions choose the
+    fused form.  Until PR 35 every step of every layer unpacked all eight
+    experts' nibbles whole in HBM ahead of the einsums (``[8,4096,14336]``
+    and ``[8,14336,4096]`` in int8: 59% of a 43.9 ms step on the chip).
+    What a later edit must not bring back, seen here without a chip: the
+    program's expert MLPs are the two packed kernels at the tiles the chip
+    measured best, it holds no array of a whole dequantized expert stack,
+    and its temporaries stay far under the parent's at this shape (the
+    parent: 524.5 MB; this program: 69.3 MB)."""
+
+    SLOTS, STEPS, LAYERS, N_PAGES, PAGE = 32, 16, 2, 4096, 16
+    PARENT_TEMP_BYTES = 524.5e6
+
+    def test_no_expert_is_dequantized_whole(self, chip, monkeypatch):
+        import re
+
+        from k8s_llm_rca_tpu.engine import paged
+        from k8s_llm_rca_tpu.engine.sampling import SamplingParams
+        from k8s_llm_rca_tpu.models import llama
+        from k8s_llm_rca_tpu.models.quant import quantize_params
+        from k8s_llm_rca_tpu.ops.quant_matmul import _ekn4_tiles
+
+        cfg = MIXTRAL_8X7B.replace(n_layers=self.LAYERS, max_seq_len=4096,
+                                   dtype="bfloat16")
+        params = _described(chip, jax.eval_shape(lambda: quantize_params(
+            llama.init_params(cfg, jax.random.PRNGKey(0)), bits=4)))
+        pool = _described(chip, jax.eval_shape(
+            lambda: paged.init_paged_cache(cfg, self.N_PAGES, self.PAGE,
+                                           "int8")))
+        assert llama.moe_fused(cfg, params["layers"][0], self.SLOTS)
+        # the kernels' interpret=None asks the backend, which is the CPU
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = jax.jit(
+            paged.paged_decode_scan, static_argnums=(0, 7, 8, 9),
+            donate_argnums=2,
+            static_argnames=("use_kernel", "expert_kernel")).lower(
+                cfg, params, pool, chip((self.SLOTS,), I32),
+                chip((self.SLOTS,), I32),
+                chip((self.SLOTS, cfg.max_seq_len // self.PAGE), I32),
+                chip((2,), jnp.uint32), self.STEPS,
+                SamplingParams(temperature=0.0, top_k=0, top_p=1.0), 2,
+                use_kernel=True, expert_kernel=True).compile()
+        text = compiled.as_text()
+
+        e, h, inter = cfg.n_experts, cfg.hidden_size, cfg.intermediate_size
+        calls = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line and "quant_matmul_ekn4" in line]
+        # gate and up share a call, down is the other; the scan's body is
+        # compiled once
+        assert len(calls) == 2 * self.LAYERS
+        assert sum("quant_matmul_ekn4_swiglu" in line
+                   for line in calls) == self.LAYERS
+        # the stacked weights reach the kernels packed, which is also what
+        # the benchmark's expert_mlp_busy_share tells the expert MLP by
+        assert all(f"s8[{e},{h},{inter // 2}]" in line
+                   or f"s8[{e},{inter},{h // 2}]" in line for line in calls)
+        assert not re.search(rf"\[{e},{h},{inter}]|\[{e},{inter},{h}]", text)
+        # the tiles of a decode call, from the shapes alone
+        assert _ekn4_tiles(self.SLOTS, h, inter // 2) == GATE_UP_TILES
+        assert _ekn4_tiles(self.SLOTS, inter, h // 2) == DOWN_TILES
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < 0.25 * self.PARENT_TEMP_BYTES
+
+
+# (bm, bk, bnp, sub) of ops/quant_matmul.py's stacked int4 kernels at 32 rows
+# of Mixtral-8x7B: the packed tiles the chip measured best (PR 35)
+GATE_UP_TILES = (32, 4096, 1024, 512)
+DOWN_TILES = (32, 3584, 1024, 512)
+
+
 def _weight(chip, bits, shape, scale_shape):
     """A quantized weight of logical ``shape``: int4 packs the last dim."""
     if bits == 8:
@@ -340,6 +415,21 @@ class TestFusedDequantMatmulsCompileForV5e:
         _compiles_with_kernel(
             functools.partial(quant_matmul_experts, interpret=False),
             chip((1, 144, k), BF16), _weight(chip, 4, (e, k, n), (e, 1, n)))
+
+
+    @pytest.mark.parametrize("m", [2, 8, 32, 256, 1024, 1168])
+    def test_quant_swiglu_experts_int4(self, chip, m):
+        """The fused expert form at the positions the rule may give it
+        (up to ``llama.MOE_FUSED_MAX_POSITIONS``): a decode call of 2, 8 or
+        32 slots, the check's 1,024-position prefill, and a prefix-hit
+        chunk of 73 pages, whose 1,168 rows no tile divides."""
+        cfg = MIXTRAL_8X7B
+        e, k, n = cfg.n_experts, cfg.hidden_size, cfg.intermediate_size
+        _compiles_with_kernel(
+            functools.partial(quant_swiglu_experts, interpret=False),
+            chip((1, m, k), BF16), _weight(chip, 4, (e, k, n), (e, 1, n)),
+            _weight(chip, 4, (e, k, n), (e, 1, n)),
+            _weight(chip, 4, (e, n, k), (e, 1, k)))
 
 
 class _Device:

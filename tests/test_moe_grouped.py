@@ -21,7 +21,8 @@ from k8s_llm_rca_tpu.config import (
 )
 from k8s_llm_rca_tpu.engine import make_engine
 from k8s_llm_rca_tpu.models import llama
-from k8s_llm_rca_tpu.models.quant import quantize_params
+from k8s_llm_rca_tpu.models.quant import dq, quantize_params
+from k8s_llm_rca_tpu.ops.quant_matmul import quant_swiglu_experts
 from k8s_llm_rca_tpu.utils import get_tokenizer
 
 E = 8
@@ -168,13 +169,150 @@ def test_moe_grouped_follows_the_router_not_the_model():
         MIXTRAL_8X7B.replace(n_experts=64, n_experts_per_tok=16), 2048) is True
 
 
-@pytest.mark.parametrize("cfg", [
-    pytest.param(TINY, id="dense-model"),
-    pytest.param(MIXTRAL_8X7B.replace(fused_quant_matmul=True),
-                 id="fused-quant-matmul-keeps-its-kernels"),
+@pytest.mark.parametrize("cfg,grouped", [
+    pytest.param(TINY, False, id="dense-model"),
+    pytest.param(MIXTRAL_8X7B.replace(fused_quant_matmul=True), True,
+                 id="fused-quant-matmul-selects-nothing-here"),
 ])
-def test_moe_grouped_never_for(cfg):
-    assert not llama.moe_grouped(cfg, 4 * 4096)
+def test_moe_grouped_at_a_large_call(cfg, grouped):
+    """A dense model never; ``fused_quant_matmul`` has no say in the expert
+    layer any more (PR 35: the call's shape chooses all three forms)."""
+    assert llama.moe_grouped(cfg, 4 * 4096) is grouped
+
+
+# ---------------------------------------------------------------------------
+# the third form (PR 35): small calls over stacked int4 SwiGLU experts read
+# them packed
+# ---------------------------------------------------------------------------
+
+NEMOTRON_LIKE = TINY_MOE.replace(
+    n_experts=E, n_experts_per_tok=2, mlp_act="relu2", dtype="bfloat16")
+
+
+# 64 experts, 2 a token: grouped from 12,288 positions, so the fused form's
+# own threshold is the one that ends it
+MANY_EXPERTS = MIXTRAL_8X7B.replace(n_experts=64)
+
+
+def _fused_cases():
+    top = llama.MOE_FUSED_MAX_POSITIONS
+    return [
+        pytest.param(MIXTRAL_8X7B, 4, 32, True, id="int4-decode-call-32"),
+        pytest.param(MIXTRAL_8X7B, 4, 1024, True,
+                     id="int4-the-check-prefill-1024"),
+        pytest.param(MIXTRAL_8X7B, 4, 1535, True,
+                     id="int4-last-call-under-the-grouped-form"),
+        pytest.param(MIXTRAL_8X7B, 4, 1536, False,
+                     id="int4-grouped-call-1536"),
+        pytest.param(MIXTRAL_8X7B, 4, 4 * 4096, False,
+                     id="int4-grouped-call-4x4096"),
+        pytest.param(MANY_EXPERTS, 4, top, True, id="int4-at-the-threshold"),
+        pytest.param(MANY_EXPERTS, 4, top + 1, False,
+                     id="int4-one-position-over"),
+        pytest.param(MIXTRAL_8X7B.replace(fused_quant_matmul=True), 4, 32,
+                     True, id="int4-whatever-the-flag"),
+        pytest.param(MIXTRAL_8X7B, 8, 32, False, id="int8-experts"),
+        pytest.param(MIXTRAL_8X7B, 0, 32, False, id="bf16-experts"),
+        pytest.param(NEMOTRON_LIKE, 4, 32, False, id="relu2-experts"),
+        pytest.param(MIXTRAL_8X7B.replace(moe_latent_size=64), 4, 32, False,
+                     id="latent-experts"),
+        pytest.param(TINY, 4, 32, False, id="dense-model"),
+    ]
+
+
+@pytest.mark.parametrize("cfg,bits,n_tokens,fused", _fused_cases())
+def test_moe_fused_is_chosen_from_storage_and_positions(cfg, bits, n_tokens,
+                                                        fused):
+    """The rule sees the weights' storage type, the MLP's kind and the
+    call's positions, nothing else (no flag, no model's name): widths play
+    no part, so toy weights stand under the published configuration."""
+    small = (CFG if cfg.n_experts else TINY).replace(mlp_act=cfg.mlp_act)
+    layer = llama.init_params(small, jax.random.PRNGKey(7))["layers"][0]
+    if bits:
+        layer = quantize_params(layer, compute_dtype=jnp.bfloat16, bits=bits)
+    assert llama.moe_fused(cfg, layer, n_tokens) is fused
+
+
+def _interpreted(monkeypatch):
+    """Off the TPU the shim keeps the XLA expression; a test that wants the
+    kernels runs them in interpret mode, and counts the calls."""
+    calls = []
+
+    def shim(x, w_gate, w_up, w_down):
+        calls.append(x.shape)
+        return quant_swiglu_experts(x, w_gate, w_up, w_down, interpret=True)
+
+    monkeypatch.setattr(llama, "qmm_swiglu_experts", shim)
+    return calls
+
+
+@pytest.mark.parametrize("routing,n_tokens", [
+    pytest.param("even", 32, id="even-spread"),
+    pytest.param("one_expert", 32, id="one-expert-seven-idle"),
+    pytest.param("random", 24, id="random"),
+    pytest.param("tied", 16, id="tied-logits"),
+])
+def test_fused_equals_dense_and_hard_routing_inside_a_scan(monkeypatch,
+                                                           routing,
+                                                           n_tokens):
+    """``_experts`` through the packed kernels (interpret mode, toy widths,
+    int4), as the decode scan runs it: every step of a ``lax.scan`` equals
+    the XLA dense form within bf16 rounding, and equals hard routing (each
+    token through its chosen experts alone, from the dequantized weights)."""
+    cfg = CFG.replace(n_experts_per_tok=1) if routing == "one_expert" else CFG
+    layer = _layer(cfg, 4)
+    x = _tokens(cfg, n_tokens, routing)
+    steps = jnp.stack([x, x * 0.5, -x])             # [3, 1, T, H]
+    calls = _interpreted(monkeypatch)
+
+    def scanned(expert_kernel):
+        def body(carry, x):
+            return carry, llama._moe_mlp(cfg, layer, x, expert_kernel)
+        return jax.jit(lambda steps: jax.lax.scan(body, 0, steps)[1])(
+            steps).astype(jnp.float32)
+
+    fused, dense = scanned(True), scanned(False)
+    assert calls == [x.shape]                       # traced once, in the scan
+    assert float(jnp.max(jnp.abs(dense))) > 0
+    tol = 2e-2 * float(jnp.max(jnp.abs(dense)))
+    np.testing.assert_allclose(fused, dense, rtol=2e-2, atol=tol)
+
+    topi, weights = llama._route(cfg, layer, x)
+    w = {k: dq(layer[k]).astype(jnp.float32)
+         for k in ("w_gate", "w_up", "w_down")}
+    rows = x[0].astype(jnp.float32)
+    hard = np.zeros((n_tokens, cfg.hidden_size), np.float32)
+    for t in range(n_tokens):
+        for j in range(cfg.n_experts_per_tok):
+            e = int(topi[0, t, j])
+            hid = jax.nn.silu(rows[t] @ w["w_gate"][e]) * (
+                rows[t] @ w["w_up"][e])
+            hard[t] += float(weights[0, t, j]) * np.asarray(
+                hid @ w["w_down"][e])
+    np.testing.assert_allclose(fused[0, 0], hard, rtol=3e-2,
+                               atol=3e-2 * float(np.max(np.abs(hard))))
+
+
+def test_expert_kernel_off_keeps_the_dense_program(monkeypatch):
+    """Training, the reference loops and every sharded path leave
+    ``expert_kernel`` False: nothing of the fused form is traced, and the
+    program is the dense form's to the jaxpr."""
+    cfg, layer = CFG, _layer(CFG, 4)
+    x = _tokens(cfg, 32, "even")
+    calls = _interpreted(monkeypatch)
+
+    def jaxpr(*a):
+        return str(jax.make_jaxpr(
+            lambda layer, x: llama._moe_mlp(cfg, layer, x, *a))(layer, x))
+
+    assert jaxpr() == jaxpr(False) and not calls
+    assert "pallas_call" not in jaxpr()
+    assert "pallas_call" in jaxpr(True) and calls
+    # a differentiated call cannot reach the kernels: the loss goes through
+    # the default
+    jax.grad(lambda x: jnp.sum(llama._moe_mlp(cfg, _layer(cfg, 0), x)
+                               .astype(jnp.float32)))(x)
+    assert len(calls) == 1
 
 
 def test_dense_models_mlp_is_untouched(monkeypatch):
@@ -243,3 +381,104 @@ class TestEngineCountsGroupedPrefill:
         counts = self._run(monkeypatch, TINY.replace(max_seq_len=256), 128)
         assert counts["engine.prefill_padded_tokens"] == 128
         assert "engine.moe_grouped_tokens" not in counts
+
+
+def _int4_engine(cfg, bits=4, decode_chunk=4, **mesh_kw):
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    if bits:
+        params = quantize_params(params, compute_dtype=jnp.float32,
+                                 bits=bits)
+    tok = get_tokenizer(vocab_size=cfg.vocab_size)
+    eng = make_engine(
+        cfg, EngineConfig(max_batch=2, max_seq_len=64, page_size=8,
+                          num_pages=32, prefill_buckets=(16, 32),
+                          max_new_tokens=6, temperature=0.0,
+                          prefix_cache=False, decode_chunk=decode_chunk),
+        params, tok, use_kernel=False, **mesh_kw)
+    return eng, tok
+
+
+class TestEngineCountsFusedSteps:
+    """``engine.moe_fused_steps`` beside ``engine.decode_steps``: the model
+    steps whose expert MLPs read their int4 experts packed, counted on the
+    host from the model's own predicate and the engine's word on meshes.
+    (On the CPU the shim computes the same form in XLA; the count says
+    which form the program asked for.)"""
+
+    def _counts(self, cfg, **kw):
+        eng, tok = _int4_engine(cfg.replace(max_seq_len=64), **kw)
+        eng.generate([tok.encode("pod oom killed", add_bos=True)],
+                     max_new_tokens=6)
+        assert eng._counts["engine.decode_steps"] >= 5
+        return eng._counts
+
+    @pytest.mark.parametrize("decode_chunk", [1, 4],
+                             ids=["stepwise", "scan"])
+    def test_int4_sparse_engine_counts_every_step(self, decode_chunk):
+        counts = self._counts(TINY_MOE, decode_chunk=decode_chunk)
+        assert (counts["engine.moe_fused_steps"]
+                == counts["engine.decode_steps"])
+
+    @pytest.mark.parametrize("cfg,bits", [
+        pytest.param(TINY, 4, id="dense-int4"),
+        pytest.param(TINY_MOE, 8, id="sparse-int8"),
+        pytest.param(TINY_MOE, 0, id="sparse-plain"),
+    ])
+    def test_absent_for(self, cfg, bits):
+        assert "engine.moe_fused_steps" not in self._counts(cfg, bits=bits)
+
+    def test_over_the_threshold_counts_nothing(self, monkeypatch):
+        monkeypatch.setattr(llama, "MOE_FUSED_MAX_POSITIONS", 1)
+        assert "engine.moe_fused_steps" not in self._counts(TINY_MOE)
+
+
+class TestNoExpertKernelUnderAMesh:
+    """``pallas_call`` has no partitioning rule: an engine with any mesh,
+    or with weights spread over devices, keeps the kernels out of every
+    program it binds, whatever the weights' type."""
+
+    def test_one_device_lets_them_in(self):
+        eng, _ = _int4_engine(TINY_MOE.replace(max_seq_len=64))
+        assert eng._expert_kernel is True
+        assert eng._decode_scan.__wrapped__.keywords["expert_kernel"] is True
+
+    @pytest.mark.parametrize("kind", ["tp", "ep", "cp", "pp", "fsdp",
+                                      "sharded-weights-no-mesh"])
+    def test_never_with(self, cpu_devices, kind):
+        from k8s_llm_rca_tpu.config import MeshConfig
+        from k8s_llm_rca_tpu.runtime.mesh import build_mesh
+        from k8s_llm_rca_tpu.runtime.sharding import (
+            llama_param_specs, shard_pytree,
+        )
+
+        cfg = TINY_MOE.replace(max_seq_len=64)
+        params = quantize_params(
+            llama.init_params(cfg, jax.random.PRNGKey(0)),
+            compute_dtype=jnp.float32, bits=4)
+        tok = get_tokenizer(vocab_size=cfg.vocab_size)
+        ecfg = EngineConfig(max_batch=4, max_seq_len=64, page_size=8,
+                            num_pages=32, prefill_buckets=(16, 32, 64),
+                            max_new_tokens=6, temperature=0.0,
+                            prefix_cache=False)
+        kw = {}
+        if kind in ("tp", "fsdp", "sharded-weights-no-mesh"):
+            mesh = build_mesh(MeshConfig(data=2, model=2),
+                              devices=cpu_devices[:4])
+            params = shard_pytree(params, llama_param_specs(cfg), mesh)
+            if kind != "sharded-weights-no-mesh":
+                kw = {f"{kind}_mesh": mesh}
+        elif kind == "ep":
+            mesh = build_mesh(MeshConfig(data=2, expert=2, model=2),
+                              devices=cpu_devices[:8])
+            params = shard_pytree(params, llama_param_specs(cfg), mesh)
+            kw = {"ep_mesh": mesh}
+        elif kind == "cp":
+            kw = {"cp_mesh": build_mesh(MeshConfig(seq=2),
+                                        devices=cpu_devices[:2])}
+        else:
+            kw = {"pp_mesh": build_mesh(MeshConfig(stage=2),
+                                        devices=cpu_devices[:2])}
+        eng = make_engine(cfg, ecfg, params, tok, use_kernel=False, **kw)
+        assert eng._expert_kernel is False
+        assert eng._decode_scan.__wrapped__.keywords["expert_kernel"] is False
+        assert "engine.moe_fused_steps" not in (eng._counts or {})

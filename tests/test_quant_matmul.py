@@ -28,8 +28,8 @@ from k8s_llm_rca_tpu.models.quant import (
     dq, quantize, quantize_params, repack_nibbles_grouped,
 )
 from k8s_llm_rca_tpu.ops.quant_matmul import (
-    qmm, qmm_experts, qmm_head, quant_matmul, quant_matmul_experts,
-    quant_matmul_head,
+    qmm, qmm_experts, qmm_head, qmm_swiglu_experts, quant_matmul,
+    quant_matmul_experts, quant_matmul_head, quant_swiglu_experts,
 )
 
 pytestmark = pytest.mark.kernels
@@ -102,6 +102,55 @@ class TestKernelParity:
         _close(quant_matmul_experts(x, w),
                jnp.einsum("bsei,eih->bseh", x, dq(w)))
 
+    @staticmethod
+    def _swiglu_ref(x, wg, wu, wd):
+        gate = jax.nn.silu(jnp.einsum("bsh,ehi->bsei", x, dq(wg)))
+        up = jnp.einsum("bsh,ehi->bsei", x, dq(wu))
+        return jnp.einsum("bsei,eih->bseh", gate * up, dq(wd))
+
+    @pytest.mark.parametrize("tiles", ["one-tile", "many-tiles"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_swiglu_experts(self, dtype, tiles, monkeypatch):
+        # the model's fused expert form: gate and up in one call, the
+        # product on the float32 sums, down from the result as it lies.
+        # "many-tiles" shrinks the tile targets so that the grid has
+        # several M, N and K steps and a tile several sub-tiles
+        import importlib
+        mod = importlib.import_module("k8s_llm_rca_tpu.ops.quant_matmul")
+        if tiles == "many-tiles":
+            monkeypatch.setattr(mod, "_EKN4_BK", 256)
+            monkeypatch.setattr(mod, "_EKN4_TILE_BYTES", 128 * 128)
+            monkeypatch.setattr(mod, "_EKN4_SUB", 64)
+            assert mod._ekn4_tiles(self.M, self.K, self.N // 2) == (
+                160, 160, 96, 40)
+        e = 3
+        x = _rand(12, (2, self.M // 2, self.K), dtype) * 0.25
+        wg, wu, wd = (
+            quantize(_rand(13 + i, shape) * 0.1, axis=(0, -1), bits=4,
+                     compute_dtype=dtype)
+            for i, shape in enumerate([(e, self.K, self.N)] * 2
+                                      + [(e, self.N, self.K)]))
+        _close(quant_swiglu_experts(x, wg, wu, wd),
+               self._swiglu_ref(x, wg, wu, wd), dtype)
+
+    @pytest.mark.parametrize("rows", [6, 272, 530])
+    def test_swiglu_experts_any_row_count(self, rows):
+        # up to 256 rows are one tile whatever their number; more are cut
+        # into equal tiles of a multiple of 16 and the last one padded
+        # (272 -> 2 x 144, 530 -> 3 x 192), and the padding never shows
+        import importlib
+        mod = importlib.import_module("k8s_llm_rca_tpu.ops.quant_matmul")
+        bm = mod._ekn4_tiles(rows, 128, 64)[0]
+        assert bm == {6: 6, 272: 144, 530: 192}[rows]
+        e = 2
+        x = _rand(30, (1, rows, 128)) * 0.25
+        wg, wu, wd = (
+            quantize(_rand(31 + i, shape) * 0.1, axis=(0, -1), bits=4)
+            for i, shape in enumerate([(e, 128, 256)] * 2 + [(e, 256, 128)]))
+        got = quant_swiglu_experts(x, wg, wu, wd)
+        assert got.shape == (1, rows, e, 128)
+        _close(got, self._swiglu_ref(x, wg, wu, wd))
+
     @pytest.mark.parametrize("bits", [8, 4])
     def test_decode_row_shapes(self, bits):
         # the decode hot shape: M=1 token row (single-block M)
@@ -134,6 +183,33 @@ class TestShimsAndExclusions:
         we = quantize(_rand(6, (3, 8, 6)), axis=(0, -1), bits=8)
         assert jnp.array_equal(
             qmm_experts(x, we), jnp.einsum("bsh,ehi->bsei", x, dq(we)))
+
+    def test_qmm_swiglu_experts_falls_back_byte_identical(self):
+        # off the TPU the fused form's shim IS the dense form's expression
+        x = _rand(20, (1, 4, 32))
+        wg, wu = (quantize(_rand(21 + i, (3, 32, 16)), axis=(0, -1), bits=4)
+                  for i in range(2))
+        wd = quantize(_rand(23, (3, 16, 32)), axis=(0, -1), bits=4)
+        np.testing.assert_array_equal(
+            qmm_swiglu_experts(x, wg, wu, wd),
+            TestKernelParity._swiglu_ref(x, wg, wu, wd))
+
+    @pytest.mark.parametrize("bad", ["int8", "plain", "2-D", "shape"])
+    def test_swiglu_experts_rejects(self, bad):
+        x = _rand(24, (1, 4, 32))
+        w = lambda k, shape, bits=4: quantize(_rand(k, shape),
+                                              axis=(0, -1), bits=bits)
+        wg, wu, wd = w(25, (3, 32, 16)), w(26, (3, 32, 16)), w(27, (3, 16, 32))
+        if bad == "int8":
+            wu = w(26, (3, 32, 16), bits=8)
+        elif bad == "plain":
+            wd = _rand(27, (3, 16, 32))
+        elif bad == "2-D":
+            wg = quantize(_rand(25, (32, 16)), axis=-1, bits=4)
+        else:
+            wd = w(27, (3, 16, 64))
+        with pytest.raises(ValueError, match="QuantTensor|int4|mismatch"):
+            quant_swiglu_experts(x, wg, wu, wd)
 
     def test_quant_matmul_rejects_plain_array(self):
         with pytest.raises(ValueError, match="QuantTensor"):
