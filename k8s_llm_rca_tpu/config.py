@@ -20,14 +20,26 @@ class ModelConfig:
 
     Two families share it.  With ``layer_pattern`` empty every layer is
     the Llama block (attention then MLP, two norms, two residuals;
-    Mixtral via ``n_experts > 0``), computed by models/llama.py.  With a
-    pattern (``nemotron_h``, models/nemotron_h.py) a layer is ONE mixer or
-    ONE feed-forward part under one norm and one residual, its kind the
-    pattern's letter: ``M`` a Mamba-2 mixer (the ``ssm_*`` fields), ``E``
-    an expert layer (router kind, latent and shared widths, the experts
-    held here), ``*`` attention.  The engine derives what it holds per
-    slot (pages for the attention layers, a recurrent state for the
-    Mamba layers) from this table and from nothing else.
+    Mixtral via ``n_experts > 0``), computed by models/llama.py.  The
+    block's layers need not be alike: ``attn_layer_types`` gives each
+    layer's attention its kind, ``full_attention`` (position ``i`` sees
+    ``0 .. i``; keys and values of every token, in pages) or
+    ``sliding_attention`` (``i`` sees the last ``attn_window``
+    positions up to itself; the last window of keys and values, in a ring
+    of pages per decode slot); ``n_dense_layers`` leading layers have the
+    dense MLP at ``intermediate_size`` and the rest the experts;
+    ``qk_norm`` puts an RMSNorm with a learned gain over every query and
+    key head before the rotary embedding, and ``rope_full_layers`` False
+    leaves the rotary embedding to the window layers (``layer_cfg`` is
+    the block's view of one layer).  With a pattern (``nemotron_h``,
+    models/nemotron_h.py) a layer is ONE mixer or ONE feed-forward part
+    under one norm and one residual, its kind the pattern's letter: ``M``
+    a Mamba-2 mixer (the ``ssm_*`` fields), ``E`` an expert layer (router
+    kind, latent and shared widths, the experts held here), ``*``
+    attention.  The engine derives what it holds per
+    slot (pages for the attention layers, a ring for the window layers,
+    a recurrent state for the Mamba layers) from these tables and from
+    nothing else.
     """
 
     name: str = "tiny"
@@ -56,6 +68,17 @@ class ModelConfig:
     # it selects nothing in the expert layer: the stacked experts' kernels
     # are chosen from the call's shape (llama.moe_fused)
     fused_quant_matmul: bool = False
+    # --- the Llama block's layers by kind (empty / 0 = every layer alike:
+    # full attention, rotary embedding, the one MLP) ---
+    attn_layer_types: Tuple[str, ...] = ()   # a layer: "full_attention" |
+                                             # "sliding_attention"
+    attn_window: int = 0               # positions a sliding layer sees,
+                                       # the query's own included
+    n_dense_layers: int = 0            # leading layers with the dense MLP
+                                       # (the rest route to the experts)
+    qk_norm: bool = False              # RMSNorm + gain over each q, k head
+    rope_full_layers: bool = True      # False: rotary embedding on the
+                                       # sliding layers only
     # --- the layer table (empty = the Llama block in every layer) ---
     layer_pattern: str = ""
     use_rope: bool = True              # nemotron_h's attention applies none
@@ -110,6 +133,31 @@ class ModelConfig:
                     f"layer_pattern {self.layer_pattern!r}: unknown layer "
                     f"kind {sorted(unknown)[0]!r} (M Mamba-2, E experts, "
                     f"* attention)")
+        if self.attn_layer_types:
+            if self.layer_pattern:
+                raise ValueError(
+                    "attn_layer_types is the Llama block's: a model with "
+                    "a layer_pattern has its attention kind in the pattern")
+            if len(self.attn_layer_types) != self.n_layers:
+                raise ValueError(
+                    f"attn_layer_types has {len(self.attn_layer_types)} "
+                    f"entries for n_layers={self.n_layers}")
+            unknown = set(self.attn_layer_types) - {"full_attention",
+                                                    "sliding_attention"}
+            if unknown:
+                raise ValueError(
+                    f"attn_layer_types: unknown attention kind "
+                    f"{sorted(unknown)[0]!r} (full_attention, "
+                    f"sliding_attention)")
+            if self.n_window_layers and self.attn_window <= 0:
+                raise ValueError(
+                    f"{self.n_window_layers} sliding_attention layers and "
+                    f"attn_window={self.attn_window}")
+        if not 0 <= self.n_dense_layers <= self.n_layers or (
+                self.n_dense_layers and self.layer_pattern):
+            raise ValueError(
+                f"n_dense_layers={self.n_dense_layers} of the Llama block's "
+                f"{self.n_layers} layers")
         if self.n_experts and not (
                 0 <= self.expert_first
                 and self.expert_first + self.n_experts <= self.n_router):
@@ -128,10 +176,51 @@ class ModelConfig:
         return self.moe_intermediate_size or self.intermediate_size
 
     @property
+    def attn_windows(self) -> Tuple[int, ...]:
+        """Each Llama-block layer's window: 0 where position ``i`` sees
+        ``0 .. i``, else how many positions up to ``i`` it sees."""
+        if not self.attn_layer_types:
+            return (0,) * self.n_layers
+        return tuple(self.attn_window if t == "sliding_attention" else 0
+                     for t in self.attn_layer_types)
+
+    @property
+    def n_window_layers(self) -> int:
+        """Layers that keep the last window of keys and values per slot."""
+        return self.attn_layer_types.count("sliding_attention")
+
+    @property
     def n_kv_layers(self) -> int:
-        """Layers that cache keys and values: the pool's layer axis."""
+        """Layers that cache every token's keys and values in pages: the
+        pool's layer axis."""
         return (self.layer_pattern.count("*") if self.layer_pattern
-                else self.n_layers)
+                else self.n_layers - self.n_window_layers)
+
+    @property
+    def mixed_layers(self) -> bool:
+        """The Llama block's layers differ in kind (window beside full
+        attention, rotary embedding by kind, a leading dense MLP): its
+        programs read ``layer_cfg`` layer by layer.  What needs more than
+        that is the window alone (``n_window_layers``: a ring per slot, a
+        prefill whose rows run one after another, ``llama.prefill_rows``)."""
+        return bool(self.attn_layer_types or self.n_dense_layers)
+
+    def ring_pages(self, page_size: int) -> int:
+        """Pages of a slot's ring in a window layer: the pages the last
+        ``attn_window`` positions can lie across."""
+        return -(-self.attn_window // page_size) + 1
+
+    def layer_cfg(self, li: int) -> "ModelConfig":
+        """The Llama block's view of layer ``li``: this config where the
+        layers are alike, else with that layer's rotary embedding (none
+        on a full layer where ``rope_full_layers`` is False) and MLP (no
+        experts in a leading dense layer)."""
+        if not self.mixed_layers:
+            return self
+        return self.replace(
+            use_rope=self.use_rope and (self.rope_full_layers
+                                        or self.attn_windows[li] > 0),
+            n_experts=0 if li < self.n_dense_layers else self.n_experts)
 
     @property
     def n_ssm_layers(self) -> int:
@@ -183,6 +272,21 @@ TINY_NEMOTRON_H = ModelConfig(
     router_kind="sigmoid", routed_scaling=2.5, moe_latent_size=64,
     moe_intermediate_size=96, shared_expert_size=192, mlp_act="relu2")
 
+# every per-layer kind of the Llama block at toy widths (exaone_moe): a
+# leading dense layer, three window layers to one full (window 8, rotary
+# embedding on the window layers only), query/key norms, a sigmoid router
+# over 16 experts of which 8 are held from the 4th, top-4, a SwiGLU shared
+# expert
+TINY_EXAONE_MOE = ModelConfig(
+    name="tiny_exaone_moe", n_layers=5,
+    attn_layer_types=("sliding_attention",) * 3 + ("full_attention",
+                                                   "sliding_attention"),
+    attn_window=8, n_dense_layers=1, qk_norm=True,
+    rope_full_layers=False, tie_embeddings=False,
+    n_experts=8, router_width=16, expert_first=4, n_experts_per_tok=4,
+    router_kind="sigmoid", routed_scaling=2.5, moe_intermediate_size=96,
+    shared_expert_size=96)
+
 TINYLLAMA_1B = ModelConfig(
     name="tinyllama-1.1b",
     vocab_size=32000,
@@ -231,8 +335,8 @@ MIXTRAL_8X7B = ModelConfig(
 )
 
 MODEL_REGISTRY = {
-    c.name: c for c in (TINY, TINY_MOE, TINY_NEMOTRON_H, TINYLLAMA_1B,
-                        LLAMA3_8B, MIXTRAL_8X7B)
+    c.name: c for c in (TINY, TINY_MOE, TINY_NEMOTRON_H, TINY_EXAONE_MOE,
+                        TINYLLAMA_1B, LLAMA3_8B, MIXTRAL_8X7B)
 }
 
 

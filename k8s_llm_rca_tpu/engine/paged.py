@@ -280,6 +280,18 @@ class PagePool(NamedTuple):
     ride in the pool because the pool is what every program is given,
     donates and hands back: the state is updated in place on the device
     and never crosses to the host in a tick.
+
+    A Llama-family model with sliding-window layers
+    (``cfg.n_window_layers``) keeps those layers' keys and values in
+    ``ring``, a pool of the same kind and precision with the window layers
+    on its layer axis and ``max_batch * cfg.ring_pages(page_size)`` pages:
+    slot ``s`` owns pages ``s * R .. s * R + R - 1`` for good, position
+    ``p`` of its sequence lies in page ``(p // page_size) % R`` of them,
+    and a write ``R`` pages on lands on the page that has left the window.
+    What a window layer holds is so bounded per slot, draws on no
+    allocator, and only the full layers' pages are budgeted by
+    ``num_pages``; the decode kernel reads a slot's ring through a table
+    laid out from the window's first page (``_ring_view``).
     """
 
     k: jnp.ndarray
@@ -289,6 +301,7 @@ class PagePool(NamedTuple):
     ssm_state: Optional[jnp.ndarray] = None
     conv_state: Optional[jnp.ndarray] = None
     moe_local_pairs: Optional[jnp.ndarray] = None
+    ring: Optional["PagePool"] = None
 
     @property
     def page_size(self) -> int:
@@ -302,7 +315,24 @@ class PagePool(NamedTuple):
 def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
                      kv_dtype=None, n_slots: int = 0) -> PagePool:
     """``n_slots``: the decode slots a model with Mamba-2 layers keeps a
-    recurrent state for (``EngineConfig.max_batch``)."""
+    recurrent state for, and one with window layers a ring
+    (``EngineConfig.max_batch``)."""
+    if cfg.n_window_layers:
+        if n_slots <= 0:
+            raise ValueError(
+                f"{cfg.name}: its {cfg.n_window_layers} sliding-window "
+                f"layers keep a ring of pages per decode slot: "
+                f"init_paged_cache needs n_slots")
+        alike = dict(attn_layer_types=(), n_dense_layers=0)
+        pages = init_paged_cache(
+            cfg.replace(n_layers=cfg.n_kv_layers, **alike), n_pages,
+            page_size, kv_dtype)
+        return pages._replace(
+            ring=init_paged_cache(
+                cfg.replace(n_layers=cfg.n_window_layers, **alike),
+                n_slots * cfg.ring_pages(page_size), page_size, kv_dtype),
+            moe_local_pairs=(jnp.zeros((1,), jnp.int32) if cfg.n_experts
+                             else None))
     if cfg.n_ssm_layers:
         if n_slots <= 0:
             raise ValueError(
@@ -402,6 +432,93 @@ def _write_pool_rows(cfg: ModelConfig, pool: PagePool, li: int, page_ids,
                          k_scale=k_scale, v_scale=v_scale)
 
 
+def _ring_view(cfg: ModelConfig, lengths: jnp.ndarray, page_size: int):
+    """How a decode step reads and writes the slots' rings (``PagePool``):
+    ``lengths`` [B] tokens already cached, slot ``b`` being row ``b``.
+    The step's token, position ``lengths[b]``, is written at ring page
+    ``write_pages[b]``, offset ``lengths[b] % page_size``; its query sees
+    the last ``cfg.attn_window`` positions up to itself, which the
+    kernel reads through ``tables`` [B, R]: the slot's R ring pages in
+    position order from the page that holds the window's first position,
+    with ``rel_lengths`` [B] positions live in it of which the first
+    ``starts`` [B] lie before the window."""
+    r = cfg.ring_pages(page_size)
+    base = jnp.arange(lengths.shape[0], dtype=lengths.dtype) * r
+    first = jnp.maximum(lengths + 1 - cfg.attn_window, 0)
+    first_page = first // page_size
+    tables = base[:, None] + (first_page[:, None]
+                              + jnp.arange(r, dtype=lengths.dtype)) % r
+    return (base + (lengths // page_size) % r, tables,
+            lengths + 1 - first_page * page_size,
+            first - first_page * page_size)
+
+
+def _ring_tail(cfg: ModelConfig, lengths: jnp.ndarray, slots: jnp.ndarray,
+               s_pad: int, page_size: int):
+    """What a prefill leaves in the rings: of each right-padded row the
+    last pages a ring holds (``tail`` positions from ``tail_starts`` [N],
+    whole pages ending with the row's last true position's), and the ring
+    pages of slot ``slots[row]`` they go to, ``page_map`` [N, tail //
+    page_size]."""
+    r = cfg.ring_pages(page_size)
+    n_tail = min(r, s_pad // page_size)
+    first_page = jnp.clip((lengths - 1) // page_size - n_tail + 1, 0,
+                          s_pad // page_size - n_tail)
+    page_map = slots[:, None] * r + (
+        first_page[:, None] + jnp.arange(n_tail, dtype=lengths.dtype)) % r
+    return first_page * page_size, n_tail * page_size, page_map
+
+
+def _prefill_rows_per_slot(cfg: ModelConfig, params, pool: PagePool,
+                           tokens, lengths, page_maps, slots,
+                           use_flash: bool, expert_kernel: bool):
+    """``paged_prefill_batch`` for a model that keeps something per decode
+    slot beside the pages, its rows run one after another by its family's
+    ``prefill_rows``: keys and values of the layers that cache every token
+    into the rows' pages, then what each row's slot keeps, over whatever
+    it held (a model with Mamba-2 layers: the recurrent state left at the
+    row's true length and the convolution's tail; one with sliding-window
+    layers: the row's tail into the slot's ring), and the local expert
+    pairs onto the pool's count."""
+    if slots is None:
+        kept = ("Mamba-2 layers needs the decode slot of each row (slots=) "
+                "to write its state to" if cfg.layer_pattern else
+                "sliding-window layers needs the decode slot of each row "
+                "(slots=) whose ring it writes")
+        raise ValueError(f"{cfg.name}: a prefill of a model with {kept}")
+    n, s_pad = tokens.shape
+    page_size = pool.page_size
+    if cfg.layer_pattern:
+        new_k, new_v, state, conv_tail, logits, n_local = \
+            nemotron_h.prefill_rows(cfg, params, tokens, lengths, use_flash)
+    else:
+        lengths = lengths.astype(jnp.int32)
+        tail_starts, tail, ring_map = _ring_tail(
+            cfg, lengths, slots.astype(jnp.int32), s_pad, page_size)
+        new_k, new_v, ring_k, ring_v, logits, n_local = llama.prefill_rows(
+            cfg, params, tokens, lengths, tail_starts, tail, use_flash,
+            expert_kernel)
+    pool = _write_pool_pages(
+        cfg, pool, new_k.reshape(cfg.n_kv_layers, n * s_pad, cfg.kv_dim),
+        new_v.reshape(cfg.n_kv_layers, n * s_pad, cfg.kv_dim),
+        page_maps.reshape(-1), n * (s_pad // page_size), page_size)
+    if cfg.layer_pattern:
+        return pool._replace(
+            ssm_state=pool.ssm_state.at[:, slots].set(
+                state.astype(pool.ssm_state.dtype)),
+            conv_state=pool.conv_state.at[:, slots].set(
+                conv_tail.astype(pool.conv_state.dtype)),
+            moe_local_pairs=pool.moe_local_pairs + n_local), logits
+    pool = pool._replace(ring=_write_pool_pages(
+        cfg, pool.ring,
+        ring_k.reshape(cfg.n_window_layers, n * tail, cfg.kv_dim),
+        ring_v.reshape(cfg.n_window_layers, n * tail, cfg.kv_dim),
+        ring_map.reshape(-1), n * (tail // page_size), page_size))
+    if pool.moe_local_pairs is not None:
+        pool = pool._replace(moe_local_pairs=pool.moe_local_pairs + n_local)
+    return pool, logits
+
+
 def paged_prefill(cfg: ModelConfig, params, pool: PagePool,
                   tokens: jnp.ndarray, length: jnp.ndarray,
                   page_map: jnp.ndarray, use_flash: bool = False,
@@ -417,10 +534,10 @@ def paged_prefill(cfg: ModelConfig, params, pool: PagePool,
     _, s_pad = tokens.shape
     page_size = pool.page_size
     assert s_pad % page_size == 0, (s_pad, page_size)
-    if cfg.layer_pattern:
-        return _prefill_rows_with_state(
+    if cfg.layer_pattern or cfg.n_window_layers:
+        return _prefill_rows_per_slot(
             cfg, params, pool, tokens, jnp.asarray(length).reshape(1),
-            page_map[None], slots, use_flash)
+            page_map[None], slots, use_flash, expert_kernel)
     new_k, new_v, logits = llama.prefill_kv(cfg, params, tokens, length,
                                             use_flash, ep_mesh, flash_mesh,
                                             sp_mesh, expert_kernel)
@@ -473,18 +590,20 @@ def paged_prefill_batch(cfg: ModelConfig, params, pool: PagePool,
     page_maps [N, S_pad // page_size] int32 page ids — DISTINCT across
     rows except padding rows repeating the last real row (idempotent
     duplicate writes).  ``slots`` [N] int32, for a model that keeps a
-    recurrent state per slot: the decode slot each row is admitted into
-    (a padding row repeats the last real row's), whose state is replaced
-    by the row's, computed from zero; such a model's rows run one after
-    another (``nemotron_h.prefill_rows``).
+    recurrent state or a ring per slot: the decode slot each row is
+    admitted into (a padding row repeats the last real row's), whose
+    state is replaced by the row's, computed from zero, and whose ring
+    takes the row's tail; such a model's rows run one after another
+    (``nemotron_h.prefill_rows``, ``llama.prefill_rows``).
     Returns (pool', logits [N, V] at each row's last valid token).
     """
     n, s_pad = tokens.shape
     page_size = pool.page_size
     assert s_pad % page_size == 0, (s_pad, page_size)
-    if cfg.layer_pattern:
-        return _prefill_rows_with_state(cfg, params, pool, tokens, lengths,
-                                        page_maps, slots, use_flash)
+    if cfg.layer_pattern or cfg.n_window_layers:
+        return _prefill_rows_per_slot(cfg, params, pool, tokens, lengths,
+                                      page_maps, slots, use_flash,
+                                      expert_kernel)
     n_seq_pages = s_pad // page_size
     new_k, new_v, logits = llama._prefill_batch_kv(cfg, params, tokens,
                                                    lengths, use_flash,
@@ -499,42 +618,26 @@ def paged_prefill_batch(cfg: ModelConfig, params, pool: PagePool,
     return pool, logits
 
 
-def _prefill_rows_with_state(cfg: ModelConfig, params, pool: PagePool,
-                             tokens, lengths, page_maps, slots,
-                             use_flash: bool):
-    """``paged_prefill_batch`` for a model with a layer table: keys and
-    values of the attention layers into the rows' pages, each row's
-    recurrent state (left at the row's true length) and convolution tail
-    over whatever its slot held, the local pairs onto the pool's count."""
-    if slots is None:
-        raise ValueError(
-            f"{cfg.name}: a prefill of a model with Mamba-2 layers needs "
-            f"the decode slot of each row (slots=) to write its state to")
-    n, s_pad = tokens.shape
-    page_size = pool.page_size
-    new_k, new_v, state, tail, logits, n_local = nemotron_h.prefill_rows(
-        cfg, params, tokens, lengths, use_flash)
-    pool = _write_pool_pages(
-        cfg, pool, new_k.reshape(cfg.n_kv_layers, n * s_pad, cfg.kv_dim),
-        new_v.reshape(cfg.n_kv_layers, n * s_pad, cfg.kv_dim),
-        page_maps.reshape(-1), n * (s_pad // page_size), page_size)
-    return pool._replace(
-        ssm_state=pool.ssm_state.at[:, slots].set(
-            state.astype(pool.ssm_state.dtype)),
-        conv_state=pool.conv_state.at[:, slots].set(
-            tail.astype(pool.conv_state.dtype)),
-        moe_local_pairs=pool.moe_local_pairs + n_local), logits
-
-
-def _refuse_for_layer_table(cfg: ModelConfig, what: str, why: str) -> None:
-    """A mechanism that is not built for a model with Mamba-2 layers is
-    refused by name where it is asked for, never fallen back from."""
+def _refuse_for_layer_table(cfg: ModelConfig, what: str, why: str,
+                            why_ring: Optional[str] = None) -> None:
+    """A mechanism that is not built for a model with Mamba-2 layers, or
+    for one with sliding-window layers, is refused by name where it is
+    asked for, never fallen back from.  ``{kept}`` in ``why`` is what the
+    model keeps per slot (its state, its ring); ``why_ring`` where the
+    ring's reason is another."""
     if cfg.n_ssm_layers:
         raise ValueError(
             f"{what} is not built for {cfg.name!r}: its "
             f"{cfg.n_ssm_layers} Mamba-2 layers (layer_pattern "
             f"{cfg.layer_pattern!r}) keep a recurrent state per slot "
-            f"beside the pages, and {why}")
+            f"beside the pages, and {why.format(kept='state')}")
+    if cfg.n_window_layers:
+        raise ValueError(
+            f"{what} is not built for {cfg.name!r}: its "
+            f"{cfg.n_window_layers} sliding-window layers keep the last "
+            f"{cfg.attn_window} positions in a ring of pages per slot "
+            f"beside the pages, and "
+            f"{(why_ring or why).format(kept='ring')}")
 
 
 def paged_prefill_cp(cfg: ModelConfig, params, pool: PagePool,
@@ -667,7 +770,10 @@ def paged_prefill_chunk_batch(cfg: ModelConfig, params, pool: PagePool,
     _refuse_for_layer_table(
         cfg, "chunked prefix prefill (paged_prefill_chunk*)",
         "a chunk would have to start from the state at its first "
-        "position, which no page holds")
+        "position, which no page holds",
+        "a chunk would have to read the ring as it stood at its first "
+        "position and leave the ring's tail behind, which the chunk "
+        "program, made for pages alone, does neither")
     n, c_pad = tokens.shape
     page_size = pool.page_size
     assert c_pad % page_size == 0, (c_pad, page_size)
@@ -693,7 +799,7 @@ def paged_prefill_chunk_batch(cfg: ModelConfig, params, pool: PagePool,
     ks, vs = [], []
     for li, layer in enumerate(params["layers"]):
         x, k, v = _chunk_layer(
-            cfg, layer, x, angles, positions, mask,
+            cfg.layer_cfg(li), layer, x, angles, positions, mask,
             pool.k[li], pool.v[li],
             pool.k_scale[li] if pool.quantized else None,
             pool.v_scale[li] if pool.quantized else None,
@@ -771,11 +877,44 @@ def paged_decode_step(cfg: ModelConfig, params, pool: PagePool,
         attn_lengths = jnp.where(block_tables[:, 0] == TRASH_PAGE, 0,
                                  attn_lengths)
 
+    def attend(src: PagePool, layer_i: int, q, lens, tables, **window):
+        """One layer's decode attention over ``src`` (the pages, or the
+        rings with ``starts=``), by the kernel or its XLA forms."""
+        if kernel_on:
+            # the kernel reads layer ``layer_i`` of the pool by reference
+            pages = (src.k, src.v, src.k_scale, src.v_scale)
+            return attn_fn(q[:, 0], *(p for p in pages if p is not None),
+                           lens, tables, layer=layer_i, **window)
+        if src.quantized:
+            k_all = _gather_dequant_pages(src.k[layer_i],
+                                          src.k_scale[layer_i], tables,
+                                          cfg.n_kv_heads, cfg.head_dim,
+                                          dtype, packed)
+            v_all = _gather_dequant_pages(src.v[layer_i],
+                                          src.v_scale[layer_i], tables,
+                                          cfg.n_kv_heads, cfg.head_dim,
+                                          dtype, packed)
+            return decode_attention(q, k_all, v_all, lens, **window)
+        return paged_attention_xla(q[:, 0], src.k[layer_i], src.v[layer_i],
+                                   lens, tables, **window)
+
+    windows = cfg.attn_windows
+    if cfg.n_window_layers:
+        # slot b is row b: where its ring takes this position, and the
+        # ring as the window's query reads it
+        ring_write, ring_tables, ring_lengths, ring_starts = _ring_view(
+            cfg, lengths, page_size)
+        if kernel_on:
+            ring_lengths = jnp.where(block_tables[:, 0] == TRASH_PAGE, 0,
+                                     ring_lengths)
+
     # the layer table: ``kind`` "" is the Llama block (attention, then its
     # MLP), a letter one mixer alone.  ``ai`` counts the layers that cache
-    # keys and values (the pool's layer axis), ``mi`` those with a state.
-    ai = mi = 0
+    # keys and values in pages (the pool's layer axis), ``wi`` those that
+    # keep a ring (the ring's), ``mi`` those with a state.
+    ai = mi = wi = 0
     n_local = jnp.int32(0)
+    pairs = None if pool.moe_local_pairs is None else []
     for li, layer in enumerate(params["layers"]):
         kind = cfg.layer_pattern[li] if cfg.layer_pattern else ""
         if kind == "M":
@@ -791,37 +930,34 @@ def paged_decode_step(cfg: ModelConfig, params, pool: PagePool,
             x, n = nemotron_h.expert_layer(cfg, layer, x)
             n_local = n_local + n
             continue
-        q, k, v = llama._decode_qkv(cfg, layer, x, angles,
+        lcfg = cfg.layer_cfg(li)
+        q, k, v = llama._decode_qkv(lcfg, layer, x, angles,
                                     positions)              # [B,1,·,d]
-        # this token's k/v: [B, n_kv*d] -> pool[ai, page, off]
-        pool = _write_pool_rows(cfg, pool, ai, page_ids, offsets,
-                                k[:, 0].reshape(b, cfg.kv_dim),
-                                v[:, 0].reshape(b, cfg.kv_dim))
-        if kernel_on:
-            # the kernel reads layer ai of the whole pool, by reference
-            pages = (pool.k, pool.v, pool.k_scale, pool.v_scale)
-            attn = attn_fn(q[:, 0], *(p for p in pages if p is not None),
-                           attn_lengths, block_tables, layer=ai)
-        elif pool.quantized:
-            k_all = _gather_dequant_pages(pool.k[ai], pool.k_scale[ai],
-                                          block_tables, cfg.n_kv_heads,
-                                          cfg.head_dim, dtype, packed)
-            v_all = _gather_dequant_pages(pool.v[ai], pool.v_scale[ai],
-                                          block_tables, cfg.n_kv_heads,
-                                          cfg.head_dim, dtype, packed)
-            attn = decode_attention(q, k_all, v_all, attn_lengths)
+        if windows[li]:
+            # this token's k/v into its slot's ring, then the window
+            pool = pool._replace(ring=_write_pool_rows(
+                cfg, pool.ring, wi, ring_write, offsets,
+                k[:, 0].reshape(b, cfg.kv_dim),
+                v[:, 0].reshape(b, cfg.kv_dim)))
+            attn = attend(pool.ring, wi, q, ring_lengths, ring_tables,
+                          starts=ring_starts)
+            wi += 1
         else:
-            attn = paged_attention_xla(q[:, 0], pool.k[ai], pool.v[ai],
-                                       attn_lengths, block_tables)
+            # this token's k/v: [B, n_kv*d] -> pool[ai, page, off]
+            pool = _write_pool_rows(cfg, pool, ai, page_ids, offsets,
+                                    k[:, 0].reshape(b, cfg.kv_dim),
+                                    v[:, 0].reshape(b, cfg.kv_dim))
+            attn = attend(pool, ai, q, attn_lengths, block_tables)
+            ai += 1
         attn = attn.reshape(b, 1, cfg.q_dim)
         if kind == "*":
             x = x + llama._w_mm(cfg, attn, layer["wo"])
         else:
-            x = llama._decode_finish(cfg, layer, x, attn, ep_mesh,
-                                     expert_kernel)
-        ai += 1
+            x = llama._decode_finish(lcfg, layer, x, attn, ep_mesh,
+                                     expert_kernel, pairs)
     if pool.moe_local_pairs is not None:
-        pool = pool._replace(moe_local_pairs=pool.moe_local_pairs + n_local)
+        pool = pool._replace(moe_local_pairs=pool.moe_local_pairs + sum(
+            pairs, n_local))
 
     logits = llama._logits(cfg, params, x)[:, 0]
     return pool, logits
@@ -845,7 +981,9 @@ def paged_decode_multi(cfg: ModelConfig, params, pool: PagePool,
     _refuse_for_layer_table(
         cfg, "multi-token decode (paged_decode_multi, speculative "
         "verification)",
-        "a rejected draft would have to roll the state back")
+        "a rejected draft would have to roll the state back",
+        "a draft's writes would land on ring pages the window still "
+        "needs if the draft is rejected")
     b, t = tokens.shape
     page_size = pool.page_size
     dtype = jnp.dtype(cfg.dtype)
@@ -861,7 +999,8 @@ def paged_decode_multi(cfg: ModelConfig, params, pool: PagePool,
     pages2d = jnp.broadcast_to(page_ids, (b, t))                 # [B, T]
 
     for li, layer in enumerate(params["layers"]):
-        q, k, v = llama._decode_qkv(cfg, layer, x, angles,
+        lcfg = cfg.layer_cfg(li)
+        q, k, v = llama._decode_qkv(lcfg, layer, x, angles,
                                     positions)               # [B,T,·,d]
         pool = _write_pool_rows(cfg, pool, li, pages2d, offsets,
                                 k.reshape(b, t, cfg.kv_dim),
@@ -874,7 +1013,7 @@ def paged_decode_multi(cfg: ModelConfig, params, pool: PagePool,
             pool.v[li], pool.v_scale[li] if pool.quantized else None,
             block_tables, cfg.n_kv_heads, cfg.head_dim, dtype, packed)
         attn = decode_attention_multi(q, k_all, v_all, lengths + 1)
-        x = llama._decode_finish(cfg, layer, x,
+        x = llama._decode_finish(lcfg, layer, x,
                                  attn.reshape(b, t, cfg.q_dim), ep_mesh,
                                  expert_kernel)
 
@@ -1058,35 +1197,51 @@ class PagedInferenceEngine(EngineBase):
                              "unsupported on the PP paths (the pipelined "
                              "prefill/decode do not thread sp_mesh)")
         # a model with Mamba-2 layers (its layer table says so) keeps a
-        # recurrent state per slot beside the pages.  What rests on a
-        # sequence's past being its pages, or on the uniform Llama block,
-        # is not built for it and is refused here by name
-        for asked, what, why in (
+        # recurrent state per slot beside the pages, one with
+        # sliding-window layers a ring of pages per slot.  What rests on
+        # a sequence's past being its pages, or on the uniform Llama
+        # block, is not built for them and is refused here by name
+        for asked, what, why, why_ring in (
                 (engine_cfg.prefix_cache,
                  "the prefix cache (EngineConfig.prefix_cache)",
-                 "a shared prefix would need the state as it stood at the "
+                 "a shared prefix would need the {kept} as it stood at the "
                  "page boundary, which nothing keeps: build the engine "
-                 "with prefix_cache=False"),
+                 "with prefix_cache=False", None),
                 (engine_cfg.max_spilled_pages,
                  "KV spill to the host (EngineConfig.max_spilled_pages)",
-                 "a spilled sequence is its pages AND its state; a "
-                 "preempted sequence is recomputed from its tokens"),
+                 "a spilled sequence is its pages AND its {kept}; a "
+                 "preempted sequence is recomputed from its tokens", None),
                 (engine_cfg.prefill_chunk_budget,
                  "chunked prefill (EngineConfig.prefill_chunk_budget)",
                  "a later chunk would have to start from the state the "
                  "earlier one left, and the chunk program starts from "
-                 "pages alone"),
+                 "pages alone",
+                 "a later chunk would have to read the ring the earlier "
+                 "one left, and the chunk program reads pages alone"),
                 (engine_cfg.speculative_k or draft_model is not None,
                  "speculative decoding (EngineConfig.speculative_k, "
                  "draft_model)",
-                 "a rejected draft would have to roll the state back"),
+                 "a rejected draft would have to roll the state back",
+                 "a rejected draft's writes would have overwritten ring "
+                 "pages the window still needs"),
                 (any(m is not None for m in (tp_mesh, ep_mesh, cp_mesh,
                                              pp_mesh, fsdp_mesh)),
                  "a TP, EP, CP, PP or FSDP mesh",
                  "the Mamba-2 and latent-expert layers have no sharding "
-                 "rule and no pipelined or ring form")):
+                 "rule and no pipelined or ring form",
+                 "the ring has no sharding rule (runtime/rules.py) and "
+                 "the window layers no pipelined or context-parallel "
+                 "form")):
             if asked:
-                _refuse_for_layer_table(model_cfg, what, why)
+                _refuse_for_layer_table(model_cfg, what, why, why_ring)
+        if any(m is not None for m in (tp_mesh, ep_mesh, cp_mesh, pp_mesh,
+                                       fsdp_mesh)):
+            # a Llama block with per-layer kinds or their leaves has no
+            # sharding rule: the rules' own refusal, asked here
+            from k8s_llm_rca_tpu.runtime.rules import refuse_per_layer_kinds
+
+            refuse_per_layer_kinds(model_cfg,
+                                   "a TP, EP, CP, PP or FSDP mesh")
         from k8s_llm_rca_tpu.engine.engine import (
             params_multi_device, validate_ep_mesh, validate_fsdp_mesh,
             validate_pp_mesh, validate_tp_mesh,
@@ -1366,6 +1521,16 @@ class PagedInferenceEngine(EngineBase):
             if a is not None)
         self._moe_pairs_seen = 0
         METRICS.gauge("engine.state_bytes", self._state_bytes)
+        # bytes one page holds in one layer (scales included), and one
+        # slot's ring in all the window layers: what the two cache gauges
+        # count in (``_count_attn_pages``)
+        self._page_layer_bytes = sum(
+            a.nbytes // (a.shape[0] * a.shape[1]) if a.shape[0] else 0
+            for a in (self.pool.k, self.pool.v, self.pool.k_scale,
+                      self.pool.v_scale) if a is not None)
+        self._ring_slot_bytes = (
+            0 if self.pool.ring is None else sum(
+                a.nbytes for a in self.pool.ring if a is not None) // b)
         if self._cp_parts:
             # CP seq-sharded pool: the PAGE axis shards over the seq mesh
             # axis — device p holds pages [p*N/P, (p+1)*N/P), exactly the
@@ -1531,6 +1696,7 @@ class PagedInferenceEngine(EngineBase):
         # every tick copies the whole pool and peak HBM doubles.  (CPU has
         # no donation support and would warn on every compile, so gate it.)
         donate = (2,) if jax.default_backend() == "tpu" else ()
+        self._flash_prefill = False
         pp_decode_fn = None
         pp_decode_multi_fn = None
         if pp_mesh is not None:
@@ -1611,6 +1777,7 @@ class PagedInferenceEngine(EngineBase):
             use_flash, flash_mesh = flash_prefill_plan(
                 params, None if fsdp_mesh is not None else tp_mesh,
                 model_cfg, ep_mesh)
+            self._flash_prefill = use_flash
             self._prefill = jax.jit(
                 profiling.named_partial(paged_prefill, use_flash=use_flash,
                                         ep_mesh=ep_mesh, flash_mesh=flash_mesh,
@@ -1774,7 +1941,8 @@ class PagedInferenceEngine(EngineBase):
         positions x Mamba layers (pad included, and the ``n_true`` real
         ones apart), and positions x picks x expert layers."""
         cfg = self.model_cfg
-        per_call = n_positions // rows if cfg.layer_pattern else n_positions
+        by_row = bool(cfg.layer_pattern or cfg.n_window_layers)
+        per_call = n_positions // rows if by_row else n_positions
         self._count("engine.prefill_padded_tokens", n_positions)
         if self._moe_in_model and llama.moe_grouped(cfg, per_call):
             self._count("engine.moe_grouped_tokens", n_positions)
@@ -1783,17 +1951,25 @@ class PagedInferenceEngine(EngineBase):
                         n_positions * cfg.n_ssm_layers)
             self._count("engine.ssm_prefill_true_tokens",
                         n_true * cfg.n_ssm_layers)
+        if self.pool.moe_local_pairs is not None:
             self._count_routed_pairs(n_positions)
+        if cfg.n_window_layers and llama.prefill_uses_flash(
+                self._flash_prefill, per_call):
+            # positions x window layers the banded flash calls covered
+            self._count("engine.attn_window_prefill_tokens",
+                        n_positions * cfg.n_window_layers)
 
     def _count_routed_pairs(self, n_positions: int) -> None:
         """``engine.moe_routed_pairs``: (position, expert) pairs the
-        expert layers of a model with a layer table routed, from the
-        shape; ``engine.moe_local_pairs`` is the part whose expert is
-        held here, counted on the device (``_note_local_pairs``)."""
+        expert layers routed, from the shape, for a model whose pool
+        counts the local ones; ``engine.moe_local_pairs`` is the part
+        whose expert is held here, counted on the device
+        (``_note_local_pairs``)."""
         cfg = self.model_cfg
+        expert_layers = (cfg.layer_pattern.count("E") if cfg.layer_pattern
+                         else cfg.n_layers - cfg.n_dense_layers)
         self._count("engine.moe_routed_pairs",
-                    n_positions * cfg.n_experts_per_tok
-                    * cfg.layer_pattern.count("E"))
+                    n_positions * cfg.n_experts_per_tok * expert_layers)
 
     def _count_moe_fused(self, steps: int, per_step: int = 1) -> None:
         """``engine.moe_fused_steps`` beside ``engine.decode_steps``: the
@@ -1813,9 +1989,11 @@ class PagedInferenceEngine(EngineBase):
         x steps x Mamba layers; a dead slot's is run too), the pairs its
         expert layers routed, and how many slots hold a live sequence."""
         cfg = self.model_cfg
-        if not cfg.layer_pattern:
-            return
         b = self.engine_cfg.max_batch
+        if not cfg.layer_pattern:
+            if self.pool.moe_local_pairs is not None:
+                self._count_routed_pairs(b * steps)
+            return
         METRICS.gauge("engine.state_slots_live", len(self._active))
         self._count("engine.ssm_decode_slot_steps",
                     b * steps * cfg.n_ssm_layers)
@@ -1838,9 +2016,10 @@ class PagedInferenceEngine(EngineBase):
             self._moe_pairs_seen = now
 
     def _slots_kw(self, slots) -> dict:
-        """The ``slots=`` a prefill of a model with a state per slot is
-        given (nothing for any other)."""
-        if not self.model_cfg.n_ssm_layers:
+        """The ``slots=`` a prefill of a model with a state or a ring per
+        slot is given (nothing for any other)."""
+        if not (self.model_cfg.n_ssm_layers
+                or self.model_cfg.n_window_layers):
             return {}
         return {"slots": np.asarray(slots, np.int32)}
 
@@ -1849,11 +2028,28 @@ class PagedInferenceEngine(EngineBase):
         host length mirror, beside the pages the decode kernel visits
         per layer: each live slot's, rounded up to the kernel's block; a
         slot that holds no sequence is visited not at all."""
-        live = -(-self.lengths[active_slots] // self.page_size)
+        lens = self.lengths[active_slots]
+        live = -(-lens // self.page_size)
         block = block_pages(self.page_size, self.pages_per_seq)
         self._count("engine.attn_pages_live", steps * int(live.sum()))
         self._count("engine.attn_pages_grid",
                     steps * int((-(-live // block) * block).sum()))
+        cfg = self.model_cfg
+        if cfg.n_window_layers:
+            # tokens the two kinds of decode call read (x their layers),
+            # and the cache each kind holds for the live sequences: pages
+            # the allocator has given out, and the live slots' rings
+            self._count("engine.attn_full_tokens",
+                        steps * int(lens.sum()) * cfg.n_kv_layers)
+            self._count("engine.attn_window_tokens",
+                        steps * int(np.minimum(lens, cfg.attn_window)
+                                    .sum()) * cfg.n_window_layers)
+            METRICS.gauge("engine.cache_bytes_full",
+                          (self.engine_cfg.num_pages - 1
+                           - self.allocator.n_free)
+                          * self._page_layer_bytes * cfg.n_kv_layers)
+            METRICS.gauge("engine.cache_bytes_window",
+                          len(active_slots) * self._ring_slot_bytes)
 
     # --------------------------------------------- device-resident state
 

@@ -107,6 +107,8 @@ def init_params(cfg: ModelConfig, key: jax.Array,
     h, q, kv, inter = cfg.hidden_size, cfg.q_dim, cfg.kv_dim, cfg.intermediate_size
     keys = jax.random.split(key, cfg.n_layers + 2)
     scale = 1.0 / math.sqrt(h)
+    # a stage cut out of a deeper model carries the deeper model's weights
+    depth = cfg.init_layers or cfg.n_layers
 
     tt = tensor_transform or (lambda w, **_: w)
 
@@ -126,22 +128,41 @@ def init_params(cfg: ModelConfig, key: jax.Array,
             "wq": _tdense(lk[0], (h, q), scale),
             "wk": _tdense(lk[1], (h, kv), scale),
             "wv": _tdense(lk[2], (h, kv), scale),
-            "wo": _tdense(lk[3], (q, h), scale / math.sqrt(2 * cfg.n_layers)),
+            "wo": _tdense(lk[3], (q, h), scale / math.sqrt(2 * depth)),
         }
-        if cfg.n_experts > 0:
-            e = cfg.n_experts
+        if cfg.qk_norm:
+            layer.update({"q_norm": jnp.ones((cfg.head_dim,), dtype),
+                          "k_norm": jnp.ones((cfg.head_dim,), dtype)})
+        if cfg.layer_cfg(i).n_experts > 0:
+            e, width = cfg.n_experts, cfg.expert_size
             layer.update(
                 {
-                    "router": _tdense(lk[4], (h, e), scale),
-                    "w_gate": _tdense(lk[5], (e, h, inter), scale,
+                    "router": _tdense(lk[4], (h, cfg.n_router), scale),
+                    "w_gate": _tdense(lk[5], (e, h, width), scale,
                                       axis=(0, -1)),
-                    "w_up": _tdense(lk[6], (e, h, inter), scale,
+                    "w_up": _tdense(lk[6], (e, h, width), scale,
                                     axis=(0, -1)),
                     "w_down": _tdense(
-                        lk[7], (e, inter, h),
-                        scale / math.sqrt(2 * cfg.n_layers), axis=(0, -1)),
+                        lk[7], (e, width, h),
+                        scale / math.sqrt(2 * depth), axis=(0, -1)),
                 }
             )
+            if cfg.router_kind == "sigmoid":
+                layer["router_bias"] = 0.1 * jax.random.normal(
+                    jax.random.fold_in(lk[4], 1), (cfg.n_router,),
+                    jnp.float32)
+            if cfg.shared_expert_size:
+                sk = jax.random.split(jax.random.fold_in(lk[4], 2), 3)
+                shared = cfg.shared_expert_size
+                layer.update(
+                    {
+                        "w_shared_gate": _tdense(sk[0], (h, shared), scale),
+                        "w_shared_up": _tdense(sk[1], (h, shared), scale),
+                        "w_shared_down": _tdense(
+                            sk[2], (shared, h),
+                            scale / math.sqrt(2 * depth)),
+                    }
+                )
         else:
             layer.update(
                 {
@@ -149,7 +170,7 @@ def init_params(cfg: ModelConfig, key: jax.Array,
                     "w_up": _tdense(lk[6], (h, inter), scale),
                     "w_down": _tdense(
                         lk[7], (inter, h),
-                        scale / math.sqrt(2 * cfg.n_layers)),
+                        scale / math.sqrt(2 * depth)),
                 }
             )
         layers.append(layer)
@@ -228,6 +249,10 @@ def _qkv(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
     q = _w_mm(cfg, x, layer["wq"]).reshape(b, s, -1, cfg.head_dim)
     k = _w_mm(cfg, x, layer["wk"]).reshape(b, s, -1, cfg.head_dim)
     v = _w_mm(cfg, x, layer["wv"]).reshape(b, s, -1, cfg.head_dim)
+    if cfg.qk_norm:
+        # one learned gain over the head's width, before the rotation
+        q = rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
     if cfg.use_rope:
         q = apply_rope(q, angles, positions)
         k = apply_rope(k, angles, positions)
@@ -236,7 +261,8 @@ def _qkv(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
 
 def _mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
          ep_mesh=None, ep_token_axis: str = "data",
-         expert_kernel: bool = False) -> jnp.ndarray:
+         expert_kernel: bool = False,
+         local_pairs: Optional[list] = None) -> jnp.ndarray:
     """``ep_mesh``: optional Mesh with an "expert" axis — the MoE block then
     dispatches through the all-to-all expert-parallel path
     (parallel/moe.expert_parallel_moe) instead of ``_moe_mlp`` (token-
@@ -248,7 +274,7 @@ def _mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
     ``ep_token_axis``: mesh axis the flattened token dim shards over
     alongside "expert" — "data" for batch prefill/decode, the CP seq axis
     under context-parallel prefill (the sequence stays put; dispatch rides
-    the expert axis only)."""
+    the expert axis only).  ``local_pairs``: see ``_moe_mlp``."""
     if cfg.n_experts > 0:
         if ep_mesh is not None:
             from k8s_llm_rca_tpu.parallel.moe import expert_parallel_moe
@@ -257,7 +283,7 @@ def _mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
                 x, layer, ep_mesh, top_k=cfg.n_experts_per_tok,
                 capacity_factor=float(cfg.n_experts),
                 data_axis=ep_token_axis)
-        return _moe_mlp(cfg, layer, x, expert_kernel)
+        return _moe_mlp(cfg, layer, x, expert_kernel, local_pairs)
     gate = jax.nn.silu(_w_mm(cfg, x, layer["w_gate"]))
     up = _w_mm(cfg, x, layer["w_up"])
     return _w_mm(cfg, gate * up, layer["w_down"])
@@ -309,6 +335,40 @@ MOE_GROUPED_MIN_ROWS_PER_EXPERT = 384
 MOE_GROUPED_MIN_ROWS_PER_EXPERT_LATENT = 44
 
 
+# The same threshold for fine-grained bf16 SwiGLU experts (their own width,
+# ``cfg.moe_intermediate_size``, not in a latent space).  Set from one sparse
+# MLP (router, routed experts, shared expert) at K-EXAONE-236B-A23B's widths
+# on one TPU v5e: 6144 -> 2048 -> 6144 SwiGLU experts in bf16, 16 of the
+# router's 128 held, 8 picks a position (my chip run, PR 36; ms a call,
+# dense | grouped, by T = B * S; in brackets the routed experts alone, without
+# router and shared expert):
+#      T    rows/expert    dense            grouped
+#     64         4          2.06 (1.76)      2.97 (2.90)    (the cell's decode call)
+#    256        16          2.09 (1.95)      3.19 (3.06)
+#    512        32          4.10 (3.83)      3.96 (3.61)    <- grouped wins from here
+#   1024        64          7.54 (6.92)      5.19 (4.16)
+#   2048       128         14.94 (13.94)     7.33 (6.37)
+#   4096       256         31.60 (27.79)    11.40 (9.51)
+#   6144       384         47.26 (44.28)    15.56 (12.67)
+# Nothing is dequantized, so the grouped form has no fixed cost to win back:
+# up to 256 positions the dense form is flat at 2 ms (it streams the 1.2 GB
+# of held experts whatever the rows) and the grouped form pays its sort and
+# its gathers of T x 8 rows; from 512 the dense form computes 16 experts on
+# every position for the one pick in eight that is local.  Int4 experts of
+# this kind would need a row of their own (the dequantization's fixed cost,
+# as in the first table).
+MOE_GROUPED_MIN_ROWS_PER_EXPERT_FINE = 32
+
+
+def _grouped_min_rows(cfg: ModelConfig) -> int:
+    """The table that holds for the experts ``cfg`` describes."""
+    if cfg.moe_latent_size:
+        return MOE_GROUPED_MIN_ROWS_PER_EXPERT_LATENT
+    if cfg.moe_intermediate_size:
+        return MOE_GROUPED_MIN_ROWS_PER_EXPERT_FINE
+    return MOE_GROUPED_MIN_ROWS_PER_EXPERT
+
+
 def moe_grouped(cfg: ModelConfig, n_tokens: int) -> bool:
     """Whether the expert layer takes the token-grouped path for a call of
     ``n_tokens`` positions (``B * S``, pad positions included).  The one
@@ -322,9 +382,7 @@ def moe_grouped(cfg: ModelConfig, n_tokens: int) -> bool:
     if cfg.n_experts <= 0:
         return False
     rows_per_expert = n_tokens * cfg.n_experts_per_tok / cfg.n_router
-    return rows_per_expert >= (MOE_GROUPED_MIN_ROWS_PER_EXPERT_LATENT
-                               if cfg.moe_latent_size
-                               else MOE_GROUPED_MIN_ROWS_PER_EXPERT)
+    return rows_per_expert >= _grouped_min_rows(cfg)
 
 
 # Positions (T = B * S) up to which a call that is not token-grouped reads
@@ -409,12 +467,40 @@ def _held(cfg: ModelConfig, topi: jnp.ndarray):
     return jnp.where(held, local, cfg.n_experts), held
 
 
+def n_local_pairs(cfg: ModelConfig, topi: jnp.ndarray) -> jnp.ndarray:
+    """How many of the router's (position, expert) choices name an expert
+    held here, an int32 scalar: ``engine.moe_local_pairs``."""
+    _, held = _held(cfg, topi)
+    return (jnp.sum(held, dtype=jnp.int32) if held is not None
+            else jnp.int32(topi.size))
+
+
+def _shared_hidden(cfg: ModelConfig, layer: Params, x: jnp.ndarray
+                   ) -> jnp.ndarray:
+    """The always-on shared expert's hidden activation on x [..., H]
+    (``w_shared_down`` takes it back): SwiGLU, or the non-gated squared
+    ReLU of a ``relu2`` model."""
+    up = _w_mm(cfg, x, layer["w_shared_up"])
+    if cfg.mlp_act == "relu2":
+        return jnp.square(jax.nn.relu(up))
+    return jax.nn.silu(_w_mm(cfg, x, layer["w_shared_gate"])) * up
+
+
 def _moe_mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
-             expert_kernel: bool = False) -> jnp.ndarray:
+             expert_kernel: bool = False,
+             local_pairs: Optional[list] = None) -> jnp.ndarray:
     """Sparse-expert MLP: the router's k experts a token (``_route``),
-    their MLPs, the weighted sum (``_experts``)."""
+    their MLPs, the weighted sum (``_experts``), and beside it the shared
+    expert where the model has one.  ``local_pairs``: a list the caller
+    sums; this layer's ``n_local_pairs`` is appended to it."""
     topi, weights = _route(cfg, layer, x)
-    return _experts(cfg, layer, x, topi, weights, expert_kernel)
+    if local_pairs is not None:
+        local_pairs.append(n_local_pairs(cfg, topi))
+    out = _experts(cfg, layer, x, topi, weights, expert_kernel)
+    if cfg.shared_expert_size:
+        out = out + _w_mm(cfg, _shared_hidden(cfg, layer, x),
+                          layer["w_shared_down"])
+    return out
 
 
 def _experts(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
@@ -572,7 +658,8 @@ def _sp_constrain(x: jnp.ndarray, sp_mesh) -> jnp.ndarray:
 def _block_prefill(cfg, layer, x, angles, positions, seq_lens,
                    attention_fn=None, ep_mesh=None,
                    ep_token_axis: str = "data", sp_mesh=None,
-                   expert_kernel: bool = False):
+                   expert_kernel: bool = False,
+                   local_pairs: Optional[list] = None):
     """One transformer block over a full sequence.  ``attention_fn``
     defaults to masked causal attention (always safe: differentiable for
     training, GSPMD-partitionable for TP); inference prefill passes the
@@ -591,7 +678,8 @@ def _block_prefill(cfg, layer, x, angles, positions, seq_lens,
     x = x + _w_mm(cfg, attn.reshape(b, s, cfg.q_dim), layer["wo"])
     x = _sp_constrain(x, sp_mesh)
     h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-    x = x + _mlp(cfg, layer, h, ep_mesh, ep_token_axis, expert_kernel)
+    x = x + _mlp(cfg, layer, h, ep_mesh, ep_token_axis, expert_kernel,
+                 local_pairs)
     return x, k, v
 
 
@@ -606,14 +694,16 @@ def _decode_qkv(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
 
 def _decode_finish(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
                    attn: jnp.ndarray, ep_mesh=None,
-                   expert_kernel: bool = False) -> jnp.ndarray:
+                   expert_kernel: bool = False,
+                   local_pairs: Optional[list] = None) -> jnp.ndarray:
     """Decode-block back half: attention output projection + residual +
     MLP (shared across decode paths, see _decode_qkv).  ``attn`` must
     already be flattened to [B, T, q_dim] — kernel outputs vary in rank,
     so call sites own the reshape."""
     x = x + _w_mm(cfg, attn, layer["wo"])
     hm = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-    return x + _mlp(cfg, layer, hm, ep_mesh, expert_kernel=expert_kernel)
+    return x + _mlp(cfg, layer, hm, ep_mesh, expert_kernel=expert_kernel,
+                    local_pairs=local_pairs)
 
 
 def _quantize_kv(kv: jnp.ndarray, packed: bool = False,
@@ -705,10 +795,71 @@ def forward(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
     x = gather_rows(params["embedding"], tokens).astype(jnp.dtype(cfg.dtype))
-    for layer in params["layers"]:
-        x, _, _ = _block_prefill(cfg, layer, x, angles, positions, seq_lens,
-                                 ep_mesh=ep_mesh, sp_mesh=sp_mesh)
+    for li, layer in enumerate(params["layers"]):
+        x, _, _ = _block_prefill(
+            cfg.layer_cfg(li), layer, x, angles, positions, seq_lens,
+            _layer_attention_fn(cfg, li, seq_lens, False, s),
+            ep_mesh=ep_mesh, sp_mesh=sp_mesh)
     return _logits(cfg, params, x)
+
+
+# padded positions from which a prefill that may use the Pallas flash kernel
+# does: below it the masked XLA form is as fast and compiles everywhere
+FLASH_MIN_POSITIONS = 1024
+
+
+def prefill_uses_flash(use_flash: bool, s_pad: int) -> bool:
+    """Whether a prefill call over ``s_pad`` padded positions runs the
+    Pallas flash kernel (a band for a window layer): the one rule of every
+    prefill loop here, and of whoever counts what those calls covered
+    (``engine.attn_window_prefill_tokens``)."""
+    return bool(use_flash) and s_pad >= FLASH_MIN_POSITIONS
+
+
+def _refuse_window_layers(cfg: ModelConfig, what: str) -> None:
+    """The loops that hand back or keep EVERY position's keys and values
+    of every layer (the contiguous ``KVCache``, the whole-depth prefills)
+    have no place for what a sliding-window layer keeps, the last window in
+    a ring of pages per decode slot: such a model is served by
+    ``prefill_rows`` and the paged decode step, and scored by ``forward``.
+    Layers that differ in anything else (rotary embedding by kind, a
+    leading dense MLP) these loops read from ``cfg.layer_cfg``."""
+    if cfg.n_window_layers:
+        raise ValueError(
+            f"{what} is not built for {cfg.name!r}: its "
+            f"{cfg.n_window_layers} sliding-window layers keep the last "
+            f"{cfg.attn_window} positions in a ring of pages per decode "
+            f"slot, and this loop keeps every position of every layer")
+
+
+# q and key block of the lean flash call (ops/flash_attention.py) that the
+# full layers of a model with window layers make: one 6144-position row of
+# 64 heads takes 93.3 ms plain, 13.0 lean in 512-blocks, 7.0 in 1024-blocks
+# (my chip run, PR 36, PERF.md section 6)
+FLASH_LEAN_BLOCK = 1024
+
+
+def _layer_attention_fn(cfg: ModelConfig, li: int, seq_lens,
+                        use_flash: bool, s_pad: int):
+    """The prefill attention of layer ``li`` over fresh sequences: the
+    flash kernel where ``prefill_uses_flash`` (a window layer's band as
+    it is, its time being grid steps; a full layer's call lean and in
+    large blocks), else the masked XLA form (None: ``_block_prefill``'s
+    default, the full causal one)."""
+    window = cfg.attn_windows[li]
+    if prefill_uses_flash(use_flash, s_pad):
+        from k8s_llm_rca_tpu.ops.flash_attention import flash_attention
+
+        if window:
+            return lambda q, k, v: flash_attention(
+                q, k, v, seq_lens, interpret=False, window=window)
+        return lambda q, k, v: flash_attention(
+            q, k, v, seq_lens, interpret=False, lean=True,
+            block_q=FLASH_LEAN_BLOCK, block_k=FLASH_LEAN_BLOCK)
+    if window:
+        return lambda q, k, v: causal_attention(q, k, v, seq_lens,
+                                                window=window)
+    return None
 
 
 def _flash_attention_fn(seq_lens, flash_mesh):
@@ -748,6 +899,7 @@ def prefill_kv(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     tokens [1, S_pad], ``length`` scalar valid length.  Returns
     (new_k [L, S_pad, n_kv, d], new_v likewise, logits [1, V]).
     """
+    _refuse_window_layers(cfg, "llama.prefill_kv")
     _, s_pad = tokens.shape
     angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
     positions = jnp.arange(s_pad)[None, :]
@@ -755,13 +907,14 @@ def prefill_kv(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     x = gather_rows(params["embedding"], tokens).astype(jnp.dtype(cfg.dtype))
 
     attention_fn = None
-    if use_flash and s_pad >= 1024:
+    if prefill_uses_flash(use_flash, s_pad):
         attention_fn = _flash_attention_fn(seq_lens, flash_mesh)
 
     ks, vs = [], []
-    for layer in params["layers"]:
-        x, k, v = _block_prefill(cfg, layer, x, angles, positions, seq_lens,
-                                 attention_fn, ep_mesh, sp_mesh=sp_mesh,
+    for li, layer in enumerate(params["layers"]):
+        x, k, v = _block_prefill(cfg.layer_cfg(li), layer, x, angles,
+                                 positions, seq_lens, attention_fn, ep_mesh,
+                                 sp_mesh=sp_mesh,
                                  expert_kernel=expert_kernel)
         ks.append(k[0])  # [S_pad, n_kv, d]
         vs.append(v[0])
@@ -838,6 +991,7 @@ def decode_step(cfg: ModelConfig, params: Params, cache: KVCache,
     cache (the new token is written at index lengths[b] and attends to
     lengths[b]+1 positions).  Returns (cache', logits [B, V]).
     """
+    _refuse_window_layers(cfg, "llama.decode_step")
     b = tokens.shape[0]
     angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
     positions = lengths[:, None]                       # [B, 1]
@@ -848,7 +1002,8 @@ def decode_step(cfg: ModelConfig, params: Params, cache: KVCache,
     packed = _kv_packed(cfg, cache)
     new_ks, new_vs, new_kss, new_vss = [], [], [], []
     for li, layer in enumerate(params["layers"]):
-        q, k, v = _decode_qkv(cfg, layer, x, angles, positions)  # q [B,1,h,d]
+        lcfg = cfg.layer_cfg(li)
+        q, k, v = _decode_qkv(lcfg, layer, x, angles, positions)  # [B,1,h,d]
         k_cache, v_cache, k_s, v_s = _store_layer_kv(
             cache, li, k[:, 0].reshape(b, cfg.kv_dim),
             v[:, 0].reshape(b, cfg.kv_dim), lengths)
@@ -863,7 +1018,7 @@ def decode_step(cfg: ModelConfig, params: Params, cache: KVCache,
             _dequant_layer(v_cache, v_s, dtype, packed).reshape(
                 b, s_max, cfg.n_kv_heads, cfg.head_dim),
             lengths + 1)
-        x = _decode_finish(cfg, layer, x,
+        x = _decode_finish(lcfg, layer, x,
                            attn.reshape(b, 1, cfg.q_dim), ep_mesh)
 
     cache = KVCache(
@@ -908,6 +1063,7 @@ def decode_multi(cfg: ModelConfig, params: Params, cache: KVCache,
     KV written past the accepted position is invisible until overwritten
     by a later decode at that position.
     """
+    _refuse_window_layers(cfg, "llama.decode_multi")
     b, t = tokens.shape
     s_max = cache.max_seq_len
     angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
@@ -918,7 +1074,8 @@ def decode_multi(cfg: ModelConfig, params: Params, cache: KVCache,
     packed = _kv_packed(cfg, cache)
     new_ks, new_vs, new_kss, new_vss = [], [], [], []
     for li, layer in enumerate(params["layers"]):
-        q, k, v = _decode_qkv(cfg, layer, x, angles, positions)  # [B,T,·,d]
+        lcfg = cfg.layer_cfg(li)
+        q, k, v = _decode_qkv(lcfg, layer, x, angles, positions)  # [B,T,·,d]
         k_cache, v_cache, k_s, v_s = _store_layer_kv(
             cache, li, k.reshape(b, t, cfg.kv_dim),
             v.reshape(b, t, cfg.kv_dim), lengths)
@@ -933,7 +1090,7 @@ def decode_multi(cfg: ModelConfig, params: Params, cache: KVCache,
             _dequant_layer(v_cache, v_s, dtype, packed).reshape(
                 b, s_max, cfg.n_kv_heads, cfg.head_dim),
             lengths + 1)
-        x = _decode_finish(cfg, layer, x,
+        x = _decode_finish(lcfg, layer, x,
                            attn.reshape(b, t, cfg.q_dim), ep_mesh)
 
     cache = KVCache(
@@ -984,6 +1141,7 @@ def prefill_kv_cp(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     from k8s_llm_rca_tpu.parallel.ring_attention import ring_attention
     from k8s_llm_rca_tpu.parallel.ulysses import ulysses_attention
 
+    _refuse_window_layers(cfg, "llama.prefill_kv_cp")
     if cp_mode not in ("ring", "ulysses"):
         raise ValueError(f"unknown cp_mode {cp_mode!r}")
     cp_attn = ring_attention if cp_mode == "ring" else ulysses_attention
@@ -998,9 +1156,9 @@ def prefill_kv_cp(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     attn = lambda q, k, v: cp_attn(q, k, v, mesh, seq_axis=seq_axis,
                                    head_axis=head_axis)
     ks, vs = [], []
-    for layer in params["layers"]:
-        x, k, v = _block_prefill(cfg, layer, x, angles, positions,
-                                 seq_lens=None, attention_fn=attn,
+    for li, layer in enumerate(params["layers"]):
+        x, k, v = _block_prefill(cfg.layer_cfg(li), layer, x, angles,
+                                 positions, seq_lens=None, attention_fn=attn,
                                  ep_mesh=ep_mesh, ep_token_axis=seq_axis)
         ks.append(k[0])
         vs.append(v[0])
@@ -1019,19 +1177,21 @@ def _prefill_batch_kv(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     right-padded, lengths [N] -> (new_k [L, N, S_pad, kv_dim], new_v,
     logits [N, V] at each row's last valid token); the caller scatters
     the KV into pool pages (engine/paged.paged_prefill_batch)."""
+    _refuse_window_layers(cfg, "llama._prefill_batch_kv")
     n, s_pad = tokens.shape
     angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
     positions = jnp.broadcast_to(jnp.arange(s_pad)[None, :], (n, s_pad))
     x = gather_rows(params["embedding"], tokens).astype(jnp.dtype(cfg.dtype))
 
     attention_fn = None
-    if use_flash and s_pad >= 1024:
+    if prefill_uses_flash(use_flash, s_pad):
         attention_fn = _flash_attention_fn(lengths, flash_mesh)
 
     ks, vs = [], []
-    for layer in params["layers"]:
-        x, k, v = _block_prefill(cfg, layer, x, angles, positions, lengths,
-                                 attention_fn, ep_mesh, sp_mesh=sp_mesh,
+    for li, layer in enumerate(params["layers"]):
+        x, k, v = _block_prefill(cfg.layer_cfg(li), layer, x, angles,
+                                 positions, lengths, attention_fn, ep_mesh,
+                                 sp_mesh=sp_mesh,
                                  expert_kernel=expert_kernel)
         ks.append(k.reshape(n, s_pad, cfg.kv_dim))   # [N, S_pad, kv]
         vs.append(v.reshape(n, s_pad, cfg.kv_dim))
@@ -1040,3 +1200,59 @@ def _prefill_batch_kv(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     last = x[idx, lengths - 1][:, None]              # [N, 1, H]
     logits = _logits(cfg, params, last)[:, 0]        # [N, V]
     return jnp.stack(ks), jnp.stack(vs), logits      # [L, N, S_pad, kv]
+
+
+def prefill_rows(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
+                 lengths: jnp.ndarray, tail_starts: jnp.ndarray, tail: int,
+                 use_flash: bool = False, expert_kernel: bool = False):
+    """Batched prefill WITHOUT a cache write for a model with
+    sliding-window layers, whose rows each leave a ring's tail behind: one
+    row after another (``jax.lax.map``): rows share nothing, one row of a
+    bucket keeps every matmul large, and the temporaries (the routed rows
+    of the picks a position, a 6144-position row's band) stay one row's.
+
+    tokens [N, S] right-padded, lengths [N].  A full layer's keys and
+    values leave the map whole; a window layer's only as the ``tail``
+    positions from ``tail_starts[row]`` (what its slot's ring keeps:
+    engine/paged.py::_ring_tail says which).  Returns (k, v [Lf, N, S,
+    kv_dim], window k, v [Lw, N, tail, kv_dim], logits [N, V] at each
+    row's last true token, the rows' local expert pairs, int32).
+    """
+    s_pad = tokens.shape[1]
+    angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
+    positions = jnp.arange(s_pad)[None, :]
+    windows = cfg.attn_windows
+
+    def one(row):
+        toks, n, start = row
+        seq_lens = n[None]
+        x = gather_rows(params["embedding"], toks[None]).astype(
+            jnp.dtype(cfg.dtype))
+        full, ring, pairs = [], [], []
+        for li, layer in enumerate(params["layers"]):
+            x, k, v = _block_prefill(
+                cfg.layer_cfg(li), layer, x, angles, positions, seq_lens,
+                _layer_attention_fn(cfg, li, seq_lens, use_flash, s_pad),
+                expert_kernel=expert_kernel, local_pairs=pairs)
+            kv = (k.reshape(s_pad, cfg.kv_dim), v.reshape(s_pad, cfg.kv_dim))
+            if windows[li]:
+                ring.append(tuple(jax.lax.dynamic_slice_in_dim(
+                    a, start, tail, axis=0) for a in kv))
+            else:
+                full.append(kv)
+        last = jax.lax.dynamic_slice_in_dim(x, n - 1, 1, axis=1)
+
+        def stacked(kvs, i, rows):
+            return (jnp.stack([kv[i] for kv in kvs]) if kvs
+                    else jnp.zeros((0, rows, cfg.kv_dim), x.dtype))
+
+        return (stacked(full, 0, s_pad), stacked(full, 1, s_pad),
+                stacked(ring, 0, tail), stacked(ring, 1, tail),
+                _logits(cfg, params, last)[0, 0], sum(pairs, jnp.int32(0)))
+
+    k, v, wk, wv, logits, n_local = jax.lax.map(
+        one, (tokens, lengths.astype(jnp.int32),
+              tail_starts.astype(jnp.int32)))
+    return (jnp.moveaxis(k, 0, 1), jnp.moveaxis(v, 0, 1),
+            jnp.moveaxis(wk, 0, 1), jnp.moveaxis(wv, 0, 1), logits,
+            jnp.sum(n_local))

@@ -218,13 +218,10 @@ def expert_layer(cfg: ModelConfig, layer: Params, x: jnp.ndarray
     topi, weights = llama._route(cfg, layer, u)
     latent = llama._w_mm(cfg, u, layer["w_latent_down"])
     routed = llama._experts(cfg, layer, latent, topi, weights)
-    shared = jnp.square(jax.nn.relu(
-        llama._w_mm(cfg, u, layer["w_shared_up"])))
+    shared = llama._shared_hidden(cfg, layer, u)
     out = (llama._w_mm(cfg, routed, layer["w_latent_up"])
            + llama._w_mm(cfg, shared, layer["w_shared_down"]))
-    _, held = llama._held(cfg, topi)
-    n_local = (jnp.sum(held, dtype=jnp.int32) if held is not None
-               else jnp.int32(topi.size))
+    n_local = llama.n_local_pairs(cfg, topi)
     return x + out, n_local
 
 
@@ -252,7 +249,7 @@ def _stack(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     [Lm, N, ...], the local-pair count)."""
     x = gather_rows(params["embedding"], tokens).astype(jnp.dtype(cfg.dtype))
     attention_fn = None
-    if use_flash and tokens.shape[1] >= 1024:
+    if llama.prefill_uses_flash(use_flash, tokens.shape[1]):
         attention_fn = llama._flash_attention_fn(lengths, None)
     ks, vs, states, tails = [], [], [], []
     n_local = jnp.int32(0)

@@ -35,8 +35,12 @@ def causal_attention(
     v: jnp.ndarray,          # [B, S, n_kv, d]
     seq_lens: jnp.ndarray,   # [B] valid lengths (right-padded inputs)
     q_offset: jnp.ndarray | None = None,  # [B] absolute pos of q[...,0,...]
+    window: int = 0,
 ) -> jnp.ndarray:
     """Causal softmax attention for prefill.  Returns [B, S, n_heads, d].
+
+    ``window`` > 0: a sliding layer, position ``i`` sees the ``window``
+    positions up to itself (``max(0, i - window + 1) .. i``).
 
     ``q_offset`` supports chunked prefill: queries at absolute positions
     offset+i attend to cached keys 0..offset+i (keys here are the chunk only
@@ -58,6 +62,8 @@ def causal_attention(
         q_pos = q_pos + q_offset[:, None]                # [B, S]
     k_pos = jnp.arange(s_k)[None, :]                     # [1, S_k]
     causal = q_pos[:, :, None] >= k_pos[:, None, :]      # [B, S, S_k]
+    if window:
+        causal &= q_pos[:, :, None] - k_pos[:, None, :] < window
     valid = k_pos[:, None, :] < seq_lens[:, None, None]  # [B, 1->S, S_k]
     mask = (causal & valid)[:, None, :, :]               # [B, 1, S, S_k]
 
@@ -73,12 +79,14 @@ def decode_attention(
     k_cache: jnp.ndarray,    # [B, S_max, n_kv, d]
     v_cache: jnp.ndarray,    # [B, S_max, n_kv, d]
     lengths: jnp.ndarray,    # [B] tokens valid in cache (incl. current)
+    starts: jnp.ndarray | None = None,   # [B] first position seen
 ) -> jnp.ndarray:
     """Single-step decode attention over the slot cache.  [B, 1, n_heads, d].
 
     The T=1 case of ``decode_attention_multi`` (delegated so the two paths
-    cannot drift numerically)."""
-    return decode_attention_multi(q, k_cache, v_cache, lengths)
+    cannot drift numerically).  ``starts``: a window layer's query sees
+    cache positions ``starts[b] .. lengths[b] - 1``."""
+    return decode_attention_multi(q, k_cache, v_cache, lengths, starts)
 
 
 def decode_attention_multi(
@@ -86,6 +94,7 @@ def decode_attention_multi(
     k_cache: jnp.ndarray,    # [B, S_max, n_kv, d]
     v_cache: jnp.ndarray,    # [B, S_max, n_kv, d]
     lengths: jnp.ndarray,    # [B] tokens valid incl. the FIRST query token
+    starts: jnp.ndarray | None = None,   # [B] first position seen
 ) -> jnp.ndarray:
     """Multi-token decode attention (speculative verification): query i of
     slot b attends to cache positions < lengths[b] + i.  [B, T, n_heads, d].
@@ -102,6 +111,8 @@ def decode_attention_multi(
     k_pos = jnp.arange(s_max)[None, None, :]              # [1, 1, S]
     limit = lengths[:, None, None] + jnp.arange(t)[None, :, None]  # [B, T, 1]
     mask = (k_pos < limit)[:, None]                       # [B, 1, T, S]
+    if starts is not None:
+        mask &= (k_pos >= starts[:, None, None])[:, None]
     logits = jnp.where(mask, logits, NEG_INF)
     probs = jnp.exp(logits - jnp.max(logits, axis=-1, keepdims=True))
     probs = probs / jnp.sum(probs, axis=-1, keepdims=True)

@@ -52,6 +52,15 @@ Layouts:
   live pages are fetched and masked out of the softmax.
 - ``lengths``: [B] valid kv tokens per sequence (including the current
   decode position); 0 for a slot that holds no sequence.
+- ``starts`` (optional): [B], the first table position each slot's query
+  sees, for a sliding-window layer: the table is then the slot's ring of
+  pages laid out from the page that holds the window's first position
+  (engine/paged.py::_ring_view), a handful of entries whatever the
+  context, so the walk is one block and the call's time does not grow
+  with the sequence; positions before ``starts[b]`` are masked as those
+  past ``lengths[b]`` are.  One more scalar-prefetch operand, and a name
+  of its own (``window_paged_attention``), so a trace tells a window
+  layer's calls from a full layer's.
 - quantized pools: int8 pages (or split-half nibble-packed int4) and one
   f32 scale a token, ``[L, n_pages, page_size]``, 1/256 of the pages'
   bytes.  A row of fewer than 128 lanes cannot be sliced out of an HBM
@@ -172,13 +181,14 @@ def _paged_kernel(
     layer_ref,          # SMEM [1]
     lengths_ref,        # SMEM [B]
     tables_ref,         # SMEM [B, pages_per_seq]
-    q_ref,              # VMEM [1, n_heads, KV]  (block-diagonal expanded)
-    *refs,
+    *refs,              # [starts SMEM [B] where ``windowed``,] then
+                        # q VMEM [1, n_heads, KV] (block-diagonal expanded)
     page_size: int,
     head_dim: int,
     n_block: int,
     quant: bool,
     packed: bool,
+    windowed: bool = False,
 ):
     """One slot a grid step: loop over the blocks of ``n_block``
     table entries that hold live context, copying the next block's pages
@@ -194,7 +204,17 @@ def _paged_kernel(
     Quantized pages are int8 (or split-half nibble-packed int4) with one
     scale per token.  The scales never touch the [T, KV] operands: the k
     scale multiplies the [n_heads, T] score columns and the v scale
-    folds into the softmax weights."""
+    folds into the softmax weights.
+
+    ``windowed``: one more scalar-prefetch operand, ``starts`` [B], the
+    first table position each slot's query sees; the head of the first
+    block before it is masked as the tail of the last one past the length
+    is."""
+    start = None
+    if windowed:
+        starts_ref, *refs = refs
+        start = starts_ref[pl.program_id(0)]
+    q_ref, *refs = refs
     n_pools = 4 if quant else 2
     pools, o_ref = refs[:n_pools], refs[n_pools]
     bufs = refs[n_pools + 1:2 * n_pools + 1]
@@ -272,7 +292,10 @@ def _paged_kernel(
             p_scale = _scale_row(bufs[3], slot, pids, page_size)
         k_pos = (jax.lax.broadcasted_iota(
             jnp.int32, (n_heads, block_tokens), 1) + blk * block_tokens)
-        s = jnp.where(k_pos < length, s, NEG_INF)
+        live = k_pos < length
+        if windowed:
+            live &= k_pos >= start
+        s = jnp.where(live, s, NEG_INF)
         _flash_accumulate(s, unpack(bufs[1], slot), acc_ref, m_ref, l_ref,
                           p_scale=p_scale)
         return carry
@@ -319,7 +342,7 @@ def _lane_dense_scales(scales: jnp.ndarray) -> jnp.ndarray:
 
 
 def _paged_call(name, q, pools, layer, lengths, block_tables, *, packed,
-                interpret):
+                interpret, starts=None):
     """The one ``pallas_call`` both pools' kernels are.  ``pools`` are the
     k and v pages and, for a quantized pool, the two scale pools: with
     ``layer`` None one layer's ([n_pages, page, KV'] and
@@ -327,7 +350,9 @@ def _paged_call(name, q, pools, layer, lengths, block_tables, *, packed,
     the engine's pool keeps them, of which the kernel reads layer
     ``layer`` (an int or a traced scalar) where it lies.  The pages are
     handed over whole and by reference; of the scales, 1/256 of the
-    bytes, the layer is sliced out here for its lane-dense rows."""
+    bytes, the layer is sliced out here for its lane-dense rows.
+    ``starts`` [B] makes it the window layers' call: each slot's query
+    sees table positions ``starts[b] .. lengths[b] - 1``."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if layer is None:
@@ -349,15 +374,20 @@ def _paged_call(name, q, pools, layer, lengths, block_tables, *, packed,
     buffers += [pltpu.VMEM((2, n_block, 1, s.shape[-1]), s.dtype)
                 for s in scales]
     slot_block = pl.BlockSpec((1, n_heads, kv_dim),
-                              lambda bi, layer, lens, tabs: (bi, 0, 0))
+                              lambda bi, *scalars: (bi, 0, 0))
+    windowed = starts is not None
+    scalars = (jnp.asarray(layer, jnp.int32).reshape(1),
+               lengths.astype(jnp.int32), block_tables.astype(jnp.int32))
+    if windowed:
+        scalars += (starts.astype(jnp.int32),)
 
     out = pl.pallas_call(
         functools.partial(_paged_kernel, page_size=page_size, head_dim=d,
                           n_block=n_block, quant=bool(scales),
-                          packed=packed),
+                          packed=packed, windowed=windowed),
         name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(scalars),
             grid=(b,),
             in_specs=[slot_block] + [pl.BlockSpec(memory_space=pl.ANY)
                                      for _ in (*pages, *scales)],
@@ -374,12 +404,7 @@ def _paged_call(name, q, pools, layer, lengths, block_tables, *, packed,
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
-    )(
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        lengths.astype(jnp.int32),
-        block_tables.astype(jnp.int32),
-        _expand_block_diag(q, n_kv), *pages, *scales,
-    )
+    )(*scalars, _expand_block_diag(q, n_kv), *pages, *scales)
     return _extract_block_diag(out, n_kv, d)
 
 
@@ -393,14 +418,20 @@ def paged_attention(
     *,
     layer=None,
     interpret: bool | None = None,
+    starts: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Single-step decode attention over a paged KV pool: [B, n_heads, d].
     With ``layer`` the pools are every layer's pages
     [L, n_pages, page_size, n_kv*d] and the kernel reads that layer of
-    them in place; without, one layer's."""
-    return _paged_call("paged_attention", q, (k_pages, v_pages), layer,
-                       lengths, block_tables, packed=False,
-                       interpret=interpret)
+    them in place; without, one layer's.  With ``starts`` [B] it is a
+    window layer's call (``window_paged_attention``): the query of slot
+    ``b`` sees the table's positions ``starts[b] .. lengths[b] - 1``, the
+    table being the slot's ring laid out from the page that holds the
+    window's first position (engine/paged.py::_ring_view)."""
+    return _paged_call("paged_attention" if starts is None
+                       else "window_paged_attention", q, (k_pages, v_pages),
+                       layer, lengths, block_tables, packed=False,
+                       interpret=interpret, starts=starts)
 
 
 @functools.partial(jax.jit, static_argnames=("packed", "interpret"))
@@ -416,15 +447,18 @@ def paged_attention_quant(
     layer=None,
     packed: bool = False,
     interpret: bool | None = None,
+    starts: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Decode attention over a QUANTIZED paged pool (int8, or split-half
-    nibble-packed int4 when ``packed``): [B, n_heads, d].  ``layer`` as
-    in ``paged_attention``: the four pools then carry a leading layer
-    axis."""
-    return _paged_call("paged_attention_quant", q,
+    nibble-packed int4 when ``packed``): [B, n_heads, d].  ``layer`` and
+    ``starts`` as in ``paged_attention``: the four pools then carry a
+    leading layer axis, and the window layers' call is
+    ``window_paged_attention_quant``."""
+    return _paged_call("paged_attention_quant" if starts is None
+                       else "window_paged_attention_quant", q,
                        (k_pages, v_pages, k_scales, v_scales), layer,
                        lengths, block_tables, packed=packed,
-                       interpret=interpret)
+                       interpret=interpret, starts=starts)
 
 
 def _validate_head_shard(n_heads: int, n_kv: int, n_tp: int) -> None:
@@ -537,11 +571,12 @@ def paged_attention_xla(
     v_pages: jnp.ndarray,
     lengths: jnp.ndarray,
     block_tables: jnp.ndarray,
+    starts: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Pure-XLA reference implementation (gather + masked softmax).
 
     Ground truth for the kernel's unit tests and the fallback for
-    platforms without Mosaic.
+    platforms without Mosaic.  ``starts`` as in ``paged_attention``.
     """
     b, n_heads, d = q.shape
     _, page_size, kv_dim = k_pages.shape
@@ -562,7 +597,10 @@ def paged_attention_xla(
 
     s = jnp.einsum("bhd,bkhd->bhk", qf, k)
     k_pos = jnp.arange(k.shape[1])[None, None, :]
-    s = jnp.where(k_pos < lengths[:, None, None], s, NEG_INF)
+    live = k_pos < lengths[:, None, None]
+    if starts is not None:
+        live &= k_pos >= starts[:, None, None]
+    s = jnp.where(live, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhk,bkhd->bhd", p, v)
     return out.astype(q.dtype)

@@ -173,6 +173,25 @@ def unused_rules(rules: RuleTable, tree: PyTree) -> List[str]:
 # rules precede the dense MLP rules that would otherwise catch w_gate/w_up.
 # ---------------------------------------------------------------------------
 
+def refuse_per_layer_kinds(cfg, what: str) -> None:
+    """The rule table and the template below are the UNIFORM Llama
+    block's.  A model whose layers differ in kind, or that has the leaves
+    that come with them (query/key norms, a router's selection bias, a
+    shared expert), has no rule here, and no mesh serves it (the engine
+    asks here before it takes one): the window layers' ring of pages per
+    slot has no sharding rule either (engine/paged.py refuses a mesh for
+    it by name)."""
+    if (getattr(cfg, "mixed_layers", False) or getattr(cfg, "qk_norm", False)
+            or (cfg.n_experts > 0 and (cfg.shared_expert_size
+                                       or cfg.router_kind != "softmax"))):
+        raise ValueError(
+            f"{what} (runtime/rules.py) is not built for {cfg.name!r}: its "
+            f"Llama block has per-layer kinds or leaves (attn_layer_types, "
+            f"n_dense_layers, qk_norm, a sigmoid router's bias, a shared "
+            f"expert) that no sharding rule covers, and a mesh over the "
+            f"window layers' ring is not built")
+
+
 def llama_rules(cfg, layout: Optional[SpecLayout] = None) -> RuleTable:
     """Rule table for models/llama.init_params (dense AND MoE/mixtral —
     ``cfg.n_experts > 0`` prepends the stacked-expert rules).
@@ -185,6 +204,7 @@ def llama_rules(cfg, layout: Optional[SpecLayout] = None) -> RuleTable:
     vocab for embedding/lm_head) along the fsdp axis — GSPMD all-gathers
     on use, which is what makes greedy parity hold byte-identically.
     """
+    refuse_per_layer_kinds(cfg, "the Llama rule table (llama_rules)")
     lo = layout or TP_LAYOUT
     f, t, e = lo.fsdp, lo.tp, lo.ep
     rules: RuleTable = []
@@ -237,6 +257,8 @@ def encoder_rules(cfg=None, layout: Optional[SpecLayout] = None) -> RuleTable:
 def llama_param_template(cfg) -> Dict[str, Any]:
     """ShapeDtypeStruct pytree with the exact structure/shapes of
     models/llama.init_params (models/llama.py:89-158)."""
+    refuse_per_layer_kinds(cfg, "the Llama parameter template "
+                            "(llama_param_template)")
     dt = jnp.dtype(cfg.dtype)
     h, q, kv, inter = (cfg.hidden_size, cfg.q_dim, cfg.kv_dim,
                        cfg.intermediate_size)

@@ -109,6 +109,20 @@ class TestAttentionKernelsCompileForV5e:
             chip((1, 512, H, D), BF16), kv, kv, chip((1,), I32))
 
 
+    def test_flash_attention_lean_in_large_blocks(self, chip):
+        """The full layers' prefill call of a model with window layers
+        (K-EXAONE's 64 / 8 heads, one 6144-position row): the lean form in
+        ``llama.FLASH_LEAN_BLOCK`` blocks fits Mosaic's VMEM."""
+        from k8s_llm_rca_tpu.models import llama
+
+        kv = chip((1, 6144, 8, D), BF16)
+        _compiles_with_kernel(
+            functools.partial(flash_attention, interpret=False, lean=True,
+                              block_q=llama.FLASH_LEAN_BLOCK,
+                              block_k=llama.FLASH_LEAN_BLOCK),
+            chip((1, 6144, 64, D), BF16), kv, kv, chip((1,), I32))
+
+
 class TestDecodeStepWritesThePoolInPlace:
     """The stepwise decode program at Mistral-7B widths (2 layers of the
     32, int8 weights, the int8 pool of ``mistral7b.chat-open``: 3,072
@@ -216,6 +230,69 @@ class TestDecodeStepUpdatesTheStateInPlace:
         pages_bytes = 2 * self.N_PAGES * self.PAGE * cfg.kv_dim * 2
         assert mem.alias_size_in_bytes >= state_bytes + pages_bytes
         assert mem.temp_size_in_bytes < state_bytes // 10
+
+
+class TestDecodeStepWritesTheRingInPlace:
+    """The stepwise decode program of ``k-exaone-d5.longdump-reason`` at the
+    configuration's own widths (2 of its 5 layers: the dense sliding layer
+    and the full one, so both kinds of cache; 64 slots, the cell's pool of
+    32,768 pages and its rings of 9 pages a slot).  A window layer's rings
+    are 18.9 MB over the slots and a full layer's pages 1.07 GB: a step that
+    sliced either out, wrote its token and set it back would copy it twice
+    (PR 28's lesson).  Seen here without a chip: both decode kernels stand in
+    the program under their own names, the compiled program holds no array
+    of one layer's ring or pages outside them, keeps ring and pages in the
+    buffers they were donated in, and needs less room for temporaries than
+    one layer's pages."""
+
+    SLOTS, N_PAGES, PAGE = 64, 32768, 16
+
+    def test_no_layer_of_the_ring_is_copied(self, chip, monkeypatch):
+        import re
+
+        from k8s_llm_rca_tpu.config import ModelConfig
+        from k8s_llm_rca_tpu.engine import paged
+        from k8s_llm_rca_tpu.models import llama
+
+        cfg = ModelConfig(
+            name="k-exaone-2-layers", vocab_size=19200, hidden_size=6144,
+            n_layers=2, n_heads=64, n_kv_heads=8, head_dim=128,
+            intermediate_size=18432, max_seq_len=8192, rope_theta=1e6,
+            dtype="bfloat16", tie_embeddings=False,
+            attn_layer_types=("sliding_attention", "full_attention"),
+            attn_window=128, n_dense_layers=2, qk_norm=True,
+            rope_full_layers=False)
+        params = _described(chip, jax.eval_shape(
+            lambda: llama.init_params(cfg, jax.random.PRNGKey(0))))
+        pool = _described(chip, jax.eval_shape(
+            lambda: paged.init_paged_cache(cfg, self.N_PAGES, self.PAGE,
+                                           n_slots=self.SLOTS)))
+        ring_pages = self.SLOTS * cfg.ring_pages(self.PAGE)
+        assert pool.ring.k.shape == (1, ring_pages, self.PAGE, cfg.kv_dim)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = jax.jit(
+            paged.paged_decode_step, static_argnums=0, donate_argnums=2,
+            static_argnames="use_kernel").lower(
+                cfg, params, pool, chip((self.SLOTS,), I32),
+                chip((self.SLOTS,), I32),
+                chip((self.SLOTS, cfg.max_seq_len // self.PAGE), I32),
+                use_kernel=True).compile()
+        text = compiled.as_text()
+        assert "window_paged_attention" in text
+        assert re.search(r'(?<!window_)paged_attention', text)
+
+        # no array of one layer's ring or pages, with or without its unit
+        # axis, is copied
+        for n_pages in (ring_pages, self.N_PAGES):
+            shape = rf"bf16\[(1,)?{n_pages},{self.PAGE},{cfg.kv_dim}\]"
+            copies = [line for line in text.splitlines()
+                      if re.search(rf"= {shape}\S* copy\(", line)]
+            assert not copies, copies[:2]
+        mem = compiled.memory_analysis()
+        layer_bytes = 2 * self.PAGE * cfg.kv_dim * 2       # k and v a page
+        assert mem.alias_size_in_bytes >= (ring_pages + self.N_PAGES) \
+            * layer_bytes
+        assert mem.temp_size_in_bytes < self.N_PAGES * layer_bytes // 2
 
 
 class TestPrefillRoutesEachTokenToItsExperts:
