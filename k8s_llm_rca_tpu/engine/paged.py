@@ -276,7 +276,11 @@ class PagePool(NamedTuple):
     kernel - 1, conv_dim] (the convolution's last inputs, channels on the
     lane axis: the published ``[conv_dim, kernel - 1]`` would pad 3 lanes
     to 128), and ``moe_local_pairs`` [1] int32, the running count of
-    (position, expert) pairs whose expert is held here, wrapping.  They
+    (position, expert) pairs whose expert is held here, wrapping; where a
+    share of the router's experts is held, ``moe_compact_overflows`` [1]
+    int32 beside it, the running count of grouped expert-layer calls
+    whose local pairs ran over the compact form's rows
+    (``llama.moe_compact_rows``).  They
     ride in the pool because the pool is what every program is given,
     donates and hands back: the state is updated in place on the device
     and never crosses to the host in a tick.
@@ -302,6 +306,7 @@ class PagePool(NamedTuple):
     conv_state: Optional[jnp.ndarray] = None
     moe_local_pairs: Optional[jnp.ndarray] = None
     ring: Optional["PagePool"] = None
+    moe_compact_overflows: Optional[jnp.ndarray] = None
 
     @property
     def page_size(self) -> int:
@@ -310,6 +315,29 @@ class PagePool(NamedTuple):
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
+
+
+def _moe_counts(cfg: ModelConfig) -> dict:
+    """The device's running counts a pool with something per slot keeps
+    for a model with experts: the local pairs, and where a share of the
+    router's experts is held the compact form's overflows."""
+    if not cfg.n_experts:
+        return {}
+    zero = lambda: jnp.zeros((1,), jnp.int32)
+    if cfg.n_router == cfg.n_experts:
+        return dict(moe_local_pairs=zero())
+    return dict(moe_local_pairs=zero(), moe_compact_overflows=zero())
+
+
+def _add_moe_counts(pool: PagePool, n_local, n_over) -> PagePool:
+    """A prefill's local pairs and compact-form overflows onto the pool's
+    running counts, where it keeps them."""
+    if pool.moe_local_pairs is not None:
+        pool = pool._replace(moe_local_pairs=pool.moe_local_pairs + n_local)
+    if pool.moe_compact_overflows is not None:
+        pool = pool._replace(
+            moe_compact_overflows=pool.moe_compact_overflows + n_over)
+    return pool
 
 
 def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
@@ -331,8 +359,7 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
             ring=init_paged_cache(
                 cfg.replace(n_layers=cfg.n_window_layers, **alike),
                 n_slots * cfg.ring_pages(page_size), page_size, kv_dtype),
-            moe_local_pairs=(jnp.zeros((1,), jnp.int32) if cfg.n_experts
-                             else None))
+            **_moe_counts(cfg))
     if cfg.n_ssm_layers:
         if n_slots <= 0:
             raise ValueError(
@@ -349,7 +376,7 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
             conv_state=jnp.zeros(
                 (*lead, cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim),
                 jnp.dtype(cfg.dtype)),
-            moe_local_pairs=jnp.zeros((1,), jnp.int32))
+            **_moe_counts(cfg))
     shape = (cfg.n_kv_layers, n_pages, page_size, cfg.kv_dim)
     if isinstance(kv_dtype, str) and kv_dtype == "int4":
         assert cfg.kv_dim % 2 == 0
@@ -479,7 +506,7 @@ def _prefill_rows_per_slot(cfg: ModelConfig, params, pool: PagePool,
     it held (a model with Mamba-2 layers: the recurrent state left at the
     row's true length and the convolution's tail; one with sliding-window
     layers: the row's tail into the slot's ring), and the local expert
-    pairs onto the pool's count."""
+    pairs and the compact form's overflows onto the pool's counts."""
     if slots is None:
         kept = ("Mamba-2 layers needs the decode slot of each row (slots=) "
                 "to write its state to" if cfg.layer_pattern else
@@ -489,34 +516,32 @@ def _prefill_rows_per_slot(cfg: ModelConfig, params, pool: PagePool,
     n, s_pad = tokens.shape
     page_size = pool.page_size
     if cfg.layer_pattern:
-        new_k, new_v, state, conv_tail, logits, n_local = \
+        new_k, new_v, state, conv_tail, logits, n_local, n_over = \
             nemotron_h.prefill_rows(cfg, params, tokens, lengths, use_flash)
     else:
         lengths = lengths.astype(jnp.int32)
         tail_starts, tail, ring_map = _ring_tail(
             cfg, lengths, slots.astype(jnp.int32), s_pad, page_size)
-        new_k, new_v, ring_k, ring_v, logits, n_local = llama.prefill_rows(
-            cfg, params, tokens, lengths, tail_starts, tail, use_flash,
-            expert_kernel)
+        new_k, new_v, ring_k, ring_v, logits, n_local, n_over = \
+            llama.prefill_rows(cfg, params, tokens, lengths, tail_starts,
+                               tail, use_flash, expert_kernel)
     pool = _write_pool_pages(
         cfg, pool, new_k.reshape(cfg.n_kv_layers, n * s_pad, cfg.kv_dim),
         new_v.reshape(cfg.n_kv_layers, n * s_pad, cfg.kv_dim),
         page_maps.reshape(-1), n * (s_pad // page_size), page_size)
     if cfg.layer_pattern:
-        return pool._replace(
+        return _add_moe_counts(pool._replace(
             ssm_state=pool.ssm_state.at[:, slots].set(
                 state.astype(pool.ssm_state.dtype)),
             conv_state=pool.conv_state.at[:, slots].set(
-                conv_tail.astype(pool.conv_state.dtype)),
-            moe_local_pairs=pool.moe_local_pairs + n_local), logits
+                conv_tail.astype(pool.conv_state.dtype))),
+            n_local, n_over), logits
     pool = pool._replace(ring=_write_pool_pages(
         cfg, pool.ring,
         ring_k.reshape(cfg.n_window_layers, n * tail, cfg.kv_dim),
         ring_v.reshape(cfg.n_window_layers, n * tail, cfg.kv_dim),
         ring_map.reshape(-1), n * (tail // page_size), page_size))
-    if pool.moe_local_pairs is not None:
-        pool = pool._replace(moe_local_pairs=pool.moe_local_pairs + n_local)
-    return pool, logits
+    return _add_moe_counts(pool, n_local, n_over), logits
 
 
 def paged_prefill(cfg: ModelConfig, params, pool: PagePool,
@@ -1515,11 +1540,12 @@ class PagedInferenceEngine(EngineBase):
             kv_dtype=engine_cfg.kv_cache_dtype, n_slots=b)
         # bytes of recurrent state the slots hold (0 for a model whose
         # past is its pages), and the host's copy of the device's
-        # running count of local expert pairs
+        # running counts of local expert pairs and compact-form overflows
         self._state_bytes = sum(
             a.nbytes for a in (self.pool.ssm_state, self.pool.conv_state)
             if a is not None)
-        self._moe_pairs_seen = 0
+        self._moe_seen = {"engine.moe_local_pairs": 0,
+                          "engine.moe_compact_overflows": 0}
         METRICS.gauge("engine.state_bytes", self._state_bytes)
         # bytes one page holds in one layer (scales included), and one
         # slot's ring in all the window layers: what the two cache gauges
@@ -1946,6 +1972,12 @@ class PagedInferenceEngine(EngineBase):
         self._count("engine.prefill_padded_tokens", n_positions)
         if self._moe_in_model and llama.moe_grouped(cfg, per_call):
             self._count("engine.moe_grouped_tokens", n_positions)
+            if (self.pool.moe_compact_overflows is not None
+                    and llama.moe_compact_rows(cfg, per_call)):
+                # expert-layer calls that hold the compact form: how many
+                # of them ran over it is the device's to count
+                self._count("engine.moe_compact_calls",
+                            n_positions // per_call * self._expert_layers)
         if cfg.layer_pattern:
             self._count("engine.ssm_prefill_tokens",
                         n_positions * cfg.n_ssm_layers)
@@ -1965,11 +1997,15 @@ class PagedInferenceEngine(EngineBase):
         counts the local ones; ``engine.moe_local_pairs`` is the part
         whose expert is held here, counted on the device
         (``_note_local_pairs``)."""
-        cfg = self.model_cfg
-        expert_layers = (cfg.layer_pattern.count("E") if cfg.layer_pattern
-                         else cfg.n_layers - cfg.n_dense_layers)
         self._count("engine.moe_routed_pairs",
-                    n_positions * cfg.n_experts_per_tok * expert_layers)
+                    n_positions * self.model_cfg.n_experts_per_tok
+                    * self._expert_layers)
+
+    @property
+    def _expert_layers(self) -> int:
+        cfg = self.model_cfg
+        return (cfg.layer_pattern.count("E") if cfg.layer_pattern
+                else cfg.n_layers - cfg.n_dense_layers)
 
     def _count_moe_fused(self, steps: int, per_step: int = 1) -> None:
         """``engine.moe_fused_steps`` beside ``engine.decode_steps``: the
@@ -2000,20 +2036,21 @@ class PagedInferenceEngine(EngineBase):
         self._count_routed_pairs(b * steps)
 
     def _local_pairs(self) -> tuple:
-        """The device's running count of local expert pairs, for the
-        tick's one coalesced fetch to bring along (nothing for a model
-        without one)."""
-        n = self.pool.moe_local_pairs
-        return () if n is None else (n,)
+        """The device's running counts of local expert pairs and of the
+        compact form's overflows, for the tick's one coalesced fetch to
+        bring along (nothing for a model without them)."""
+        return tuple(n for n in (self.pool.moe_local_pairs,
+                                 self.pool.moe_compact_overflows)
+                     if n is not None)
 
     def _note_local_pairs(self, fetched) -> None:
-        """``engine.moe_local_pairs`` from what ``_local_pairs`` fetched:
-        the count since the last fetch (it wraps at 2**32)."""
-        for n in fetched:
+        """``engine.moe_local_pairs`` and ``engine.moe_compact_overflows``
+        from what ``_local_pairs`` fetched: the counts since the last
+        fetch (they wrap at 2**32)."""
+        for name, n in zip(self._moe_seen, fetched):
             now = int(n[0]) & 0xFFFFFFFF
-            self._count("engine.moe_local_pairs",
-                        (now - self._moe_pairs_seen) & 0xFFFFFFFF)
-            self._moe_pairs_seen = now
+            self._count(name, (now - self._moe_seen[name]) & 0xFFFFFFFF)
+            self._moe_seen[name] = now
 
     def _slots_kw(self, slots) -> dict:
         """The ``slots=`` a prefill of a model with a state or a ring per

@@ -321,7 +321,7 @@ MOE_GROUPED_MIN_ROWS_PER_EXPERT = 384
 #     32         1.4        3.15     3.10       5.26       (a decode call)
 #     64         2.75       3.03     3.92       6.03       (the cell's decode call)
 #    256        11          3.11     6.18       8.48
-#   1024        44          9.76     9.10      12.71      <- grouped wins from here
+#   1024        44          9.76     9.10      12.71      <- grouped won from here
 #   2048        88         18.06    14.37      21.26
 #   4096       176         35.12    20.72      32.49
 #  16384       704           -      60.53     101.68      (4 x 4096)
@@ -329,10 +329,24 @@ MOE_GROUPED_MIN_ROWS_PER_EXPERT = 384
 # Nothing is dequantized, so the grouped form has no fixed cost to win back;
 # up to 256 positions the dense form is flat at 3 ms (it streams the 0.7 GB
 # of held experts through the MXU whatever the rows) and the grouped form
-# pays its sort and two row gathers.  The grouped kernel skips part of the
-# rows behind the last group (a quarter of the picks is local, and it costs
-# 0.6 of the all-local call, not 0.25): PERF.md section 7.
-MOE_GROUPED_MIN_ROWS_PER_EXPERT_LATENT = 44
+# pays its sort and two row gathers.  That grouped form cost 0.6 of its
+# all-local call for a quarter of the rows, and PR 38 found why: XLA's own
+# tiles for 2,688 columns (``_grouped_matmul_tiles``), not the rows behind
+# the last group, which the kernel skips whole.  The routed experts alone
+# (``_experts``; my chip run, PR 38; ms a call: dense | grouped as it was |
+# the whole form at fitted tiles | the compact form before it,
+# ``moe_compact_rows`` at a slack of 2):
+#      T    rows/expert    dense    was     whole   compact
+#    256        11          2.08    7.05     2.28     2.29
+#    512        22          4.00    7.55     2.57     2.46   <- grouped wins from here
+#   1024        44          7.63    8.83     3.23     2.98
+#   2048        88           -     10.51     4.55     4.22
+#   3072       132           -     15.74     7.13     5.79
+#   4096       176           -     17.93     8.58     7.51
+# (every pick local, 3,072: 23.67 as it was, 10.42 now; a call that runs
+# over the compact form: 10.46).  No bucket of a cell lies between the old
+# threshold and the new.
+MOE_GROUPED_MIN_ROWS_PER_EXPERT_LATENT = 22
 
 
 # The same threshold for fine-grained bf16 SwiGLU experts (their own width,
@@ -356,7 +370,20 @@ MOE_GROUPED_MIN_ROWS_PER_EXPERT_LATENT = 44
 # its gathers of T x 8 rows; from 512 the dense form computes 16 experts on
 # every position for the one pick in eight that is local.  Int4 experts of
 # this kind would need a row of their own (the dequantization's fixed cost,
-# as in the first table).
+# as in the first table).  The routed experts alone with the compact form
+# (my chip run, PR 38; ms a call: dense | the whole form | the compact
+# form before it, ``moe_compact_rows`` at a slack of 2):
+#      T    rows/expert    dense    whole   compact
+#    512        32          3.85     4.00     4.12   (the threshold stays)
+#   2048       128           -       6.67     5.96
+#   3072       192           -       8.54     8.02
+#   4096       256           -      10.34     9.43
+#   6144       384           -      14.01    13.21
+# Here the tiles divide and were the measured ones already; what the
+# compact form saves is the gather of 6,144-wide rows for picks that are
+# nobody's here (4.4 ms of the 14 at 6,144), and what it pays is a
+# scatter-add of float32 rows as wide (4.5 ms where the whole form's
+# gather back and weighted sum take 2.1).
 MOE_GROUPED_MIN_ROWS_PER_EXPERT_FINE = 32
 
 
@@ -516,7 +543,9 @@ def _experts(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
 
     - a large call (prefill, training) is **token-grouped**
       (``_moe_experts_grouped``): each token's row goes to its experts
-      only, so the expert arithmetic is k/E of the dense form's;
+      only, so the expert arithmetic is k/E of the dense form's, and where
+      a share of the router's experts is held, to those held here only
+      (the compact form, ``moe_compact_rows``);
     - a small call (decode) is **dense soft dispatch**: every expert held
       runs on every token and the router's weights zero out the rest — one
       einsum per projection, no sort, exactly equal to hard routing;
@@ -530,9 +559,13 @@ def _experts(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
       weights whole on one device); every other caller (training, the
       reference loops, the sharded paths) leaves it False.
 
-    All are lossless (no capacity, no dropped pair).  The bandwidth-
-    optimal EP dispatch (all_to_all over the "expert" axis) lives in
-    parallel/moe.py and is used by the sharded engine path.
+    All are lossless: no pair of an expert held here is dropped.  The one
+    capacity there is, the compact form's row count, sizes a buffer and
+    never drops: a call whose local pairs run over it takes the whole
+    grouped form behind it and computes the same sum
+    (``n_compact_overflows`` counts such calls).  The bandwidth-optimal EP
+    dispatch (all_to_all over the "expert" axis) lives in parallel/moe.py
+    and is used by the sharded engine path.
     """
     b, s, h = x.shape
     e = cfg.n_experts
@@ -572,22 +605,115 @@ def _experts(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
 # over; 256 x 2048 x 1024 is 4-5% better at 2048 and 4096 and worse above.
 _GROUPED_MATMUL_TILES = (512, 1024, 1024)
 
+# Where they do not divide (NVIDIA-Nemotron-3-Super's experts are 1024 ->
+# 2688 -> 1024, and 2688 is 21 x 128) XLA's own choice falls to a 128-wide
+# tile of the dimension that 512 does not divide (512 x 512 x 128 up,
+# 512 x 128 x 512 down).  The tiles taken then are the largest multiples
+# of 128 that divide, within these bounds (the row tile, a side of the
+# weight tile, its elements): what was best of what the kernel's VMEM
+# admitted on one TPU v5e (my chip run, PR 38; 25,600 rows of which
+# 16,064 in 128 groups, ms a call, up | down; down's tiles mirror up's):
+#   XLA's own                   6.80 | 4.53
+#   128 x 1024 x 896            1.86 | 2.14
+#   256 x 1024 x 896            1.78 | 1.86
+#   256 x 512 x 2688            1.79 | 1.62
+#   256 x 1024 x 2688           1.64 | 1.55    <- taken
+#   512 x 1024 x 896            2.45 | 2.49
+#   512 x 1024 x 2688           refused: out of VMEM
+# The same times at 67,584 rows with the same 16,064 in groups: the kernel
+# skips the rows behind the last group whole, and what it costs is set by
+# the groups it visits, a row tile each at the least.
+_GROUPED_MATMUL_FITTED = (256, 2688, 1024 * 2688)
+
+
+def _grouped_matmul_tiles(m: int, k: int, n: int) -> Optional[Tuple]:
+    """The tiles ``_grouped_matmul`` asks of XLA's kernel for rows ``[m,
+    k]`` against weights ``[E, k, n]``: the measured ones where they
+    divide; for weights they do not divide the largest multiples of 128
+    that do, within the bounds measured with them (row tile, a side of
+    the weight tile, its elements); else None, XLA's own choice."""
+    tile_m, tile_k, tile_n = _GROUPED_MATMUL_TILES
+    if k % tile_k == 0 and n % tile_n == 0:
+        return None if m % tile_m else _GROUPED_MATMUL_TILES
+
+    def fit(dim, most):
+        return max((t for t in range(128, min(dim, most) + 1, 128)
+                    if dim % t == 0), default=0)
+
+    rows, side, weight = _GROUPED_MATMUL_FITTED
+    tile_m, tile_k = fit(m, rows), fit(k, side)
+    tile_n = tile_k and fit(n, min(side, weight // tile_k))
+    return (tile_m, tile_k, tile_n) if tile_m and tile_n else None
+
 
 def _grouped_matmul(rows: jnp.ndarray, w: jnp.ndarray,
                     group_sizes: jnp.ndarray) -> jnp.ndarray:
     """``rows[g_e : g_e+1] @ w[e]`` for every expert ``e``: rows ``[M, K]``
     sorted by expert, ``w`` ``[E, K, N]``, ``group_sizes`` ``[E]``.  On a
     TPU ``jax.lax.ragged_dot`` is XLA's own grouped-matmul kernel, which
-    reads its tiles from the operation's ``ragged_dot_tiling`` attribute;
-    a shape the tiles do not divide keeps XLA's choice (its row tile is
-    the largest divisor of ``M`` up to 512).  Elsewhere the attribute is
-    ignored."""
-    dims = (rows.shape[0], rows.shape[1], w.shape[-1])
-    if any(d % t for d, t in zip(dims, _GROUPED_MATMUL_TILES)):
+    reads its tiles from the operation's ``ragged_dot_tiling`` attribute
+    (``_grouped_matmul_tiles``); a shape no multiple of 128 divides keeps
+    XLA's choice (its row tile is the largest divisor of ``M`` up to
+    512).  Elsewhere the attribute is ignored."""
+    tiles = _grouped_matmul_tiles(rows.shape[0], rows.shape[1], w.shape[-1])
+    if tiles is None:
         return jax.lax.ragged_dot(rows, w, group_sizes)
-    with set_xla_metadata(
-            ragged_dot_tiling=",".join(map(str, _GROUPED_MATMUL_TILES))):
+    with set_xla_metadata(ragged_dot_tiling=",".join(map(str, tiles))):
         return jax.lax.ragged_dot(rows, w, group_sizes)
+
+
+# Rows of the compact grouped form over the local pairs a uniform router
+# would send here (``pairs * n_experts / n_router``): room for a call whose
+# router favours the experts held, before the whole form has to take it.
+# Set from one call's local pairs over that number (one row of a bucket in
+# one expert layer; the two held-share cells' own weights at three seeds,
+# prompts as their mixes make them, pad positions and all; my chip run, PR
+# 38; 180 and 144 calls):
+#                                     least  median  9 in 10   most
+#   128 of 512 held, 22 picks          0.75    0.97    1.28    1.47
+#   16 of 128 held, 8 picks            0.42    1.37    1.73    1.98
+# A layer's share is its seeded selection bias's and hardly a prompt's (a
+# layer's calls lie within 0.1 of each other), so a layer that runs over
+# does so in every call: the slack is for the luckiest layer of a seed, and
+# the fewer experts are held the further it lies (a layer's mean: 0.79-1.40
+# and 0.50-1.75).  At 1.5 no call of the first kind and three in ten of
+# the second run over; at 2 none of either.  What the room costs (the
+# routed experts of one layer, ms a call, slack 1.5 | 2): 3,072 positions of
+# the first kind 5.16 | 5.79; 6,144 of the second 11.81 | 13.21, and a call
+# that runs over 34.7 where the whole form alone takes 31.3.  A ladder of
+# sizes chosen from the count would win back that tenth and is not built:
+# a third and fourth program a layer for 2% of a prefill.
+MOE_COMPACT_SLACK = 2.0
+
+
+def moe_compact_rows(cfg: ModelConfig, n_positions: int) -> Optional[int]:
+    """Rows of the compact form of ``_moe_experts_grouped`` for a call of
+    ``n_positions``, from what the call shows: its pairs, the share of the
+    router's experts held here, ``MOE_COMPACT_SLACK``, up to a whole row
+    tile of the grouped kernel.  None where such a call has no compact
+    form: it is not token-grouped, every expert is held, or the rows would
+    be all its pairs (a call so small that one tile covers it)."""
+    if cfg.n_router == cfg.n_experts or not moe_grouped(cfg, n_positions):
+        return None
+    pairs = n_positions * cfg.n_experts_per_tok
+    tile = _GROUPED_MATMUL_TILES[0]
+    local = pairs * cfg.n_experts / cfg.n_router * MOE_COMPACT_SLACK
+    rows = -(-math.ceil(local) // tile) * tile
+    return rows if rows < pairs else None
+
+
+def n_compact_overflows(cfg: ModelConfig, n_positions: int,
+                        local_pairs) -> jnp.ndarray:
+    """How many expert-layer calls of ``n_positions`` each, whose local
+    pairs ``local_pairs`` lists (``n_local_pairs``, a call an entry), ran
+    over the compact form of ``_moe_experts_grouped`` and took the whole
+    one, an int32 scalar: ``engine.moe_compact_overflows``.  0 where such
+    a call holds one form alone."""
+    rows = moe_compact_rows(cfg, n_positions)
+    if rows is None:
+        return jnp.int32(0)
+    return sum(((n > rows).astype(jnp.int32) for n in local_pairs),
+               jnp.int32(0))
 
 
 def _moe_experts_grouped(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
@@ -595,17 +721,28 @@ def _moe_experts_grouped(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
                          ) -> jnp.ndarray:
     """The expert MLPs of ``_experts`` on routed rows only.  The ``T * k``
     (token, expert) pairs are stable-sorted by expert, the tokens' rows
-    gathered in that order (``[T * k, H]``: a static shape), and the
-    projections run as grouped matmuls over the stacked expert weights
-    (``_grouped_matmul``: rows ``[g_e, g_e+1)`` meet expert ``e`` only).
-    ``group_sizes`` is counted from the data, so it is exact: every pair
-    of an expert held here is computed, whatever the spread (an expert no
-    token chose is an empty group).  ``topi`` numbers the experts held
-    (``_held``); a pair whose expert lives elsewhere carries the id one
-    past the last, sorts behind every group, belongs to none and is given
-    no weight.  The pairs go back to their
-    tokens by the inverse permutation and are summed under the router's
-    weights, as the dense form sums them.  ``ragged_dot`` has JVP and
+    gathered in that order, and the projections run as grouped matmuls
+    over the stacked expert weights (``_grouped_matmul``: rows ``[g_e,
+    g_e+1)`` meet expert ``e`` only).  ``group_sizes`` is counted from the
+    data, so it is exact: every pair of an expert held here is computed,
+    whatever the spread (an expert no token chose is an empty group).
+    ``topi`` numbers the experts held (``_held``); a pair whose expert
+    lives elsewhere carries the id one past the last, sorts behind every
+    group, belongs to none and is given no weight.
+
+    Two forms of the same sum.  The **whole** form gathers a row for every
+    pair (``[T * k, H]``), and the pairs go back to their tokens by the
+    inverse permutation and are summed under the router's weights, as the
+    dense form sums them.  Where a share of the router's experts is held,
+    the first ``n = sum(group_sizes)`` sorted pairs are exactly the local
+    ones, and the **compact** form takes a static ``moe_compact_rows``
+    prefix of the order instead: that many rows gathered, multiplied and
+    added to their tokens' rows under the router's weights (in float32,
+    cast once; a row at or past ``n`` is set to zero, never multiplied).
+    It is chosen on the device (``lax.cond``) while ``n`` fits; a call
+    whose router sends more here takes the whole form and costs what it
+    cost before: the capacity sizes a buffer and drops nothing.
+    ``ragged_dot``, the conditional and the scatter-add have JVP and
     transpose rules, so the path differentiates (engine/train.py)."""
     b, s, h = x.shape
     k = cfg.n_experts_per_tok
@@ -616,26 +753,54 @@ def _moe_experts_grouped(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
         group_sizes = jnp.bincount(
             expert, length=cfg.n_experts).astype(jnp.int32)
     else:
-        group_sizes = jnp.bincount(
-            expert, length=cfg.n_experts + 1)[:-1].astype(jnp.int32)
-    rows = x.reshape(b * s, h)[order // k]                         # [T*k,H]
-    if cfg.mlp_act == "relu2":
-        hid = jnp.square(jax.nn.relu(
-            _grouped_matmul(rows, dq(layer["w_up"]), group_sizes)))
-        out = _grouped_matmul(hid, dq(layer["w_down"]), group_sizes)
-    else:
+        # compared and summed: a bincount is a scatter-add of every pair
+        group_sizes = jnp.sum(
+            expert[:, None] == jnp.arange(cfg.n_experts, dtype=expert.dtype),
+            axis=0, dtype=jnp.int32)
+    x_rows = x.reshape(b * s, h)
+
+    def mlp(rows):
+        if cfg.mlp_act == "relu2":
+            hid = jnp.square(jax.nn.relu(
+                _grouped_matmul(rows, dq(layer["w_up"]), group_sizes)))
+            return _grouped_matmul(hid, dq(layer["w_down"]), group_sizes)
         gate = jax.nn.silu(
             _grouped_matmul(rows, dq(layer["w_gate"]), group_sizes))
         up = _grouped_matmul(rows, dq(layer["w_up"]), group_sizes)
-        out = _grouped_matmul(gate * up, dq(layer["w_down"]), group_sizes)
-    inverse = jnp.zeros_like(order).at[order].set(
-        jnp.arange(pairs, dtype=order.dtype))           # pair -> sorted row
-    per_pair = out[inverse].reshape(b, s, k, -1)
-    if cfg.n_router != cfg.n_experts:
-        # rows behind the last group are no expert's: whatever the grouped
-        # kernel left there is dropped, not multiplied by a zero weight
-        per_pair = jnp.where((topi < cfg.n_experts)[..., None], per_pair, 0)
-    return jnp.einsum("bskh,bsk->bsh", per_pair, weights.astype(x.dtype))
+        return _grouped_matmul(gate * up, dq(layer["w_down"]), group_sizes)
+
+    def whole():
+        out = mlp(x_rows[order // k])                              # [T*k,H]
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(pairs, dtype=order.dtype))       # pair -> sorted row
+        per_pair = out[inverse].reshape(b, s, k, -1)
+        if cfg.n_router != cfg.n_experts:
+            # rows behind the last group are no expert's: whatever the
+            # grouped kernel left there is dropped, not multiplied by a
+            # zero weight
+            per_pair = jnp.where((topi < cfg.n_experts)[..., None],
+                                 per_pair, 0)
+        return jnp.einsum("bskh,bsk->bsh", per_pair, weights.astype(x.dtype))
+
+    cap = moe_compact_rows(cfg, b * s)
+    if cap is None:
+        return whole()
+    n_local = jnp.sum(group_sizes)
+
+    def compact():
+        picked = order[:cap]                # the local pairs first, by expert
+        token = picked // k
+        out = mlp(x_rows[token])                                   # [cap,H]
+        weighted = jnp.where(
+            (jnp.arange(cap) < n_local)[:, None],
+            out.astype(jnp.float32)
+            * weights.reshape(pairs)[picked][:, None], 0)
+        summed = jnp.zeros((b * s, out.shape[-1]), jnp.float32).at[
+            token].add(weighted)
+        return summed.astype(jnp.result_type(out.dtype, x.dtype)).reshape(
+            b, s, -1)
+
+    return jax.lax.cond(n_local <= cap, compact, whole)
 
 
 def _sp_constrain(x: jnp.ndarray, sp_mesh) -> jnp.ndarray:
@@ -1216,7 +1381,8 @@ def prefill_rows(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     positions from ``tail_starts[row]`` (what its slot's ring keeps:
     engine/paged.py::_ring_tail says which).  Returns (k, v [Lf, N, S,
     kv_dim], window k, v [Lw, N, tail, kv_dim], logits [N, V] at each
-    row's last true token, the rows' local expert pairs, int32).
+    row's last true token, the rows' local expert pairs, and how many of
+    their expert-layer calls ran over the compact form, both int32).
     """
     s_pad = tokens.shape[1]
     angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
@@ -1248,11 +1414,12 @@ def prefill_rows(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
 
         return (stacked(full, 0, s_pad), stacked(full, 1, s_pad),
                 stacked(ring, 0, tail), stacked(ring, 1, tail),
-                _logits(cfg, params, last)[0, 0], sum(pairs, jnp.int32(0)))
+                _logits(cfg, params, last)[0, 0], sum(pairs, jnp.int32(0)),
+                n_compact_overflows(cfg, s_pad, pairs))
 
-    k, v, wk, wv, logits, n_local = jax.lax.map(
+    k, v, wk, wv, logits, n_local, n_over = jax.lax.map(
         one, (tokens, lengths.astype(jnp.int32),
               tail_starts.astype(jnp.int32)))
     return (jnp.moveaxis(k, 0, 1), jnp.moveaxis(v, 0, 1),
             jnp.moveaxis(wk, 0, 1), jnp.moveaxis(wv, 0, 1), logits,
-            jnp.sum(n_local))
+            jnp.sum(n_local), jnp.sum(n_over))

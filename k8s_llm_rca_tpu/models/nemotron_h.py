@@ -246,13 +246,14 @@ def _stack(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     """The layers over right-padded rows tokens [N, S]: the residual
     stream [N, S, H] and what each row leaves behind (keys and values of
     the attention layers [La, N, S, kv_dim], the Mamba layers' states
-    [Lm, N, ...], the local-pair count)."""
+    [Lm, N, ...], the local-pair count, and how many expert-layer calls
+    ran over the compact form)."""
     x = gather_rows(params["embedding"], tokens).astype(jnp.dtype(cfg.dtype))
     attention_fn = None
     if llama.prefill_uses_flash(use_flash, tokens.shape[1]):
         attention_fn = llama._flash_attention_fn(lengths, None)
     ks, vs, states, tails = [], [], [], []
-    n_local = jnp.int32(0)
+    pairs = []
     for kind, layer in zip(cfg.layer_pattern, params["layers"]):
         if kind == "M":
             x, state, tail = mamba_prefill(cfg, layer, x, lengths)
@@ -260,14 +261,15 @@ def _stack(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
             tails.append(tail)
         elif kind == "E":
             x, n = expert_layer(cfg, layer, x)
-            n_local = n_local + n
+            pairs.append(n)
         else:
             x, k, v = attention_prefill(cfg, layer, x, lengths,
                                         attention_fn)
             ks.append(k)
             vs.append(v)
     return x, (jnp.stack(ks), jnp.stack(vs), jnp.stack(states),
-               jnp.stack(tails), n_local)
+               jnp.stack(tails), sum(pairs, jnp.int32(0)),
+               llama.n_compact_overflows(cfg, tokens.size, pairs))
 
 
 def forward(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
@@ -286,19 +288,21 @@ def prefill_rows(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     temporaries stay one row's): tokens [N, S] right-padded, lengths [N] ->
     (k, v [La, N, S, kv_dim], ssm_state [Lm, N, heads, head_dim, N_state],
     conv_state [Lm, N, kernel - 1, conv_dim], logits [N, V] at each row's
-    last true token, local pairs int32).  The caller writes pages and the
-    slots' state (engine/paged.paged_prefill_batch)."""
+    last true token, local pairs and calls that ran over the compact form,
+    both int32).  The caller writes pages and the slots' state
+    (engine/paged.paged_prefill_batch)."""
 
     def one(row):
         toks, n = row
-        x, (k, v, state, tail, n_local) = _stack(
+        x, (k, v, state, tail, n_local, n_over) = _stack(
             cfg, params, toks[None], n[None], use_flash)
         last = jax.lax.dynamic_slice_in_dim(x, n - 1, 1, axis=1)
         logits = llama._logits(cfg, params, last)[0, 0]
-        return k[:, 0], v[:, 0], state[:, 0], tail[:, 0], logits, n_local
+        return (k[:, 0], v[:, 0], state[:, 0], tail[:, 0], logits, n_local,
+                n_over)
 
-    k, v, state, tail, logits, n_local = jax.lax.map(
+    k, v, state, tail, logits, n_local, n_over = jax.lax.map(
         one, (tokens, lengths.astype(jnp.int32)))
     return (jnp.moveaxis(k, 0, 1), jnp.moveaxis(v, 0, 1),
             jnp.moveaxis(state, 0, 1), jnp.moveaxis(tail, 0, 1), logits,
-            jnp.sum(n_local))
+            jnp.sum(n_local), jnp.sum(n_over))

@@ -11,8 +11,9 @@ expert MLPs, and reverses the exchange.
 With sufficient capacity this computes exactly the same function as
 models/llama._moe_mlp (tests assert parity), whose two one-chip forms
 (token-grouped matmuls for a large call, dense soft dispatch for a small
-one) have no capacity and drop nothing; under pressure this path drops
-overflow tokens like production MoE stacks do.
+one) drop nothing (the grouped form's one capacity, the rows of its
+compact form, falls back to every pick's row); under pressure this path
+drops overflow tokens like production MoE stacks do.
 """
 
 from __future__ import annotations

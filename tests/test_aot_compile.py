@@ -382,6 +382,83 @@ class TestPrefillRoutesEachTokenToItsExperts:
         assert f'ragged_dot_tiling="{tiling}"' in text
 
 
+class TestHeldShareCompactsItsExpertRows:
+    """One expert layer's routed experts as a prefill row of the two cells
+    that hold a share of their router's experts calls them, at the
+    configurations' own widths: ``nemotron3-super-d11.audit-report`` (128
+    of 512 squared-ReLU latent experts, 22 picks, a 3,072 bucket) and
+    ``k-exaone-d5.longdump-reason`` (16 of 128 SwiGLU experts, 8 picks, a
+    6,144 bucket).  Until PR 38 the grouped form gathered and multiplied a
+    row for every pick, three quarters and seven eighths of them nobody's
+    here.  Seen without a chip: the program keeps a conditional (XLA has
+    not flattened it into a select that would run both), its compact
+    branch's grouped matmuls have ``moe_compact_rows`` rows, a multiple of
+    the kernel's row tile, the whole form behind it has every pair's, and
+    the two share their temporaries."""
+
+    @pytest.mark.parametrize("name,positions,rows", [
+        pytest.param("nemotron", 3072, 33792, id="nemotron-3072"),
+        pytest.param("exaone", 6144, 12288, id="exaone-6144"),
+    ])
+    def test_both_forms_behind_one_conditional(self, chip, name, positions,
+                                               rows):
+        import re
+
+        from k8s_llm_rca_tpu.config import ModelConfig
+        from k8s_llm_rca_tpu.models import llama
+
+        if name == "nemotron":
+            cfg = ModelConfig(
+                name="nemotron-experts", hidden_size=4096, n_experts=128,
+                router_width=512, n_experts_per_tok=22,
+                router_kind="sigmoid", moe_latent_size=1024,
+                moe_intermediate_size=2688, mlp_act="relu2",
+                dtype="bfloat16")
+        else:
+            cfg = ModelConfig(
+                name="exaone-experts", hidden_size=6144, n_experts=16,
+                router_width=128, n_experts_per_tok=8,
+                router_kind="sigmoid", moe_intermediate_size=2048,
+                dtype="bfloat16")
+        e, k = cfg.n_experts, cfg.n_experts_per_tok
+        width, inter = (cfg.moe_latent_size or cfg.hidden_size,
+                        cfg.moe_intermediate_size)
+        pairs = positions * k
+        assert llama.moe_compact_rows(cfg, positions) == rows
+        assert rows % llama._GROUPED_MATMUL_TILES[0] == 0
+
+        layer = {"w_up": chip((e, width, inter), BF16),
+                 "w_down": chip((e, inter, width), BF16)}
+        if cfg.mlp_act != "relu2":
+            layer["w_gate"] = chip((e, width, inter), BF16)
+
+        compiled = jax.jit(functools.partial(llama._experts, cfg)).lower(
+            layer, chip((1, positions, width), BF16),
+            chip((1, positions, k), I32), chip((1, positions, k), F32)
+        ).compile()
+        text = compiled.as_text()
+        assert len(re.findall(r"\bconditional\(", text)) == 1
+        grouped = [line for line in text.splitlines()
+                   if "ragged-dot" in line
+                   and re.search(r"= bf16\[\d+,\d+\]\S* custom-call\(", line)]
+        per_form = 2 if cfg.mlp_act == "relu2" else 3
+        assert sum(f"= bf16[{rows}," in line
+                   for line in grouped) == per_form
+        assert sum(f"= bf16[{pairs}," in line
+                   for line in grouped) == per_form
+        assert len(grouped) == 2 * per_form
+        # at tiles that divide: the measured ones, or for 2,688 columns
+        # the fitted ones, never XLA's 128-wide fallback
+        tilings = {re.search(r'ragged_dot_tiling="([^"]*)"', line).group(1)
+                   for line in grouped}
+        assert tilings == ({"256,1024,2688", "256,2688,1024"}
+                           if name == "nemotron" else {"512,1024,1024"})
+        # the branches share their room: no more than the whole form's
+        # activations (hidden and output of every pair) and the gathers
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < pairs * (2 * inter + 3 * width) * 2
+
+
 class TestDecodeScanReadsItsExpertsPacked:
     """The decode scan of ``mixtral-d8.audit-prefill`` as the cell's engine
     binds it: 16 steps over 32 slots at Mixtral-8x7B widths (2 layers of

@@ -482,3 +482,305 @@ class TestNoExpertKernelUnderAMesh:
         assert eng._expert_kernel is False
         assert eng._decode_scan.__wrapped__.keywords["expert_kernel"] is False
         assert "engine.moe_fused_steps" not in (eng._counts or {})
+
+
+# ---------------------------------------------------------------------------
+# a share of the router's experts held (PR 38): the grouped form compacts to
+# the local pairs, up to a capacity that never drops
+# ---------------------------------------------------------------------------
+
+HELD_POSITIONS = 512        # 2,048 pairs; a quarter of the experts held
+HELD_CAP = 1024             # twice the 512 a uniform router sends here
+
+
+def _held_share(act, dtype="float32", **kw):
+    """(cfg, its weights) of a preset made to hold 8 of its router's 32
+    experts from the 4th: SwiGLU experts at the model's width
+    (``TINY_EXAONE_MOE``) or squared-ReLU experts in a latent space
+    (``TINY_NEMOTRON_H``); layer 1 of either is an expert layer."""
+    from k8s_llm_rca_tpu.config import TINY_EXAONE_MOE, TINY_NEMOTRON_H
+    from k8s_llm_rca_tpu.models import nemotron_h
+
+    preset, init = ((TINY_EXAONE_MOE, llama.init_params) if act == "swiglu"
+                    else (TINY_NEMOTRON_H, nemotron_h.init_params))
+    cfg = preset.replace(dtype=dtype, router_width=32, **kw)
+    return cfg, init(cfg, jax.random.PRNGKey(3))
+
+
+def _routed(cfg, n_local):
+    """Rows for the experts and a routing, in the router's numbering, of
+    which exactly ``n_local`` (position, expert) pairs name an expert held
+    here; a position's picks are distinct."""
+    t, k = HELD_POSITIONS, cfg.n_experts_per_tok
+    rng = np.random.default_rng(n_local)
+    held = np.arange(cfg.expert_first, cfg.expert_first + cfg.n_experts)
+    far = np.setdiff1d(np.arange(cfg.n_router), held)
+    local = np.zeros(t * k, bool)
+    local[rng.permutation(t * k)[:n_local]] = True
+    topi = np.where(local.reshape(t, k),
+                    np.stack([rng.permutation(held)[:k] for _ in range(t)]),
+                    np.stack([rng.permutation(far)[:k] for _ in range(t)]))
+    weights = rng.uniform(0.5, 1.5, (t, k)).astype(np.float32)
+    x = jax.random.normal(
+        jax.random.PRNGKey(n_local),
+        (1, t, cfg.moe_latent_size or cfg.hidden_size), jnp.float32)
+    return (x.astype(jnp.dtype(cfg.dtype)), jnp.asarray(topi[None], jnp.int32),
+            jnp.asarray(weights[None]))
+
+
+def _held_forms(monkeypatch, cfg, layer, x, topi, weights):
+    """``_experts`` in its dense form, in the whole grouped form alone (a
+    slack that leaves nothing to compact: the parent's program) and with
+    the compact form before it; and the device's word on whether the last
+    ran over (``n_compact_overflows``)."""
+    def run(min_rows, slack):
+        for name in ("MOE_GROUPED_MIN_ROWS_PER_EXPERT_FINE",
+                     "MOE_GROUPED_MIN_ROWS_PER_EXPERT_LATENT"):
+            monkeypatch.setattr(llama, name, min_rows)
+        monkeypatch.setattr(llama, "MOE_COMPACT_SLACK", slack)
+
+        def fn(layer, x, topi, weights):
+            out = llama._experts(cfg, layer, x, topi, weights)
+            return out.astype(jnp.float32), llama.n_compact_overflows(
+                cfg, x.shape[1], [llama.n_local_pairs(cfg, topi)])
+
+        return jax.jit(fn)(layer, x, topi, weights)
+
+    slack = llama.MOE_COMPACT_SLACK
+    dense, none = run(1 << 30, slack)
+    assert int(none) == 0               # not grouped: one form
+    whole, none = run(0, 1e9)
+    assert int(none) == 0               # nothing to compact: one form
+    compact, over = run(0, slack)
+    return dense, whole, compact, int(over)
+
+
+@pytest.mark.parametrize("act", ["swiglu", "relu2"])
+@pytest.mark.parametrize("n_local,overflows", [
+    pytest.param(300, 0, id="well-under-the-capacity"),
+    pytest.param(HELD_CAP, 0, id="exactly-at-it"),
+    pytest.param(HELD_CAP + 1, 1, id="one-over-it"),
+    pytest.param(4 * HELD_POSITIONS, 1, id="every-pair-local"),
+    pytest.param(0, 0, id="none-local"),
+])
+def test_compact_equals_dense_and_whole(monkeypatch, act, n_local,
+                                        overflows):
+    """The same sum over the same local pairs whichever form the device
+    takes: under the capacity the compact rows, over it the whole form,
+    which is then the parent's to the bit."""
+    cfg, params = _held_share(act)
+    pairs = HELD_POSITIONS * cfg.n_experts_per_tok
+    assert llama.moe_compact_rows(cfg, HELD_POSITIONS) == HELD_CAP < pairs
+    dense, whole, compact, over = _held_forms(
+        monkeypatch, cfg, params["layers"][1], *_routed(cfg, n_local))
+    assert over == overflows
+    assert (float(jnp.max(jnp.abs(dense))) > 0) == (n_local > 0)
+    np.testing.assert_allclose(compact, dense, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(compact, whole, rtol=1e-4, atol=1e-5)
+    if overflows:
+        np.testing.assert_array_equal(compact, whole)
+
+
+@pytest.mark.parametrize("act", ["swiglu", "relu2"])
+@pytest.mark.parametrize("n_local", [300, HELD_CAP + 1],
+                         ids=["under", "over"])
+def test_compact_equals_dense_within_bf16_rounding(monkeypatch, act,
+                                                   n_local):
+    cfg, params = _held_share(act, "bfloat16")
+    dense, whole, compact, _ = _held_forms(
+        monkeypatch, cfg, params["layers"][1], *_routed(cfg, n_local))
+    tol = 2e-2 * float(jnp.max(jnp.abs(dense)))
+    np.testing.assert_allclose(compact, dense, rtol=2e-2, atol=tol)
+    np.testing.assert_allclose(compact, whole, rtol=2e-2, atol=tol)
+
+
+@pytest.mark.parametrize("act", ["swiglu", "relu2"])
+@pytest.mark.parametrize("n_local", [300, HELD_CAP + 1],
+                         ids=["under", "over"])
+def test_gradient_through_the_conditional(monkeypatch, act, n_local):
+    """``engine/train.py`` with a held share: the gradient through the
+    conditional, to the rows, the router's weights and every expert's
+    weights, is the dense form's on either side of the capacity."""
+    cfg, params = _held_share(act)
+    layer = params["layers"][1]
+    x, topi, weights = _routed(cfg, n_local)
+
+    def loss(layer, x, weights):
+        return jnp.sum(jnp.square(llama._experts(cfg, layer, x, topi,
+                                                 weights)))
+
+    grads = {}
+    for name, rows in (("grouped", 0), ("dense", 1 << 30)):
+        for const in ("MOE_GROUPED_MIN_ROWS_PER_EXPERT_FINE",
+                      "MOE_GROUPED_MIN_ROWS_PER_EXPERT_LATENT"):
+            monkeypatch.setattr(llama, const, rows)
+        grads[name] = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(
+            layer, x, weights)
+        if name == "grouped":
+            text = str(jax.make_jaxpr(loss)(layer, x, weights))
+            assert "cond[" in text and "ragged_dot" in text
+    flat_g, tree_g = jax.tree.flatten(grads["grouped"])
+    flat_d, tree_d = jax.tree.flatten(grads["dense"])
+    assert tree_g == tree_d
+    for g, d in zip(flat_g, flat_d):
+        np.testing.assert_allclose(g, d, rtol=1e-3,
+                                   atol=1e-4 * float(jnp.max(jnp.abs(d))))
+
+
+@pytest.mark.parametrize("name,positions,rows", [
+    # the third cell's buckets (22 picks, 128 of 512 held)
+    pytest.param("nemotron", 1024, 11264, id="nemotron-1024"),
+    pytest.param("nemotron", 2048, 22528, id="nemotron-2048"),
+    pytest.param("nemotron", 3072, 33792, id="nemotron-3072"),
+    pytest.param("nemotron", 4096, 45056, id="nemotron-4096"),
+    # the fourth cell's (8 picks, 16 of 128 held)
+    pytest.param("exaone", 2048, 4096, id="exaone-2048"),
+    pytest.param("exaone", 3072, 6144, id="exaone-3072"),
+    pytest.param("exaone", 4096, 8192, id="exaone-4096"),
+    pytest.param("exaone", 6144, 12288, id="exaone-6144"),
+    pytest.param("exaone", 8192, 16384, id="exaone-8192"),
+])
+def test_compact_rows_from_the_calls_shape(name, positions, rows):
+    """The capacity is the share of the experts held, times the slack, up
+    to a whole row tile of the grouped kernel; never above the pairs."""
+    from k8s_llm_rca_tpu.config import TINY_EXAONE_MOE, TINY_NEMOTRON_H
+
+    cfg = (TINY_NEMOTRON_H.replace(n_experts=128, router_width=512,
+                                   expert_first=0, n_experts_per_tok=22)
+           if name == "nemotron" else
+           TINY_EXAONE_MOE.replace(n_experts=16, router_width=128,
+                                   expert_first=0, n_experts_per_tok=8))
+    pairs = positions * cfg.n_experts_per_tok
+    assert llama.moe_compact_rows(cfg, positions) == rows < pairs
+    assert rows % llama._GROUPED_MATMUL_TILES[0] == 0
+    assert rows >= pairs * cfg.n_experts / cfg.n_router * \
+        llama.MOE_COMPACT_SLACK
+
+
+@pytest.mark.parametrize("cfg,router_width,positions,min_rows", [
+    pytest.param(MIXTRAL_8X7B, 0, 4096, None, id="every-expert-held"),
+    pytest.param(TINY_MOE, 0, 512, 0, id="tiny-every-expert-held"),
+    pytest.param(None, 32, 64, None, id="a-call-that-is-not-grouped"),
+    pytest.param(None, 16, 512, None, id="twice-half-the-pairs-is-all"),
+    pytest.param(None, 32, 128, 0, id="one-row-tile-covers-the-call"),
+])
+def test_a_call_with_nothing_to_save_has_the_whole_form_alone(
+        monkeypatch, cfg, router_width, positions, min_rows):
+    from k8s_llm_rca_tpu.config import TINY_EXAONE_MOE
+
+    if min_rows is not None:
+        for name in ("MOE_GROUPED_MIN_ROWS_PER_EXPERT",
+                     "MOE_GROUPED_MIN_ROWS_PER_EXPERT_FINE"):
+            monkeypatch.setattr(llama, name, min_rows)
+    cfg = cfg or TINY_EXAONE_MOE.replace(router_width=router_width)
+    assert llama.moe_compact_rows(cfg, positions) is None
+
+
+class TestCompactCounters:
+    """``engine.moe_compact_calls`` (from shapes, on the host) and
+    ``engine.moe_compact_overflows`` (counted on the device, fetched with
+    the tick's tokens) of an engine whose model holds a share of its
+    router's experts: two prompts in a 512 bucket, so every expert layer
+    of every row holds both forms."""
+
+    @staticmethod
+    def _engine(act, bias):
+        cfg, params = _held_share(act, max_seq_len=1024)
+        params = dict(params, layers=[
+            dict(layer, router_bias=jnp.asarray(bias, jnp.float32))
+            if "router_bias" in layer else layer
+            for layer in params["layers"]])
+        ecfg = EngineConfig(max_batch=2, max_seq_len=1024, page_size=16,
+                            num_pages=96, prefill_buckets=(512, 1024),
+                            max_new_tokens=4, decode_chunk=2,
+                            temperature=0.0, prefix_cache=False)
+        return cfg, make_engine(cfg, ecfg, params,
+                                get_tokenizer(vocab_size=cfg.vocab_size))
+
+    @staticmethod
+    def _run(engine, cfg):
+        rng = np.random.default_rng(1)
+        for n in (300, 410):
+            engine.submit([int(t) for t in rng.integers(
+                3, cfg.vocab_size - 1, n)], max_new_tokens=4)
+        assert len(engine.run_to_completion()) == 2
+        return engine._counts
+
+    @pytest.mark.parametrize("act", ["swiglu", "relu2"])
+    @pytest.mark.parametrize("router", ["uniform", "every-pick-held"])
+    def test_calls_from_shapes_and_overflows_from_the_device(self, act,
+                                                             router):
+        # no selection bias: the scores alone choose, evenly enough; or
+        # one that lifts experts 4..11 of the router's 32 over the rest
+        bias = np.zeros(32)
+        if router == "every-pick-held":
+            bias[4:12] = 10.0
+        cfg, engine = self._engine(act, bias)
+        assert engine.pool.moe_compact_overflows is not None
+        counts = self._run(engine, cfg)
+        expert_layers = (cfg.layer_pattern.count("E") if cfg.layer_pattern
+                         else cfg.n_layers - cfg.n_dense_layers)
+        rows = counts["engine.prefill_padded_tokens"] // 512
+        assert rows >= 2
+        assert counts["engine.moe_compact_calls"] == rows * expert_layers
+        assert counts["engine.moe_compact_overflows"] == (
+            0 if router == "uniform" else rows * expert_layers)
+        share = (counts["engine.moe_local_pairs"]
+                 / counts["engine.moe_routed_pairs"])
+        assert (share == 1.0) if bias.any() else (0.1 < share < 0.4)
+
+    def test_absent_where_every_expert_is_held(self):
+        eng, _ = _int4_engine(TINY_MOE.replace(max_seq_len=64))
+        eng.submit(list(range(3, 30)), max_new_tokens=4)
+        eng.run_to_completion()
+        assert eng.pool.moe_compact_overflows is None
+        assert not {"engine.moe_compact_calls",
+                    "engine.moe_compact_overflows"} & set(eng._counts)
+
+    def test_absent_under_the_compact_forms_size(self):
+        """A 64-position bucket is not grouped (and one tile would cover
+        it): nothing is counted as a call, and the device's count, which
+        the pool keeps all the same, reads 0."""
+        from k8s_llm_rca_tpu.config import TINY_EXAONE_MOE
+
+        cfg = TINY_EXAONE_MOE
+        ecfg = EngineConfig(max_batch=2, max_seq_len=128, page_size=16,
+                            num_pages=32, prefill_buckets=(64, 128),
+                            max_new_tokens=4, temperature=0.0,
+                            prefix_cache=False)
+        eng = make_engine(cfg, ecfg,
+                          llama.init_params(cfg, jax.random.PRNGKey(0)),
+                          get_tokenizer(vocab_size=cfg.vocab_size))
+        eng.submit(list(range(3, 40)), max_new_tokens=4)
+        eng.run_to_completion()
+        assert "engine.moe_compact_calls" not in eng._counts
+        assert eng._counts["engine.moe_compact_overflows"] == 0
+
+
+@pytest.mark.parametrize("dims,tiles", [
+    pytest.param((2 * 4096, 4096, 14336), (512, 1024, 1024),
+                 id="mixtral-up-the-measured-tiles"),
+    pytest.param((2 * 4096, 14336, 4096), (512, 1024, 1024),
+                 id="mixtral-down"),
+    pytest.param((12288, 6144, 2048), (512, 1024, 1024), id="exaone-up"),
+    pytest.param((2 * 1552, 4096, 14336), None,
+                 id="rows-512-does-not-divide-xlas-own"),
+    pytest.param((33792, 1024, 2688), (256, 1024, 2688), id="nemotron-up"),
+    pytest.param((33792, 2688, 1024), (256, 2688, 1024),
+                 id="nemotron-down"),
+    pytest.param((22 * 3072, 1024, 2688), (256, 1024, 2688),
+                 id="nemotron-up-the-whole-form"),
+    pytest.param((33792, 5376, 1024), (256, 2688, 1024),
+                 id="a-side-no-longer-than-measured"),
+    pytest.param((33792, 2688, 2688), (256, 2688, 896),
+                 id="a-weight-tile-no-larger-than-measured"),
+    pytest.param((300, 1024, 2688), None, id="rows-no-128-divides"),
+    pytest.param((2048, 128, 96), None, id="toy-widths"),
+])
+def test_grouped_matmul_tiles_divide_or_are_left_to_xla(dims, tiles):
+    """The measured tiles where they divide (the older cells' programs keep
+    theirs), the largest multiples of 128 within the measured bounds for
+    weights they do not divide, XLA's own choice otherwise."""
+    assert llama._grouped_matmul_tiles(*dims) == tiles
+    if tiles:
+        assert not any(d % t for d, t in zip(dims, tiles))
