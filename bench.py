@@ -1409,8 +1409,8 @@ def bench_prefix_leg(n_incidents: int = 100, max_new: int = 8):
     wave served COLD and then WARM from a flushed ``PrefixStore``.
 
     - ``warmstart_prefill_dispatches_saved``: cold-minus-warm prefill
-      dispatch count (direct ``engine.prefill`` spans + chunked
-      ``engine.tick.prefill_chunk`` spans from the METRICS timers) for
+      dispatch count (``engine.prefill`` spans from the METRICS
+      timers: one per dispatch, a chunk's among them) for
       the SAME wave on a fresh engine sharing the store — exact event
       counts.
     - ``l1_hit_ratio``: L1 page hits / all prefix page hits (L0+L1+L2)
@@ -1453,9 +1453,7 @@ def bench_prefix_leg(n_incidents: int = 100, max_new: int = 8):
                         prefix_cache=True, prefill_chunk_budget=32)
 
     def prefill_dispatches():
-        snap = METRICS.snapshot()
-        return (snap.get("engine.prefill.count", 0.0)
-                + snap.get("engine.tick.prefill_chunk.count", 0.0))
+        return METRICS.snapshot().get("engine.prefill.count", 0.0)
 
     def run_wave(engine):
         # compile pass on a DISJOINT preamble so it seeds no shared pages
@@ -1564,7 +1562,7 @@ def bench_store_leg(n_incidents: int = 40, n_gets: int = 40,
                     temperature=0.0, page_size=16,
                     num_pages=40, prefix_cache=True, decode_chunk=4,
                     # chunked prefill: warm-start savings surface as
-                    # fewer engine.tick.prefill_chunk dispatches, not
+                    # fewer engine.prefill dispatches, not
                     # just smaller ones (the bench_prefix_leg idiom)
                     prefill_chunk_budget=32)
         base.update(over)
@@ -1580,9 +1578,7 @@ def bench_store_leg(n_incidents: int = 40, n_gets: int = 40,
         return [out[s].token_ids for s in sids]
 
     def prefill_dispatches():
-        snap = METRICS.snapshot()
-        return (snap.get("engine.prefill.count", 0.0)
-                + snap.get("engine.tick.prefill_chunk.count", 0.0))
+        return METRICS.snapshot().get("engine.prefill.count", 0.0)
 
     server = StoreServer(host_pages=1024, transport="socket")
     try:

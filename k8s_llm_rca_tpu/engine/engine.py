@@ -27,11 +27,12 @@ index clamping would otherwise hide).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -455,8 +456,11 @@ class SequenceTiming:
     """One request's lifecycle, stamped on the engine's clock
     (``EngineBase._now``): arrival at ``submit``, the first slot grant,
     the host commit of its first and of its newest token.  A stamp the
-    sequence never reached is None.  ``seq_id`` is the key its
-    ``engine.request`` span carries."""
+    sequence never reached is None.  ``stall_s`` is the time it stood,
+    holding a first token, behind the tick's prefill phases (other
+    callers' prefills, and its own again after a preemption), on the same
+    clock; None where it got no first token in this engine.  ``seq_id``
+    is the key its ``engine.request`` span carries."""
 
     seq_id: int
     t_arrival: float
@@ -464,6 +468,7 @@ class SequenceTiming:
     t_first: Optional[float] = None
     t_last: Optional[float] = None
     preemptions: int = 0
+    stall_s: Optional[float] = None
 
     @property
     def queue_wait_s(self) -> Optional[float]:
@@ -485,13 +490,17 @@ class _Life:
     """The mutable form of ``SequenceTiming`` (the same fields in the
     same order, less the id): ONE record per sequence, handed from its
     ``_Pending`` to its ``_Active`` and back through a preemption's
-    requeue, so the stamps survive every resume."""
+    requeue, so the stamps survive every resume.  Its ``stall_s`` is
+    the engine's running total of prefill-phase seconds
+    (``EngineBase._stall_total``) as it stood at the first token;
+    ``_settle_timing`` turns it into the seconds since."""
 
     t_arrival: float
     t_admitted: Optional[float] = None
     t_first: Optional[float] = None
     t_last: Optional[float] = None
     preemptions: int = 0
+    stall_s: Optional[float] = None
 
     def admitted(self, now: float) -> None:
         if self.t_admitted is None:
@@ -742,7 +751,8 @@ class EngineBase:
             seq_id=seq_id, token_ids=list(gen),
             text=self._final_text(gen, "expired", req.stop_strings),
             finish_reason="expired", prompt_tokens=len(prompt),
-            completion_tokens=len(gen))
+            completion_tokens=len(gen),
+            timing=self._settle_timing(req, len(gen)))
 
     def _register(self, seq_id: int, prompt_ids: List[int]) -> None:
         """Keep a submitted (or restored) sequence's ORIGINAL prompt:
@@ -1058,13 +1068,18 @@ class EngineBase:
         dispatches, this counts the steps they ran."""
         self._count("engine.decode_steps", steps)
 
-    def _settle_timing(self, st: _Active, tokens: int) -> SequenceTiming:
-        """Close a retiring sequence's lifecycle: the frozen record for
+    def _settle_timing(self, st: Union[_Active, _Pending],
+                       tokens: int) -> SequenceTiming:
+        """Close a retiring sequence's lifecycle (``st`` active, or
+        queued when its deadline passed): the frozen record for
         its ``SequenceResult``, the three durations into METRICS
         (``engine.queue_wait`` / ``engine.ttft`` / ``engine.tpot``, read
         as ``.total_s`` / ``.count``) and, under an active tracer, one
         ``engine.request`` span from arrival to the newest token."""
         timing = SequenceTiming(st.seq_id, *dataclasses.astuple(st.life))
+        if timing.stall_s is not None:
+            timing = dataclasses.replace(
+                timing, stall_s=self._stall_total() - timing.stall_s)
         if timing.t_admitted is not None:
             METRICS.observe("engine.queue_wait", timing.queue_wait_s)
         if timing.t_first is None:
@@ -1081,9 +1096,46 @@ class EngineBase:
                       "queue_wait_s": timing.queue_wait_s,
                       "prefill_s": timing.t_first - timing.t_admitted,
                       "decode_s": timing.decode_s,
+                      "stall_s": timing.stall_s,
                       "tokens": tokens,
                       "preemptions": timing.preemptions})
         return timing
+
+    # the stall a live sequence suffers from the tick's prefill phases
+    # (``_prefill_phases``): a running total of their seconds on
+    # ``_now``'s clock, and the start of the one that is open
+    _stall_s: float = 0.0
+    _stall_t0: Optional[float] = None
+
+    def _stall_total(self) -> float:
+        """Seconds this engine's ticks have spent in their prefill
+        phases, up to this instant where one is open.  A sequence's
+        ``stall_s`` is the difference between its first token and its
+        settling, so it costs nothing per tick."""
+        if self._stall_t0 is not None:
+            now = self._now()
+            self._stall_s += now - self._stall_t0
+            self._stall_t0 = now
+        return self._stall_s
+
+    @contextlib.contextmanager
+    def _prefill_phases(self):
+        """Around a tick's prefill phases (chunks, admission, the first
+        tokens' fetch and commit): their seconds times the sequences
+        that hold a first token, and so wait for the decode behind them,
+        go to ``engine.prefill_stall_seq_s`` (over
+        ``engine.decode_tokens``: the stall a decoded token carries);
+        present at 0 where nobody waited."""
+        live = sum(1 for st in self._active.values()
+                   if st.life.t_first is not None)
+        before = self._stall_s
+        self._stall_t0 = self._now()
+        try:
+            yield
+        finally:
+            seconds = self._stall_total() - before
+            self._stall_t0 = None
+            self._count("engine.prefill_stall_seq_s", live * seconds)
 
     # ---------------------------------------- overlapped hot loop (shared)
     #
@@ -1159,6 +1211,8 @@ class EngineBase:
         if not live:
             return None
         st.generated.append(token)
+        if st.life.t_first is None:
+            st.life.stall_s = self._stall_total()
         st.life.committed(self._now())
         self._note_first_token(st.slot, token, update_dev=update_dev)
         reason = self._finish_reason(st, token, st.prompt_tokens)

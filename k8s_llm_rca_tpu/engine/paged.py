@@ -2179,59 +2179,92 @@ class PagedInferenceEngine(EngineBase):
             self._dev_edit_token(slot, token)
 
     def _tick(self) -> List[SequenceResult]:
-        finished: List[SequenceResult] = self._reap_deadlines()
-        if self._flushed_out:
-            # results finished by an out-of-tick flush (cancel/snapshot/
-            # fault barrier) surface here so step() callers never lose them
-            finished.extend(self._flushed_out)
-            self._flushed_out = []
+        """One tick as a run of phases, each a span directly under
+        ``engine.tick`` and none inside another (docs/observability.md):
+        reap, prefill_chunk, admission, first_tokens, eviction, decode."""
+        finished: List[SequenceResult] = []
+        if self._deadlines or self._flushed_out or self._inflight:
+            with profiling.annotate("engine.tick.reap"):
+                self._tick_reap(finished)
         fast = self._overlap_fast()
-        if self._inflight and not fast:
-            # a sync path (grammar, speculation, scan) runs this tick:
-            # commit the lag first so it observes fully committed state
-            finished.extend(self._overlap_flush())
-        if self._prefilling:
-            # advance every in-progress chunked prefill by ONE chunk
-            # BEFORE admission: budget-limited sequences make progress
-            # each tick even while new admissions compete for pages
-            finished.extend(self._tick_prefill_chunks())
-        if self._pending and self._free_slots:
-            with profiling.annotate("engine.tick.admission"):
-                finished.extend(self._tick_admission())
-        if not fast:
-            # one coalesced fetch commits every deferred admission first
-            # token before any state-dependent path (spec drafts, scan
-            # chunk bounds, a dirty re-upload) reads host mirrors
-            finished.extend(self._drain_admission_commits())
+        if (self._prefilling or self._admit_pending
+                or (self._pending and self._free_slots)):
+            with self._prefill_phases():
+                self._tick_prefill_phases(finished, fast)
         if not self._active:
-            finished.extend(self._overlap_flush())
-            return finished
+            return self._tick_idle(finished)
 
         with profiling.annotate("engine.tick.eviction"):
             self._tick_pressure()
             self._tick_growth()
         active_slots = sorted(self._active)
         if not active_slots:
+            return self._tick_idle(finished)
+        with profiling.annotate("engine.tick.decode"):
+            finished.extend(self._tick_decode(active_slots, fast))
+        return finished
+
+    def _tick_reap(self, finished: List[SequenceResult]) -> None:
+        finished.extend(self._reap_deadlines())
+        if self._flushed_out:
+            # results finished by an out-of-tick flush (cancel/snapshot/
+            # fault barrier) surface here so step() callers never lose them
+            finished.extend(self._flushed_out)
+            self._flushed_out = []
+        if self._inflight and not self._overlap_fast():
+            # a sync path (grammar, speculation, scan) runs this tick:
+            # commit the lag first so it observes fully committed state
             finished.extend(self._overlap_flush())
-            return finished
 
+    def _tick_prefill_phases(self, finished: List[SequenceResult],
+                             fast: bool) -> None:
+        if self._prefilling:
+            # advance every in-progress chunked prefill by ONE chunk
+            # BEFORE admission: budget-limited sequences make progress
+            # each tick even while new admissions compete for pages
+            with profiling.annotate("engine.tick.prefill_chunk"):
+                finished.extend(self._tick_prefill_chunks())
+        if self._pending and self._free_slots:
+            with profiling.annotate("engine.tick.admission"):
+                finished.extend(self._tick_admission())
+        if self._admit_pending and not fast:
+            # one coalesced fetch (the wait for this tick's prefills)
+            # commits every deferred admission first token before any
+            # state-dependent path (spec drafts, scan chunk bounds, a
+            # dirty re-upload) reads host mirrors
+            with profiling.annotate("engine.tick.first_tokens"):
+                finished.extend(self._drain_admission_commits())
+
+    def _tick_idle(self, finished: List[SequenceResult]
+                   ) -> List[SequenceResult]:
+        """Nothing is live: what the overlapped path still has in flight
+        is committed, as the end of its decode."""
+        if self._inflight or self._admit_pending:
+            with profiling.annotate("engine.tick.decode"):
+                finished.extend(self._overlap_flush())
+        return finished
+
+    def _tick_decode(self, active_slots, fast: bool) -> List[SequenceResult]:
+        """The tick's decode phase, from its set-up through its commit,
+        by whichever of the four programs applies."""
         if self._speculation_applies():
-            finished.extend(self._speculative_tick(active_slots))
-            return finished
-
+            return self._speculative_tick(active_slots)
         chunk = self._scan_chunk()
         if chunk > 1:
-            finished.extend(self._scan_tick(chunk, active_slots))
-            return finished
-
+            return self._scan_tick(chunk, active_slots)
         if fast:
-            finished.extend(self._overlap_step_tick(active_slots))
-            return finished
+            return self._overlap_step_tick(active_slots)
+        return self._step_tick(active_slots)
 
-        forced, allow = self._tick_constraints(
-            active_slots, self.engine_cfg.max_batch,
-            self.model_cfg.vocab_size)
-        cur_d, lens_d, bt_d = self._device_state()
+    def _step_tick(self, active_slots) -> List[SequenceResult]:
+        """The plain tick: one decode step, a blocking fetch, the host
+        commit."""
+        finished: List[SequenceResult] = []
+        with profiling.annotate("engine.scan_setup"):
+            forced, allow = self._tick_constraints(
+                active_slots, self.engine_cfg.max_batch,
+                self.model_cfg.vocab_size)
+            cur_d, lens_d, bt_d = self._device_state()
         with profiling.annotate("engine.decode_step"):
             self._count("engine.dispatches")
             self._count_decode(1)
@@ -2281,8 +2314,9 @@ class PagedInferenceEngine(EngineBase):
         # device state FIRST: a dirty upload re-applies _admit_pending
         # device tokens over the stale host mirror, so take the admits
         # only after the resident arrays are materialised
-        cur_d, lens_d, bt_d = self._device_state()
-        admits = self._take_admit_pending()
+        with profiling.annotate("engine.scan_setup"):
+            cur_d, lens_d, bt_d = self._device_state()
+            admits = self._take_admit_pending()
         slots = [(s, self._active[s].seq_id) for s in active_slots]
         with profiling.annotate("engine.decode_step"):
             self._count("engine.dispatches")
@@ -2532,13 +2566,14 @@ class PagedInferenceEngine(EngineBase):
     def _scan_tick(self, chunk: int, active_slots) -> List[SequenceResult]:
         """Commit ``chunk`` paged decode steps from one on-device scan;
         accounting identical to the stepwise tick (shared commit loop)."""
-        setup = self._scan_dfa_setup()
-        self._key, sub = jax.random.split(self._key)
-        cur_d, lens_d, bt_d = self._device_state()
-        self._count_decode(chunk)
-        self._count_moe_fused(chunk)
-        self._count_attn_pages(chunk, active_slots)
-        self._count_state_steps(chunk)
+        with profiling.annotate("engine.scan_setup"):
+            setup = self._scan_dfa_setup()
+            self._key, sub = jax.random.split(self._key)
+            cur_d, lens_d, bt_d = self._device_state()
+            self._count_decode(chunk)
+            self._count_moe_fused(chunk)
+            self._count_attn_pages(chunk, active_slots)
+            self._count_state_steps(chunk)
         if setup is None:
             with profiling.annotate("engine.decode_step"):
                 self._count("engine.dispatches")
@@ -2710,37 +2745,38 @@ class PagedInferenceEngine(EngineBase):
     def _admit(self, req: _Pending,
                matched: Optional[Tuple[List[int], int]] = None
                ) -> Optional[SequenceResult]:
-        n = len(req.prompt_ids)
-        if matched is None:
-            matched = (self.prefix_cache.match(req.prompt_ids)
-                       if self.prefix_cache is not None else ([], 0))
-        cached_pages, n_cached = matched
-        n_cp = len(cached_pages)
-        rest = req.prompt_ids[n_cached:]
-        # suffix bucket capped at the table space left after the cached
-        # prefix (utils/pages.py — one definition with _admit_chunked
-        # and _admit_spilled, so allocator state evolves identically)
-        bucket, n_pages = suffix_bucket(self._bucket, len(rest), n_cp,
-                                        self.page_size, self.pages_per_seq)
-        assert len(rest) <= bucket, (len(rest), bucket)
-        try:
-            # sequence-page indices n_cp..n_cp+n_pages-1 (partition-aligned
-            # under the CP seq-sharded pool; plain allocation otherwise)
-            pages = self._alloc_seq_pages(range(n_cp, n_cp + n_pages),
-                                          owner=req.seq_id)
-        except OutOfPages:
-            if cached_pages:
-                self.prefix_cache.release(cached_pages)
-            raise
-        slot = self._free_slots.pop(0)
+        with profiling.annotate("engine.admission.stage"):
+            n = len(req.prompt_ids)
+            if matched is None:
+                matched = (self.prefix_cache.match(req.prompt_ids)
+                           if self.prefix_cache is not None else ([], 0))
+            cached_pages, n_cached = matched
+            n_cp = len(cached_pages)
+            rest = req.prompt_ids[n_cached:]
+            # suffix bucket capped at the table space left after the cached
+            # prefix (utils/pages.py — one definition with _admit_chunked
+            # and _admit_spilled, so allocator state evolves identically)
+            bucket, n_pages = suffix_bucket(self._bucket, len(rest), n_cp,
+                                            self.page_size, self.pages_per_seq)
+            assert len(rest) <= bucket, (len(rest), bucket)
+            try:
+                # sequence-page indices n_cp..n_cp+n_pages-1 (partition-aligned
+                # under the CP seq-sharded pool; plain allocation otherwise)
+                pages = self._alloc_seq_pages(range(n_cp, n_cp + n_pages),
+                                              owner=req.seq_id)
+            except OutOfPages:
+                if cached_pages:
+                    self.prefix_cache.release(cached_pages)
+                raise
+            slot = self._free_slots.pop(0)
 
-        table = np.full((self.pages_per_seq,), TRASH_PAGE, np.int32)
-        table[:n_cp] = cached_pages
-        table[n_cp:n_cp + n_pages] = pages
-        self.block_tables[slot] = table
+            table = np.full((self.pages_per_seq,), TRASH_PAGE, np.int32)
+            table[:n_cp] = cached_pages
+            table[n_cp:n_cp + n_pages] = pages
+            self.block_tables[slot] = table
 
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :len(rest)] = rest
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :len(rest)] = rest
         with profiling.annotate("engine.prefill"):
             self._count("engine.dispatches")
             if n_cached:
@@ -2766,23 +2802,24 @@ class PagedInferenceEngine(EngineBase):
                     jnp.asarray(table[:n_pages]), **self._slots_kw([slot]))
             self._key, sub = jax.random.split(self._key)
             first = self._sample(logits, sub, self.sampling)
-        self._count("engine.prefill_tokens", len(rest))
-        self._count_prefill_padded(padded.size, n_true=len(rest))
+        with profiling.annotate("engine.admission.activate"):
+            self._count("engine.prefill_tokens", len(rest))
+            self._count_prefill_padded(padded.size, n_true=len(rest))
 
-        if req.grammar is not None:
-            # grammar first tokens stay synchronous: the FSM needs the
-            # sampled value (and possibly a masked resample off these
-            # logits) before the next dispatch
-            return self._activate_paged(req, slot, table, n_cp, logits,
-                                        int(self._fetch(first)[0][0]))
-        # deferred admission (docs/performance.md): the device value goes
-        # straight into the resident cur array; the HOST value lands at
-        # the next coalesced drain/flush — single-sequence admission
-        # pays no blocking fetch of its own
-        st = self._preactivate_paged(req, slot, table, n_cp)
-        self._dev_edit_token(slot, first[0])
-        self._defer_first(st, first, 0)
-        return None
+            if req.grammar is not None:
+                # grammar first tokens stay synchronous: the FSM needs the
+                # sampled value (and possibly a masked resample off these
+                # logits) before the next dispatch
+                return self._activate_paged(req, slot, table, n_cp, logits,
+                                            int(self._fetch(first)[0][0]))
+            # deferred admission (docs/performance.md): the device value goes
+            # straight into the resident cur array; the HOST value lands at
+            # the next coalesced drain/flush — single-sequence admission
+            # pays no blocking fetch of its own
+            st = self._preactivate_paged(req, slot, table, n_cp)
+            self._dev_edit_token(slot, first[0])
+            self._defer_first(st, first, 0)
+            return None
 
     def _admit_chunked(self, req: _Pending) -> Optional[SequenceResult]:
         """Admit a long prompt through the chunk-prefill path spread
@@ -2807,33 +2844,36 @@ class PagedInferenceEngine(EngineBase):
         rest = req.prompt_ids[n_cached:]
         if len(rest) <= self.engine_cfg.prefill_chunk_budget:
             return self._admit(req, matched)
-        n_cp = len(cached_pages)
-        bucket, n_pages = suffix_bucket(self._bucket, len(rest), n_cp,
-                                        self.page_size, self.pages_per_seq)
-        try:
-            pages = self._alloc_seq_pages(range(n_cp, n_cp + n_pages),
-                                          owner=req.seq_id)
-        except OutOfPages:
-            if cached_pages:
-                self.prefix_cache.release(cached_pages)
-            raise
-        slot = self._free_slots.pop(0)
-        req.life.admitted(self._now())
-        # the full table lives in _prefilling, NOT block_tables: the slot
-        # stays inactive (row TRASH_PAGE) until the final chunk activates
-        # it, so interleaved decode ticks' garbage writes for this slot
-        # cannot land in the chunk pages being filled
-        table = np.full((self.pages_per_seq,), TRASH_PAGE, np.int32)
-        table[:n_cp] = cached_pages
-        table[n_cp:n_cp + n_pages] = pages
-        self._prefilling[slot] = {
-            "req": req, "table": table, "n_cp": n_cp,
-            "cached": [int(p) for p in cached_pages],
-            "pages": [int(p) for p in pages],
-            "done": n_cached, "total": len(req.prompt_ids),
-        }
-        if n_cached:
-            self._count("engine.prefix_hit_tokens", n_cached)
+        # the pages and the slot; each chunk's rows are staged with it
+        with profiling.annotate("engine.admission.stage"):
+            n_cp = len(cached_pages)
+            bucket, n_pages = suffix_bucket(
+                self._bucket, len(rest), n_cp, self.page_size,
+                self.pages_per_seq)
+            try:
+                pages = self._alloc_seq_pages(range(n_cp, n_cp + n_pages),
+                                              owner=req.seq_id)
+            except OutOfPages:
+                if cached_pages:
+                    self.prefix_cache.release(cached_pages)
+                raise
+            slot = self._free_slots.pop(0)
+            req.life.admitted(self._now())
+            # the full table lives in _prefilling, NOT block_tables: the
+            # slot stays inactive (row TRASH_PAGE) until the final chunk
+            # activates it, so interleaved decode ticks' garbage writes
+            # for this slot cannot land in the chunk pages being filled
+            table = np.full((self.pages_per_seq,), TRASH_PAGE, np.int32)
+            table[:n_cp] = cached_pages
+            table[n_cp:n_cp + n_pages] = pages
+            self._prefilling[slot] = {
+                "req": req, "table": table, "n_cp": n_cp,
+                "cached": [int(p) for p in cached_pages],
+                "pages": [int(p) for p in pages],
+                "done": n_cached, "total": len(req.prompt_ids),
+            }
+            if n_cached:
+                self._count("engine.prefix_hit_tokens", n_cached)
         return self._advance_prefill(slot)   # first chunk dispatches NOW
 
     def _advance_prefill(self, slot: int) -> Optional[SequenceResult]:
@@ -2845,26 +2885,27 @@ class PagedInferenceEngine(EngineBase):
         done, total = st["done"], st["total"]
         chunk_len = min(budget, total - done)
         ps = self.page_size
-        # ``done`` is page-aligned here: it starts at the (whole-page)
-        # cached-prefix length and every non-final chunk advances it by
-        # the page-multiple budget
-        n_pre_pages = done // ps
-        pb = 1
-        while pb < n_pre_pages:
-            pb *= 2
-        prefix_table = np.full((pb,), TRASH_PAGE, np.int32)
-        prefix_table[:n_pre_pages] = table[:n_pre_pages]
-        # fixed [1, budget] compile shape for every chunk; the final
-        # (short) chunk right-pads and maps only its valid pages — the
-        # padding positions scatter to TRASH_PAGE, the engine's standing
-        # garbage-containment convention
-        padded = np.zeros((1, budget), np.int32)
-        padded[0, :chunk_len] = req.prompt_ids[done:done + chunk_len]
-        page_map = np.full((budget // ps,), TRASH_PAGE, np.int32)
-        n_chunk_pages = -(-chunk_len // ps)
-        page_map[:n_chunk_pages] = table[n_pre_pages:
-                                         n_pre_pages + n_chunk_pages]
-        with profiling.annotate("engine.tick.prefill_chunk"):
+        with profiling.annotate("engine.admission.stage"):
+            # ``done`` is page-aligned here: it starts at the (whole-page)
+            # cached-prefix length and every non-final chunk advances it
+            # by the page-multiple budget
+            n_pre_pages = done // ps
+            pb = 1
+            while pb < n_pre_pages:
+                pb *= 2
+            prefix_table = np.full((pb,), TRASH_PAGE, np.int32)
+            prefix_table[:n_pre_pages] = table[:n_pre_pages]
+            # fixed [1, budget] compile shape for every chunk; the final
+            # (short) chunk right-pads and maps only its valid pages — the
+            # padding positions scatter to TRASH_PAGE, the engine's
+            # standing garbage-containment convention
+            padded = np.zeros((1, budget), np.int32)
+            padded[0, :chunk_len] = req.prompt_ids[done:done + chunk_len]
+            page_map = np.full((budget // ps,), TRASH_PAGE, np.int32)
+            n_chunk_pages = -(-chunk_len // ps)
+            page_map[:n_chunk_pages] = table[n_pre_pages:
+                                             n_pre_pages + n_chunk_pages]
+        with profiling.annotate("engine.prefill"):
             self._count("engine.dispatches")
             self._count("engine.prefill_chunks")
             self.pool, logits = self._prefill_chunk(
@@ -2880,18 +2921,19 @@ class PagedInferenceEngine(EngineBase):
         # final chunk: its last-valid-token logits are the whole prompt's
         # — sample the first token with exactly the monolithic _admit's
         # single RNG split, publish the table, activate
-        del self._prefilling[slot]
-        self.block_tables[slot] = table
-        self._key, sub = jax.random.split(self._key)
-        first = self._sample(logits, sub, self.sampling)
-        if req.grammar is not None:
-            return self._activate_paged(req, slot, table, st["n_cp"],
-                                        logits,
-                                        int(self._fetch(first)[0][0]))
-        act = self._preactivate_paged(req, slot, table, st["n_cp"])
-        self._dev_edit_token(slot, first[0])
-        self._defer_first(act, first, 0)
-        return None
+        with profiling.annotate("engine.admission.activate"):
+            del self._prefilling[slot]
+            self.block_tables[slot] = table
+            self._key, sub = jax.random.split(self._key)
+            first = self._sample(logits, sub, self.sampling)
+            if req.grammar is not None:
+                return self._activate_paged(req, slot, table, st["n_cp"],
+                                            logits,
+                                            int(self._fetch(first)[0][0]))
+            act = self._preactivate_paged(req, slot, table, st["n_cp"])
+            self._dev_edit_token(slot, first[0])
+            self._defer_first(act, first, 0)
+            return None
 
     def _tick_prefill_chunks(self) -> List[SequenceResult]:
         """The tick's chunked-prefill phase: every in-progress slot
@@ -3016,57 +3058,58 @@ class PagedInferenceEngine(EngineBase):
         dispatch-bound bench host).  Matches arrive ACQUIRED from
         _admission_group; on allocation failure every ref is released
         before the OutOfPages escapes (retry next tick)."""
-        n_cached = matches[0][1]
-        n_cp = len(matches[0][0])
-        rests = [r.prompt_ids[n_cached:] for r in reqs]
-        bucket = min(self._bucket(max(len(rest) for rest in rests)),
-                     (self.pages_per_seq - n_cp) * self.page_size)
-        assert all(len(rest) <= bucket for rest in rests)
-        n_pages = bucket // self.page_size
-        n = len(reqs)
-        allocated: List[List[int]] = []
-        try:
-            for r in reqs:
-                allocated.append(
-                    self._alloc_with_evict(n_pages, owner=r.seq_id))
-        except OutOfPages:
-            for r, pages in zip(reqs, allocated):
-                self.allocator.free(pages, owner=r.seq_id)
-            for m in matches:
-                self.prefix_cache.release(m[0])
-            raise
-        slots = [self._free_slots.pop(0) for _ in range(n)]
+        with profiling.annotate("engine.admission.stage"):
+            n_cached = matches[0][1]
+            n_cp = len(matches[0][0])
+            rests = [r.prompt_ids[n_cached:] for r in reqs]
+            bucket = min(self._bucket(max(len(rest) for rest in rests)),
+                         (self.pages_per_seq - n_cp) * self.page_size)
+            assert all(len(rest) <= bucket for rest in rests)
+            n_pages = bucket // self.page_size
+            n = len(reqs)
+            allocated: List[List[int]] = []
+            try:
+                for r in reqs:
+                    allocated.append(
+                        self._alloc_with_evict(n_pages, owner=r.seq_id))
+            except OutOfPages:
+                for r, pages in zip(reqs, allocated):
+                    self.allocator.free(pages, owner=r.seq_id)
+                for m in matches:
+                    self.prefix_cache.release(m[0])
+                raise
+            slots = [self._free_slots.pop(0) for _ in range(n)]
 
-        n_pad = 1
-        while n_pad < n:
-            n_pad *= 2
-        pb = 1
-        while pb < n_cp:
-            pb *= 2
-        tokens = np.zeros((n_pad, bucket), np.int32)
-        clens = np.zeros((n_pad,), np.int32)
-        plens = np.full((n_pad,), n_cached, np.int32)
-        ptabs = np.full((n_pad, pb), TRASH_PAGE, np.int32)
-        maps = np.zeros((n_pad, n_pages), np.int32)
-        tables = []
-        for i, (r, m, rest) in enumerate(zip(reqs, matches, rests)):
-            tokens[i, :len(rest)] = rest
-            clens[i] = len(rest)
-            ptabs[i, :n_cp] = m[0]
-            maps[i] = allocated[i]
-            table = np.full((self.pages_per_seq,), TRASH_PAGE, np.int32)
-            table[:n_cp] = m[0]
-            table[n_cp:n_cp + n_pages] = allocated[i]
-            self.block_tables[slots[i]] = table
-            tables.append(table)
-        # padding rows repeat the last real row (tokens, prefix AND
-        # pages): the duplicate scatter writes recompute identical KV
-        # into the same pages — idempotent, the paged_prefill_batch
-        # contract
-        tokens[n:] = tokens[n - 1]
-        clens[n:] = clens[n - 1]
-        ptabs[n:] = ptabs[n - 1]
-        maps[n:] = maps[n - 1]
+            n_pad = 1
+            while n_pad < n:
+                n_pad *= 2
+            pb = 1
+            while pb < n_cp:
+                pb *= 2
+            tokens = np.zeros((n_pad, bucket), np.int32)
+            clens = np.zeros((n_pad,), np.int32)
+            plens = np.full((n_pad,), n_cached, np.int32)
+            ptabs = np.full((n_pad, pb), TRASH_PAGE, np.int32)
+            maps = np.zeros((n_pad, n_pages), np.int32)
+            tables = []
+            for i, (r, m, rest) in enumerate(zip(reqs, matches, rests)):
+                tokens[i, :len(rest)] = rest
+                clens[i] = len(rest)
+                ptabs[i, :n_cp] = m[0]
+                maps[i] = allocated[i]
+                table = np.full((self.pages_per_seq,), TRASH_PAGE, np.int32)
+                table[:n_cp] = m[0]
+                table[n_cp:n_cp + n_pages] = allocated[i]
+                self.block_tables[slots[i]] = table
+                tables.append(table)
+            # padding rows repeat the last real row (tokens, prefix AND
+            # pages): the duplicate scatter writes recompute identical KV
+            # into the same pages — idempotent, the paged_prefill_batch
+            # contract
+            tokens[n:] = tokens[n - 1]
+            clens[n:] = clens[n - 1]
+            ptabs[n:] = ptabs[n - 1]
+            maps[n:] = maps[n - 1]
 
         with profiling.annotate("engine.prefill"):
             self._count("engine.dispatches")
@@ -3077,74 +3120,77 @@ class PagedInferenceEngine(EngineBase):
                 jnp.asarray(maps))
             self._key, sub = jax.random.split(self._key)
             firsts = self._sample(logits, sub, self.sampling)
-        self._count("engine.prefill_tokens",
-                    sum(len(rest) for rest in rests))
-        self._count_prefill_padded(tokens.size)
-        self._count("engine.prefix_hit_tokens", n_cached * n)
-        self._count("engine.prefix_batch_hit_admissions", n)
+        with profiling.annotate("engine.admission.activate"):
+            self._count("engine.prefill_tokens",
+                        sum(len(rest) for rest in rests))
+            self._count_prefill_padded(tokens.size)
+            self._count("engine.prefix_hit_tokens", n_cached * n)
+            self._count("engine.prefix_batch_hit_admissions", n)
 
-        if any(r.grammar is not None for r in reqs):
-            # grammar groups stay synchronous (FSM needs the values now)
-            finished: List[SequenceResult] = []
-            (firsts_host,) = self._fetch(firsts)
-            for i, (req, m) in enumerate(zip(reqs, matches)):
-                early = self._activate_paged(req, slots[i], tables[i], n_cp,
-                                             logits[i:i + 1],
-                                             int(firsts_host[i]))
-                if early is not None:
-                    finished.append(early)
-            return finished
-        # deferred batch admission: ONE coalesced fetch at the next
-        # drain/flush covers the whole wave (docs/performance.md)
-        for i, req in enumerate(reqs):
-            st = self._preactivate_paged(req, slots[i], tables[i], n_cp)
-            self._dev_edit_token(slots[i], firsts[i])
-            self._defer_first(st, firsts, i)
-        return []
+            if any(r.grammar is not None for r in reqs):
+                # grammar groups stay synchronous (FSM needs the values now)
+                finished: List[SequenceResult] = []
+                (firsts_host,) = self._fetch(firsts)
+                for i, (req, m) in enumerate(zip(reqs, matches)):
+                    early = self._activate_paged(
+                        req, slots[i], tables[i], n_cp, logits[i:i + 1],
+                        int(firsts_host[i]))
+                    if early is not None:
+                        finished.append(early)
+                return finished
+            # deferred batch admission: ONE coalesced fetch at the next
+            # drain/flush covers the whole wave (docs/performance.md)
+            for i, req in enumerate(reqs):
+                st = self._preactivate_paged(req, slots[i], tables[i], n_cp)
+                self._dev_edit_token(slots[i], firsts[i])
+                self._defer_first(st, firsts, i)
+            return []
 
     def _admit_batch(self, reqs: List[_Pending]) -> List[SequenceResult]:
         """Admit N same-bucket prefix-miss sequences with ONE batched
         paged prefill (pads to a power of two by repeating the last real
         row's tokens AND pages — the duplicate scatter writes are
         idempotent)."""
-        n = len(reqs)
-        bucket = min(self._bucket(max(len(r.prompt_ids) for r in reqs)),
-                     self.pages_per_seq * self.page_size)
-        n_pages = bucket // self.page_size
-        allocated: List[List[int]] = []
-        try:
-            for r in reqs:
-                allocated.append(
-                    self._alloc_with_evict(n_pages, owner=r.seq_id))
-        except OutOfPages:
-            for r, pages in zip(reqs, allocated):
-                self.allocator.free(pages, owner=r.seq_id)
-            raise
-        slots = [self._free_slots.pop(0) for _ in range(n)]
+        with profiling.annotate("engine.admission.stage"):
+            n = len(reqs)
+            bucket = min(self._bucket(max(len(r.prompt_ids) for r in reqs)),
+                         self.pages_per_seq * self.page_size)
+            n_pages = bucket // self.page_size
+            allocated: List[List[int]] = []
+            try:
+                for r in reqs:
+                    allocated.append(
+                        self._alloc_with_evict(n_pages, owner=r.seq_id))
+            except OutOfPages:
+                for r, pages in zip(reqs, allocated):
+                    self.allocator.free(pages, owner=r.seq_id)
+                raise
+            slots = [self._free_slots.pop(0) for _ in range(n)]
 
-        n_pad = 1
-        while n_pad < n:
-            n_pad *= 2
-        if self._pp and n_pad % self._pp_m:
-            # the pipelined prefill microbatches its rows: pad to a
-            # microbatch multiple (padding rows repeat the last real row's
-            # tokens AND pages, so duplicate scatter writes stay idempotent)
-            n_pad = -(-n_pad // self._pp_m) * self._pp_m
-        tokens = np.zeros((n_pad, bucket), np.int32)
-        lens = np.zeros((n_pad,), np.int32)
-        maps = np.zeros((n_pad, n_pages), np.int32)
-        tables = []
-        for i, r in enumerate(reqs):
-            tokens[i, :len(r.prompt_ids)] = r.prompt_ids
-            lens[i] = len(r.prompt_ids)
-            maps[i] = allocated[i]
-            table = np.full((self.pages_per_seq,), TRASH_PAGE, np.int32)
-            table[:n_pages] = allocated[i]
-            self.block_tables[slots[i]] = table
-            tables.append(table)
-        tokens[n:] = tokens[n - 1]
-        lens[n:] = lens[n - 1]
-        maps[n:] = maps[n - 1]
+            n_pad = 1
+            while n_pad < n:
+                n_pad *= 2
+            if self._pp and n_pad % self._pp_m:
+                # the pipelined prefill microbatches its rows: pad to a
+                # microbatch multiple (padding rows repeat the last real
+                # row's tokens AND pages, so duplicate scatter writes stay
+                # idempotent)
+                n_pad = -(-n_pad // self._pp_m) * self._pp_m
+            tokens = np.zeros((n_pad, bucket), np.int32)
+            lens = np.zeros((n_pad,), np.int32)
+            maps = np.zeros((n_pad, n_pages), np.int32)
+            tables = []
+            for i, r in enumerate(reqs):
+                tokens[i, :len(r.prompt_ids)] = r.prompt_ids
+                lens[i] = len(r.prompt_ids)
+                maps[i] = allocated[i]
+                table = np.full((self.pages_per_seq,), TRASH_PAGE, np.int32)
+                table[:n_pages] = allocated[i]
+                self.block_tables[slots[i]] = table
+                tables.append(table)
+            tokens[n:] = tokens[n - 1]
+            lens[n:] = lens[n - 1]
+            maps[n:] = maps[n - 1]
 
         with profiling.annotate("engine.prefill"):
             self._count("engine.dispatches")
@@ -3155,29 +3201,30 @@ class PagedInferenceEngine(EngineBase):
                 **self._slots_kw(slots + slots[-1:] * (n_pad - n)))
             self._key, sub = jax.random.split(self._key)
             firsts = self._sample(logits, sub, self.sampling)
-        self._count("engine.prefill_tokens", int(lens[:n].sum()))
-        self._count_prefill_padded(tokens.size, rows=n_pad,
-                                   n_true=int(lens[:n].sum()))
-        self._count("engine.batched_admissions", n)
+        with profiling.annotate("engine.admission.activate"):
+            self._count("engine.prefill_tokens", int(lens[:n].sum()))
+            self._count_prefill_padded(tokens.size, rows=n_pad,
+                                       n_true=int(lens[:n].sum()))
+            self._count("engine.batched_admissions", n)
 
-        if any(r.grammar is not None for r in reqs):
-            # grammar groups stay synchronous (FSM needs the values now)
-            finished: List[SequenceResult] = []
-            (firsts_host,) = self._fetch(firsts)
+            if any(r.grammar is not None for r in reqs):
+                # grammar groups stay synchronous (FSM needs the values now)
+                finished: List[SequenceResult] = []
+                (firsts_host,) = self._fetch(firsts)
+                for i, req in enumerate(reqs):
+                    early = self._activate_paged(req, slots[i], tables[i], 0,
+                                                 logits[i:i + 1],
+                                                 int(firsts_host[i]))
+                    if early is not None:
+                        finished.append(early)
+                return finished
+            # deferred batch admission: ONE coalesced fetch at the next
+            # drain/flush covers the whole wave (docs/performance.md)
             for i, req in enumerate(reqs):
-                early = self._activate_paged(req, slots[i], tables[i], 0,
-                                             logits[i:i + 1],
-                                             int(firsts_host[i]))
-                if early is not None:
-                    finished.append(early)
-            return finished
-        # deferred batch admission: ONE coalesced fetch at the next
-        # drain/flush covers the whole wave (docs/performance.md)
-        for i, req in enumerate(reqs):
-            st = self._preactivate_paged(req, slots[i], tables[i], 0)
-            self._dev_edit_token(slots[i], firsts[i])
-            self._defer_first(st, firsts, i)
-        return []
+                st = self._preactivate_paged(req, slots[i], tables[i], 0)
+                self._dev_edit_token(slots[i], firsts[i])
+                self._defer_first(st, firsts, i)
+            return []
 
     def _grow(self, slot: int) -> None:
         st = self._active[slot]

@@ -56,23 +56,43 @@ SITES = frozenset({
     # engine layer: every span goes through profiling.annotate, so each
     # name is also a TraceAnnotation in an XProf capture and a METRICS
     # timer (<name>.total_s / .count).  engine.tick wraps the whole tick
-    # (EngineBase.step); .admission / .eviction are the paged tick's
-    # phases; engine.prefill and engine.decode_step time the DISPATCH of a
-    # program, engine.fetch the time the host then blocks on the chip;
+    # (EngineBase.step) and engine.tick.* are its phases, siblings in
+    # this order with no time of one inside another: .reap (deadlines,
+    # results of an out-of-tick flush, the lag flush before a sync path;
+    # opened only when there is a deadline, a flushed result or a lag),
+    # .prefill_chunk, .admission, .first_tokens (the fetch that waits
+    # for the tick's prefills and the first tokens' commit), .eviction,
+    # .decode (whichever decode program runs, from its set-up through
+    # its commit)
+    "engine.tick",
+    "engine.tick.reap",
+    "engine.tick.prefill_chunk",
+    "engine.tick.admission",
+    "engine.tick.first_tokens",
+    "engine.tick.eviction",
+    "engine.tick.decode",
+    # under the phases: engine.prefill and engine.decode_step time the
+    # DISPATCH of a program, engine.fetch the time the host then blocks
+    # on the chip; engine.admission.stage / .activate the host work of
+    # one admission group before its prefill dispatch (pages, slots, the
+    # numpy rows) and after it (the slot's registration, the first
+    # token's way to the resident state); engine.scan_setup everything a
+    # decode tick does before its dispatch (DFA tables, the key split,
+    # the uploads of the host mirrors, the work counters);
     # engine.grammar_mask the FSM masks and DFA tables built on the host
     # for a tick; engine.commit the host loop that appends the tick's
     # tokens and retires finished sequences
-    "engine.tick",
-    "engine.tick.admission",
     "engine.prefill",
     "engine.decode_step",
-    "engine.tick.eviction",
+    "engine.admission.stage",
+    "engine.admission.activate",
+    "engine.scan_setup",
     "engine.fetch",
     "engine.grammar_mask",
     "engine.commit",
     # one span per retired sequence from its arrival to its newest token
     # (explicit times on the engine's clock; args carry seq, queue_wait_s,
-    # prefill_s, decode_s, tokens, preemptions) — the record
+    # prefill_s, decode_s, stall_s, tokens, preemptions) — the record
     # obs/critical_path.py reads for the run that owns the seq
     "engine.request",
     # overload survival (engine/paged.py): KV page spill-to-host on

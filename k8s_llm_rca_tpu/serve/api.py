@@ -117,8 +117,10 @@ class Run:
         "prompt_tokens": 0, "completion_tokens": 0, "total_tokens": 0})
     # the engine's own clock on the run (None until it settles, and from
     # backends that time nothing): seconds queued before a slot, to the
-    # first token, and from the first token to the last; with the
-    # engine's ``seq``, which the run's ``engine.request`` span carries
+    # first token, from the first token to the last, and of those the
+    # seconds it stood behind the ticks' prefill phases (``stall_s``);
+    # with the engine's ``seq``, which the run's ``engine.request`` span
+    # carries
     timing: Optional[Dict[str, Any]] = None
     error: Optional[str] = None
     # book-keeping
@@ -516,7 +518,8 @@ class AssistantService:
                                   "t_arrival": timing.t_arrival,
                                   "queue_wait_s": timing.queue_wait_s,
                                   "ttft_s": timing.ttft_s,
-                                  "decode_s": timing.decode_s}
+                                  "decode_s": timing.decode_s,
+                                  "stall_s": timing.stall_s}
                 run.completed_at = int(self._clock.time())
                 del self._inflight[handle]
                 if self._journal is not None:

@@ -101,7 +101,7 @@ class TestLifecycle:
         assert res.seq_id == sid and res.completion_tokens == 9
         assert res.timing == SequenceTiming(
             seq_id=sid, t_arrival=1.0, t_admitted=1.25, t_first=1.25,
-            t_last=1.75, preemptions=0)
+            t_last=1.75, preemptions=0, stall_s=0.0)
         assert (res.timing.queue_wait_s, res.timing.ttft_s,
                 res.timing.decode_s) == (0.25, 0.25, 0.5)
         snap = counters.snapshot()
@@ -169,6 +169,7 @@ class TestLifecycle:
         assert (sp.t0, sp.t1) == (0.0, 1.0)
         assert sp.args == {"seq": sid, "queue_wait_s": 0.5,
                            "prefill_s": 0.0, "decode_s": 0.5,
+                           "stall_s": 0.0,
                            "tokens": 9, "preemptions": 0}
 
 
@@ -436,7 +437,7 @@ class TestServePassThrough:
         # first pump at 0.5 (admit, first token, 4 more), second at 1.0
         assert run.timing == {"seq": run.timing["seq"], "t_arrival": 0.0,
                               "queue_wait_s": 0.5, "ttft_s": 0.5,
-                              "decode_s": 0.5}
+                              "decode_s": 0.5, "stall_s": 0.0}
         (sp,) = [s for s in tr.spans if s.name == "serve.run"]
         (req,) = [s for s in tr.spans if s.name == "engine.request"]
         assert sp.args["seq"] == req.args["seq"] == run.timing["seq"]

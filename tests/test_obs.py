@@ -665,6 +665,27 @@ class TestSiteCoverage:
         assert {"engine.spill", "engine.restore"} \
             <= tr_spill.emitted_names()
 
+        # (5b) the tick phases a plain run does not open: a prompt over
+        # the chunk budget prefills one chunk a tick
+        # (engine.tick.prefill_chunk), and a sequence with a deadline
+        # gives every tick something to reap (engine.tick.reap)
+        tr_chunk = Tracer(clock=VirtualClock())
+        tracers.append(tr_chunk)
+        chunk_eng = make_engine(
+            TINY.replace(max_seq_len=64),
+            EngineConfig(max_batch=2, max_seq_len=64,
+                         page_size=8, num_pages=24,
+                         prefill_buckets=(16, 32), max_new_tokens=2,
+                         temperature=0.0, decode_chunk=1,
+                         prefix_cache=False, prefill_chunk_budget=8),
+            engine.params, tok, use_kernel=False)
+        with obs_trace.tracing(tr_chunk):
+            chunk_eng.submit(list(range(1, 21)), deadline_s=1e9)
+            while chunk_eng.has_work:
+                chunk_eng.step()
+        assert {"engine.tick.prefill_chunk", "engine.tick.reap"} \
+            <= tr_chunk.emitted_names()
+
         # (6) self-heal sites: wedge a replica on a watchdog-armed echo
         # cluster — SUSPECT/DEAD verdicts, poison-run quarantine (K=1),
         # supervisor restart and the MTTD/MTTR spans all fire

@@ -331,11 +331,9 @@ class TestWarmStart:
         assert eng0.prefix_store is store and eng1.prefix_store is store
 
         def _prefills(fn):
-            # prefill dispatches = direct prefill spans + chunk spans
+            # prefill dispatches, a chunk's among them
             def n():
-                snap = METRICS.snapshot()
-                return (snap.get("engine.prefill.count", 0)
-                        + snap.get("engine.tick.prefill_chunk.count", 0))
+                return METRICS.snapshot().get("engine.prefill.count", 0)
 
             before = n()
             out = fn()
@@ -470,9 +468,7 @@ class TestAcceptanceSweep:
         ecfg = _ecfg(prefill_chunk_budget=32)
 
         def prefill_spans():
-            snap = METRICS.snapshot()
-            return (snap.get("engine.prefill.count", 0)
-                    + snap.get("engine.tick.prefill_chunk.count", 0))
+            return METRICS.snapshot().get("engine.prefill.count", 0)
 
         def sweep(eng):
             before = prefill_spans()
