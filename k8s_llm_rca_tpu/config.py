@@ -10,8 +10,13 @@ frozen dataclass so drivers, tests and benches share one source of truth.
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
+
+# the published names of ``ModelConfig.mixer_types`` and the layer table's
+# letter for each
+LAYER_TYPE_LETTERS = {"mamba": "M", "attention": "*"}
 
 
 @dataclass(frozen=True)
@@ -31,15 +36,25 @@ class ModelConfig:
     ``qk_norm`` puts an RMSNorm with a learned gain over every query and
     key head before the rotary embedding, and ``rope_full_layers`` False
     leaves the rotary embedding to the window layers (``layer_cfg`` is
-    the block's view of one layer).  With a pattern (``nemotron_h``,
-    models/nemotron_h.py) a layer is ONE mixer or ONE feed-forward part
-    under one norm and one residual, its kind the pattern's letter: ``M``
-    a Mamba-2 mixer (the ``ssm_*`` fields), ``E`` an expert layer (router
-    kind, latent and shared widths, the experts held here), ``*``
-    attention.  The engine derives what it holds per
-    slot (pages for the attention layers, a ring for the window layers,
-    a recurrent state for the Mamba layers) from these tables and from
-    nothing else.
+    the block's view of one layer).  With a layer table
+    (models/nemotron_h.py walks it; ``layer_table`` is its one form, a
+    letter a layer) a layer is ONE mixer or ONE feed-forward part under
+    one norm and one residual, its kind the letter: ``M`` a Mamba-2 mixer
+    (the ``ssm_*`` fields), ``E`` an expert layer (router kind, latent and
+    shared widths, the experts held here), ``*`` attention.  The table is
+    stated as ``layer_pattern``, the letters themselves (``nemotron_h``),
+    or as ``mixer_types``, the published names ``mamba`` / ``attention``
+    (``granitemoehybrid``).  ``block_mlp_size`` makes every layer of the
+    table a block of TWO sublayers: behind the mixer, under a norm and a
+    residual of its own, a gated MLP of that width.  Four scale factors,
+    each 1 (or 0 for the softmax scale) where a model has none:
+    ``embedding_multiplier`` on the embedded tokens,
+    ``residual_multiplier`` on what every sublayer adds to the residual
+    stream, ``logits_scaling`` dividing the logits, and ``attn_scale``,
+    the model's own softmax scale in place of ``1 / sqrt(head_dim)``.
+    The engine derives what it holds per slot (pages for the attention
+    layers, a ring for the window layers, a recurrent state for the Mamba
+    layers) from these tables and from nothing else.
     """
 
     name: str = "tiny"
@@ -79,9 +94,21 @@ class ModelConfig:
     qk_norm: bool = False              # RMSNorm + gain over each q, k head
     rope_full_layers: bool = True      # False: rotary embedding on the
                                        # sliding layers only
-    # --- the layer table (empty = the Llama block in every layer) ---
-    layer_pattern: str = ""
-    use_rope: bool = True              # nemotron_h's attention applies none
+    # --- the layer table (empty = the Llama block in every layer),
+    # stated one way or the other; ``layer_table`` is what is walked ---
+    layer_pattern: str = ""            # a letter a layer: M | E | *
+    mixer_types: Tuple[str, ...] = ()  # a name a layer: "mamba" |
+                                       # "attention"
+    block_mlp_size: int = 0            # >0: a gated MLP of this width
+                                       # behind every mixer of the table
+    use_rope: bool = True              # a table's attention applies none
+    # --- scale factors (granitemoehybrid's four) ---
+    embedding_multiplier: float = 1.0  # x = E[token] * this
+    residual_multiplier: float = 1.0   # x = x + this * sublayer(norm(x))
+    logits_scaling: float = 1.0        # logits = head(x) / this
+    attn_scale: float = 0.0            # softmax(q k^T * this); 0 =
+                                       # 1 / sqrt(head_dim).  Folded into
+                                       # the query (``q_fold``)
     # Mamba-2 mixer: heads x head_dim inner width, B and C shared by the
     # heads of a group, a [heads, head_dim, state] recurrent state per
     # sequence kept in ``ssm_state_dtype`` plus the depthwise
@@ -122,6 +149,10 @@ class ModelConfig:
 
     def __post_init__(self):
         if self.layer_pattern:
+            if self.mixer_types:
+                raise ValueError(
+                    "layer_pattern and mixer_types both state the layer "
+                    "table: give one")
             if len(self.layer_pattern) != self.n_layers:
                 raise ValueError(
                     f"layer_pattern {self.layer_pattern!r} has "
@@ -131,13 +162,49 @@ class ModelConfig:
             if unknown:
                 raise ValueError(
                     f"layer_pattern {self.layer_pattern!r}: unknown layer "
-                    f"kind {sorted(unknown)[0]!r} (M Mamba-2, E experts, "
-                    f"* attention)")
+                    f"kind {sorted(unknown)[0]!r} (M: a Mamba-2 mixer, "
+                    f"which keeps a recurrent state per sequence; E: "
+                    f"experts, which keep nothing; *: attention, which "
+                    f"keeps keys and values in pages)")
+        if self.mixer_types:
+            if len(self.mixer_types) != self.n_layers:
+                raise ValueError(
+                    f"mixer_types has {len(self.mixer_types)} entries for "
+                    f"n_layers={self.n_layers}")
+            unknown = set(self.mixer_types) - set(LAYER_TYPE_LETTERS)
+            if unknown:
+                raise ValueError(
+                    f"mixer_types: unknown layer kind "
+                    f"{sorted(unknown)[0]!r} (mamba: a Mamba-2 mixer, which "
+                    f"keeps a recurrent state per sequence; attention: "
+                    f"which keeps keys and values in pages)")
+        if self.block_mlp_size and (not self.layer_table or self.n_experts):
+            raise ValueError(
+                f"block_mlp_size={self.block_mlp_size} is a dense gated "
+                f"MLP behind every mixer of a layer table: the Llama block "
+                f"has its own MLP (intermediate_size), and beside experts "
+                f"(n_experts={self.n_experts}) it is not built")
+        if not self.layer_table and (self.embedding_multiplier != 1.0
+                                     or self.residual_multiplier != 1.0):
+            raise ValueError(
+                f"embedding_multiplier={self.embedding_multiplier} and "
+                f"residual_multiplier={self.residual_multiplier} are "
+                f"applied by the layer table's programs "
+                f"(models/nemotron_h.py); the Llama block's loops apply "
+                f"neither (logits_scaling and attn_scale they do)")
+        if self.attn_scale and math.frexp(self.q_fold)[0] != 0.5:
+            raise ValueError(
+                f"attn_scale={self.attn_scale} with head_dim="
+                f"{self.head_dim}: the softmax scale is folded into the "
+                f"query as attn_scale * sqrt(head_dim) = {self.q_fold}, "
+                f"which is exact in every dtype only for a power of two; "
+                f"another value needs the scale as an argument of the "
+                f"attention forms, which is not built")
         if self.attn_layer_types:
-            if self.layer_pattern:
+            if self.layer_table:
                 raise ValueError(
                     "attn_layer_types is the Llama block's: a model with "
-                    "a layer_pattern has its attention kind in the pattern")
+                    "a layer table has its attention kind in the table")
             if len(self.attn_layer_types) != self.n_layers:
                 raise ValueError(
                     f"attn_layer_types has {len(self.attn_layer_types)} "
@@ -154,7 +221,7 @@ class ModelConfig:
                     f"{self.n_window_layers} sliding_attention layers and "
                     f"attn_window={self.attn_window}")
         if not 0 <= self.n_dense_layers <= self.n_layers or (
-                self.n_dense_layers and self.layer_pattern):
+                self.n_dense_layers and self.layer_table):
             raise ValueError(
                 f"n_dense_layers={self.n_dense_layers} of the Llama block's "
                 f"{self.n_layers} layers")
@@ -165,6 +232,25 @@ class ModelConfig:
             raise ValueError(
                 f"experts {self.expert_first}..{last} held of a router "
                 f"over {self.n_router}")
+
+    @property
+    def layer_table(self) -> str:
+        """The layer table, a letter a layer (``M`` | ``E`` | ``*``),
+        however it was stated; empty for the Llama block in every layer.
+        The one table every program walks."""
+        if self.mixer_types:
+            return "".join(LAYER_TYPE_LETTERS[t] for t in self.mixer_types)
+        return self.layer_pattern
+
+    @property
+    def q_fold(self) -> float:
+        """What a query is multiplied by ahead of every attention form,
+        all of which scale by ``1 / sqrt(head_dim)``: the model's own
+        softmax scale over that one (a power of two, ``__post_init__``
+        holds it to that), 1 where the model has none."""
+        if not self.attn_scale:
+            return 1.0
+        return self.attn_scale * math.sqrt(self.head_dim)
 
     @property
     def n_router(self) -> int:
@@ -193,7 +279,7 @@ class ModelConfig:
     def n_kv_layers(self) -> int:
         """Layers that cache every token's keys and values in pages: the
         pool's layer axis."""
-        return (self.layer_pattern.count("*") if self.layer_pattern
+        return (self.layer_table.count("*") if self.layer_table
                 else self.n_layers - self.n_window_layers)
 
     @property
@@ -225,7 +311,7 @@ class ModelConfig:
     @property
     def n_ssm_layers(self) -> int:
         """Layers that keep a recurrent state per sequence."""
-        return self.layer_pattern.count("M")
+        return self.layer_table.count("M")
 
     @property
     def ssm_inner(self) -> int:
@@ -287,6 +373,21 @@ TINY_EXAONE_MOE = ModelConfig(
     router_kind="sigmoid", routed_scaling=2.5, moe_intermediate_size=96,
     shared_expert_size=96)
 
+# granitemoehybrid at toy widths: every kind of layer by its published name,
+# two sublayers a layer (a gated MLP behind every mixer), one group of B and
+# C, all four scale factors at values that are not 1, a softmax scale of
+# 1 / head_dim (a query fold of 1/4), attention without rotary embedding,
+# tied head
+TINY_GRANITE_HYBRID = ModelConfig(
+    name="tiny_granite_hybrid", n_layers=4,
+    mixer_types=("mamba", "attention", "mamba", "mamba"),
+    block_mlp_size=192, n_heads=4, n_kv_heads=2, head_dim=16,
+    use_rope=False, tie_embeddings=True,
+    ssm_heads=8, ssm_head_dim=16, ssm_groups=1, ssm_state_size=16,
+    ssm_conv_kernel=4, ssm_chunk=16,
+    embedding_multiplier=12.0, residual_multiplier=0.22,
+    logits_scaling=8.0, attn_scale=1.0 / 16)
+
 TINYLLAMA_1B = ModelConfig(
     name="tinyllama-1.1b",
     vocab_size=32000,
@@ -336,7 +437,8 @@ MIXTRAL_8X7B = ModelConfig(
 
 MODEL_REGISTRY = {
     c.name: c for c in (TINY, TINY_MOE, TINY_NEMOTRON_H, TINY_EXAONE_MOE,
-                        TINYLLAMA_1B, LLAMA3_8B, MIXTRAL_8X7B)
+                        TINY_GRANITE_HYBRID, TINYLLAMA_1B, LLAMA3_8B,
+                        MIXTRAL_8X7B)
 }
 
 

@@ -10,8 +10,10 @@ from k8s_llm_rca_tpu.models import encoder, mixtral  # noqa: F401
 
 def init_params(cfg, key, tensor_transform=None):
     """Seeded weights from the builder of the configuration's family: a
-    layer table (``cfg.layer_pattern``) is nemotron_h's, none is Llama's."""
+    model with a layer table (``cfg.layer_table``, however it is stated:
+    ``nemotron_h``'s one mixer a layer, ``granitemoehybrid``'s mixer and
+    MLP) is models/nemotron_h.py's, one without is Llama's."""
     from k8s_llm_rca_tpu.models import llama, nemotron_h
 
-    module = nemotron_h if cfg.layer_pattern else llama
+    module = nemotron_h if cfg.layer_table else llama
     return module.init_params(cfg, key, tensor_transform=tensor_transform)

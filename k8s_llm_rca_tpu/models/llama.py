@@ -226,6 +226,17 @@ def _kv_packed(cfg: ModelConfig, cache: KVCache) -> bool:
 # ---------------------------------------------------------------------------
 
 
+def embed(cfg: ModelConfig, params: Params, tokens: jnp.ndarray
+          ) -> jnp.ndarray:
+    """tokens [...] -> the residual stream's first value [..., H]: the
+    embedding's rows, times ``cfg.embedding_multiplier`` where the model
+    has one."""
+    x = gather_rows(params["embedding"], tokens).astype(jnp.dtype(cfg.dtype))
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
+
+
 def _w_mm(cfg: ModelConfig, x: jnp.ndarray, w) -> jnp.ndarray:
     """Every weight-matmul site funnels through here so
     ``cfg.fused_quant_matmul`` can swap the ``x @ dq(w)`` XLA expression
@@ -256,6 +267,11 @@ def _qkv(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
     if cfg.use_rope:
         q = apply_rope(q, angles, positions)
         k = apply_rope(k, angles, positions)
+    if cfg.attn_scale:
+        # the model's own softmax scale: every attention form scales by
+        # 1 / sqrt(head_dim), so the query carries the rest, a power of
+        # two (``ModelConfig.q_fold``) and so exact in every dtype
+        q = q * jnp.asarray(cfg.q_fold, q.dtype)
     return q, k, v
 
 
@@ -937,8 +953,12 @@ def _logits(cfg: ModelConfig, params: Params, x: jnp.ndarray) -> jnp.ndarray:
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     head = params["embedding"] if cfg.tie_embeddings else params["lm_head"]
     if cfg.fused_quant_matmul:
-        return qmm_head(x, head).astype(jnp.float32)
-    return jnp.einsum("bsh,vh->bsv", x, dq(head)).astype(jnp.float32)
+        logits = qmm_head(x, head).astype(jnp.float32)
+    else:
+        logits = jnp.einsum("bsh,vh->bsv", x, dq(head)).astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 # ---------------------------------------------------------------------------

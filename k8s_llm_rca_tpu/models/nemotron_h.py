@@ -1,7 +1,9 @@
-"""nemotron_h: a decoder whose layers are of three kinds, one a layer.
+"""The layer table: a decoder whose layers are of three kinds, one a layer
+(``nemotron_h``), or one and a gated MLP behind it (``granitemoehybrid``).
 
-``cfg.layer_pattern`` gives each layer its letter, and every layer is
-``x = x + mix(RMSNorm(x))`` with ``mix`` by the letter:
+``cfg.layer_table`` gives each layer its letter, and every layer is
+``x = x + r * mix(RMSNorm(x))`` with ``r = cfg.residual_multiplier`` (1
+for ``nemotron_h``) and ``mix`` by the letter:
 
 - ``M`` — a Mamba-2 mixer: ``[z | xBC | dt] = u W_in``; a depthwise causal
   convolution and SiLU over ``xBC``; ``dt = softplus(dt + dt_bias)``,
@@ -15,7 +17,23 @@
   projections (llama._experts, on the experts held here), and an
   always-on shared expert at the full width.
 - ``*`` — grouped-query attention with NO rotary embedding
-  (``cfg.use_rope`` False), the only kind that caches keys and values.
+  (``cfg.use_rope`` False), the only kind that caches keys and values;
+  its softmax scale is the model's where it states one (``cfg.q_fold``,
+  folded into the query by ``llama._qkv``).
+
+Where ``cfg.block_mlp_size`` is set (``granitemoehybrid``: the published
+``layer_types`` names ``mamba`` / ``attention``, held as
+``cfg.mixer_types``, are the letters ``M`` /
+``*``), every layer is a block of TWO sublayers, the mixer and then ``x =
+x + r * MLP(RMSNorm(x))`` with the gated MLP of ``llama._mlp``
+(``block_mlp``); the embedded tokens are scaled by
+``cfg.embedding_multiplier`` (``llama.embed``) and the logits divided by
+``cfg.logits_scaling`` over a head that may be tied (``llama._logits``).
+That second family has no module of its own: it calls ``init_params``,
+``mamba_prefill`` / ``mamba_decode``, ``attention_prefill``,
+``block_mlp``, ``prefill_rows`` and ``forward`` here, each of which reads
+the factors from ``cfg`` and leaves a program of the first family as it
+was where they are 1.
 
 The engine's programs (engine/paged.py) walk this table; here are the
 weights, the three mixers in their prefill and their decode form, and a
@@ -36,7 +54,6 @@ import jax.numpy as jnp
 
 from k8s_llm_rca_tpu.config import ModelConfig
 from k8s_llm_rca_tpu.models import llama
-from k8s_llm_rca_tpu.models.quant import gather_rows
 from k8s_llm_rca_tpu.ops import ssm
 from k8s_llm_rca_tpu.ops.attention import causal_attention
 from k8s_llm_rca_tpu.ops.norms import rms_norm
@@ -56,12 +73,19 @@ def init_params(cfg: ModelConfig, key: jax.Array,
     projection is scaled by ``1 / sqrt(depth)`` (one residual a layer;
     the family's ``rescale_prenorm_residual``), ``depth`` the whole
     model's (``cfg.init_layers``) where these layers are a stage of a
-    deeper one."""
+    deeper one; a model with a ``residual_multiplier`` bounds its stream
+    by that factor instead and its output projections are not rescaled.
+    The embedding's rows have the variance that lets the embedded tokens
+    enter the stream at 1 (``1 / embedding_multiplier``).  A block of two
+    sublayers (``cfg.block_mlp_size``) has in every layer ``mlp_norm``,
+    ``w_gate``, ``w_up`` and ``w_down`` beside the mixer's weights; a tied
+    head (``cfg.tie_embeddings``) makes no ``lm_head``."""
     dtype = jnp.dtype(cfg.dtype)
     h = cfg.hidden_size
     keys = jax.random.split(key, cfg.n_layers + 2)
     scale = 1.0 / math.sqrt(h)
-    out_scale = 1.0 / math.sqrt(cfg.init_layers or cfg.n_layers)
+    out_scale = (1.0 / math.sqrt(cfg.init_layers or cfg.n_layers)
+                 if cfg.residual_multiplier == 1.0 else 1.0)
     tt = tensor_transform or (lambda w, **_: w)
 
     def dense(k, shape, sc, **tt_kw):
@@ -72,7 +96,7 @@ def init_params(cfg: ModelConfig, key: jax.Array,
         return out
 
     layers = []
-    for i, kind in enumerate(cfg.layer_pattern):
+    for i, kind in enumerate(cfg.layer_table):
         lk = jax.random.split(keys[i], 8)
         if kind == "M":
             inner, heads = cfg.ssm_inner, cfg.ssm_heads
@@ -124,18 +148,50 @@ def init_params(cfg: ModelConfig, key: jax.Array,
                 "wv": dense(lk[2], (h, kv), scale),
                 "wo": dense(lk[3], (q, h), out_scale / math.sqrt(q)),
             }
+        if cfg.block_mlp_size:
+            inter = cfg.block_mlp_size
+            mk = jax.random.split(jax.random.fold_in(keys[i], 1), 3)
+            layer.update({
+                "mlp_norm": jnp.ones((h,), dtype),
+                "w_gate": dense(mk[0], (h, inter), scale),
+                "w_up": dense(mk[1], (h, inter), scale),
+                "w_down": dense(mk[2], (inter, h),
+                                out_scale / math.sqrt(inter)),
+            })
         layers.append(layer)
-    return {
-        "embedding": dense(keys[-2], (cfg.vocab_size, h), 1.0, axis=0),
+    params = {
+        "embedding": dense(keys[-2], (cfg.vocab_size, h),
+                           1.0 / cfg.embedding_multiplier, axis=0),
         "final_norm": jnp.ones((h,), dtype),
         "layers": layers,
-        "lm_head": dense(keys[-1], (cfg.vocab_size, h), scale, axis=0),
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = dense(keys[-1], (cfg.vocab_size, h), scale,
+                                  axis=0)
+    return params
 
 
 # ---------------------------------------------------------------------------
 # the three mixers
 # ---------------------------------------------------------------------------
+
+
+def _residual(cfg: ModelConfig, x: jnp.ndarray, out: jnp.ndarray
+              ) -> jnp.ndarray:
+    """``x + out``, ``out`` scaled by ``cfg.residual_multiplier`` where
+    the model has one: what every sublayer of the table ends in."""
+    if cfg.residual_multiplier != 1.0:
+        out = out * jnp.asarray(cfg.residual_multiplier, out.dtype)
+    return x + out
+
+
+def block_mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray
+              ) -> jnp.ndarray:
+    """The second sublayer of a block of two (``cfg.block_mlp_size``), over
+    x [..., H]: the gated MLP of ``llama._mlp`` under its own norm and
+    residual."""
+    u = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
+    return _residual(cfg, x, llama._mlp(cfg, layer, u))
 
 
 def _mamba_split(cfg: ModelConfig, layer: Params, u: jnp.ndarray):
@@ -189,7 +245,7 @@ def mamba_prefill(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
     dt = jnp.where(true[..., None], dt, 0.0)
     y, state = ssm.ssm_chunk_scan(xs, dt, -jnp.exp(layer["A_log"]), b, c,
                                   layer["D"], cfg.ssm_chunk)
-    return x + _mamba_out(cfg, layer, y, z), state, tail
+    return _residual(cfg, x, _mamba_out(cfg, layer, y, z)), state, tail
 
 
 def mamba_decode(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
@@ -205,8 +261,8 @@ def mamba_decode(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
     dt = jax.nn.softplus(dt.astype(F32) + layer["dt_bias"])
     y, ssm_state = ssm.ssm_state_update(
         ssm_state, xs, dt, -jnp.exp(layer["A_log"]), b, c, layer["D"])
-    return (x + _mamba_out(cfg, layer, y, z)[:, None], ssm_state,
-            conv_state)
+    return (_residual(cfg, x, _mamba_out(cfg, layer, y, z)[:, None]),
+            ssm_state, conv_state)
 
 
 def expert_layer(cfg: ModelConfig, layer: Params, x: jnp.ndarray
@@ -222,7 +278,7 @@ def expert_layer(cfg: ModelConfig, layer: Params, x: jnp.ndarray
     out = (llama._w_mm(cfg, routed, layer["w_latent_up"])
            + llama._w_mm(cfg, shared, layer["w_shared_down"]))
     n_local = llama.n_local_pairs(cfg, topi)
-    return x + out, n_local
+    return _residual(cfg, x, out), n_local
 
 
 def attention_prefill(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
@@ -232,8 +288,16 @@ def attention_prefill(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
     q, k, v = llama._decode_qkv(cfg, layer, x, None, None)
     attn = (causal_attention(q, k, v, lengths) if attention_fn is None
             else attention_fn(q, k, v))
-    x = x + llama._w_mm(cfg, attn.reshape(b, s, cfg.q_dim), layer["wo"])
+    x = attention_out(cfg, layer, x, attn.reshape(b, s, cfg.q_dim))
     return x, k.reshape(b, s, cfg.kv_dim), v.reshape(b, s, cfg.kv_dim)
+
+
+def attention_out(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
+                  attn: jnp.ndarray) -> jnp.ndarray:
+    """The attention sublayer's end, shared by its prefill and decode
+    forms: attn [B, S, q_dim] through the output projection onto the
+    residual stream."""
+    return _residual(cfg, x, llama._w_mm(cfg, attn, layer["wo"]))
 
 
 # ---------------------------------------------------------------------------
@@ -248,13 +312,13 @@ def _stack(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     the attention layers [La, N, S, kv_dim], the Mamba layers' states
     [Lm, N, ...], the local-pair count, and how many expert-layer calls
     ran over the compact form)."""
-    x = gather_rows(params["embedding"], tokens).astype(jnp.dtype(cfg.dtype))
+    x = llama.embed(cfg, params, tokens)
     attention_fn = None
     if llama.prefill_uses_flash(use_flash, tokens.shape[1]):
         attention_fn = llama._flash_attention_fn(lengths, None)
     ks, vs, states, tails = [], [], [], []
     pairs = []
-    for kind, layer in zip(cfg.layer_pattern, params["layers"]):
+    for kind, layer in zip(cfg.layer_table, params["layers"]):
         if kind == "M":
             x, state, tail = mamba_prefill(cfg, layer, x, lengths)
             states.append(state.astype(jnp.dtype(cfg.ssm_state_dtype)))
@@ -267,6 +331,8 @@ def _stack(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
                                         attention_fn)
             ks.append(k)
             vs.append(v)
+        if cfg.block_mlp_size:
+            x = block_mlp(cfg, layer, x)
     return x, (jnp.stack(ks), jnp.stack(vs), jnp.stack(states),
                jnp.stack(tails), sum(pairs, jnp.int32(0)),
                llama.n_compact_overflows(cfg, tokens.size, pairs))

@@ -80,7 +80,7 @@ def build_service(args) -> AssistantService:
     tokenizer = get_tokenizer(vocab_size=model_cfg.vocab_size)
     bits = 4 if args.int4 else 8 if args.int8 else None
     if args.weights:
-        if model_cfg.layer_pattern:
+        if model_cfg.layer_table:
             raise SystemExit(
                 f"--weights: models/loader.py reads Llama-family "
                 f"checkpoints; {model_cfg.name!r} has a layer table")

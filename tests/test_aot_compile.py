@@ -231,6 +231,137 @@ class TestDecodeStepUpdatesTheStateInPlace:
         assert mem.alias_size_in_bytes >= state_bytes + pages_bytes
         assert mem.temp_size_in_bytes < state_bytes // 10
 
+    @staticmethod
+    def _granite(layer_types):
+        """granite-4.0-h-micro at its published widths, the given layers."""
+        from k8s_llm_rca_tpu.config import ModelConfig
+
+        return ModelConfig(
+            name=f"granite-{len(layer_types)}-layers", vocab_size=100352,
+            hidden_size=2048, n_layers=len(layer_types),
+            mixer_types=tuple(layer_types), block_mlp_size=8192, n_heads=32,
+            n_kv_heads=8, head_dim=64, use_rope=False, max_seq_len=4096,
+            dtype="bfloat16", tie_embeddings=True, ssm_heads=64,
+            ssm_head_dim=64, ssm_groups=1, ssm_state_size=128,
+            ssm_chunk=256, embedding_multiplier=12.0,
+            residual_multiplier=0.22, logits_scaling=8.0,
+            attn_scale=0.015625)
+
+    @pytest.mark.parametrize("program", ["decode", "prefill-8x512"])
+    def test_no_state_is_copied_behind_a_block_of_two_sublayers(
+            self, chip, monkeypatch, program):
+        """``granite4-h-micro.chat-open`` at the configuration's own widths
+        (2 of its 40 layers: a Mamba-2 mixer and an attention layer, each
+        with its gated MLP behind it; 64 slots, the cell's pool).  A slot
+        keeps 2.1 MB of recurrent state a Mamba layer, 4.83 GB over 36
+        layers and 64 slots: more than a third of what the chip holds
+        beside the weights, so a program that copied it would not fit.
+        The stepwise decode program and the 8 x 512 batched prefill (its
+        rows' states scattered into their slots) copy no array of the
+        slots' state, and keep state and pages in the buffers they were
+        donated in."""
+        import re
+
+        from k8s_llm_rca_tpu.engine import paged
+        from k8s_llm_rca_tpu.models import nemotron_h
+
+        cfg = self._granite(("mamba", "attention"))
+        params = _described(chip, jax.eval_shape(
+            lambda: nemotron_h.init_params(cfg, jax.random.PRNGKey(0))))
+        assert "lm_head" not in params
+        pool = _described(chip, jax.eval_shape(
+            lambda: paged.init_paged_cache(cfg, self.N_PAGES, self.PAGE,
+                                           n_slots=self.SLOTS)))
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        if program == "decode":
+            compiled = jax.jit(
+                paged.paged_decode_step, static_argnums=0, donate_argnums=2,
+                static_argnames="use_kernel").lower(
+                    cfg, params, pool, chip((self.SLOTS,), I32),
+                    chip((self.SLOTS,), I32),
+                    chip((self.SLOTS, cfg.max_seq_len // self.PAGE), I32),
+                    use_kernel=True).compile()
+        else:
+            compiled = jax.jit(
+                paged.paged_prefill_batch, static_argnums=0,
+                donate_argnums=2).lower(
+                    cfg, params, pool, chip((8, 512), I32), chip((8,), I32),
+                    chip((8, 512 // self.PAGE), I32),
+                    slots=chip((8,), I32)).compile()
+        text = compiled.as_text()
+        if program == "decode":
+            assert "tpu_custom_call" in text        # the attention kernel
+
+        state = rf"f32\[(1,)?{self.SLOTS},64,64,128\]"
+        assert re.search(state, text)               # it is there, updated
+        copies = [line for line in text.splitlines()
+                  if re.search(rf"= {state}\S* copy\(", line)]
+        assert not copies, copies[:2]
+        mem = compiled.memory_analysis()
+        state_bytes = self.SLOTS * 64 * 64 * 128 * 4
+        pages_bytes = 2 * self.N_PAGES * self.PAGE * cfg.kv_dim * 2
+        assert mem.alias_size_in_bytes >= state_bytes + pages_bytes
+        # decode: a step's activations; prefill: one 512-row's, and the
+        # eight rows' states on their way to the slots
+        assert mem.temp_size_in_bytes < (
+            state_bytes // 10 if program == "decode" else state_bytes)
+
+
+    def test_the_whole_model_updates_each_state_once_a_step(
+            self, chip, monkeypatch):
+        """``granite4-h-micro.chat-open`` whole: all 40 layers, 64 slots,
+        13.4 GB of arguments.  Arguments and one more copy of the 4.8 GB
+        state do not fit the chip together, and XLA's rematerialization
+        pass, which counts every in-place update as a new buffer, then
+        duplicates the first Mamba layer's update
+        (``add_dynamic-update-slice_fusion.35.remat`` and ``.remat2``, both
+        on the program's parameter): in place on the one donated buffer
+        that state moves on twice a step (seen on the chip, PR 44).  With
+        the options the engine compiles its decode programs with
+        (``paged.decode_compiler_options``) nothing is rematerialized,
+        nothing of the state's shape is copied and state and pages stay
+        where they were donated."""
+        import re
+
+        from k8s_llm_rca_tpu.config import TINY, TINY_GRANITE_HYBRID
+        from k8s_llm_rca_tpu.engine import paged
+        from k8s_llm_rca_tpu.models import nemotron_h
+
+        cfg = self._granite((("mamba",) * 5 + ("attention",)
+                             + ("mamba",) * 4) * 4)
+        assert (cfg.n_layers, cfg.n_ssm_layers, cfg.n_kv_layers) == (40, 36,
+                                                                     4)
+        assert paged.decode_compiler_options(cfg) == {}       # a CPU here
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert paged.decode_compiler_options(TINY) == {}
+        assert paged.decode_compiler_options(TINY_GRANITE_HYBRID)
+        params = _described(chip, jax.eval_shape(
+            lambda: nemotron_h.init_params(cfg, jax.random.PRNGKey(0))))
+        pool = _described(chip, jax.eval_shape(
+            lambda: paged.init_paged_cache(cfg, self.N_PAGES, self.PAGE,
+                                           n_slots=self.SLOTS)))
+        compiled = jax.jit(
+            paged.paged_decode_step, static_argnums=0, donate_argnums=2,
+            static_argnames="use_kernel").lower(
+                cfg, params, pool, chip((self.SLOTS,), I32),
+                chip((self.SLOTS,), I32),
+                chip((self.SLOTS, 4096 // self.PAGE), I32),
+                use_kernel=True).compile(
+                    compiler_options=paged.decode_compiler_options(cfg))
+        text = compiled.as_text()
+        assert not re.findall(r"%\S*remat\d* = ", text)
+        state = rf"f32\[36,{self.SLOTS},64,64,128\]"
+        updates = re.findall(
+            rf"%(add_dynamic-update-slice_fusion\S*) = {state}", text)
+        assert len(updates) == len(set(updates)) == cfg.n_ssm_layers
+        assert not [line for line in text.splitlines()
+                    if re.search(rf"= {state}\S* copy\(", line)]
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= sum(
+            a.size * a.dtype.itemsize
+            for a in jax.tree_util.tree_leaves(pool))
+        assert mem.temp_size_in_bytes < 100e6
+
 
 class TestDecodeStepWritesTheRingInPlace:
     """The stepwise decode program of ``k-exaone-d5.longdump-reason`` at the
