@@ -249,18 +249,27 @@ def mamba_prefill(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
 
 
 def mamba_decode(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
-                 ssm_state: jnp.ndarray, conv_state: jnp.ndarray):
+                 ssm_state: jnp.ndarray, conv_state: jnp.ndarray,
+                 pool_layer=None, slots: Optional[ssm.LiveSlots] = None):
     """One Mamba-2 layer for one new position of every slot: x [B, 1, H],
     ssm_state [B, heads, head_dim, N], conv_state [B, kernel - 1,
-    conv_dim].  Returns (x', ssm_state', conv_state')."""
+    conv_dim].  Returns (x', ssm_state', conv_state').  With
+    ``pool_layer`` the state is every Mamba layer's as the pool keeps it,
+    [layers, B, heads, head_dim, N], of which the kernel moves layer
+    ``pool_layer`` on in place, for the live ``slots`` alone
+    (``ssm.ssm_state_update_in_place``); it comes back whole."""
     u = rms_norm(x[:, 0], layer["norm"], cfg.rms_norm_eps)
     z, xbc, dt = _mamba_split(cfg, layer, u)
     xbc, conv_state = ssm.conv_step(xbc, layer["conv_w"], layer["conv_b"],
                                     conv_state)
     xs, b, c = _xbc_split(cfg, xbc)
     dt = jax.nn.softplus(dt.astype(F32) + layer["dt_bias"])
-    y, ssm_state = ssm.ssm_state_update(
-        ssm_state, xs, dt, -jnp.exp(layer["A_log"]), b, c, layer["D"])
+    operands = (xs, dt, -jnp.exp(layer["A_log"]), b, c, layer["D"])
+    if pool_layer is None:
+        y, ssm_state = ssm.ssm_state_update(ssm_state, *operands)
+    else:
+        y, ssm_state = ssm.ssm_state_update_in_place(
+            ssm_state, pool_layer, *operands, slots)
     return (_residual(cfg, x, _mamba_out(cfg, layer, y, z)[:, None]),
             ssm_state, conv_state)
 

@@ -1,6 +1,8 @@
 """Mamba-2 state-space ops: the chunked scan a prefill runs, the one-step
 update a decode step runs, and the depthwise causal convolution in front
-of both.  Plain XLA (einsums and one ``lax.scan`` over chunks): the
+of both.  Plain XLA (einsums and one ``lax.scan`` over chunks) but for
+the decode step where the engine runs its kernels, whose update is one
+Pallas call on the slots' pool (``ssm_state_update_in_place``, below).  The
 recurrence is
 
     h_t = exp(dt_t * A) * h_{t-1} + dt_t * x_t (x) B_t
@@ -18,17 +20,49 @@ positions are kept out of it.
 
 ``ssm_chunk_scan`` and ``ssm_state_update`` are the names the benchmark's
 readers know the two by (benchmarks/trace/ssm_costs.py); a kernel that
-replaces either keeps its name.
+replaces either keeps its name, as the decode step's does.
+
+The decode step's kernel (``ssm_state_update_in_place``).  XLA runs the
+update as two passes over every slot's state (``h' = decay h + dx (x) B``
+in place, then ``y = h' . C`` over what it wrote), for a slot that holds
+no sequence as for one that does: at 2.1 MB a slot and layer
+(granite-4.0-h-micro) the state is most of what a decode step moves.  The
+kernel takes the pool's ``ssm_state`` [layers, slots, heads, head_dim,
+state] whole and by reference, with the layer as a prefetched scalar (as
+ops/paged_attention.py takes the pages: a layer sliced out and set back is
+two copies of it), and hands it back through ``input_output_aliases``.
+Its grid walks a LIST of the slots, the live ones first, and the tiles of
+heads of each: a live slot's tile is read once, moved on in float32,
+multiplied against ``C`` while it is held, and written where it was read;
+the steps past the last live slot name the block the last live step left,
+so nothing of a dead slot's state is read or written, and its ``y`` is
+zeros.  A tile is as many heads as ``_TILE_BYTES`` holds, in whole groups
+or in equal parts of one: a slot whole for granite-4.0-h-micro (one group
+of 64 heads, 2.1 MB), four groups of sixteen for nemotron-3-super (2.1 MB,
+two tiles a slot).  The state keeps its layout, head_dim on sublanes and
+the state's axis on lanes, so what is a vector over head_dim (``x`` in,
+``y`` out) enters and leaves as columns: the wrapper hands ``x`` over as
+[slots, head_dim, heads] and takes ``y`` back so (two transposes of a few
+hundred KB in XLA), a head's column is a static lane slice, and where a
+slot has several tiles a lane rotation brings the tile's heads to the
+front.  A head's decay is a scalar and comes through SMEM.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import functools
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 F32 = jnp.float32
+_LANES = 128
+# the largest tile of one slot's state the kernel holds at once (it keeps
+# four: two on their way in, two on their way out)
+_TILE_BYTES = 2 << 20
 
 
 def causal_conv(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
@@ -109,6 +143,192 @@ def ssm_state_update(h, x, dt, a, b, c, d):
     y = jnp.sum(new * ch[:, :, None, :], axis=-1) \
         + d.astype(F32)[None, :, None] * xf
     return y, new.astype(h.dtype)
+
+
+class LiveSlots(NamedTuple):
+    """The slots of a decode step as its state kernel walks them: ``order``
+    [slots] int32, the slots that hold a sequence first (each group in
+    slot order), and ``count`` [1] int32, how many of them do."""
+    order: jnp.ndarray
+    count: jnp.ndarray
+
+
+def live_slots(live: jnp.ndarray) -> LiveSlots:
+    """``live`` [slots] bool -> the list the kernel's grid walks (on the
+    device: a stable sort of 64 flags, once a step)."""
+    order = jnp.argsort(jnp.logical_not(live), stable=True)
+    return LiveSlots(order.astype(jnp.int32),
+                     jnp.sum(live, dtype=jnp.int32).reshape(1))
+
+
+def head_tile(heads: int, groups: int, head_bytes: int) -> int:
+    """Heads a tile of the state kernel holds: as many as ``_TILE_BYTES``
+    holds (one at least), in whole groups or in equal parts of one."""
+    rep = heads // groups
+    return max(t for t in range(1, heads + 1)
+               if heads % t == 0 and (rep % t == 0 or t % rep == 0)
+               and (t == 1 or t * head_bytes <= _TILE_BYTES))
+
+
+def _bf16_pieces(v, n: int):
+    """``v`` float32 as ``n`` bfloat16 arrays that add up to it, eight
+    bits of it a piece: three carry a float32 whole."""
+    pieces = []
+    for _ in range(n):
+        pieces.append(v.astype(jnp.bfloat16))
+        v = v - pieces[-1].astype(F32)
+    return pieces
+
+
+def _state_update_kernel(layer_ref, order_ref, count_ref, decay_ref, xt_ref,
+                         dt_ref, d_ref, b_ref, c_ref, h_ref, yt_ref, ho_ref,
+                         *, tile: int, rep: int, n_tiles: int,
+                         c_pieces: int):
+    """Grid step (i, j): tile ``j`` of the ``i``-th slot of the list.
+
+    ``decay_ref`` [slots, H] in SMEM (a head's decay is a scalar),
+    ``xt_ref`` [1, P, Hp] the slot's ``x`` with heads on lanes, ``dt_ref``
+    [1, 1, Hp], ``d_ref`` [1, Hp], ``b_ref``/``c_ref`` [1, G, N],
+    ``h_ref``/``ho_ref`` [1, 1, tile, P, N] the tile of the state where it
+    lies in the pool, ``yt_ref`` [1, P, Hp] the slot's ``y``, which stays
+    in VMEM over the slot's tiles.  A step past the live slots is given
+    the state block of the last live step, which it leaves alone, and its
+    own slot's ``y`` block, which it zeroes.
+
+    ``y = h' . C`` is a product on the MXU, which has nothing else to do
+    here, against ``C`` laid on 128 rows, so that every lane of the result
+    holds the head's ``y`` column: the 64 x 64 lane reductions a slot and
+    layer on the XLU set the kernel's pace where the copies should (my
+    chip run, PR 45).  ``h'`` goes in as three bfloat16 pieces and ``C``
+    as ``c_pieces`` (one where the model's activations are bfloat16), the
+    products of the leading orders summed in float32: a float32 product,
+    in three MXU passes where ``C`` is one piece."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    n_live = count_ref[0]
+    width = xt_ref.shape[-1]
+
+    def to_front(v):
+        # this tile's heads to lanes 0 .. tile - 1
+        if n_tiles == 1:
+            return v
+        return pltpu.roll(v, jax.lax.rem(width - j * tile, width), 1)
+
+    @pl.when(i < n_live)
+    def _live():
+        slot = order_ref[i]
+        xt = xt_ref[0]                                         # [P, Hp]
+        dx = to_front(xt * dt_ref[0])
+        lane = jax.lax.broadcasted_iota(jnp.int32, xt.shape, 1)
+        yt = jnp.zeros_like(xt)
+        for hh in range(tile):
+            if hh % rep == 0:
+                # the head's group: the tile's first, or a later whole one
+                group = (j * tile) // rep + hh // rep
+                b = b_ref[0, pl.ds(group, 1), :]               # [1, N]
+                cs = [jnp.broadcast_to(piece, (_LANES, piece.shape[-1]))
+                      for piece in _bf16_pieces(
+                          c_ref[0, pl.ds(group, 1), :], c_pieces)]
+            new = (h_ref[0, 0, hh].astype(F32)
+                   * decay_ref[slot, j * tile + hh]
+                   + dx[:, hh:hh + 1] * b)                     # [P, N]
+            ho_ref[0, 0, hh] = new.astype(ho_ref.dtype)
+            ycol = sum(
+                jax.lax.dot_general(p, q, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=F32)
+                for a, p in enumerate(_bf16_pieces(new, 3))
+                for k, q in enumerate(cs) if a + k < 3)        # [P, 128]
+            yt = jnp.where(lane == hh, ycol[:, :width], yt)
+        if n_tiles > 1:
+            yt = pltpu.roll(yt, j * tile, 1)
+
+        @pl.when(j == 0)
+        def _first():
+            yt_ref[0] = xt * d_ref[...]
+
+        yt_ref[0] += yt
+
+    @pl.when(i >= n_live)
+    def _dead():
+        yt_ref[...] = jnp.zeros_like(yt_ref)
+
+    @pl.when(n_live == 0)
+    def _nothing_live():
+        # every step names one block of the state, which is written back
+        # once: as it was
+        ho_ref[...] = h_ref[...]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def ssm_state_update_in_place(state, layer, x, dt, a, b, c, d,
+                              slots: LiveSlots, *,
+                              interpret: Optional[bool] = None):
+    """``ssm_state_update`` for the slots that hold a sequence, on the
+    pool: ``state`` [L, slots, H, P, N] every Mamba layer's state as the
+    engine's pool keeps it, of which layer ``layer`` (an int or a traced
+    scalar) is moved on where it lies; x [slots, H, P]; dt [slots, H];
+    a, d [H]; b, c [slots, G, N]; ``slots`` from ``live_slots``.  Returns
+    (y [slots, H, P] float32, zeros for a slot that holds nothing,
+    state' in the buffer ``state`` came in where that was donated).
+    Nothing of a dead slot's state, and nothing of another layer, is
+    read or written."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    n_slots, heads, p = x.shape
+    groups, n = b.shape[1], b.shape[2]
+    head_bytes = p * n * state.dtype.itemsize
+    tile = head_tile(heads, groups, head_bytes)
+    n_tiles = heads // tile
+    # heads on lanes; a rotation wants whole vregs of them
+    width = heads if n_tiles == 1 else -(-heads // _LANES) * _LANES
+
+    def lanes(v):
+        v = v.astype(F32)
+        return jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, width - heads)])
+
+    dt = dt.astype(F32)
+    scalars = (jnp.asarray(layer, jnp.int32).reshape(1), slots.order,
+               slots.count, jnp.exp(dt * a.astype(F32)))
+
+    def listed(i, order_ref, count_ref):
+        # the list's i-th slot, and past the live ones the last of them
+        return order_ref[jnp.minimum(i, jnp.maximum(count_ref[0] - 1, 0))]
+
+    def of_slot(*block):
+        return pl.BlockSpec(block, lambda i, j, _, order_ref, count_ref, __: (
+            listed(i, order_ref, count_ref),) + (0,) * (len(block) - 1))
+
+    tile_spec = pl.BlockSpec(
+        (1, 1, tile, p, n), lambda i, j, layer_ref, order_ref, count_ref, _: (
+            layer_ref[0], listed(i, order_ref, count_ref),
+            jnp.where(i < count_ref[0], j, n_tiles - 1), 0, 0))
+    yt, state = pl.pallas_call(
+        functools.partial(_state_update_kernel, tile=tile,
+                          rep=heads // groups, n_tiles=n_tiles,
+                          c_pieces=1 if c.dtype == jnp.bfloat16 else 3),
+        name="ssm_state_update",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(n_slots, n_tiles),
+            in_specs=[of_slot(1, p, width), of_slot(1, 1, width),
+                      pl.BlockSpec((1, width), lambda i, j, *s: (0, 0)),
+                      of_slot(1, groups, n), of_slot(1, groups, n),
+                      tile_spec],
+            out_specs=[
+                pl.BlockSpec((1, p, width), lambda i, j, _, order_ref, *s: (
+                    order_ref[i], 0, 0)),
+                tile_spec],
+        ),
+        out_shape=(jax.ShapeDtypeStruct((n_slots, p, width), F32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)),
+        input_output_aliases={len(scalars) + 5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # two tiles on their way in, two on their way out
+            vmem_limit_bytes=4 * tile * head_bytes + (16 << 20)),
+        interpret=interpret,
+    )(*scalars, lanes(jnp.swapaxes(x, 1, 2)), lanes(dt)[:, None],
+      lanes(d)[None], b.astype(F32), c.astype(F32), state)
+    return jnp.swapaxes(yt[:, :, :heads], 1, 2), state
 
 
 @jax.named_call

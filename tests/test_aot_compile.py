@@ -123,6 +123,44 @@ class TestAttentionKernelsCompileForV5e:
             chip((1, 6144, 64, D), BF16), kv, kv, chip((1,), I32))
 
 
+class TestStateKernelCompilesForV5e:
+    # the two layer-table cells' pools of state: granite-4.0-h-micro (36
+    # Mamba layers, one group of 64 heads, 2.1 MB a slot and layer: a tile
+    # is a slot whole) and nemotron3-super-120b-d11 (5 layers, 8 groups of
+    # 16 heads, 4.2 MB: two tiles of four groups a slot, the tile's heads
+    # brought to the front by a lane rotation)
+    @pytest.mark.parametrize("layers, heads, groups, tile", [
+        (36, 64, 1, 64), (5, 128, 8, 64)])
+    def test_ssm_state_update(self, chip, layers, heads, groups, tile):
+        from k8s_llm_rca_tpu.ops import ssm
+
+        slots, p, n = 64, 64, 128
+        assert ssm.head_tile(heads, groups, p * n * 4) == tile
+
+        def update(state, layer, x, dt, a, b, c, d, live):
+            return ssm.ssm_state_update_in_place(
+                state, layer, x, dt, a, b, c, d, ssm.live_slots(live),
+                interpret=False)
+
+        state = (layers, slots, heads, p, n)
+        compiled = jax.jit(update, donate_argnums=0).lower(
+            chip(state, F32), chip((), I32), chip((slots, heads, p), BF16),
+            chip((slots, heads), F32), chip((heads,), F32),
+            chip((slots, groups, n), BF16), chip((slots, groups, n), BF16),
+            chip((heads,), F32), chip((slots,), jnp.bool_)).compile()
+        text = compiled.as_text()
+        shape = ",".join(map(str, state))
+        calls = [line for line in text.splitlines()
+                 if "custom-call(" in line and "tpu_custom_call" in line]
+        # one call, under the name the benchmark's reader knows it by, on
+        # the whole pool, which comes back in the buffer it was donated in
+        assert len(calls) == 1 and "%ssm_state_update" in calls[0]
+        assert f"f32[{shape}]" in calls[0]
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= 4 * layers * slots * heads * p * n
+        assert mem.temp_size_in_bytes < 4 * heads * p * n    # one slot's
+
+
 class TestDecodeStepWritesThePoolInPlace:
     """The stepwise decode program at Mistral-7B widths (2 layers of the
     32, int8 weights, the int8 pool of ``mistral7b.chat-open``: 3,072
@@ -187,13 +225,56 @@ class TestDecodeStepUpdatesTheStateInPlace:
     that twice a layer (PR 28's lesson, for pages).  Seen here without a
     chip: the compiled program copies no array of the state's shape, keeps
     state and pages in the buffers they were donated in, and needs less
-    room for temporaries than a tenth of one layer's state."""
+    room for temporaries than a tenth of one layer's state.  Each test in
+    both forms of the update: XLA's two passes (``use_kernel=False``, which
+    takes the attention kernel away too, so the gathered pages are its
+    temporaries) and the one kernel on the pool (``use_kernel=True``)."""
 
     SLOTS, N_PAGES, PAGE = 64, 16384, 16
 
-    def test_no_layer_of_the_state_is_copied(self, chip, monkeypatch):
+    @staticmethod
+    def _kernel_calls(text, name):
+        """The lines of ``text`` that call the Pallas kernel ``name``."""
         import re
 
+        return [line for line in text.splitlines()
+                if re.search(rf"%{name}\S* = .*custom-call\(", line)
+                and "tpu_custom_call" in line]
+
+    def _decode_step(self, chip, cfg, params, pool, kernel, **compile_kw):
+        from k8s_llm_rca_tpu.engine import paged
+
+        return jax.jit(
+            paged.paged_decode_step, static_argnums=0, donate_argnums=2,
+            static_argnames="use_kernel").lower(
+                cfg, params, pool, chip((self.SLOTS,), I32),
+                chip((self.SLOTS,), I32),
+                chip((self.SLOTS, cfg.max_seq_len // self.PAGE), I32),
+                use_kernel=kernel).compile(**compile_kw)
+
+    def _state_stays_where_it_is(self, compiled, cfg, state, state_bytes,
+                                 temp_limit=None):
+        """No array of the shape ``state`` is copied, state and pages keep
+        their donated buffers, and the temporaries stay under
+        ``temp_limit`` (not held for the decode step without the attention
+        kernel: its temporaries are the pages every slot's table gathers,
+        gigabytes at this pool, the form no chip runs)."""
+        import re
+
+        text = compiled.as_text()
+        assert re.search(state, text)               # it is there, updated
+        copies = [line for line in text.splitlines()
+                  if re.search(rf"= {state}\S* copy\(", line)]
+        assert not copies, copies[:2]
+        mem = compiled.memory_analysis()
+        pages_bytes = 2 * self.N_PAGES * self.PAGE * cfg.kv_dim * 2
+        assert mem.alias_size_in_bytes >= state_bytes + pages_bytes
+        if temp_limit is not None:
+            assert mem.temp_size_in_bytes < temp_limit
+
+    @pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
+    def test_no_layer_of_the_state_is_copied(self, chip, monkeypatch,
+                                             kernel):
         from k8s_llm_rca_tpu.config import ModelConfig
         from k8s_llm_rca_tpu.engine import paged
         from k8s_llm_rca_tpu.models import nemotron_h
@@ -210,26 +291,14 @@ class TestDecodeStepUpdatesTheStateInPlace:
             lambda: paged.init_paged_cache(cfg, self.N_PAGES, self.PAGE,
                                            n_slots=self.SLOTS)))
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        compiled = jax.jit(
-            paged.paged_decode_step, static_argnums=0, donate_argnums=2,
-            static_argnames="use_kernel").lower(
-                cfg, params, pool, chip((self.SLOTS,), I32),
-                chip((self.SLOTS,), I32),
-                chip((self.SLOTS, cfg.max_seq_len // self.PAGE), I32),
-                use_kernel=True).compile()
+        compiled = self._decode_step(chip, cfg, params, pool, kernel)
         text = compiled.as_text()
-        assert "tpu_custom_call" in text            # the attention kernel
-
-        state = rf"f32\[(1,)?{self.SLOTS},128,64,128\]"
-        assert re.search(state, text)               # it is there, updated
-        copies = [line for line in text.splitlines()
-                  if re.search(rf"= {state}\S* copy\(", line)]
-        assert not copies, copies[:2]
-        mem = compiled.memory_analysis()
+        assert len(self._kernel_calls(text, "paged_attention")) == kernel
+        assert len(self._kernel_calls(text, "ssm_state_update")) == kernel
         state_bytes = self.SLOTS * 128 * 64 * 128 * 4
-        pages_bytes = 2 * self.N_PAGES * self.PAGE * cfg.kv_dim * 2
-        assert mem.alias_size_in_bytes >= state_bytes + pages_bytes
-        assert mem.temp_size_in_bytes < state_bytes // 10
+        self._state_stays_where_it_is(
+            compiled, cfg, rf"f32\[(1,)?{self.SLOTS},128,64,128\]",
+            state_bytes, state_bytes // 10 if kernel else None)
 
     @staticmethod
     def _granite(layer_types):
@@ -247,7 +316,8 @@ class TestDecodeStepUpdatesTheStateInPlace:
             residual_multiplier=0.22, logits_scaling=8.0,
             attn_scale=0.015625)
 
-    @pytest.mark.parametrize("program", ["decode", "prefill-8x512"])
+    @pytest.mark.parametrize("program", ["decode", "decode-xla",
+                                         "prefill-8x512"])
     def test_no_state_is_copied_behind_a_block_of_two_sublayers(
             self, chip, monkeypatch, program):
         """``granite4-h-micro.chat-open`` at the configuration's own widths
@@ -256,11 +326,10 @@ class TestDecodeStepUpdatesTheStateInPlace:
         keeps 2.1 MB of recurrent state a Mamba layer, 4.83 GB over 36
         layers and 64 slots: more than a third of what the chip holds
         beside the weights, so a program that copied it would not fit.
-        The stepwise decode program and the 8 x 512 batched prefill (its
-        rows' states scattered into their slots) copy no array of the
-        slots' state, and keep state and pages in the buffers they were
-        donated in."""
-        import re
+        The stepwise decode program (with the kernels and without) and the
+        8 x 512 batched prefill (its rows' states scattered into their
+        slots) copy no array of the slots' state, and keep state and pages
+        in the buffers they were donated in."""
 
         from k8s_llm_rca_tpu.engine import paged
         from k8s_llm_rca_tpu.models import nemotron_h
@@ -273,42 +342,34 @@ class TestDecodeStepUpdatesTheStateInPlace:
             lambda: paged.init_paged_cache(cfg, self.N_PAGES, self.PAGE,
                                            n_slots=self.SLOTS)))
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        if program == "decode":
-            compiled = jax.jit(
-                paged.paged_decode_step, static_argnums=0, donate_argnums=2,
-                static_argnames="use_kernel").lower(
-                    cfg, params, pool, chip((self.SLOTS,), I32),
-                    chip((self.SLOTS,), I32),
-                    chip((self.SLOTS, cfg.max_seq_len // self.PAGE), I32),
-                    use_kernel=True).compile()
-        else:
-            compiled = jax.jit(
-                paged.paged_prefill_batch, static_argnums=0,
-                donate_argnums=2).lower(
-                    cfg, params, pool, chip((8, 512), I32), chip((8,), I32),
-                    chip((8, 512 // self.PAGE), I32),
-                    slots=chip((8,), I32)).compile()
-        text = compiled.as_text()
-        if program == "decode":
-            assert "tpu_custom_call" in text        # the attention kernel
-
         state = rf"f32\[(1,)?{self.SLOTS},64,64,128\]"
-        assert re.search(state, text)               # it is there, updated
-        copies = [line for line in text.splitlines()
-                  if re.search(rf"= {state}\S* copy\(", line)]
-        assert not copies, copies[:2]
-        mem = compiled.memory_analysis()
         state_bytes = self.SLOTS * 64 * 64 * 128 * 4
-        pages_bytes = 2 * self.N_PAGES * self.PAGE * cfg.kv_dim * 2
-        assert mem.alias_size_in_bytes >= state_bytes + pages_bytes
-        # decode: a step's activations; prefill: one 512-row's, and the
-        # eight rows' states on their way to the slots
-        assert mem.temp_size_in_bytes < (
-            state_bytes // 10 if program == "decode" else state_bytes)
+        if program != "prefill-8x512":
+            kernel = program == "decode"
+            compiled = self._decode_step(chip, cfg, params, pool, kernel)
+            text = compiled.as_text()
+            assert len(self._kernel_calls(text, "paged_attention")) == kernel
+            assert len(self._kernel_calls(text,
+                                          "ssm_state_update")) == kernel
+            # a step's activations
+            self._state_stays_where_it_is(
+                compiled, cfg, state, state_bytes,
+                state_bytes // 10 if kernel else None)
+            return
+        compiled = jax.jit(
+            paged.paged_prefill_batch, static_argnums=0,
+            donate_argnums=2).lower(
+                cfg, params, pool, chip((8, 512), I32), chip((8,), I32),
+                chip((8, 512 // self.PAGE), I32),
+                slots=chip((8,), I32)).compile()
+        # one 512-row's activations, and the eight rows' states on their
+        # way to the slots
+        self._state_stays_where_it_is(compiled, cfg, state, state_bytes,
+                                      state_bytes)
 
-
+    @pytest.mark.parametrize("program", ["step", "scan-16"])
     def test_the_whole_model_updates_each_state_once_a_step(
-            self, chip, monkeypatch):
+            self, chip, monkeypatch, program):
         """``granite4-h-micro.chat-open`` whole: all 40 layers, 64 slots,
         13.4 GB of arguments.  Arguments and one more copy of the 4.8 GB
         state do not fit the chip together, and XLA's rematerialization
@@ -316,15 +377,22 @@ class TestDecodeStepUpdatesTheStateInPlace:
         duplicates the first Mamba layer's update
         (``add_dynamic-update-slice_fusion.35.remat`` and ``.remat2``, both
         on the program's parameter): in place on the one donated buffer
-        that state moves on twice a step (seen on the chip, PR 44).  With
-        the options the engine compiles its decode programs with
+        that state moves on twice a step (seen on the chip, PR 44).  A
+        duplicated call of the aliasing kernel would square a decay just
+        so.  With the options the engine compiles its decode programs with
         (``paged.decode_compiler_options``) nothing is rematerialized,
-        nothing of the state's shape is copied and state and pages stay
-        where they were donated."""
+        nothing of the state's shape is copied, state and pages stay where
+        they were donated, and each Mamba layer's ``ssm_state_update``
+        call is in the program once, in the stepwise program and in the
+        body of a scan of 16 steps.  (Without the kernels the whole model
+        at 64 slots does not fit the chip: XLA's attention gathers every
+        slot's pages.  The XLA update is held in place at two layers,
+        above.)"""
         import re
 
         from k8s_llm_rca_tpu.config import TINY, TINY_GRANITE_HYBRID
         from k8s_llm_rca_tpu.engine import paged
+        from k8s_llm_rca_tpu.engine.sampling import SamplingParams
         from k8s_llm_rca_tpu.models import nemotron_h
 
         cfg = self._granite((("mamba",) * 5 + ("attention",)
@@ -340,27 +408,37 @@ class TestDecodeStepUpdatesTheStateInPlace:
         pool = _described(chip, jax.eval_shape(
             lambda: paged.init_paged_cache(cfg, self.N_PAGES, self.PAGE,
                                            n_slots=self.SLOTS)))
-        compiled = jax.jit(
-            paged.paged_decode_step, static_argnums=0, donate_argnums=2,
-            static_argnames="use_kernel").lower(
-                cfg, params, pool, chip((self.SLOTS,), I32),
-                chip((self.SLOTS,), I32),
-                chip((self.SLOTS, 4096 // self.PAGE), I32),
-                use_kernel=True).compile(
-                    compiler_options=paged.decode_compiler_options(cfg))
+        options = paged.decode_compiler_options(cfg)
+        if program == "scan-16":
+            compiled = jax.jit(
+                paged.paged_decode_scan, static_argnums=(0, 7, 8, 9),
+                donate_argnums=2, static_argnames="use_kernel").lower(
+                    cfg, params, pool, chip((self.SLOTS,), I32),
+                    chip((self.SLOTS,), I32),
+                    chip((self.SLOTS, 4096 // self.PAGE), I32),
+                    chip((2,), jnp.uint32), 16,
+                    SamplingParams(temperature=0.0, top_k=0, top_p=1.0), 2,
+                    use_kernel=True).compile(compiler_options=options)
+        else:
+            compiled = self._decode_step(chip, cfg, params, pool, True,
+                                         compiler_options=options)
         text = compiled.as_text()
         assert not re.findall(r"%\S*remat\d* = ", text)
         state = rf"f32\[36,{self.SLOTS},64,64,128\]"
-        updates = re.findall(
-            rf"%(add_dynamic-update-slice_fusion\S*) = {state}", text)
+        calls = self._kernel_calls(text, "ssm_state_update")
+        updates = [re.match(r"\s*(?:ROOT )?%(\S+) = ", line).group(1)
+                   for line in calls]
         assert len(updates) == len(set(updates)) == cfg.n_ssm_layers
+        assert all(re.search(state, line) for line in calls)
+        assert not re.search(r"add_dynamic-update-slice_fusion\S* = "
+                             + state, text)
         assert not [line for line in text.splitlines()
                     if re.search(rf"= {state}\S* copy\(", line)]
         mem = compiled.memory_analysis()
         assert mem.alias_size_in_bytes >= sum(
             a.size * a.dtype.itemsize
             for a in jax.tree_util.tree_leaves(pool))
-        assert mem.temp_size_in_bytes < 100e6
+        assert mem.temp_size_in_bytes < 100e6       # a step's activations
 
 
 class TestDecodeStepWritesTheRingInPlace:

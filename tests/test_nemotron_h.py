@@ -56,12 +56,13 @@ def params():
     return nemotron_h.init_params(CFG, jax.random.PRNGKey(SEED))
 
 
-def engine_of(params, **kw):
+def engine_of(params, use_kernel=None, **kw):
     ecfg = EngineConfig(**{**dict(
         max_batch=4, max_seq_len=256, prefill_buckets=(64, 128, 256),
         page_size=16, num_pages=64, prefix_cache=False, decode_chunk=4), **kw})
     return make_engine(CFG, ecfg, params,
-                       get_tokenizer(vocab_size=CFG.vocab_size))
+                       get_tokenizer(vocab_size=CFG.vocab_size),
+                       use_kernel=use_kernel)
 
 
 def prompts_of(lengths, seed=0):
@@ -510,3 +511,51 @@ def test_llama_family_programs_keep_their_hlo(name):
     cfg = {"tiny": TINY, "tiny_moe": TINY_MOE}[preset]
     text = _lowered(cfg, program)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == PARENT_HLO[name]
+
+
+# ------------------------------------ the state kernel (interpret mode here)
+
+
+@pytest.mark.parametrize("n_prompts", [1, 3, 4],
+                         ids=["one-live", "one-dead", "all-live"])
+def test_the_state_kernel_gives_the_same_tokens_and_counts_what_it_ran(
+        params, n_prompts):
+    """``use_kernel=True`` (off the TPU the kernels are interpreted): the
+    decode step's state update is the Pallas call on the pool, told the
+    live slots by the table rows.  The tokens are the XLA form's; the
+    updates the step ran are the live slots' (live x steps x Mamba
+    layers), the other slots' are counted as skipped, and the two add up
+    to what the XLA form runs."""
+    n_m = CFG.n_ssm_layers
+    prompts = prompts_of([40, 33, 50, 45][:n_prompts])
+    want = tokens_of(engine_of(params), prompts, 12)
+    with METRICS.scoped():
+        engine = engine_of(params, use_kernel=True)
+        assert tokens_of(engine, prompts, 12) == want
+        count = METRICS.count
+        steps = count("engine.decode_steps")
+        assert steps > 0
+        assert count("engine.ssm_decode_slot_steps") == (
+            n_prompts * steps * n_m)
+        assert count("engine.ssm_decode_skipped_slot_steps") == (
+            (4 - n_prompts) * steps * n_m)
+        assert count("engine.ssm_decode_live_slot_steps") == count(
+            "engine.ssm_decode_slot_steps")
+    with METRICS.scoped():
+        # without the kernels every slot's update is run, none skipped
+        tokens_of(engine_of(params, use_kernel=False), prompts, 12)
+        assert METRICS.count("engine.ssm_decode_slot_steps") == (
+            4 * METRICS.count("engine.decode_steps") * n_m)
+        assert METRICS.count("engine.ssm_decode_skipped_slot_steps") == 0
+
+
+def test_a_slot_the_kernel_passed_over_is_clean_for_its_next_tenant(params):
+    """Two slots, three sequences one after another, the kernel on: a slot
+    is freed, skipped while it is empty (its last tenant's state stays in
+    it untouched), and taken again; each sequence reads as it does alone
+    on a fresh engine without the kernel."""
+    prompts = prompts_of([50, 30, 61], seed=9)
+    engine = engine_of(params, max_batch=2, use_kernel=True)
+    for prompt in prompts:
+        assert tokens_of(engine, [prompt], 8) == tokens_of(
+            engine_of(params, max_batch=2), [prompt], 8)

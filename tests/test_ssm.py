@@ -96,3 +96,106 @@ def test_convolution_steps_equal_the_sequence_form(length):
     padded = jnp.concatenate([x, 9.0 * jnp.ones((2, 5, ch))], axis=1)
     np.testing.assert_allclose(
         ssm.conv_tail(padded, jnp.array([length, length]), k), tail)
+
+
+# ------------------------------------- the decode step's kernel (interpret)
+
+SHAPES = {"one-group": (8, 4, 1, 16), "groups-of-heads": (8, 4, 2, 16)}
+MASKS = {"all-live": [True] * 4, "some-dead": [False, True, False, True],
+         "all-dead": [False] * 4}
+LAYERS, LAYER = 3, 2
+
+
+def _pool_inputs(shape, dtype, length, seed=0):
+    """A stacked state of ``LAYERS`` layers over four slots, and ``length``
+    positions of operands for them."""
+    heads, p, groups, n = shape
+    k = jax.random.split(jax.random.PRNGKey(seed), 7)
+    state = jax.random.normal(k[0], (LAYERS, 4, heads, p, n))
+    x = jax.random.normal(k[1], (4, length, heads, p)).astype(dtype)
+    dt = jax.nn.softplus(jax.random.normal(k[2], (4, length, heads)) - 2.0)
+    a = -jnp.exp(jax.random.uniform(k[3], (heads,), minval=0.0, maxval=2.5))
+    b = jax.random.normal(k[4], (4, length, groups, n)).astype(dtype)
+    c = jax.random.normal(k[5], (4, length, groups, n)).astype(dtype)
+    d = jax.random.normal(k[6], (heads,))
+    return state, x, dt, a, b, c, d
+
+
+@pytest.mark.parametrize("mask", list(MASKS))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_kernel_moves_the_live_slots_of_one_layer_and_nothing_else(
+        shape, dtype, mask):
+    """``ssm_state_update_in_place`` on layer 2 of a stacked state: a live
+    slot's ``h'`` and ``y`` are the XLA form's, over three positions the
+    recurrence's; a dead slot's state comes back bit for bit with ``y``
+    zeros; no other layer is touched."""
+    live = np.asarray(MASKS[mask])
+    state, x, dt, a, b, c, d = _pool_inputs(SHAPES[shape], dtype, 3,
+                                            seed=len(mask))
+    slots = ssm.live_slots(jnp.asarray(live))
+    assert int(slots.count[0]) == live.sum()
+    assert sorted(np.asarray(slots.order)[:live.sum()]) == list(
+        np.flatnonzero(live))
+    want_y, want_h = ssm.ssm_state_update(state[LAYER], x[:, 0], dt[:, 0],
+                                          a, b[:, 0], c[:, 0], d)
+    # one program for the three positions, the layer a traced scalar
+    step = jax.jit(lambda s, x, dt, b, c: ssm.ssm_state_update_in_place(
+        s, jnp.int32(LAYER), x, dt, a, b, c, d, slots, interpret=True))
+    y, new = step(state, x[:, 0], dt[:, 0], b[:, 0], c[:, 0])
+    assert (y.dtype, new.dtype, new.shape) == (jnp.float32, state.dtype,
+                                               state.shape)
+    np.testing.assert_allclose(y[live], want_y[live], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(new[LAYER][live], want_h[live], rtol=1e-6,
+                               atol=1e-6)
+    assert not np.asarray(y)[~live].any()
+    np.testing.assert_array_equal(new[LAYER][~live], state[LAYER][~live])
+    others = [i for i in range(LAYERS) if i != LAYER]
+    np.testing.assert_array_equal(np.asarray(new)[others],
+                                  np.asarray(state)[others])
+
+    # two more positions: the recurrence from there
+    ys = [y]
+    for t in (1, 2):
+        y, new = step(new, x[:, t], dt[:, t], b[:, t], c[:, t])
+        ys.append(y)
+    want_ys, want_h = ssm.ssm_recurrence(x, dt, a, b, c, d,
+                                         h0=state[LAYER])
+    np.testing.assert_allclose(jnp.stack(ys, 1)[live], want_ys[live],
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(new[LAYER][live], want_h[live], rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_array_equal(new[LAYER][~live], state[LAYER][~live])
+
+
+def test_kernel_keeps_a_bfloat16_state_and_tiles_inside_a_group(monkeypatch):
+    """A state kept in bfloat16 is computed in float32 and stored back in
+    its dtype; with room for two heads a tile, a group of four heads is
+    two tiles, and the result is the same."""
+    live = np.asarray(MASKS["some-dead"])
+    state, x, dt, a, b, c, d = _pool_inputs(SHAPES["groups-of-heads"],
+                                            "float32", 1)
+    state = state.astype(jnp.bfloat16)
+    want_y, want_h = ssm.ssm_state_update(state[LAYER], x[:, 0], dt[:, 0],
+                                          a, b[:, 0], c[:, 0], d)
+    monkeypatch.setattr(ssm, "_TILE_BYTES", 2 * 4 * 16 * 2)
+    assert ssm.head_tile(8, 2, 4 * 16 * 2) == 2
+    y, new = ssm.ssm_state_update_in_place.__wrapped__(
+        state, LAYER, x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0], d,
+        ssm.live_slots(jnp.asarray(live)), interpret=True)
+    assert new.dtype == jnp.bfloat16
+    np.testing.assert_allclose(y[live], want_y[live], rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(new[LAYER][live], want_h[live])
+    np.testing.assert_array_equal(new[LAYER][~live], state[LAYER][~live])
+
+
+@pytest.mark.parametrize("heads, groups, head_bytes, want", [
+    (64, 1, 64 * 128 * 4, 64),      # granite-4.0-h-micro: a slot whole
+    (128, 8, 64 * 128 * 4, 64),     # nemotron-3-super: four groups of 16
+    (128, 8, 3 * 64 * 128 * 4, 16),  # a head three times the size: one
+    (64, 1, 128 * 128 * 4, 32),     # twice the head: half the group
+    (6, 2, 4 << 20, 1),             # a head over the budget: one head
+])
+def test_a_tile_is_whole_groups_or_an_equal_part_of_one(heads, groups,
+                                                    head_bytes, want):
+    assert ssm.head_tile(heads, groups, head_bytes) == want
