@@ -55,6 +55,17 @@ class ModelConfig:
     The engine derives what it holds per slot (pages for the attention
     layers, a ring for the window layers, a recurrent state for the Mamba
     layers) from these tables and from nothing else.
+
+    ``kv_lora_rank`` > 0 makes every layer's attention LATENT
+    (multi-head latent attention, ``deepseek_v3``): a token's keys and
+    values are up-projections of ONE vector of that width, and the cache
+    keeps that vector (normalized) and one rotated key of
+    ``qk_rope_head_dim`` shared by all heads, ``latent_row`` values a
+    token and layer, in place of keys and values per head.  A head's
+    query and key are ``qk_nope_head_dim`` unrotated and
+    ``qk_rope_head_dim`` rotated values, its value ``v_head_dim``;
+    ``head_dim`` is the rotary table's width (the published ``head_dim``
+    of such a model is its ``qk_rope_head_dim``).
     """
 
     name: str = "tiny"
@@ -94,6 +105,14 @@ class ModelConfig:
     qk_norm: bool = False              # RMSNorm + gain over each q, k head
     rope_full_layers: bool = True      # False: rotary embedding on the
                                        # sliding layers only
+    # --- latent attention (0 = keys and values per head, the Llama
+    # block's): the four widths, and how the rotated pairs lie ---
+    kv_lora_rank: int = 0              # the latent a token is cached as
+    qk_nope_head_dim: int = 0          # a head's unrotated q/k width
+    qk_rope_head_dim: int = 0          # the rotated width, ONE key a token
+    v_head_dim: int = 0                # a head's value width
+    rope_interleave: bool = False      # rotated pairs are (2i, 2i + 1):
+                                       # de-interleaved, then rotate-half
     # --- the layer table (empty = the Llama block in every layer),
     # stated one way or the other; ``layer_table`` is what is walked ---
     layer_pattern: str = ""            # a letter a layer: M | E | *
@@ -200,6 +219,16 @@ class ModelConfig:
                 f"which is exact in every dtype only for a power of two; "
                 f"another value needs the scale as an argument of the "
                 f"attention forms, which is not built")
+        if self.kv_lora_rank:
+            self._check_latent()
+        elif (self.qk_nope_head_dim or self.qk_rope_head_dim
+              or self.v_head_dim or self.rope_interleave):
+            raise ValueError(
+                f"qk_nope_head_dim={self.qk_nope_head_dim}, "
+                f"qk_rope_head_dim={self.qk_rope_head_dim}, v_head_dim="
+                f"{self.v_head_dim} and rope_interleave="
+                f"{self.rope_interleave} are latent attention's: they "
+                f"need kv_lora_rank > 0")
         if self.attn_layer_types:
             if self.layer_table:
                 raise ValueError(
@@ -232,6 +261,41 @@ class ModelConfig:
             raise ValueError(
                 f"experts {self.expert_first}..{last} held of a router "
                 f"over {self.n_router}")
+
+    def _check_latent(self) -> None:
+        """Latent attention is built for the Llama block with every layer
+        full and rotated; what it is not built beside is refused by
+        name."""
+        what = f"latent attention (kv_lora_rank={self.kv_lora_rank})"
+        if not (self.qk_nope_head_dim > 0 and self.qk_rope_head_dim > 0
+                and self.v_head_dim > 0):
+            raise ValueError(
+                f"{what} needs its three head widths: qk_nope_head_dim="
+                f"{self.qk_nope_head_dim}, qk_rope_head_dim="
+                f"{self.qk_rope_head_dim}, v_head_dim={self.v_head_dim}")
+        if self.head_dim != self.qk_rope_head_dim:
+            raise ValueError(
+                f"{what}: head_dim={self.head_dim} is the rotary table's "
+                f"width and has to be qk_rope_head_dim="
+                f"{self.qk_rope_head_dim}")
+        if self.layer_pattern or self.mixer_types:
+            raise ValueError(
+                f"{what} in a layer table is not built: the table's "
+                f"attention (models/nemotron_h.py) caches keys and values "
+                f"per head")
+        if "sliding_attention" in self.attn_layer_types:
+            raise ValueError(
+                f"{what} beside sliding-window layers is not built: a "
+                f"ring holds keys and values per head")
+        if self.qk_norm or self.attn_scale or not self.use_rope \
+                or not self.rope_full_layers:
+            raise ValueError(
+                f"{what} is built with its own norm (on the latent), its "
+                f"own softmax scale (1 / sqrt(qk_nope_head_dim + "
+                f"qk_rope_head_dim), an argument of its attention forms) "
+                f"and the rotary embedding in every layer: qk_norm="
+                f"{self.qk_norm}, attn_scale={self.attn_scale}, use_rope="
+                f"{self.use_rope}, rope_full_layers={self.rope_full_layers}")
 
     @property
     def layer_table(self) -> str:
@@ -323,11 +387,29 @@ class ModelConfig:
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
 
     @property
+    def qk_head_dim(self) -> int:
+        """A latent head's query and key width, unrotated then rotated."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_row(self) -> int:
+        """What latent attention caches a token and layer: the latent and
+        the one rotated key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
     def q_dim(self) -> int:
+        """The width attention hands to ``wo``."""
+        if self.kv_lora_rank:
+            return self.n_heads * self.v_head_dim
         return self.n_heads * self.head_dim
 
     @property
     def kv_dim(self) -> int:
+        """The width of a cached row: a token's keys (and as many values)
+        of every kv head, or its latent row."""
+        if self.kv_lora_rank:
+            return self.latent_row
         return self.n_kv_heads * self.head_dim
 
     def replace(self, **kw) -> "ModelConfig":
@@ -372,6 +454,20 @@ TINY_EXAONE_MOE = ModelConfig(
     n_experts=8, router_width=16, expert_first=4, n_experts_per_tok=4,
     router_kind="sigmoid", routed_scaling=2.5, moe_intermediate_size=96,
     shared_expert_size=96)
+
+# deepseek_v3 (kanana-2) at toy widths: latent attention in every layer (a
+# latent of 32 and one rotated key of 16 a token where keys and values per
+# head would be 4 x (24 + 16) + 4 x 16 = 224), pairs interleaved, a leading
+# dense layer, a sigmoid router over 16 experts of which 8 are held from the
+# 4th, top-4, a SwiGLU shared expert
+TINY_KANANA_MOE = ModelConfig(
+    name="tiny_kanana_moe", n_layers=3, n_heads=4, n_kv_heads=4,
+    head_dim=16, kv_lora_rank=32, qk_nope_head_dim=24, qk_rope_head_dim=16,
+    v_head_dim=16, rope_interleave=True, n_dense_layers=1,
+    tie_embeddings=False, rms_norm_eps=1e-6,
+    n_experts=8, router_width=16, expert_first=4, n_experts_per_tok=4,
+    router_kind="sigmoid", routed_scaling=2.448, moe_intermediate_size=96,
+    shared_expert_size=192)
 
 # granitemoehybrid at toy widths: every kind of layer by its published name,
 # two sublayers a layer (a gated MLP behind every mixer), one group of B and
@@ -437,8 +533,8 @@ MIXTRAL_8X7B = ModelConfig(
 
 MODEL_REGISTRY = {
     c.name: c for c in (TINY, TINY_MOE, TINY_NEMOTRON_H, TINY_EXAONE_MOE,
-                        TINY_GRANITE_HYBRID, TINYLLAMA_1B, LLAMA3_8B,
-                        MIXTRAL_8X7B)
+                        TINY_KANANA_MOE, TINY_GRANITE_HYBRID, TINYLLAMA_1B,
+                        LLAMA3_8B, MIXTRAL_8X7B)
 }
 
 

@@ -33,6 +33,7 @@ from k8s_llm_rca_tpu.models.quant import (
 from k8s_llm_rca_tpu.ops.attention import (
     causal_attention, decode_attention, decode_attention_multi,
 )
+from k8s_llm_rca_tpu.ops.mla_attention import absorb_query, unabsorb_values
 from k8s_llm_rca_tpu.ops.norms import rms_norm
 from k8s_llm_rca_tpu.ops.quant_matmul import qmm, qmm_head, qmm_swiglu_experts
 from k8s_llm_rca_tpu.ops.rope import apply_rope, rope_frequencies
@@ -125,11 +126,27 @@ def init_params(cfg: ModelConfig, key: jax.Array,
         layer: Dict[str, Any] = {
             "attn_norm": jnp.ones((h,), dtype),
             "mlp_norm": jnp.ones((h,), dtype),
-            "wq": _tdense(lk[0], (h, q), scale),
-            "wk": _tdense(lk[1], (h, kv), scale),
-            "wv": _tdense(lk[2], (h, kv), scale),
             "wo": _tdense(lk[3], (q, h), scale / math.sqrt(2 * depth)),
         }
+        if cfg.kv_lora_rank:
+            # latent attention: the query whole (no q_lora_rank), the
+            # latent and the one rotated key side by side, the latent's own
+            # norm, and every head's unrotated key and value out of it
+            r, nh = cfg.kv_lora_rank, cfg.n_heads
+            layer.update({
+                "wq": _tdense(lk[0], (h, nh * cfg.qk_head_dim), scale),
+                "w_kva": _tdense(lk[1], (h, cfg.latent_row), scale),
+                "kv_norm": jnp.ones((r,), dtype),
+                "w_kvb": _tdense(
+                    lk[2], (r, nh * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+                    1.0 / math.sqrt(r)),
+            })
+        else:
+            layer.update({
+                "wq": _tdense(lk[0], (h, q), scale),
+                "wk": _tdense(lk[1], (h, kv), scale),
+                "wv": _tdense(lk[2], (h, kv), scale),
+            })
         if cfg.qk_norm:
             layer.update({"q_norm": jnp.ones((cfg.head_dim,), dtype),
                           "k_norm": jnp.ones((cfg.head_dim,), dtype)})
@@ -273,6 +290,77 @@ def _qkv(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
         # two (``ModelConfig.q_fold``) and so exact in every dtype
         q = q * jnp.asarray(cfg.q_fold, q.dtype)
     return q, k, v
+
+
+def _latent_project(cfg: ModelConfig, layer: Params, h: jnp.ndarray,
+                    angles: jnp.ndarray, positions: jnp.ndarray):
+    """Latent attention's projections of the normed stream ``h`` [B, S, H]:
+    every head's unrotated and rotated query ([B, S, n_heads,
+    qk_nope_head_dim] and [..., qk_rope_head_dim]) and the ROW the cache
+    keeps of each token, [B, S, latent_row]: the latent under its own
+    norm, then the one rotated key all heads share."""
+    b, s, _ = h.shape
+    r, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    q = _w_mm(cfg, h, layer["wq"]).reshape(b, s, -1, cfg.qk_head_dim)
+    kva = _w_mm(cfg, h, layer["w_kva"])
+    latent = rms_norm(kva[..., :r], layer["kv_norm"], cfg.rms_norm_eps)
+    q_rope = apply_rope(q[..., nope:], angles, positions,
+                        cfg.rope_interleave)
+    k_rope = apply_rope(kva[..., None, r:], angles, positions,
+                        cfg.rope_interleave)[:, :, 0]
+    return q[..., :nope], q_rope, jnp.concatenate([latent, k_rope], axis=-1)
+
+
+def _latent_qkv(cfg: ModelConfig, layer: Params, h: jnp.ndarray, angles,
+                positions):
+    """Latent attention over whole sequences, in the published form: each
+    head's keys (its own unrotated part out of the latent, the shared
+    rotated part behind it) and values are written out, to be attended as
+    any head's are, at a key width of ``qk_head_dim`` and a value width
+    of ``v_head_dim``; every attention form scales by ``1 /
+    sqrt(q.shape[-1])``, which is the model's.  Returns q, k [B, S,
+    n_heads, qk_head_dim], v [B, S, n_heads, v_head_dim] and the rows to
+    cache."""
+    q_nope, q_rope, row = _latent_project(cfg, layer, h, angles, positions)
+    b, s, nh, nope = q_nope.shape
+    r = cfg.kv_lora_rank
+    kv = _w_mm(cfg, row[..., :r], layer["w_kvb"]).reshape(b, s, nh, -1)
+    k = jnp.concatenate(
+        [kv[..., :nope],
+         jnp.broadcast_to(row[:, :, None, r:], (b, s, nh, row.shape[-1] - r))],
+        axis=-1)
+    return jnp.concatenate([q_nope, q_rope], axis=-1), k, kv[..., nope:], row
+
+
+def latent_decode_query(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
+                        angles, positions):
+    """The decode block's front half under latent attention, ABSORBED: x
+    [B, 1, H] -> the query against the cached rows, [B, n_heads,
+    latent_row] (each head's unrotated query carried into the latent's
+    space by its own key up-projection, its rotated query behind it), and
+    this token's row [B, latent_row]."""
+    h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
+    q_nope, q_rope, row = _latent_project(cfg, layer, h, angles, positions)
+    q_abs = absorb_query(q_nope[:, 0], _latent_up(cfg, layer),
+                         cfg.qk_nope_head_dim)
+    return jnp.concatenate([q_abs, q_rope[:, 0]], axis=-1), row[:, 0]
+
+
+def _latent_up(cfg: ModelConfig, layer: Params) -> jnp.ndarray:
+    """``w_kvb`` by head: [kv_lora_rank, n_heads, qk_nope_head_dim +
+    v_head_dim]."""
+    return dq(layer["w_kvb"]).reshape(
+        cfg.kv_lora_rank, -1, cfg.qk_nope_head_dim + cfg.v_head_dim)
+
+
+def latent_decode_values(cfg: ModelConfig, layer: Params,
+                         o_latent: jnp.ndarray) -> jnp.ndarray:
+    """What the absorbed walk returns, the probabilities over the cached
+    latents [B, n_heads, kv_lora_rank], through each head's value
+    up-projection: [B, 1, q_dim], ``_decode_finish``'s ``attn``."""
+    out = unabsorb_values(o_latent, _latent_up(cfg, layer),
+                          cfg.qk_nope_head_dim)
+    return out.reshape(out.shape[0], 1, cfg.q_dim)
 
 
 def _mlp(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
@@ -850,11 +938,17 @@ def _block_prefill(cfg, layer, x, angles, positions, seq_lens,
     seq-shards over "model" at both norm points (_sp_constrain)."""
     x = _sp_constrain(x, sp_mesh)
     h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-    q, k, v = _qkv(cfg, layer, h, angles, positions)
+    if cfg.kv_lora_rank:
+        q, k, v, row = _latent_qkv(cfg, layer, h, angles, positions)
+    else:
+        q, k, v = _qkv(cfg, layer, h, angles, positions)
     if attention_fn is None:
         attn = causal_attention(q, k, v, seq_lens)
     else:
         attn = attention_fn(q, k, v)
+    if cfg.kv_lora_rank:
+        # what is cached is the token's latent row, and no value beside it
+        k, v = row, None
     b, s, _, _ = attn.shape
     x = x + _w_mm(cfg, attn.reshape(b, s, cfg.q_dim), layer["wo"])
     x = _sp_constrain(x, sp_mesh)
@@ -1017,6 +1111,20 @@ def _refuse_window_layers(cfg: ModelConfig, what: str) -> None:
             f"slot, and this loop keeps every position of every layer")
 
 
+def _refuse_latent(cfg: ModelConfig, what: str) -> None:
+    """The loops over the contiguous ``KVCache`` and the whole-depth
+    prefills hand back keys and values per head; a model with latent
+    attention caches one latent row a token and is served by
+    ``prefill_latent_row`` and the paged decode step, and scored by
+    ``forward``."""
+    if cfg.kv_lora_rank:
+        raise ValueError(
+            f"{what} is not built for {cfg.name!r}: latent attention "
+            f"(kv_lora_rank={cfg.kv_lora_rank}) caches one row of "
+            f"{cfg.latent_row} values a token, and this loop keeps keys "
+            f"and values per head")
+
+
 # q and key block of the lean flash call (ops/flash_attention.py) that the
 # full layers of a model with window layers make: one 6144-position row of
 # 64 heads takes 93.3 ms plain, 13.0 lean in 512-blocks, 7.0 in 1024-blocks
@@ -1085,6 +1193,7 @@ def prefill_kv(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     (new_k [L, S_pad, n_kv, d], new_v likewise, logits [1, V]).
     """
     _refuse_window_layers(cfg, "llama.prefill_kv")
+    _refuse_latent(cfg, "llama.prefill_kv")
     _, s_pad = tokens.shape
     angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
     positions = jnp.arange(s_pad)[None, :]
@@ -1177,6 +1286,7 @@ def decode_step(cfg: ModelConfig, params: Params, cache: KVCache,
     lengths[b]+1 positions).  Returns (cache', logits [B, V]).
     """
     _refuse_window_layers(cfg, "llama.decode_step")
+    _refuse_latent(cfg, "llama.decode_step")
     b = tokens.shape[0]
     angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
     positions = lengths[:, None]                       # [B, 1]
@@ -1249,6 +1359,7 @@ def decode_multi(cfg: ModelConfig, params: Params, cache: KVCache,
     by a later decode at that position.
     """
     _refuse_window_layers(cfg, "llama.decode_multi")
+    _refuse_latent(cfg, "llama.decode_multi")
     b, t = tokens.shape
     s_max = cache.max_seq_len
     angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
@@ -1327,6 +1438,7 @@ def prefill_kv_cp(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     from k8s_llm_rca_tpu.parallel.ulysses import ulysses_attention
 
     _refuse_window_layers(cfg, "llama.prefill_kv_cp")
+    _refuse_latent(cfg, "llama.prefill_kv_cp")
     if cp_mode not in ("ring", "ulysses"):
         raise ValueError(f"unknown cp_mode {cp_mode!r}")
     cp_attn = ring_attention if cp_mode == "ring" else ulysses_attention
@@ -1363,6 +1475,7 @@ def _prefill_batch_kv(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     logits [N, V] at each row's last valid token); the caller scatters
     the KV into pool pages (engine/paged.paged_prefill_batch)."""
     _refuse_window_layers(cfg, "llama._prefill_batch_kv")
+    _refuse_latent(cfg, "llama._prefill_batch_kv")
     n, s_pad = tokens.shape
     angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
     positions = jnp.broadcast_to(jnp.arange(s_pad)[None, :], (n, s_pad))
@@ -1443,3 +1556,33 @@ def prefill_rows(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     return (jnp.moveaxis(k, 0, 1), jnp.moveaxis(v, 0, 1),
             jnp.moveaxis(wk, 0, 1), jnp.moveaxis(wv, 0, 1), logits,
             jnp.sum(n_local), jnp.sum(n_over))
+
+
+def prefill_latent_row(cfg: ModelConfig, params: Params,
+                       tokens: jnp.ndarray, length: jnp.ndarray,
+                       use_flash: bool = False,
+                       expert_kernel: bool = False):
+    """Prefill WITHOUT a cache write of ONE right-padded row of a model
+    with latent attention: tokens [S], ``length`` its true tokens.  The
+    engine runs a batch's rows one after another and writes each into its
+    pages before the next (engine/paged.py::_prefill_latent_rows): rows
+    share nothing, and a 12k-position row's keys and values per head,
+    which only the prefill writes out, stay one row's.  Returns (the rows
+    to cache [L, S, latent_row], logits [V] at the last true token, the
+    row's local expert pairs, and how many of its expert-layer calls ran
+    over the compact form, both int32)."""
+    s_pad = tokens.shape[0]
+    angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
+    positions = jnp.arange(s_pad)[None, :]
+    seq_lens = length[None]
+    x = embed(cfg, params, tokens[None])
+    rows, pairs = [], []
+    for li, layer in enumerate(params["layers"]):
+        x, latent, _ = _block_prefill(
+            cfg.layer_cfg(li), layer, x, angles, positions, seq_lens,
+            _layer_attention_fn(cfg, li, seq_lens, use_flash, s_pad),
+            expert_kernel=expert_kernel, local_pairs=pairs)
+        rows.append(latent[0])
+    last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
+    return (jnp.stack(rows), _logits(cfg, params, last)[0, 0],
+            sum(pairs, jnp.int32(0)), n_compact_overflows(cfg, s_pad, pairs))

@@ -33,9 +33,9 @@ def _flash_kernel(
     q_off_ref,          # SMEM [B]  (absolute position of q block row 0)
     q_ref,              # VMEM [1, 1, block_q, d]   (head-major layout)
     k_ref,              # VMEM [1, 1, block_k, d]
-    v_ref,              # VMEM [1, 1, block_k, d]
-    o_ref,              # VMEM [1, 1, block_q, d]
-    acc_ref,            # VMEM scratch [block_q, d] f32
+    v_ref,              # VMEM [1, 1, block_k, d_v]
+    o_ref,              # VMEM [1, 1, block_q, d_v]
+    acc_ref,            # VMEM scratch [block_q, d_v] f32
     m_ref,              # VMEM scratch [block_q, _LANES] f32
     l_ref,              # VMEM scratch [block_q, _LANES] f32
     *,
@@ -153,7 +153,7 @@ def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
 def flash_attention(
     q: jnp.ndarray,          # [B, S_q, n_heads, d]
     k: jnp.ndarray,          # [B, S_k, n_kv, d]
-    v: jnp.ndarray,          # [B, S_k, n_kv, d]
+    v: jnp.ndarray,          # [B, S_k, n_kv, d_v]
     seq_lens: jnp.ndarray,   # [B] valid kv lengths
     q_offset: jnp.ndarray | None = None,   # [B] absolute pos of q[:, 0]
     *,
@@ -167,6 +167,10 @@ def flash_attention(
 
     ``interpret=None`` auto-selects the Pallas interpreter off-TPU so the
     same code path is exercised hermetically in CPU tests.
+
+    The values' width is their own (``v.shape[-1]``, the output's): latent
+    attention's heads have keys of 192 and values of 128.  The scale is
+    the query's, ``1 / sqrt(d)``.
 
     ``window`` > 0 is a band (a sliding layer: position ``i`` sees the
     ``window`` positions up to itself): the grid's k axis covers only the
@@ -194,6 +198,7 @@ def flash_attention(
     s_k = k.shape[1]
     n_kv = k.shape[2]
     n_rep = n_heads // n_kv
+    d_v = v.shape[-1]
 
     block_q = min(block_q, max(8, s_q))
     block_k = min(block_k, max(8, s_k))
@@ -257,15 +262,15 @@ def flash_attention(
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, block_k, d), kv_block,
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, block_k, d), kv_block,
+            pl.BlockSpec((1, 1, block_k, d_v), kv_block,
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, d),
+        out_specs=pl.BlockSpec((1, 1, block_q, d_v),
                                lambda bi, h, qi, ki: (bi, h, qi, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((*qp.shape[:3], d_v), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],

@@ -25,6 +25,44 @@ jax.config.update("jax_enable_compilation_cache", False)
 import pytest  # noqa: E402
 
 
+# Seconds a file's tests take together on six workers (the junit of PR 46's
+# whole run, rounded; files under a minute are left out).  ``--dist loadfile``
+# hands out whole files, by default the ones with the most tests first, and
+# so the run ended with one worker alone for 200 s in
+# ``test_sweeps_and_graft`` (10 tests, one of them 280 s) while five stood
+# idle.  Longest first, the short files fill the end and the run takes what
+# the work takes (ROADMAP.md C6).  A file that grows past a minute belongs
+# here; one that is missing only runs later than it might.
+FILE_SECONDS = {
+    "test_parallel_pp": 530, "test_kernels": 480, "test_exaone_moe": 420,
+    "test_parallel": 360, "test_store_fabric": 330, "test_disagg": 320,
+    "test_aot_compile": 320, "test_sweeps_and_graft": 310,
+    "test_speculative": 290, "test_nemotron_h": 280,
+    "test_granite_hybrid": 200, "test_paged": 190, "test_kanana_moe": 180,
+    "test_ssm": 180, "test_moe_grouped": 180, "test_net_cluster": 150,
+    "test_prefix_tiers": 150, "test_overlap": 140, "test_proc_cluster": 130,
+    "test_quant": 130, "test_distill_e2e": 130, "test_quant_matmul": 120,
+    "test_constrain": 100, "test_fleet_obs": 100, "test_sweep_sched": 90,
+    "test_engine": 90, "test_parallel_composed": 90, "test_rca_pipeline": 90,
+    "test_overload": 80, "test_encoder_rerank": 80, "test_engine_timing": 80,
+    "test_mla_attention": 70, "test_faults": 70, "test_model_llama": 60,
+}
+
+
+def pytest_configure(config):
+    # xdist would sort the files by their number of tests again
+    # (--loadscope-reorder, on by default) and undo the order below
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
+def pytest_collection_modifyitems(items):
+    """Whole files, the longest first; inside a file, and among the files
+    the table does not name, the order stays as collected (the sort is
+    stable), so every worker still sees one and the same collection."""
+    items.sort(key=lambda item: -FILE_SECONDS.get(item.path.stem, 0))
+
+
 @pytest.fixture(scope="session")
 def cpu_devices():
     devs = [d for d in jax.devices() if d.platform == "cpu"]

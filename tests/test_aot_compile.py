@@ -123,6 +123,46 @@ class TestAttentionKernelsCompileForV5e:
             chip((1, 6144, 64, D), BF16), kv, kv, chip((1,), I32))
 
 
+class TestLatentAttentionKernelsCompileForV5e:
+    """kanana-2-30b-a3b's widths (``benchmarks/configs/
+    kanana-2-30b-a3b-d12.json``): the absorbed decode walk over rows of 576
+    kept at 640 lanes (a pool of 576 lanes Mosaic refuses: "Slice shape
+    along dimension 3 must be aligned to tiling (128), but is 576"), 64
+    slots, pages of 16, tables of 16k tokens; and the prefill's flash call
+    at a key width of 192 and a value width of 128, lean in 1024-blocks."""
+
+    def test_the_absorbed_walk_over_the_cells_pool(self, chip):
+        from k8s_llm_rca_tpu.ops.mla_attention import (
+            mla_paged_attention, stored_lanes,
+        )
+
+        assert stored_lanes(576) == 640
+        pool = chip((12, 4096, 16, 640), BF16)
+        _compiles_with_kernel(
+            lambda q, pool, lens, tables, layer: mla_paged_attention(
+                q, pool, lens, tables, scale=192 ** -0.5, n_value=512,
+                layer=layer, interpret=False),
+            chip((64, 32, 576), BF16), pool, chip((64,), I32),
+            chip((64, 1024), I32), chip((), I32))
+
+    def test_a_pool_of_unpadded_rows_is_refused(self, chip):
+        from k8s_llm_rca_tpu.ops.mla_attention import mla_paged_attention
+
+        with pytest.raises(Exception, match="aligned to tiling"):
+            jax.jit(lambda q, pool, lens, tables: mla_paged_attention(
+                q, pool, lens, tables, scale=192 ** -0.5, n_value=512,
+                layer=0, interpret=False)).lower(
+                    chip((64, 32, 576), BF16), chip((1, 256, 16, 576), BF16),
+                    chip((64,), I32), chip((64, 64), I32)).compile()
+
+    def test_flash_attention_at_keys_of_192_and_values_of_128(self, chip):
+        _compiles_with_kernel(
+            functools.partial(flash_attention, interpret=False, lean=True,
+                              block_q=1024, block_k=1024),
+            chip((1, 8192, 32, 192), BF16), chip((1, 8192, 32, 192), BF16),
+            chip((1, 8192, 32, 128), BF16), chip((1,), I32))
+
+
 class TestStateKernelCompilesForV5e:
     # the two layer-table cells' pools of state: granite-4.0-h-micro (36
     # Mamba layers, one group of 64 heads, 2.1 MB a slot and layer: a tile

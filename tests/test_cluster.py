@@ -8,7 +8,7 @@ Three layers of proof, mirroring the repo's parallelism conventions:
   all raise ValueError at construction.
 - **exact greedy parity**: each supported replica configuration emits
   byte-identical text to the plain single-engine path — the same parity
-  bar every other parallelism mode meets (tests/test_parallel.py).
+  bar every other parallelism mode meets (tests/test_parallel*.py).
 - **failover**: hard kills re-start journal-recorded prompts on
   survivors under unchanged global handles; graceful drains migrate
   sequences WITH decode position via snapshot/adopt and finish
