@@ -46,9 +46,12 @@ enum Status : int32_t {
 // 1. page allocator
 // ---------------------------------------------------------------------------
 
+// Address-ordered with two ends, as engine/paged.PageAllocator: several
+// pages come from the LOWEST free ids, ascending; one page is the HIGHEST.
 struct PageAlloc {
   int32_t n_pages;
-  std::vector<int32_t> free_list;
+  int32_t n_free;
+  std::vector<uint8_t> is_free;                // by page id; [0] never set
   std::unordered_map<int32_t, int64_t> owner;  // page -> owner tag
 };
 
@@ -56,28 +59,37 @@ void* pagealloc_create(int32_t n_pages) {
   if (n_pages < 2) return nullptr;
   auto* a = new PageAlloc();
   a->n_pages = n_pages;
-  a->free_list.reserve(n_pages - 1);
-  for (int32_t p = 1; p < n_pages; ++p) a->free_list.push_back(p);
+  a->n_free = n_pages - 1;
+  a->is_free.assign(n_pages, 1);
+  a->is_free[0] = 0;
   return a;
 }
 
 void pagealloc_destroy(void* h) { delete static_cast<PageAlloc*>(h); }
 
-int32_t pagealloc_n_free(void* h) {
-  return static_cast<int32_t>(static_cast<PageAlloc*>(h)->free_list.size());
-}
+int32_t pagealloc_n_free(void* h) { return static_cast<PageAlloc*>(h)->n_free; }
 
 int32_t pagealloc_alloc(void* h, int32_t n, int64_t owner_tag,
                         int32_t* out_pages) {
   auto* a = static_cast<PageAlloc*>(h);
   if (n < 0) return ERR_BAD_ARG;
-  if (n > static_cast<int32_t>(a->free_list.size())) return ERR_OUT_OF_PAGES;
-  for (int32_t i = 0; i < n; ++i) {
-    int32_t p = a->free_list.back();
-    a->free_list.pop_back();
-    a->owner[p] = owner_tag;
-    out_pages[i] = p;
+  if (n > a->n_free) return ERR_OUT_OF_PAGES;
+  const uint8_t* map = a->is_free.data();
+  if (n == 1) {
+    out_pages[0] = static_cast<int32_t>(
+        static_cast<const uint8_t*>(memrchr(map, 1, a->n_pages)) - map);
+  } else {
+    const uint8_t* at = map;
+    for (int32_t i = 0; i < n; ++i) {
+      at = static_cast<const uint8_t*>(memchr(at, 1, map + a->n_pages - at));
+      out_pages[i] = static_cast<int32_t>(at++ - map);
+    }
   }
+  for (int32_t i = 0; i < n; ++i) {
+    a->is_free[out_pages[i]] = 0;
+    a->owner[out_pages[i]] = owner_tag;
+  }
+  a->n_free -= n;
   return OK;
 }
 
@@ -91,7 +103,8 @@ int32_t pagealloc_free(void* h, const int32_t* pages, int32_t n,
     if (it == a->owner.end()) return ERR_DOUBLE_FREE;
     if (it->second != owner_tag) return ERR_FOREIGN_PAGE;
     a->owner.erase(it);
-    a->free_list.push_back(p);
+    a->is_free[p] = 1;
+    ++a->n_free;
   }
   return OK;
 }
@@ -126,11 +139,11 @@ int32_t pagealloc_pages_of(void* h, int64_t owner_tag, int32_t* out,
 
 int32_t pagealloc_check(void* h) {
   auto* a = static_cast<PageAlloc*>(h);
-  std::vector<uint8_t> seen(a->n_pages, 0);
-  for (int32_t p : a->free_list) {
-    if (p <= 0 || p >= a->n_pages || seen[p]) return ERR_LEAK;
-    seen[p] = 1;
-  }
+  std::vector<uint8_t> seen(a->is_free);
+  if (seen[0]) return ERR_LEAK;
+  int32_t n_free = 0;
+  for (uint8_t f : seen) n_free += f;
+  if (n_free != a->n_free) return ERR_LEAK;
   for (const auto& kv : a->owner) {
     int32_t p = kv.first;
     if (p <= 0 || p >= a->n_pages || seen[p]) return ERR_LEAK;

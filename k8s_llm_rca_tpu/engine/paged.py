@@ -50,8 +50,8 @@ from k8s_llm_rca_tpu.models.llama import _quantize_kv
 from k8s_llm_rca_tpu.ops.attention import decode_attention
 from k8s_llm_rca_tpu.ops import ssm
 from k8s_llm_rca_tpu.ops.mla_attention import (
-    mla_block_pages, mla_paged_attention, mla_paged_attention_xla,
-    stored_lanes,
+    mla_block_pages, mla_page_copies, mla_paged_attention,
+    mla_paged_attention_xla, stored_lanes,
 )
 from k8s_llm_rca_tpu.ops.norms import rms_norm
 from k8s_llm_rca_tpu.ops.paged_attention import (
@@ -90,45 +90,63 @@ class OutOfPages(RuntimeError):
 
 
 class PageAllocator:
-    """Host-side free-list allocator over page ids 1..n_pages-1.
+    """Host-side allocator over page ids 1..n_pages-1, address-ordered
+    with two ends.
 
     Page 0 is never handed out (trash page, see module docstring).
     Every page is owned by at most one owner tag; `free` verifies
     ownership so a double-free or cross-sequence free fails loudly
     instead of silently aliasing KV state.
+
+    An allocation of several pages (an admission) takes the LOWEST free
+    ids, ascending; an allocation of one (a sequence growing past its
+    bucket) takes the HIGHEST.  Pages handed out together so lie together
+    in the pool, where a kernel can fetch a run of them in one copy
+    (ops/mla_attention.py), and the single pages, which come back as
+    single holes, stay out of the end the next admission takes from.
     """
 
     def __init__(self, n_pages: int):
         if n_pages < 2:
             raise ValueError("need at least 2 pages (page 0 is reserved)")
         self.n_pages = n_pages
-        self._free: List[int] = list(range(1, n_pages))
+        self._is_free = bytearray(b"\x00" + b"\x01" * (n_pages - 1))
+        self._n_free = n_pages - 1
         self._owner: Dict[int, int] = {}          # page -> owner tag
 
     @property
     def n_free(self) -> int:
-        return len(self._free)
+        return self._n_free
 
     def pages_of(self, owner: int) -> List[int]:
         return [p for p, o in self._owner.items() if o == owner]
 
     def alloc(self, n: int, owner: int) -> List[int]:
-        if n > len(self._free):
+        if n > self._n_free:
             raise OutOfPages(
-                f"need {n} pages, {len(self._free)} free of {self.n_pages}")
-        pages = [self._free.pop() for _ in range(n)]
+                f"need {n} pages, {self._n_free} free of {self.n_pages}")
+        if n == 1:
+            pages = [self._is_free.rfind(1)]
+        else:
+            pages, at = [], -1
+            for _ in range(n):
+                at = self._is_free.find(1, at + 1)
+                pages.append(at)
         for p in pages:
+            self._is_free[p] = 0
             self._owner[p] = owner
+        self._n_free -= n
         return pages
 
     def _push_free(self, p: int) -> None:
         """Return one validated page to the free store (subclass hook —
         the partitioned allocator routes it to the page's partition)."""
-        self._free.append(p)
+        self._is_free[p] = 1
+        self._n_free += 1
 
     def _free_pages(self) -> List[int]:
         """All free page ids (subclass hook for check())."""
-        return self._free
+        return [p for p in range(self.n_pages) if self._is_free[p]]
 
     def free(self, pages: Sequence[int], owner: int) -> None:
         for p in pages:
@@ -198,7 +216,7 @@ class PartitionedPageAllocator(PageAllocator):
             list(range(max(1, i * per), (i + 1) * per))
             for i in range(n_parts)
         ]
-        self._free = []          # base free list unused; see properties
+        self._is_free = bytearray()     # the base's store unused; see hooks
 
     def part_of(self, page: int) -> int:
         return page * self.n_parts // self.n_pages
@@ -2266,10 +2284,17 @@ class PagedInferenceEngine(EngineBase):
         # mla_paged_attention a ``block_tokens``, so both take BLOCK_TOKENS
         block = (mla_block_pages if cfg.kv_lora_rank else block_pages)(
             self.page_size, self.pages_per_seq)
+        blocks = -(-live // block)
         self._count("engine.attn_pages_live", steps * int(live.sum()))
         self._count("engine.attn_pages_grid",
-                    steps * int((-(-live // block) * block).sum()))
+                    steps * int((blocks * block).sum()))
         if cfg.kv_lora_rank:
+            # the copies the walk starts for those pages: a group of
+            # adjacent pages is one (``mla_page_copies``, the kernel's rule
+            # on the host's mirror of the table it is given)
+            self._count("engine.mla_decode_page_copies",
+                        steps * mla_page_copies(
+                            self.block_tables[active_slots], blocks, block))
             # cached rows the absorbed walk attends: step j of the
             # dispatch sees each live slot's tokens and the j + 1 it has
             # written since, in every layer

@@ -35,6 +35,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -45,10 +46,24 @@ from k8s_llm_rca_tpu.ops.paged_attention import (
 # tokens one loop iteration attends.  On a v5e, 64 slots of 5-14k tokens in
 # rows of 576 kept at 640 lanes, one layer's call (my chip run, PR 46; ms |
 # GB/s of the 1,152 B a row needs): pages of 16 in blocks of 256 2.28 | 310,
-# 512 1.76 | 401, 1024 1.52 | 465; pages of 64 in blocks of 1024 1.24 | 570
-# (a page is one copy: the copies' count sets part of the pace).  1024 is 64
-# copies in flight a half of the buffer, 1.3 MB a half
+# 512 1.76 | 401, 1024 1.52 | 465; pages of 64 in blocks of 1024 1.24 | 570.
+# 1024 is 1.3 MB a half of the buffer
 BLOCK_TOKENS = 1024
+
+# table entries the kernel looks at together: where their ids ascend by one
+# (a run) they are ONE copy, else one each (mla_page_copies counts by the
+# same rule).  The same call at pages of 16 in blocks of 1024 (my chip run,
+# PR 47; ms on a table that is all runs | all scattered | runs of twelve
+# pages with strangers between | as the engine's allocator leaves it after
+# 640 requests; the kernel before: 1.51 | 1.52 | 1.51 | 1.33): 2 1.78 |
+# 1.78 | 1.78 | 1.57; 4 1.62 | 1.63 | 1.62 | 1.43; 8 1.26 | 1.46 | 1.26 |
+# 1.11; 16 1.26 | 1.41 | 1.41 | 1.11.  Not the copies set the pace but the
+# branches: some hundred cycles of the scalar unit each, whatever is in
+# them, in front of the block's products; eight a block hide behind the
+# block's copies, sixteen do not, and 1.26 is what HBM gives (620 GB/s of
+# the lanes fetched).  16 wins nothing over 8 but on a table with no run at
+# all, and loses the runs shorter than 16
+RUN_PAGES = 8
 
 
 def mla_block_pages(page_size: int, pages_per_seq: int,
@@ -57,6 +72,41 @@ def mla_block_pages(page_size: int, pages_per_seq: int,
     pages as make ``block_tokens`` tokens, at least one, at most the
     table (the engine's ``engine.attn_pages_grid`` rounds by it)."""
     return max(1, min(pages_per_seq, block_tokens // page_size))
+
+
+def _group_runs(tables, n_block: int):
+    """Which groups of ``RUN_PAGES`` entries of a table [B, pages_per_seq]
+    are runs, ids that ascend by one: [B, n_blocks * (n_block // RUN_PAGES)],
+    beside the table in whole blocks [B, n_blocks * n_block] (a table no
+    multiple of the block repeats its last entry, whose columns lie past
+    every length).  numpy or jax.numpy, by the table's type."""
+    xp = jnp if isinstance(tables, jax.Array) else np
+    b, pad = tables.shape[0], -tables.shape[1] % n_block
+    if pad:
+        tables = xp.pad(tables, ((0, 0), (0, pad)), mode="edge")
+    groups = tables.reshape(b, -1, n_block)[
+        :, :, :n_block - n_block % RUN_PAGES].reshape(b, -1, RUN_PAGES)
+    # a group's entries along the FIRST axis: numpy reduces a long last
+    # axis several times faster than a last axis of eight
+    groups = xp.moveaxis(groups, -1, 0)
+    if xp is np:
+        groups = np.ascontiguousarray(groups)
+    return (groups[1:] == groups[:-1] + 1).all(axis=0), tables
+
+
+def mla_page_copies(tables: np.ndarray, blocks: np.ndarray,
+                    n_block: int) -> int:
+    """Copies the kernel starts to fetch the first ``blocks[i]`` blocks of
+    ``n_block`` entries of row i of ``tables`` (host arrays), by the
+    kernel's own rule: within a block one for a group of ``RUN_PAGES``
+    entries that is a run, ``RUN_PAGES`` for a group that is not, one for
+    each entry behind the last whole group."""
+    run, _ = _group_runs(tables, n_block)
+    n_groups = n_block // RUN_PAGES
+    fetched = np.arange(run.shape[1])[None, :] < (blocks * n_groups)[:, None]
+    runs, n_blocks = np.count_nonzero(run & fetched), int(blocks.sum())
+    return (runs + RUN_PAGES * (n_blocks * n_groups - runs)
+            + n_blocks * (n_block % RUN_PAGES))
 
 
 def stored_lanes(row: int) -> int:
@@ -87,7 +137,8 @@ def unabsorb_values(o_latent: jnp.ndarray, w_up: jnp.ndarray,
 def _mla_kernel(
     layer_ref,          # SMEM [1]
     lengths_ref,        # SMEM [B]
-    tables_ref,         # SMEM [B, pages_per_seq]
+    tables_ref,         # SMEM [B, n_blocks * n_block]: whole blocks
+    run_ref,            # SMEM [B, n_blocks * (n_block // RUN_PAGES)]
     q_ref,              # VMEM [1, n_heads, row]
     pool,               # HBM  [L, n_pages, page, row]
     o_ref,              # VMEM [1, n_heads, n_value]
@@ -105,24 +156,50 @@ def _mla_kernel(
     bi = pl.program_id(0)
     layer = layer_ref[0]
     length = lengths_ref[bi]
-    pages_per_seq = tables_ref.shape[1]
     block_tokens = n_block * page_size
     n_blocks = (length + block_tokens - 1) // block_tokens
+    n_groups = n_block // RUN_PAGES
 
-    def copies(blk, slot):
-        # a table no multiple of the block: the tail repeats its last
-        # entry, whose columns lie past every length
-        return [pltpu.make_async_copy(
-            pool.at[layer, tables_ref[bi, jnp.minimum(
-                blk * n_block + i, pages_per_seq - 1)]],
-            buf.at[slot, i], sems.at[slot]) for i in range(n_block)]
+    def start(blk, slot):
+        def page_id(i):
+            return tables_ref[bi, blk * n_block + i]
+
+        def fetch(first, n, page):
+            # n adjacent pages lie one behind the other in the pool as
+            # they do in the buffer: one copy puts the same rows at the
+            # same places as the n it stands for
+            pltpu.make_async_copy(
+                pool.at[layer, pl.ds(page, n)],
+                buf.at[slot, pl.ds(first, n)], sems.at[slot]).start()
+
+        def apart(first, n):
+            for i in range(first, first + n):
+                fetch(i, 1, page_id(i))
+
+        # a branch costs the scalar unit some hundred cycles whatever is
+        # in it: what the branches need is read in front of them all
+        groups = [(g * RUN_PAGES, run_ref[bi, blk * n_groups + g],
+                   page_id(g * RUN_PAGES)) for g in range(n_groups)]
+        for first, run, page in groups:
+            jax.lax.cond(
+                run != 0,
+                functools.partial(fetch, first, RUN_PAGES, page),
+                functools.partial(apart, first, RUN_PAGES))
+        apart(n_groups * RUN_PAGES, n_block % RUN_PAGES)
+
+    def wait(slot):
+        # a DMA semaphore counts what has arrived and a wait takes off
+        # its destination's size: one wait on the whole half of the
+        # buffer is the sum of the copies started into it, whichever
+        # branches ran
+        pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                              sems.at[slot]).wait()
 
     _flash_init(acc_ref, m_ref, l_ref)
 
     @pl.when(n_blocks > 0)
     def _first():
-        for c in copies(0, 0):
-            c.start()
+        start(0, 0)
 
     q = q_ref[0]
     n_heads = q.shape[0]
@@ -132,11 +209,9 @@ def _mla_kernel(
 
         @pl.when(blk + 1 < n_blocks)
         def _next():
-            for c in copies(blk + 1, 1 - slot):
-                c.start()
+            start(blk + 1, 1 - slot)
 
-        for c in copies(blk, slot):
-            c.wait()
+        wait(slot)
 
         rows = buf[slot].reshape(block_tokens, buf.shape[-1])
         s = jax.lax.dot_general(
@@ -198,6 +273,11 @@ def mla_paged_attention(
     q = jnp.pad(q, ((0, 0), (0, 0), (0, stored - q.shape[-1])))
     b, n_heads, row = q.shape
     n_block = mla_block_pages(page_size, block_tables.shape[1], block_tokens)
+    # which groups of the table are runs is told here, in XLA: a flag costs
+    # the kernel's scalar unit one read where the ids cost it one each and
+    # their compares (a block under a group has none: one flag nothing reads)
+    run, tables = _group_runs(block_tables.astype(jnp.int32), n_block)
+    run = run.astype(jnp.int32) if run.size else jnp.zeros((b, 1), jnp.int32)
 
     def q_block(width):
         return pl.BlockSpec((1, n_heads, width),
@@ -208,7 +288,7 @@ def mla_paged_attention(
                           n_value=n_value, scale=scale),
         name="mla_paged_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(b,),
             in_specs=[q_block(row), pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=q_block(n_value),
@@ -225,7 +305,7 @@ def mla_paged_attention(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1), lengths.astype(jnp.int32),
-      block_tables.astype(jnp.int32), q.astype(pages.dtype), pages)
+      tables, run, q.astype(pages.dtype), pages)
 
 
 def mla_paged_attention_xla(

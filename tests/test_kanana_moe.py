@@ -273,6 +273,30 @@ def test_the_engine_serves_it(weights, use_kernel):
         > 0
 
 
+@pytest.mark.parametrize("layout", ["runs", "scattered"])
+def test_the_walks_copies_are_counted_by_the_tables_runs(weights, layout):
+    """A prompt whose bucket is the whole table (16 pages, one block): out
+    of a fresh pool they are 1..16 and each group of eight is one copy, an
+    eighth of the pages the walk visits; out of a pool whose even pages
+    are held they are odd ids, no group is a run, and it is a copy a
+    page."""
+    engine = _engine(weights)
+    if layout == "scattered":
+        held = engine.allocator.alloc(engine.allocator.n_free, owner=-99)
+        engine.allocator.free([p for p in held if p % 2], owner=-99)
+    before = METRICS.snapshot()
+    prompt = [int(t) for t in np.random.default_rng(3).integers(3, 500, 40)]
+    engine.submit(prompt, max_new_tokens=5)
+    (result,) = engine.run_to_completion()
+    assert len(result.token_ids) == 5
+    after = METRICS.snapshot()
+    count = lambda name: after.get(name, 0) - before.get(name, 0)
+    grid = count("engine.attn_pages_grid")
+    assert grid and grid % 16 == 0
+    assert count("engine.mla_decode_page_copies") == (
+        grid // 8 if layout == "runs" else grid)
+
+
 def test_assistant_service_serves_it(weights):
     """The serving path a cell takes: ``AssistantService`` over
     ``EngineBackend`` over the one paged engine, no flag anywhere."""

@@ -16,8 +16,9 @@ from k8s_llm_rca_tpu.models import llama
 from k8s_llm_rca_tpu.ops.attention import causal_attention
 from k8s_llm_rca_tpu.ops.flash_attention import flash_attention
 from k8s_llm_rca_tpu.ops.mla_attention import (
-    absorb_query, mla_block_pages, mla_paged_attention,
-    mla_paged_attention_xla, stored_lanes, unabsorb_values,
+    RUN_PAGES, absorb_query, mla_block_pages, mla_page_copies,
+    mla_paged_attention, mla_paged_attention_xla, stored_lanes,
+    unabsorb_values,
 )
 from k8s_llm_rca_tpu.ops.rope import apply_rope, rope_frequencies
 
@@ -95,6 +96,130 @@ def test_the_block_and_the_stored_width():
     assert mla_block_pages(16, 1024) == 64 and mla_block_pages(16, 8) == 8
     assert mla_block_pages(1024, 4) == 1
     assert [stored_lanes(n) for n in (48, 128, 576)] == [128, 128, 640]
+
+
+# ------------------------------------------- adjacent pages are one copy
+
+# one slot's table each, 24 entries over a pool of 40 pages: where a group
+# of RUN_PAGES = 8 entries ascends by one the kernel starts one copy for it
+TABLES = {
+    "one-run": list(range(5, 29)),
+    "scattered": [9, 3, 30, 17, 6, 26, 1, 12, 38, 21, 15, 33,
+                  24, 4, 36, 19, 7, 28, 13, 39, 2, 31, 10, 22],
+    # a run (1..11) that starts inside a group and crosses into the next,
+    # where a stranger breaks it; the last group is a run
+    "mixed": [20, 1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 33, 13, 14, 15,
+              16, 17, 18, 19, 20, 21, 22, 23],
+    "descending": list(range(28, 4, -1)),
+    "run-to-the-pools-last-page": list(range(16, 40)),
+    # pages held for 11 entries and the trash page's zeros behind them
+    "trash-behind": list(range(7, 18)) + [0] * 13,
+}
+T_PPS = 24
+
+
+def _copies_by_hand(table, n_block):
+    """The kernel's rule in plain loops: whole blocks (the tail repeats the
+    table's last entry), within a block groups of RUN_PAGES, the entries
+    behind the last whole group one copy each."""
+    table = list(table) + [table[-1]] * (-len(table) % n_block)
+    copies = 0
+    for at in range(0, len(table), n_block):
+        block = table[at:at + n_block]
+        whole = n_block - n_block % RUN_PAGES
+        for g in range(0, whole, RUN_PAGES):
+            group = block[g:g + RUN_PAGES]
+            run = all(b == a + 1 for a, b in zip(group, group[1:]))
+            copies += 1 if run else RUN_PAGES
+        copies += n_block - whole
+    return copies
+
+
+@pytest.mark.parametrize("block_tokens", [64, 128, 1024],
+                         ids=["blocks-of-8", "blocks-of-16", "one-block"])
+@pytest.mark.parametrize("name", list(TABLES))
+def test_the_kernel_equals_the_xla_form_wherever_the_pages_lie(name,
+                                                               block_tokens):
+    """The same table at five lengths, 0 among them: whichever branch a
+    group takes, the rows land where the single copies put them.  Blocks
+    of 16 leave the table's last block half behind its end."""
+    assert RUN_PAGES == 8
+    pool = _pool()
+    lens = (0, 5, 67, 128, 192) if name != "trash-behind" else (0, 5, 65, 88)
+    lengths = jnp.asarray(lens, jnp.int32)
+    tables = jnp.tile(jnp.asarray(TABLES[name], jnp.int32), (len(lens), 1))
+    q = jax.random.normal(jax.random.PRNGKey(5), (len(lens), HEADS, ROW))
+    got = mla_paged_attention(q, pool, lengths, tables, scale=0.2,
+                              n_value=VALUE, layer=1, interpret=True,
+                              block_tokens=block_tokens)
+    want = mla_paged_attention_xla(q, pool[1], lengths, tables, scale=0.2,
+                                   n_value=VALUE)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+    assert not np.asarray(got[0]).any()
+    n_block = mla_block_pages(PAGE, T_PPS, block_tokens)
+    assert mla_page_copies(
+        np.asarray(tables[:1]), np.asarray([-(-T_PPS // n_block)]),
+        n_block) == _copies_by_hand(TABLES[name], n_block)
+
+
+def test_the_copies_of_a_dispatch_by_the_kernels_rule():
+    """``mla_page_copies`` in numbers: a run is one copy wherever it lies
+    in the pool, ids that ascend across a group's edge are two groups'
+    business, and only the blocks a slot's length reaches count."""
+    by_name = {name: [_copies_by_hand(t, n) for n in (8, 16, 24)]
+               for name, t in TABLES.items()}
+    assert by_name == {
+        "one-run": [3, 3 + 8, 3], "scattered": [24, 24 + 8, 24],
+        "mixed": [17, 17 + 8, 17], "descending": [24, 24 + 8, 24],
+        "run-to-the-pools-last-page": [3, 3 + 8, 3],
+        "trash-behind": [17, 17 + 8, 17]}
+    tables = np.asarray(list(TABLES.values()), np.int32)
+    assert mla_page_copies(tables, np.full(6, 3), 8) == 3 + 24 + 17 + 24 + 3 \
+        + 17
+    assert mla_page_copies(tables, np.asarray([1, 2, 0, 0, 0, 3]), 8) == 1 \
+        + 16 + 17
+    assert mla_page_copies(tables, np.zeros(6, int), 8) == 0
+
+
+@pytest.mark.parametrize("pps, lens", [(9, (0, 3, 72)), (5, (0, 40))],
+                         ids=["a-group-and-one-entry", "under-a-group"])
+def test_a_table_shorter_than_a_block(pps, lens):
+    """Nine entries are one group and a single copy behind it; five are
+    single copies alone."""
+    pool = _pool(layers=1)[0]
+    lengths = jnp.asarray(lens, jnp.int32)
+    tables = jnp.tile(jnp.arange(11, 11 + pps, dtype=jnp.int32),
+                      (len(lens), 1))
+    q = jax.random.normal(jax.random.PRNGKey(6), (len(lens), HEADS, ROW))
+    got = mla_paged_attention(q, pool, lengths, tables, scale=0.2,
+                              n_value=VALUE, interpret=True)
+    want = mla_paged_attention_xla(q, pool, lengths, tables, scale=0.2,
+                                   n_value=VALUE)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+    assert mla_page_copies(np.asarray(tables[:1]), np.asarray([1]),
+                           pps) == (2 if pps == 9 else 5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_same_rows_as_runs_and_scattered_are_equal_bit_for_bit(dtype):
+    """One sequence's rows laid out twice, in adjacent pages and in
+    scattered ones: the run's one copy and the single copies put the same
+    rows at the same places of the buffer, so not a bit differs."""
+    rows = _pool(layers=1, n_pages=T_PPS, dtype=dtype, seed=7)[0]
+    adjacent = np.arange(10, 10 + T_PPS)
+    scattered = np.asarray(TABLES["scattered"])
+    pools = [jnp.zeros((40,) + rows.shape[1:], dtype).at[ids].set(rows)
+             for ids in (adjacent, scattered)]
+    lengths = jnp.asarray([192, 83, 7], jnp.int32)
+    q = jax.random.normal(jax.random.PRNGKey(8), (3, HEADS, ROW)).astype(
+        dtype)
+    outs = [np.asarray(mla_paged_attention(
+        q, pool, lengths, jnp.tile(jnp.asarray(ids, jnp.int32), (3, 1)),
+        scale=0.2, n_value=VALUE, interpret=True, block_tokens=64)
+        .astype(jnp.float32)) for pool, ids in zip(pools,
+                                                   (adjacent, scattered))]
+    assert outs[0].any()
+    np.testing.assert_array_equal(outs[0], outs[1])
 
 
 # ------------------------------------------------------- the prefill's widths

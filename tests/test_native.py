@@ -72,8 +72,8 @@ class TestNativeAllocator:
 
     def test_interleaved_sequence_parity(self):
         """Drive both allocators through the same random alloc/free
-        schedule; free-list order may differ, but counts and failures
-        must match exactly."""
+        schedule: counts, failures and the ids handed out match exactly
+        (one address-ordered store with two ends behind both)."""
         rng = np.random.default_rng(0)
         py, cc = PageAllocator(32), native.NativePageAllocator(32)
         held_py, held_cc = {}, {}
@@ -93,6 +93,8 @@ class TestNativeAllocator:
                     ok2 = False
                 assert ok1 == ok2, f"step {step}"
                 if ok1:
+                    assert p1 == p2, f"step {step}"
+                    assert p1 == sorted(p1), f"step {step}"
                     held_py.setdefault(owner, []).extend(p1)
                     held_cc.setdefault(owner, []).extend(p2)
             else:

@@ -59,6 +59,108 @@ class TestPageAllocator:
             a.free([TRASH_PAGE], owner=1)
 
 
+def _allocators():
+    """The Python allocator and, where it builds, its C++ twin: one
+    behaviour, the ids included."""
+    from k8s_llm_rca_tpu import native
+    yield pytest.param(PageAllocator, id="python")
+    yield pytest.param(
+        lambda n: native.NativePageAllocator(n), id="native",
+        marks=pytest.mark.skipif(not native.available(),
+                                 reason="native toolchain unavailable"))
+
+
+class TestTheFreeStoreKeepsPagesTogether:
+    """Address-ordered with two ends: several pages are the lowest free
+    ids, ascending; one page is the highest."""
+
+    @pytest.mark.parametrize("make", _allocators())
+    def test_a_bulk_allocation_is_the_lowest_and_a_single_the_highest(
+            self, make):
+        a = make(32)
+        assert a.alloc(6, owner=1) == [1, 2, 3, 4, 5, 6]
+        assert a.alloc(1, owner=2) == [31]
+        assert a.alloc(1, owner=2) == [30]
+        assert a.alloc(3, owner=3) == [7, 8, 9]
+        a.free([2, 3, 5], owner=1)              # holes at the low end
+        a.free([31], owner=2)                   # and the top
+        assert a.alloc(4, owner=4) == [2, 3, 5, 10]
+        assert a.alloc(1, owner=4) == [31]
+        assert a.alloc(1, owner=4) == [29]
+        assert a.alloc(0, owner=5) == []
+        a.check()
+        assert a.n_free == 31 - 3 - 3 - 4 - 3
+
+    @pytest.mark.parametrize("make", _allocators())
+    def test_the_two_ends_meet_and_the_pool_empties(self, make):
+        a = make(6)
+        assert a.alloc(2, owner=1) == [1, 2]
+        assert a.alloc(1, owner=1) == [5]
+        assert a.alloc(2, owner=1) == [3, 4]
+        with pytest.raises(OutOfPages):
+            a.alloc(1, owner=1)
+        a.free([3], owner=1)
+        assert a.alloc(1, owner=2) == [3]
+        a.check()
+
+    @pytest.mark.parametrize("make", _allocators())
+    def test_copies_per_page_stay_flat_over_a_long_life(self, make):
+        """The sixth cell's page arithmetic on the host alone (64 callers
+        in a closed loop, prompts of 4-12k tokens in buckets of 6144 /
+        8192 / 12288 whose pages come at admission, 1-2k tokens out a page
+        at a time, 16 a tick, 40,896 pages of 16; a sequence's pages freed
+        in table order): the copies the latent walk would start per page
+        it visits stay where they began over 600 settled requests.  A free
+        store that hands freed pages back in another order lets the share
+        decay (a stack, in groups of four: 0.35 of the best's 0.25 after 64
+        settled, 0.85 after 128)."""
+        from k8s_llm_rca_tpu.ops.mla_attention import (
+            mla_block_pages, mla_page_copies,
+        )
+
+        page, buckets, pps, slots = 16, (6144, 8192, 12288), 1024, 64
+        block = mla_block_pages(page, pps)
+        rng = np.random.default_rng(0)
+
+        def lengths(median, sigma, lo, hi):
+            return np.clip(np.exp(rng.normal(np.log(median), sigma, 800)),
+                           lo, hi).astype(int)
+
+        prompts = lengths(7168, 0.3, 4096, 12288)
+        goals = prompts + lengths(1536, 0.25, 1024, 2048)
+        a = make(40896)
+        tables = np.zeros((slots, pps), np.int32)
+        held, length = [0] * slots, [0] * slots     # pages, tokens
+        owner = [None] * slots
+        sent = settled = 0
+        share = {}
+        while settled < 600:
+            for s in range(slots):
+                if owner[s] is not None and length[s] >= goals[owner[s]]:
+                    a.free([int(p) for p in tables[s, :held[s]]], owner[s])
+                    tables[s], held[s], length[s], owner[s] = 0, 0, 0, None
+                    settled += 1
+                    if settled in (64, 128, 320, 600):
+                        blocks = -(-(-(-np.asarray(length) // page))
+                                   // block)
+                        share[settled] = (
+                            mla_page_copies(tables, blocks, block)
+                            / (blocks.sum() * block))
+            for s in range(slots):
+                if owner[s] is None:
+                    n = next(b for b in buckets if prompts[sent] <= b) // page
+                    tables[s, :n] = a.alloc(n, owner=sent)
+                    held[s], length[s], owner[s] = n, prompts[sent], sent
+                    sent += 1
+                while held[s] * page < length[s] + 16:
+                    (tables[s, held[s]],) = a.alloc(1, owner=owner[s])
+                    held[s] += 1
+                length[s] += 16
+        a.check()
+        assert all(0.125 <= v < 0.25 for v in share.values()), share
+        assert share[600] < share[64] + 0.03, share
+
+
 class TestPagedModelPath:
     """paged prefill+decode must produce the same greedy tokens as the
     contiguous cache path."""
