@@ -2155,8 +2155,10 @@ class PagedInferenceEngine(EngineBase):
         Under EP or PP the MLP runs those paths' own dispatch, over other
         row counts, and nothing is counted.  A model with a layer table
         also counts what its Mamba-2 and expert layers ran over:
-        positions x Mamba layers (pad included, and the ``n_true`` real
-        ones apart), and positions x picks x expert layers.  A model with
+        positions x Mamba layers (pad included, the ``n_true`` real ones
+        apart, and those whose scan ran as its kernel, which is all of
+        them wherever the prefill's kernels stand), and positions x picks
+        x expert layers.  A model with
         latent attention counts the (query, key) pairs its rows' causal
         attention covers, from ``row_lens`` (every row the dispatch runs,
         a padding row's repeat too), x layers."""
@@ -2181,6 +2183,10 @@ class PagedInferenceEngine(EngineBase):
                         n_positions * cfg.n_ssm_layers)
             self._count("engine.ssm_prefill_true_tokens",
                         n_true * cfg.n_ssm_layers)
+            if self._flash_prefill:
+                # the scan ran as its kernel (ops/ssm.py::ssm_chunk_scan)
+                self._count("engine.ssm_prefill_kernel_tokens",
+                            n_positions * cfg.n_ssm_layers)
         if self.pool.moe_local_pairs is not None:
             self._count_routed_pairs(n_positions)
         if cfg.n_window_layers and llama.prefill_uses_flash(

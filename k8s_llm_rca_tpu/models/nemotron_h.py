@@ -229,12 +229,14 @@ def _mamba_out(cfg: ModelConfig, layer: Params, y: jnp.ndarray,
 
 
 def mamba_prefill(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
-                  lengths: jnp.ndarray):
+                  lengths: jnp.ndarray, scan_kernel: bool = False):
     """One Mamba-2 layer over fresh right-padded sequences x [B, S, H].
     Returns (x', ssm_state [B, heads, head_dim, N] float32, conv_state
     [B, kernel - 1, conv_dim]) with both states as they stand after each
     row's last TRUE position: a pad position's ``dt`` is 0, and the
-    convolution's tail is cut at ``lengths``."""
+    convolution's tail is cut at ``lengths``.  ``scan_kernel`` (static):
+    the chunked scan as its Pallas kernel, where the prefill's kernels
+    may stand (``_stack``'s ``use_flash``)."""
     u = rms_norm(x, layer["norm"], cfg.rms_norm_eps)
     z, xbc, dt = _mamba_split(cfg, layer, u)
     tail = ssm.conv_tail(xbc, lengths, cfg.ssm_conv_kernel)
@@ -243,8 +245,9 @@ def mamba_prefill(cfg: ModelConfig, layer: Params, x: jnp.ndarray,
     dt = jax.nn.softplus(dt.astype(F32) + layer["dt_bias"])
     true = jnp.arange(x.shape[1])[None, :] < lengths[:, None]
     dt = jnp.where(true[..., None], dt, 0.0)
-    y, state = ssm.ssm_chunk_scan(xs, dt, -jnp.exp(layer["A_log"]), b, c,
-                                  layer["D"], cfg.ssm_chunk)
+    scan = ssm.ssm_chunk_scan if scan_kernel else ssm.ssm_chunk_scan_xla
+    y, state = scan(xs, dt, -jnp.exp(layer["A_log"]), b, c, layer["D"],
+                    cfg.ssm_chunk)
     return _residual(cfg, x, _mamba_out(cfg, layer, y, z)), state, tail
 
 
@@ -320,7 +323,10 @@ def _stack(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     stream [N, S, H] and what each row leaves behind (keys and values of
     the attention layers [La, N, S, kv_dim], the Mamba layers' states
     [Lm, N, ...], the local-pair count, and how many expert-layer calls
-    ran over the compact form)."""
+    ran over the compact form).  ``use_flash`` (static): the prefill's
+    kernels may stand in this program, the flash call from
+    ``llama.FLASH_MIN_POSITIONS`` positions, the Mamba layers' chunked
+    scan at every length."""
     x = llama.embed(cfg, params, tokens)
     attention_fn = None
     if llama.prefill_uses_flash(use_flash, tokens.shape[1]):
@@ -329,7 +335,7 @@ def _stack(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     pairs = []
     for kind, layer in zip(cfg.layer_table, params["layers"]):
         if kind == "M":
-            x, state, tail = mamba_prefill(cfg, layer, x, lengths)
+            x, state, tail = mamba_prefill(cfg, layer, x, lengths, use_flash)
             states.append(state.astype(jnp.dtype(cfg.ssm_state_dtype)))
             tails.append(tail)
         elif kind == "E":

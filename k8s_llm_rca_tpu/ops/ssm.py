@@ -1,9 +1,10 @@
 """Mamba-2 state-space ops: the chunked scan a prefill runs, the one-step
 update a decode step runs, and the depthwise causal convolution in front
-of both.  Plain XLA (einsums and one ``lax.scan`` over chunks) but for
-the decode step where the engine runs its kernels, whose update is one
-Pallas call on the slots' pool (``ssm_state_update_in_place``, below).  The
-recurrence is
+of both.  Plain XLA (einsums and one ``lax.scan`` over chunks) on a CPU,
+under a mesh and where a gradient is taken; where the engine runs its
+kernels the decode step's update is one Pallas call on the slots' pool
+(``ssm_state_update_in_place``) and the prefill's scan one Pallas call a
+layer (``ssm_chunk_scan``), both below.  The recurrence is
 
     h_t = exp(dt_t * A) * h_{t-1} + dt_t * x_t (x) B_t
     y_t = h_t . C_t + D * x_t
@@ -20,7 +21,42 @@ positions are kept out of it.
 
 ``ssm_chunk_scan`` and ``ssm_state_update`` are the names the benchmark's
 readers know the two by (benchmarks/trace/ssm_costs.py); a kernel that
-replaces either keeps its name, as the decode step's does.
+replaces either keeps its name, as both kernels do (the XLA form of the
+scan is ``ssm_chunk_scan_xla`` here and ``ssm_chunk_scan`` in its HLO).
+
+The prefill's kernel (``ssm_chunk_scan``).  The XLA form writes, for every
+chunk and head, the ``[chunk, chunk]`` float32 decay between the chunk's
+positions to HBM and reads it again (64 KB a position and layer at both
+cells' widths) in front of a product that needs 2.1 MFLOP.  The kernel's
+grid walks (row, tile of heads, chunk), a tile's chunks one after
+another, with the tile's state carried in VMEM: per step the group's
+``C.B^T`` on the MXU, per head the decay ``exp(cum_t - cum_s)`` under the
+causal mask built in VMEM (subtracted first, then ``exp``: the factors
+alone overflow), the masked product with ``x``, what the carried state
+adds to ``y`` and what the chunk adds to the state.  ``x``, ``B`` and
+``C`` go to the MXU as the bfloat16 values they arrive as; whatever is
+float32 by construction (the decay, ``dt``, the carried state) goes as
+three bfloat16 pieces that add up to it (``_bf16_pieces``), accumulated
+in float32, so no float32 factor is rounded to one bfloat16.  A tile is
+the heads whose state is ``_SCAN_TILE_BYTES`` (thirty-two at both cells'
+widths: half of granite's one group, two of nemotron's eight), the
+carried state lies transposed (``[state, heads x head_dim]``) so that
+the two products with it fill the MXU's 128 columns, and the heads go two
+at a time, as many as fill 128 lanes.  The decay goes by blocks of 128
+positions, so the blocks over a chunk's diagonal are never built, and a
+chunk in which every ``dt`` is 0 (the tail of a padded bucket: a quarter
+to a third of the cells' positions), told by a flag XLA computes from
+``dt`` in front of the call, costs the carried state's product alone.
+The cumulative sums (a [B, S, H] float32 array) stay in XLA in front of
+the call too, and reach the kernel as XLA has them, heads on lanes: the
+kernel rotates its tile's heads to the front and transposes them for the
+rows it needs.  Two other layouts did not work (chip runs of PR 48): a
+tile's sums handed over 16 lanes wide, as columns and as rows, cost 942 us
+of cumulative sum over an array padded eightfold in HBM in front of a
+705 us kernel (nemotron, 4,096 positions), and one operand with positions
+on lanes made XLA lay the whole in-projection out with positions minor,
+so that the convolution and the out-projection behind it ran a third
+slower (granite's layer 3.6% slower than with the XLA scan).
 
 The decode step's kernel (``ssm_state_update_in_place``).  XLA runs the
 update as two passes over every slot's state (``h' = decay h + dx (x) B``
@@ -63,6 +99,10 @@ _LANES = 128
 # the largest tile of one slot's state the kernel holds at once (it keeps
 # four: two on their way in, two on their way out)
 _TILE_BYTES = 2 << 20
+# the state of the heads one grid step of the prefill's kernel works on
+# (32 heads at both cells' widths; 16 cost nemotron's scan a tenth more, in
+# fixed costs of twice the steps, 64 win 5% for twice the unrolled code)
+_SCAN_TILE_BYTES = 1 << 20
 
 
 def causal_conv(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
@@ -161,13 +201,17 @@ def live_slots(live: jnp.ndarray) -> LiveSlots:
                      jnp.sum(live, dtype=jnp.int32).reshape(1))
 
 
-def head_tile(heads: int, groups: int, head_bytes: int) -> int:
-    """Heads a tile of the state kernel holds: as many as ``_TILE_BYTES``
-    holds (one at least), in whole groups or in equal parts of one."""
+def head_tile(heads: int, groups: int, head_bytes: int,
+              tile_bytes: Optional[int] = None) -> int:
+    """Heads a tile of a state kernel holds: as many as ``tile_bytes``
+    (the decode step's ``_TILE_BYTES`` where None) holds, one at least,
+    in whole groups or in equal parts of one."""
     rep = heads // groups
+    if tile_bytes is None:
+        tile_bytes = _TILE_BYTES
     return max(t for t in range(1, heads + 1)
                if heads % t == 0 and (rep % t == 0 or t % rep == 0)
-               and (t == 1 or t * head_bytes <= _TILE_BYTES))
+               and (t == 1 or t * head_bytes <= tile_bytes))
 
 
 def _bf16_pieces(v, n: int):
@@ -331,9 +375,21 @@ def ssm_state_update_in_place(state, layer, x, dt, a, b, c, d,
     return jnp.swapaxes(yt[:, :, :heads], 1, 2), state
 
 
-@jax.named_call
-def ssm_chunk_scan(x, dt, a, b, c, d, chunk: int):
-    """The recurrence over whole sequences from a zero state, in chunks.
+def _whole_chunks(x, dt, b, c, chunk: int):
+    """The scan's sequences padded to whole chunks (``dt = 0`` there)."""
+    pad = -x.shape[1] % chunk
+    if pad:
+        x, dt, b, c = (
+            jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+            for v in (x, dt, b, c))
+    return x, dt, b, c
+
+
+@functools.partial(jax.named_call, name="ssm_chunk_scan")
+def ssm_chunk_scan_xla(x, dt, a, b, c, d, chunk: int):
+    """The recurrence over whole sequences from a zero state, in chunks:
+    the XLA form (a CPU, a mesh, differentiation, the tests' yardstick;
+    ``ssm_chunk_scan`` is the kernel).
 
     Arguments as ``ssm_recurrence``; ``chunk`` positions a chunk (the
     sequence is padded to a multiple with ``dt = 0``).  Returns
@@ -343,13 +399,8 @@ def ssm_chunk_scan(x, dt, a, b, c, d, chunk: int):
     g, n = b.shape[2], b.shape[3]
     rep = h // g
     q = chunk
-    pad = -s % q
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        b = jnp.pad(b, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        c = jnp.pad(c, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    nc = (s + pad) // q
+    x, dt, b, c = _whole_chunks(x, dt, b, c, q)
+    nc = x.shape[1] // q
     xf = x.astype(F32).reshape(bt, nc, q, g, rep, p)
     dtf = dt.astype(F32).reshape(bt, nc, q, g, rep)
     bf = b.astype(F32).reshape(bt, nc, q, g, n)
@@ -385,3 +436,210 @@ def ssm_chunk_scan(x, dt, a, b, c, d, chunk: int):
     y = y + d.astype(F32).reshape(g, rep)[:, :, None] * xf
     y = y.reshape(bt, nc * q, h, p)[:, :s]
     return y, hs.reshape(bt, h, p, n)
+
+
+def _pieces_of(v):
+    """A matmul operand as the bfloat16 arrays that add up to it: itself
+    where it is bfloat16, three pieces of a float32."""
+    return [v] if v.dtype == jnp.bfloat16 else _bf16_pieces(v.astype(F32), 3)
+
+
+def _dot(lhs, rhs, contract):
+    """``lhs . rhs`` over the ``contract`` axes as a float32 product on the
+    MXU: every pair of pieces whose orders add up to under three, summed in
+    float32 (one pass for two bfloat16 operands, three where one of them
+    is float32)."""
+    return sum(
+        jax.lax.dot_general(l, r, (contract, ((), ())),
+                            preferred_element_type=F32)
+        for i, l in enumerate(_pieces_of(lhs))
+        for k, r in enumerate(_pieces_of(rhs)) if i + k < 3)
+
+
+def _chunk_scan_kernel(live_ref, x_ref, b_ref, c_ref, cols_ref, d_ref,
+                       y_ref, h_ref, state, *, tile: int, rep: int, p: int,
+                       n: int, slab: int):
+    """Grid step (row, tile of heads, chunk), the chunks of a tile one
+    after another: ``state`` [N, tile x P] float32 carries the tile's state
+    from chunk to chunk, transposed (the state's axis on sublanes, heads x
+    head_dim on lanes) so that the two products with it are as wide as the
+    MXU.
+
+    ``live_ref`` [B, tiles, chunks] in SMEM: whether the chunk holds a
+    position whose ``dt`` is not 0; ``x_ref``/``y_ref`` [1, Q, tile x P],
+    ``b_ref``/``c_ref`` [1, Q, groups of the tile x N], ``cols_ref`` [1, 2,
+    Q, H] the cumulative log-decay and ``dt`` of EVERY head as XLA has
+    them, positions on sublanes (the tile's heads are rotated to the
+    front; a head's decay between two positions needs one as a column and
+    one as a row, and the rows are the tile's columns transposed, here:
+    an operand with the positions on lanes made XLA lay the whole
+    in-projection out that way), ``d_ref`` [1, 1, tile], ``h_ref`` [1,
+    tile, P, N] the state after the row's last chunk.
+
+    The heads go ``slab`` at a time, as many as fill 128 lanes: what is a
+    scalar a head and position is spread over the slab's lanes by selects,
+    and a head's masked product meets ``x`` with the other heads' lanes
+    zeroed, so a slab's ``y`` is one sum.  Every float32 factor (the decay,
+    ``dt``, the carried state) goes to the MXU as three bfloat16 pieces
+    (``_dot``).  A chunk that is all padding (a bucket's tail) leaves the
+    state alone and adds nothing of its own to ``y``: it costs the carried
+    state's product alone."""
+    j, ci = pl.program_id(1), pl.program_id(2)
+    live = live_ref[pl.program_id(0), j, ci] != 0
+    per_group = min(tile, rep)
+    d = d_ref[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, slab * p), 1)
+
+    def spread(v, first):
+        # v [rows, tile] -> [rows, slab x P]: each head's column over its P
+        out = v[:, first:first + 1]
+        for k in range(1, slab):
+            out = jnp.where(lane >= k * p, v[:, first + k:first + k + 1], out)
+        return out
+
+    def slabs():
+        # (group of the tile, first head of the slab, the slab's lanes)
+        for g in range(tile // per_group):
+            for s in range(per_group // slab):
+                first = g * per_group + s * slab
+                yield (slice(g * n, (g + 1) * n), first,
+                       slice(first * p, (first + slab) * p))
+
+    @pl.when(ci == 0)
+    def _fresh():
+        state[...] = jnp.zeros_like(state)
+
+    @pl.when(jnp.logical_not(live))
+    def _all_padding():
+        # y is what the standing state gives against each position's C,
+        # and D x
+        for group, first, lanes in slabs():
+            y_ref[0, :, lanes] = (
+                _dot(c_ref[0, :, group], state[:, lanes], ((1,), (0,)))
+                + spread(d, first) * x_ref[0, :, lanes].astype(F32))
+
+    @pl.when(live)
+    def _chunk():
+        q, heads = cols_ref.shape[2], cols_ref.shape[3]
+
+        def of_tile(v):
+            # [Q, H] -> the tile's columns [Q, tile] and, transposed by
+            # whole tiles of 128, its rows [tile, Q]
+            fill = -heads % _LANES
+            if fill:
+                v = jnp.concatenate([v, jnp.zeros((q, fill), F32)], axis=1)
+            if tile < heads:
+                width = heads + fill
+                v = pltpu.roll(v, jax.lax.rem(width - j * tile, width), 1)
+            return v[:, :tile], v[:, :tile + -tile % _LANES].T[:tile]
+
+        (cum_q, cum_r), (dt_q, dt_r) = (of_tile(cols_ref[0, 0]),
+                                        of_tile(cols_ref[0, 1]))
+        last = cum_q[q - 1:q, :]
+        from_start = jnp.exp(cum_q)
+        to_end = jnp.exp(last - cum_q) * dt_q
+        whole = jnp.exp(last)
+        causal = (jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+                  >= jax.lax.broadcasted_iota(jnp.int32, (q, q), 1))
+        # the decay goes by blocks of 128 positions: the blocks over the
+        # diagonal are never built
+        blk = _LANES if q % _LANES == 0 else q
+        cb = None
+        for group, first, lanes in slabs():
+            bg, cg = b_ref[0, :, group], c_ref[0, :, group]        # [Q, N]
+            if first % per_group == 0:
+                cb = jnp.where(causal, _dot(cg, bg, ((1,), (1,))), 0.0)
+            xs = x_ref[0, :, lanes]                        # [Q, slab x P]
+            xf = xs.astype(F32)
+            before = state[:, lanes]                       # [N, slab x P]
+            y = (spread(from_start, first) * _dot(cg, before, ((1,), (0,)))
+                 + spread(d, first) * xf)
+            own = [xs if slab == 1 else jnp.where(
+                (lane >= k * p) & (lane < (k + 1) * p), xs,
+                jnp.zeros_like(xs)) for k in range(slab)]
+            for t0 in range(0, q, blk):
+                yt = y[t0:t0 + blk]
+                for k in range(slab):
+                    h = first + k
+                    for s0 in range(0, t0 + blk, blk):
+                        # the decay between two positions: subtracted,
+                        # then exp
+                        seg = (cum_q[t0:t0 + blk, h:h + 1]
+                               - cum_r[h:h + 1, s0:s0 + blk])
+                        m = (jnp.exp(jnp.minimum(seg, 0.0))
+                             * cb[t0:t0 + blk, s0:s0 + blk]
+                             * dt_r[h:h + 1, s0:s0 + blk])
+                        yt = yt + _dot(m, own[k][s0:s0 + blk],
+                                       ((1,), (0,)))
+                y_ref[0, t0:t0 + blk, lanes] = yt
+            state[:, lanes] = spread(whole, first) * before + _dot(
+                bg, xf * spread(to_end, first), ((0,), (0,)))
+
+    @pl.when(ci == pl.num_programs(2) - 1)
+    def _last():
+        h_ref[0] = state[...].T.reshape(tile, p, n)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def ssm_chunk_scan(x, dt, a, b, c, d, chunk: int, *,
+                   interpret: Optional[bool] = None):
+    """``ssm_chunk_scan_xla`` as one Pallas kernel (same arguments, same
+    results): a chunk's decay between its positions is built in VMEM and
+    never written out, and the state is carried from chunk to chunk in
+    VMEM.  The cumulative sums (a [B, S, H] float32 array) are XLA's, in
+    front of the call."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    bt, s, h, p = x.shape
+    g, n = b.shape[2], b.shape[3]
+    rep, q = h // g, chunk
+    x, dt, b, c = _whole_chunks(x, dt, b, c, q)
+    nc = x.shape[1] // q
+    tile = head_tile(h, g, p * n * 4, _SCAN_TILE_BYTES)
+    nt, groups, per_group = h // tile, max(1, tile // rep), min(tile, rep)
+    # heads that go side by side: as many of a group as fill 128 lanes
+    slab = max(k for k in range(1, per_group + 1)
+               if per_group % k == 0 and k * p <= max(p, _LANES))
+    dtf = dt.astype(F32).reshape(bt, nc, q, h)
+    cum = jnp.cumsum(dtf * a.astype(F32), axis=2)
+    cols = jnp.stack([cum, dtf], axis=1).reshape(bt, 2, nc * q, h)
+    # the chunks of a tile that hold a position whose dt is not 0
+    live = jnp.any((dtf != 0).reshape(bt, nc, q, nt, tile), axis=(2, 4))
+    live = jnp.swapaxes(live, 1, 2).astype(jnp.int32)          # [B, nt, nc]
+
+    def of_group(bi, j, ci, _):
+        return bi, ci, (j * tile) // rep // groups
+
+    positions = pl.BlockSpec((1, q, tile * p),
+                             lambda bi, j, ci, _: (bi, ci, j))
+    y, state = pl.pallas_call(
+        functools.partial(_chunk_scan_kernel, tile=tile, rep=rep, p=p, n=n,
+                          slab=slab),
+        name="ssm_chunk_scan",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bt, nt, nc),
+            in_specs=[
+                positions,
+                pl.BlockSpec((1, q, groups * n), of_group),
+                pl.BlockSpec((1, q, groups * n), of_group),
+                pl.BlockSpec((1, 2, q, h),
+                             lambda bi, j, ci, _: (bi, 0, ci, 0)),
+                pl.BlockSpec((1, 1, tile), lambda bi, j, ci, _: (j, 0, 0)),
+            ],
+            out_specs=[
+                positions,
+                pl.BlockSpec((1, tile, p, n),
+                             lambda bi, j, ci, _: (bi, j, 0, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((n, tile * p), F32)]),
+        out_shape=(jax.ShapeDtypeStruct((bt, nc * q, h * p), F32),
+                   jax.ShapeDtypeStruct((bt, h, p, n), F32)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+    )(live, x.reshape(bt, nc * q, h * p), b.reshape(bt, nc * q, g * n),
+      c.reshape(bt, nc * q, g * n), cols,
+      d.astype(F32).reshape(nt, 1, tile))
+    return y.reshape(bt, nc * q, h, p)[:, :s], state

@@ -35,11 +35,12 @@ import pytest  # noqa: E402
 # here; one that is missing only runs later than it might.
 FILE_SECONDS = {
     "test_parallel_pp": 530, "test_kernels": 480, "test_exaone_moe": 420,
-    "test_parallel": 360, "test_store_fabric": 330, "test_disagg": 320,
-    "test_aot_compile": 320, "test_sweeps_and_graft": 310,
-    "test_speculative": 290, "test_nemotron_h": 280,
-    "test_granite_hybrid": 200, "test_paged": 190, "test_kanana_moe": 180,
-    "test_ssm": 180, "test_moe_grouped": 180, "test_net_cluster": 150,
+    "test_aot_compile": 420, "test_ssm": 380, "test_parallel": 360,
+    "test_store_fabric": 330, "test_disagg": 320,
+    "test_sweeps_and_graft": 310, "test_nemotron_h": 310,
+    "test_speculative": 290, "test_granite_hybrid": 230,
+    "test_paged": 190, "test_kanana_moe": 180,
+    "test_moe_grouped": 180, "test_net_cluster": 150,
     "test_prefix_tiers": 150, "test_overlap": 140, "test_proc_cluster": 130,
     "test_quant": 130, "test_distill_e2e": 130, "test_quant_matmul": 120,
     "test_constrain": 100, "test_fleet_obs": 100, "test_sweep_sched": 90,
@@ -92,3 +93,44 @@ def reference_greedy(cfg, params, prompt_ids, n_new, max_seq_len=None):
         out.append(int(jnp.argmax(logits[0])))
         lengths = lengths + 1
     return out
+
+
+def scan_kernel_in_the_engine(monkeypatch, n_ssm_layers, run):
+    """The prefill's chunked scan as its kernel inside an engine, for the
+    two layer-table models' test files.  ``run()`` builds an engine, runs
+    its prompts and returns their tokens.  On a CPU ``flash_prefill_plan``
+    says no: the programs are today's (their StableHLO is pinned in
+    tests/test_exaone_moe.py), the kernel's entry point is never reached
+    and ``engine.ssm_prefill_kernel_tokens`` is absent.  With the plan
+    forced to yes (the kernel interpreted) the tokens are the same, every
+    prefill program traced calls the kernel once a Mamba layer, and the
+    counter equals ``engine.ssm_prefill_tokens``: positions x Mamba
+    layers."""
+    from k8s_llm_rca_tpu.engine import paged
+    from k8s_llm_rca_tpu.ops import ssm
+    from k8s_llm_rca_tpu.utils.logging import METRICS
+
+    def refuse(*_, **__):
+        raise AssertionError("the scan's kernel on a CPU engine")
+
+    with METRICS.scoped(), monkeypatch.context() as off:
+        off.setattr(ssm, "ssm_chunk_scan", refuse)
+        want = run()
+        assert METRICS.count("engine.ssm_prefill_tokens") > 0
+        assert "engine.ssm_prefill_kernel_tokens" not in METRICS.snapshot()
+    kernel, traced = ssm.ssm_chunk_scan, []
+
+    def spy(*args, **kw):
+        traced.append(args[0].shape)
+        return kernel(*args, **kw)
+
+    monkeypatch.setattr(ssm, "ssm_chunk_scan", spy)
+    monkeypatch.setattr(paged, "flash_prefill_plan",
+                        lambda *_, **__: (True, None))
+    with METRICS.scoped():
+        assert run() == want
+        assert traced and len(traced) % n_ssm_layers == 0
+        assert METRICS.count("engine.ssm_prefill_kernel_tokens") == (
+            METRICS.count("engine.ssm_prefill_tokens"))
+        assert METRICS.count("engine.ssm_prefill_tokens") == (
+            n_ssm_layers * METRICS.count("engine.prefill_padded_tokens"))

@@ -68,6 +68,16 @@ def _described(chip, tree):
     return jax.tree.map(lambda s: chip(s.shape, s.dtype), tree)
 
 
+def _kernel_calls(text, name):
+    """The lines of the compiled module ``text`` that call the Pallas
+    kernel ``name``."""
+    import re
+
+    return [line for line in text.splitlines()
+            if re.search(rf"%{name}\S* = .*custom-call\(", line)
+            and "tpu_custom_call" in line]
+
+
 def _compiles_with_kernel(fn, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
@@ -201,6 +211,101 @@ class TestStateKernelCompilesForV5e:
         assert mem.temp_size_in_bytes < 4 * heads * p * n    # one slot's
 
 
+class TestScanKernelCompilesForV5e:
+    """The prefill's chunked scan as its kernel, at the two layer-table
+    cells' widths and their largest bucket: granite-4.0-h-micro (one group
+    of 64 heads, chunk 256, 2,048 positions) and nemotron3-super-120b-d11
+    (8 groups of 16 heads, chunk 128, 4,096).  What the XLA form writes
+    out for every chunk, the float32 decay between the chunk's positions
+    for each head (``[.., heads, chunk, chunk]``), is in no program that
+    holds the kernel."""
+
+    CELLS = {"granite": ("granite-4.0-h-micro", 2048, 512, 36),
+             "nemotron": ("nemotron3-super-120b-d11", 4096, 1024, 5)}
+
+    @staticmethod
+    def _cfg(name):
+        import os
+
+        from benchmarks.lib import build
+
+        return build.model_config(build.load_json(os.path.join(
+            build.BENCH_DIR, "configs", name + ".json")), name)
+
+    @staticmethod
+    def _decay(cfg):
+        """A float32 array that ends in ``heads (of a group), chunk,
+        chunk``, as HLO text writes its shape."""
+        rep, q = cfg.ssm_heads // cfg.ssm_groups, cfg.ssm_chunk
+        return rf"f32\[[0-9,]*\b{rep},{q},{q}\]"
+
+    @pytest.mark.parametrize("cell", sorted(CELLS))
+    def test_ssm_chunk_scan(self, chip, cell):
+        import re
+
+        from k8s_llm_rca_tpu.ops import ssm
+
+        name, positions, _, _ = self.CELLS[cell]
+        cfg = self._cfg(name)
+        h, p = cfg.ssm_heads, cfg.ssm_head_dim
+        g, n = cfg.ssm_groups, cfg.ssm_state_size
+        # thirty-two heads a grid step: half of granite's one group, two
+        # of nemotron's eight
+        assert ssm.head_tile(h, g, p * n * 4, ssm._SCAN_TILE_BYTES) == 32
+        args = (chip((1, positions, h, p), BF16),
+                chip((1, positions, h), F32), chip((h,), F32),
+                chip((1, positions, g, n), BF16),
+                chip((1, positions, g, n), BF16), chip((h,), F32))
+        text = jax.jit(functools.partial(
+            ssm.ssm_chunk_scan, chunk=cfg.ssm_chunk,
+            interpret=False)).lower(*args).compile().as_text()
+        assert len(_kernel_calls(text, "ssm_chunk_scan")) == 1
+        assert not re.search(self._decay(cfg), text)
+        xla = jax.jit(functools.partial(
+            ssm.ssm_chunk_scan_xla, chunk=cfg.ssm_chunk)).lower(
+                *args).compile().as_text()
+        assert re.search(self._decay(cfg), xla)
+        assert not _kernel_calls(xla, "ssm_chunk_scan")
+
+    @pytest.mark.parametrize("cell", sorted(CELLS))
+    def test_a_prefill_program_calls_it_once_a_mamba_layer(
+            self, chip, monkeypatch, cell):
+        """The cell's whole model, one row of its smallest bucket through
+        ``paged_prefill_batch`` as the engine compiles it on the chip
+        (``use_flash=True``: granite's 512 is under the flash call's 1,024
+        positions and takes the scan's kernel all the same)."""
+        import re
+
+        from k8s_llm_rca_tpu.engine import paged
+        from k8s_llm_rca_tpu.models import nemotron_h
+
+        name, _, bucket, mamba_layers = self.CELLS[cell]
+        cfg = self._cfg(name)
+        assert cfg.n_ssm_layers == mamba_layers
+        params = _described(chip, jax.eval_shape(
+            lambda: nemotron_h.init_params(cfg, jax.random.PRNGKey(0))))
+        pool = _described(chip, jax.eval_shape(
+            lambda: paged.init_paged_cache(cfg, 16384, 16, n_slots=64)))
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        text = jax.jit(
+            paged.paged_prefill_batch, static_argnums=0, donate_argnums=2,
+            static_argnames="use_flash").lower(
+                cfg, params, pool, chip((1, bucket), I32), chip((1,), I32),
+                chip((1, bucket // 16), I32), slots=chip((1,), I32),
+                use_flash=True).compile().as_text()
+        calls = [re.match(r"\s*(?:ROOT )?%(\S+) = ", line).group(1)
+                 for line in _kernel_calls(text, "ssm_chunk_scan")]
+        assert len(calls) == len(set(calls)) == mamba_layers
+        assert not re.search(self._decay(cfg), text)
+        # the in-projection's output stays row-major: an operand of the
+        # kernel with positions on lanes made XLA lay it out positions-
+        # minor, and the convolution and out-projection ran a third slower
+        width = cfg.ssm_inner + cfg.ssm_conv_dim + cfg.ssm_heads
+        layouts = set(re.findall(
+            rf"bf16\[1,{bucket},{width}\]\{{([0-9,]+)", text))
+        assert layouts == {"2,1,0"}
+
+
 class TestDecodeStepWritesThePoolInPlace:
     """The stepwise decode program at Mistral-7B widths (2 layers of the
     32, int8 weights, the int8 pool of ``mistral7b.chat-open``: 3,072
@@ -272,15 +377,6 @@ class TestDecodeStepUpdatesTheStateInPlace:
 
     SLOTS, N_PAGES, PAGE = 64, 16384, 16
 
-    @staticmethod
-    def _kernel_calls(text, name):
-        """The lines of ``text`` that call the Pallas kernel ``name``."""
-        import re
-
-        return [line for line in text.splitlines()
-                if re.search(rf"%{name}\S* = .*custom-call\(", line)
-                and "tpu_custom_call" in line]
-
     def _decode_step(self, chip, cfg, params, pool, kernel, **compile_kw):
         from k8s_llm_rca_tpu.engine import paged
 
@@ -333,8 +429,8 @@ class TestDecodeStepUpdatesTheStateInPlace:
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         compiled = self._decode_step(chip, cfg, params, pool, kernel)
         text = compiled.as_text()
-        assert len(self._kernel_calls(text, "paged_attention")) == kernel
-        assert len(self._kernel_calls(text, "ssm_state_update")) == kernel
+        assert len(_kernel_calls(text, "paged_attention")) == kernel
+        assert len(_kernel_calls(text, "ssm_state_update")) == kernel
         state_bytes = self.SLOTS * 128 * 64 * 128 * 4
         self._state_stays_where_it_is(
             compiled, cfg, rf"f32\[(1,)?{self.SLOTS},128,64,128\]",
@@ -388,8 +484,8 @@ class TestDecodeStepUpdatesTheStateInPlace:
             kernel = program == "decode"
             compiled = self._decode_step(chip, cfg, params, pool, kernel)
             text = compiled.as_text()
-            assert len(self._kernel_calls(text, "paged_attention")) == kernel
-            assert len(self._kernel_calls(text,
+            assert len(_kernel_calls(text, "paged_attention")) == kernel
+            assert len(_kernel_calls(text,
                                           "ssm_state_update")) == kernel
             # a step's activations
             self._state_stays_where_it_is(
@@ -465,7 +561,7 @@ class TestDecodeStepUpdatesTheStateInPlace:
         text = compiled.as_text()
         assert not re.findall(r"%\S*remat\d* = ", text)
         state = rf"f32\[36,{self.SLOTS},64,64,128\]"
-        calls = self._kernel_calls(text, "ssm_state_update")
+        calls = _kernel_calls(text, "ssm_state_update")
         updates = [re.match(r"\s*(?:ROOT )?%(\S+) = ", line).group(1)
                    for line in calls]
         assert len(updates) == len(set(updates)) == cfg.n_ssm_layers

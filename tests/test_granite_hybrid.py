@@ -22,6 +22,7 @@ sys.path.insert(0, ROOT)
 
 from benchmarks.lib import build  # noqa: E402
 from benchmarks.reference import granite_hybrid as reference  # noqa: E402
+from conftest import scan_kernel_in_the_engine  # noqa: E402
 from k8s_llm_rca_tpu import models  # noqa: E402
 from k8s_llm_rca_tpu.config import (  # noqa: E402
     TINY_GRANITE_HYBRID, EngineConfig, ModelConfig,
@@ -284,6 +285,22 @@ def test_the_state_kernel_gives_the_same_tokens_and_counts_what_it_ran(
         assert METRICS.count("engine.ssm_decode_slot_steps") == (
             4 * METRICS.count("engine.decode_steps") * n_m)
         assert METRICS.count("engine.ssm_decode_skipped_slot_steps") == 0
+
+
+# ------------------------------ the prefill's scan kernel (interpret mode here)
+
+
+@pytest.mark.parametrize("lengths", [[40], [40, 70, 33]],
+                         ids=["one-row", "three-rows-two-buckets"])
+def test_the_scan_kernel_gives_the_same_tokens_and_counts_what_it_ran(
+        params, monkeypatch, lengths):
+    """The prefill's chunked scan is its Pallas kernel exactly where the
+    prefill's other kernel may stand, at every bucket; off (a CPU) the
+    engine is today's (``conftest.scan_kernel_in_the_engine``)."""
+    prompts = prompts_of(lengths)
+    scan_kernel_in_the_engine(
+        monkeypatch, CFG.n_ssm_layers,
+        lambda: tokens_of(engine_of(params), prompts, 8))
 
 
 def test_a_slot_the_kernel_passed_over_is_clean_for_its_next_tenant(params):

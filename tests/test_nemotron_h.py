@@ -23,6 +23,7 @@ sys.path.insert(0, ROOT)
 
 from benchmarks.lib import build  # noqa: E402
 from benchmarks.reference import nemotron_h as reference  # noqa: E402
+from conftest import scan_kernel_in_the_engine  # noqa: E402
 from k8s_llm_rca_tpu.config import (  # noqa: E402
     TINY, TINY_MOE, TINY_NEMOTRON_H, EngineConfig, ModelConfig,
 )
@@ -361,6 +362,22 @@ def test_counters_of_the_new_layers(params):
         assert METRICS.count("engine.prefill_padded_tokens") == 4 * 64
         assert METRICS.count("engine.ssm_prefill_tokens") == n_m * 4 * 64
     assert got == [tokens_of(engine_of(params), [p], 6)[0] for p in prompts]
+
+
+# ------------------------------ the prefill's scan kernel (interpret mode here)
+
+
+@pytest.mark.parametrize("lengths", [[40], [40, 70, 33]],
+                         ids=["one-row", "three-rows-two-buckets"])
+def test_the_scan_kernel_gives_the_same_tokens_and_counts_what_it_ran(
+        params, monkeypatch, lengths):
+    """The prefill's chunked scan is its Pallas kernel exactly where the
+    prefill's other kernel may stand, at every bucket; off (a CPU) the
+    engine is today's (``conftest.scan_kernel_in_the_engine``)."""
+    prompts = prompts_of(lengths)
+    scan_kernel_in_the_engine(
+        monkeypatch, CFG.n_ssm_layers,
+        lambda: tokens_of(engine_of(params), prompts, 8))
 
 
 # ------------------------------------------------------------- the refusals

@@ -1,8 +1,8 @@
-"""The Mamba-2 ops (ops/ssm.py): the chunked scan a prefill runs and the
-one-step update a decode step runs both equal the recurrence they stand for,
-a position whose ``dt`` is 0 leaves the state alone, and the convolution's
-tail is the last true inputs.  On the CPU: a correctness check, never a
-time."""
+"""The Mamba-2 ops (ops/ssm.py): the chunked scan a prefill runs (its XLA
+form and its kernel, interpreted) and the one-step update a decode step runs
+equal the recurrence they stand for, a position whose ``dt`` is 0 leaves the
+state alone, and the convolution's tail is the last true inputs.  On the
+CPU: a correctness check, never a time."""
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +34,7 @@ def test_chunked_scan_equals_the_recurrence(length, chunk):
     x, dt, a, b, c, d = _inputs(2, length, seed=length)
     want_y, want_h = ssm.ssm_recurrence(x, dt, a, b, c, d)
     with jax.default_matmul_precision("highest"):
-        y, h = ssm.ssm_chunk_scan(x, dt, a, b, c, d, chunk)
+        y, h = ssm.ssm_chunk_scan_xla(x, dt, a, b, c, d, chunk)
     np.testing.assert_allclose(y, want_y, rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(h, want_h, rtol=2e-4, atol=2e-4)
 
@@ -49,8 +49,107 @@ def test_a_pad_position_does_not_advance_the_state(true):
                                    b[:, :true], c[:, :true], d)
     masked = jnp.where(jnp.arange(32)[None, :, None] < true, dt, 0.0)
     with jax.default_matmul_precision("highest"):
-        _, h = ssm.ssm_chunk_scan(x, masked, a, b, c, d, 16)
+        _, h = ssm.ssm_chunk_scan_xla(x, masked, a, b, c, d, 16)
     np.testing.assert_allclose(h, want_h, rtol=2e-4, atol=2e-4)
+
+
+# ------------------------------------------ the prefill's kernel (interpret)
+
+# heads, head_dim, groups, state, chunk: the two cells' geometry at a small
+# state, and a tiny one
+GRANITE = (64, 64, 1, 16, 256)
+NEMOTRON = (128, 64, 8, 16, 128)
+SMALL = (8, 4, 2, 16, 16)
+# name -> (geometry, padded length, true length of each row, dtype of x, B
+# and C, factor on dt)
+SCAN_CASES = {
+    "granite-geometry-ragged-length": (GRANITE, 300, (300,), "bfloat16", 1),
+    "nemotron-geometry-whole-chunks": (NEMOTRON, 256, (256,), "bfloat16", 1),
+    "length-under-one-chunk": (SMALL, 5, (5, 5), "bfloat16", 1),
+    "float32-inputs": (SMALL, 37, (37, 37), "float32", 1),
+    "tail-is-padding": (SMALL, 48, (21,), "bfloat16", 1),
+    "pad-from-a-chunk-boundary": (SMALL, 64, (32,), "bfloat16", 1),
+    "two-rows-two-lengths": (SMALL, 48, (48, 7), "bfloat16", 1),
+    "decay-underflows-inside-a-chunk": (SMALL, 48, (48,), "bfloat16", 200),
+}
+
+
+def _scan_inputs(geometry, length, true, dtype, factor):
+    """One row a true length, right-padded to ``length`` (``dt = 0`` at the
+    pad positions), ``x``, ``B`` and ``C`` in ``dtype``, ``dt`` times
+    ``factor``."""
+    heads, p, groups, n, _ = geometry
+    k = jax.random.split(jax.random.PRNGKey(length), 6)
+    rows = len(true)
+    x = jax.random.normal(k[0], (rows, length, heads, p)).astype(dtype)
+    dt = factor * jax.nn.softplus(
+        jax.random.normal(k[1], (rows, length, heads)) - 2.0)
+    a = -jnp.exp(jax.random.uniform(k[2], (heads,), minval=0.0, maxval=2.5))
+    b = jax.random.normal(k[3], (rows, length, groups, n)).astype(dtype)
+    c = jax.random.normal(k[4], (rows, length, groups, n)).astype(dtype)
+    d = jax.random.normal(k[5], (heads,))
+    dt = jnp.where(jnp.arange(length)[None, :, None]
+                   < jnp.asarray(true)[:, None, None], dt, 0.0)
+    return x, dt, a, b, c, d
+
+
+@pytest.mark.parametrize("case", sorted(SCAN_CASES))
+def test_scan_kernel_equals_the_recurrence_and_the_xla_form(case):
+    """``ssm_chunk_scan`` (interpreted) against ``ssm_recurrence`` over each
+    row's true positions and against ``ssm_chunk_scan_xla``, at float32's
+    level: 2e-5 of the largest value where one bfloat16 rounding is 4e-3.
+    A pad position (``dt = 0``) leaves the state the last true position's,
+    and its ``y`` is what the recurrence gives there (the standing state
+    against that position's ``C``, and ``D x``)."""
+    geometry, length, true, dtype, factor = SCAN_CASES[case]
+    (heads, p, _, n, chunk), rows = geometry, len(true)
+    x, dt, a, b, c, d = _scan_inputs(geometry, length, true, dtype, factor)
+    y, h = ssm.ssm_chunk_scan(x, dt, a, b, c, d, chunk, interpret=True)
+    assert y.dtype == jnp.float32 and h.dtype == jnp.float32
+    assert y.shape == (rows, length, heads, p)
+    assert h.shape == (rows, heads, p, n)
+    assert bool(jnp.isfinite(y).all()) and bool(jnp.isfinite(h).all())
+
+    def close(got, want):
+        np.testing.assert_allclose(
+            got, want, rtol=2e-5,
+            atol=2e-5 * max(1.0, float(jnp.max(jnp.abs(want)))))
+
+    with jax.default_matmul_precision("highest"):
+        xla_y, xla_h = ssm.ssm_chunk_scan_xla(x, dt, a, b, c, d, chunk)
+    close(y, xla_y)
+    close(h, xla_h)
+    want_y, whole_h = ssm.ssm_recurrence(x, dt, a, b, c, d)
+    close(y, want_y)
+    for row, n_true in enumerate(true):
+        # the state is the one the true positions alone leave
+        want_h = whole_h[row:row + 1]
+        if n_true < length:
+            _, want_h = ssm.ssm_recurrence(
+                x[row:row + 1, :n_true], dt[row:row + 1, :n_true], a,
+                b[row:row + 1, :n_true], c[row:row + 1, :n_true], d)
+        close(h[row:row + 1], want_h)
+
+
+@pytest.mark.parametrize("heads_a_tile", [4, 2],
+                         ids=["a-group-a-tile", "half-a-group-a-tile"])
+def test_scan_kernel_walks_its_tiles_of_heads(monkeypatch, heads_a_tile):
+    """With room for fewer heads than the model has, the grid walks tiles
+    of heads (whole groups, or equal parts of one): each step rotates its
+    tile's columns of ``cum`` and ``dt`` to the front and reads its own
+    group's ``B`` and ``C``; the result is the one-tile result."""
+    heads, p, groups, n, chunk = SMALL
+    monkeypatch.setattr(ssm, "_SCAN_TILE_BYTES", heads_a_tile * p * n * 4)
+    assert ssm.head_tile(heads, groups, p * n * 4,
+                         ssm._SCAN_TILE_BYTES) == heads_a_tile
+    x, dt, a, b, c, d = _scan_inputs(SMALL, 40, (29, 29), "bfloat16", 1)
+    # the jitted entry point caches by its arguments, not by the budget
+    y, h = ssm.ssm_chunk_scan.__wrapped__(x, dt, a, b, c, d, chunk,
+                                          interpret=True)
+    with jax.default_matmul_precision("highest"):
+        want_y, want_h = ssm.ssm_chunk_scan_xla(x, dt, a, b, c, d, chunk)
+    np.testing.assert_allclose(y, want_y, rtol=2e-5, atol=2e-4)
+    np.testing.assert_allclose(h, want_h, rtol=2e-5, atol=2e-5)
 
 
 def test_state_update_is_one_step_of_the_recurrence():
