@@ -19,8 +19,9 @@ Layer map (PARITY.md §cluster, docs/cluster.md):
   restart-and-rejoin on the original submesh, and poison-run
   quarantine after ``quarantine_after`` fatal incarnations;
 - ``proc.ProcReplica`` / ``proc.build_proc_replicas`` — out-of-process
-  replicas: each backend runs in its own OS process (spawned with the
-  bench.py per-leg env recipe) behind the length-prefixed CRC-framed
+  replicas: each backend runs in its own OS process (spawned with
+  ``proc.worker_env``: pinned to the CPU, its own virtual device count)
+  behind the length-prefixed CRC-framed
   wire protocol (``wire.py``); the watchdog's liveness verdicts gain
   hard OS evidence (pipe EOF / exit codes) and the supervisor's
   ``rebuild`` restarts the actual process;

@@ -2,8 +2,8 @@
 
 The RCA pipeline is prefill-heavy (long Cypher-result and state-audit
 prompts) and decode-light (short JSON verdicts), so one homogeneous
-fleet leaves whichever phase is off-ratio idle (BENCH_r05's 0.41 sweep
-occupancy; ROADMAP item 1 move (b)).  ``TierRouter`` splits the fleet:
+fleet leaves whichever phase is off-ratio idle (ROADMAP item 1 move
+(b)).  ``TierRouter`` splits the fleet:
 a run ADMITS on the prefill tier, and once its prompt is computed its
 KV moves to a decode replica as the host-safe page records
 ``utils/pages.py`` already gathers/restores byte-identically.

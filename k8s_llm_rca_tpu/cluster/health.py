@@ -42,8 +42,8 @@ fleet.  This module closes the loop in-process:
 
 MTTD (last beat -> DEAD verdict) and MTTR (DEAD verdict -> rejoined)
 are measured on the watchdog's injectable clock and surfaced as
-``cluster.mttd`` / ``cluster.mttr`` spans plus lists on the objects for
-bench.py's measured-or-null fields.
+``cluster.mttd`` / ``cluster.mttr`` spans plus lists on the objects
+(``mttd_s``, ``mttr_s``) for whoever measures them on a wall clock.
 """
 
 from __future__ import annotations
@@ -112,7 +112,7 @@ class HealthWatchdog:
     escalates per ``HealthPolicy``.
 
     ``clock``: injectable time source (VirtualClock in soaks, wall time
-    in bench) — the same discipline as ``EngineBase._now``.  The clock
+    where it is measured) — the same discipline as ``EngineBase._now``.  The clock
     only timestamps MTTD/MTTR; classification depends on probe counts
     alone.
     """
@@ -304,7 +304,7 @@ class ReplicaSupervisor:
     fresh engine before rejoin, forcing compilation out of the serving
     path; never use it under an armed FaultPlan (the warmup ticks would
     shift ``SITE_ENGINE_TICK`` poll counters).  Rebuild + warmup wall
-    cost lands in ``restart_s`` (bench's ``selfheal_restart_warmup_s``).
+    cost lands in ``restart_s``.
     """
 
     def __init__(self, restart: bool = True,

@@ -1726,15 +1726,10 @@ class PagedInferenceEngine(EngineBase):
                      else _device_bytes_in_use() - held)
             METRICS.gauge("engine.latent_cache_bytes_per_token",
                           spent / (engine_cfg.num_pages * self.page_size))
-        # bytes of recurrent state the slots hold (0 for a model whose
-        # past is its pages), and the host's copy of the device's
-        # running counts of local expert pairs and compact-form overflows
-        self._state_bytes = sum(
-            a.nbytes for a in (self.pool.ssm_state, self.pool.conv_state)
-            if a is not None)
+        # the host's copy of the device's running counts of local expert
+        # pairs and compact-form overflows
         self._moe_seen = {"engine.moe_local_pairs": 0,
                           "engine.moe_compact_overflows": 0}
-        METRICS.gauge("engine.state_bytes", self._state_bytes)
         # bytes one page holds in one layer (scales included), and one
         # slot's ring in all the window layers: what the two cache gauges
         # count in (``_count_attn_pages``)
@@ -2145,7 +2140,7 @@ class PagedInferenceEngine(EngineBase):
         return g
 
     def _count_prefill_padded(self, n_positions: int, rows: int = 1,
-                              n_true: int = 0, row_lens=()) -> None:
+                              row_lens=()) -> None:
         """One prefill dispatch of ``n_positions`` (rows x bucket, pad
         included), and whether its expert MLPs took the token-grouped
         path (``llama.moe_grouped``, decided from the positions of one
@@ -2155,9 +2150,9 @@ class PagedInferenceEngine(EngineBase):
         Under EP or PP the MLP runs those paths' own dispatch, over other
         row counts, and nothing is counted.  A model with a layer table
         also counts what its Mamba-2 and expert layers ran over:
-        positions x Mamba layers (pad included, the ``n_true`` real ones
-        apart, and those whose scan ran as its kernel, which is all of
-        them wherever the prefill's kernels stand), and positions x picks
+        positions x Mamba layers (pad included, and those whose scan ran
+        as its kernel, which is all of them wherever the prefill's kernels
+        stand), and positions x picks
         x expert layers.  A model with
         latent attention counts the (query, key) pairs its rows' causal
         attention covers, from ``row_lens`` (every row the dispatch runs,
@@ -2181,8 +2176,6 @@ class PagedInferenceEngine(EngineBase):
         if cfg.layer_table:
             self._count("engine.ssm_prefill_tokens",
                         n_positions * cfg.n_ssm_layers)
-            self._count("engine.ssm_prefill_true_tokens",
-                        n_true * cfg.n_ssm_layers)
             if self._flash_prefill:
                 # the scan ran as its kernel (ops/ssm.py::ssm_chunk_scan)
                 self._count("engine.ssm_prefill_kernel_tokens",
@@ -2227,8 +2220,8 @@ class PagedInferenceEngine(EngineBase):
         """One decode dispatch of ``steps`` model steps, for a model with
         a layer table: the state updates it ran (slots x steps x Mamba
         layers) and, beside them, those of the slots that hold a live
-        sequence, the pairs its expert layers routed, and how many slots
-        are live.  Where the step runs its kernels the update walks the
+        sequence, and the pairs its expert layers routed.  Where the step
+        runs its kernels the update walks the
         slots whose table row holds a page (``paged_decode_step``): the
         updates it ran are those slots', and the rest of the slots'
         count as skipped.  The XLA form runs a dead slot's update too."""
@@ -2238,7 +2231,6 @@ class PagedInferenceEngine(EngineBase):
             if self.pool.moe_local_pairs is not None:
                 self._count_routed_pairs(b * steps)
             return
-        METRICS.gauge("engine.state_slots_live", len(self._active))
         ran = b
         if cfg.n_ssm_layers and decode_kernels_on(self.use_kernel,
                                                   self._kernel_mesh):
@@ -3039,8 +3031,7 @@ class PagedInferenceEngine(EngineBase):
             first = self._sample(logits, sub, self.sampling)
         with profiling.annotate("engine.admission.activate"):
             self._count("engine.prefill_tokens", len(rest))
-            self._count_prefill_padded(padded.size, n_true=len(rest),
-                                       row_lens=(len(rest),))
+            self._count_prefill_padded(padded.size, row_lens=(len(rest),))
 
             if req.grammar is not None:
                 # grammar first tokens stay synchronous: the FSM needs the
@@ -3440,7 +3431,6 @@ class PagedInferenceEngine(EngineBase):
         with profiling.annotate("engine.admission.activate"):
             self._count("engine.prefill_tokens", int(lens[:n].sum()))
             self._count_prefill_padded(tokens.size, rows=n_pad,
-                                       n_true=int(lens[:n].sum()),
                                        row_lens=lens)
             self._count("engine.batched_admissions", n)
 

@@ -280,7 +280,7 @@ def run_chaos_soak(seed: int = 0, n_incidents: int = 3,
 
     ``backend``: "engine" (the real paged TINY engine — tick faults and
     stalls bite) or "oracle" (scripted backend — graph faults only; the
-    cheap mode bench.py publishes alongside the engine soak), or their
+    cheap mode), or their
     multi-replica forms "cluster" / "cluster-oracle" — ``cluster_replicas``
     engines (or scripted oracles) on disjoint submeshes behind a
     ClusterRouter (cluster/router.py).  "proc-cluster" runs the oracle
@@ -751,7 +751,7 @@ def run_pipelined_sweep(seed: int = 0, n_incidents: int = 10,
     """Plan-free pipelined RCA sweep: ``concurrency`` incidents in flight
     over one shared backend (rca/scheduler.py::SweepScheduler).
 
-    This is the scheduling-parity and bench surface of ISSUE 11: the
+    This is the scheduling-parity surface of ISSUE 11: the
     returned ``report`` carries only scheduling-INVARIANT fields — per-
     incident statuses, degradation annotations, attempt counts, the
     decoded cypher queries and audit report texts, and exact run-id-
@@ -1147,8 +1147,8 @@ def run_open_loop_soak(seed: int = 0, rate_per_s: float = 200.0,
     exponential inter-arrivals feed ``create_run`` at ``rate_per_s``
     regardless of completions, and the report carries p50/p99
     time-to-report on the VirtualClock (each pump advances ``tick_s``,
-    so latency is a deterministic function of pump counts — the
-    measured-wall twin lives in bench.py).
+    so latency is a deterministic function of pump counts, never a
+    measured wall time).
 
     Composable with the kill-and-heal machinery for the SRE-storm
     scenario: ``killer`` (faults.supervisor.ReplicaKiller) is polled

@@ -1,9 +1,9 @@
 """Pipelined cross-incident sweep scheduler: K incidents in flight over
 one shared engine pump loop.
 
-The RCA sweep's occupancy gap (BENCH_r05: decode occupancy 0.99 inside a
-run vs 0.41 across the 100-incident sweep) is a SCHEDULING gap, not a
-kernel gap: every stage of the blocking pipeline parks in
+The RCA sweep's occupancy gap (the batch full inside a run, mostly empty
+across a sweep of incidents) is a SCHEDULING gap, not a kernel gap: every
+stage of the blocking pipeline parks in
 ``serve/api.py::wait_run`` while the continuous batcher idles between
 that incident's stages.  The reference sweep has the same shape — one
 incident at a time, one blocking OpenAI call at a time

@@ -4,8 +4,9 @@
 nothing.  Unset: one fixed directory inside the checkout — the path is part
 of the cache key, so a directory named after a pid, a time or a temp file
 would never hit.  Entry points that compile real-size programs
-(chip_smoke.py, bench.py legs, sweeps/*) call ``enable_compile_cache`` once
-before their first jit; tests keep the cache off (tests/conftest.py).
+(chip_smoke.py, sweeps/*) call ``enable_compile_cache`` once before their
+first jit; the benchmark places its own (benchmarks/lib/build.py); tests
+share one directory of their own outside the checkout (tests/conftest.py).
 """
 
 from __future__ import annotations

@@ -110,7 +110,7 @@ class LMBackend(Protocol):
 
 def _assert_fully_addressable(engine) -> None:
     """The engine's threaded serving driver (EngineBackend under worker
-    threads, e.g. bench_rca_p50_engine) has nondeterministic tick
+    threads, e.g. ``sweeps.run_file --workers``) has nondeterministic tick
     interleaving, while ``host_np``'s process_allgather path requires every
     process to issue identical host syncs in identical order — driving a
     process-spanning mesh through this backend would misalign the

@@ -3,8 +3,9 @@
 The reference's observability is bare ``print`` banners plus wall-clock
 bracketing (test_all.py:143-151, test_with_file.py:173-175).  This module
 keeps that per-phase timing but as structured, queryable records, and adds
-engine-side counters (tokens, steps, queue depth) that the sweep drivers and
-``bench.py`` report.
+engine-side counters (tokens, steps, queue depth) that the sweep drivers
+report and the benchmark's readers (benchmarks/lib/observe.py) turn into its
+per-layer metrics.
 """
 
 from __future__ import annotations

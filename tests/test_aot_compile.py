@@ -10,8 +10,9 @@
    in a scratch rehearsal (.claude/skills/verify/SKILL.md), but for one:
    the decode step at 2 layers, for what it must not do to the pool.
 2. No fallback hides the device: an unknown TPU kind has no peaks, a missing
-   TPU or a dead leg fails bench.py, an unknown ``--model`` is an argument
-   error, and the compile cache is placed from outside.
+   TPU or an unknown kind of one stops chip_smoke.py before any model, an
+   unknown ``--model`` is an argument error, and the compile cache is placed
+   from outside.
 """
 
 import functools
@@ -22,6 +23,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from conftest import open_compile_cache
 from k8s_llm_rca_tpu.config import LLAMA3_8B, MIXTRAL_8X7B
 from k8s_llm_rca_tpu.models.quant import QuantTensor, QuantTensor4
 from k8s_llm_rca_tpu.ops.flash_attention import flash_attention
@@ -42,8 +44,11 @@ BF16, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
 @pytest.fixture(scope="module")
 def chip():
     """Shapes placed on one chip of a described (not attached) v5e:2x2,
-    with the persistent compile cache off: a described-chip executable can
-    be written to the cache but not read back without a chip."""
+    with the persistent compile cache off for this file (the tests' shared
+    directory, tests/conftest.py, is opened again behind it): a
+    described-chip executable can be written to the cache but not read back
+    without a chip, and what this file proves is that Mosaic and XLA
+    compile these programs NOW, never that an earlier run did."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -61,6 +66,7 @@ def chip():
                                                     sharding=one_chip)
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
+    open_compile_cache()
 
 
 def _described(chip, tree):
@@ -944,22 +950,35 @@ class TestNoFallbackHidesTheDevice:
             profiling.mfu(CFG, 100.0, 512, device=_Device("tpu", "TPU v9"))
         assert profiling.chip_peaks(_Device("cpu", "cpu")) is None
 
-    def test_bench_fails_without_a_tpu(self, monkeypatch):
-        import bench
+    def test_chip_smoke_exits_before_any_model_without_a_tpu(
+            self, monkeypatch, capsys):
+        """The one entry point that measures on a chip: where JAX finds no
+        TPU it exits non-zero with no phase run, no model built and no
+        verdict printed."""
+        import chip_smoke
 
-        monkeypatch.setattr(bench, "_leg", lambda expr, timeout=0: {
-            "platform": "cpu", "kind": "cpu", "count": 1})
+        def refuse(*_, **__):
+            raise AssertionError("a phase ran without a TPU")
+
+        monkeypatch.setattr(chip_smoke, "run_phase", refuse)
+        # its meter would stay subscribed to jax.monitoring in this process
+        monkeypatch.setattr(chip_smoke, "CompileMeter", lambda: None)
+        # main() places the compile cache as every entry point does
+        monkeypatch.setenv(compile_cache.ENV_VAR, "/somewhere/else")
         with pytest.raises(SystemExit) as e:
-            bench.main()
+            chip_smoke.main([])
         assert e.value.code not in (0, None)
+        said = capsys.readouterr()
+        assert "JAX found no TPU" in said.err
+        assert '"ok"' not in said.out and '"phase"' not in said.out
 
-    def test_bench_leg_that_dies_raises(self):
-        import bench
+    def test_chip_smoke_unknown_tpu_kind_is_an_error(self, monkeypatch):
+        import chip_smoke
 
-        with pytest.raises(bench.LegFailed, match="rc=1"):
-            bench._leg("1 / 0")
-        with pytest.raises(bench.LegFailed, match="timed out"):
-            bench._leg("__import__('time').sleep(60)", timeout=1)
+        monkeypatch.setattr(jax, "devices",
+                            lambda *_: [_Device("tpu", "TPU v9")])
+        with pytest.raises(ValueError, match="TPU v9"):
+            chip_smoke.phase_device(1)
 
     def test_unknown_model_is_an_argument_error(self, capsys):
         from k8s_llm_rca_tpu.sweeps import run_file

@@ -21,6 +21,7 @@ import re
 import jax
 import pytest
 
+from conftest import tiny_on_a_tp_mesh
 from k8s_llm_rca_tpu.config import TINY, EngineConfig
 from k8s_llm_rca_tpu.engine import make_engine
 from k8s_llm_rca_tpu.faults import inject
@@ -563,6 +564,44 @@ class TestEngineSnapshotRestore:
             assert got[sid].token_ids == ref.token_ids
             assert got[sid].text == ref.text
         resume.allocator.check()
+
+    def test_mid_decode_snapshot_restores_into_fresh_tp_mesh_engine(
+            self, cpu_devices):
+        """Crash-resume on a mesh (moved here from the dryrun at PR 50):
+        a mid-decode snapshot of a GSPMD paged TP engine on dp2 x tp4
+        restores into a FRESH engine over the same sharded params, which
+        finishes byte-identically to the never-interrupted run."""
+        cfg, sharded, tok, mesh = tiny_on_a_tp_mesh(cpu_devices, 18)
+        ecfg = EngineConfig(max_batch=2, max_seq_len=64,
+                            prefill_buckets=(16, 32), max_new_tokens=8,
+                            page_size=8, num_pages=32, decode_chunk=1)
+
+        def fresh():
+            return make_engine(cfg, ecfg, sharded, tok, tp_mesh=mesh,
+                               use_kernel=False)
+
+        ids = [tok.encode(p, add_bos=True)
+               for p in ("pod crashloop kube-system", "node disk pressure")]
+        with jax.default_matmul_precision("float32"):
+            want = fresh().generate(ids, max_new_tokens=8)
+            crash = fresh()
+            for i in ids:
+                crash.submit(i, max_new_tokens=8)
+            results = []
+            for _ in range(3):
+                results.extend(crash.step())
+            snap = crash.snapshot_sequences()
+            assert snap["sequences"], "nothing live to snapshot"
+            # the crash: this engine's device KV is abandoned
+            resume = fresh()
+            resume.restore_sequences(snap)
+            while resume.has_work:
+                results.extend(resume.step())
+        resume.allocator.check()
+        got = sorted(results, key=lambda r: r.seq_id)
+        assert [r.token_ids for r in got] == [r.token_ids for r in want]
+        assert [r.prompt_tokens for r in got] == [
+            r.prompt_tokens for r in want]
 
     def test_restore_requires_fresh_fsm_for_grammar_sequences(
             self, tiny_engine):

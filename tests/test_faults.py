@@ -421,8 +421,7 @@ class TestChaosSoak:
         assert r["engine_clean"]
 
     def test_oracle_soak_byte_identical(self):
-        """The cheap soak mode (scripted backend, graph faults only) —
-        what bench.py's chaos leg publishes."""
+        """The cheap soak mode (scripted backend, graph faults only)."""
         from k8s_llm_rca_tpu.faults.soak import report_bytes, run_chaos_soak
 
         r1 = run_chaos_soak(seed=3, n_incidents=4, backend="oracle")

@@ -343,6 +343,59 @@ class TestMergedFleetTrace:
             assert set(row["segments_us"]) == set(SEGMENTS)
 
 
+    def test_respawned_worker_gets_a_track_of_its_own(self):
+        """A traced echo fleet, one worker SIGKILLed mid-flight and
+        respawned by the supervisor (moved here from the dryrun at PR 50;
+        the units above hold the buckets and the validator apart): the
+        merged document validates with the new incarnation on its own pid
+        track, frames were shipped, and every run's critical-path segments
+        sum to its total."""
+        from k8s_llm_rca_tpu.cluster import (
+            ClusterRouter, HealthPolicy, HealthWatchdog, ReplicaSupervisor,
+        )
+
+        tr = Tracer()
+        replicas = build_proc_replicas(2, kind="echo", echo_delay_pumps=2,
+                                       trace=True)
+        try:
+            with obs_trace.tracing(tr):
+                router = ClusterRouter(replicas)
+                router.attach_health(
+                    HealthWatchdog(HealthPolicy(miss_budget=1,
+                                                hung_tick_threshold=2),
+                                   clock=VirtualClock()),
+                    ReplicaSupervisor())
+                t0 = tr.now()
+                handles = [router.start(f"fleet obs {i}", GenOptions())
+                           for i in range(3)]
+                victim = max(router.alive_ids(), key=lambda r: (
+                    router.replicas[r].queue_depth(), r))
+                router.replicas[victim].kill_process()
+                out = {}
+                for _ in range(64):
+                    out.update(router.pump())
+                    if (all(h in out for h in handles) and all(
+                            r.healthy() for r in router.replicas.values())):
+                        break
+                assert all(h in out for h in handles)
+                tr.add_span("serve.run", t0, tr.now(), cat="serve",
+                            args={"run": "fleet-obs", "status": "completed"})
+        finally:
+            for r in replicas:
+                r.close()
+        doc = chrome_trace(tr)
+        validate_chrome_trace(doc)
+        tracks = [e["args"]["name"] for e in doc["traceEvents"]
+                  if e.get("ph") == "M" and e["name"] == "process_name"
+                  and e["pid"] != 1]
+        assert any(t.endswith("/1") for t in tracks), tracks
+        assert sum(r.backend.telemetry_frames for r in replicas) > 0
+        rows = critical_path(tr)
+        assert rows and all(
+            sum(row["segments_us"].values()) == row["total_us"]
+            for row in rows.values())
+
+
 # ---------------------------------------------------------------------------
 # acceptance: telemetry changes no fault draws (SIGKILL soak identity)
 # ---------------------------------------------------------------------------

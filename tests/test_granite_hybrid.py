@@ -241,12 +241,8 @@ def test_the_state_updates_are_counted_over_all_slots_and_the_live(
         assert count("engine.ssm_decode_slot_steps") == 4 * steps * n_m
         assert count("engine.ssm_decode_live_slot_steps") == (
             n_prompts * steps * n_m)
-        assert count("engine.state_bytes") == (
-            engine.pool.ssm_state.nbytes + engine.pool.conv_state.nbytes)
-        assert count("engine.state_slots_live") == n_prompts
         assert count("engine.ssm_prefill_tokens") == n_m * count(
             "engine.prefill_padded_tokens") > 0
-        assert count("engine.ssm_prefill_true_tokens") == n_m * sum(lengths)
         # no expert layer, so nothing is routed
         assert count("engine.moe_routed_pairs") == 0
 

@@ -338,12 +338,8 @@ def test_counters_of_the_new_layers(params):
         tokens_of(engine, prompts_of([40, 70, 33]), 12)
         count = METRICS.count
         n_m, n_e = CFG.n_ssm_layers, CFG.layer_pattern.count("E")
-        assert count("engine.state_bytes") == (
-            engine.pool.ssm_state.nbytes + engine.pool.conv_state.nbytes)
-        assert 1 <= count("engine.state_slots_live") <= 3
         assert count("engine.ssm_prefill_tokens") == n_m * count(
             "engine.prefill_padded_tokens")
-        assert count("engine.ssm_prefill_true_tokens") == n_m * (40 + 70 + 33)
         assert count("engine.ssm_decode_slot_steps") == (
             n_m * 4 * count("engine.decode_steps"))
         routed = count("engine.moe_routed_pairs")

@@ -14,6 +14,7 @@ import dataclasses
 import jax
 import pytest
 
+from conftest import tiny_on_a_tp_mesh
 from k8s_llm_rca_tpu.config import TINY, EngineConfig, MeshConfig
 from k8s_llm_rca_tpu.engine import make_engine
 from k8s_llm_rca_tpu.engine.constrain import SchemaGrammar, make_grammar
@@ -239,6 +240,40 @@ def test_pp_tp_overlap_matches_plain(setup, cpu_devices):
             [list(p) for p in prompts], max_new_tokens=6)
     for r, g in zip(plain, over):
         assert r.token_ids == g.token_ids
+
+
+def test_tp_mesh_overlap_matches_plain_with_resident_state(cpu_devices):
+    """The overlapped loop on a mesh (moved here from the dryrun at PR 50;
+    its two neighbours above are ``slow``): the same GSPMD paged TP engine
+    on dp2 x tp4 ticked stepwise with ``host_overlap`` off and on emits
+    the same greedy tokens, while the overlapped run's counters show
+    device-resident state (one dirty upload, no steady-state re-uploads)
+    and at most half the d2h sync points."""
+    cfg, params, tok, mesh = tiny_on_a_tp_mesh(cpu_devices, 21)
+    prompts = [tok.encode(s, add_bos=True) for s in
+               ("oom killed in payments", "dns resolution flaking",
+                "pvc stuck terminating")]
+
+    def run(overlap):
+        ecfg = EngineConfig(max_batch=2, max_seq_len=64,
+                            prefill_buckets=(16, 32), max_new_tokens=8,
+                            page_size=8, num_pages=32, decode_chunk=1,
+                            host_overlap=overlap)
+        eng = make_engine(cfg, ecfg, params, tok, tp_mesh=mesh,
+                          use_kernel=False)
+        with jax.default_matmul_precision("float32"):
+            res = eng.generate(prompts, max_new_tokens=8)
+        eng.allocator.check()
+        return [r.token_ids for r in res], dict(eng._counts)
+
+    plain, plain_counts = run(False)
+    over, over_counts = run(True)
+    assert plain == over
+    assert over_counts["engine.h2d_uploads"] == 3    # one dirty upload
+    assert over_counts["engine.h2d_uploads"] < plain_counts[
+        "engine.h2d_uploads"]
+    assert 2 * over_counts["engine.d2h_syncs"] <= plain_counts[
+        "engine.d2h_syncs"]
 
 
 def test_cp_composition_rejected_loudly(setup, cpu_devices):

@@ -17,6 +17,7 @@ import dataclasses
 import jax
 import pytest
 
+from conftest import tiny_on_a_tp_mesh
 from k8s_llm_rca_tpu.config import TINY, EngineConfig, MeshConfig
 from k8s_llm_rca_tpu.engine import make_engine
 from k8s_llm_rca_tpu.models import llama
@@ -112,6 +113,32 @@ class TestSpillParity:
         assert c.get("engine.spilled_pages", 0) > 0
         assert c.get("engine.restored_pages", 0) > 0
         assert c.get("engine.spill_budget_fallbacks", 0) == 0
+
+    def test_preempt_spill_restore_on_a_tp_mesh(self, cpu_devices):
+        """The same on a mesh (moved here from the dryrun at PR 50): a
+        GSPMD paged TP engine on dp2 x tp4 spills the preempted victim's
+        pages to host and restores them by h2d page writes; tokens are
+        those of the never-preempted run."""
+        cfg, params, tok, mesh = tiny_on_a_tp_mesh(cpu_devices, 31)
+        ecfg = _ecfg(max_seq_len=64, prefill_buckets=(16, 32),
+                     max_new_tokens=8, page_size=8, num_pages=32,
+                     decode_chunk=1, max_spilled_pages=32)
+
+        def run(preempt_at):
+            eng = make_engine(cfg, ecfg, params, tok, tp_mesh=mesh,
+                              use_kernel=False)
+            sids = [eng.submit(tok.encode(p, add_bos=True), priority=pri)
+                    for pri, p in enumerate(("api server latency spike",
+                                             "registry pull rate limited",
+                                             "coredns pods evicted"))]
+            with jax.default_matmul_precision("float32"):
+                return _drive(eng, sids, preempt_at), dict(eng._counts)
+
+        base, _ = run(None)
+        spill, c = run(2)
+        assert base == spill
+        assert c.get("engine.spilled_pages", 0) > 0
+        assert c.get("engine.restored_pages", 0) > 0
 
     def test_re_prefill_fallback_parity(self, setup):
         """With spill disabled the same preemption takes the legacy

@@ -540,6 +540,66 @@ def test_paged_tp_kernel_matches_unsharded(cpu_devices, use_kernel):
     eng.allocator.check()
 
 
+def test_paged_tp_kernel_engine_under_tick_faults_and_the_tracer(cpu_devices):
+    """The fault plan and the flight recorder on a multi-chip engine (both
+    moved here from the dryrun at PR 50; tests/test_faults.py and
+    tests/test_obs.py hold them on one device): an oom page steal, a forced
+    preemption and a host stall fire against the paged TP engine that
+    decodes through the kernel per head shard and leave greedy output and
+    page accounting untouched; a VirtualClock-bound tracer over the same
+    engine records tick spans and pool gauges, perturbs nothing, and its
+    Chrome export validates."""
+    from k8s_llm_rca_tpu.config import EngineConfig
+    from k8s_llm_rca_tpu.engine import make_engine
+    from k8s_llm_rca_tpu.faults import Fault, FaultPlan, inject
+    from k8s_llm_rca_tpu.faults.plan import VirtualClock
+    from k8s_llm_rca_tpu.obs import (
+        Tracer, chrome_trace, trace, validate_chrome_trace,
+    )
+    from k8s_llm_rca_tpu.runtime.sharding import (
+        llama_param_specs, shard_pytree,
+    )
+    from k8s_llm_rca_tpu.utils.tokenizer import get_tokenizer
+
+    cfg = TINY.replace(max_seq_len=64)
+    mesh = build_mesh(MeshConfig(data=4, model=2), devices=cpu_devices)
+    params = llama.init_params(cfg, jax.random.PRNGKey(12))
+    ecfg = EngineConfig(max_batch=4, max_seq_len=64, page_size=8,
+                        num_pages=32, prefill_buckets=(16, 32),
+                        max_new_tokens=4, decode_chunk=4)
+    tok = get_tokenizer(vocab_size=cfg.vocab_size)
+    prompts = [tok.encode("pod crashloop kube-system", add_bos=True)]
+    with jax.default_matmul_precision("float32"):
+        ref = make_engine(cfg, ecfg, params, tok, use_kernel=False).generate(
+            prompts, max_new_tokens=4)[0].token_ids
+        eng = make_engine(
+            cfg, ecfg, shard_pytree(params, llama_param_specs(cfg), mesh),
+            tok, tp_mesh=mesh, use_kernel=True)
+        assert eng._kernel_mesh is mesh
+
+        plan = FaultPlan([Fault(inject.SITE_ENGINE_TICK, 1, "oom"),
+                          Fault(inject.SITE_ENGINE_TICK, 2, "preempt"),
+                          Fault(inject.SITE_ENGINE_TICK, 3, "stall",
+                                delay_s=0.01)])
+        with inject.armed(plan):
+            # a longer budget so the schedule spans several ticks; the
+            # greedy prefix must match the unfaulted 4-token run
+            chaos = eng.generate(prompts, max_new_tokens=12)
+        assert chaos[0].token_ids[:len(ref)] == ref
+        assert plan.fired, "no scheduled tick fault fired"
+        eng.allocator.check()
+
+        tracer = Tracer(clock=VirtualClock())
+        with trace.tracing(tracer):
+            traced = eng.generate(prompts, max_new_tokens=4)
+    assert traced[0].token_ids == ref
+    assert tracer.timeline.total > 0, "no engine tick sampled"
+    assert {"engine.tick", "engine.prefill",
+            "engine.decode_step"} <= tracer.emitted_names()
+    assert tracer.timeline.samples()[-1].free_pages == eng.allocator.n_free
+    assert validate_chrome_trace(chrome_trace(tracer)) > 0
+
+
 def test_paged_tp_kernel_int8_pool_matches_unsharded(cpu_devices):
     """TP x int8 pool x kernel: paged_attention_quant_sharded (per-shard
     quantized kernel, replicated full-row scales) matches the unsharded

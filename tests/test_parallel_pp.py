@@ -206,8 +206,13 @@ def test_pp_paged_prefix_cache_reuse(cpu_devices, kv_dtype):
     eng.allocator.check()
 
 
-@pytest.mark.parametrize("kv_dtype", [None, "int8", "int4"])
-def test_pp_tp_paged_prefix_cache_reuse(cpu_devices, kv_dtype):
+@pytest.mark.parametrize("kv_dtype,weight_bits", [
+    (None, None), ("int8", None), ("int4", None),
+    # int4 weights (re-packed per shard) under the int4 pool: the reuse
+    # through the manual-TP chunk prefill at the quantization the smoke
+    # serves (moved here from the dryrun at PR 50)
+    ("int4", 4)])
+def test_pp_tp_paged_prefix_cache_reuse(cpu_devices, kv_dtype, weight_bits):
     """Prefix caching composes with PP×TP (round-4 review item 9 — the
     production mesh of the agent workload the cache was built for): a
     repeated prompt's second admission routes through the pipelined
@@ -225,6 +230,11 @@ def test_pp_tp_paged_prefix_cache_reuse(cpu_devices, kv_dtype):
     mesh = build_mesh(MeshConfig(stage=2, model=2),
                       devices=cpu_devices[:4])
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    if weight_bits:
+        from k8s_llm_rca_tpu.models.quant import quantize_params
+
+        params = quantize_params(params, compute_dtype=jnp.float32,
+                                 bits=weight_bits)
     tok = get_tokenizer(vocab_size=cfg.vocab_size)
     ecfg = EngineConfig(max_batch=2, max_seq_len=64, page_size=8,
                         num_pages=64, prefill_buckets=(16, 32),
@@ -476,10 +486,14 @@ def test_pp_composed_speculative_matches_plain(cpu_devices):
             assert r.token_ids == g.token_ids
 
 
-@pytest.mark.parametrize("page_size", [16, 8])
-@pytest.mark.parametrize("bits", [8, 4])
-def test_pp_tp_quantized_weights_matches_plain(cpu_devices, page_size,
-                                               bits):
+@pytest.mark.parametrize("bits,page_size,spec_k", [
+    (8, 16, 0), (8, 8, 0), (4, 16, 0), (4, 8, 0),
+    # int8 weights + int8 pool + the pipelined multi-token verify, against
+    # the plain engine that does not speculate (moved here from the dryrun
+    # at PR 50)
+    (8, 16, 2)])
+def test_pp_tp_quantized_weights_matches_plain(cpu_devices, bits, page_size,
+                                               spec_k):
     """Quantized WEIGHTS compose with PP×TP (the quantized-flagship pod
     serving shape): stacked QuantTensor leaves shard their payload on
     the weight spec and their per-channel scales with reduced dims
@@ -512,8 +526,9 @@ def test_pp_tp_quantized_weights_matches_plain(cpu_devices, page_size,
         ref = make_engine(cfg, ecfg, params, tok,
                           use_kernel=False).generate(
             prompts, max_new_tokens=6)
-        eng = make_engine(cfg, ecfg, params, tok, pp_mesh=mesh,
-                          tp_mesh=mesh, use_kernel=False)
+        eng = make_engine(
+            cfg, dataclasses.replace(ecfg, speculative_k=spec_k), params,
+            tok, pp_mesh=mesh, tp_mesh=mesh, use_kernel=False)
         got = eng.generate(prompts, max_new_tokens=6)
     for r, g in zip(ref, got):
         assert r.token_ids == g.token_ids

@@ -24,6 +24,7 @@ import os
 import jax
 import pytest
 
+from conftest import tiny_on_a_tp_mesh
 from k8s_llm_rca_tpu.config import TINY, EngineConfig, MeshConfig
 from k8s_llm_rca_tpu.engine import make_engine
 from k8s_llm_rca_tpu.engine.prefix import PrefixStore
@@ -132,6 +133,32 @@ class TestTieredParity:
         assert hits > 0, c
         assert c.get("engine.prefix_promoted_pages", 0) == hits
         assert c.get("engine.prefix_bytes_restored", 0) > 0
+
+    def test_demote_promote_on_a_tp_mesh(self, cpu_devices):
+        """The tiers on a mesh (moved here from the dryrun at PR 50; the
+        matrix above is single-device): a GSPMD paged TP engine on
+        dp2 x tp4 with a host store demotes every resident prefix page
+        d2h at eviction and a warm re-run promotes them back by h2d page
+        writes, tokens unchanged."""
+        cfg, params, tok, mesh = tiny_on_a_tp_mesh(cpu_devices, 37)
+        # 16 shared tokens = 2 full pages
+        prompts = ["shared preamble " + s for s in ("node oom", "dns fail")]
+        eng = make_engine(
+            cfg, _ecfg(max_seq_len=64, prefill_buckets=(32, 64),
+                       max_new_tokens=6, page_size=8, num_pages=32,
+                       decode_chunk=1, prefix_host_pages=32),
+            params, tok, tp_mesh=mesh, use_kernel=False)
+
+        def run():
+            with jax.default_matmul_precision("float32"):
+                return _drive(eng, [eng.submit(tok.encode(p, add_bos=True))
+                                    for p in prompts])
+
+        cold = run()
+        assert eng.prefix_cache.evict(10 ** 6) > 0
+        assert run() == cold
+        assert eng._counts.get("engine.prefix_demotions", 0) > 0
+        assert eng._counts.get("engine.prefix_hits_l1", 0) > 0
 
     def test_l2_hits_after_l1_overflow(self, setup, tmp_path):
         """With a tiny L1, demotion overflows the early-chain pages to

@@ -361,11 +361,15 @@ class TestPrefillChunkBudget:
         assert 32 < len(p) <= 64 - 6 - 1
         return p
 
-    @pytest.mark.parametrize(
-        "overlap", [False, pytest.param(True, marks=pytest.mark.slow)])
-    def test_byte_parity_vs_monolithic(self, overlap):
+    @pytest.mark.parametrize("overlap,fused", [
+        (False, False), pytest.param(True, False, marks=pytest.mark.slow),
+        # the fused flag and the budget together against neither (moved
+        # here from the dryrun at PR 50)
+        (False, True)])
+    def test_byte_parity_vs_monolithic(self, overlap, fused):
         ref_eng, tok = _quant_engine(TINY, host_overlap=overlap)
-        chunk_eng, _ = _quant_engine(TINY, prefill_chunk_budget=16,
+        chunk_eng, _ = _quant_engine(TINY, fused=fused,
+                                     prefill_chunk_budget=16,
                                      host_overlap=overlap)
         long_p = self._long_prompt(tok)
         short_p = tok.encode("node notready", add_bos=True)

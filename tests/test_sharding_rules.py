@@ -11,7 +11,8 @@
 - **page-record conversion** (``utils.pages.convert_page_record``): the
   deterministic page-size re-chunk the tier handoff rides, plus its
   loud refusals;
-- **exact greedy parity** (slow, virtual 8-device CPU mesh): fsdp and
+- **exact greedy parity** (virtual 8-device CPU mesh; ``slow`` but for
+  the fsdp×tp engine and the short-cache fleet): fsdp and
   fsdp×tp sharded engines decode byte-identically
   to the plain single-device engine, a 1P+2D TierRouter fleet with
   DIFFERING per-tier KV page sizes settles byte-identically, and a
@@ -359,16 +360,19 @@ def _plain_reference(cfg, ecfg, params, tok, prompt, opts):
             return res.text
 
 
-@pytest.mark.slow
 class TestFsdpGreedyParity:
     """Byte-identical greedy decode for every fsdp composition: the
     params are rule-sharded and COMMITTED before the engine builds, so
     GSPMD inserts the all-gathers (committed-input propagation) whether
-    or not the engine also receives the mesh for cache placement."""
+    or not the engine also receives the mesh for cache placement.  The
+    fsdp x tp case runs in tier-1 since PR 50 (the dryrun's row, which
+    no tier-1 test repeated); the others stay ``slow``."""
 
     @pytest.mark.parametrize("axes,pass_mesh", [
-        ({"fsdp": 4}, False),                 # fsdp-only, params-committed
-        ({"fsdp": 4}, True),                  # fsdp-only + cache placement
+        # fsdp-only, params-committed
+        pytest.param({"fsdp": 4}, False, marks=pytest.mark.slow),
+        # fsdp-only + cache placement
+        pytest.param({"fsdp": 4}, True, marks=pytest.mark.slow),
         ({"fsdp": 2, "model": 2}, True),      # fsdp×tp on one mesh
     ])
     def test_fsdp_matches_plain_engine(self, cpu_devices, axes, pass_mesh):
@@ -409,6 +413,7 @@ class TestFsdpGreedyParity:
         assert res.error is None
         assert res.text == want               # byte-identical greedy
 
+    @pytest.mark.slow
     def test_fsdp_cp_composition_is_refused_loudly(self, cpu_devices):
         from k8s_llm_rca_tpu.engine import make_engine
         from k8s_llm_rca_tpu.models import llama
@@ -426,20 +431,21 @@ class TestFsdpGreedyParity:
                         cp_mesh=mesh)
 
 
-@pytest.mark.slow
 @pytest.mark.disagg
 class TestPerTierLayoutParity:
-    def _fleet(self, cpu_devices, page_size_decode):
+    def _fleet(self, cpu_devices, page_size_decode, seq_len):
         from k8s_llm_rca_tpu.cluster.disagg import TierRouter
         from k8s_llm_rca_tpu.cluster.replica import build_replicas
 
-        cfg = TINY.replace(max_seq_len=512)
-        ecfg = EngineConfig(max_batch=2, max_seq_len=512,
-                            prefill_buckets=(512,), max_new_tokens=16,
+        n_pages = 96 * seq_len // 512
+        cfg = TINY.replace(max_seq_len=seq_len)
+        ecfg = EngineConfig(max_batch=2, max_seq_len=seq_len,
+                            prefill_buckets=(seq_len,), max_new_tokens=16,
                             temperature=0.0, page_size=16,
-                            num_pages=96, prefix_cache=False)
-        ecfg_d = dataclasses.replace(ecfg, page_size=page_size_decode,
-                                     num_pages=96 * 16 // page_size_decode)
+                            num_pages=n_pages, prefix_cache=False)
+        ecfg_d = dataclasses.replace(
+            ecfg, page_size=page_size_decode,
+            num_pages=n_pages * 16 // page_size_decode)
         # prefill TP-heavy (tp4), decode KV-wide (tp2 × 2 replicas) —
         # same checkpoint, same seed, different per-tier layouts
         pre = build_replicas(cfg, ecfg, 1, devices=cpu_devices[:4],
@@ -451,11 +457,15 @@ class TestPerTierLayoutParity:
             r.backend.engine.obs_replica = i + 1
         return cfg, ecfg, TierRouter(pre, dec)
 
+    # the short cache runs in tier-1 since PR 50 (the dryrun's row, which
+    # no tier-1 test repeated)
+    @pytest.mark.parametrize(
+        "seq_len", [128, pytest.param(512, marks=pytest.mark.slow)])
     def test_1p2d_differing_kv_page_sizes_settle_byte_identically(
-            self, cpu_devices):
+            self, cpu_devices, seq_len):
         from k8s_llm_rca_tpu.models import llama
 
-        cfg, ecfg, router = self._fleet(cpu_devices, page_size_decode=32)
+        cfg, ecfg, router = self._fleet(cpu_devices, 32, seq_len)
         assert router.replicas[0].kv_layout["page_size"] == 16
         assert router.replicas[1].kv_layout["page_size"] == 32
         params = llama.init_params(cfg, jax.random.PRNGKey(0))
@@ -473,6 +483,7 @@ class TestPerTierLayoutParity:
         assert res.text == want
         assert router.handoffs == 1
 
+    @pytest.mark.slow
     def test_mid_decode_relayout_adopt_is_byte_identical(self):
         """The conversion path proper: export mid-decode from a
         page_size=8 engine, adopt on a page_size=4 engine — the record
@@ -525,6 +536,7 @@ class TestPerTierLayoutParity:
         assert out[h2].error is None
         assert out[h2].text == ref[ref_h].text
 
+    @pytest.mark.slow
     def test_incompatible_kv_dtype_is_a_loud_adopt_error(self):
         from k8s_llm_rca_tpu.engine import make_engine
         from k8s_llm_rca_tpu.models import llama

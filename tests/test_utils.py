@@ -1,7 +1,14 @@
-"""utils/ coverage: tokenizer roundtrip properties and the METRICS sink."""
+"""utils/ coverage: tokenizer roundtrip properties and the METRICS sink;
+and the test harness's own shared compile directory (tests/conftest.py)."""
 
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
 import pytest
 
+from conftest import COMPILE_CACHE_DIR
 from k8s_llm_rca_tpu.utils.logging import Metrics
 from k8s_llm_rca_tpu.utils.tokenizer import ByteTokenizer, get_tokenizer
 
@@ -93,3 +100,40 @@ class TestMetrics:
         assert m.p50("t") == m.timings["t"][0]
         snap = m.snapshot()
         assert snap["a"] == 3 and "t.total_s" in snap
+
+
+class TestSharedCompileCache:
+    def test_an_entry_cut_short_is_a_miss_that_recompiles(self):
+        """The gate guarded against its own cache: a worker killed at the
+        limit while it writes an entry leaves the head of a file behind,
+        and every later run finds it.  Reading it is a miss (JAX warns and
+        compiles), never a failure."""
+        def fresh():
+            # a function object of its own each time, so nothing JAX holds
+            # in memory stands in for the directory
+            def _entry_cut_short_probe(x):
+                return jnp.cumsum(x * 3.0) + 1.0
+            return jax.jit(_entry_cut_short_probe)
+
+        def entries():
+            return glob.glob(os.path.join(
+                COMPILE_CACHE_DIR, "jit__entry_cut_short_probe-*"))
+
+        for path in entries():          # what a killed run of this test left
+            os.remove(path)
+        x = jnp.arange(8.0)
+        want = fresh()(x)
+        written = entries()
+        assert written, "the tests' compiles are not kept"
+        try:
+            for path in written:
+                with open(path, "r+b") as f:
+                    f.truncate(os.path.getsize(path) // 2)
+            with pytest.warns(UserWarning, match="Error reading persistent "
+                                                 "compilation cache entry"):
+                got = fresh()(x)
+            assert got.tolist() == want.tolist()
+        finally:
+            # JAX never writes over an entry that exists: take ours away
+            for path in entries():
+                os.remove(path)
